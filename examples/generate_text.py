@@ -40,9 +40,10 @@ def main():
     args = ap.parse_args()
 
     import jax
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        jax.config.update("jax_platforms", "cpu")  # wins over a pinned plugin
     import jax.numpy as jnp
+
+    from mpi_acx_tpu import backend
+    backend.enable_compile_cache()
 
     from mpi_acx_tpu.models import llama as lm
     from mpi_acx_tpu.models import transformer as tfm

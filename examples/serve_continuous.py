@@ -49,8 +49,8 @@ def main():
     if args.tp and args.int8_kv and args.family == "moe":
         ap.error("--tp --int8-kv: gpt2/llama only for now")
 
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        jax.config.update("jax_platforms", "cpu")  # wins over a pinned plugin
+    from mpi_acx_tpu import backend
+    backend.enable_compile_cache()
 
     from mpi_acx_tpu.models import serving
     # Under --tp the toy geometry scales with the mesh so the TP

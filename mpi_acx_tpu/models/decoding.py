@@ -16,10 +16,44 @@ import jax.numpy as jnp
 from jax import lax
 
 
+def to_cache_layout(x):
+    """Token-major K/V as the model computes it ``[..., S, H, D]`` (or
+    its kv_quant scales ``[..., S, H, 1]``) -> THE cache layout
+    ``[..., H, D, S]`` (scales ``[..., H, 1, S]``): heads outside,
+    features on sublanes, tokens on lanes. It is the layout in which a
+    block of one slot's tokens is a whole number of (8..32, 128) TPU
+    tiles for any head_dim that is a multiple of 32 — head_dim 64
+    included, which as a minor dimension would leave half of every
+    lane row empty — and the one XLA's TPU layout assignment picks for
+    such arrays by itself, so the Pallas decode kernels
+    (ops/flash_decode.py) read the cache with no relayout copy. Every
+    cache, page pool and KV wire partition in the tree holds this
+    layout; this function is the only crossing."""
+    return jnp.moveaxis(x, -3, -1)
+
+
+def new_kv_cache(n_layers, batch, n_kv_heads, head_dim, max_len, dtype,
+                 kv_int8: bool = False):
+    """Zeroed cache pytree in THE cache layout: {'k','v': [L, B, Hkv,
+    Dh, max_len], 'pos': int32}; ``kv_int8`` stores int8 codes plus
+    per-(position, head) f32 scales 'ks'/'vs' [L, B, Hkv, 1, max_len]
+    (ops/kvquant.py). Every family's ``init_kv_cache`` is this."""
+    shape = (n_layers, batch, n_kv_heads, head_dim, max_len)
+    cache = {
+        "k": jnp.zeros(shape, jnp.int8 if kv_int8 else dtype),
+        "v": jnp.zeros(shape, jnp.int8 if kv_int8 else dtype),
+        "pos": jnp.zeros((), jnp.int32),
+    }
+    if kv_int8:
+        cache["ks"] = jnp.zeros(shape[:3] + (1, max_len), jnp.float32)
+        cache["vs"] = jnp.zeros(shape[:3] + (1, max_len), jnp.float32)
+    return cache
+
+
 def grouped_decode_attend(q, kc, vc, pos, max_len, n_rep, flash=None):
     """W-token grouped-query attention against an UN-REPEATED KV cache:
     q [B, W, Hq, D] occupying positions pos..pos+W-1, kc/vc
-    [B, max_len, Hkv, D] with Hq = Hkv*n_rep -> o [B, W, Hq*D]. Query
+    [B, Hkv, D, max_len] with Hq = Hkv*n_rep -> o [B, W, Hq*D]. Query
     head g*n_rep + r reads K/V group g directly — no [B, L, Hq, D]
     materialization, preserving GQA's cache-bandwidth win; window row w
     attends cache entries <= pos+w. With n_rep=1 this IS plain
@@ -42,17 +76,17 @@ def grouped_decode_attend(q, kc, vc, pos, max_len, n_rep, flash=None):
 
 def dense_decode_attend(q, kc, vc, pos, max_len, n_rep):
     """Dense-einsum reference for :func:`grouped_decode_attend` — reads
-    the whole [B, max_len, Hkv, D] cache every step (the flash kernel's
+    the whole [B, Hkv, D, max_len] cache every step (the flash kernel's
     parity ground truth; also the dispatch target below the kernel's
     crossover and on non-TPU backends).
 
-    ``kc``/``vc`` may each be an ``(int8 codes, f32 scales [B, max_len,
-    Hkv, 1])`` tuple (ops/kvquant.py layout). The per-position scales
-    are then applied to the SMALL tensors — K's to the logits, V's to
-    the probabilities — never to the cache itself: the r05 chip A/B
-    showed the obvious dequantize-then-attend path at 0.73x the bf16
-    baseline because XLA materializes the dequantized [B, max_len, H,
-    D] tensor in HBM (int8 read + bf16 write + bf16 read — MORE
+    ``kc``/``vc`` may each be an ``(int8 codes, f32 scales [B, Hkv, 1,
+    max_len])`` tuple (ops/kvquant.py codes in cache layout). The
+    per-position scales are then applied to the SMALL tensors — K's to
+    the logits, V's to the probabilities — never to the cache itself:
+    the r05 chip A/B showed the obvious dequantize-then-attend path at
+    0.73x the bf16 baseline because XLA materializes the dequantized
+    cache tensor in HBM (int8 read + bf16 write + bf16 read — MORE
     traffic than the bf16 cache the codes were meant to halve). With
     the factoring, the full-cache operands stay int8 end-to-end.
     Algebraically identical: sum_d q_d*(K_kd*s_k) == (sum_d q_d*K_kd)
@@ -64,16 +98,16 @@ def dense_decode_attend(q, kc, vc, pos, max_len, n_rep):
     if isinstance(vc, tuple):
         vc, vs = vc
     B, W = q.shape[:2]
-    Hkv, Dh = kc.shape[2], kc.shape[3]
+    Hkv, Dh = kc.shape[1], kc.shape[2]
     qg = q.reshape(B, W, Hkv, n_rep, Dh)
     # Pre-scale q by 1/sqrt(Dh) (W*Hq*Dh elements) instead of dividing
     # the [B, g, r, W, max_len] f32 logits — same trick as _flash_kernel.
     qg = (qg.astype(jnp.float32) * (1.0 / Dh ** 0.5)).astype(q.dtype)
     kin = kc if ks is None else kc.astype(q.dtype)  # int8 exact in bf16
-    logits = jnp.einsum("bqgrd,bkgd->bgrqk", qg, kin).astype(jnp.float32)
+    logits = jnp.einsum("bqgrd,bgdk->bgrqk", qg, kin).astype(jnp.float32)
     if ks is not None:
-        # [B, max_len, Hkv, 1] -> [B, g, 1, 1, k] against bgrqk.
-        logits = logits * ks[..., 0].transpose(0, 2, 1)[:, :, None, None]
+        # [B, Hkv, 1, max_len] -> [B, g, 1, 1, k] against bgrqk.
+        logits = logits * ks[:, :, None]
     pos = jnp.asarray(pos)
     if pos.ndim == 0:
         rows = pos + jnp.arange(W)[:, None]            # [W, 1]
@@ -88,10 +122,10 @@ def dense_decode_attend(q, kc, vc, pos, max_len, n_rep):
     logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
     p = jax.nn.softmax(logits, axis=-1)
     if vs is not None:
-        p = p * vs[..., 0].transpose(0, 2, 1)[:, :, None, None]
+        p = p * vs[:, :, None]
     p = p.astype(q.dtype)
     vin = vc if vs is None else vc.astype(q.dtype)
-    return jnp.einsum("bgrqk,bkgd->bqgrd", p, vin).reshape(
+    return jnp.einsum("bgrqk,bgdk->bqgrd", p, vin).reshape(
         B, W, Hkv * n_rep * Dh)
 
 
@@ -103,18 +137,20 @@ def decode_layer_scan(layers, x, kc_all, vc_all, pos, qkv_fn, attend_fn,
     The KV cache rides the scan's CARRY with ONE in-place
     dynamic_update_slice per layer. Passing it as scan xs/ys instead (the
     obvious structure) makes XLA re-materialize the whole
-    [L, B, max_len, H, D] buffer every step — measured 1.9x slower
+    [L, B, H, D, max_len] buffer every step — measured 1.9x slower
     end-to-end GPT-2 decode on v5e (the copies, not attention math,
     dominated).
 
-    qkv_fn(lp, x, pos) -> (q, k [B,1,H,D], v); attend_fn(lp, x, q, kc_l,
-    vc_l, pos) -> x consumes the layer's UPDATED cache slices. Returns
+    The caches are [L, B, Hkv, D, max_len] (:func:`to_cache_layout`).
+    qkv_fn(lp, x, pos) -> (q, k [B,W,H,D], v), token-major as the model
+    computes them; attend_fn(lp, x, q, kc_l, vc_l, pos) -> x consumes
+    the layer's UPDATED cache slices. Returns
     (x, kc_all, vc_all). ``pos`` may be a scalar (every row at the same
     position — the generate paths) or [B] (each slot at its own
     position — continuous-batching serving, models/serving.py), in
     which case the cache writes vmap per slot.
 
-    With ``ksc_all``/``vsc_all`` ([L, B, max_len, H, 1] f32) the cache
+    With ``ksc_all``/``vsc_all`` ([L, B, H, 1, max_len] f32) the cache
     is INT8 (ops/kvquant.py): the fresh K/V vectors are quantized on
     write, the scale buffers ride the carry beside the code buffers,
     and attend_fn receives ``(codes, scales)`` tuples that
@@ -131,15 +167,16 @@ def decode_layer_scan(layers, x, kc_all, vc_all, pos, qkv_fn, attend_fn,
     slotwise = pos.ndim == 1   # per-slot positions (serving.py)
 
     def write(cache, fresh, i):
-        """Land fresh [B, 1, H, D] at this layer's write position(s):
+        """Land fresh [B, W, H, *] at this layer's write position(s):
         one slice write at scalar pos, a vmapped per-slot write when
         each slot sits at its own position."""
+        fresh = to_cache_layout(fresh)                 # [B, H, *, W]
         if not slotwise:
             return lax.dynamic_update_slice(cache, fresh[None],
-                                            (i, 0, pos, 0, 0))
+                                            (i, 0, 0, 0, pos))
         layer = lax.dynamic_index_in_dim(cache, i, 0, keepdims=False)
         layer = jax.vmap(
-            lambda c, f, p: lax.dynamic_update_slice(c, f, (p, 0, 0)))(
+            lambda c, f, p: lax.dynamic_update_slice(c, f, (0, 0, p)))(
             layer, fresh, pos)
         return lax.dynamic_update_index_in_dim(cache, layer, i, 0)
 
@@ -181,22 +218,29 @@ def decode_layer_scan(layers, x, kc_all, vc_all, pos, qkv_fn, attend_fn,
     return x, kc_all, vc_all
 
 
-def fill_kv_cache(cache, ks, vs, pos):
-    """Land the prefill K/V ([L, B, S, H, D], compute dtype) into a
-    fresh cache from a family's ``init_kv_cache`` and set ``pos`` —
-    quantizing when the cache is int8 ('ks' present). The ONE
-    definition of the fill, so the int8 layout can't drift between
-    families."""
+def pack_kv(k, v, kv_int8: bool):
+    """Token-major K/V ``[..., S, H, D]`` as a prompt pass computes
+    them -> the form that lands in a cache, a page pool or a KV wire
+    partition: ``{'k','v': [..., H, D, S]}`` in cache layout, quantized
+    to int8 codes plus ``'ks','vs'`` f32 scales ``[..., H, 1, S]`` when
+    ``kv_int8``. The ONE definition, so neither the int8 form nor the
+    layout can drift between the families and the serving planes."""
     from mpi_acx_tpu.ops.kvquant import kv_quant
-    if "ks" in cache:
-        ks, kscale = kv_quant(ks)
-        vs, vscale = kv_quant(vs)
-        cache["ks"] = lax.dynamic_update_slice(cache["ks"], kscale,
-                                               (0,) * 5)
-        cache["vs"] = lax.dynamic_update_slice(cache["vs"], vscale,
-                                               (0,) * 5)
-    cache["k"] = lax.dynamic_update_slice(cache["k"], ks, (0,) * 5)
-    cache["v"] = lax.dynamic_update_slice(cache["v"], vs, (0,) * 5)
+    out = {}
+    if kv_int8:
+        k, ks = kv_quant(k)
+        v, vs = kv_quant(v)
+        out["ks"], out["vs"] = to_cache_layout(ks), to_cache_layout(vs)
+    out["k"], out["v"] = to_cache_layout(k), to_cache_layout(v)
+    return out
+
+
+def fill_kv_cache(cache, ks, vs, pos):
+    """Land the prefill K/V ([L, B, S, H, D], token-major, compute
+    dtype) into a fresh cache from a family's ``init_kv_cache`` and set
+    ``pos`` — quantizing when the cache is int8 ('ks' present)."""
+    for key, val in pack_kv(ks, vs, "ks" in cache).items():
+        cache[key] = lax.dynamic_update_slice(cache[key], val, (0,) * 5)
     cache["pos"] = jnp.asarray(pos, jnp.int32)
     return cache
 

@@ -172,21 +172,20 @@ def make_layerwise_prefill_fns(params, cfg, family=None):
     Only the dense transformer scaffold is supported (the layer
     internals are family-specific; llama/MoE would need their own
     block closures)."""
+    from mpi_acx_tpu.backend import jit_bound
     from mpi_acx_tpu.models import transformer as tfm
-    from mpi_acx_tpu.ops.kvquant import kv_quant
+    from mpi_acx_tpu.models.decoding import pack_kv
     from mpi_acx_tpu.ops.wquant import wread
     if family is not None and family is not tfm:
         raise NotImplementedError(
             "layerwise prefill: dense transformer family only")
 
-    @jax.jit
-    def embed_fn(tokens):
+    def embed(params, tokens):
         S = tokens.shape[1]
         return (params["embed"][tokens]
                 + params["pos"][:S]).astype(cfg.dtype)
 
-    @jax.jit
-    def layer_fn(x, layer):
+    def layer_step(params, x, layer):
         lp = jax.tree.map(
             lambda a: lax.dynamic_index_in_dim(a, layer, 0,
                                                keepdims=False),
@@ -195,8 +194,7 @@ def make_layerwise_prefill_fns(params, cfg, family=None):
         x = x + tfm._attend(cfg, q, k, v) @ wread(lp, "wo", x.dtype)
         return tfm._mlp(cfg, lp, x), k, v
 
-    @jax.jit
-    def head_fn(x, last_index):
+    def head(params, x, last_index):
         x = tfm.layernorm(x, params["lnf_g"], params["lnf_b"])
         x = lax.dynamic_slice_in_dim(x, last_index, 1, axis=1)
         return jnp.einsum("bsd,vd->bsv", x,
@@ -205,11 +203,15 @@ def make_layerwise_prefill_fns(params, cfg, family=None):
 
     @jax.jit
     def quant_fn(k, v):
-        kq, ks = kv_quant(k)
-        vq, vs = kv_quant(v)
-        return kq, ks, vq, vs
+        # The wire carries what lands in the cache: codes + scales in
+        # cache layout ([1, H, *, bucket]; decoding.pack_kv).
+        one = pack_kv(k, v, True)
+        return one["k"], one["ks"], one["v"], one["vs"]
 
-    return embed_fn, layer_fn, head_fn, quant_fn
+    # The weights are arguments of the compiled programs, not constants
+    # baked into each of them (backend.jit_bound).
+    return (jit_bound(embed, params), jit_bound(layer_step, params),
+            jit_bound(head, params), quant_fn)
 
 
 def _prefill_ship(ch, pfns, cfg, padded, last_index, overlap,
@@ -274,15 +276,15 @@ def _prefill_ship(ch, pfns, cfg, padded, last_index, overlap,
 
 def _splice_poll(ch, bucket, heads, head_dim, timeout_s=30.0):
     """Poll every layer partition, splicing arrivals into the assembled
-    [L, 1, bucket, ...] host cache as they land (arrival order, not
+    [L, 1, H, *, bucket] host cache as they land (arrival order, not
     layer order). Raises AcxTimeoutError past ``timeout_s`` — the
     bound that keeps a decode rank from spinning forever on a prefill
     rank that died before heartbeat detection."""
     from mpi_acx_tpu.runtime import ERR_TIMEOUT, AcxTimeoutError
     L = ch.geom.n_layers
-    kq = np.zeros((L, 1, bucket, heads, head_dim), np.int8)
+    kq = np.zeros((L, 1, heads, head_dim, bucket), np.int8)
     vq = np.zeros_like(kq)
-    ks = np.zeros((L, 1, bucket, heads, 1), np.float32)
+    ks = np.zeros((L, 1, heads, 1, bucket), np.float32)
     vs = np.zeros_like(ks)
     pending = set(range(L))
     deadline = time.monotonic() + timeout_s

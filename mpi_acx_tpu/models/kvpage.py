@@ -10,8 +10,10 @@ per-slot rows with a shared pool of fixed-size PAGES (default 128
 tokens, matching flash-decode's block granularity) and a per-slot
 block table:
 
-* **Pool** — ``{'k','v': [L, P, page_tokens, H, Dh]}`` device buffers
-  (plus ``'ks','vs'`` f32 scale pages when the cache is int8 —
+* **Pool** — ``{'k','v': [L, P, H, Dh, page_tokens]}`` device buffers
+  in the cache layout (decoding.to_cache_layout: a page of one head is
+  a ``[Dh, page_tokens]`` slab of whole TPU tiles), plus ``'ks','vs'``
+  ``[L, P, H, 1, page_tokens]`` f32 scale pages when the cache is int8 —
   ops/kvquant.py codes + scales stay the only page-resident form, the
   same EQuARX rule the wire plane enforces). The trailing ``n_slots``
   pages of P are per-slot PARKING pages: an idle slot's table points
@@ -33,7 +35,7 @@ block table:
   invariant defensively.
 
 Bit-equality contract: the paged dense attend gathers the slot's
-pages into the SAME ``[B, max_len, H, Dh]`` shape the fixed-slot path
+pages into the SAME ``[B, H, Dh, max_len]`` shape the fixed-slot path
 attends (mpi_acx_tpu/ops/flash_decode.py:paged_gather_attend), so on
 a cold (no-prefix-hit) schedule paged greedy serving is bit-equal to
 fixed-slot ``serve_greedy`` — dead gathered positions contribute
@@ -249,19 +251,15 @@ class RadixPrefixCache:
 
 def init_page_pool(cfg, n_pages: int, page_tokens: int, n_slots: int,
                    kv_int8: bool = False):
-    """Zeroed page pool: ``{'k','v': [L, P, page_tokens, H, Dh]}``
-    (+ ``'ks','vs'`` f32 scale pages when int8) with
-    ``P = n_pages + n_slots`` — the trailing ``n_slots`` pages are the
-    per-slot parking pages (module docstring), outside the allocator."""
-    P = n_pages + n_slots
-    shape = (cfg.n_layers, P, page_tokens, cfg.n_heads, cfg.head_dim)
-    pool = {
-        "k": jnp.zeros(shape, jnp.int8 if kv_int8 else cfg.dtype),
-        "v": jnp.zeros(shape, jnp.int8 if kv_int8 else cfg.dtype),
-    }
-    if kv_int8:
-        pool["ks"] = jnp.zeros(shape[:-1] + (1,), jnp.float32)
-        pool["vs"] = jnp.zeros(shape[:-1] + (1,), jnp.float32)
+    """Zeroed page pool: ``{'k','v': [L, P, H, Dh, page_tokens]}``
+    (+ ``'ks','vs'`` [L, P, H, 1, page_tokens] f32 scale pages when
+    int8) with ``P = n_pages + n_slots`` — the trailing ``n_slots``
+    pages are the per-slot parking pages (module docstring), outside
+    the allocator. A pool IS a cache whose batch axis counts pages."""
+    from mpi_acx_tpu.models.decoding import new_kv_cache
+    pool = new_kv_cache(cfg.n_layers, n_pages + n_slots, cfg.n_heads,
+                        cfg.head_dim, page_tokens, cfg.dtype, kv_int8)
+    del pool["pos"]
     return pool
 
 
@@ -287,7 +285,7 @@ def paged_decode_step(params, cfg, state, token, page_tokens: int,
     ``transformer.decode_step`` exactly (same _qkv/attend/ffn math, so
     active slots are bit-equal to the fixed-slot step) with the cache
     writes routed through the block table: layer i's fresh K/V for
-    slot b lands at ``pool[i, table[b, pos_b // pt], pos_b % pt]``.
+    slot b lands at ``pool[i, table[b, pos_b // pt], :, :, pos_b % pt]``.
     ``state`` = pool keys + ``'table'`` [B, max_pages] + ``'pos'``
     [B]. Idle slots write their parking page (their table rows point
     nowhere else) and the page index is clipped so a long-idle slot's
@@ -310,12 +308,12 @@ def paged_decode_step(params, cfg, state, token, page_tokens: int,
     off = pos % page_tokens
 
     def write(pool, fresh, i):
-        """pool [L, P, pt, H, *]; fresh [B, 1, H, *] -> slot b's row
-        (write_page[b], off[b]). Distinct pages per slot (each slot
-        owns its pages; idle slots own their parking page), so the
-        scatter never collides."""
+        """pool [L, P, H, *, pt]; fresh [B, 1, H, *] -> slot b's token
+        column (write_page[b], :, :, off[b]). Distinct pages per slot
+        (each slot owns its pages; idle slots own their parking page),
+        so the scatter never collides."""
         layer = lax.dynamic_index_in_dim(pool, i, 0, keepdims=False)
-        layer = layer.at[write_page, off].set(
+        layer = layer.at[write_page, :, :, off].set(
             fresh[:, 0].astype(pool.dtype))
         return lax.dynamic_update_index_in_dim(pool, layer, i, 0)
 
@@ -372,10 +370,10 @@ def make_paged_step_fn(params, cfg, family, chunk: int,
     """Jitted chunked decode step over the paged state (the paged
     sibling of make_server_fns' step_fn — greedy only; the state is
     donated so XLA updates the pool in place)."""
+    from mpi_acx_tpu.backend import jit_bound
     _check_family(family)
 
-    @partial(jax.jit, donate_argnums=(0,))
-    def step_fn(state, tok, keys):
+    def step(params, state, tok, keys):
         def one(carry, _):
             state, tok, keys = carry
             logits, state = paged_decode_step(params, cfg, state, tok,
@@ -386,7 +384,7 @@ def make_paged_step_fn(params, cfg, family, chunk: int,
                                           length=chunk)
         return state, toks, keys
 
-    return step_fn
+    return jit_bound(step, params, donate_argnums=(1,))
 
 
 # --------------------------------------------------------------------------
@@ -394,16 +392,18 @@ def make_paged_step_fn(params, cfg, family, chunk: int,
 
 
 def prefill_with_history(params, cfg, suffix, hk, hv, last_index,
-                         ffn=None):
+                         ffn=None, kv_int8: bool = False):
     """Prefill ONLY the suffix of a prompt whose first ``P`` tokens'
     K/V are already paged in (a radix prefix hit): ``suffix``
     [1, S_suf] tokens occupying absolute positions ``P..P+S_suf-1``,
-    ``hk``/``hv`` [L, P, H, Dh] the gathered (dequantized) history.
-    Per layer the suffix queries attend ``concat(history, suffix)``
-    through the shared :func:`dense_decode_attend` definition (pos=P
-    scalar — row w sees cols <= P + w, full history + causal suffix).
-    Returns (logits [1, 1, vocab] at ``last_index``, suffix K/V
-    [L, 1, S_suf, H, Dh] in compute dtype, ready for page scatter).
+    ``hk``/``hv`` [L, H, Dh, P] the gathered (dequantized) history in
+    cache layout. Per layer the suffix queries attend ``concat(history,
+    suffix)`` through the shared :func:`dense_decode_attend` definition
+    (pos=P scalar — row w sees cols <= P + w, full history + causal
+    suffix). Returns (logits [1, 1, vocab] at ``last_index``, the
+    suffix K/V as a :func:`decoding.pack_kv` dict [L, 1, H, *, S_suf]
+    ready for :meth:`PagedKV.scatter_prompt` — int8 codes + scales when
+    ``kv_int8``).
 
     The compute skipped is the point: a hit at depth P runs S_suf
     rows through the trunk instead of P + S_suf. The cost is bitwise
@@ -411,20 +411,23 @@ def prefill_with_history(params, cfg, suffix, hk, hv, last_index,
     pass, so hit-path logits match cold only to numerics (docs/
     DESIGN.md §19)."""
     from mpi_acx_tpu.models import transformer as tfm
-    from mpi_acx_tpu.models.decoding import dense_decode_attend
+    from mpi_acx_tpu.models.decoding import (dense_decode_attend, pack_kv,
+                                             to_cache_layout)
     from mpi_acx_tpu.ops.wquant import wread
 
     ffn = ffn or tfm._mlp
     B, Sb = suffix.shape
-    P = hk.shape[1]
+    P = hk.shape[-1]
     x = (params["embed"][suffix]
          + params["pos"][P:P + Sb]).astype(cfg.dtype)
 
     def body(x, xs):
         lp, hkl, hvl = xs
         q, k, v = tfm._qkv(cfg, lp, x)
-        kcat = jnp.concatenate([hkl[None].astype(x.dtype), k], axis=1)
-        vcat = jnp.concatenate([hvl[None].astype(x.dtype), v], axis=1)
+        kcat = jnp.concatenate(
+            [hkl[None].astype(x.dtype), to_cache_layout(k)], axis=-1)
+        vcat = jnp.concatenate(
+            [hvl[None].astype(x.dtype), to_cache_layout(v)], axis=-1)
         o = dense_decode_attend(q, kcat, vcat, P, P + Sb, 1)
         x = x + o @ wread(lp, "wo", x.dtype)
         return ffn(cfg, lp, x), (k, v)
@@ -435,7 +438,7 @@ def prefill_with_history(params, cfg, suffix, hk, hv, last_index,
     logits = jnp.einsum("bsd,vd->bsv", x,
                         params["embed"].astype(x.dtype),
                         preferred_element_type=jnp.float32)
-    return logits, ks, vs
+    return logits, pack_kv(ks, vs, kv_int8)
 
 
 # --------------------------------------------------------------------------
@@ -618,13 +621,13 @@ class PagedKV:
     def scatter_prompt(self, one, pages: List[int], start_page: int = 0
                        ) -> None:
         """Write a prefilled cache (``one`` = {'k','v'[,'ks','vs']:
-        [L, 1, S_bucket, H, *]}) into ``pages`` — page d takes bucket
-        rows [d*pt, (d+1)*pt) (zero-padded rows past the prompt are
+        [L, 1, H, *, S_bucket]}) into ``pages`` — page d takes bucket
+        tokens [d*pt, (d+1)*pt) (zero-padded tokens past the prompt are
         never attended). ``start_page`` offsets the SOURCE rows only
         (0 for a cold full-prompt scatter; unused pages cost
         nothing — only ``len(pages)`` pages are written)."""
         pt = self.page_tokens
-        bucket = one["k"].shape[2]
+        bucket = one["k"].shape[-1]
         keys = tuple(k for k in _POOL_KEYS if k in one and k in self.pool)
         ck = (bucket, len(pages), keys)
         if ck not in self._scatter_cache:
@@ -636,7 +639,7 @@ class PagedKV:
                     if n <= 0:
                         break
                     for key in keys:
-                        src = one[key][:, 0, j * pt:j * pt + n]
+                        src = one[key][:, 0, ..., j * pt:j * pt + n]
                         pool[key] = lax.dynamic_update_slice(
                             pool[key], src[:, None].astype(
                                 pool[key].dtype),
@@ -648,9 +651,9 @@ class PagedKV:
                 self.pool, one, jnp.asarray(pages, jnp.int32))
 
     def gather_history(self, pages: List[int]):
-        """Gather ``pages`` into contiguous [L, n*pt, H, Dh] history
-        K/V in compute dtype (dequantizing int8 pages — the only
-        page-resident form — through their f32 scales)."""
+        """Gather ``pages`` into contiguous [L, H, Dh, n*pt] history
+        K/V (cache layout) in compute dtype (dequantizing int8 pages —
+        the only page-resident form — through their f32 scales)."""
         ck = len(pages)
         if ck not in self._gather_cache:
             @jax.jit
@@ -661,10 +664,11 @@ class PagedKV:
                 if "ks" in pool:
                     k = k.astype(jnp.float32) * grab("ks")
                     v = v.astype(jnp.float32) * grab("vs")
-                L = k.shape[0]
-                shp = (L, ck * self.page_tokens) + k.shape[3:]
-                return (k.reshape(shp).astype(self.cfg.dtype),
-                        v.reshape(shp).astype(self.cfg.dtype))
+                def join(t):          # [L, n, H, Dh, pt] -> [L, H, Dh, n*pt]
+                    t = jnp.moveaxis(t, 1, 3)
+                    return t.reshape(t.shape[:3] + (-1,)).astype(
+                        self.cfg.dtype)
+                return join(k), join(v)
             self._gather_cache[ck] = _gather
         return self._gather_cache[ck](
             self.pool, jnp.asarray(pages, jnp.int32))

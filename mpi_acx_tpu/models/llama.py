@@ -208,17 +208,11 @@ def loss_fn(params: Params, cfg: LlamaConfig, tokens: jax.Array,
 def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int,
                   kv_int8: bool = False):
     """GQA cache (n_kv_heads, the memory win); ``kv_int8=True`` stores
-    int8 codes + per-(position, head) f32 scales (ops/kvquant.py)."""
-    shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
-    cache = {
-        "k": jnp.zeros(shape, jnp.int8 if kv_int8 else cfg.dtype),
-        "v": jnp.zeros(shape, jnp.int8 if kv_int8 else cfg.dtype),
-        "pos": jnp.zeros((), jnp.int32),
-    }
-    if kv_int8:
-        cache["ks"] = jnp.zeros(shape[:-1] + (1,), jnp.float32)
-        cache["vs"] = jnp.zeros(shape[:-1] + (1,), jnp.float32)
-    return cache
+    int8 codes + per-(position, head) f32 scales (ops/kvquant.py).
+    Layout [L, B, Hkv, Dh, max_len] (decoding.new_kv_cache)."""
+    from mpi_acx_tpu.models.decoding import new_kv_cache
+    return new_kv_cache(cfg.n_layers, batch, cfg.n_kv_heads, cfg.head_dim,
+                        max_len, cfg.dtype, kv_int8)
 
 
 def prefill(params: Params, cfg: LlamaConfig, tokens: jax.Array,
@@ -268,7 +262,7 @@ def decode_step(params: Params, cfg: LlamaConfig, cache,
     (decoding.decode_layer_scan): in-place updates, 1.9x faster decode
     on v5e than scan-ys stacking."""
     pos = jnp.asarray(cache["pos"])
-    max_len = cache["k"].shape[2]
+    max_len = cache["k"].shape[-1]
     n_rep = cfg.n_heads // cfg.n_kv_heads
     x = params["embed"][token][:, None, :].astype(cfg.dtype)
     # Scalar pos -> shared position [1]; per-slot pos [B] (serving) ->

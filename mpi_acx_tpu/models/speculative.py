@@ -58,7 +58,7 @@ def _window_pass_llama(params, cfg, cache, tokens):
     decoding.grouped_decode_attend)."""
     W = tokens.shape[1]
     pos = cache["pos"]
-    max_len = cache["k"].shape[2]
+    max_len = cache["k"].shape[-1]
     n_rep = cfg.n_heads // cfg.n_kv_heads
     x = params["embed"][tokens].astype(cfg.dtype)
     positions = pos + jnp.arange(W)
@@ -110,7 +110,7 @@ def _window_pass(params, cfg, cache, tokens, ffn=None):
     ffn = ffn or tfm._mlp
     W = tokens.shape[1]
     pos = cache["pos"]
-    max_len = cache["k"].shape[2]
+    max_len = cache["k"].shape[-1]
     x = (params["embed"][tokens]
          + lax.dynamic_slice_in_dim(params["pos"], pos, W, 0)[None]
          ).astype(cfg.dtype)

@@ -179,28 +179,23 @@ def cast_params(params: Params, dtype=jnp.bfloat16) -> Params:
 
 # -- KV-cache decode -------------------------------------------------------
 #
-# Static-shape autoregressive inference: the cache holds [L, B, max_len, H,
-# Dh] for k and v; every decode step attends over the full cache width with
+# Static-shape autoregressive inference: the cache holds [L, B, H, Dh,
+# max_len] for k and v (decoding.to_cache_layout: tokens on the lane
+# dimension); every decode step attends over the full cache width with
 # an iota<=pos mask, so the jitted step has one shape for the whole
 # generation (no recompiles, MXU-friendly).
 
 
 def init_kv_cache(cfg: TransformerConfig, batch: int, max_len: int,
                   kv_int8: bool = False):
-    """Zeroed cache pytree: {'k','v': [L, B, max_len, H, Dh], 'pos':
+    """Zeroed cache pytree: {'k','v': [L, B, H, Dh, max_len], 'pos':
     int32}. ``kv_int8=True`` stores int8 codes plus per-(position,
-    head) f32 scale buffers 'ks'/'vs' (ops/kvquant.py) — half the
-    cache-read bandwidth, the binding term at long max_len."""
-    shape = (cfg.n_layers, batch, max_len, cfg.n_heads, cfg.head_dim)
-    cache = {
-        "k": jnp.zeros(shape, jnp.int8 if kv_int8 else cfg.dtype),
-        "v": jnp.zeros(shape, jnp.int8 if kv_int8 else cfg.dtype),
-        "pos": jnp.zeros((), jnp.int32),
-    }
-    if kv_int8:
-        cache["ks"] = jnp.zeros(shape[:-1] + (1,), jnp.float32)
-        cache["vs"] = jnp.zeros(shape[:-1] + (1,), jnp.float32)
-    return cache
+    head) f32 scale buffers 'ks'/'vs' [L, B, H, 1, max_len]
+    (ops/kvquant.py) — half the cache-read bandwidth, the binding term
+    at long max_len."""
+    from mpi_acx_tpu.models.decoding import new_kv_cache
+    return new_kv_cache(cfg.n_layers, batch, cfg.n_heads, cfg.head_dim,
+                        max_len, cfg.dtype, kv_int8)
 
 
 def _qkv(cfg: TransformerConfig, lp: Params, x: jax.Array):
@@ -277,7 +272,7 @@ def decode_step(params: Params, cfg: TransformerConfig, cache,
     faster decode on v5e than the scan-xs/ys structure."""
     ffn = ffn or _mlp
     pos = jnp.asarray(cache["pos"])
-    max_len = cache["k"].shape[2]
+    max_len = cache["k"].shape[-1]
     # Scalar pos: one learned position row for the whole batch; [B]
     # pos (continuous-batching serving): each slot reads its own row.
     pe = (params["pos"][pos][:, None, :] if pos.ndim

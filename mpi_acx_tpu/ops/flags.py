@@ -37,6 +37,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from mpi_acx_tpu import backend
+
 # Op states — the wire protocol shared with the native runtime
 # (include/acx/state.h; reference mpi-acx-internal.h:196-203).
 AVAILABLE = 0
@@ -48,12 +50,6 @@ CLEANUP = 5
 
 _LANE = 128
 _MIN_ROWS = 8  # int32 min tile is (8, 128)
-
-
-def _interpret() -> bool:
-    # Compiled Mosaic kernels need a real TPU; everywhere else (the CPU
-    # test mesh, the driver's virtual-device dryrun) use interpret mode.
-    return jax.default_backend() != "tpu"
 
 
 def _padded(flags: jax.Array):
@@ -97,7 +93,7 @@ def pready(flags: jax.Array, idx: jax.Array | int) -> jax.Array:
         ],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         input_output_aliases={1: 0},
-        interpret=_interpret(),
+        interpret=not backend.on_tpu(),
     )(idx, f2)
     return out.reshape(-1)[:n]
 
@@ -127,7 +123,7 @@ def pready_many(flags: jax.Array, idxs: jax.Array) -> jax.Array:
         ],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         input_output_aliases={1: 0},
-        interpret=_interpret(),
+        interpret=not backend.on_tpu(),
     )(idxs, f2)
     return out.reshape(-1)[:n]
 
@@ -151,7 +147,7 @@ def parrived(flags: jax.Array, idx: jax.Array | int) -> jax.Array:
             pl.BlockSpec(memory_space=pltpu.VMEM),
         ],
         out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
-        interpret=_interpret(),
+        interpret=not backend.on_tpu(),
     )(idx, f2)
     return out[0, 0]
 
@@ -182,7 +178,7 @@ def parrived_all(flags: jax.Array, idxs: jax.Array) -> jax.Array:
             pl.BlockSpec(memory_space=pltpu.VMEM),
         ],
         out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
-        interpret=_interpret(),
+        interpret=not backend.on_tpu(),
     )(idxs, f2)
     return out[0, 0]
 
@@ -228,6 +224,6 @@ def produce_and_pready(
             pl.BlockSpec(memory_space=pltpu.VMEM),
         ),
         input_output_aliases={2: 1},
-        interpret=_interpret(),
+        interpret=not backend.on_tpu(),
     )(idx, x, f2)
     return payload, fout.reshape(-1)[:n]

@@ -3,35 +3,52 @@
 :func:`mpi_acx_tpu.models.decoding.grouped_decode_attend` — the single
 decode-attention definition every family, the serving loop, the
 speculative window passes, and the TP generation loops share — is a
-dense einsum that reads the ENTIRE ``[B, max_len, Hkv, D]`` cache every
-token, even when a slot sits at position 40 of 4096 (measured ~17% of
-the KV-bandwidth roofline on the longctx bench). This kernel replaces
-that read with an online softmax over K/V blocks (the same
-``_online_softmax_step`` as ops/attention.py — THE shared block-update
-definition) that is
+dense einsum that reads the ENTIRE ``[B, Hkv, D, max_len]`` cache every
+token, even when a slot sits at position 40 of 4096. This kernel replaces
+that read with an online softmax over K/V blocks that is
 
-* **length-aware** — each slot's ``pos`` lands in SMEM and bounds the
-  fori_loop at ``ceil((pos + W) / block_k)`` blocks, with per-row
-  causal masking only on the straddle block. The K/V cache stays in HBM
-  (``memory_space=ANY``) and each program DMAs exactly the live blocks
-  into VMEM scratch, so HBM traffic is O(live length), not O(max_len).
-* **GQA-native** — q ``[B, W, Hkv, n_rep, D]`` rides the grid as
+* **length-aware** — the per-slot ``pos`` vector is scalar-prefetched
+  into SMEM; the grid walks ``(slot, K/V block)`` and the block index
+  map clamps at the slot's last LIVE block ``ceil((pos + W) / block_k)
+  - 1``. Pallas re-fetches a block only when its index changes, so the
+  dead tail of a cache row never crosses HBM->VMEM, and the steps past
+  the horizon skip their compute. HBM traffic is O(live length), not
+  O(max_len), and the block DMAs are double-buffered against compute by
+  the pipeline.
+* **GQA-native** — q ``[B, W, Hkv, n_rep, D]`` rides as
   ``[B, Hkv, W*n_rep, D]`` (row ``i`` is window slot ``i // n_rep``),
-  attending the UN-repeated KV group directly.
+  attending the UN-repeated KV groups directly; one grid step carries
+  ALL of a slot's KV heads (one head-batched matmul), so a step moves
+  ``Hkv * D * block_k`` elements per operand, not one head's sliver.
 * **int8-fused** — when the cache is an ``(int8 codes, f32 scales)``
-  tuple (ops/kvquant.py), the codes blocks are dequantized IN REGISTER
-  in VMEM via the per-position scales: ``kb = codes_f32 * scales``.
-  Algebraically identical to the dense path's scale-on-scores factoring
-  (``sum_d q_d*(K_kd*s_k) == (sum_d q_d*K_kd)*s_k``), but int8 is the
-  only HBM-resident form and the only form that crosses the DMA — the
-  bytes halving the factoring was built for finally reaches the wire.
+  tuple (ops/kvquant.py), int8 is the only HBM-resident form and the
+  only form that crosses the DMA. The per-position scales arrive as
+  ``[Hkv, 1, block_k]`` lane rows and are applied to the SMALL tensors —
+  K's to the scores, V's to the probabilities — the same
+  scale-on-scores factoring as the dense path
+  (``sum_d q_d*(K_kd*s_k) == (sum_d q_d*K_kd)*s_k``).
 * **window-capable** — W > 1 for the speculative-decode window passes,
   and ``pos`` scalar or ``[B]`` for continuous-batching serving.
+* **paged or contiguous** — one kernel body: block j is either tokens
+  ``[j*block_k, (j+1)*block_k)`` of the slot's own cache row or pool
+  page ``table[b, j]`` (models/kvpage.py), resolved in the index map
+  from a second scalar-prefetched operand.
+
+Cache layout (models/decoding.to_cache_layout): ``[B, Hkv, D,
+max_len]``, pool ``[P, Hkv, D, page_tokens]``, scales ``[..., 1, T]`` —
+tokens on the lane dimension. A block is then ``[Hkv, D, block_k]``:
+whole (8..32, 128) tiles for any head_dim that is a multiple of 32, with
+no lane padding at head_dim 64, in the layout XLA's TPU layout
+assignment gives such arrays anyway (no relayout copy in front of the
+kernel). With tokens outside heads and features minor the chip's
+compiler refuses to slice a head out of the ``(Hkv, D)`` tile, and
+refuses any DMA slice of an array whose minor dimension is 64.
 
 Dispatch mirrors ``select_attention``: :func:`select_decode_attend` is
 the ONE flash/dense decode switch (``decode_flash`` config field on all
-three families). Off-TPU the pallas_call runs in interpret mode, so the
-tier-1 CPU tests exercise this exact code path.
+three families). Off-TPU the pallas_call runs in interpret mode; that
+checks the math, not that the chip's compiler accepts the kernel —
+tests/test_tpu_compile.py compiles it for a described v5e.
 """
 
 from __future__ import annotations
@@ -43,272 +60,183 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from mpi_acx_tpu.ops.attention import (_NEG_INF, _online_softmax_step,
-                                       _out_struct)
-
-# jax renamed TPUCompilerParams -> CompilerParams; accept either.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
+from mpi_acx_tpu import backend
+from mpi_acx_tpu.ops.attention import _NEG_INF, _out_struct
 
 
 def _fit_block_k(max_len, want):
-    """Largest divisor of max_len <= want, preferring 128-multiples
-    (Mosaic-native tiling); any divisor as a last resort (interpret
-    mode, where arbitrary cache lengths are legal)."""
-    b = min(want, max_len)
-    while b > 128 and max_len % b:
+    """Largest 128-multiple divisor of max_len <= want (whole lane
+    tiles); a cache with none gets its largest divisor, which only
+    interpret mode accepts."""
+    b = min(want, max_len) // 128 * 128
+    while b >= 128:
+        if max_len % b == 0:
+            return b
         b -= 128
+    b = min(want, max_len)
     while max_len % b:
         b -= 1
     return b
 
 
-_fallback_warned: set = set()
+def _n_live(pos, W, block_k, n_k):
+    """Blocks that carry any live key of a slot at ``pos``: block j
+    holds cache cols [j*bk, (j+1)*bk) and the last visible col is
+    pos + W - 1."""
+    return jnp.minimum((pos + W + block_k - 1) // block_k, n_k)
 
 
-def _warn_dense_fallback(max_len):
-    if max_len not in _fallback_warned:
-        _fallback_warned.add(max_len)
-        import warnings
+def _decode_kernel(*refs, block_k, n_rep, n_k, quant, n_prefetch, scale):
+    """One (batch slot, K/V block) grid step: fold block j of every KV
+    head of this slot into the online-softmax state held in VMEM
+    scratch across the sequential block dimension.
 
-        warnings.warn(
-            f"flash_decode: max_len={max_len} is not a multiple of 128; "
-            "Mosaic cannot tile the cache — using the dense decode "
-            "reference for this cache", RuntimeWarning, stacklevel=3)
-
-
-def _decode_kernel(pos_ref, q_ref, *refs, block_k, n_rep, n_k, quant,
-                   scale):
-    """One (batch slot, KV group) program: online softmax over the LIVE
-    K/V blocks of this slot's cache row.
-
-    ``pos_ref`` is this slot's position in SMEM — it sets the trip
-    counts, so a slot at position 40 of a 4096 cache issues one block's
-    DMA, not 16. Blocks [0, n_full) are visible to every window row and
-    run unmasked; blocks [n_full, n_live) straddle some row's horizon
-    and mask with the ABSOLUTE row positions ``pos + i // n_rep``
-    (row i of the [W*n_rep, D] q tile is window slot i // n_rep — not
-    affine in i, hence the ``rows=`` form of _online_softmax_step).
-    K/V HBM refs are manually DMA'd block-by-block into VMEM scratch;
-    with ``quant`` the scales ride two extra [block_k, 1] f32 copies
-    and dequantization happens in register, after the wire."""
+    ``pos_ref`` ([B], SMEM) bounds the live blocks; steps at or past
+    ``n_live`` neither fetch (the index map repeats the last live
+    block) nor compute. Every live block masks with the ABSOLUTE row
+    positions ``pos + i // n_rep`` (row i of the [W*n_rep, D] q tile is
+    window slot i // n_rep); on fully visible blocks the mask is all
+    true. With ``quant`` the K/V blocks are int8 codes and their scales
+    [Hkv, 1, block_k] multiply the scores / probabilities."""
+    pos_ref = refs[0]       # a block table after it is the index map's
+    q_ref, k_ref, v_ref, *refs = refs[n_prefetch:]
     if quant:
-        (k_ref, v_ref, ks_ref, vs_ref, o_ref,
-         k_scr, v_scr, ks_scr, vs_scr, sem) = refs
-    else:
-        k_ref, v_ref, o_ref, k_scr, v_scr, sem = refs
-    b = pl.program_id(0)
-    g = pl.program_id(1)
-    pos = pos_ref[0, 0]
-    Wn, D = q_ref.shape[2], q_ref.shape[3]
-    W = Wn // n_rep
+        ks_ref, vs_ref, *refs = refs
+    o_ref, m_scr, l_scr, acc_scr = refs
+    j = pl.program_id(1)
+    pos = pos_ref[pl.program_id(0)]
+    Wn = q_ref.shape[2]
 
-    # Pre-scale q once (the _flash_kernel idiom); on the quant path q
-    # stays f32 to dot against the dequantized f32 blocks exactly.
-    qv = q_ref[0, 0].astype(jnp.float32) * scale         # [Wn, D]
-    if quant:
-        q, prec = qv, jax.lax.Precision.HIGHEST
-    else:
-        q = qv.astype(q_ref.dtype)
-        prec = (jax.lax.Precision.HIGHEST if q_ref.dtype == jnp.float32
-                else jax.lax.Precision.DEFAULT)
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # Absolute row positions for the straddle-block mask.
-    rows = pos + jax.lax.broadcasted_iota(jnp.int32, (Wn, 1), 0) // n_rep
-
-    def load(j):
-        cps = [pltpu.make_async_copy(
-                   k_ref.at[b, pl.ds(j * block_k, block_k), g],
-                   k_scr, sem.at[0]),
-               pltpu.make_async_copy(
-                   v_ref.at[b, pl.ds(j * block_k, block_k), g],
-                   v_scr, sem.at[1])]
+    @pl.when(j < _n_live(pos, Wn // n_rep, block_k, n_k))
+    def _fold():
+        # Pre-scale q once (the _flash_kernel idiom); on the quant path
+        # q stays f32 to dot against the f32-converted codes exactly.
+        q = q_ref[0].astype(jnp.float32) * scale            # [Hkv, Wn, D]
+        kb, vb = k_ref[0], v_ref[0]                         # [Hkv, D, bk]
         if quant:
-            cps += [pltpu.make_async_copy(
-                        ks_ref.at[b, pl.ds(j * block_k, block_k), g],
-                        ks_scr, sem.at[2]),
-                    pltpu.make_async_copy(
-                        vs_ref.at[b, pl.ds(j * block_k, block_k), g],
-                        vs_scr, sem.at[3])]
-        for c in cps:
-            c.start()
-        for c in cps:
-            c.wait()
+            kb, vb = kb.astype(jnp.float32), vb.astype(jnp.float32)
+            prec = jax.lax.Precision.HIGHEST
+        else:
+            q = q.astype(q_ref.dtype)
+            prec = (jax.lax.Precision.HIGHEST
+                    if q_ref.dtype == jnp.float32
+                    else jax.lax.Precision.DEFAULT)
+        s = jax.lax.dot_general(
+            q, kb, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32, precision=prec)
         if quant:
-            return (k_scr[...].astype(jnp.float32) * ks_scr[...],
-                    v_scr[...].astype(jnp.float32) * vs_scr[...])
-        return k_scr[...], v_scr[...]
+            s = s * ks_ref[0]                               # [Hkv, Wn, bk]
+        rows = pos + jax.lax.broadcasted_iota(
+            jnp.int32, (1, Wn, 1), 1) // n_rep
+        cols = j * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (1, 1, block_k), 2)
+        s = jnp.where(rows >= cols, s, _NEG_INF)
+        m = m_scr[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m - m_new)
+        l_scr[...] = corr * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
+        if quant:
+            p = p * vs_ref[0]
+        acc_scr[...] = corr * acc_scr[...] + jax.lax.dot_general(
+            p.astype(vb.dtype), vb, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32, precision=prec)
+        m_scr[...] = m_new
 
-    def step(j, carry, masked):
-        m, l, acc = carry
-        kb, vb = load(j)
-        return _online_softmax_step(q, kb, vb, m, l, acc, 0, j * block_k,
-                                    masked, prec, rows=rows)
-
-    m0 = jnp.full((Wn, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((Wn, 1), jnp.float32)
-    acc0 = jnp.zeros((Wn, D), jnp.float32)
-
-    # Block-skip bounds: block j holds cache cols [j*bk, (j+1)*bk); the
-    # last visible col is pos + W - 1, so n_live = ceil((pos+W)/bk)
-    # blocks carry any live key. A block is FULLY visible to every row
-    # when its last col <= pos (row 0's horizon): n_full blocks.
-    n_live = jnp.minimum((pos + W + block_k - 1) // block_k, n_k)
-    n_full = jnp.minimum((pos + 1) // block_k, n_live)
-    carry = jax.lax.fori_loop(
-        0, n_full, lambda j, c: step(j, c, masked=False), (m0, l0, acc0))
-    m, l, acc = jax.lax.fori_loop(
-        n_full, n_live, lambda j, c: step(j, c, masked=True), carry)
-    o_ref[0, 0] = (acc / l).astype(o_ref.dtype)
+    @pl.when(j == n_k - 1)
+    def _finish():
+        o_ref[0] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
 
 
-def flash_decode_attend(q, kc, vc, pos, max_len, n_rep, block_k: int = 256):
-    """Length-aware Pallas decode attention; drop-in for
-    :func:`mpi_acx_tpu.models.decoding.dense_decode_attend` — same
-    signature, same output [B, W, Hq*D], same (codes, scales) tuple
-    convention for int8 caches. See the module docstring."""
+def _decode_call(q, k, v, pos, n_rep, block_k, n_k, table=None):
+    """The one pallas_call behind both kernels. ``k``/``v`` are K/V
+    arrays or (codes, scales) tuples in cache layout
+    ([B, Hkv, *, max_len]) or, with ``table``, pool layout
+    ([P, Hkv, *, page_tokens])."""
     ks = vs = None
-    if isinstance(kc, tuple):
-        kc, ks = kc
-    if isinstance(vc, tuple):
-        vc, vs = vc
+    if isinstance(k, tuple):
+        k, ks = k
+    if isinstance(v, tuple):
+        v, vs = v
     quant = ks is not None
-    if jax.default_backend() == "tpu" and max_len % 128:
-        _warn_dense_fallback(max_len)
-        from mpi_acx_tpu.models.decoding import dense_decode_attend
-        kin = kc if ks is None else (kc, ks)
-        vin = vc if vs is None else (vc, vs)
-        return dense_decode_attend(q, kin, vin, pos, max_len, n_rep)
+    interpret = not backend.on_tpu()
+    if not interpret and block_k % 128:
+        raise ValueError(
+            f"flash decode: a K/V block of {block_k} tokens is not a "
+            "whole number of 128-lane tiles, and the chip's compiler "
+            "does not take it. Use a max_len / page_tokens that is a "
+            "multiple of 128, or decode_flash=False for the dense "
+            "reference.")
 
     B, W, Hq, D = q.shape
-    Hkv = kc.shape[2]
+    Hkv = k.shape[1]
     assert Hq == Hkv * n_rep, (Hq, Hkv, n_rep)
     Wn = W * n_rep
-    block_k = _fit_block_k(max_len, block_k)
 
     pos = jnp.asarray(pos, jnp.int32)
     if pos.ndim == 0:
         pos = jnp.full((B,), pos, jnp.int32)
-    pos2 = pos.reshape(B, 1)
+    prefetch = [pos]
+    if table is not None:
+        prefetch.append(jnp.asarray(table, jnp.int32))
 
     # [B, W, Hkv, n_rep, D] -> [B, Hkv, W*n_rep, D]: row i = w*n_rep + r
     # so the kernel recovers the window slot as i // n_rep.
     qg = q.reshape(B, W, Hkv, n_rep, D).transpose(0, 2, 1, 3, 4).reshape(
         B, Hkv, Wn, D)
 
-    kernel = functools.partial(
-        _decode_kernel, block_k=block_k, n_rep=n_rep,
-        n_k=max_len // block_k, quant=quant, scale=1.0 / D ** 0.5)
-    in_specs = [
-        pl.BlockSpec((1, 1), lambda b, g: (b, 0),
-                     memory_space=pltpu.SMEM),
-        pl.BlockSpec((1, 1, Wn, D), lambda b, g: (b, g, 0, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec(memory_space=pltpu.ANY),     # K cache stays in HBM
-        pl.BlockSpec(memory_space=pltpu.ANY),     # V cache stays in HBM
-    ]
-    operands = [pos2, qg, kc, vc]
-    scratch = [pltpu.VMEM((block_k, D), kc.dtype),
-               pltpu.VMEM((block_k, D), vc.dtype)]
-    if quant:
-        in_specs += [pl.BlockSpec(memory_space=pltpu.ANY)] * 2
-        operands += [ks, vs]
-        scratch += [pltpu.VMEM((block_k, 1), jnp.float32)] * 2
-    scratch.append(pltpu.SemaphoreType.DMA((4,)))
+    def kv_index(b, j, pos_ref, *table_ref):
+        jj = jnp.minimum(j, _n_live(pos_ref[b], W, block_k, n_k) - 1)
+        if table_ref:
+            return (table_ref[0][b, jj], 0, 0, 0)
+        return (b, 0, 0, jj)
 
+    q_spec = pl.BlockSpec((1, Hkv, Wn, D), lambda b, j, *_: (b, 0, 0, 0))
+    in_specs = [q_spec] + [pl.BlockSpec((1, Hkv, D, block_k), kv_index)] * 2
+    operands = [qg, k, v]
+    if quant:
+        in_specs += [pl.BlockSpec((1, Hkv, 1, block_k), kv_index)] * 2
+        operands += [ks, vs]
+
+    kernel = functools.partial(
+        _decode_kernel, block_k=block_k, n_rep=n_rep, n_k=n_k,
+        quant=quant, n_prefetch=len(prefetch), scale=1.0 / D ** 0.5)
     out = pl.pallas_call(
         kernel,
-        grid=(B, Hkv),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, Wn, D), lambda b, g: (b, g, 0, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=_out_struct((B, Hkv, Wn, D), q.dtype, q, kc, vc),
-        scratch_shapes=scratch,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
-        interpret=jax.default_backend() != "tpu",
-    )(*operands)
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch), grid=(B, n_k),
+            in_specs=in_specs, out_specs=q_spec,
+            scratch_shapes=[pltpu.VMEM((Hkv, Wn, 1), jnp.float32),
+                            pltpu.VMEM((Hkv, Wn, 1), jnp.float32),
+                            pltpu.VMEM((Hkv, Wn, D), jnp.float32)]),
+        out_shape=_out_struct((B, Hkv, Wn, D), q.dtype, q, k, v),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(*prefetch, *operands)
     return out.reshape(B, Hkv, W, n_rep, D).transpose(0, 2, 1, 3, 4).reshape(
         B, W, Hq * D)
 
 
-def _paged_decode_kernel(pos_ref, table_ref, q_ref, *refs, page_tokens,
-                         n_rep, n_k, quant, scale):
-    """The paged sibling of :func:`_decode_kernel`: one (batch slot, KV
-    group) program whose K/V blocks are POOL PAGES resolved through the
-    slot's block table instead of contiguous rows of a private cache.
-    ``table_ref`` rides SMEM next to ``pos`` — the per-slot ``pos``
-    plumbing generalized to a ``[B, max_pages]`` row — and block j's
-    DMA source is ``k_ref.at[table[j]]`` in the
-    ``[P, page_tokens, Hkv, D]`` pool. Everything else (q pre-scale,
-    GQA rows, n_full/n_live trip counts, the _online_softmax_step
-    order) is byte-for-byte the fixed kernel's math, which is the
-    bit-equality proof: at ``block_k == page_tokens`` the two kernels
-    run identical FLOPs over identical block values."""
-    if quant:
-        (k_ref, v_ref, ks_ref, vs_ref, o_ref,
-         k_scr, v_scr, ks_scr, vs_scr, sem) = refs
-    else:
-        k_ref, v_ref, o_ref, k_scr, v_scr, sem = refs
-    b = pl.program_id(0)
-    g = pl.program_id(1)
-    pos = pos_ref[0, 0]
-    Wn, D = q_ref.shape[2], q_ref.shape[3]
-    W = Wn // n_rep
-
-    qv = q_ref[0, 0].astype(jnp.float32) * scale         # [Wn, D]
-    if quant:
-        q, prec = qv, jax.lax.Precision.HIGHEST
-    else:
-        q = qv.astype(q_ref.dtype)
-        prec = (jax.lax.Precision.HIGHEST if q_ref.dtype == jnp.float32
-                else jax.lax.Precision.DEFAULT)
-
-    rows = pos + jax.lax.broadcasted_iota(jnp.int32, (Wn, 1), 0) // n_rep
-
-    def load(j):
-        page = table_ref[0, j]
-        cps = [pltpu.make_async_copy(k_ref.at[page, :, g], k_scr,
-                                     sem.at[0]),
-               pltpu.make_async_copy(v_ref.at[page, :, g], v_scr,
-                                     sem.at[1])]
-        if quant:
-            cps += [pltpu.make_async_copy(ks_ref.at[page, :, g], ks_scr,
-                                          sem.at[2]),
-                    pltpu.make_async_copy(vs_ref.at[page, :, g], vs_scr,
-                                          sem.at[3])]
-        for c in cps:
-            c.start()
-        for c in cps:
-            c.wait()
-        if quant:
-            return (k_scr[...].astype(jnp.float32) * ks_scr[...],
-                    v_scr[...].astype(jnp.float32) * vs_scr[...])
-        return k_scr[...], v_scr[...]
-
-    def step(j, carry, masked):
-        m, l, acc = carry
-        kb, vb = load(j)
-        return _online_softmax_step(q, kb, vb, m, l, acc, 0,
-                                    j * page_tokens, masked, prec,
-                                    rows=rows)
-
-    m0 = jnp.full((Wn, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((Wn, 1), jnp.float32)
-    acc0 = jnp.zeros((Wn, D), jnp.float32)
-
-    n_live = jnp.minimum((pos + W + page_tokens - 1) // page_tokens, n_k)
-    n_full = jnp.minimum((pos + 1) // page_tokens, n_live)
-    carry = jax.lax.fori_loop(
-        0, n_full, lambda j, c: step(j, c, masked=False), (m0, l0, acc0))
-    m, l, acc = jax.lax.fori_loop(
-        n_full, n_live, lambda j, c: step(j, c, masked=True), carry)
-    o_ref[0, 0] = (acc / l).astype(o_ref.dtype)
+def flash_decode_attend(q, kc, vc, pos, max_len, n_rep, block_k: int = 256):
+    """Length-aware Pallas decode attention; drop-in for
+    :func:`mpi_acx_tpu.models.decoding.dense_decode_attend` — same
+    signature, same output [B, W, Hq*D], same (codes, scales) tuple
+    convention for int8 caches. See the module docstring. Compiled for
+    the chip, a ``max_len`` with no 128-multiple divisor raises."""
+    block_k = _fit_block_k(max_len, block_k)
+    return _decode_call(q, kc, vc, pos, n_rep, block_k, max_len // block_k)
 
 
 def paged_gather_attend(q, kp, vp, table, pos, page_tokens, n_rep):
     """Dense reference for paged attention: gather each slot's pages
-    into the contiguous ``[B, max_len, Hkv, D]`` layout the fixed-slot
+    into the contiguous ``[B, Hkv, D, max_len]`` layout the fixed-slot
     path attends and call :func:`dense_decode_attend` — identical
     shapes, identical XLA reduction, so a paged slot whose pages hold
     the fixed cache's rows produces BIT-EQUAL output (gathered garbage
@@ -320,8 +248,9 @@ def paged_gather_attend(q, kp, vp, table, pos, page_tokens, n_rep):
     max_len = max_pages * page_tokens
 
     def gather(pool):
-        t = jnp.take(pool, table, axis=0)     # [B, max_pages, pt, H, *]
-        return t.reshape((B, max_len) + pool.shape[2:])
+        t = jnp.take(pool, table, axis=0)     # [B, max_pages, H, *, pt]
+        t = jnp.moveaxis(t, 1, 3)             # [B, H, *, max_pages, pt]
+        return t.reshape(t.shape[:3] + (max_len,))
 
     kin = ((gather(kp[0]), gather(kp[1])) if isinstance(kp, tuple)
            else gather(kp))
@@ -331,76 +260,15 @@ def paged_gather_attend(q, kp, vp, table, pos, page_tokens, n_rep):
 
 
 def paged_flash_decode_attend(q, kp, vp, table, pos, page_tokens, n_rep):
-    """Pallas paged decode attention: K/V pools ``[P, page_tokens,
-    Hkv, D]`` (plus (codes, scales) tuples for int8 pools) addressed
-    through a ``[B, max_pages]`` block table. Block size IS the page
-    size; a page that Mosaic cannot tile (page_tokens % 128 on TPU)
-    falls back to :func:`paged_gather_attend` with a one-time
-    warning."""
-    ks = vs = None
-    if isinstance(kp, tuple):
-        kp, ks = kp
-    if isinstance(vp, tuple):
-        vp, vs = vp
-    quant = ks is not None
-    if jax.default_backend() == "tpu" and page_tokens % 128:
-        _warn_dense_fallback(page_tokens)
-        kin = kp if ks is None else (kp, ks)
-        vin = vp if vs is None else (vp, vs)
-        return paged_gather_attend(q, kin, vin, table, pos, page_tokens,
-                                   n_rep)
-
-    B, W, Hq, D = q.shape
-    Hkv = kp.shape[2]
-    assert Hq == Hkv * n_rep, (Hq, Hkv, n_rep)
-    Wn = W * n_rep
-    max_pages = table.shape[1]
-
-    pos = jnp.asarray(pos, jnp.int32)
-    if pos.ndim == 0:
-        pos = jnp.full((B,), pos, jnp.int32)
-    pos2 = pos.reshape(B, 1)
-    table = jnp.asarray(table, jnp.int32)
-
-    qg = q.reshape(B, W, Hkv, n_rep, D).transpose(0, 2, 1, 3, 4).reshape(
-        B, Hkv, Wn, D)
-
-    kernel = functools.partial(
-        _paged_decode_kernel, page_tokens=page_tokens, n_rep=n_rep,
-        n_k=max_pages, quant=quant, scale=1.0 / D ** 0.5)
-    in_specs = [
-        pl.BlockSpec((1, 1), lambda b, g: (b, 0),
-                     memory_space=pltpu.SMEM),
-        pl.BlockSpec((1, max_pages), lambda b, g: (b, 0),
-                     memory_space=pltpu.SMEM),
-        pl.BlockSpec((1, 1, Wn, D), lambda b, g: (b, g, 0, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec(memory_space=pltpu.ANY),     # K pool stays in HBM
-        pl.BlockSpec(memory_space=pltpu.ANY),     # V pool stays in HBM
-    ]
-    operands = [pos2, table, qg, kp, vp]
-    scratch = [pltpu.VMEM((page_tokens, D), kp.dtype),
-               pltpu.VMEM((page_tokens, D), vp.dtype)]
-    if quant:
-        in_specs += [pl.BlockSpec(memory_space=pltpu.ANY)] * 2
-        operands += [ks, vs]
-        scratch += [pltpu.VMEM((page_tokens, 1), jnp.float32)] * 2
-    scratch.append(pltpu.SemaphoreType.DMA((4,)))
-
-    out = pl.pallas_call(
-        kernel,
-        grid=(B, Hkv),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, Wn, D), lambda b, g: (b, g, 0, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=_out_struct((B, Hkv, Wn, D), q.dtype, q, kp, vp),
-        scratch_shapes=scratch,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
-        interpret=jax.default_backend() != "tpu",
-    )(*operands)
-    return out.reshape(B, Hkv, W, n_rep, D).transpose(0, 2, 1, 3, 4).reshape(
-        B, W, Hq * D)
+    """Pallas paged decode attention: K/V pools ``[P, Hkv, D,
+    page_tokens]`` (plus (codes, scales) tuples for int8 pools) addressed through
+    a ``[B, max_pages]`` block table. Block size IS the page size, so
+    at ``block_k == page_tokens`` this and :func:`flash_decode_attend`
+    run identical FLOPs over identical block values (one kernel body).
+    Compiled for the chip, a page that is not a multiple of 128 tokens
+    raises."""
+    return _decode_call(q, kp, vp, pos, n_rep, page_tokens,
+                        table.shape[1], table=table)
 
 
 def auto_paged_decode_attend(q, kp, vp, table, pos, page_tokens, n_rep):
@@ -408,7 +276,7 @@ def auto_paged_decode_attend(q, kp, vp, table, pos, page_tokens, n_rep):
     can tile the page (page_tokens % 128 == 0); the gather-dense
     reference elsewhere — on CPU a dense einsum beats an interpreted
     kernel, and gather-dense is also the bit-equality anchor."""
-    if jax.default_backend() == "tpu" and page_tokens % 128 == 0:
+    if backend.on_tpu() and page_tokens % 128 == 0:
         return paged_flash_decode_attend(q, kp, vp, table, pos,
                                          page_tokens, n_rep)
     return paged_gather_attend(q, kp, vp, table, pos, page_tokens, n_rep)
@@ -432,8 +300,7 @@ def auto_decode_attend(q, kc, vc, pos, max_len, n_rep):
     block-skip to pay (max_len >= 1024) and Mosaic can tile it
     (max_len % 128 == 0); the dense reference elsewhere — including
     every CPU path, where a dense einsum beats an interpreted kernel."""
-    if (jax.default_backend() == "tpu" and max_len >= 1024
-            and max_len % 128 == 0):
+    if backend.on_tpu() and max_len >= 1024 and max_len % 128 == 0:
         return flash_decode_attend(q, kc, vc, pos, max_len, n_rep)
     from mpi_acx_tpu.models.decoding import dense_decode_attend
 

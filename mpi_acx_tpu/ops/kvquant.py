@@ -9,18 +9,20 @@ int8 codes halve the dominant term.
 
 Scheme: symmetric per-(position, head) scales — each cached K/V vector
 [head_dim] gets one f32 scale (amax/127), stored in a parallel
-[..., 1] buffer. Quantization happens at WRITE time (one new vector
+[..., 1] buffer (moved with the codes into the cache layout
+[..., H, 1, S] by models/decoding.pack_kv). Quantization happens at
+WRITE time (one new vector
 per step; the prompt bulk at prefill). At READ time the codes are NOT
 dequantized to HBM — there are two read paths, both keeping int8 as
 the only HBM-resident form. The dense path
 (decoding.dense_decode_attend) keeps the int8 buffers as the attention
 einsums' operands and applies K's scales to the logits and V's to the
-probabilities (scale-on-scores factoring). The flash path
-(ops/flash_decode.py, the default on TPU for long caches) DMAs each
-live int8 block into VMEM and dequantizes IN REGISTER against the
-per-position scales before the dot — algebraically the same factoring
-(sum_d q_d*(K_kd*s_k) == (sum_d q_d*K_kd)*s_k), with the added
-length-aware win that dead blocks never cross the wire at all. What
+probabilities (scale-on-scores factoring:
+sum_d q_d*(K_kd*s_k) == (sum_d q_d*K_kd)*s_k). The flash path
+(ops/flash_decode.py, the default on TPU for long caches) moves each
+live int8 block into VMEM and applies the same factoring there, the
+block's scales arriving as one lane row — with the added length-aware
+win that dead blocks never cross the wire at all. What
 is never done: dequantizing the full cache slice before attending.
 The first design did, betting XLA would fuse the convert+mul into the
 einsum's operand read the way it does for int8 weights (wquant.py) —

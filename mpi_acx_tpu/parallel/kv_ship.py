@@ -1,7 +1,7 @@
 """Per-layer KV shipping over host-plane partitioned channels.
 
 The disaggregated-serving handoff (models/disagg.py): a prefill rank
-maps ONE request's quantized KV cache — [L, prompt_bucket, H, D] int8
+maps ONE request's quantized KV cache — [L, H, D, prompt_bucket] int8
 codes plus their f32 scales — onto ONE partitioned send with L
 partitions, one per transformer layer. The prefill publishes partition
 l with MPIX_Pready the moment layer l's K/V leave the device, while
@@ -16,8 +16,10 @@ Wire form (the EQuARX rule, PAPERS.md): quantized codes + scales are
 the ONLY form KV ever takes on the wire — a bf16-cached prefill
 quantizes before packing, never after. Per layer the partition packs
 ``[k codes | v codes | k scales | v scales]`` contiguously; codes are
-int8 [bucket, H, D], scales f32 [bucket, H, 1] (ops/kvquant.py's
-per-(position, head) layout), so every partition has identical size
+int8 [H, D, bucket], scales f32 [H, 1, bucket] (ops/kvquant.py's
+per-(position, head) scheme in the cache layout of
+models/decoding.to_cache_layout — the bytes land in the decode rank's
+slot or page as they arrive), so every partition has identical size
 and the partitioned channel's equal-partition contract holds for any
 layer count.
 
@@ -56,7 +58,7 @@ def layer_part_bytes(bucket: int, heads: int, head_dim: int) -> int:
     """Bytes of one layer partition: k+v int8 codes plus k+v f32
     per-(position, head) scales."""
     codes = bucket * heads * head_dim      # int8, 1 byte each
-    scales = bucket * heads * 4            # f32 [bucket, H, 1]
+    scales = bucket * heads * 4            # f32 [H, 1, bucket]
     return 2 * codes + 2 * scales
 
 
@@ -86,20 +88,20 @@ def unpack_layer(row: np.ndarray, bucket: int, heads: int,
                                          np.ndarray, np.ndarray]:
     """Inverse of :func:`pack_layer`: staging row -> (kq, ks, vq, vs)
     with the shapes scatter_fn's per-slot splice expects (B=1 axis
-    added by the caller when assembling the [L, 1, bucket, ...] cache).
+    added by the caller when assembling the [L, 1, H, *, bucket] cache).
     Returns copies — the staging row is reused by the next round."""
     nc = bucket * heads * head_dim
     ns = bucket * heads * 4
     o = 0
-    kq = row[o:o + nc].view(np.int8).reshape(bucket, heads,
-                                             head_dim).copy()
+    kq = row[o:o + nc].view(np.int8).reshape(heads, head_dim,
+                                             bucket).copy()
     o += nc
-    vq = row[o:o + nc].view(np.int8).reshape(bucket, heads,
-                                             head_dim).copy()
+    vq = row[o:o + nc].view(np.int8).reshape(heads, head_dim,
+                                             bucket).copy()
     o += nc
-    ks = row[o:o + ns].view(np.float32).reshape(bucket, heads, 1).copy()
+    ks = row[o:o + ns].view(np.float32).reshape(heads, 1, bucket).copy()
     o += ns
-    vs = row[o:o + ns].view(np.float32).reshape(bucket, heads, 1).copy()
+    vs = row[o:o + ns].view(np.float32).reshape(heads, 1, bucket).copy()
     return kq, ks, vq, vs
 
 

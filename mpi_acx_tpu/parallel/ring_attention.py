@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from mpi_acx_tpu import backend
 from mpi_acx_tpu.parallel.collective import _ring_perm
 
 _NEG = float(jnp.finfo(jnp.float32).min)
@@ -92,7 +93,7 @@ def ring_attention_batched(q: jax.Array, k: jax.Array, v: jax.Array,
     mb, sq, h, dh = q.shape
     assert k.shape[2] * kv_repeat == h, (k.shape, h, kv_repeat)
     if use_flash is None:
-        use_flash = (jax.default_backend() == "tpu"
+        use_flash = (backend.on_tpu()
                      and sq >= FLASH_MIN_SHARD and sq % 128 == 0)
 
     def expand(x):

@@ -40,7 +40,7 @@ from mpi_acx_tpu.models import llama as lm
 from mpi_acx_tpu.models import transformer as tfm
 from mpi_acx_tpu.models.decoding import (decode_layer_scan,
                                          grouped_decode_attend,
-                                         sample_logits)
+                                         new_kv_cache, sample_logits)
 from mpi_acx_tpu.ops.attention import select_attention
 from mpi_acx_tpu.ops.wquant import wread
 
@@ -65,8 +65,10 @@ def _run_generation(hooks, layers, prompt, key, n_new, *, pick):
     x, (ks, vs) = lax.scan(hooks["prefill_layer"], x, layers)
     logits0 = hooks["finish"](x[:, -1:])[:, 0]            # [B, vocab]
 
-    # Cache layout follows the prefill outputs ([L, B, S, H?, D] local).
-    kc, vc = _init_kv_from_prefill(ks, vs, max_len)
+    # Prefill K/V are token-major ([L, B, S, H_local, D]); the cache is
+    # [L, B, H_local, D, max_len] (decoding.to_cache_layout).
+    cache = _pack_prefill_cache(ks, vs, max_len, kv_int8=False)
+    kc, vc = cache["k"], cache["v"]
 
     def dec_body(carry, step_key):
         kc, vc, pos, tok = carry
@@ -237,16 +239,6 @@ def _gpt2_finish(params, cfg, x):
     x = tfm.layernorm(x, params["lnf_g"], params["lnf_b"])
     return jnp.einsum("bsd,vd->bsv", x, params["embed"].astype(x.dtype),
                       preferred_element_type=jnp.float32)
-
-
-def _init_kv_from_prefill(ks, vs, cap):
-    """Allocate [L, B, cap, H_local, D] caches and land the prefill
-    K/V at positions [0, S)."""
-    kc = jnp.zeros(ks.shape[:2] + (cap,) + ks.shape[3:], ks.dtype)
-    vc = jnp.zeros_like(kc)
-    kc = lax.dynamic_update_slice(kc, ks, (0,) * kc.ndim)
-    vc = lax.dynamic_update_slice(vc, vs, (0,) * vc.ndim)
-    return kc, vc
 
 
 def _gpt2_tp_layer_ops(cfg, tp: int, axis: str):
@@ -633,17 +625,9 @@ def _pack_prefill_cache(ks, vs, cap, kv_int8):
     (int8) cache layout, so the TP serving path cannot drift from the
     single-device one."""
     from mpi_acx_tpu.models.decoding import fill_kv_cache
-    L, B = ks.shape[:2]
-    H, D = ks.shape[3], ks.shape[4]
-    if kv_int8:
-        cache = {"k": jnp.zeros((L, B, cap, H, D), jnp.int8),
-                 "v": jnp.zeros((L, B, cap, H, D), jnp.int8),
-                 "ks": jnp.zeros((L, B, cap, H, 1), jnp.float32),
-                 "vs": jnp.zeros((L, B, cap, H, 1), jnp.float32)}
-    else:
-        cache = {"k": jnp.zeros((L, B, cap, H, D), ks.dtype),
-                 "v": jnp.zeros((L, B, cap, H, D), vs.dtype)}
-    return fill_kv_cache(cache, ks, vs, ks.shape[2])
+    L, B, S, H, D = ks.shape
+    cache = new_kv_cache(L, B, H, D, cap, ks.dtype, kv_int8)
+    return fill_kv_cache(cache, ks, vs, S)
 
 
 def _tp_family_ops(cfg, tp: int, axis: str, ffn=None,
@@ -694,7 +678,7 @@ def _tp_family_ops(cfg, tp: int, axis: str, ffn=None,
 
     def decode(params, _cfg, cache, tok):
         pos = jnp.asarray(cache["pos"])
-        max_len = cache["k"].shape[2]
+        max_len = cache["k"].shape[-1]
         # Scalar pos (generation/speculative) or [B] per-slot positions
         # (continuous-batching serving) — as transformer.decode_step.
         pe = params["pos"][pos]
@@ -709,7 +693,7 @@ def _tp_family_ops(cfg, tp: int, axis: str, ffn=None,
     def window(params, _cfg, cache, tokens):
         W = tokens.shape[1]
         pos = cache["pos"]
-        max_len = cache["k"].shape[2]
+        max_len = cache["k"].shape[-1]
         x = (params["embed"][tokens]
              + lax.dynamic_slice_in_dim(params["pos"], pos, W, 0)[None]
              ).astype(cfg.dtype)
@@ -766,7 +750,7 @@ def _llama_tp_family_ops(cfg, tp: int, axis: str,
 
     def decode(params, _cfg, cache, tok):
         pos = jnp.asarray(cache["pos"])
-        max_len = cache["k"].shape[2]
+        max_len = cache["k"].shape[-1]
         x = params["embed"][tok][:, None, :].astype(cfg.dtype)
 
         def qkv_fn(lp, x, pos):
@@ -783,7 +767,7 @@ def _llama_tp_family_ops(cfg, tp: int, axis: str,
     def window(params, _cfg, cache, tokens):
         W = tokens.shape[1]
         pos = cache["pos"]
-        max_len = cache["k"].shape[2]
+        max_len = cache["k"].shape[-1]
         x = params["embed"][tokens].astype(cfg.dtype)
 
         def qkv_fn(lp, x, pos):
@@ -918,7 +902,7 @@ def make_tp_server_fns(params, cfg, mesh: Mesh, chunk: int = 1,
     """Server-fns tuple for models.serving._serve whose three programs
     run tensor-parallel over the mesh: continuous batching composes
     with the Megatron weight split. Each slot's KV cache shards by
-    attention head (the same [L, B, max_len, H, D] layout with H on
+    attention head (the same [L, B, H, D, max_len] layout with H on
     ``axis``); per-slot positions ride the shared decode scaffold's
     vector-pos mode unchanged, so outputs equal the single-device
     serve_greedy's token for token up to the matmul split's summation
@@ -980,7 +964,7 @@ def make_tp_server_fns(params, cfg, mesh: Mesh, chunk: int = 1,
         shard_fn = tp_shard_params_llama
     else:
         raise ValueError(f"unknown family {family!r}")
-    cspec = P(None, None, None, axis, None)
+    cspec = P(None, None, axis, None, None)     # [L, B, H, D, max_len]
     cache_spec = {"k": cspec, "v": cspec, "pos": P()}
     if kv_int8:
         cache_spec.update(ks=cspec, vs=cspec)   # scales shard by head
@@ -1022,7 +1006,7 @@ def make_tp_server_fns(params, cfg, mesh: Mesh, chunk: int = 1,
         return cache, toks
 
     # Donate the slot caches: the host loop always proceeds with the
-    # returned slots, and a non-donated [L, B, max_len, H, D] pair
+    # returned slots, and a non-donated [L, B, H, D, max_len] pair
     # would cost a full-cache copy per chunk on top of doubled peak
     # memory.
     step_prog = jax.jit(shard_map(
@@ -1051,6 +1035,10 @@ def make_tp_server_fns(params, cfg, mesh: Mesh, chunk: int = 1,
 
     def prefill_fn(tokens, last):
         return prefill_prog(sharded, tokens, last)
+
+    # The weight tree as the server holds it, for inspection (which
+    # device holds which shard — chip_smoke.py --chips 4 prints it).
+    prefill_fn.sharded_params = sharded
 
     def step_fn(slots, tok, keys):
         slots, toks = step_prog(sharded, slots, tok)

@@ -1,8 +1,7 @@
 """Worker for the IN-PROGRAM partitioned publish test: one acxrun rank.
 
-Round-3 verdict item 3 (VERDICT.md "In-program partitioned signaling"):
-the previous bridge worker drove the publish loop from the HOST between
-kernel launches; the reference signals from inside a running kernel
+In-program partitioned signaling: the earlier bridge worker drove the
+publish loop from the HOST between kernel launches; the reference signals from inside a running kernel
 while later partitions are still being produced
 (reference partitioned.cu:200-212 -> init.cpp:82-115). This worker is
 the TPU-native equivalent with the host making exactly ONE jitted call

@@ -1,13 +1,13 @@
 """Interpret-mode parity for the Pallas flash-decode kernel.
 
-ops/flash_decode.py runs the SAME code path interpreted on CPU that it
-compiles on TPU (pallas_call interpret mode), so these tests pin the
-kernel's math — GQA rows, window masking, per-slot positions, in-register
-int8 dequant — against :func:`dense_decode_attend`, the dense reference
-every decode path used before the kernel existed. ``block_k=32`` on a
-96-long cache forces multiple K/V blocks so the unmasked/straddle loop
-split and the block-skip bounds are actually exercised (the default
-block_k would cover the toy cache with one block).
+ops/flash_decode.py runs interpreted on CPU (pallas_call interpret
+mode), so these tests pin the kernel's MATH — GQA rows, window masking,
+per-slot positions, int8 scale-on-scores — against
+:func:`dense_decode_attend`, the dense reference. Whether the chip's
+compiler takes the kernel is tests/test_tpu_compile.py's business.
+``block_k=32`` on a 96-long cache forces multiple K/V blocks so the
+live-block bound and the straddle mask are actually exercised (the
+default block_k would cover the toy cache with one block).
 
 The block-skip test is the length-aware claim itself: tail blocks past
 ``pos + W`` are filled with NaN — if the kernel read them, the online
@@ -20,8 +20,10 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from mpi_acx_tpu import backend
 from mpi_acx_tpu.models.decoding import (dense_decode_attend,
-                                         grouped_decode_attend)
+                                         grouped_decode_attend,
+                                         to_cache_layout)
 from mpi_acx_tpu.ops import attention
 from mpi_acx_tpu.ops.flash_decode import (_fit_block_k, auto_decode_attend,
                                           flash_decode_attend,
@@ -32,7 +34,8 @@ B, Hkv, D, MAX_LEN, BLOCK_K = 3, 2, 16, 96, 32
 
 
 def _case(n_rep, W, kind, seed=0):
-    """(q, kc, vc, tol): bf16 arrays or f32 q + (codes, scales) caches."""
+    """(q, kc, vc, tol): bf16 arrays or f32 q + (codes, scales) caches,
+    in cache layout [B, Hkv, D, MAX_LEN] (scales [B, Hkv, 1, MAX_LEN])."""
     rng = np.random.default_rng(seed)
     q = rng.standard_normal((B, W, Hkv * n_rep, D))
     kc = rng.standard_normal((B, MAX_LEN, Hkv, D))
@@ -41,12 +44,14 @@ def _case(n_rep, W, kind, seed=0):
         # f32 q against (int8 codes, f32 scales) tuple caches; both
         # paths dequantize exactly, tolerance is accumulation order.
         q = jnp.asarray(q, jnp.float32)
-        kc = kv_quant(jnp.asarray(kc, jnp.float32))
-        vc = kv_quant(jnp.asarray(vc, jnp.float32))
+        kc = tuple(map(to_cache_layout,
+                       kv_quant(jnp.asarray(kc, jnp.float32))))
+        vc = tuple(map(to_cache_layout,
+                       kv_quant(jnp.asarray(vc, jnp.float32))))
         return q, kc, vc, 2e-4
     q = jnp.asarray(q, jnp.bfloat16)
-    kc = jnp.asarray(kc, jnp.bfloat16)
-    vc = jnp.asarray(vc, jnp.bfloat16)
+    kc = to_cache_layout(jnp.asarray(kc, jnp.bfloat16))
+    vc = to_cache_layout(jnp.asarray(vc, jnp.bfloat16))
     return q, kc, vc, 4e-2
 
 
@@ -81,10 +86,10 @@ def test_block_skip_ignores_dead_tail(kind):
     def poison(c):
         if isinstance(c, tuple):
             codes, scales = c
-            codes = codes.at[:, live:].set(127)
-            scales = scales.at[:, live:].set(jnp.nan)
+            codes = codes.at[..., live:].set(127)
+            scales = scales.at[..., live:].set(jnp.nan)
             return codes, scales
-        return c.at[:, live:].set(jnp.nan)
+        return c.at[..., live:].set(jnp.nan)
 
     clean = flash_decode_attend(q, kc, vc, pos, MAX_LEN, n_rep,
                                 block_k=BLOCK_K)

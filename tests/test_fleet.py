@@ -44,19 +44,6 @@ def _run(cmd, env_extra=None, timeout=120):
                           timeout=timeout, cwd=REPO, env=env)
 
 
-def _load_multihost():
-    """Load parallel/multihost.py directly: going through the package
-    __init__ drags in collective.py, whose jax.shard_map import is absent
-    on some CPU-only jax builds — the fleet helpers don't need it."""
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "acx_test_multihost",
-        os.path.join(REPO, "mpi_acx_tpu", "parallel", "multihost.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 # -- pure-Python surface ----------------------------------------------------
 
 
@@ -73,7 +60,7 @@ def test_fleet_state_names_cover_lifecycle():
 def test_fleet_join_budget_defaults_and_env(monkeypatch):
     """Join budget = ACX_FLEET_JOIN_TIMEOUT_MS (default 10 s) plus the
     handshake margin; an explicit timeout wins over the env."""
-    multihost = _load_multihost()
+    from mpi_acx_tpu.parallel import multihost
     monkeypatch.delenv("ACX_FLEET_JOIN_TIMEOUT_MS", raising=False)
     assert multihost.fleet_join_budget_s() == pytest.approx(11.0)
     assert multihost.fleet_join_budget_s(timeout_ms=4000.0) == \
@@ -140,7 +127,7 @@ def test_fleet_leave_loopback_is_clean():
 def _fleet_loopback_worker() -> int:
     sys.path.insert(0, REPO)
     from mpi_acx_tpu import runtime
-    multihost = _load_multihost()
+    from mpi_acx_tpu.parallel import multihost
     rt = runtime.Runtime()
     assert rt.fleet_epoch() >= 1
     assert rt.fleet_view() == ["active"]

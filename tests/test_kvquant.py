@@ -134,15 +134,16 @@ def test_scale_on_scores_matches_dequant_attend():
     path only re-factors the scale multiplies onto the logits/probs
     (the r05 chip A/B showed materializing the dequantized cache is a
     0.73x regression, so the factored path is the production one)."""
-    from mpi_acx_tpu.models.decoding import grouped_decode_attend
+    from mpi_acx_tpu.models.decoding import (grouped_decode_attend,
+                                             to_cache_layout)
 
     key = jax.random.key(3)
     B, W, Hkv, n_rep, D, L = 2, 3, 2, 2, 16, 12
     q = jax.random.normal(key, (B, W, Hkv * n_rep, D), jnp.float32)
     kf = jax.random.normal(jax.random.key(4), (B, L, Hkv, D))
     vf = jax.random.normal(jax.random.key(5), (B, L, Hkv, D))
-    kq, ks = kv_quant(kf)
-    vq, vs = kv_quant(vf)
+    kq, ks = map(to_cache_layout, kv_quant(kf))
+    vq, vs = map(to_cache_layout, kv_quant(vf))
 
     want = grouped_decode_attend(q, kv_dequant(kq, ks, q.dtype),
                                  kv_dequant(vq, vs, q.dtype), 4, L, n_rep)

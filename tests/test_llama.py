@@ -139,8 +139,8 @@ class TestDecode:
         pre, cache = prefill(params, cfg, tokens, max_len=32)
         np.testing.assert_allclose(np.asarray(full), np.asarray(pre),
                                    rtol=1e-4, atol=1e-4)
-        assert cache["k"].shape == (cfg.n_layers, 2, 32, cfg.n_kv_heads,
-                                    cfg.head_dim)
+        assert cache["k"].shape == (cfg.n_layers, 2, cfg.n_kv_heads,
+                                    cfg.head_dim, 32)
 
     def test_decode_matches_forward(self, setup):
         cfg, params, tokens = setup

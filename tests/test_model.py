@@ -111,8 +111,8 @@ class TestDecode:
         np.testing.assert_allclose(np.asarray(full), np.asarray(pre),
                                    rtol=1e-4, atol=1e-4)
         assert int(cache["pos"]) == tokens.shape[1]
-        assert cache["k"].shape == (cfg.n_layers, 2, 32, cfg.n_heads,
-                                    cfg.head_dim)
+        assert cache["k"].shape == (cfg.n_layers, 2, cfg.n_heads,
+                                    cfg.head_dim, 32)
 
     def test_decode_step_matches_forward(self):
         """Logits from cached single-token decode == logits from running

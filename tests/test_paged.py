@@ -27,7 +27,8 @@ import jax.numpy as jnp
 from mpi_acx_tpu.models import kvpage
 from mpi_acx_tpu.models import serving
 from mpi_acx_tpu.models import transformer as tfm
-from mpi_acx_tpu.models.decoding import dense_decode_attend
+from mpi_acx_tpu.models.decoding import (dense_decode_attend,
+                                         to_cache_layout)
 from mpi_acx_tpu.ops.flash_decode import (flash_decode_attend,
                                           paged_flash_decode_attend,
                                           paged_gather_attend,
@@ -42,7 +43,7 @@ B, Hkv, D, MAX_LEN, PT = 3, 2, 16, 96, 32       # max_pages = 3
 
 
 def _fixed_case(n_rep, W, kind, seed=0):
-    """(q, kc, vc): the fixed-slot [B, MAX_LEN, Hkv, D] caches of
+    """(q, kc, vc): the fixed-slot [B, Hkv, D, MAX_LEN] caches of
     tests/test_flash_decode.py, bf16 or (int8 codes, f32 scales)."""
     rng = np.random.default_rng(seed)
     q = rng.standard_normal((B, W, Hkv * n_rep, D))
@@ -50,11 +51,14 @@ def _fixed_case(n_rep, W, kind, seed=0):
     vc = rng.standard_normal((B, MAX_LEN, Hkv, D))
     if kind == "int8":
         q = jnp.asarray(q, jnp.float32)
-        kc = kv_quant(jnp.asarray(kc, jnp.float32))
-        vc = kv_quant(jnp.asarray(vc, jnp.float32))
+        kc = tuple(map(to_cache_layout,
+                       kv_quant(jnp.asarray(kc, jnp.float32))))
+        vc = tuple(map(to_cache_layout,
+                       kv_quant(jnp.asarray(vc, jnp.float32))))
         return q, kc, vc
-    return (jnp.asarray(q, jnp.bfloat16), jnp.asarray(kc, jnp.bfloat16),
-            jnp.asarray(vc, jnp.bfloat16))
+    return (jnp.asarray(q, jnp.bfloat16),
+            to_cache_layout(jnp.asarray(kc, jnp.bfloat16)),
+            to_cache_layout(jnp.asarray(vc, jnp.bfloat16)))
 
 
 def _paginate(kc, vc, shared_prefix=False):
@@ -63,21 +67,22 @@ def _paginate(kc, vc, shared_prefix=False):
     aliased pool page (their row contents are first made identical) —
     the layout a radix-cache hit produces."""
     def split(c):
-        # [B, MAX_LEN, Hkv, *] -> [B*max_pages, PT, Hkv, *]
-        return c.reshape(B, MAX_LEN // PT, PT, *c.shape[2:]).reshape(
-            B * (MAX_LEN // PT), PT, *c.shape[2:])
+        # [B, Hkv, *, MAX_LEN] -> [B*max_pages, Hkv, *, PT]
+        c = c.reshape(*c.shape[:3], MAX_LEN // PT, PT)
+        return jnp.moveaxis(c, 3, 1).reshape(
+            B * (MAX_LEN // PT), *c.shape[1:3], PT)
 
     max_pages = MAX_LEN // PT
     table = np.arange(B * max_pages, dtype=np.int32).reshape(B, max_pages)
     if shared_prefix:
         if isinstance(kc, tuple):
-            kc = (kc[0].at[:, :PT].set(kc[0][0, :PT]),
-                  kc[1].at[:, :PT].set(kc[1][0, :PT]))
-            vc = (vc[0].at[:, :PT].set(vc[0][0, :PT]),
-                  vc[1].at[:, :PT].set(vc[1][0, :PT]))
+            kc = (kc[0].at[..., :PT].set(kc[0][0, ..., :PT]),
+                  kc[1].at[..., :PT].set(kc[1][0, ..., :PT]))
+            vc = (vc[0].at[..., :PT].set(vc[0][0, ..., :PT]),
+                  vc[1].at[..., :PT].set(vc[1][0, ..., :PT]))
         else:
-            kc = kc.at[:, :PT].set(kc[0, :PT])
-            vc = vc.at[:, :PT].set(vc[0, :PT])
+            kc = kc.at[..., :PT].set(kc[0, ..., :PT])
+            vc = vc.at[..., :PT].set(vc[0, ..., :PT])
         table[:, 0] = 0                           # alias slot 0's page
     pk = ((split(kc[0]), split(kc[1])) if isinstance(kc, tuple)
           else split(kc))
