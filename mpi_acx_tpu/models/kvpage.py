@@ -373,7 +373,7 @@ def make_paged_step_fn(params, cfg, family, chunk: int,
     from mpi_acx_tpu.backend import jit_bound
     _check_family(family)
 
-    def step(params, state, tok, keys):
+    def paged_decode_chunk(params, state, tok, keys):   # the trace's name
         def one(carry, _):
             state, tok, keys = carry
             logits, state = paged_decode_step(params, cfg, state, tok,
@@ -384,7 +384,7 @@ def make_paged_step_fn(params, cfg, family, chunk: int,
                                           length=chunk)
         return state, toks, keys
 
-    return jit_bound(step, params, donate_argnums=(1,))
+    return jit_bound(paged_decode_chunk, params, donate_argnums=(1,))
 
 
 # --------------------------------------------------------------------------

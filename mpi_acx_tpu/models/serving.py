@@ -63,6 +63,10 @@ class RequestTelemetry:
     new_tokens: int
     tokens_per_s: float  # new_tokens / latency_s
     retries: int         # failed attempts that re-queued this request
+    # serve_paged_greedy only, on its ENTRY clock (see its docstring):
+    queue_wait_s: float = 0.0  # entry -> start of the refill that seated it
+    prefill_s: float = 0.0     # that refill's ``refill.prefill`` span
+    refill_host_s: float = 0.0  # its ``refill.match`` + ``.scatter`` + ``.seat``
 
 
 @dataclass
@@ -94,8 +98,24 @@ class ServingMetrics:
     itl_p99_s: float = 0.0
     queue_depth_max: int = 0
     queue_depth_mean: float = 0.0
-    slot_occupancy_mean: float = 0.0  # fraction of slots owned per step
+    slot_occupancy_mean: float = 0.0  # slots owned at a chunk's START
     per_request: List[RequestTelemetry] = field(default_factory=list)
+    # serve_paged_greedy only: the call split into the phases of its
+    # docstring's table (profiling.Phases: self seconds and entries per
+    # span name; the self times sum to call_s), and the decode work
+    # counted where the tokens are consumed.
+    call_s: float = 0.0           # function entry -> return (wall_s + set-up)
+    phase_s: Dict[str, float] = field(default_factory=dict)
+    phase_n: Dict[str, int] = field(default_factory=dict)
+    decode_slot_steps: int = 0    # every chunk: chunk x n_slots
+    decode_tokens: int = 0        # tokens the deliver loop consumed
+
+    @property
+    def step_utilization(self) -> float:
+        """Share of decode slot-steps that delivered a token (an empty
+        slot, and a slot whose request ended mid-chunk, deliver none)."""
+        return (self.decode_tokens / self.decode_slot_steps
+                if self.decode_slot_steps else 0.0)
 
 
 @dataclass
@@ -292,6 +312,19 @@ class RollingSLO:
         }
 
 
+def _tseries_armed() -> bool:
+    """True iff ACX_TSERIES is set, the native runtime is ALREADY loaded
+    and its sampler runs: a caller whose fragment is dear to build
+    (``RollingSLO.live_slos`` sorts its windows) asks first."""
+    if not os.environ.get("ACX_TSERIES"):
+        return False
+    try:
+        import mpi_acx_tpu.runtime as _rt
+        return _rt._lib is not None and bool(_rt._lib.acx_tseries_enabled())
+    except Exception:  # pragma: no cover — diagnostics must never raise
+        return False
+
+
 def _tseries_annotate_best_effort(fragment: dict) -> bool:
     """Publish ``fragment`` to the native telemetry sampler (it rides along
     under ``"app"`` in every subsequent ACX_TSERIES sample) — but only if
@@ -299,13 +332,11 @@ def _tseries_annotate_best_effort(fragment: dict) -> bool:
     no-build/no-load discipline as ``_flight_dump_best_effort``, plus the
     JSON encode is skipped entirely when nobody is sampling. Returns True
     iff the fragment was handed to the sampler."""
-    if not os.environ.get("ACX_TSERIES"):
+    if not _tseries_armed():
         return False
     try:
         import json as _json
         import mpi_acx_tpu.runtime as _rt
-        if _rt._lib is None or not _rt._lib.acx_tseries_enabled():
-            return False
         _rt._lib.acx_tseries_annotate(
             _json.dumps(fragment, separators=(",", ":")).encode())
         return True
@@ -956,9 +987,52 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
     prefix_evictions, prefix_pages_reused, pages_hwm) in ``.metrics``;
     ``return_paged_state=True`` additionally exposes the live
     :class:`~mpi_acx_tpu.models.kvpage.PagedKV` as ``.paged_state``
-    (tests and benches inspect allocator occupancy through it)."""
-    from mpi_acx_tpu.models import kvpage
+    (tests and benches inspect allocator occupancy through it).
 
+    **Two clocks, both ``time.perf_counter``.** ``wall_s``, ``ttft_s``,
+    ``latency_s`` and the ``itl_*`` count from ``t0``, taken AFTER the
+    set-up (pool allocated, programs wrapped), as they always have.
+    ``call_s``, ``queue_wait_s`` and the phases count from function
+    ENTRY, so ``call_s = wall_s +`` set-up and a caller's own TTFT is
+    ``ttft_s + phase_s["serve.setup"]``.
+
+    **Phases** (``profiling.Phases``): the call is tiled by the spans
+    below, each a ``jax.profiler.TraceAnnotation`` (on the device
+    trace's clock when the profiler runs; ``refill.*`` carry ``rid``,
+    ``chunk.*`` and ``loop.other`` the chunk's number ``step``) and a
+    self-time counter in ``metrics.phase_s`` / ``phase_n``; the self
+    times sum to ``call_s``. Only two WAIT on the device::
+
+        serve.setup     entry -> first refill: admission, PagedKV, jit
+                        wrappers, make_paged_step_fn (allocation only)
+        refill.match    SLO gate, prefix.match, alloc_evicting
+        refill.prefill  pad, gather_history on a hit, the prefill's
+                        dispatch, int(argmax): WAITS for the device
+        refill.scatter  scatter_prompt's DISPATCH; its device time
+                        lands in whichever span syncs next (the next
+                        refill.prefill or chunk.step)
+        refill.seat     pkv.seat, prefix.insert, bookkeeping, the first
+                        on_token
+        chunk.grow      grow_for_chunk (preemptions inside) + COW guard
+        chunk.upload    pkv.device_state()
+        chunk.step      step_fn dispatch, absorb, np.asarray(tokens):
+                        WAITS for the device
+        chunk.deliver   the token loop and its on_token calls
+        chunk.retire    one per retired request: output, release
+        loop.other      gauges, page stats, tseries fragment, fleet
+                        checks, and the metrics at the end
+
+    ``decode_slot_steps`` / ``decode_tokens`` / ``step_utilization``
+    count, where the tokens are consumed, how many of the chunks'
+    slot-steps delivered one; per request, ``queue_wait_s`` (entry ->
+    start of the refill that seated it), ``prefill_s`` and
+    ``refill_host_s`` are that refill's spans."""
+    from mpi_acx_tpu.models import kvpage
+    from mpi_acx_tpu.profiling import Phases
+
+    ph = Phases()
+    setup = ph("serve.setup")
+    setup.__enter__()               # closed before the first refill
     if family is None:
         from mpi_acx_tpu.models import transformer as family  # noqa: N813
     assert prompts, "no requests"
@@ -995,13 +1069,17 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
 
     # One compile per (bucket) / (suffix bucket, history length): jit's
     # own shape cache. The weights are arguments (backend.jit_bound).
-    prefill_fn = jit_bound(
-        lambda p, t, li: family.prefill(p, cfg, t, t.shape[1],
-                                        kv_int8=kv_int8, last_index=li),
-        params)
-    suffix_prefill_fn = jit_bound(
-        lambda p, s, k, v, li: kvpage.prefill_with_history(
-            p, cfg, s, k, v, li, kv_int8=kv_int8), params)
+    # Named, so the trace prints ``PjitFunction(paged_prefill)``.
+    def paged_prefill(p, t, li):
+        return family.prefill(p, cfg, t, t.shape[1], kv_int8=kv_int8,
+                              last_index=li)
+
+    def paged_suffix_prefill(p, s, k, v, li):
+        return kvpage.prefill_with_history(p, cfg, s, k, v, li,
+                                           kv_int8=kv_int8)
+
+    prefill_fn = jit_bound(paged_prefill, params)
+    suffix_prefill_fn = jit_bound(paged_suffix_prefill, params)
 
     step_fn = kvpage.make_paged_step_fn(params, cfg, family, chunk, pt)
 
@@ -1021,6 +1099,10 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
     t0 = time.perf_counter()
     ttft = [None] * len(prompts)      # type: List[Optional[float]]
     finish = [None] * len(prompts)    # type: List[Optional[float]]
+    # Per request, of the refill that seated it: (queue_wait_s,
+    # prefill_s, refill_host_s), the first on the entry clock.
+    refill_times = [(0.0, 0.0, 0.0)] * len(prompts)
+    n_decode_tokens = n_slot_steps = 0
     slo = RollingSLO()
     for rej in rejected.values():
         slo.note_reject(rej.reason)
@@ -1099,51 +1181,58 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
         not cover the prompt (ditto — a retire will free pages), or
         the prefill failed (request re-queued via the retry rules)."""
         nonlocal n_prefills, n_slo_defer
-        if _slo_defers():
-            n_slo_defer += 1
-            return False
-        rid, prompt = queue.popleft()
-        S = len(prompt)
-        hit_pages = (pkv.prefix.match(prompt)
-                     if pkv.prefix is not None else [])
-        if hit_pages:
-            reqlog.emit("prefix_hit", rid, pages=len(hit_pages))
-        n_fresh = kvpage.pages_needed(S, pt) - len(hit_pages)
-        fresh = pkv.alloc_evicting(n_fresh)
-        if fresh is None:
-            # Page pressure at admission: put the request BACK at the
-            # head (arrival order preserved) and release the trie refs
-            # the failed match took; a later retire frees pages.
-            for p in hit_pages:
-                pkv.alloc.decref(p)
-            queue.appendleft((rid, prompt))
-            return False
-        spanned = _span_app_begin_best_effort(rid)
-        reqlog.emit("prefill_start", rid, prompt_len=S,
-                    hit_pages=len(hit_pages), fresh_pages=len(fresh))
-        try:
+        rid, prompt = queue[0]
+        with ph("refill.match", rid=rid) as match:
+            if _slo_defers():
+                n_slo_defer += 1
+                return False
+            queue.popleft()
+            S = len(prompt)
+            hit_pages = (pkv.prefix.match(prompt)
+                         if pkv.prefix is not None else [])
             if hit_pages:
-                # Radix hit: prefill ONLY the suffix against the
-                # cached pages' gathered history.
-                P = len(hit_pages) * pt
-                suffix = prompt[P:]
-                Sb = min(_bucket(len(suffix)), max_len - P,
-                         cfg.max_seq - P)
-                padded = np.zeros((1, Sb), np.int32)
-                padded[0, :len(suffix)] = suffix
-                hk, hv = pkv.gather_history(hit_pages)
-                logits, one = suffix_prefill_fn(
-                    jnp.asarray(padded), hk, hv, len(suffix) - 1)
-                first = int(jnp.argmax(logits[0, 0]))
+                reqlog.emit("prefix_hit", rid, pages=len(hit_pages))
+            n_fresh = kvpage.pages_needed(S, pt) - len(hit_pages)
+            fresh = pkv.alloc_evicting(n_fresh)
+            if fresh is None:
+                # Page pressure at admission: put the request BACK at
+                # the head (arrival order preserved) and release the
+                # trie refs the failed match took; a later retire frees
+                # pages.
+                for p in hit_pages:
+                    pkv.alloc.decref(p)
+                queue.appendleft((rid, prompt))
+                return False
+        spanned = False
+        try:
+            with ph("refill.prefill", rid=rid) as pre:
+                spanned = _span_app_begin_best_effort(rid)
+                reqlog.emit("prefill_start", rid, prompt_len=S,
+                            hit_pages=len(hit_pages),
+                            fresh_pages=len(fresh))
+                if hit_pages:
+                    # Radix hit: prefill ONLY the suffix against the
+                    # cached pages' gathered history.
+                    P = len(hit_pages) * pt
+                    suffix = prompt[P:]
+                    Sb = min(_bucket(len(suffix)), max_len - P,
+                             cfg.max_seq - P)
+                    padded = np.zeros((1, Sb), np.int32)
+                    padded[0, :len(suffix)] = suffix
+                    hk, hv = pkv.gather_history(hit_pages)
+                    logits, one = suffix_prefill_fn(
+                        jnp.asarray(padded), hk, hv, len(suffix) - 1)
+                else:
+                    padded = np.zeros(
+                        (1, min(_bucket(S), max_len, cfg.max_seq)),
+                        np.int32)
+                    padded[0, :S] = prompt
+                    logits, one = prefill_fn(jnp.asarray(padded), S - 1)
+                    one = {k: v for k, v in one.items() if k != "pos"}
+                first = int(jnp.argmax(logits[0, 0]))   # the host waits
+                reqlog.emit("prefill_end", rid, first_token=first)
+            with ph("refill.scatter", rid=rid) as scatter:
                 pkv.scatter_prompt(one, fresh)
-            else:
-                padded = np.zeros(
-                    (1, min(_bucket(S), max_len, cfg.max_seq)), np.int32)
-                padded[0, :S] = prompt
-                logits, one = prefill_fn(jnp.asarray(padded), S - 1)
-                first = int(jnp.argmax(logits[0, 0]))
-                pkv.scatter_prompt(
-                    {k: v for k, v in one.items() if k != "pos"}, fresh)
         except Exception as exc:  # noqa: BLE001 — any device failure
             for p in hit_pages + fresh:
                 pkv.alloc.decref(p)
@@ -1152,35 +1241,39 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
         finally:
             if spanned:
                 _span_app_end_best_effort()
-        reqlog.emit("prefill_end", rid, first_token=first)
-        pkv.seat(b, hit_pages, fresh, S, rid=rid)
-        if pkv.prefix is not None:
-            pkv.prefix.insert(prompt, pkv.pages[b])
-        owner[b] = rid
-        if rid in preempted_rids:
-            preempted_rids.discard(rid)
-            slo.note_resume()
-            reqlog.emit("resume", rid, slot=b)
-        emitted[rid].append(first)
-        if on_token is not None:
-            on_token(rid, first)
-        last_tok[b] = first
-        n_prefills += 1
-        ttft[rid] = time.perf_counter() - t0
-        slo.note_ttft(ttft[rid])
-        reqlog.emit("stream", rid, n=1, ttft_s=ttft[rid])
+        with ph("refill.seat", rid=rid) as seat:
+            pkv.seat(b, hit_pages, fresh, S, rid=rid)
+            if pkv.prefix is not None:
+                pkv.prefix.insert(prompt, pkv.pages[b])
+            owner[b] = rid
+            if rid in preempted_rids:
+                preempted_rids.discard(rid)
+                slo.note_resume()
+                reqlog.emit("resume", rid, slot=b)
+            emitted[rid].append(first)
+            if on_token is not None:
+                on_token(rid, first)
+            last_tok[b] = first
+            n_prefills += 1
+            ttft[rid] = time.perf_counter() - t0
+            slo.note_ttft(ttft[rid])
+            reqlog.emit("stream", rid, n=1, ttft_s=ttft[rid])
+        refill_times[rid] = (
+            match.t0 - setup.t0, pre.seconds,
+            match.seconds + scatter.seconds + seat.seconds)
         return True
 
     def retire(b):
         rid = owner[b]
-        done[rid] = np.concatenate(
-            [np.asarray(prompts[rid], np.int32),
-             np.asarray(emitted[rid], np.int32)])
-        finish[rid] = time.perf_counter() - t0
-        reqlog.emit("finish", rid, new_tokens=len(emitted[rid]),
-                    latency_s=finish[rid])
-        owner[b] = -1
-        pkv.release(b)              # pages back to the pool, slot parked
+        with ph("chunk.retire", step=n_steps, rid=rid):
+            done[rid] = np.concatenate(
+                [np.asarray(prompts[rid], np.int32),
+                 np.asarray(emitted[rid], np.int32)])
+            finish[rid] = time.perf_counter() - t0
+            reqlog.emit("finish", rid, new_tokens=len(emitted[rid]),
+                        latency_s=finish[rid])
+            owner[b] = -1
+            pkv.release(b)          # pages back to the pool, slot parked
 
     def preempt(b):
         """Page-pressure eviction: requeue slot b's request UNCHARGED
@@ -1235,6 +1328,7 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
             pkv.preemptions)
 
     qd_samples.append(len(queue))
+    setup.__exit__(None, None, None)
     while queue and any(o == -1 for o in owner):
         b = owner.index(-1)
         if refill(b):
@@ -1245,15 +1339,18 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
 
     stalls = 0
     while any(o >= 0 for o in owner) or queue:
-        qd_samples.append(len(queue))
-        occ_samples.append(sum(o >= 0 for o in owner) / n_slots)
-        slo.note_gauges(qd_samples[-1], occ_samples[-1])
-        _tseries_annotate_best_effort(slo.live_slos())
-        _publish()
-        if queue:
-            for b in _check_fleet_rejoin():
-                if queue and refill(b) and slot_finished(b):
-                    retire(b)
+        step_no = n_steps + 1       # the chunk this pass leads up to
+        with ph("loop.other", step=step_no):
+            qd_samples.append(len(queue))
+            occ_samples.append(sum(o >= 0 for o in owner) / n_slots)
+            slo.note_gauges(qd_samples[-1], occ_samples[-1])
+            if _tseries_armed():    # live_slos() sorts both windows
+                _tseries_annotate_best_effort(slo.live_slos())
+            _publish()
+            if queue:
+                for b in _check_fleet_rejoin():
+                    if queue and refill(b) and slot_finished(b):
+                        retire(b)
         if not any(o >= 0 for o in owner):
             # All slots idle with requests queued (failure requeues, a
             # deferred seed, or total preemption): reseed. The SLO gate
@@ -1276,119 +1373,136 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
                     "seatable (pool exhausted below a single request?)")
             continue
         stalls = 0
-        grow_for_chunk()
+        with ph("chunk.grow", step=step_no):
+            grow_for_chunk()
+            # COW guard (unreachable under the radix policy —
+            # defensive): the pages this chunk writes must be privately
+            # owned.
+            for b in range(n_slots):
+                if owner[b] < 0:
+                    continue
+                for j in range(int(pkv.pos[b]) // pt,
+                               (int(pkv.pos[b]) + chunk - 1) // pt + 1):
+                    if j < len(pkv.pages[b]):
+                        pkv.ensure_writable(b, j)
         if not any(o >= 0 for o in owner):
             continue                # grow_for_chunk preempted everyone
-        # COW guard (unreachable under the radix policy — defensive):
-        # the pages this chunk writes must be privately owned.
-        for b in range(n_slots):
-            if owner[b] < 0:
+        with ph("chunk.upload", step=step_no) as upload:
+            state = pkv.device_state()
+        with ph("chunk.step", step=step_no) as stepped:
+            try:
+                state, toks, keys = step_fn(state, jnp.asarray(last_tok),
+                                            keys)
+                pkv.absorb(state)
+            except Exception as exc:  # noqa: BLE001 — any device failure
+                lost_peer = _peer_dead(exc)
+                if _flight_dump_best_effort():
+                    n_hang_dumps += 1
+                for b in range(n_slots):
+                    if owner[b] >= 0:
+                        rid = owner[b]
+                        owner[b] = -1
+                        _requeue(rid, np.asarray(prompts[rid], np.int32),
+                                 exc, charge=not lost_peer)
+                if lost_peer:
+                    _shed_slot()
+                # The step donated the pool buffers: rebuild from zeros
+                # and drop every reference (prefix cache included — its
+                # pages lived in the donated pool).
+                pkv.reset_pool()
+                last_tok = np.zeros((n_slots,), np.int32)
                 continue
-            for j in range(int(pkv.pos[b]) // pt,
-                           (int(pkv.pos[b]) + chunk - 1) // pt + 1):
-                if j < len(pkv.pages[b]):
-                    pkv.ensure_writable(b, j)
-        step_t0 = time.perf_counter()
-        state = pkv.device_state()
-        try:
-            state, toks, keys = step_fn(state, jnp.asarray(last_tok),
-                                        keys)
-            pkv.absorb(state)
-        except Exception as exc:  # noqa: BLE001 — any device failure
-            lost_peer = _peer_dead(exc)
-            if _flight_dump_best_effort():
-                n_hang_dumps += 1
-            for b in range(n_slots):
-                if owner[b] >= 0:
-                    rid = owner[b]
-                    owner[b] = -1
-                    _requeue(rid, np.asarray(prompts[rid], np.int32),
-                             exc, charge=not lost_peer)
-            if lost_peer:
-                _shed_slot()
-            # The step donated the pool buffers: rebuild from zeros and
-            # drop every reference (prefix cache included — its pages
-            # lived in the donated pool).
-            pkv.reset_pool()
-            last_tok = np.zeros((n_slots,), np.int32)
-            continue
-        block = np.asarray(toks, np.int32)           # [chunk, B]
-        step_dt = time.perf_counter() - step_t0
+            block = np.asarray(toks, np.int32)       # [chunk, B]: waits
+        step_dt = upload.seconds + stepped.seconds   # the spans' readings
         n_steps += 1
+        n_slot_steps += block.shape[0] * n_slots
         reqlog.emit("decode_step", step=n_steps, dt_s=step_dt,
                     active=sum(o >= 0 for o in owner))
-        for b in range(n_slots):
-            last_tok[b] = block[-1, b]
-            if owner[b] < 0:
-                continue
-            got = 0
-            for c in range(block.shape[0]):
-                if slot_finished(b):
-                    break
-                tok = int(block[c, b])
-                emitted[owner[b]].append(tok)
-                if on_token is not None:
-                    on_token(owner[b], tok)
-                itl_samples.append(step_dt / chunk)
-                slo.note_itl(step_dt / chunk)
-                got += 1
-            if got:
-                reqlog.emit("stream", owner[b], n=got, itl_s=step_dt / chunk)
+        with ph("chunk.deliver", step=step_no):
+            for b in range(n_slots):
+                last_tok[b] = block[-1, b]
+                if owner[b] < 0:
+                    continue
+                got = 0
+                for c in range(block.shape[0]):
+                    if slot_finished(b):
+                        break
+                    tok = int(block[c, b])
+                    emitted[owner[b]].append(tok)
+                    if on_token is not None:
+                        on_token(owner[b], tok)
+                    itl_samples.append(step_dt / chunk)
+                    slo.note_itl(step_dt / chunk)
+                    got += 1
+                if got:
+                    n_decode_tokens += got
+                    reqlog.emit("stream", owner[b], n=got,
+                                itl_s=step_dt / chunk)
         for b in range(n_slots):
             while owner[b] >= 0 and slot_finished(b):
                 retire(b)
                 if queue:
                     refill(b)
 
-    _publish()
-    assert all(d is not None for d in done)
-    wall = time.perf_counter() - t0
-    per_request = []
-    total_new = 0
-    for rid in range(len(prompts)):
-        if rid in rejected:
-            continue
-        nt = len(emitted[rid])
-        total_new += nt
-        lat = finish[rid] if finish[rid] is not None else wall
-        per_request.append(RequestTelemetry(
-            rid=rid,
-            ttft_s=ttft[rid] if ttft[rid] is not None else lat,
-            latency_s=lat,
-            new_tokens=nt,
-            tokens_per_s=nt / lat if lat > 0 else 0.0,
-            retries=attempts[rid]))
-    metrics = ServingMetrics(
-        requests=len(prompts),
-        wall_s=wall,
-        new_tokens=total_new,
-        tokens_per_s=total_new / wall if wall > 0 else 0.0,
-        steps=n_steps,
-        prefills=n_prefills,
-        requeues=n_requeues,
-        peer_requeues=n_peer_requeues,
-        slots_shed=n_shed,
-        slots_revived=n_revived,
-        hang_dumps=n_hang_dumps,
-        rejections=len(rejected),
-        rejection_reasons=_count_reasons(rejected.values()),
-        preemptions=n_preempts,
-        prefix_hits=pkv.prefix.hits if pkv.prefix else 0,
-        prefix_evictions=pkv.prefix.evictions if pkv.prefix else 0,
-        prefix_pages_reused=(pkv.prefix.pages_reused if pkv.prefix
-                             else 0),
-        pages_hwm=pkv.pages_hwm,
-        slo_deferrals=n_slo_defer,
-        ttft_p50_s=_pct([r.ttft_s for r in per_request], 0.50),
-        ttft_p99_s=_pct([r.ttft_s for r in per_request], 0.99),
-        itl_p50_s=_pct(itl_samples, 0.50),
-        itl_p99_s=_pct(itl_samples, 0.99),
-        queue_depth_max=max(qd_samples) if qd_samples else 0,
-        queue_depth_mean=(sum(qd_samples) / len(qd_samples)
-                          if qd_samples else 0.0),
-        slot_occupancy_mean=(sum(occ_samples) / len(occ_samples)
-                             if occ_samples else 1.0),
-        per_request=per_request)
+    with ph("loop.other", step=n_steps) as tail:
+        _publish()
+        assert all(d is not None for d in done)
+        wall = time.perf_counter() - t0
+        per_request = []
+        total_new = 0
+        for rid in range(len(prompts)):
+            if rid in rejected:
+                continue
+            nt = len(emitted[rid])
+            total_new += nt
+            lat = finish[rid] if finish[rid] is not None else wall
+            queue_wait_s, prefill_s, refill_host_s = refill_times[rid]
+            per_request.append(RequestTelemetry(
+                rid=rid,
+                ttft_s=ttft[rid] if ttft[rid] is not None else lat,
+                latency_s=lat,
+                new_tokens=nt,
+                tokens_per_s=nt / lat if lat > 0 else 0.0,
+                retries=attempts[rid],
+                queue_wait_s=queue_wait_s,
+                prefill_s=prefill_s,
+                refill_host_s=refill_host_s))
+        metrics = ServingMetrics(
+            requests=len(prompts),
+            wall_s=wall,
+            new_tokens=total_new,
+            tokens_per_s=total_new / wall if wall > 0 else 0.0,
+            steps=n_steps,
+            prefills=n_prefills,
+            requeues=n_requeues,
+            peer_requeues=n_peer_requeues,
+            slots_shed=n_shed,
+            slots_revived=n_revived,
+            hang_dumps=n_hang_dumps,
+            rejections=len(rejected),
+            rejection_reasons=_count_reasons(rejected.values()),
+            preemptions=n_preempts,
+            prefix_hits=pkv.prefix.hits if pkv.prefix else 0,
+            prefix_evictions=pkv.prefix.evictions if pkv.prefix else 0,
+            prefix_pages_reused=(pkv.prefix.pages_reused if pkv.prefix
+                                 else 0),
+            pages_hwm=pkv.pages_hwm,
+            slo_deferrals=n_slo_defer,
+            ttft_p50_s=_pct([r.ttft_s for r in per_request], 0.50),
+            ttft_p99_s=_pct([r.ttft_s for r in per_request], 0.99),
+            itl_p50_s=_pct(itl_samples, 0.50),
+            itl_p99_s=_pct(itl_samples, 0.99),
+            queue_depth_max=max(qd_samples) if qd_samples else 0,
+            queue_depth_mean=(sum(qd_samples) / len(qd_samples)
+                              if qd_samples else 0.0),
+            slot_occupancy_mean=(sum(occ_samples) / len(occ_samples)
+                                 if occ_samples else 1.0),
+            per_request=per_request,
+            decode_slot_steps=n_slot_steps,
+            decode_tokens=n_decode_tokens)
+    # Filled in once the last span has closed: its end is the call's.
+    metrics.call_s = tail.t1 - setup.t0
+    metrics.phase_s, metrics.phase_n = ph.seconds, ph.count
     batch = ServedBatch(done, metrics)
     if return_paged_state:
         batch.paged_state = pkv

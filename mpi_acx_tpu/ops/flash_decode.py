@@ -219,6 +219,8 @@ def _decode_call(q, k, v, pos, n_rep, block_k, n_k, table=None):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        # What the device trace prints the kernel as, by variant.
+        name=("paged_" if table is not None else "") + "flash_decode_attend",
     )(*prefetch, *operands)
     return out.reshape(B, Hkv, W, n_rep, D).transpose(0, 2, 1, 3, 4).reshape(
         B, W, Hq * D)

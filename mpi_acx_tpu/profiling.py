@@ -47,6 +47,68 @@ def annotate(name: str):
     return jax.profiler.TraceAnnotation(name)
 
 
+class _Span:
+    """One open :class:`Phases` span. After the block, ``t0``/``t1`` are
+    its two clock readings and ``seconds`` their difference (children
+    included): a caller that needs the boundary reads it here instead of
+    reading the clock again."""
+
+    __slots__ = ("_ph", "name", "_note", "t0", "t1", "seconds", "_children")
+
+    def __init__(self, ph, name, ids):
+        self._ph, self.name = ph, name
+        self._note = jax.profiler.TraceAnnotation(name, **ids)
+        self.t0 = self.t1 = self.seconds = self._children = 0.0
+
+    def __enter__(self):
+        self._note.__enter__()
+        self._ph._open.append(self)
+        self.t0 = self._ph._clock()
+        return self
+
+    def __exit__(self, *exc):
+        ph = self._ph
+        self.t1 = ph._clock()
+        self._note.__exit__(*exc)
+        self.seconds = self.t1 - self.t0
+        ph._open.pop()
+        if ph._open:
+            ph._open[-1]._children += self.seconds
+        ph.seconds[self.name] = (ph.seconds.get(self.name, 0.0)
+                                 + self.seconds - self._children)
+        ph.count[self.name] = ph.count.get(self.name, 0) + 1
+        return False
+
+
+class Phases:
+    """Named phases of a host loop, as spans on the device trace's clock
+    and as counters, from one ``with``:
+
+        ph = Phases()
+        with ph("refill.prefill", rid=rid) as span:
+            ...
+        ph.seconds["refill.prefill"], ph.count["refill.prefill"]
+
+    ``ph(name, **ids)`` opens ``jax.profiler.TraceAnnotation(name,
+    **ids)``: while the profiler runs, the span sits on the host's
+    ``python`` line of the same ``.xplane.pb`` as the device's ops (one
+    clock), nested under the span that was open, carrying ``ids``; with
+    the profiler off it costs a flag test. It also adds the span's SELF
+    time (its duration on ``clock``, less the spans opened inside it)
+    to ``seconds[name]`` and one to ``count[name]``, so the self times
+    of spans that tile a call sum to the call. The profiler's buffer is
+    the only span store; nothing is written. One thread."""
+
+    def __init__(self, clock=time.perf_counter):
+        self.seconds: Dict[str, float] = {}
+        self.count: Dict[str, int] = {}
+        self._clock = clock
+        self._open: List[_Span] = []
+
+    def __call__(self, name: str, **ids) -> _Span:
+        return _Span(self, name, ids)
+
+
 class StepTimer:
     """Wall-clock statistics over training/serving steps.
 

@@ -429,3 +429,72 @@ def test_slo_gate_off_by_default_and_defers_under_target(monkeypatch):
     for f, p in zip(want, out):
         np.testing.assert_array_equal(np.asarray(f), np.asarray(p))
     assert out.metrics.slo_deferrals >= 1
+
+
+# --------------------------------------------------------------------------
+# phase spans and counters of the serve loop (profiling.Phases)
+
+PHASES = ("serve.setup", "refill.match", "refill.prefill", "refill.scatter",
+          "refill.seat", "chunk.grow", "chunk.upload", "chunk.step",
+          "chunk.deliver", "chunk.retire", "loop.other")
+
+
+def test_serve_paged_phases_cover_the_call_and_count_the_decode_work():
+    """Seven requests through three slots, outputs of mixed length, so
+    four wait for a retire: every span of the docstring's table is
+    there, their self times sum to the call, the per-request queue wait
+    and prefill sit inside its TTFT, the decode counters equal a hand
+    count, and the outputs are still serve_greedy's bit for bit."""
+    cfg, params, prompts = _serve_setup()
+    n_new = [6, 3, 9, 2, 5, 7, 4]
+    chunk, n_slots = 4, 3
+    fixed = serving.serve_greedy(params, cfg, prompts, n_new,
+                                 n_slots=n_slots, max_len=32, family=tfm,
+                                 chunk=chunk)
+    streamed = []
+    paged = serving.serve_paged_greedy(
+        params, cfg, prompts, n_new, n_slots=n_slots, max_len=32,
+        family=tfm, chunk=chunk, page_tokens=8,
+        on_token=lambda rid, tok: streamed.append(rid))
+    for i, (f, p) in enumerate(zip(fixed, paged)):
+        np.testing.assert_array_equal(np.asarray(f), np.asarray(p),
+                                      err_msg=f"request {i}")
+    m = paged.metrics
+    assert set(m.phase_s) == set(m.phase_n) == set(PHASES)
+    assert all(v >= 0 for v in m.phase_s.values())
+    assert abs(sum(m.phase_s.values()) - m.call_s) <= 0.02 * m.call_s
+    assert m.call_s >= m.wall_s + 0.9 * m.phase_s["serve.setup"]
+    # one span per event: a refill's four, a chunk's four, one set-up
+    for name in ("refill.match", "refill.prefill", "refill.scatter",
+                 "refill.seat", "chunk.retire"):
+        assert m.phase_n[name] == len(prompts), name
+    for name in ("chunk.grow", "chunk.upload", "chunk.step",
+                 "chunk.deliver"):
+        assert m.phase_n[name] == m.steps, name
+    assert m.phase_n["serve.setup"] == 1
+    assert m.phase_n["loop.other"] == m.steps + 1      # + the tail
+
+    # decode work, by hand: every chunk offers chunk x slots steps; a
+    # request's tokens beyond its first (the prefill's) come from them
+    assert m.decode_slot_steps == m.steps * chunk * n_slots
+    assert m.decode_tokens == sum(n_new) - len(prompts) == 29
+    assert m.decode_tokens == len(streamed) - len(prompts)
+    assert m.step_utilization == 29 / m.decode_slot_steps
+    assert 0 < m.step_utilization < m.slot_occupancy_mean   # mid-chunk ends
+
+    setup = m.phase_s["serve.setup"]
+    by_rid = {r.rid: r for r in m.per_request}
+    for r in m.per_request:
+        assert r.prefill_s > 0 and r.refill_host_s > 0
+        assert r.queue_wait_s + r.prefill_s <= r.ttft_s + setup
+    assert setup <= by_rid[0].queue_wait_s <= setup + 0.05
+    # the first three are seated at once, the rest by a refill on retire
+    assert all(by_rid[i].queue_wait_s > by_rid[0].queue_wait_s
+               for i in range(1, len(prompts)))
+    assert all(by_rid[i].queue_wait_s > by_rid[2].queue_wait_s
+               + by_rid[2].prefill_s for i in range(3, len(prompts)))
+    assert abs(sum(r.prefill_s for r in m.per_request)
+               - m.phase_s["refill.prefill"]) < 1e-6
+    assert abs(sum(r.refill_host_s for r in m.per_request)
+               - (m.phase_s["refill.match"] + m.phase_s["refill.scatter"]
+                  + m.phase_s["refill.seat"])) < 1e-6
