@@ -341,6 +341,7 @@ def phase_serve_paged(size: Size = FULL, seed: int = 0,
              **_serve_stats(outs.metrics),
              prefix_hits=outs.metrics.prefix_hits,
              pages_hwm=outs.metrics.pages_hwm,
+             kv_write_path=outs.metrics.paged_kv_write,
              token_mismatch_vs_dense=_mismatch_share(outs, ref, prompts),
              **row)
     return True

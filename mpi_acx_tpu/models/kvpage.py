@@ -12,7 +12,10 @@ block table:
 
 * **Pool** — ``{'k','v': [L, P, H, Dh, page_tokens]}`` device buffers
   in the cache layout (decoding.to_cache_layout: a page of one head is
-  a ``[Dh, page_tokens]`` slab of whole TPU tiles), plus ``'ks','vs'``
+  a ``[Dh, page_tokens]`` slab of whole TPU tiles), kept WHOLE through
+  the decode step: its kernels address a page as ``(layer, page)``
+  from prefetched scalars and no layer is ever sliced out
+  (:func:`paged_decode_step`), plus ``'ks','vs'``
   ``[L, P, H, 1, page_tokens]`` f32 scale pages when the cache is int8 —
   ops/kvquant.py codes + scales stay the only page-resident form, the
   same EQuARX rule the wire plane enforces). The trailing ``n_slots``
@@ -289,41 +292,45 @@ def paged_decode_step(params, cfg, state, token, page_tokens: int,
     ``state`` = pool keys + ``'table'`` [B, max_pages] + ``'pos'``
     [B]. Idle slots write their parking page (their table rows point
     nowhere else) and the page index is clipped so a long-idle slot's
-    walking pos can never index past its table row."""
+    walking pos can never index past its table row.
+
+    The pools ride the layer scan WHOLE: the write and the attend are
+    handed ``[L, P, H, *, pt]`` and the layer index, and on the chip
+    both are Pallas calls whose index maps address ``(layer, page)``
+    from prefetched scalars (ops/flash_decode.py: ``paged_kv_write``
+    updates the pool in place, a page's read-modify-write a slot;
+    ``paged_flash_decode_attend`` reads the live pages). Nothing here
+    may slice a layer out of a pool: that is a copy of the layer every
+    layer of every step. Off the chip, or at a page Mosaic cannot tile,
+    ``select_paged_kv_write`` / ``select_paged_decode_attend`` hand back
+    the dense pair (``.at[].set`` on a sliced layer, gather + dense
+    attend): the same values, the bit-equality anchor."""
     from mpi_acx_tpu.models import transformer as tfm
-    from mpi_acx_tpu.ops.flash_decode import select_paged_decode_attend
+    from mpi_acx_tpu.ops.flash_decode import (select_paged_decode_attend,
+                                              select_paged_kv_write)
     from mpi_acx_tpu.ops.kvquant import kv_quant
     from mpi_acx_tpu.ops.wquant import wread
 
     ffn = ffn or tfm._mlp
     table, pos = state["table"], state["pos"]
     B, max_pages = table.shape
-    quant = "ks" in state
+    keys = tuple(k for k in _POOL_KEYS if k in state)   # k, v[, ks, vs]
+    quant = "ks" in keys
     pe = params["pos"][pos][:, None, :]
     x = (params["embed"][token][:, None, :] + pe).astype(cfg.dtype)
 
+    # Slot b's token column: distinct pages per slot (each slot owns its
+    # pages; idle slots own their parking page), so writes never collide.
     write_page = jnp.take_along_axis(
         table, jnp.minimum(pos // page_tokens, max_pages - 1)[:, None],
         axis=1)[:, 0]                                  # [B]
     off = pos % page_tokens
 
-    def write(pool, fresh, i):
-        """pool [L, P, H, *, pt]; fresh [B, 1, H, *] -> slot b's token
-        column (write_page[b], :, :, off[b]). Distinct pages per slot
-        (each slot owns its pages; idle slots own their parking page),
-        so the scatter never collides."""
-        layer = lax.dynamic_index_in_dim(pool, i, 0, keepdims=False)
-        layer = layer.at[write_page, :, :, off].set(
-            fresh[:, 0].astype(pool.dtype))
-        return lax.dynamic_update_index_in_dim(pool, layer, i, 0)
-
+    write = select_paged_kv_write(cfg.decode_flash, page_tokens)
     attend = select_paged_decode_attend(cfg.decode_flash)
 
     def body(carry, i):
-        if quant:
-            x, kp, vp, ksp, vsp = carry
-        else:
-            x, kp, vp = carry
+        x, pools = carry
         lp = jax.tree.map(
             lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
             params["layers"])
@@ -331,31 +338,19 @@ def paged_decode_step(params, cfg, state, token, page_tokens: int,
         if quant:
             k, ks = kv_quant(k)
             v, vs = kv_quant(v)
-            ksp = write(ksp, ks, i)
-            vsp = write(vsp, vs, i)
-        kp = write(kp, k, i)
-        vp = write(vp, v, i)
-        kl = lax.dynamic_index_in_dim(kp, i, 0, keepdims=False)
-        vl = lax.dynamic_index_in_dim(vp, i, 0, keepdims=False)
+        pools = write(pools, (k, v, ks, vs) if quant else (k, v), i,
+                      write_page, off)
+        kp, vp = pools[:2]
         if quant:
-            kl = (kl, lax.dynamic_index_in_dim(ksp, i, 0, keepdims=False))
-            vl = (vl, lax.dynamic_index_in_dim(vsp, i, 0, keepdims=False))
-        o = attend(q, kl, vl, table, pos, page_tokens, 1)
+            kp, vp = (kp, pools[2]), (vp, pools[3])
+        o = attend(q, kp, vp, table, pos, page_tokens, 1, layer=i)
         x = ffn(cfg, lp, x + o @ wread(lp, "wo", x.dtype))
-        if quant:
-            return (x, kp, vp, ksp, vsp), None
-        return (x, kp, vp), None
+        return (x, pools), None
 
     n_layers = jax.tree.leaves(params["layers"])[0].shape[0]
-    if quant:
-        carry = (x, state["k"], state["v"], state["ks"], state["vs"])
-        (x, kp, vp, ksp, vsp), _ = lax.scan(body, carry,
-                                            jnp.arange(n_layers))
-        out = {"k": kp, "v": vp, "ks": ksp, "vs": vsp}
-    else:
-        (x, kp, vp), _ = lax.scan(body, (x, state["k"], state["v"]),
-                                  jnp.arange(n_layers))
-        out = {"k": kp, "v": vp}
+    (x, pools), _ = lax.scan(body, (x, tuple(state[k] for k in keys)),
+                             jnp.arange(n_layers))
+    out = dict(zip(keys, pools))
     out["table"] = table
     out["pos"] = pos + 1
     x = tfm.layernorm(x, params["lnf_g"], params["lnf_b"])

@@ -91,6 +91,8 @@ class ServingMetrics:
     prefix_evictions: int = 0     # paged: trie pages evicted under pressure
     prefix_pages_reused: int = 0  # paged: prompt pages seated from the trie
     pages_hwm: int = 0            # paged: pool pages-in-use high-water mark
+    paged_kv_write: str = ""      # paged: the write the step program was built
+                                  # with (flash_decode.select_paged_kv_write)
     slo_deferrals: int = 0        # paged: refills deferred by the SLO gate
     ttft_p50_s: float = 0.0
     ttft_p99_s: float = 0.0
@@ -1028,6 +1030,7 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
     start of the refill that seated it), ``prefill_s`` and
     ``refill_host_s`` are that refill's spans."""
     from mpi_acx_tpu.models import kvpage
+    from mpi_acx_tpu.ops.flash_decode import select_paged_kv_write
     from mpi_acx_tpu.profiling import Phases
 
     ph = Phases()
@@ -1487,6 +1490,8 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
             prefix_pages_reused=(pkv.prefix.pages_reused if pkv.prefix
                                  else 0),
             pages_hwm=pkv.pages_hwm,
+            paged_kv_write=select_paged_kv_write(cfg.decode_flash,
+                                                 pt).__name__,
             slo_deferrals=n_slo_defer,
             ttft_p50_s=_pct([r.ttft_s for r in per_request], 0.50),
             ttft_p99_s=_pct([r.ttft_s for r in per_request], 0.99),

@@ -30,13 +30,26 @@ that read with an online softmax over K/V blocks that is
 * **window-capable** — W > 1 for the speculative-decode window passes,
   and ``pos`` scalar or ``[B]`` for continuous-batching serving.
 * **paged or contiguous** — one kernel body: block j is either tokens
-  ``[j*block_k, (j+1)*block_k)`` of the slot's own cache row or pool
-  page ``table[b, j]`` (models/kvpage.py), resolved in the index map
-  from a second scalar-prefetched operand.
+  ``[j*block_k, (j+1)*block_k)`` of the slot's own cache row or page
+  ``(layer, table[b, j])`` of the WHOLE pool ``[L, P, Hkv, D,
+  page_tokens]`` (models/kvpage.py), resolved in the index map from two
+  more scalar-prefetched operands: the block table and the layer
+  index. The step program never slices a layer out of the pool — a
+  ``dynamic_index_in_dim`` there is a copy of 98 MB a layer a step at
+  GPT-2 XL's 240 pages, and was 93% of a decode step (PERF.md, PR 25).
+* **written in place** — :func:`paged_kv_write` is the step's other
+  Pallas call: the one fresh K/V token of every slot goes into lane
+  ``pos % page_tokens`` of page ``(layer, write_page[b])`` of the same
+  whole pool, aliased to the call's result. With tokens on lanes one
+  token is one lane of every ``(8..32, 128)`` tile of the page, so a
+  page is the smallest unit a DMA can move for it: the write is a
+  page's read-modify-write, not a scatter (XLA's ``.at[..., off].set``
+  on the lane dimension relayouts the whole layer there and back).
 
 Cache layout (models/decoding.to_cache_layout): ``[B, Hkv, D,
-max_len]``, pool ``[P, Hkv, D, page_tokens]``, scales ``[..., 1, T]`` —
-tokens on the lane dimension. A block is then ``[Hkv, D, block_k]``:
+max_len]``, pool ``[L, P, Hkv, D, page_tokens]`` (one layer of it ``[P,
+Hkv, D, page_tokens]``), scales ``[..., 1, T]`` — tokens on the lane
+dimension. A block is then ``[Hkv, D, block_k]``:
 whole (8..32, 128) tiles for any head_dim that is a multiple of 32, with
 no lane padding at head_dim 64, in the layout XLA's TPU layout
 assignment gives such arrays anyway (no relayout copy in front of the
@@ -98,7 +111,7 @@ def _decode_kernel(*refs, block_k, n_rep, n_k, quant, n_prefetch, scale):
     window slot i // n_rep); on fully visible blocks the mask is all
     true. With ``quant`` the K/V blocks are int8 codes and their scales
     [Hkv, 1, block_k] multiply the scores / probabilities."""
-    pos_ref = refs[0]       # a block table after it is the index map's
+    pos_ref = refs[0]       # block table and layer after it: the index map's
     q_ref, k_ref, v_ref, *refs = refs[n_prefetch:]
     if quant:
         ks_ref, vs_ref, *refs = refs
@@ -154,11 +167,13 @@ def _decode_kernel(*refs, block_k, n_rep, n_k, quant, n_prefetch, scale):
         o_ref[0] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
 
 
-def _decode_call(q, k, v, pos, n_rep, block_k, n_k, table=None):
+def _decode_call(q, k, v, pos, n_rep, block_k, n_k, table=None, layer=None):
     """The one pallas_call behind both kernels. ``k``/``v`` are K/V
     arrays or (codes, scales) tuples in cache layout
-    ([B, Hkv, *, max_len]) or, with ``table``, pool layout
-    ([P, Hkv, *, page_tokens])."""
+    ([B, Hkv, *, max_len]) or, with ``table``, pool layout: the whole
+    pool ([L, P, Hkv, *, page_tokens]) with ``layer`` (a traced scalar
+    is fine) naming the layer to read, or one layer of it
+    ([P, Hkv, *, page_tokens]) without."""
     ks = vs = None
     if isinstance(k, tuple):
         k, ks = k
@@ -175,7 +190,7 @@ def _decode_call(q, k, v, pos, n_rep, block_k, n_k, table=None):
             "reference.")
 
     B, W, Hq, D = q.shape
-    Hkv = k.shape[1]
+    Hkv = k.shape[-3]
     assert Hq == Hkv * n_rep, (Hq, Hkv, n_rep)
     Wn = W * n_rep
 
@@ -184,24 +199,40 @@ def _decode_call(q, k, v, pos, n_rep, block_k, n_k, table=None):
         pos = jnp.full((B,), pos, jnp.int32)
     prefetch = [pos]
     if table is not None:
-        prefetch.append(jnp.asarray(table, jnp.int32))
+        # One layer's pool is a pool of one layer (a free reshape of
+        # major dimensions), so the paged index map has one form.
+        if layer is None:
+            layer = 0
+            k, v = k[None], v[None]
+            if quant:
+                ks, vs = ks[None], vs[None]
+        prefetch += [jnp.asarray(table, jnp.int32),
+                     jnp.asarray(layer, jnp.int32).reshape(1)]
 
     # [B, W, Hkv, n_rep, D] -> [B, Hkv, W*n_rep, D]: row i = w*n_rep + r
     # so the kernel recovers the window slot as i // n_rep.
     qg = q.reshape(B, W, Hkv, n_rep, D).transpose(0, 2, 1, 3, 4).reshape(
         B, Hkv, Wn, D)
 
-    def kv_index(b, j, pos_ref, *table_ref):
+    def kv_index(b, j, pos_ref, *paged):
         jj = jnp.minimum(j, _n_live(pos_ref[b], W, block_k, n_k) - 1)
-        if table_ref:
-            return (table_ref[0][b, jj], 0, 0, 0)
+        if paged:
+            table_ref, layer_ref = paged
+            return (layer_ref[0], table_ref[b, jj], 0, 0, 0)
         return (b, 0, 0, jj)
 
+    def kv_spec(rows):
+        """One slot's block of every KV head: [1, Hkv, rows, block_k] in
+        the kernel either way (the pool's layer dimension is squeezed)."""
+        block = (1, Hkv, rows, block_k)
+        return pl.BlockSpec(block if table is None else (None,) + block,
+                            kv_index)
+
     q_spec = pl.BlockSpec((1, Hkv, Wn, D), lambda b, j, *_: (b, 0, 0, 0))
-    in_specs = [q_spec] + [pl.BlockSpec((1, Hkv, D, block_k), kv_index)] * 2
+    in_specs = [q_spec] + [kv_spec(D)] * 2
     operands = [qg, k, v]
     if quant:
-        in_specs += [pl.BlockSpec((1, Hkv, 1, block_k), kv_index)] * 2
+        in_specs += [kv_spec(1)] * 2
         operands += [ks, vs]
 
     kernel = functools.partial(
@@ -236,14 +267,26 @@ def flash_decode_attend(q, kc, vc, pos, max_len, n_rep, block_k: int = 256):
     return _decode_call(q, kc, vc, pos, n_rep, block_k, max_len // block_k)
 
 
-def paged_gather_attend(q, kp, vp, table, pos, page_tokens, n_rep):
+def _layer_of(pool, layer):
+    """One layer of a whole pool, sliced out (a copy of the layer: the
+    dense reference's way), for arrays and (codes, scales) tuples."""
+    if layer is None:
+        return pool
+    return jax.tree.map(lambda p: jax.lax.dynamic_index_in_dim(
+        p, layer, 0, keepdims=False), pool)
+
+
+def paged_gather_attend(q, kp, vp, table, pos, page_tokens, n_rep,
+                        layer=None):
     """Dense reference for paged attention: gather each slot's pages
     into the contiguous ``[B, Hkv, D, max_len]`` layout the fixed-slot
     path attends and call :func:`dense_decode_attend` — identical
     shapes, identical XLA reduction, so a paged slot whose pages hold
     the fixed cache's rows produces BIT-EQUAL output (gathered garbage
     past the horizon contributes exactly 0.0 through the masked
-    softmax, same as the fixed cache's own dead tail)."""
+    softmax, same as the fixed cache's own dead tail). With ``layer``
+    the pools are whole (``[L, P, ...]``) and that layer is sliced out
+    first."""
     from mpi_acx_tpu.models.decoding import dense_decode_attend
 
     B, max_pages = table.shape
@@ -254,34 +297,41 @@ def paged_gather_attend(q, kp, vp, table, pos, page_tokens, n_rep):
         t = jnp.moveaxis(t, 1, 3)             # [B, H, *, max_pages, pt]
         return t.reshape(t.shape[:3] + (max_len,))
 
-    kin = ((gather(kp[0]), gather(kp[1])) if isinstance(kp, tuple)
-           else gather(kp))
-    vin = ((gather(vp[0]), gather(vp[1])) if isinstance(vp, tuple)
-           else gather(vp))
+    kin = jax.tree.map(gather, _layer_of(kp, layer))
+    vin = jax.tree.map(gather, _layer_of(vp, layer))
     return dense_decode_attend(q, kin, vin, pos, max_len, n_rep)
 
 
-def paged_flash_decode_attend(q, kp, vp, table, pos, page_tokens, n_rep):
+def paged_flash_decode_attend(q, kp, vp, table, pos, page_tokens, n_rep,
+                              layer=None):
     """Pallas paged decode attention: K/V pools ``[P, Hkv, D,
     page_tokens]`` (plus (codes, scales) tuples for int8 pools) addressed through
-    a ``[B, max_pages]`` block table. Block size IS the page size, so
+    a ``[B, max_pages]`` block table — or, with ``layer``, the whole
+    pools ``[L, P, Hkv, D, page_tokens]``, of which the index map reads
+    layer ``layer`` in place. Block size IS the page size, so
     at ``block_k == page_tokens`` this and :func:`flash_decode_attend`
     run identical FLOPs over identical block values (one kernel body).
     Compiled for the chip, a page that is not a multiple of 128 tokens
     raises."""
     return _decode_call(q, kp, vp, pos, n_rep, page_tokens,
-                        table.shape[1], table=table)
+                        table.shape[1], table=table, layer=layer)
 
 
-def auto_paged_decode_attend(q, kp, vp, table, pos, page_tokens, n_rep):
+def _paged_kernels_fit(page_tokens):
+    """The paged auto policy's one predicate, for the attend and the
+    write alike: on a TPU, and a page Mosaic can tile."""
+    return backend.on_tpu() and page_tokens % 128 == 0
+
+
+def auto_paged_decode_attend(q, kp, vp, table, pos, page_tokens, n_rep,
+                             layer=None):
     """Paged auto policy: the Pallas paged kernel on TPU when Mosaic
     can tile the page (page_tokens % 128 == 0); the gather-dense
     reference elsewhere — on CPU a dense einsum beats an interpreted
     kernel, and gather-dense is also the bit-equality anchor."""
-    if backend.on_tpu() and page_tokens % 128 == 0:
-        return paged_flash_decode_attend(q, kp, vp, table, pos,
-                                         page_tokens, n_rep)
-    return paged_gather_attend(q, kp, vp, table, pos, page_tokens, n_rep)
+    attend = (paged_flash_decode_attend if _paged_kernels_fit(page_tokens)
+              else paged_gather_attend)
+    return attend(q, kp, vp, table, pos, page_tokens, n_rep, layer=layer)
 
 
 def select_paged_decode_attend(decode_flash):
@@ -289,11 +339,127 @@ def select_paged_decode_attend(decode_flash):
     same ``decode_flash`` config field: ``None`` -> auto, ``True`` ->
     paged Pallas kernel, ``False`` -> gather-dense reference. All
     returned callables take
-    ``(q, kp, vp, table, pos, page_tokens, n_rep)``."""
+    ``(q, kp, vp, table, pos, page_tokens, n_rep, layer=None)``."""
     if decode_flash is None:
         return auto_paged_decode_attend
     return (paged_flash_decode_attend if decode_flash
             else paged_gather_attend)
+
+
+def _kv_write_kernel(layer_ref, page_ref, off_ref, *refs):
+    """One slot's grid step: for each pool, its page ``[H, *, pt]``
+    with lane ``off`` replaced by the slot's fresh vector. ``refs`` =
+    the fresh blocks ``[H, *, B]`` (every slot's vector, slots on
+    lanes), the pages in, the pages out; the layer and the page were
+    the index maps' business. The slot's column is picked by a masked
+    lane sum of the values' BITS (one nonzero term: exact, and a -0.0
+    or a NaN stays what it was), then broadcast along the page's lanes."""
+    n = len(refs) // 3
+    b = pl.program_id(0)
+    off = off_ref[b]
+    for fresh, page, out in zip(refs[:n], refs[n:2 * n], refs[2 * n:]):
+        x = fresh[...]
+        floating = jnp.issubdtype(x.dtype, jnp.floating)
+        bits = (jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)
+                if floating else x.astype(jnp.int32))
+        slot = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+        col = jnp.sum(jnp.where(slot == b, bits, 0), axis=-1, keepdims=True)
+        if floating:
+            col = jax.lax.bitcast_convert_type(col, jnp.float32)
+        lane = jax.lax.broadcasted_iota(jnp.int32, page.shape,
+                                        page.ndim - 1)
+        out[...] = jnp.where(lane == off, col.astype(page.dtype), page[...])
+
+
+def paged_kv_write(pools, fresh, layer, write_page, off):
+    """Write one token per slot into the page pools IN PLACE: for every
+    pool ``[L, P, H, *, pt]`` of ``pools`` and its ``fresh`` ``[B, 1, H,
+    *]``, slot b's vector lands at ``pool[layer, write_page[b], :, :,
+    off[b]]``. One Pallas call over grid ``(slots,)`` for all the pools
+    (K and V, and their scale pages when the cache is int8), each
+    passed whole and aliased to its result; ``layer``, ``write_page``
+    and ``off`` are scalar-prefetched and the index maps address
+    ``(layer, page)``, so no value of a layer's pool is ever made. A
+    slot's step reads its page, replaces one lane and writes the page
+    back (module docstring: the page is the unit). Slots write distinct
+    pages (each owns its pages; an idle slot its parking page), so the
+    grid steps never collide. Returns the pools as a tuple.
+
+    The call is jitted on its own, inside whatever program calls it,
+    so that it is traced ONCE a process. A Mosaic kernel is serialized
+    into its program with the Python traceback of where it was traced,
+    ten frames deep, and the persistent compilation cache hashes those
+    bytes: traced afresh under each caller, this call (eight frames
+    below ``serve_paged_greedy``'s caller) made the step program a new
+    cache entry, and a compile, for every call path to the server
+    (PERF.md, PR 25). The attend's frames end inside this package."""
+    return _paged_kv_write(tuple(pools), tuple(fresh), layer, write_page,
+                           off, interpret=not backend.on_tpu())
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
+def _paged_kv_write(pools, fresh, layer, write_page, off, interpret):
+    B, n = write_page.shape[0], len(pools)
+    # All slots' fresh vectors ride as ONE resident block a pool, slots
+    # on lanes ([H, *, B]: a small transpose outside). One lane-wide
+    # block a slot ([H, *, 1]) works too, but a lane-1 array is padded
+    # to 128 lanes in HBM and XLA's relayout into it cost as much as
+    # the page writes themselves (PERF.md, PR 25).
+    fresh = [jnp.moveaxis(f[:, 0], 0, -1).astype(p.dtype)
+             for f, p in zip(fresh, pools)]
+
+    def page_spec(p):
+        return pl.BlockSpec(
+            (None, None) + p.shape[2:],
+            lambda b, layer_ref, page_ref, off_ref: (
+                layer_ref[0], page_ref[b], 0, 0, 0))
+
+    fresh_specs = [pl.BlockSpec(f.shape, lambda b, *_: (0, 0, 0))
+                   for f in fresh]
+    page_specs = [page_spec(p) for p in pools]
+    prefetch = (jnp.asarray(layer, jnp.int32).reshape(1),
+                write_page.astype(jnp.int32), off.astype(jnp.int32))
+    out = pl.pallas_call(
+        _kv_write_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch), grid=(B,),
+            in_specs=fresh_specs + page_specs, out_specs=page_specs),
+        out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools],
+        # Operand numbers count the prefetched scalars.
+        input_output_aliases={len(prefetch) + n + j: j for j in range(n)},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name="paged_kv_write",          # what the device trace prints
+    )(*prefetch, *fresh, *pools)
+    return tuple(out)
+
+
+def paged_kv_write_dense(pools, fresh, layer, write_page, off):
+    """Dense reference for :func:`paged_kv_write`, same arguments and
+    result: slice the layer out of each pool, scatter the token columns
+    (``.at[write_page, :, :, off].set``), put the layer back. Right
+    anywhere and the anchor the kernel is held bit-equal to; on the
+    chip it copies and relayouts the whole layer for one token."""
+    def write(pool, f):
+        lyr = jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False)
+        lyr = lyr.at[write_page, :, :, off].set(f[:, 0].astype(pool.dtype))
+        return jax.lax.dynamic_update_index_in_dim(pool, lyr, layer, 0)
+    return tuple(write(p, f) for p, f in zip(pools, fresh))
+
+
+def select_paged_kv_write(decode_flash, page_tokens):
+    """The write arm of the same idiom, keyed on the same
+    ``decode_flash`` field and choosing as the attend does: ``None`` ->
+    :func:`paged_kv_write` where :func:`auto_paged_decode_attend` takes
+    the Pallas kernel (on a TPU, ``page_tokens % 128 == 0``) and the
+    dense write elsewhere; ``True`` -> the kernel (interpret mode
+    off-TPU); ``False`` -> the dense write. Both take
+    ``(pools, fresh, layer, write_page, off)``; the chosen function's
+    ``__name__`` is what ``ServingMetrics.paged_kv_write`` records."""
+    if decode_flash is None:
+        decode_flash = _paged_kernels_fit(page_tokens)
+    return paged_kv_write if decode_flash else paged_kv_write_dense
 
 
 def auto_decode_attend(q, kc, vc, pos, max_len, n_rep):

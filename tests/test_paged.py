@@ -29,10 +29,15 @@ from mpi_acx_tpu.models import serving
 from mpi_acx_tpu.models import transformer as tfm
 from mpi_acx_tpu.models.decoding import (dense_decode_attend,
                                          to_cache_layout)
+from mpi_acx_tpu import backend
+from mpi_acx_tpu.ops import flash_decode
 from mpi_acx_tpu.ops.flash_decode import (flash_decode_attend,
                                           paged_flash_decode_attend,
                                           paged_gather_attend,
-                                          select_paged_decode_attend)
+                                          paged_kv_write,
+                                          paged_kv_write_dense,
+                                          select_paged_decode_attend,
+                                          select_paged_kv_write)
 from mpi_acx_tpu.ops.kvquant import kv_quant
 
 B, Hkv, D, MAX_LEN, PT = 3, 2, 16, 96, 32       # max_pages = 3
@@ -143,6 +148,178 @@ def test_select_paged_decode_attend_dispatch():
     _, _, pk, pv, table = _paginate(kc, vc)
     out = auto(q, pk, pv, table, 10, PT, 1)
     assert out.shape == (B, 1, Hkv * D)
+
+
+@pytest.mark.parametrize("kind", ["bf16", "int8"])
+@pytest.mark.parametrize("posmode", ["scalar", "vector"])
+def test_whole_pool_attend_bit_equals_per_layer(kind, posmode):
+    """Handed the WHOLE pool [L, P, ...] and a layer index (traced, as
+    in the layer scan), both paged attends read that layer's pages and
+    no other's: bit-equal to the same call on the layer alone."""
+    q, kc, vc = _fixed_case(n_rep=2, W=1, kind=kind, seed=11)
+    _, _, pk, pv, table = _paginate(kc, vc)
+    pos = 50 if posmode == "scalar" else jnp.array([0, 41, 77], jnp.int32)
+    rng = np.random.default_rng(5)
+
+    def whole(layer_pool, at):
+        """3 layers, ``layer_pool`` at index ``at``, noise elsewhere."""
+        def stack(p):
+            noise = jnp.asarray(rng.integers(-100, 100, (3,) + p.shape),
+                                p.dtype)
+            return noise.at[at].set(p)
+        return jax.tree.map(stack, layer_pool)
+
+    for attend in (paged_flash_decode_attend, paged_gather_attend):
+        ref = attend(q, pk, pv, table, pos, PT, 2)
+        got = jax.jit(lambda k, v, i: attend(q, k, v, table, pos, PT, 2,
+                                             layer=i))(
+            whole(pk, 1), whole(pv, 1), jnp.int32(1))
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(ref, np.float32),
+                                      err_msg=attend.__name__)
+
+
+# --------------------------------------------------------------------------
+# the in-place page write vs the dense scatter
+
+N_LAYERS, MAX_PAGES = 3, MAX_LEN // PT
+PARK = B * MAX_PAGES                      # slot b parks on page PARK + b
+
+# name -> (pos [B], slots that are idle: their table row is all parking)
+WRITE_CASES = {
+    "uniform-off-0": ([PT, PT, PT], ()),
+    "uniform-off-last": ([2 * PT - 1] * 3, ()),
+    "per-slot-pos": ([0, PT + 7, 2 * PT - 1], ()),
+    "idle-slot-parked": ([5, 3 * PT + 9, PT], (1,)),   # idle pos walks on
+    "last-table-column": ([MAX_LEN - 1, 2 * PT, 4], ()),
+}
+
+
+def _write_case(kind, case, seed=0):
+    """(pools, fresh, write_page, off) as paged_decode_step makes them:
+    random pools [L, P, H, *, PT] (+ scale pages when int8), one fresh
+    vector a slot, the slot's page and lane from its table row."""
+    rng = np.random.default_rng(seed)
+    pos, idle = WRITE_CASES[case]
+    P = PARK + B
+    shape = (N_LAYERS, P, Hkv, D, PT)
+    if kind == "int8":
+        def pool():
+            return jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+        def scales(*lead):
+            return jnp.asarray(rng.random(lead, np.float32) + 0.01)
+        pools = (pool(), pool(), scales(N_LAYERS, P, Hkv, 1, PT),
+                 scales(N_LAYERS, P, Hkv, 1, PT))
+        fk, fks = kv_quant(jnp.asarray(
+            rng.standard_normal((B, 1, Hkv, D)), jnp.float32))
+        fv, fvs = kv_quant(jnp.asarray(
+            rng.standard_normal((B, 1, Hkv, D)), jnp.float32))
+        fresh = (fk, fv, fks, fvs)
+    else:
+        pools = tuple(jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+                      for _ in range(2))
+        fresh = tuple(jnp.asarray(rng.standard_normal((B, 1, Hkv, D)),
+                                  jnp.bfloat16).at[0, 0, 0, 0].set(-0.0)
+                      for _ in range(2))
+    table = np.arange(PARK, dtype=np.int32).reshape(B, MAX_PAGES)
+    for b in idle:
+        table[b] = PARK + b
+    pos = jnp.asarray(pos, jnp.int32)
+    write_page = jnp.take_along_axis(
+        jnp.asarray(table), jnp.minimum(pos // PT, MAX_PAGES - 1)[:, None],
+        axis=1)[:, 0]
+    return pools, fresh, write_page, pos % PT
+
+
+@pytest.mark.parametrize("kind", ["bf16", "int8"])
+@pytest.mark.parametrize("case", list(WRITE_CASES))
+def test_paged_kv_write_bit_equals_dense_and_touches_one_lane(kind, case):
+    """The page-write kernel (interpret mode) leaves every pool bit-equal
+    to the dense ``.at[].set`` write, and differs from the pool it was
+    given in nothing but lane ``off[b]`` of page ``(layer,
+    write_page[b])``, which holds slot b's fresh vector."""
+    pools, fresh, write_page, off = _write_case(kind, case)
+    layer = 1
+    ref = paged_kv_write_dense(pools, fresh, jnp.int32(layer), write_page,
+                               off)
+    got = jax.jit(paged_kv_write)(pools, fresh, jnp.int32(layer),
+                                  write_page, off)
+    assert len(got) == len(pools)
+    for name, before, r, g, f in zip("k v ks vs".split(), pools, ref, got,
+                                     fresh):
+        assert g.dtype == before.dtype and g.shape == before.shape
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(r, np.float32),
+                                      err_msg=name)
+        np.testing.assert_array_equal(                  # a -0.0 stays one
+            np.signbit(np.asarray(g, np.float32)),
+            np.signbit(np.asarray(r, np.float32)), err_msg=name)
+        want = np.array(before, np.float32)        # a copy: writable
+        for b in range(B):
+            want[layer, int(write_page[b]), :, :, int(off[b])] = \
+                np.asarray(f[b, 0], np.float32)
+        np.testing.assert_array_equal(np.asarray(g, np.float32), want,
+                                      err_msg=name + ": another page or "
+                                      "lane changed")
+    if case == "idle-slot-parked":
+        assert int(write_page[1]) == PARK + 1
+    if case == "last-table-column":
+        assert int(write_page[0]) == MAX_PAGES - 1
+
+
+@pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8kv"])
+def test_paged_step_same_with_either_write(kv_int8, monkeypatch):
+    """One whole paged_decode_step at decode_flash=True (the kernel
+    write and the whole-pool kernel attend, interpret mode) against the
+    same step with the dense write swapped in: logits and every pool
+    bit-equal, slots at different positions, one of them idle."""
+    import dataclasses
+    cfg, params = _pool_cfg()
+    cfg = dataclasses.replace(cfg, decode_flash=True)
+    pkv = kvpage.PagedKV(cfg, tfm, n_slots=3, max_len=32, page_tokens=8,
+                         n_pages=9, kv_int8=kv_int8)
+    rng = np.random.default_rng(2)
+    pkv.pool = {k: jnp.asarray(rng.integers(-3, 4, v.shape), v.dtype)
+                for k, v in pkv.pool.items()}
+    pkv.seat(0, [], pkv.alloc_evicting(2), new_pos=8)      # off 0
+    pkv.seat(2, [], pkv.alloc_evicting(3), new_pos=23)     # off last
+    tok = jnp.asarray([4, 0, 9], jnp.int32)
+
+    def step():
+        return jax.jit(lambda p, s, t: kvpage.paged_decode_step(
+            p, cfg, s, t, 8))(params, pkv.device_state(), tok)
+
+    assert select_paged_kv_write(True, 8) is paged_kv_write
+    logits, out = step()
+    monkeypatch.setattr(flash_decode, "select_paged_kv_write",
+                        lambda *_: paged_kv_write_dense)
+    ref_logits, ref = step()
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(ref_logits))
+    assert sorted(out) == sorted(ref)
+    for key in out:
+        np.testing.assert_array_equal(np.asarray(out[key], np.float32),
+                                      np.asarray(ref[key], np.float32),
+                                      err_msg=key)
+
+
+@pytest.mark.parametrize("on_tpu,page_tokens,decode_flash,kernel", [
+    (False, 128, None, False),        # off the chip: the dense pair
+    (True, 128, None, True),          # the cells' geometry on the chip
+    (True, 96, None, False),          # a page Mosaic cannot tile
+    (True, 256, None, True),
+    (False, 32, True, True),          # explicit: interpret mode off-TPU
+    (True, 128, False, False),
+])
+def test_select_paged_kv_write_follows_the_attend(on_tpu, page_tokens,
+                                                  decode_flash, kernel,
+                                                  monkeypatch):
+    """The write is chosen as the attend is: auto takes the Pallas pair
+    only on a TPU at a page of whole lane tiles; True / False force it."""
+    monkeypatch.setattr(backend, "on_tpu", lambda: on_tpu)
+    write = select_paged_kv_write(decode_flash, page_tokens)
+    assert write is (paged_kv_write if kernel else paged_kv_write_dense)
+    if decode_flash is None:
+        assert flash_decode._paged_kernels_fit(page_tokens) is kernel
 
 
 # --------------------------------------------------------------------------
@@ -460,6 +637,7 @@ def test_serve_paged_phases_cover_the_call_and_count_the_decode_work():
         np.testing.assert_array_equal(np.asarray(f), np.asarray(p),
                                       err_msg=f"request {i}")
     m = paged.metrics
+    assert m.paged_kv_write == "paged_kv_write_dense"    # off the chip
     assert set(m.phase_s) == set(m.phase_n) == set(PHASES)
     assert all(v >= 0 for v in m.phase_s.values())
     assert abs(sum(m.phase_s.values()) - m.call_s) <= 0.02 * m.call_s

@@ -17,12 +17,14 @@ compile or interpret; the fixture patches that one name.
 
 import dataclasses
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 import jax
 import jax.numpy as jnp
 import pytest
+from jax import lax
 from jax.experimental import topologies
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
@@ -179,3 +181,146 @@ def test_explicit_kernel_raises_on_untileable_length(attend, args, v5e):
     own guard and never gets here)."""
     with pytest.raises(ValueError, match="128"):
         jax.jit(attend).lower(*_place(args, v5e))
+
+
+# --------------------------------------------------------------------------
+# The paged decode step at the benchmark's serving geometry (GPT-2 XL:
+# 25 heads x 64, 32 slots, page 128, 8 table columns), depth, pages and
+# vocabulary cut: the page-write kernel alone, then a whole chunk of
+# steps, which must hold both Mosaic calls and move no pool.
+
+XL_B, XL_H, XL_L, XL_PAGES = 32, 25, 2, 16 + 32        # + parking pages
+
+
+def _xl_pools(kind, n_layers=XL_L):
+    pool = (n_layers, XL_PAGES, XL_H, D, PAGE)
+    if kind == "int8":
+        scales = _s(pool[:3] + (1, PAGE), jnp.float32)
+        return (_s(pool, jnp.int8),) * 2 + (scales,) * 2
+    return (_s(pool, jnp.bfloat16),) * 2
+
+
+def _compiled_write(write, kind, v5e):
+    """(compiled text, pool shape) of a page write on donated pools:
+    (pools, fresh [B, 1, H, *], layer, write_page [B], off [B])."""
+    pools = _xl_pools(kind)
+    fresh = tuple(_s((XL_B, 1) + p.shape[2:4], p.dtype) for p in pools)
+    args = [pools, fresh, _s((), jnp.int32), _s((XL_B,), jnp.int32),
+            _s((XL_B,), jnp.int32)]
+    text = jax.jit(write, donate_argnums=(0,)).lower(
+        *_place(args, v5e)).compile().as_text()
+    return text, pools[0].shape
+
+
+@pytest.mark.parametrize("kind", ["bf16", "int8"])
+def test_page_write_kernel_compiles_for_v5e(kind, v5e):
+    """flash_decode.paged_kv_write alone: the 5-D page blocks addressed
+    from prefetched scalars, the fresh blocks with slots on lanes, the
+    f32 scale pages, and the pools aliased to the results."""
+    text, pool = _compiled_write(flash_decode.paged_kv_write, kind, v5e)
+    assert "%paged_kv_write" in text and "tpu_custom_call" in text
+    assert not _pool_movers(text, pool)
+
+
+def _pool_movers(text, pool_shape):
+    """Instructions of a compiled program that mention an array the
+    size of one layer's pool or of the whole pool (K/V or scale pages)
+    and are anything but plumbing or a Mosaic call: the copies, slices,
+    updates and fusions that would move a pool."""
+    L, P, H, _, T = pool_shape
+    sized = re.compile(rf"\[(?:{L},)?{P},{H},(?:{D}|1),{T}\]")
+    plumbing = {"parameter", "get-tuple-element", "tuple", "while",
+                "bitcast", "custom-call"}
+    found = []
+    for line in text.splitlines():
+        op = re.search(r"\s([a-z][a-z0-9-]*)\(", line)
+        if (" = " in line and sized.search(line) and op
+                and op.group(1) not in plumbing):
+            found.append(line.strip()[:160])
+    return found
+
+
+def test_pool_movers_guard_sees_a_moved_pool(v5e):
+    """The guard itself: the dense write on the same pools compiles to
+    the slice / scatter / update of a layer that it must report."""
+    text, pool = _compiled_write(flash_decode.paged_kv_write_dense, "bf16",
+                                 v5e)
+    assert _pool_movers(text, pool)
+
+
+def _xl_chunk(kind):
+    """(paged_decode_chunk, args): make_paged_step_fn's scan of
+    paged_decode_step at the cell's geometry and default config."""
+    cfg = tfm.TransformerConfig(vocab=512, d_model=XL_H * D, n_heads=XL_H,
+                                n_layers=1, d_ff=4 * XL_H * D,
+                                max_seq=MAX_LEN)
+    params = jax.tree.map(
+        lambda a: _s(a.shape, a.dtype),
+        tfm.cast_params(tfm.init_params(jax.random.key(0), cfg)))
+    params["layers"] = jax.tree.map(
+        lambda a: _s((XL_L,) + a.shape[1:], a.dtype), params["layers"])
+    pools = _xl_pools(kind)
+    state = dict(zip(("k", "v", "ks", "vs"), pools),
+                 table=_s((XL_B, MAX_LEN // PAGE), jnp.int32),
+                 pos=_s((XL_B,), jnp.int32))
+
+    def paged_decode_chunk(params, state, tok):
+        def one(carry, _):
+            state, tok = carry
+            logits, state = kvpage.paged_decode_step(params, cfg, state,
+                                                     tok, PAGE)
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return (state, nxt), nxt
+        return lax.scan(one, (state, tok), None, length=2)
+
+    return paged_decode_chunk, [params, state, _s((XL_B,), jnp.int32)]
+
+
+def test_page_write_kernel_bytes_do_not_depend_on_the_caller(v5e):
+    """The write kernel as it is serialized into the step program (its
+    ``backend_config``, which the persistent compilation cache hashes)
+    is the same whoever asks for the program. Mosaic bodies carry ten
+    frames of the Python traceback they were traced under; traced
+    afresh per caller, the write made the benchmark's warm-up burst and
+    its window two cache entries for one program, and a compile inside
+    the window (PERF.md, PR 25). The attend is not held to this here:
+    in the serve loop its ten frames end inside the package, in this
+    shallow test they would not."""
+    def lowered():
+        fn, args = _xl_chunk("bf16")      # a new function, as a serve call's
+        return jax.jit(fn, donate_argnums=(1,)).lower(
+            *_place(args, v5e)).compiler_ir("stablehlo")
+
+    def one_caller():
+        return lowered()
+
+    def another_caller():
+        return (lowered(),)[0]
+
+    def write_config(module):
+        """The write's custom call, locations of the outer program off."""
+        return [re.search(r'backend_config = "(.*?)"[,}]', line).group(1)
+                for line in module.operation.get_asm().splitlines()
+                if 'kernel_name = "paged_kv_write"' in line]
+
+    a, b = write_config(one_caller()), write_config(another_caller())
+    assert len(a) == 1 and a == b
+
+
+@pytest.mark.parametrize("kind", ["bf16", "int8"])
+def test_paged_decode_chunk_moves_no_pool(kind, v5e):
+    """A chunk of paged_decode_step (make_paged_step_fn's scan, state
+    donated) at the cell's geometry and default config: both Mosaic
+    calls are in the program, once each, and no instruction copies,
+    slices or updates an array the size of a layer's pool or of the
+    pool. The one-token write once did exactly that: 93% of a decode
+    step on the chip (PERF.md, PR 25), invisible off it."""
+    paged_decode_chunk, args = _xl_chunk(kind)
+    pools = _xl_pools(kind)
+    text = jax.jit(paged_decode_chunk, donate_argnums=(1,)).lower(
+        *_place(args, v5e)).compile().as_text()
+    calls = re.findall(r"%([a-z_]+)[.0-9]* = .*custom_call_target="
+                       r"\"tpu_custom_call\"", text)
+    assert sorted(calls) == ["paged_flash_decode_attend", "paged_kv_write"]
+    assert not _pool_movers(text, pools[0].shape), \
+        "\n".join(_pool_movers(text, pools[0].shape))
