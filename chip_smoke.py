@@ -13,7 +13,8 @@ facts (how long compiles and runs took here), never a rate.
 One chip:
   serve_paged  serving.serve_paged_greedy at its defaults (128-token
                pages, radix prefix cache on), bf16 pool and int8 pool,
-               against the dense reference configuration on the same chip
+               twice (the second call traces no program), against the
+               dense reference configuration on the same chip
   serve_fixed  serving.serve_greedy at max_len 1024 (auto -> the Pallas
                decode kernel), and disagg.serve_disagg_greedy in loopback
                (native runtime, per-layer Pready/Parrived, int8 wire)
@@ -332,6 +333,15 @@ def phase_serve_paged(size: Size = FULL, seed: int = 0,
         tokens = _check_outputs(outs, prompts, n_new, outs.metrics)
         _require(outs.metrics.prefix_hits >= 2,
                  f"prefix_hits={outs.metrics.prefix_hits}")
+        # The same call again: the process has its programs, so nothing
+        # is traced, and the tokens are the first call's.
+        again = serving.serve_paged_greedy(params, cfg, prompts, n_new,
+                                           **kw)
+        traced = [outs.metrics.programs_traced,
+                  again.metrics.programs_traced]
+        _require(traced[0] > 0 and traced[1] == 0
+                 and _mismatch_share(again, outs, prompts) == 0,
+                 f"second serve call: programs_traced={traced}")
         ref = serving.serve_paged_greedy(params, _reference(cfg), prompts,
                                          n_new, **kw)
         _check_outputs(ref, prompts, n_new, ref.metrics)
@@ -342,6 +352,7 @@ def phase_serve_paged(size: Size = FULL, seed: int = 0,
              prefix_hits=outs.metrics.prefix_hits,
              pages_hwm=outs.metrics.pages_hwm,
              kv_write_path=outs.metrics.paged_kv_write,
+             programs_traced=traced,
              token_mismatch_vs_dense=_mismatch_share(outs, ref, prompts),
              **row)
     return True

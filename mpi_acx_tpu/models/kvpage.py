@@ -360,26 +360,63 @@ def paged_decode_step(params, cfg, state, token, page_tokens: int,
     return logits, out
 
 
+# The paged serve path's programs (the chunk below, PagedKV's _scatter /
+# _gather / _copy, serving.paged_prefill / paged_suffix_prefill) are
+# module-level jitted functions, so that jax.jit's own cache finds the
+# traced, loaded program in every later call of the process: made per
+# call or per PagedKV, each was traced and loaded anew at its first use
+# in every call (PERF.md, PR 28). What a trace reads from the process
+# and not from its arguments, ``backend.on_tpu()`` (the ops' compile or
+# interpret, the auto policies), the caller reads once a call and passes
+# as the static ``on_tpu``: two calls that would trace different
+# programs never share one.
+
+_programs_traced = 0
+
+
+def programs_traced() -> int:
+    """How many of the paged serve path's programs this process has
+    TRACED so far; the difference over a serve call is its
+    ``ServingMetrics.programs_traced``."""
+    return _programs_traced
+
+
+def note_trace() -> None:
+    """First line of each of those programs' Python bodies, which run
+    only under a trace."""
+    global _programs_traced
+    _programs_traced += 1
+
+
+@partial(jax.jit, static_argnames=("cfg", "chunk", "page_tokens", "on_tpu"),
+         donate_argnames="state")
+def paged_decode_chunk(params, state, tok, keys, *, cfg, chunk,
+                       page_tokens, on_tpu):
+    note_trace()
+
+    def one(carry, _):
+        state, tok, keys = carry
+        logits, state = paged_decode_step(params, cfg, state, tok,
+                                          page_tokens)
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return (state, nxt, keys), nxt
+    (state, _, keys), toks = lax.scan(one, (state, tok, keys), None,
+                                      length=chunk)
+    return state, toks, keys
+
+
 def make_paged_step_fn(params, cfg, family, chunk: int,
                        page_tokens: int):
-    """Jitted chunked decode step over the paged state (the paged
-    sibling of make_server_fns' step_fn — greedy only; the state is
-    donated so XLA updates the pool in place)."""
-    from mpi_acx_tpu.backend import jit_bound
+    """The chunked decode step over the paged state (the paged sibling
+    of make_server_fns' step_fn — greedy only): ``(state, tok, keys) ->
+    (state, toks [chunk, B], keys)``, the state donated so XLA updates
+    the pool in place. It only binds ``params`` (an argument, never a
+    constant) and the static key to the process's one
+    :func:`paged_decode_chunk`; nothing is traced here."""
+    from mpi_acx_tpu import backend
     _check_family(family)
-
-    def paged_decode_chunk(params, state, tok, keys):   # the trace's name
-        def one(carry, _):
-            state, tok, keys = carry
-            logits, state = paged_decode_step(params, cfg, state, tok,
-                                              page_tokens)
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return (state, nxt, keys), nxt
-        (state, _, keys), toks = lax.scan(one, (state, tok, keys), None,
-                                          length=chunk)
-        return state, toks, keys
-
-    return jit_bound(paged_decode_chunk, params, donate_argnums=(1,))
+    return partial(paged_decode_chunk, params, cfg=cfg, chunk=chunk,
+                   page_tokens=page_tokens, on_tpu=backend.on_tpu())
 
 
 # --------------------------------------------------------------------------
@@ -477,9 +514,6 @@ class PagedKV:
         self._dev_table = None
         self.pages_hwm = 0
         self.preemptions = 0
-        self._scatter_cache: Dict = {}
-        self._gather_cache: Dict = {}
-        self._copy_fn = None
 
     # -- device state ------------------------------------------------------
 
@@ -593,19 +627,7 @@ class PagedKV:
             raise RuntimeError(
                 "copy-on-write with a dry pool (admission should have "
                 "bounded the request)")
-        if self._copy_fn is None:
-            @partial(jax.jit, donate_argnums=(0,))
-            def _copy(pool, src, dst):
-                out = {}
-                for key in pool:
-                    page_data = lax.dynamic_index_in_dim(
-                        pool[key], src, 1, keepdims=True)
-                    out[key] = lax.dynamic_update_slice(
-                        pool[key], page_data, (0, dst, 0, 0, 0))
-                return out
-            self._copy_fn = _copy
-        self.pool = self._copy_fn(self.pool, jnp.int32(page),
-                                  jnp.int32(got[0]))
+        self.pool = _copy(self.pool, jnp.int32(page), jnp.int32(got[0]))
         self.pages[b][j] = got[0]
         self.alloc.decref(page)
         self._sync_row(b)
@@ -621,52 +643,68 @@ class PagedKV:
         never attended). ``start_page`` offsets the SOURCE rows only
         (0 for a cold full-prompt scatter; unused pages cost
         nothing — only ``len(pages)`` pages are written)."""
-        pt = self.page_tokens
-        bucket = one["k"].shape[-1]
-        keys = tuple(k for k in _POOL_KEYS if k in one and k in self.pool)
-        ck = (bucket, len(pages), keys)
-        if ck not in self._scatter_cache:
-            @partial(jax.jit, donate_argnums=(0,))
-            def _scatter(pool, one, pages_arr, n_pg=len(pages),
-                         bucket=bucket, keys=keys):
-                for j in range(n_pg):
-                    n = min(pt, bucket - j * pt)
-                    if n <= 0:
-                        break
-                    for key in keys:
-                        src = one[key][:, 0, ..., j * pt:j * pt + n]
-                        pool[key] = lax.dynamic_update_slice(
-                            pool[key], src[:, None].astype(
-                                pool[key].dtype),
-                            (0, pages_arr[j], 0, 0, 0))
-                return pool
-            self._scatter_cache[ck] = _scatter
         if pages:
-            self.pool = self._scatter_cache[ck](
-                self.pool, one, jnp.asarray(pages, jnp.int32))
+            one = {k: one[k] for k in _POOL_KEYS
+                   if k in one and k in self.pool}
+            self.pool = _scatter(self.pool, one,
+                                 jnp.asarray(pages, jnp.int32))
 
     def gather_history(self, pages: List[int]):
         """Gather ``pages`` into contiguous [L, H, Dh, n*pt] history
         K/V (cache layout) in compute dtype (dequantizing int8 pages —
         the only page-resident form — through their f32 scales)."""
-        ck = len(pages)
-        if ck not in self._gather_cache:
-            @jax.jit
-            def _gather(pool, pages_arr):
-                def grab(key):
-                    return jnp.take(pool[key], pages_arr, axis=1)
-                k, v = grab("k"), grab("v")
-                if "ks" in pool:
-                    k = k.astype(jnp.float32) * grab("ks")
-                    v = v.astype(jnp.float32) * grab("vs")
-                def join(t):          # [L, n, H, Dh, pt] -> [L, H, Dh, n*pt]
-                    t = jnp.moveaxis(t, 1, 3)
-                    return t.reshape(t.shape[:3] + (-1,)).astype(
-                        self.cfg.dtype)
-                return join(k), join(v)
-            self._gather_cache[ck] = _gather
-        return self._gather_cache[ck](
-            self.pool, jnp.asarray(pages, jnp.int32))
+        return _gather(self.pool, jnp.asarray(pages, jnp.int32),
+                       dtype=self.cfg.dtype)
+
+
+# PagedKV's three programs, one compile per shape in jit's own cache:
+# what the per-instance closures captured (pages written, bucket, pool
+# keys, page size) is all in the arguments' shapes and tree.
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _copy(pool, src, dst):
+    note_trace()
+    out = {}
+    for key in pool:
+        page_data = lax.dynamic_index_in_dim(pool[key], src, 1,
+                                             keepdims=True)
+        out[key] = lax.dynamic_update_slice(pool[key], page_data,
+                                            (0, dst, 0, 0, 0))
+    return out
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _scatter(pool, one, pages_arr):
+    note_trace()
+    pt, bucket = pool["k"].shape[-1], one["k"].shape[-1]
+    for j in range(pages_arr.shape[0]):
+        n = min(pt, bucket - j * pt)
+        if n <= 0:
+            break
+        for key in one:
+            src = one[key][:, 0, ..., j * pt:j * pt + n]
+            pool[key] = lax.dynamic_update_slice(
+                pool[key], src[:, None].astype(pool[key].dtype),
+                (0, pages_arr[j], 0, 0, 0))
+    return pool
+
+
+@partial(jax.jit, static_argnames="dtype")
+def _gather(pool, pages_arr, *, dtype):
+    note_trace()
+
+    def grab(key):
+        return jnp.take(pool[key], pages_arr, axis=1)
+    k, v = grab("k"), grab("v")
+    if "ks" in pool:
+        k = k.astype(jnp.float32) * grab("ks")
+        v = v.astype(jnp.float32) * grab("vs")
+
+    def join(t):          # [L, n, H, Dh, pt] -> [L, H, Dh, n*pt]
+        t = jnp.moveaxis(t, 1, 3)
+        return t.reshape(t.shape[:3] + (-1,)).astype(dtype)
+    return join(k), join(v)
 
 
 # --------------------------------------------------------------------------

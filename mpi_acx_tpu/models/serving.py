@@ -39,7 +39,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from mpi_acx_tpu import reqlog
+from mpi_acx_tpu import backend, reqlog
+from mpi_acx_tpu.models import kvpage
 
 
 def _pct(samples: List[float], p: float) -> float:
@@ -111,6 +112,10 @@ class ServingMetrics:
     phase_n: Dict[str, int] = field(default_factory=dict)
     decode_slot_steps: int = 0    # every chunk: chunk x n_slots
     decode_tokens: int = 0        # tokens the deliver loop consumed
+    # How many of the paged path's programs were TRACED during this call
+    # (kvpage.programs_traced): 10-20 in a process's first call, 0 in
+    # every later one with the same static arguments and shapes.
+    programs_traced: int = 0
 
     @property
     def step_utilization(self) -> float:
@@ -929,6 +934,27 @@ def _slo_admit_targets(slo_admit) -> tuple:
             itl_ms / 1e3 if itl_ms > 0 else None)
 
 
+# serve_paged_greedy's two prefill programs, jitted once a process (see
+# kvpage.paged_decode_chunk, and its note on ``on_tpu``). Named, so the
+# trace prints ``PjitFunction(paged_prefill)``.
+
+
+@partial(jax.jit, static_argnames=("cfg", "family", "kv_int8", "on_tpu"))
+def paged_prefill(params, tokens, last_index, *, cfg, family, kv_int8,
+                  on_tpu):
+    kvpage.note_trace()
+    return family.prefill(params, cfg, tokens, tokens.shape[1],
+                          kv_int8=kv_int8, last_index=last_index)
+
+
+@partial(jax.jit, static_argnames=("cfg", "kv_int8", "on_tpu"))
+def paged_suffix_prefill(params, suffix, hk, hv, last_index, *, cfg,
+                         kv_int8, on_tpu):
+    kvpage.note_trace()
+    return kvpage.prefill_with_history(params, cfg, suffix, hk, hv,
+                                       last_index, kv_int8=kv_int8)
+
+
 def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
                        n_slots: int, max_len: int, family=None,
                        eos: Optional[int] = None, chunk: int = 1,
@@ -1005,11 +1031,14 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
     self-time counter in ``metrics.phase_s`` / ``phase_n``; the self
     times sum to ``call_s``. Only two WAIT on the device::
 
-        serve.setup     entry -> first refill: admission, PagedKV, jit
-                        wrappers, make_paged_step_fn (allocation only)
+        serve.setup     entry -> first refill: admission, PagedKV (the
+                        pool's allocation), the weights bound to the
+                        process's programs (nothing is traced here)
         refill.match    SLO gate, prefix.match, alloc_evicting
         refill.prefill  pad, gather_history on a hit, the prefill's
-                        dispatch, int(argmax): WAITS for the device
+                        dispatch, int(argmax): WAITS for the device;
+                        the process's first use of a shape traces and
+                        loads its program here (programs_traced)
         refill.scatter  scatter_prompt's DISPATCH; its device time
                         lands in whichever span syncs next (the next
                         refill.prefill or chunk.step)
@@ -1029,13 +1058,13 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
     slot-steps delivered one; per request, ``queue_wait_s`` (entry ->
     start of the refill that seated it), ``prefill_s`` and
     ``refill_host_s`` are that refill's spans."""
-    from mpi_acx_tpu.models import kvpage
     from mpi_acx_tpu.ops.flash_decode import select_paged_kv_write
     from mpi_acx_tpu.profiling import Phases
 
     ph = Phases()
     setup = ph("serve.setup")
     setup.__enter__()               # closed before the first refill
+    traced_at_entry = kvpage.programs_traced()
     if family is None:
         from mpi_acx_tpu.models import transformer as family  # noqa: N813
     assert prompts, "no requests"
@@ -1068,21 +1097,15 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
     pkv = kvpage.PagedKV(cfg, family, n_slots, max_len, pt, n_pages,
                          kv_int8=kv_int8, prefix_cache=prefix_cache)
 
-    from mpi_acx_tpu.backend import jit_bound
-
-    # One compile per (bucket) / (suffix bucket, history length): jit's
-    # own shape cache. The weights are arguments (backend.jit_bound).
-    # Named, so the trace prints ``PjitFunction(paged_prefill)``.
-    def paged_prefill(p, t, li):
-        return family.prefill(p, cfg, t, t.shape[1], kv_int8=kv_int8,
-                              last_index=li)
-
-    def paged_suffix_prefill(p, s, k, v, li):
-        return kvpage.prefill_with_history(p, cfg, s, k, v, li,
-                                           kv_int8=kv_int8)
-
-    prefill_fn = jit_bound(paged_prefill, params)
-    suffix_prefill_fn = jit_bound(paged_suffix_prefill, params)
+    # One compile per (bucket) / (suffix bucket, history length) a
+    # PROCESS: the module-level programs, in jit's own cache. The
+    # weights are arguments; what a trace reads from the process is in
+    # the static key.
+    on_tpu = backend.on_tpu()
+    prefill_fn = partial(paged_prefill, params, cfg=cfg, family=family,
+                         kv_int8=kv_int8, on_tpu=on_tpu)
+    suffix_prefill_fn = partial(paged_suffix_prefill, params, cfg=cfg,
+                                kv_int8=kv_int8, on_tpu=on_tpu)
 
     step_fn = kvpage.make_paged_step_fn(params, cfg, family, chunk, pt)
 
@@ -1504,7 +1527,8 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
                                  if occ_samples else 1.0),
             per_request=per_request,
             decode_slot_steps=n_slot_steps,
-            decode_tokens=n_decode_tokens)
+            decode_tokens=n_decode_tokens,
+            programs_traced=kvpage.programs_traced() - traced_at_entry)
     # Filled in once the last span has closed: its end is the call's.
     metrics.call_s = tail.t1 - setup.t0
     metrics.phase_s, metrics.phase_n = ph.seconds, ph.count

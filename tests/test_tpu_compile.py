@@ -248,12 +248,15 @@ def test_pool_movers_guard_sees_a_moved_pool(v5e):
     assert _pool_movers(text, pool)
 
 
+_XL_CFG = tfm.TransformerConfig(vocab=512, d_model=XL_H * D, n_heads=XL_H,
+                                n_layers=1, d_ff=4 * XL_H * D,
+                                max_seq=MAX_LEN)
+
+
 def _xl_chunk(kind):
     """(paged_decode_chunk, args): make_paged_step_fn's scan of
     paged_decode_step at the cell's geometry and default config."""
-    cfg = tfm.TransformerConfig(vocab=512, d_model=XL_H * D, n_heads=XL_H,
-                                n_layers=1, d_ff=4 * XL_H * D,
-                                max_seq=MAX_LEN)
+    cfg = _XL_CFG
     params = jax.tree.map(
         lambda a: _s(a.shape, a.dtype),
         tfm.cast_params(tfm.init_params(jax.random.key(0), cfg)))
@@ -305,6 +308,50 @@ def test_page_write_kernel_bytes_do_not_depend_on_the_caller(v5e):
 
     a, b = write_config(one_caller()), write_config(another_caller())
     assert len(a) == 1 and a == b
+
+
+def test_the_process_level_chunk_program_is_one_whoever_calls(v5e):
+    """``kvpage.paged_decode_chunk`` itself, the jitted function that
+    every serve call of a process shares (PERF.md, PR 28), bound by
+    ``make_paged_step_fn`` as each serve call binds it and lowered for
+    the described v5e from two call paths. Traced under the first, it
+    is the same program to the last byte under the second, both Mosaic
+    bodies and the tracebacks inside them included (a fresh trace from
+    this shallow a caller would carry the caller's frames in the
+    attend's): a call from the benchmark's window cannot make another
+    cache entry, and a compile, than its warm-up call did."""
+    _, (params, state, tok) = _xl_chunk("bf16")
+    keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0), XL_B))
+
+    def lowered():
+        step = kvpage.make_paged_step_fn(params, _XL_CFG, tfm, 2, PAGE)
+        assert step.func is kvpage.paged_decode_chunk
+        return step.func.lower(
+            *_place([*step.args, state, tok, keys], v5e),
+            **step.keywords).compiler_ir("stablehlo")
+
+    def one_caller():
+        return lowered()
+
+    def another_caller():
+        return (lowered(),)[0]
+
+    def kernels(module):
+        """kernel name -> its custom call's serialized body."""
+        found = {}
+        for line in module.operation.get_asm().splitlines():
+            name = re.search(r'kernel_name = "(\w+)"', line)
+            if name:
+                found[name.group(1)] = re.search(
+                    r'backend_config = "(.*?)"[,}]', line).group(1)
+        return found
+
+    jax.clear_caches()
+    traced = kvpage.programs_traced()
+    first = kernels(one_caller())
+    assert sorted(first) == ["paged_flash_decode_attend", "paged_kv_write"]
+    assert kernels(another_caller()) == first
+    assert kvpage.programs_traced() == traced + 1
 
 
 @pytest.mark.parametrize("kind", ["bf16", "int8"])
