@@ -332,16 +332,13 @@ causality-check: itest tools
 
 # --- flash-decode kernel (ops/flash_decode.py, DESIGN.md §11) ---
 # Interpret-mode parity of the Pallas decode kernel vs the dense
-# reference (GQA/window/per-slot-pos/int8 grid + block-skip), then a
-# CPU dryrun of the decode bench child asserting the dense-vs-flash
-# A/B rows land. No chip required — the kernel runs interpreted.
+# reference (GQA/window/per-slot-pos/int8 grid + block-skip). No chip
+# required — the kernel runs interpreted.
 .PHONY: decode-check
 decode-check:
 	@echo "== decode-check: flash-decode interpret parity"
 	@JAX_PLATFORMS=cpu python3 -m pytest tests/test_flash_decode.py -q \
 	  -p no:cacheprovider || exit 1
-	@echo "== decode-check: bench.py --dryrun-decode (A/B rows emitted)"
-	@JAX_PLATFORMS=cpu python3 bench.py --dryrun-decode || exit 1
 	@echo "DECODE CHECK PASSED"
 
 # --- multi-path striped transport end-to-end (DESIGN.md §15) ---
@@ -379,11 +376,10 @@ stripe-check: itest tools
 # Loopback parity suite (the full wire handoff bit-equal to the
 # monolithic server, mid-handoff failure requeue), a 3-rank role-split
 # fleet (1 prefill + 2 decode) on the socket plane with both decode
-# ranks byte-checking against a local monolithic serve, the same fleet
-# with the prefill rank SIGKILLed mid-handoff under the chaos oracle
-# (supervisor respawns it, the torn handoff requeues UNCHARGED, the
-# re-ship satisfies it, acx_doctor attributes the dead link), and the
-# bench disagg dryrun (TTFT-split + handoff-GB/s rows land).
+# ranks byte-checking against a local monolithic serve, and the same
+# fleet with the prefill rank SIGKILLed mid-handoff under the chaos
+# oracle (supervisor respawns it, the torn handoff requeues UNCHARGED,
+# the re-ship satisfies it, acx_doctor attributes the dead link).
 .PHONY: disagg-check
 disagg-check: tools
 	@echo "== disagg-check: loopback parity + handoff-failure suite"
@@ -398,19 +394,16 @@ disagg-check: tools
 	  --timeout 240 --acxrun $(BUILD)/acxrun \
 	  --out $(BUILD)/disagg-oracle/kill --fault kill:rank=0:nth=8 \
 	  -- python3 tests/disagg_worker.py || exit 1
-	@echo "== disagg-check: bench.py --dryrun-disagg (TTFT split rows)"
-	@JAX_PLATFORMS=cpu python3 bench.py --dryrun-disagg || exit 1
 	@echo "DISAGG CHECK PASSED"
 
 # --- paged KV cache + radix prefix sharing + page-pressure scheduling
-# (DESIGN.md §19). Four legs: the pytest suite (kernel bit-parity grid,
+# (DESIGN.md §19). Three legs: the pytest suite (kernel bit-parity grid,
 # allocator/trie/COW units, serve_paged_greedy vs serve_greedy
 # bit-equality incl. preempt-then-resume, prefix reuse), a CPU interpret
-# smoke of the paged Pallas kernel proper, the 3-rank fleet with decode
-# ranks seating SHIPPED pages (byte-checked against a local monolithic
-# serve) plus the same fleet with the prefill rank SIGKILLed under the
-# chaos oracle, and the bench paged dryrun (HBM-scaling + prefix-TTFT +
-# fixed-budget-concurrency rows land in the newest BENCH_r*.json).
+# smoke of the paged Pallas kernel proper, and the 3-rank fleet with
+# decode ranks seating SHIPPED pages (byte-checked against a local
+# monolithic serve) plus the same fleet with the prefill rank SIGKILLed
+# under the chaos oracle.
 .PHONY: paged-check
 paged-check: tools
 	@echo "== paged-check: paged KV parity + scheduler suite"
@@ -429,8 +422,6 @@ paged-check: tools
 	  --timeout 240 --acxrun $(BUILD)/acxrun \
 	  --out $(BUILD)/paged-oracle/kill --fault kill:rank=0:nth=8 \
 	  -- python3 tests/paged_worker.py || exit 1
-	@echo "== paged-check: bench.py --dryrun-paged (§19 rows land)"
-	@JAX_PLATFORMS=cpu python3 bench.py --dryrun-paged || exit 1
 	@echo "PAGED CHECK PASSED"
 
 # --- request-journey tracing + SLO burn-rate plane (DESIGN.md §20) ---

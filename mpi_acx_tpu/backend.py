@@ -11,8 +11,7 @@ Facts every entry point and every kernel needs, each defined once:
   from outside by ``JAX_COMPILATION_CACHE_DIR`` or else at one fixed
   directory inside the checkout. The directory is part of the cache key:
   it never comes from a temporary name, a pid or the time, so every
-  process of one run (chip_smoke.py's children, bench.py's children, the
-  examples) finds what an earlier one compiled.
+  process of one run (chip_smoke.py's children, the examples) finds what an earlier one compiled.
 * :func:`jit_bound` — how a closure hands the weights to a compiled
   program: as arguments, never as constants baked into it.
 """

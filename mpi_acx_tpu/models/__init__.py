@@ -2,8 +2,7 @@
 
 The reference ships no models (SURVEY.md §0: "it is not a training
 framework") — but its driver-defined target configs are model workloads
-(BASELINE.json configs[3,4]: GPT-2 125M and Llama-style pipeline
-exchanges). These are those workloads, TPU-native: MXU-shaped matmuls in
+(GPT-2 125M and Llama-style pipeline exchanges). These are those workloads, TPU-native: MXU-shaped matmuls in
 bfloat16, static shapes, and parallelism expressed through the
 mpi_acx_tpu.parallel primitives.
 """

@@ -19,7 +19,10 @@ variant and a disagg serve is bit-equal to the monolithic
 (``prefill_kv_int8``): quantize-at-compute and quantize-at-wire
 produce identical bytes because prefill attention runs on the exact
 bf16 K/V either way and ops/kvquant.py is deterministic. Pinned by
-tests/test_disagg.py.
+tests/test_disagg.py. The loopback mode does not copy that server: it
+calls ``_serve``, the one fixed-slot loop, and hands it the wire handoff
+as its prefill; the decode worker keeps its intake-driven loop and
+shares the RequestBook.
 
 Handoff protocol, per request (descriptor + one partitioned round):
 
@@ -53,14 +56,13 @@ Telemetry: every handoff records the TTFT split — prefill-compute vs
 ship (publish -> last arrival) vs decode-pickup (unpack + scatter) —
 as ``HandoffTelemetry`` rows on ``DisaggMetrics.handoffs``;
 ``overlap=False`` (ship only after the full prompt pass) is the
-baseline the bench compares against (bench.py disagg rows).
+baseline an overlap A/B compares against.
 """
 
 from __future__ import annotations
 
 import os
 import time
-from collections import deque
 from dataclasses import dataclass, field
 from typing import Callable, List, Optional, Sequence
 
@@ -71,10 +73,10 @@ from jax import lax
 
 from mpi_acx_tpu import reqlog
 from mpi_acx_tpu.models.serving import (
-    RollingSLO, RequestTelemetry, ServedBatch, ServingMetrics, _bucket,
-    _flight_dump_best_effort, _pct, _peer_dead,
-    _span_app_begin_best_effort, _span_app_end_best_effort,
-    _tseries_annotate_best_effort, make_server_fns)
+    RequestBook, ServedBatch, ServingMetrics, _padded,
+    _flight_dump_best_effort, _pct, _peer_dead, _per_request_n_new,
+    _serve, _span_app_begin_best_effort, _span_app_end_best_effort,
+    make_server_fns)
 from mpi_acx_tpu.parallel.kv_ship import (
     DESC_FIN_TAG, DESC_HDR_TAG, KvReceiver, KvShipper)
 
@@ -235,8 +237,8 @@ def _prefill_ship(ch, pfns, cfg, padded, last_index, overlap,
     Returns (first_token, prefill_s, expose_s) — ``expose_s`` is the
     publish time left EXPOSED after the head finished: ~0 with per-layer
     overlap (everything already shipped under compute), the full
-    serialized pack+publish cost without it. The bench's overlap A/B
-    reads this off the FIN descriptor."""
+    serialized pack+publish cost without it. It rides the FIN
+    descriptor."""
     embed_fn, layer_fn, head_fn, quant_fn = pfns
     t0 = time.perf_counter()
     x = embed_fn(padded)
@@ -358,8 +360,9 @@ def serve_disagg_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
     """Disaggregated greedy serve. With $ACX_ROLE unset: loopback mode
     — this process plays both roles against a self-channel, so the
     full wire path (descriptors, partitioned round, per-layer Pready /
-    Parrived, splice) runs single-process; outputs are bit-equal to
-    the monolithic ``serve_greedy(..., kv_int8=True)``. With $ACX_ROLE
+    Parrived, splice) runs single-process, as the prefill handed to
+    the monolithic server's own loop (``serving._serve``); outputs are
+    bit-equal to ``serve_greedy(..., kv_int8=True)``. With $ACX_ROLE
     set (under acxrun): dispatches to this rank's role worker —
     prefill ranks return an empty batch, decode ranks return their
     requests' outputs (None rows elsewhere).
@@ -368,7 +371,7 @@ def serve_disagg_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
     tuple — decode slots are always int8, the wire form.
     ``prefill_kv_int8`` picks the prefill-side variant (see
     ``_prefill_ship``); ``overlap=False`` ships only after the full
-    prompt pass (the bench baseline). ``ship_fault(rid, layer)`` is a
+    prompt pass (the A/B baseline). ``ship_fault(rid, layer)`` is a
     failure-injection hook (see ``_prefill_ship``)."""
     roles = None
     if os.environ.get("ACX_ROLE", "").strip():
@@ -397,92 +400,37 @@ def _serve_disagg_loopback(params, cfg, prompts, n_new, n_slots, max_len,
                            family, eos, chunk, server_fns,
                            prefill_kv_int8, max_request_retries, rt,
                            overlap, ship_fault, poll_timeout_s):
-    """Single-process disagg scheduler: models/serving.py's ``_serve``
-    with the refill path replaced by a real wire handoff (descriptor
-    exchange + partitioned round against the loopback transport). The
-    decode loop is byte-for-byte the monolithic one — that, plus the
+    """Single-process disagg serve: models/serving.py's ``_serve`` — the
+    one fixed-slot loop — with a real wire handoff (descriptor exchange
+    + partitioned round against the loopback transport) as the prefill
+    it is given. The decode loop IS the monolithic one; that, plus the
     wire carrying the exact int8 codes the monolithic fill would have
-    produced, is the bit-equality argument."""
-    if family is None:
-        from mpi_acx_tpu.models import transformer as family  # noqa: N813
-    assert prompts, "no requests"
-    assert all(len(p) > 0 for p in prompts), "zero-length prompt"
-    n_new = ([int(n_new)] * len(prompts) if np.ndim(n_new) == 0
-             else [int(n) for n in n_new])
-    assert len(n_new) == len(prompts), (len(n_new), len(prompts))
-    assert all(n >= 1 for n in n_new), "n_new >= 1 per request"
+    produced, is the bit-equality argument.
+
+    What a caller can tell from the monolithic server, kept: an
+    oversized request is an AssertionError here, not a RequestRejected
+    row; and a peer-loss shaped step failure sheds no slot (one process
+    has no peer whose loss shrinks it)."""
+    n_new = _per_request_n_new(prompts, n_new)
     assert all(len(p) + n + chunk <= max_len
                for p, n in zip(prompts, n_new)), "request exceeds max_len"
     assert all(len(p) + n + chunk <= cfg.max_seq
                for p, n in zip(prompts, n_new)), "request exceeds max_seq"
 
-    if server_fns is None:
-        server_fns = make_server_fns(params, cfg, family, chunk=chunk,
-                                     kv_int8=True)
-    (_, step_fn, scatter_fn, fns_chunk, fns_int8, fns_sample) = server_fns
-    assert fns_chunk == chunk, (fns_chunk, chunk)
-    assert fns_int8, "disagg decode slots are int8 (the wire form)"
-    assert fns_sample is None, "disagg serving is greedy-only for now"
-
     pfns = make_layerwise_prefill_fns(params, cfg, family)
     shipper = KvShipper(rt, cfg.n_layers, cfg.n_heads, cfg.head_dim)
     receiver = KvReceiver(rt, cfg.n_layers, cfg.n_heads, cfg.head_dim)
+    handoffs = {}                # rid -> the handoff that seated it
 
-    slots = family.init_kv_cache(cfg, n_slots, max_len, kv_int8=True)
-    slots["pos"] = jnp.zeros((n_slots,), jnp.int32)
-    queue = deque(enumerate(np.asarray(p, np.int32) for p in prompts))
-    for depth, (rid, p) in enumerate(queue):
-        reqlog.emit("admit", rid, prompt_len=len(p), n_new=n_new[rid])
-        reqlog.emit("queue", rid, depth=depth)
-    owner = [-1] * n_slots
-    emitted: List[List[int]] = [[] for _ in prompts]
-    done: List[Optional[np.ndarray]] = [None] * len(prompts)
-    last_tok = np.zeros((n_slots,), np.int32)
-    keys = jax.random.split(jax.random.key(0), n_slots)  # greedy dummies
-    attempts = [0] * len(prompts)
-
-    t0 = time.perf_counter()
-    ttft = [None] * len(prompts)
-    finish = [None] * len(prompts)
-    slo = RollingSLO()
-    itl_samples: List[float] = []
-    qd_samples: List[int] = []
-    occ_samples: List[float] = []
-    handoffs: List[HandoffTelemetry] = []
-    n_steps = n_prefills = n_requeues = n_peer_requeues = 0
-    n_hang_dumps = 0
-
-    def _requeue(rid, prompt, exc, charge=True):
-        nonlocal n_requeues, n_peer_requeues
-        if charge:
-            attempts[rid] += 1
-            if attempts[rid] > max_request_retries:
-                raise RuntimeError(
-                    f"request {rid} failed {attempts[rid]} time(s), past "
-                    f"max_request_retries={max_request_retries}") from exc
-        else:
-            n_peer_requeues += 1
-        emitted[rid] = []
-        ttft[rid] = None
-        n_requeues += 1
-        reqlog.emit("requeue", rid, charged=bool(charge))
-        queue.append((rid, prompt))
-
-    def refill(b):
-        """Handoff-refill: prefill-side layer loop publishes into the
-        loopback self-channel, decode side splices and scatters —
-        the wire path the role-split fleet runs, serialized in one
-        process."""
-        nonlocal slots, n_prefills
-        rid, prompt = queue.popleft()
-        S = len(prompt)
-        bucket = min(_bucket(S), max_len, cfg.max_seq)
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :S] = prompt
+    def handoff(_prefill_fn, rid, padded, S):
+        """The prefill-side layer loop publishes into the loopback
+        self-channel, the decode side splices: the wire path the
+        role-split fleet runs, serialized in one process. Returns the
+        first token off the FIN descriptor and the spliced cache, on
+        the device."""
+        bucket = padded.shape[1]
         send_ch = shipper.channel(rt.rank, bucket)
         recv_ch = receiver.channel(rt.rank, bucket)
-        spanned = _span_app_begin_best_effort(rid)
-        reqlog.emit("prefill_start", rid, prompt_len=S, bucket=bucket)
         try:
             # Descriptor header: recv posted first, both waited — the
             # exchange is atomic, so a later handoff failure can never
@@ -515,144 +463,29 @@ def _serve_disagg_loopback(params, cfg, prompts, n_new, n_slots, max_len,
                                cfg.head_dim, timeout_s=poll_timeout_s)
             send_ch.finish()
             recv_ch.finish()
-            ship_s = time.perf_counter() - t_ship
             t_pick = time.perf_counter()
             one = {k: jnp.asarray(v) for k, v in one.items()}
-            slots = scatter_fn(slots, one, b, S)
-            pickup_s = time.perf_counter() - t_pick
-        except Exception as exc:  # noqa: BLE001 — any handoff failure
+        except Exception:  # noqa: BLE001 — the loop requeues the request
             _abort_rounds(send_ch, recv_ch)
-            _requeue(rid, prompt, exc, charge=not _peer_dead(exc))
-            return False
-        finally:
-            if spanned:
-                _span_app_end_best_effort()
-        owner[b] = rid
-        reqlog.emit("seat", rid, slot=b, pos=S)
-        emitted[rid].append(int(fin[2]))
-        last_tok[b] = int(fin[2])
-        n_prefills += 1
-        ttft[rid] = time.perf_counter() - t0
-        slo.note_ttft(ttft[rid])
-        reqlog.emit("stream", rid, n=1, ttft_s=ttft[rid])
-        handoffs.append(HandoffTelemetry(
+            raise
+        handoffs.pop(rid, None)
+        handoffs[rid] = HandoffTelemetry(
             rid=rid, layers=cfg.n_layers,
             wire_bytes=cfg.n_layers * send_ch.geom.part_bytes,
-            prefill_s=prefill_s, ship_s=ship_s, pickup_s=pickup_s,
-            overlap=overlap, expose_s=expose_s))
-        return True
+            prefill_s=prefill_s, ship_s=t_pick - t_ship,
+            pickup_s=time.perf_counter() - t_pick,
+            overlap=overlap, expose_s=expose_s)
+        return int(fin[2]), one, None
 
-    def retire(b):
-        nonlocal slots
-        rid = owner[b]
-        done[rid] = np.concatenate(
-            [np.asarray(prompts[rid], np.int32),
-             np.asarray(emitted[rid], np.int32)])
-        finish[rid] = time.perf_counter() - t0
-        reqlog.emit("finish", rid, new_tokens=len(emitted[rid]),
-                    latency_s=finish[rid])
-        owner[b] = -1
-        slots["pos"] = slots["pos"].at[b].set(0)
-
-    def slot_finished(b):
-        rid = owner[b]
-        return (len(emitted[rid]) >= n_new[rid]
-                or (eos is not None and emitted[rid]
-                    and emitted[rid][-1] == eos))
-
-    qd_samples.append(len(queue))
-    while queue and any(o == -1 for o in owner):
-        b = owner.index(-1)
-        if refill(b) and slot_finished(b):
-            retire(b)
-
-    while any(o >= 0 for o in owner) or queue:
-        qd_samples.append(len(queue))
-        occ_samples.append(sum(o >= 0 for o in owner) / n_slots)
-        slo.note_gauges(qd_samples[-1], occ_samples[-1])
-        _tseries_annotate_best_effort(slo.live_slos())
-        if not any(o >= 0 for o in owner):
-            while queue and any(o == -1 for o in owner):
-                b = owner.index(-1)
-                if refill(b) and slot_finished(b):
-                    retire(b)
-            continue
-        step_t0 = time.perf_counter()
-        try:
-            slots, toks, keys = step_fn(slots, jnp.asarray(last_tok), keys)
-        except Exception as exc:  # noqa: BLE001 — any device failure
-            lost_peer = _peer_dead(exc)
-            if _flight_dump_best_effort():
-                n_hang_dumps += 1
-            for b in range(n_slots):
-                if owner[b] >= 0:
-                    rid = owner[b]
-                    owner[b] = -1
-                    _requeue(rid, np.asarray(prompts[rid], np.int32),
-                             exc, charge=not lost_peer)
-            slots = family.init_kv_cache(cfg, n_slots, max_len,
-                                         kv_int8=True)
-            slots["pos"] = jnp.zeros((n_slots,), jnp.int32)
-            keys = jax.random.split(jax.random.key(0), n_slots)
-            last_tok = np.zeros((n_slots,), np.int32)
-            continue
-        block = np.asarray(toks, np.int32)
-        step_dt = time.perf_counter() - step_t0
-        n_steps += 1
-        reqlog.emit("decode_step", step=n_steps, dt_s=step_dt,
-                    active=sum(o >= 0 for o in owner))
-        for b in range(n_slots):
-            last_tok[b] = block[-1, b]
-            if owner[b] < 0:
-                continue
-            got = 0
-            for c in range(block.shape[0]):
-                if slot_finished(b):
-                    break
-                emitted[owner[b]].append(int(block[c, b]))
-                itl_samples.append(step_dt / chunk)
-                slo.note_itl(step_dt / chunk)
-                got += 1
-            if got:
-                reqlog.emit("stream", owner[b], n=got, itl_s=step_dt / chunk)
-        for b in range(n_slots):
-            while owner[b] >= 0 and slot_finished(b):
-                retire(b)
-                if queue:
-                    refill(b)
-
-    assert all(d is not None for d in done)
+    batch = _serve(params, cfg, prompts, n_new, n_slots, max_len, family,
+                   eos, chunk, server_fns, True, None, None, handoff,
+                   max_request_retries=max_request_retries,
+                   shed_on_peer_loss=False)
     shipper.close()
     receiver.close()
-    wall = time.perf_counter() - t0
-    per_request = []
-    total_new = 0
-    for rid in range(len(prompts)):
-        nt = len(emitted[rid])
-        total_new += nt
-        lat = finish[rid] if finish[rid] is not None else wall
-        per_request.append(RequestTelemetry(
-            rid=rid,
-            ttft_s=ttft[rid] if ttft[rid] is not None else lat,
-            latency_s=lat, new_tokens=nt,
-            tokens_per_s=nt / lat if lat > 0 else 0.0,
-            retries=attempts[rid]))
-    metrics = DisaggMetrics(
-        requests=len(prompts), wall_s=wall, new_tokens=total_new,
-        tokens_per_s=total_new / wall if wall > 0 else 0.0,
-        steps=n_steps, prefills=n_prefills, requeues=n_requeues,
-        peer_requeues=n_peer_requeues, hang_dumps=n_hang_dumps,
-        ttft_p50_s=_pct([r.ttft_s for r in per_request], 0.50),
-        ttft_p99_s=_pct([r.ttft_s for r in per_request], 0.99),
-        itl_p50_s=_pct(itl_samples, 0.50),
-        itl_p99_s=_pct(itl_samples, 0.99),
-        queue_depth_max=max(qd_samples) if qd_samples else 0,
-        queue_depth_mean=(sum(qd_samples) / len(qd_samples)
-                          if qd_samples else 0.0),
-        slot_occupancy_mean=(sum(occ_samples) / len(occ_samples)
-                             if occ_samples else 1.0),
-        per_request=per_request, handoffs=handoffs)
-    return ServedBatch(done, _finish_handoff_metrics(metrics))
+    batch.metrics = _finish_handoff_metrics(DisaggMetrics(
+        **vars(batch.metrics), handoffs=list(handoffs.values())))
+    return batch
 
 
 # -- fleet-mode role workers (under acxrun, $ACX_ROLE set) -----------------
@@ -686,9 +519,8 @@ def run_prefill_worker(rt, params, cfg, prompts, max_len, family=None,
         dst = decode_ranks[rid % len(decode_ranks)]
         prompt = np.asarray(prompts[rid], np.int32)
         S = len(prompt)
-        bucket = min(_bucket(S), max_len, cfg.max_seq)
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :S] = prompt
+        padded = _padded(prompt, max_len, cfg.max_seq)
+        bucket = padded.shape[1]
         ch = shipper.channel(dst, bucket)
         spanned = _span_app_begin_best_effort(rid)
         reqlog.emit("prefill_start", rid, prompt_len=S, bucket=bucket)
@@ -749,8 +581,7 @@ def run_decode_worker(rt, params, cfg, prompts, n_new, n_slots, max_len,
     assert len(prefill_ranks) == 1, \
         "decode worker handles a single prefill rank for now"
     src = prefill_ranks[0]
-    n_new = ([int(n_new)] * len(prompts) if np.ndim(n_new) == 0
-             else [int(n) for n in n_new])
+    n_new = _per_request_n_new(prompts, n_new)
     my_rids = [rid for rid in range(len(prompts))
                if decode_ranks[rid % len(decode_ranks)] == rt.rank]
 
@@ -775,48 +606,22 @@ def run_decode_worker(rt, params, cfg, prompts, n_new, n_slots, max_len,
         assert fns_chunk == chunk and fns_int8 and fns_sample is None
 
     receiver = KvReceiver(rt, cfg.n_layers, cfg.n_heads, cfg.head_dim)
-    slots = family.init_kv_cache(cfg, n_slots, max_len, kv_int8=True) \
-        if not paged else None
     if not paged:
+        slots = family.init_kv_cache(cfg, n_slots, max_len, kv_int8=True)
         slots["pos"] = jnp.zeros((n_slots,), jnp.int32)
-    owner = [-1] * n_slots
-    emitted = {rid: [] for rid in my_rids}
-    done: List[Optional[np.ndarray]] = [None] * len(prompts)
-    last_tok = np.zeros((n_slots,), np.int32)
     keys = jax.random.split(jax.random.key(0), n_slots)
-    attempts = {rid: 0 for rid in my_rids}
-    pending = set(my_rids)       # not yet retired
-    seated = set()               # currently owning a slot
-
-    t0 = time.perf_counter()
-    ttft = {rid: None for rid in my_rids}
-    finish = {rid: None for rid in my_rids}
+    # The book over this rank's own rids. Its queue holds the requests
+    # still awaited from the prefill rank: the wire, not the queue,
+    # decides which one arrives next.
+    book = RequestBook(prompts, n_new, n_slots, eos, chunk,
+                       max_request_retries, rids=my_rids)
     handoffs: List[HandoffTelemetry] = []
-    itl_samples: List[float] = []
-    n_steps = n_prefills = n_requeues = n_peer_requeues = 0
-    n_hang_dumps = 0
-
-    def _note_failure(rid, exc):
-        nonlocal n_requeues, n_peer_requeues
-        charge = not _peer_dead(exc)
-        if charge:
-            attempts[rid] += 1
-            if attempts[rid] > max_request_retries:
-                raise RuntimeError(
-                    f"request {rid} failed {attempts[rid]} time(s), past "
-                    f"max_request_retries={max_request_retries}") from exc
-        else:
-            n_peer_requeues += 1
-        emitted[rid] = []
-        ttft[rid] = None
-        n_requeues += 1
-        reqlog.emit("requeue", rid, charged=bool(charge))
 
     def intake(b) -> bool:
         """Consume the next inbound handoff. Seats it in slot ``b`` and
         returns True; returns False for a discarded duplicate or a
         failed handoff (requeued — the re-ship will satisfy it)."""
-        nonlocal slots, n_prefills
+        nonlocal slots
         hdr = np.zeros(4, np.int64)
         recv_ch = None
         rid = -1
@@ -836,7 +641,7 @@ def run_decode_worker(rt, params, cfg, prompts, n_new, n_slots, max_len,
                     and int(fin[1]) == rid), (fin, rid)
             reqlog.emit("ship_fin", rid, side="recv", src=src)
             recv_ch.finish()
-            if rid not in pending or rid in seated:
+            if rid not in book.queue:
                 return False      # re-ship duplicate: drained, dropped
             t_pick = time.perf_counter()
             one = {k: jnp.asarray(v) for k, v in one.items()}
@@ -867,26 +672,20 @@ def run_decode_worker(rt, params, cfg, prompts, n_new, n_slots, max_len,
                 reqlog.emit("seat", rid, slot=b, pos=S)
             pickup_s = time.perf_counter() - t_pick
         except Exception as exc:  # noqa: BLE001 — any handoff failure
-            nonlocal n_hang_dumps
             # Snapshot the comm plane before healing: the flight dump
             # is the evidence trail acx_doctor (and the chaos oracle's
             # doctor_verdict audit) attributes the dead link from.
-            if n_hang_dumps == 0 and _flight_dump_best_effort():
-                n_hang_dumps += 1
+            if book.hang_dumps == 0 and _flight_dump_best_effort():
+                book.hang_dumps += 1
             _abort_rounds(None, recv_ch)
-            if rid in pending and rid not in seated:
-                _note_failure(rid, exc)
+            if rid in book.queue:
+                book.queue.remove(rid)    # requeue puts it at the back
+                book.requeue(rid, exc, charge=not _peer_dead(exc))
             elif rid < 0 and not _peer_dead(exc):
                 raise
             return False
-        owner[b] = rid
-        seated.add(rid)
-        first = int(fin[2])
-        emitted[rid].append(first)
-        last_tok[b] = first
-        n_prefills += 1
-        ttft[rid] = time.perf_counter() - t0
-        reqlog.emit("stream", rid, n=1, ttft_s=ttft[rid])
+        book.queue.remove(rid)
+        book.seat(b, rid, int(fin[2]))
         handoffs.append(HandoffTelemetry(
             rid=rid, layers=cfg.n_layers,
             wire_bytes=cfg.n_layers * recv_ch.geom.part_bytes,
@@ -894,93 +693,40 @@ def run_decode_worker(rt, params, cfg, prompts, n_new, n_slots, max_len,
             overlap=True, expose_s=int(fin[4]) / 1e6))
         return True
 
-    def retire(b):
+    def retire_finished(b):
         nonlocal slots
-        rid = owner[b]
-        done[rid] = np.concatenate(
-            [np.asarray(prompts[rid], np.int32),
-             np.asarray(emitted[rid], np.int32)])
-        finish[rid] = time.perf_counter() - t0
-        reqlog.emit("finish", rid, new_tokens=len(emitted[rid]),
-                    latency_s=finish[rid])
-        pending.discard(rid)
-        seated.discard(rid)
-        owner[b] = -1
+        if book.owner[b] < 0 or not book.slot_finished(b):
+            return
+        book.finish_request(b)
         if paged:
             pkv.release(b)        # pages back to the pool, slot parked
         else:
             slots["pos"] = slots["pos"].at[b].set(0)
 
-    def slot_finished(b):
-        rid = owner[b]
-        return (len(emitted[rid]) >= n_new[rid]
-                or (eos is not None and emitted[rid]
-                    and emitted[rid][-1] == eos))
-
-    while pending:
+    while book.queue or book.active():
         # Seat inbound handoffs on every free slot before stepping.
-        while (len(seated) < len(pending)
-               and any(o == -1 for o in owner)):
-            b = owner.index(-1)
-            if intake(b) and slot_finished(b):
-                retire(b)
-        if not any(o >= 0 for o in owner):
+        while book.queue and (b := book.free_slot()) is not None:
+            if intake(b):
+                retire_finished(b)
+        if not book.active():
             continue
+        book.sample_gauges()
         step_t0 = time.perf_counter()
         if paged:
             state = pkv.device_state()
-            state, toks, keys = step_fn(state, jnp.asarray(last_tok),
-                                        keys)
+            state, toks, keys = step_fn(
+                state, jnp.asarray(book.last_tok), keys)
             pkv.absorb(state)
             kvpage.publish_page_stats_best_effort(
                 pkv.alloc.free_count, pkv.alloc.shared_count(), 0, 0, 0)
         else:
-            slots, toks, keys = step_fn(slots, jnp.asarray(last_tok),
-                                        keys)
+            slots, toks, keys = step_fn(
+                slots, jnp.asarray(book.last_tok), keys)
         block = np.asarray(toks, np.int32)
-        step_dt = time.perf_counter() - step_t0
-        n_steps += 1
-        reqlog.emit("decode_step", step=n_steps, dt_s=step_dt,
-                    active=sum(o >= 0 for o in owner))
+        book.deliver(block, time.perf_counter() - step_t0)
         for b in range(n_slots):
-            last_tok[b] = block[-1, b]
-            if owner[b] < 0:
-                continue
-            got = 0
-            for c in range(block.shape[0]):
-                if slot_finished(b):
-                    break
-                emitted[owner[b]].append(int(block[c, b]))
-                itl_samples.append(step_dt / chunk)
-                got += 1
-            if got:
-                reqlog.emit("stream", owner[b], n=got,
-                            itl_s=step_dt / chunk)
-        for b in range(n_slots):
-            if owner[b] >= 0 and slot_finished(b):
-                retire(b)
+            retire_finished(b)
 
     receiver.close()
-    wall = time.perf_counter() - t0
-    per_request = []
-    total_new = 0
-    for rid in my_rids:
-        nt = len(emitted[rid])
-        total_new += nt
-        lat = finish[rid] if finish[rid] is not None else wall
-        per_request.append(RequestTelemetry(
-            rid=rid, ttft_s=ttft[rid] if ttft[rid] is not None else lat,
-            latency_s=lat, new_tokens=nt,
-            tokens_per_s=nt / lat if lat > 0 else 0.0,
-            retries=attempts[rid]))
-    metrics = DisaggMetrics(
-        requests=len(my_rids), wall_s=wall, new_tokens=total_new,
-        tokens_per_s=total_new / wall if wall > 0 else 0.0,
-        steps=n_steps, prefills=n_prefills, requeues=n_requeues,
-        peer_requeues=n_peer_requeues, hang_dumps=n_hang_dumps,
-        ttft_p50_s=_pct([r.ttft_s for r in per_request], 0.50),
-        ttft_p99_s=_pct([r.ttft_s for r in per_request], 0.99),
-        itl_p50_s=_pct(itl_samples, 0.50),
-        itl_p99_s=_pct(itl_samples, 0.99),
-        per_request=per_request, handoffs=handoffs)
-    return ServedBatch(done, _finish_handoff_metrics(metrics))
+    return ServedBatch(book.done, _finish_handoff_metrics(
+        book.metrics(DisaggMetrics, handoffs=handoffs)))

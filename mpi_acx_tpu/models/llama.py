@@ -2,8 +2,8 @@
 pure functional JAX.
 
 Second model family next to the GPT-2 transformer (models/transformer.py);
-the workload behind BASELINE.json configs[4] ("Llama-3-8B activation/grad
-pipeline exchange"). Same TPU-first construction: stacked-layer params
+the workload behind the reference's "Llama-3-8B activation/grad
+pipeline exchange" config. Same TPU-first construction: stacked-layer params
 scanned with ``lax.scan`` (stage-sliceable for pipeline parallelism with
 :func:`mpi_acx_tpu.models.transformer.stage_slice`-style reshapes), bf16
 compute with f32 norms/softmax, static shapes, and the shared flash/dense
@@ -44,7 +44,7 @@ class LlamaConfig:
 
 
 def llama3_8b() -> LlamaConfig:
-    """Llama-3-8B geometry (BASELINE.json configs[4])."""
+    """Llama-3-8B geometry."""
     return LlamaConfig()
 
 

@@ -26,10 +26,11 @@ schedules.
 from __future__ import annotations
 
 import ctypes
+import json
 import math
 import os
 import time
-from collections import deque
+from collections import Counter, deque
 from dataclasses import dataclass, field
 from functools import partial
 from typing import Dict, List, Optional, Sequence
@@ -54,9 +55,9 @@ def _pct(samples: List[float], p: float) -> float:
 
 @dataclass
 class RequestTelemetry:
-    """Per-request serving telemetry (times from the batch's arrival at
-    _serve entry, so queue wait is included — the number a caller of a
-    serving system actually experiences)."""
+    """Per-request serving telemetry (times from the batch's arrival,
+    RequestBook.t0, so queue wait is included — the number a caller of
+    a serving system actually experiences)."""
 
     rid: int
     ttft_s: float        # time to first token (prefill emits it)
@@ -103,15 +104,16 @@ class ServingMetrics:
     queue_depth_mean: float = 0.0
     slot_occupancy_mean: float = 0.0  # slots owned at a chunk's START
     per_request: List[RequestTelemetry] = field(default_factory=list)
+    # The decode work, counted where the tokens are consumed
+    # (RequestBook.deliver).
+    decode_slot_steps: int = 0    # every chunk: chunk x n_slots
+    decode_tokens: int = 0        # tokens the deliver loop consumed
     # serve_paged_greedy only: the call split into the phases of its
     # docstring's table (profiling.Phases: self seconds and entries per
-    # span name; the self times sum to call_s), and the decode work
-    # counted where the tokens are consumed.
+    # span name; the self times sum to call_s).
     call_s: float = 0.0           # function entry -> return (wall_s + set-up)
     phase_s: Dict[str, float] = field(default_factory=dict)
     phase_n: Dict[str, int] = field(default_factory=dict)
-    decode_slot_steps: int = 0    # every chunk: chunk x n_slots
-    decode_tokens: int = 0        # tokens the deliver loop consumed
     # How many of the paged path's programs were TRACED during this call
     # (kvpage.programs_traced): 10-20 in a process's first call, 0 in
     # every later one with the same static arguments and shapes.
@@ -140,40 +142,36 @@ class RequestRejected:
     detail: str = ""
 
 
-def _admission_check(rid, prompt, n, chunk, max_len, max_seq,
-                     page_budget=None, page_tokens=None
-                     ) -> Optional[RequestRejected]:
-    """The serving admission rule: a request needs ``len(prompt) + n +
-    chunk`` cache positions (the chunk overrun is real — a slot
-    finishing mid-chunk keeps writing until the boundary). Returns a
-    RequestRejected or None; the paged path adds the pool-budget bound
-    (``page_budget`` in pages of ``page_tokens``)."""
-    total = len(prompt) + n + chunk
-    if total > max_len:
-        return RequestRejected(
-            rid, "exceeds_max_len",
-            f"len(prompt)={len(prompt)} + n_new={n} + chunk={chunk} "
-            f"= {total} > max_len={max_len}")
-    if total > max_seq:
-        return RequestRejected(
-            rid, "exceeds_model_ceiling",
-            f"len(prompt)={len(prompt)} + n_new={n} + chunk={chunk} "
-            f"= {total} > cfg.max_seq={max_seq}")
-    if page_budget is not None:
-        need = -(-total // page_tokens)
-        if need > page_budget:
-            return RequestRejected(
-                rid, "exceeds_page_budget",
-                f"ceil({total} / {page_tokens}) = {need} pages > "
-                f"pool n_pages={page_budget}")
-    return None
-
-
-def _count_reasons(rejections) -> Dict[str, int]:
-    out: Dict[str, int] = {}
-    for rej in rejections:
-        out[rej.reason] = out.get(rej.reason, 0) + 1
-    return out
+def _admit(prompts, n_new, chunk, max_len, max_seq, page_budget=None,
+           page_tokens=None) -> Dict[int, RequestRejected]:
+    """The serving admission rule over a batch: a request needs
+    ``len(prompt) + n + chunk`` cache positions (the chunk overrun is
+    real — a slot finishing mid-chunk keeps writing until the boundary);
+    the paged path adds the pool-budget bound (``page_budget`` in pages
+    of ``page_tokens``). Returns the rejections by rid, and emits the
+    journey's ``admit`` / ``reject`` / ``queue`` events."""
+    rejected: Dict[int, RequestRejected] = {}
+    for rid, (p, n) in enumerate(zip(prompts, n_new)):
+        total = len(p) + n + chunk
+        need = -(-total // page_tokens) if page_budget is not None else 0
+        if total <= min(max_len, max_seq) and need <= (page_budget or 0):
+            reqlog.emit("admit", rid, prompt_len=len(p), n_new=n)
+            continue
+        sums = f"len(prompt)={len(p)} + n_new={n} + chunk={chunk} = {total}"
+        if total > max_len:
+            rej = ("exceeds_max_len", f"{sums} > max_len={max_len}")
+        elif total > max_seq:
+            rej = ("exceeds_model_ceiling", f"{sums} > cfg.max_seq={max_seq}")
+        else:
+            rej = ("exceeds_page_budget",
+                   f"ceil({total} / {page_tokens}) = {need} pages > "
+                   f"pool n_pages={page_budget}")
+        rejected[rid] = RequestRejected(rid, *rej)
+        reqlog.emit("reject", rid, reason=rej[0])
+    admitted = (rid for rid in range(len(prompts)) if rid not in rejected)
+    for depth, rid in enumerate(admitted):
+        reqlog.emit("queue", rid, depth=depth)
+    return rejected
 
 
 class ServedBatch(list):
@@ -206,19 +204,28 @@ def _peer_dead(exc: BaseException) -> bool:
     return "peer dead" in msg or "peer_dead" in msg
 
 
+def _native_lib():
+    """The native runtime's library if it is ALREADY loaded, else None.
+    The diagnostics below never build or load it (the serving loop must
+    keep making progress) and never raise."""
+    try:
+        import mpi_acx_tpu.runtime as _rt
+        return _rt._lib
+    except Exception:  # pragma: no cover — runtime layer unavailable
+        return None
+
+
 def _fleet_active() -> Optional[int]:
     """Best-effort count of ACTIVE rank slots in this process's fleet view
-    (docs/DESIGN.md §12), or None when the native runtime isn't loaded —
-    same no-build/no-load discipline as ``_flight_dump_best_effort``. The
-    serving loop polls this to notice capacity RETURNING: a replacement
-    rank joining raises the count, and shed slots come back."""
+    (docs/DESIGN.md §12), or None when the native runtime isn't loaded.
+    The serving loop polls this to notice capacity RETURNING: a
+    replacement rank joining raises the count, and shed slots come back."""
+    lib = _native_lib()
+    if lib is None:
+        return None
     try:
-        import ctypes
-        import mpi_acx_tpu.runtime as _rt
-        if _rt._lib is None:
-            return None
         out = (ctypes.c_uint64 * 5)()
-        _rt._lib.acx_fleet_stats(out)
+        lib.acx_fleet_stats(out)
         return int(out[4])
     except Exception:  # pragma: no cover — diagnostics must never raise
         return None
@@ -228,18 +235,15 @@ def _flight_dump_best_effort() -> bool:
     """Write this rank's flight-recorder dump if the operator opted in
     ($ACX_FLIGHT names a prefix — same gate as the fatal-signal dump, so
     deliberate failure-path tests don't litter the cwd) and the native
-    runtime is already loaded (never build or load the library just for a
-    dump — the serving loop must keep making progress). A failed step
-    usually means a comm op wedged underneath XLA; the dump plus
-    tools/acx_doctor.py turns 'the batch hung' into 'rank R never sent
-    tag T'. Returns True iff a dump file was written."""
-    if not os.environ.get("ACX_FLIGHT"):
+    runtime is loaded. A failed step usually means a comm op wedged
+    underneath XLA; the dump plus tools/acx_doctor.py turns 'the batch
+    hung' into 'rank R never sent tag T'. Returns True iff a dump file
+    was written."""
+    lib = _native_lib() if os.environ.get("ACX_FLIGHT") else None
+    if lib is None:
         return False
     try:
-        import mpi_acx_tpu.runtime as _rt
-        if _rt._lib is None:
-            return False
-        return _rt._lib.acx_flight_dump(None) == 0
+        return lib.acx_flight_dump(None) == 0
     except Exception:  # pragma: no cover — diagnostics must never raise
         return False
 
@@ -319,33 +323,21 @@ class RollingSLO:
         }
 
 
-def _tseries_armed() -> bool:
-    """True iff ACX_TSERIES is set, the native runtime is ALREADY loaded
-    and its sampler runs: a caller whose fragment is dear to build
-    (``RollingSLO.live_slos`` sorts its windows) asks first."""
-    if not os.environ.get("ACX_TSERIES"):
+def _tseries_annotate_best_effort(slo: RollingSLO) -> bool:
+    """Publish ``slo.live_slos()`` to the native telemetry sampler (it
+    rides along under ``"app"`` in every subsequent ACX_TSERIES sample)
+    — but only if ACX_TSERIES is set, the native runtime is loaded and
+    its sampler runs: the fragment (``live_slos`` sorts both windows)
+    is not built when nobody is sampling. Returns True iff a fragment
+    was handed to the sampler."""
+    lib = _native_lib() if os.environ.get("ACX_TSERIES") else None
+    if lib is None:
         return False
     try:
-        import mpi_acx_tpu.runtime as _rt
-        return _rt._lib is not None and bool(_rt._lib.acx_tseries_enabled())
-    except Exception:  # pragma: no cover — diagnostics must never raise
-        return False
-
-
-def _tseries_annotate_best_effort(fragment: dict) -> bool:
-    """Publish ``fragment`` to the native telemetry sampler (it rides along
-    under ``"app"`` in every subsequent ACX_TSERIES sample) — but only if
-    the native runtime is already loaded AND sampling is armed: same
-    no-build/no-load discipline as ``_flight_dump_best_effort``, plus the
-    JSON encode is skipped entirely when nobody is sampling. Returns True
-    iff the fragment was handed to the sampler."""
-    if not _tseries_armed():
-        return False
-    try:
-        import json as _json
-        import mpi_acx_tpu.runtime as _rt
-        _rt._lib.acx_tseries_annotate(
-            _json.dumps(fragment, separators=(",", ":")).encode())
+        if not lib.acx_tseries_enabled():
+            return False
+        lib.acx_tseries_annotate(
+            json.dumps(slo.live_slos(), separators=(",", ":")).encode())
         return True
     except Exception:  # pragma: no cover — diagnostics must never raise
         return False
@@ -355,19 +347,15 @@ def _span_app_begin_best_effort(request_id: int) -> bool:
     """Bracket-open for causal tracing (docs/DESIGN.md §14): ties every
     native op enqueued until the matching end-call to ``request_id``, so
     an offline acx_critpath.py run splits this request's TTFT into queue
-    vs compute vs wire. Same no-build/no-load discipline as the
-    annotate helper: only if the native runtime is ALREADY loaded and
-    tracing is armed (ACX_TRACE). The id is offset by 1 — request ids
-    start at 0 and span id 0 means "unspanned" on the native side.
-    Returns True iff the bracket was opened (the caller must then close
-    it)."""
-    if not os.environ.get("ACX_TRACE"):
+    vs compute vs wire. Only if the native runtime is loaded and tracing
+    is armed (ACX_TRACE). The id is offset by 1 — request ids start at 0
+    and span id 0 means "unspanned" on the native side. Returns True iff
+    the bracket was opened (the caller must then close it)."""
+    lib = _native_lib() if os.environ.get("ACX_TRACE") else None
+    if lib is None:
         return False
     try:
-        import mpi_acx_tpu.runtime as _rt
-        if _rt._lib is None:
-            return False
-        _rt._lib.acx_span_app_begin(ctypes.c_uint64(request_id + 1))
+        lib.acx_span_app_begin(ctypes.c_uint64(request_id + 1))
         return True
     except Exception:  # pragma: no cover — diagnostics must never raise
         return False
@@ -375,9 +363,7 @@ def _span_app_begin_best_effort(request_id: int) -> bool:
 
 def _span_app_end_best_effort() -> None:
     try:
-        import mpi_acx_tpu.runtime as _rt
-        if _rt._lib is not None:
-            _rt._lib.acx_span_app_end()
+        _native_lib().acx_span_app_end()
     except Exception:  # pragma: no cover — diagnostics must never raise
         pass
 
@@ -387,6 +373,291 @@ def _bucket(n: int, lo: int = 8) -> int:
     while b < n:
         b *= 2
     return b
+
+
+def _padded(tokens, *caps) -> np.ndarray:
+    """``tokens`` as a ``[1, bucket]`` row, right-padded with zeros to
+    its power-of-two bucket (one prefill compile per bucket) capped at
+    ``caps``: the cache length, so the scatter's update always fits the
+    slot buffer, and the model's position ceiling (prefill asserts
+    padded S <= max_seq)."""
+    out = np.zeros((1, min(_bucket(len(tokens)), *caps)), np.int32)
+    out[0, :len(tokens)] = tokens
+    return out
+
+
+def _per_request_n_new(prompts, n_new) -> List[int]:
+    """``n_new`` as one int per request, with the checks every serve
+    entry point makes of its requests."""
+    assert prompts, "no requests"
+    assert all(len(p) > 0 for p in prompts), \
+        "zero-length prompt (prefill needs at least one token to attend)"
+    n_new = ([int(n_new)] * len(prompts) if np.ndim(n_new) == 0
+             else [int(n) for n in n_new])
+    assert len(n_new) == len(prompts), (len(n_new), len(prompts))
+    assert all(n >= 1 for n in n_new), \
+        "n_new >= 1 per request (the prefill itself emits the first token)"
+    return n_new
+
+
+class RequestBook:
+    """What every serve loop keeps per request and per slot, and the
+    rules over it, each written once. The loops (``_serve``,
+    ``serve_paged_greedy``, disagg's ``run_decode_worker``) own what is
+    particular to their cache: where a first token comes from, where a
+    slot's K/V lives, what a step is. The book owns ``queue`` (rids,
+    arrival order), ``owner[b]`` (the rid in slot b; -1 idle, -2 shed:
+    capacity retired after a peer loss, never refilled, skipped by
+    every ``owner[b] >= 0`` loop), ``last_tok`` (the step's input), per
+    rid ``emitted`` / ``done`` / ``attempts`` / ``ttft`` / ``finish``,
+    the counters, gauge samples and RollingSLO, and ``metrics()``.
+
+    ``rids`` narrows the book to the requests this rank serves (a
+    decode rank's share); ``rejected`` rows are never queued. All
+    requests "arrive" when the book is made (``t0``), so queue wait
+    counts toward TTFT and latency."""
+
+    def __init__(self, prompts, n_new, n_slots, eos, chunk,
+                 max_request_retries, rejected=None, rids=None,
+                 on_token=None):
+        n = len(prompts)
+        self.prompts = [np.asarray(p, np.int32) for p in prompts]
+        self.n_new, self.n_slots, self.eos, self.chunk = (
+            n_new, n_slots, eos, chunk)
+        # A request whose prefill or step raised restarts from scratch
+        # this many times before the error propagates (0 = fail fast).
+        self.max_request_retries = max_request_retries
+        self.on_token = on_token
+        self.rejected: Dict[int, RequestRejected] = dict(rejected or {})
+        self.rids = [rid for rid in (range(n) if rids is None else rids)
+                     if rid not in self.rejected]
+        self.queue = deque(self.rids)
+        self.owner = [-1] * n_slots
+        self.last_tok = np.zeros((n_slots,), np.int32)
+        self.emitted: List[List[int]] = [[] for _ in range(n)]
+        self.done: List[Optional[object]] = [None] * n
+        self.attempts = [0] * n
+        self.ttft: List[Optional[float]] = [None] * n
+        self.finish: List[Optional[float]] = [None] * n
+        self.steps = self.prefills = self.requeues = self.peer_requeues = 0
+        self.slots_shed = self.slots_revived = self.hang_dumps = 0
+        self.decode_tokens = self.decode_slot_steps = 0
+        self.itl_samples: List[float] = []
+        self.qd_samples: List[int] = []
+        self.occ_samples: List[float] = []
+        # Rolling-window SLOs for the live telemetry plane, fed
+        # alongside the whole-batch lists (sample_gauges publishes).
+        self.slo = RollingSLO()
+        for rid, rej in self.rejected.items():
+            self.done[rid] = rej
+            self.slo.note_reject(rej.reason)
+        # Fleet-elastic capacity (docs/DESIGN.md §12): rank slots
+        # ACTIVE when last looked; None = no native runtime, dormant.
+        self._fleet_active_seen = _fleet_active()
+        self.t0 = time.perf_counter()
+
+    def active(self) -> bool:
+        return any(o >= 0 for o in self.owner)
+
+    def free_slot(self) -> Optional[int]:
+        """The lowest idle slot, or None."""
+        return self.owner.index(-1) if -1 in self.owner else None
+
+    def restart(self, rid):
+        """Back on the queue for a bit-equal replay: the emitted tokens
+        are discarded and the replayed attempt re-earns its first
+        token."""
+        self.emitted[rid] = []
+        self.ttft[rid] = None
+        self.queue.append(rid)
+
+    def requeue(self, rid, exc, charge=True):
+        """Put a failed request back on the queue, or re-raise past the
+        retry budget. ``charge=False`` (peer loss) requeues without
+        spending the request's retry budget: losing a rank is not the
+        request's fault, and a long recovery must not burn victims out
+        of the server."""
+        if charge:
+            self.attempts[rid] += 1
+            if self.attempts[rid] > self.max_request_retries:
+                raise RuntimeError(
+                    f"request {rid} failed {self.attempts[rid]} time(s), "
+                    f"past max_request_retries={self.max_request_retries}"
+                ) from exc
+        else:
+            self.peer_requeues += 1
+        self.requeues += 1
+        reqlog.emit("requeue", rid, charged=bool(charge))
+        self.restart(rid)
+
+    def step_failed(self, exc, shed=True):
+        """After a failed step: snapshot the comm plane first (the
+        flight dump captures the wedged op/link state as the failure
+        left it), then requeue every active slot's request, in slot
+        order. A peer-loss failure does NOT charge the victims and,
+        with ``shed``, retires one slot: the job's capacity shrank with
+        the lost rank. The caller rebuilds its cache."""
+        lost_peer = _peer_dead(exc)
+        if _flight_dump_best_effort():
+            self.hang_dumps += 1
+        for b in range(self.n_slots):
+            if self.owner[b] >= 0:
+                rid, self.owner[b] = self.owner[b], -1
+                self.requeue(rid, exc, charge=not lost_peer)
+        if lost_peer and shed:
+            self.shed_slot()
+        self.last_tok[:] = 0
+
+    def shed_slot(self):
+        """Retire the highest idle slot for good (owner -2): a lost
+        rank shrank the job's capacity, so the batch shrinks with it
+        instead of hammering the survivors at the old width. Always
+        keeps at least one slot alive — a server with zero slots is
+        just an outage."""
+        alive = [b for b in range(self.n_slots) if self.owner[b] != -2]
+        idle = [b for b in alive if self.owner[b] == -1]
+        if len(alive) <= 1 or not idle:
+            return
+        self.owner[max(idle)] = -2
+        self.slots_shed += 1
+
+    def revive(self) -> List[int]:
+        """Return shed slots to service when the fleet view shows
+        capacity back (a replacement joined). Returns the revived slot
+        indices so the caller rebalances queued requests onto exactly
+        those. A drop in ACTIVE rank slots just lowers the watermark:
+        the NEXT join, not the leave before it, triggers revival."""
+        if self._fleet_active_seen is None:
+            return []
+        act = _fleet_active()
+        if act is None:
+            return []
+        revived = []
+        if act > self._fleet_active_seen:
+            for b in range(self.n_slots):
+                if self.owner[b] == -2:
+                    self.owner[b] = -1
+                    revived.append(b)
+            self.slots_revived += len(revived)
+        self._fleet_active_seen = act
+        return revived
+
+    def seat(self, b, rid, first):
+        """Slot b now serves rid, whose prefill emitted ``first``."""
+        self.owner[b] = rid
+        self.emitted[rid].append(first)
+        if self.on_token is not None:
+            self.on_token(rid, first)
+        self.last_tok[b] = first
+        self.prefills += 1
+        self.ttft[rid] = time.perf_counter() - self.t0
+        self.slo.note_ttft(self.ttft[rid])
+        reqlog.emit("stream", rid, n=1, ttft_s=self.ttft[rid])
+
+    def slot_finished(self, b) -> bool:
+        """Slot b's request has its ``n_new`` tokens, or ended on
+        ``eos``."""
+        out = self.emitted[self.owner[b]]
+        return (len(out) >= self.n_new[self.owner[b]]
+                or (self.eos is not None and bool(out)
+                    and out[-1] == self.eos))
+
+    def deliver(self, block, step_dt):
+        """Consume one step's ``[chunk, B]`` token block, which took
+        ``step_dt`` (each of the chunk's tokens shares it evenly). A
+        slot that finishes mid-chunk idles: its further tokens are
+        valid continuations past the request's end, dropped."""
+        self.steps += 1
+        self.decode_slot_steps += block.shape[0] * self.n_slots
+        reqlog.emit("decode_step", step=self.steps, dt_s=step_dt,
+                    active=sum(o >= 0 for o in self.owner))
+        itl = step_dt / self.chunk
+        for b in range(self.n_slots):
+            self.last_tok[b] = block[-1, b]
+            rid = self.owner[b]
+            if rid < 0:
+                continue
+            got = 0
+            for c in range(block.shape[0]):
+                if self.slot_finished(b):
+                    break
+                tok = int(block[c, b])
+                self.emitted[rid].append(tok)
+                if self.on_token is not None:
+                    self.on_token(rid, tok)
+                self.itl_samples.append(itl)
+                self.slo.note_itl(itl)
+                got += 1
+            if got:
+                self.decode_tokens += got
+                reqlog.emit("stream", rid, n=got, itl_s=itl)
+
+    def finish_request(self, b) -> int:
+        """Slot b's request is done: its output is prompt + emitted and
+        the slot is idle (the caller frees the slot's cache). Returns
+        the rid."""
+        rid = self.owner[b]
+        self.done[rid] = np.concatenate(
+            [self.prompts[rid], np.asarray(self.emitted[rid], np.int32)])
+        self.finish[rid] = time.perf_counter() - self.t0
+        reqlog.emit("finish", rid, new_tokens=len(self.emitted[rid]),
+                    latency_s=self.finish[rid])
+        self.owner[b] = -1
+        return rid
+
+    def sample_gauges(self):
+        """Once a scheduler iteration: queue depth, slot occupancy (at
+        a chunk's START), and the live SLO fragment for the sampler."""
+        self.qd_samples.append(len(self.queue))
+        self.occ_samples.append(
+            sum(o >= 0 for o in self.owner) / self.n_slots)
+        self.slo.note_gauges(self.qd_samples[-1], self.occ_samples[-1])
+        _tseries_annotate_best_effort(self.slo)
+
+    def metrics(self, cls=ServingMetrics, **own):
+        """The batch's telemetry; ``own`` are the caller's fields (a
+        rejected request never ran and has no row)."""
+        assert all(self.done[rid] is not None for rid in self.rids)
+        wall = time.perf_counter() - self.t0
+        per_request = []
+        for rid in self.rids:
+            nt = len(self.emitted[rid])
+            lat = self.finish[rid] if self.finish[rid] is not None else wall
+            per_request.append(RequestTelemetry(
+                rid=rid,
+                ttft_s=self.ttft[rid] if self.ttft[rid] is not None else lat,
+                latency_s=lat,
+                new_tokens=nt,
+                tokens_per_s=nt / lat if lat > 0 else 0.0,
+                retries=self.attempts[rid]))
+        total_new = sum(r.new_tokens for r in per_request)
+        qd, occ = self.qd_samples, self.occ_samples
+        return cls(
+            requests=len(self.rids) + len(self.rejected),
+            wall_s=wall,
+            new_tokens=total_new,
+            tokens_per_s=total_new / wall if wall > 0 else 0.0,
+            steps=self.steps,
+            prefills=self.prefills,
+            requeues=self.requeues,
+            peer_requeues=self.peer_requeues,
+            slots_shed=self.slots_shed,
+            slots_revived=self.slots_revived,
+            hang_dumps=self.hang_dumps,
+            rejections=len(self.rejected),
+            rejection_reasons=dict(Counter(
+                rej.reason for rej in self.rejected.values())),
+            ttft_p50_s=_pct([r.ttft_s for r in per_request], 0.50),
+            ttft_p99_s=_pct([r.ttft_s for r in per_request], 0.99),
+            itl_p50_s=_pct(self.itl_samples, 0.50),
+            itl_p99_s=_pct(self.itl_samples, 0.99),
+            queue_depth_max=max(qd) if qd else 0,
+            queue_depth_mean=sum(qd) / len(qd) if qd else 0.0,
+            slot_occupancy_mean=sum(occ) / len(occ) if occ else 1.0,
+            per_request=per_request,
+            decode_slot_steps=self.decode_slot_steps,
+            decode_tokens=self.decode_tokens,
+            **own)
 
 
 def make_server_fns(params, cfg, family, chunk: int = 1,
@@ -488,44 +759,57 @@ def make_server_fns(params, cfg, family, chunk: int = 1,
     return prefill_fn, step_fn, scatter_fn, chunk, kv_int8, sample_cfg
 
 
+def _local_prefill(sample_cfg=None, key=None):
+    """The prefill serve_greedy / serve_sample hand to ``_serve``: the
+    prompt pass on this device, then the first token by argmax, or drawn
+    on the host from request rid's own key stream ``fold_in(key, rid)``,
+    split exactly as decoding.sample_generate splits."""
+    from mpi_acx_tpu.models.decoding import sample_logits
+
+    def prefill(prefill_fn, rid, padded, S):
+        logits, one = prefill_fn(jnp.asarray(padded), S - 1)
+        rkey = None
+        if sample_cfg is None:
+            first = int(jnp.argmax(logits[0, 0]))
+        else:
+            rkey, sub = jax.random.split(jax.random.fold_in(key, rid))
+            first = int(sample_logits(
+                logits[0, 0][None].astype(jnp.float32), sub,
+                *sample_cfg)[0])
+        reqlog.emit("prefill_end", rid, first_token=first)
+        return first, one, rkey
+    return prefill
+
+
 def _serve(params, cfg, prompts, n_new, n_slots, max_len, family, eos,
-           chunk, server_fns, kv_int8, sample_cfg, key,
-           max_request_retries=2):
-    """The scheduler shared by serve_greedy and serve_sample — queue,
-    slot ownership, chunk-block consumption, retire/refill. Sampling
-    only changes (a) how the step picks tokens (make_server_fns
-    sample_cfg) and (b) the first token at refill, drawn on the host
-    with request rid's own key stream fold_in(key, rid), split exactly
-    as decoding.sample_generate splits.
+           chunk, server_fns, kv_int8, sample_cfg, key, prefill,
+           max_request_retries=2, shed_on_peer_loss=True):
+    """The fixed-slot loop, written once: seed the slots, step, deliver
+    the ``[chunk, B]`` block, retire and refill at the chunk boundary.
+    The requests and their rules are the RequestBook's.
+
+    Where a queued request's first token and one-request cache come
+    from is the loop's INPUT: ``prefill(prefill_fn, rid, padded, S) ->
+    (first, one, rkey)`` (``prefill_fn`` is the ``server_fns`` tuple's,
+    ``rkey`` the slot's new sampling key or None; it emits the
+    ``prefill_end`` event itself). serve_greedy / serve_sample pass
+    ``_local_prefill``; disagg's loopback passes its wire handoff. The
+    loop scatters ``one`` into the slot and seats the request, and
+    requeues it when either raises. Sampling changes only how the step
+    picks tokens (make_server_fns ``sample_cfg``) and that first draw.
 
     Degrades gracefully under step/prefill failure (the serving face of
     the runtime's retry plane): a request whose device step raised is
     re-queued from scratch — emitted tokens discarded, so the restart
     replays the same greedy/sampled path bit for bit — up to
     ``max_request_retries`` times before the failure is re-raised with
-    the request id attached."""
+    the request id attached. A peer-loss failure is not charged and
+    sheds one slot, unless the caller has no peer whose loss shrinks it
+    (``shed_on_peer_loss=False``: the loopback)."""
     if family is None:
         from mpi_acx_tpu.models import transformer as family  # noqa: N813
-    assert prompts, "no requests"
-    assert all(len(p) > 0 for p in prompts), \
-        "zero-length prompt (prefill needs at least one token to attend)"
-    n_new = ([int(n_new)] * len(prompts) if np.ndim(n_new) == 0
-             else [int(n) for n in n_new])
-    assert len(n_new) == len(prompts), (len(n_new), len(prompts))
-    assert all(n >= 1 for n in n_new), \
-        "n_new >= 1 per request (the prefill itself emits the first token)"
-
-    # Typed admission: an oversized request degrades to a
-    # RequestRejected at its output index instead of an assert killing
-    # the server for everyone else in the batch.
-    rejected: Dict[int, RequestRejected] = {}
-    for rid, (p, n) in enumerate(zip(prompts, n_new)):
-        rej = _admission_check(rid, p, n, chunk, max_len, cfg.max_seq)
-        if rej is not None:
-            rejected[rid] = rej
-            reqlog.emit("reject", rid, reason=rej.reason)
-        else:
-            reqlog.emit("admit", rid, prompt_len=len(p), n_new=n)
+    n_new = _per_request_n_new(prompts, n_new)
+    rejected = _admit(prompts, n_new, chunk, max_len, cfg.max_seq)
 
     if server_fns is None:
         server_fns = make_server_fns(params, cfg, family, chunk=chunk,
@@ -542,318 +826,108 @@ def _serve(params, cfg, prompts, n_new, n_slots, max_len, family, eos,
         ("server_fns built for different sampling settings "
          f"({fns_sample} vs {sample_cfg})")
 
-    slots = family.init_kv_cache(cfg, n_slots, max_len, kv_int8=kv_int8)
-    slots["pos"] = jnp.zeros((n_slots,), jnp.int32)
+    def fresh_cache():
+        """Zeroed slot cache and per-slot key streams (greedy: dummies
+        the step passes through)."""
+        slots = family.init_kv_cache(cfg, n_slots, max_len, kv_int8=kv_int8)
+        slots["pos"] = jnp.zeros((n_slots,), jnp.int32)
+        return slots, jax.random.split(
+            key if key is not None else jax.random.key(0), n_slots)
 
-    queue = deque((rid, np.asarray(p, np.int32))
-                  for rid, p in enumerate(prompts) if rid not in rejected)
-    for depth, (rid, _p) in enumerate(queue):
-        reqlog.emit("queue", rid, depth=depth)
-    # Request id per slot; -1 = idle, -2 = shed (capacity retired after a
-    # peer loss — never refilled, skipped by every owner[b] >= 0 loop).
-    owner = [-1] * n_slots
-    emitted: List[List[int]] = [[] for _ in prompts]
-    done: List[Optional[object]] = [None] * len(prompts)
-    for rid, rej in rejected.items():
-        done[rid] = rej
-    last_tok = np.zeros((n_slots,), np.int32)
-    # Per-slot key streams (greedy: dummies the step passes through).
-    keys = jax.random.split(key if key is not None else jax.random.key(0),
-                            n_slots)
-
-    # Per-request failure budget: a request whose prefill or decode step
-    # raised restarts from scratch this many times before the error
-    # propagates (0 = fail fast).
-    attempts = [0] * len(prompts)
-
-    # Telemetry (RequestTelemetry/ServingMetrics above). All requests
-    # "arrive" at entry, so per-request clocks start at t0 — queue wait
-    # counts toward TTFT and latency.
-    t0 = time.perf_counter()
-    ttft = [None] * len(prompts)      # type: List[Optional[float]]
-    finish = [None] * len(prompts)    # type: List[Optional[float]]
-    # Rolling-window SLOs for the live telemetry plane: fed alongside the
-    # whole-batch lists below, published to the ACX_TSERIES sampler once
-    # per scheduler iteration (a no-op unless sampling is armed).
-    slo = RollingSLO()
-    for rej in rejected.values():
-        slo.note_reject(rej.reason)
-    itl_samples: List[float] = []
-    qd_samples: List[int] = []
-    occ_samples: List[float] = []
-    n_steps = 0
-    n_prefills = 0
-    n_requeues = 0
-    n_peer_requeues = 0
-    n_shed = 0
-    n_revived = 0
-    n_hang_dumps = 0
-    # Fleet-elastic capacity (docs/DESIGN.md §12): remember how many rank
-    # slots were ACTIVE at entry; a later rise (a replacement joined)
-    # revives shed serving slots so queued requests rebalance onto the
-    # restored capacity. None = no native runtime loaded, feature dormant.
-    fleet_active_seen = _fleet_active()
-
-    def _requeue(rid, prompt, exc, charge=True):
-        """Put a failed request back on the queue for a bit-equal
-        restart (emitted tokens discarded; refill replays the same
-        greedy/per-rid-key path), or re-raise past the retry budget.
-        ``charge=False`` (peer loss) requeues without spending the
-        request's retry budget: losing a rank is not the request's
-        fault, and a long recovery must not burn victims out of the
-        server."""
-        nonlocal n_requeues, n_peer_requeues
-        if charge:
-            attempts[rid] += 1
-            if attempts[rid] > max_request_retries:
-                raise RuntimeError(
-                    f"request {rid} failed {attempts[rid]} time(s), past "
-                    f"max_request_retries={max_request_retries}") from exc
-        else:
-            n_peer_requeues += 1
-        emitted[rid] = []
-        ttft[rid] = None   # the replayed attempt re-earns its first token
-        n_requeues += 1
-        reqlog.emit("requeue", rid, charged=bool(charge))
-        queue.append((rid, prompt))
-
-    def _check_fleet_rejoin():
-        """Revive shed slots when the fleet view shows capacity back: a
-        joined replacement returns the serving width a peer loss took
-        away. Returns the revived slot indices so the caller rebalances
-        queued requests onto exactly those — the rest of the schedule is
-        untouched. A drop in ACTIVE slots just lowers the watermark, so
-        the NEXT join (not the leave that preceded it) triggers revival."""
-        nonlocal fleet_active_seen, n_revived
-        if fleet_active_seen is None:
-            return []
-        act = _fleet_active()
-        if act is None:
-            return []
-        revived = []
-        if act > fleet_active_seen:
-            for b in range(n_slots):
-                if owner[b] == -2:
-                    owner[b] = -1
-                    revived.append(b)
-            n_revived += len(revived)
-        fleet_active_seen = act
-        return revived
-
-    def _shed_slot():
-        """Retire one idle slot for good (owner -2): a lost rank shrank
-        the job's capacity, so the batch shrinks with it instead of
-        hammering the survivors at the old width. Always keeps at least
-        one slot alive — a server with zero slots is just an outage."""
-        nonlocal n_shed
-        alive = [b for b in range(n_slots) if owner[b] != -2]
-        idle = [b for b in alive if owner[b] == -1]
-        if len(alive) <= 1 or not idle:
-            return
-        owner[max(idle)] = -2
-        n_shed += 1
+    slots, keys = fresh_cache()
+    book = RequestBook(prompts, n_new, n_slots, eos, chunk,
+                       max_request_retries, rejected)
 
     def refill(b):
         """Returns True iff slot b now owns a request; a failed prefill
         re-queues the request instead of killing the server."""
-        nonlocal slots, keys, n_prefills
-        rid, prompt = queue.popleft()
+        nonlocal slots, keys
+        rid = book.queue.popleft()
+        prompt = book.prompts[rid]
         S = len(prompt)
-        # Bucket for the prefill compile cache, capped at max_len so
-        # the scatter's update always fits the slot buffer, and at the
-        # model's position ceiling (prefill asserts padded S <= max_seq).
-        padded = np.zeros((1, min(_bucket(S), max_len, cfg.max_seq)),
-                          np.int32)
-        padded[0, :S] = prompt
+        padded = _padded(prompt, max_len, cfg.max_seq)
         # Causal-tracing bracket: any native op the prefill triggers
         # (multihost sharded serving pushes activations through MPIX
-        # enqueues) is span-tagged with this request's id, so the
-        # request's TTFT decomposes offline (acx_critpath.py).
+        # enqueues, the loopback its handoff) is span-tagged with this
+        # request's id, so the request's TTFT decomposes offline
+        # (acx_critpath.py).
         spanned = _span_app_begin_best_effort(rid)
         reqlog.emit("prefill_start", rid, prompt_len=S, bucket=padded.shape[1])
         try:
-            logits, one = prefill_fn(jnp.asarray(padded), S - 1)
-            if sample_cfg is None:
-                first = int(jnp.argmax(logits[0, 0]))
-            else:
-                from mpi_acx_tpu.models.decoding import sample_logits
-                rkey, sub = jax.random.split(jax.random.fold_in(key, rid))
-                first = int(sample_logits(
-                    logits[0, 0][None].astype(jnp.float32), sub,
-                    *sample_cfg)[0])
+            first, one, rkey = prefill(prefill_fn, rid, padded, S)
+            if rkey is not None:
                 keys = keys.at[b].set(rkey)
             slots = scatter_fn(slots, one, b, S)
         except Exception as exc:  # noqa: BLE001 — any device failure
-            _requeue(rid, prompt, exc, charge=not _peer_dead(exc))
+            book.requeue(rid, exc, charge=not _peer_dead(exc))
             return False
         finally:
             if spanned:
                 _span_app_end_best_effort()
-        owner[b] = rid
-        emitted[rid].append(first)
-        last_tok[b] = first
-        n_prefills += 1
-        reqlog.emit("prefill_end", rid, first_token=first)
         reqlog.emit("seat", rid, slot=b, pos=S)
-        ttft[rid] = time.perf_counter() - t0  # prefill emitted token one
-        slo.note_ttft(ttft[rid])
-        reqlog.emit("stream", rid, n=1, ttft_s=ttft[rid])
+        book.seat(b, rid, first)
         return True
 
-    def retire(b):
+    def retire_finished(b):
+        """Retire slot b's request if it has ended. The freed slot is
+        parked at pos 0: an idle slot keeps stepping in the batch, and
+        a stale pos walks toward max_len where the decode write would
+        land out of bounds on a long-idle slot."""
         nonlocal slots
-        rid = owner[b]
-        done[rid] = np.concatenate(
-            [np.asarray(prompts[rid], np.int32),
-             np.asarray(emitted[rid], np.int32)])
-        finish[rid] = time.perf_counter() - t0
-        reqlog.emit("finish", rid, new_tokens=len(emitted[rid]),
-                    latency_s=finish[rid])
-        owner[b] = -1
-        # Park the freed slot at pos 0: an idle slot keeps stepping in
-        # the batch, and a stale pos walks toward max_len where the
-        # decode write would land out of bounds on a long-idle slot.
+        if book.owner[b] < 0 or not book.slot_finished(b):
+            return False
+        book.finish_request(b)
         slots["pos"] = slots["pos"].at[b].set(0)
+        return True
 
-    def slot_finished(b):
-        rid = owner[b]
-        return (len(emitted[rid]) >= n_new[rid]
-                or (eos is not None and emitted[rid]
-                    and emitted[rid][-1] == eos))
+    def seed():
+        """Fill every idle slot from the queue, retiring 1-token
+        requests on the spot so a slot never enters the decode loop
+        already finished."""
+        while book.queue and (b := book.free_slot()) is not None:
+            if refill(b):
+                retire_finished(b)
 
-    # Seed the slots, retiring 1-token requests on the spot so a slot
-    # never enters the decode loop already finished.
-    qd_samples.append(len(queue))
-    while queue and any(o == -1 for o in owner):
-        b = owner.index(-1)
-        if refill(b) and slot_finished(b):
-            retire(b)
-
-    while any(o >= 0 for o in owner) or queue:
-        qd_samples.append(len(queue))
-        occ_samples.append(sum(o >= 0 for o in owner) / n_slots)
-        slo.note_gauges(qd_samples[-1], occ_samples[-1])
-        _tseries_annotate_best_effort(slo.live_slos())
-        if queue:
+    book.qd_samples.append(len(book.queue))
+    seed()
+    while book.active() or book.queue:
+        book.sample_gauges()
+        if book.queue:
             # Capacity may have returned (a replacement rank joined):
             # revive shed slots and rebalance the backlog onto them.
-            for b in _check_fleet_rejoin():
-                if queue and refill(b) and slot_finished(b):
-                    retire(b)
-        if not any(o >= 0 for o in owner):
+            for b in book.revive():
+                if book.queue and refill(b):
+                    retire_finished(b)
+        if not book.active():
             # All slots idle with requests still queued: only reachable
             # after a failure re-queued them — reseed and keep serving.
-            while queue and any(o == -1 for o in owner):
-                b = owner.index(-1)
-                if refill(b) and slot_finished(b):
-                    retire(b)
+            seed()
             continue
         step_t0 = time.perf_counter()
         try:
-            slots, toks, keys = step_fn(slots, jnp.asarray(last_tok), keys)
+            slots, toks, keys = step_fn(slots, jnp.asarray(book.last_tok),
+                                        keys)
         except Exception as exc:  # noqa: BLE001 — any device failure
             # step_fn donates the slot cache, so after a failed dispatch
-            # its buffers cannot be trusted. Re-queue every active
-            # request (bit-equal restart, bounded per request by
-            # max_request_retries), rebuild the cache, and continue —
-            # the queued-but-unstarted requests are unaffected. A
-            # peer-loss failure additionally sheds a slot (the job's
-            # capacity shrank with the lost rank) and does NOT charge
-            # the victims' retry budget.
-            lost_peer = _peer_dead(exc)
-            # Snapshot the comm plane before touching anything: the flight
-            # dump captures the wedged op/link state as the failure left it.
-            if _flight_dump_best_effort():
-                n_hang_dumps += 1
-            for b in range(n_slots):
-                if owner[b] >= 0:
-                    rid = owner[b]
-                    owner[b] = -1
-                    _requeue(rid, np.asarray(prompts[rid], np.int32), exc,
-                             charge=not lost_peer)
-            if lost_peer:
-                _shed_slot()
-            slots = family.init_kv_cache(cfg, n_slots, max_len,
-                                         kv_int8=kv_int8)
-            slots["pos"] = jnp.zeros((n_slots,), jnp.int32)
-            keys = jax.random.split(
-                key if key is not None else jax.random.key(0), n_slots)
-            last_tok = np.zeros((n_slots,), np.int32)
+            # its buffers cannot be trusted: the book re-queues every
+            # active request (the queued-but-unstarted ones are
+            # unaffected), the cache is rebuilt, and the loop goes on.
+            book.step_failed(exc, shed=shed_on_peer_loss)
+            slots, keys = fresh_cache()
             continue
         block = np.asarray(toks, np.int32)           # [chunk, B]
         # np.asarray forced the device sync, so this dt covers the real
         # device step; each of the chunk tokens shares it evenly — the
         # per-token cadence a streaming client would see.
-        step_dt = time.perf_counter() - step_t0
-        n_steps += 1
-        reqlog.emit("decode_step", step=n_steps, dt_s=step_dt,
-                    active=sum(o >= 0 for o in owner))
+        book.deliver(block, time.perf_counter() - step_t0)
+        # Retire/refill happens only at chunk boundaries, the
+        # granularity ``chunk`` buys.
         for b in range(n_slots):
-            last_tok[b] = block[-1, b]
-            if owner[b] < 0:
-                continue
-            got = 0
-            for c in range(block.shape[0]):
-                # A slot that finishes mid-chunk idles (its further
-                # tokens are valid continuations past the request's
-                # end — dropped); retire/refill happens only at chunk
-                # boundaries, the granularity ``chunk`` buys.
-                if slot_finished(b):
-                    break
-                emitted[owner[b]].append(int(block[c, b]))
-                itl_samples.append(step_dt / chunk)
-                slo.note_itl(step_dt / chunk)
-                got += 1
-            if got:
-                reqlog.emit("stream", owner[b], n=got, itl_s=step_dt / chunk)
-        for b in range(n_slots):
-            while owner[b] >= 0 and slot_finished(b):
-                retire(b)
-                if queue:
+            while retire_finished(b):
+                if book.queue:
                     refill(b)
 
-    assert all(d is not None for d in done)
-    wall = time.perf_counter() - t0
-    per_request = []
-    total_new = 0
-    for rid in range(len(prompts)):
-        if rid in rejected:
-            continue            # never ran — no telemetry to report
-        nt = len(emitted[rid])
-        total_new += nt
-        lat = finish[rid] if finish[rid] is not None else wall
-        per_request.append(RequestTelemetry(
-            rid=rid,
-            ttft_s=ttft[rid] if ttft[rid] is not None else lat,
-            latency_s=lat,
-            new_tokens=nt,
-            tokens_per_s=nt / lat if lat > 0 else 0.0,
-            retries=attempts[rid]))
-    metrics = ServingMetrics(
-        requests=len(prompts),
-        wall_s=wall,
-        new_tokens=total_new,
-        tokens_per_s=total_new / wall if wall > 0 else 0.0,
-        steps=n_steps,
-        prefills=n_prefills,
-        requeues=n_requeues,
-        peer_requeues=n_peer_requeues,
-        slots_shed=n_shed,
-        slots_revived=n_revived,
-        hang_dumps=n_hang_dumps,
-        rejections=len(rejected),
-        rejection_reasons=_count_reasons(rejected.values()),
-        ttft_p50_s=_pct([r.ttft_s for r in per_request], 0.50),
-        ttft_p99_s=_pct([r.ttft_s for r in per_request], 0.99),
-        itl_p50_s=_pct(itl_samples, 0.50),
-        itl_p99_s=_pct(itl_samples, 0.99),
-        queue_depth_max=max(qd_samples) if qd_samples else 0,
-        queue_depth_mean=(sum(qd_samples) / len(qd_samples)
-                          if qd_samples else 0.0),
-        slot_occupancy_mean=(sum(occ_samples) / len(occ_samples)
-                             if occ_samples else 1.0),
-        per_request=per_request)
-    return ServedBatch(done, metrics)
+    return ServedBatch(book.done, book.metrics())
 
 
 def serve_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
@@ -890,6 +964,7 @@ def serve_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
     """
     return _serve(params, cfg, prompts, n_new, n_slots, max_len, family,
                   eos, chunk, server_fns, kv_int8, None, None,
+                  _local_prefill(),
                   max_request_retries=max_request_retries)
 
 
@@ -912,9 +987,10 @@ def serve_sample(params, cfg, prompts: Sequence[np.ndarray], n_new,
     any request's sample path. All other parameters (and the
     ``ServedBatch``/telemetry return) as serve_greedy.
     """
+    sample_cfg = (temperature, top_k, top_p)
     return _serve(params, cfg, prompts, n_new, n_slots, max_len, family,
-                  eos, chunk, server_fns, kv_int8,
-                  (temperature, top_k, top_p), key,
+                  eos, chunk, server_fns, kv_int8, sample_cfg, key,
+                  _local_prefill(sample_cfg, key),
                   max_request_retries=max_request_retries)
 
 
@@ -977,7 +1053,10 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
     decode step is the fixed step with table-routed writes (tested in
     tests/test_paged.py for bf16 and int8 caches alike).
 
-    Beyond the fixed path it adds:
+    The loop is this function's own (seat, grow, preempt, step and the
+    spans below); the requests, and the requeue / shed / revive /
+    deliver / finish rules over them, are the RequestBook's, as in the
+    fixed-slot loop. Beyond the fixed path it adds:
 
     * **Typed admission** — a request that cannot fit ``max_len``,
       ``cfg.max_seq``, or the page budget degrades to a
@@ -1067,14 +1146,7 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
     traced_at_entry = kvpage.programs_traced()
     if family is None:
         from mpi_acx_tpu.models import transformer as family  # noqa: N813
-    assert prompts, "no requests"
-    assert all(len(p) > 0 for p in prompts), \
-        "zero-length prompt (prefill needs at least one token to attend)"
-    n_new = ([int(n_new)] * len(prompts) if np.ndim(n_new) == 0
-             else [int(n) for n in n_new])
-    assert len(n_new) == len(prompts), (len(n_new), len(prompts))
-    assert all(n >= 1 for n in n_new), \
-        "n_new >= 1 per request (the prefill itself emits the first token)"
+    n_new = _per_request_n_new(prompts, n_new)
 
     pt = page_tokens or kvpage.default_page_tokens(max_len)
     assert max_len % pt == 0, \
@@ -1084,15 +1156,8 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
         n_pages = n_slots * max_pages
     ttft_target, itl_target = _slo_admit_targets(slo_admit)
 
-    rejected: Dict[int, RequestRejected] = {}
-    for rid, (p, n) in enumerate(zip(prompts, n_new)):
-        rej = _admission_check(rid, p, n, chunk, max_len, cfg.max_seq,
-                               page_budget=n_pages, page_tokens=pt)
-        if rej is not None:
-            rejected[rid] = rej
-            reqlog.emit("reject", rid, reason=rej.reason)
-        else:
-            reqlog.emit("admit", rid, prompt_len=len(p), n_new=n)
+    rejected = _admit(prompts, n_new, chunk, max_len, cfg.max_seq,
+                      page_budget=n_pages, page_tokens=pt)
 
     pkv = kvpage.PagedKV(cfg, family, n_slots, max_len, pt, n_pages,
                          kv_int8=kv_int8, prefix_cache=prefix_cache)
@@ -1109,80 +1174,19 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
 
     step_fn = kvpage.make_paged_step_fn(params, cfg, family, chunk, pt)
 
-    queue = deque((rid, np.asarray(p, np.int32))
-                  for rid, p in enumerate(prompts) if rid not in rejected)
-    for depth, (rid, _p) in enumerate(queue):
-        reqlog.emit("queue", rid, depth=depth)
-    owner = [-1] * n_slots          # -1 idle, -2 shed (as _serve)
-    emitted: List[List[int]] = [[] for _ in prompts]
-    done: List[Optional[object]] = [None] * len(prompts)
-    for rid, rej in rejected.items():
-        done[rid] = rej
-    last_tok = np.zeros((n_slots,), np.int32)
     keys = jax.random.split(jax.random.key(0), n_slots)  # greedy dummies
-    attempts = [0] * len(prompts)
-
-    t0 = time.perf_counter()
-    ttft = [None] * len(prompts)      # type: List[Optional[float]]
-    finish = [None] * len(prompts)    # type: List[Optional[float]]
+    # The requests and their rules (module docstring of RequestBook);
+    # its clock ``t0`` starts here, after the set-up.
+    book = RequestBook(prompts, n_new, n_slots, eos, chunk,
+                       max_request_retries, rejected, on_token=on_token)
+    queue, owner, slo = book.queue, book.owner, book.slo
     # Per request, of the refill that seated it: (queue_wait_s,
     # prefill_s, refill_host_s), the first on the entry clock.
     refill_times = [(0.0, 0.0, 0.0)] * len(prompts)
-    n_decode_tokens = n_slot_steps = 0
-    slo = RollingSLO()
-    for rej in rejected.values():
-        slo.note_reject(rej.reason)
-    itl_samples: List[float] = []
-    qd_samples: List[int] = []
-    occ_samples: List[float] = []
-    n_steps = n_prefills = n_requeues = n_peer_requeues = 0
-    n_shed = n_revived = n_hang_dumps = n_preempts = n_slo_defer = 0
+    n_preempts = n_slo_defer = 0
     # Requests currently evicted by page pressure: membership here turns
     # the next successful seat into a journey "resume" event.
     preempted_rids: set = set()
-    fleet_active_seen = _fleet_active()
-
-    def _requeue(rid, prompt, exc, charge=True):
-        nonlocal n_requeues, n_peer_requeues
-        if charge:
-            attempts[rid] += 1
-            if attempts[rid] > max_request_retries:
-                raise RuntimeError(
-                    f"request {rid} failed {attempts[rid]} time(s), past "
-                    f"max_request_retries={max_request_retries}") from exc
-        else:
-            n_peer_requeues += 1
-        emitted[rid] = []
-        ttft[rid] = None
-        n_requeues += 1
-        reqlog.emit("requeue", rid, charged=bool(charge))
-        queue.append((rid, prompt))
-
-    def _check_fleet_rejoin():
-        nonlocal fleet_active_seen, n_revived
-        if fleet_active_seen is None:
-            return []
-        act = _fleet_active()
-        if act is None:
-            return []
-        revived = []
-        if act > fleet_active_seen:
-            for b in range(n_slots):
-                if owner[b] == -2:
-                    owner[b] = -1
-                    revived.append(b)
-            n_revived += len(revived)
-        fleet_active_seen = act
-        return revived
-
-    def _shed_slot():
-        nonlocal n_shed
-        alive = [b for b in range(n_slots) if owner[b] != -2]
-        idle = [b for b in alive if owner[b] == -1]
-        if len(alive) <= 1 or not idle:
-            return
-        owner[max(idle)] = -2
-        n_shed += 1
 
     def _slo_defers() -> bool:
         """SLO-aware batch formation: with a target set, a violating
@@ -1191,7 +1195,7 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
         sees further past target for queue wait nobody measures."""
         if ttft_target is None and itl_target is None:
             return False
-        if not any(o >= 0 for o in owner):
+        if not book.active():
             return False            # an empty server always admits
         live = slo.live_slos()
         if (itl_target is not None and live["itl_n"]
@@ -1206,8 +1210,9 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
         gate deferred (request left at the queue head), the pool could
         not cover the prompt (ditto — a retire will free pages), or
         the prefill failed (request re-queued via the retry rules)."""
-        nonlocal n_prefills, n_slo_defer
-        rid, prompt = queue[0]
+        nonlocal n_slo_defer
+        rid = queue[0]
+        prompt = book.prompts[rid]
         with ph("refill.match", rid=rid) as match:
             if _slo_defers():
                 n_slo_defer += 1
@@ -1227,7 +1232,7 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
                 # pages.
                 for p in hit_pages:
                     pkv.alloc.decref(p)
-                queue.appendleft((rid, prompt))
+                queue.appendleft(rid)
                 return False
         spanned = False
         try:
@@ -1241,18 +1246,12 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
                     # cached pages' gathered history.
                     P = len(hit_pages) * pt
                     suffix = prompt[P:]
-                    Sb = min(_bucket(len(suffix)), max_len - P,
-                             cfg.max_seq - P)
-                    padded = np.zeros((1, Sb), np.int32)
-                    padded[0, :len(suffix)] = suffix
+                    padded = _padded(suffix, max_len - P, cfg.max_seq - P)
                     hk, hv = pkv.gather_history(hit_pages)
                     logits, one = suffix_prefill_fn(
                         jnp.asarray(padded), hk, hv, len(suffix) - 1)
                 else:
-                    padded = np.zeros(
-                        (1, min(_bucket(S), max_len, cfg.max_seq)),
-                        np.int32)
-                    padded[0, :S] = prompt
+                    padded = _padded(prompt, max_len, cfg.max_seq)
                     logits, one = prefill_fn(jnp.asarray(padded), S - 1)
                     one = {k: v for k, v in one.items() if k != "pos"}
                 first = int(jnp.argmax(logits[0, 0]))   # the host waits
@@ -1262,7 +1261,7 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
         except Exception as exc:  # noqa: BLE001 — any device failure
             for p in hit_pages + fresh:
                 pkv.alloc.decref(p)
-            _requeue(rid, prompt, exc, charge=not _peer_dead(exc))
+            book.requeue(rid, exc, charge=not _peer_dead(exc))
             return False
         finally:
             if spanned:
@@ -1271,47 +1270,34 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
             pkv.seat(b, hit_pages, fresh, S, rid=rid)
             if pkv.prefix is not None:
                 pkv.prefix.insert(prompt, pkv.pages[b])
-            owner[b] = rid
             if rid in preempted_rids:
                 preempted_rids.discard(rid)
                 slo.note_resume()
                 reqlog.emit("resume", rid, slot=b)
-            emitted[rid].append(first)
-            if on_token is not None:
-                on_token(rid, first)
-            last_tok[b] = first
-            n_prefills += 1
-            ttft[rid] = time.perf_counter() - t0
-            slo.note_ttft(ttft[rid])
-            reqlog.emit("stream", rid, n=1, ttft_s=ttft[rid])
+            book.seat(b, rid, first)
         refill_times[rid] = (
             match.t0 - setup.t0, pre.seconds,
             match.seconds + scatter.seconds + seat.seconds)
         return True
 
-    def retire(b):
-        rid = owner[b]
-        with ph("chunk.retire", step=n_steps, rid=rid):
-            done[rid] = np.concatenate(
-                [np.asarray(prompts[rid], np.int32),
-                 np.asarray(emitted[rid], np.int32)])
-            finish[rid] = time.perf_counter() - t0
-            reqlog.emit("finish", rid, new_tokens=len(emitted[rid]),
-                        latency_s=finish[rid])
-            owner[b] = -1
-            pkv.release(b)          # pages back to the pool, slot parked
+    def retire_finished(b):
+        """Retire slot b's request if it has ended: its pages go back
+        to the pool and the slot is parked."""
+        if owner[b] < 0 or not book.slot_finished(b):
+            return False
+        with ph("chunk.retire", step=book.steps, rid=owner[b]):
+            book.finish_request(b)
+            pkv.release(b)
+        return True
 
     def preempt(b):
         """Page-pressure eviction: requeue slot b's request UNCHARGED
         (server pressure is not the request's fault — the peer-loss
         rule) with its pages freed; the replay is bit-equal."""
         nonlocal n_preempts
-        rid = owner[b]
-        owner[b] = -1
+        rid, owner[b] = owner[b], -1
         pkv.release(b)
-        emitted[rid] = []
-        ttft[rid] = None
-        queue.append((rid, np.asarray(prompts[rid], np.int32)))
+        book.restart(rid)
         n_preempts += 1
         pkv.preemptions += 1
         preempted_rids.add(rid)
@@ -1340,11 +1326,18 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
             else:
                 return
 
-    def slot_finished(b):
-        rid = owner[b]
-        return (len(emitted[rid]) >= n_new[rid]
-                or (eos is not None and emitted[rid]
-                    and emitted[rid][-1] == eos))
+    def seed() -> bool:
+        """Fill idle slots from the queue head until one refill does
+        not seat (deferred, short on pages, or failed), retiring
+        1-token requests on the spot. True iff any request was
+        seated."""
+        progressed = False
+        while queue and (b := book.free_slot()) is not None:
+            if not refill(b):
+                break
+            progressed = True
+            retire_finished(b)
+        return progressed
 
     def _publish():
         kvpage.publish_page_stats_best_effort(
@@ -1353,46 +1346,27 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
             pkv.prefix.evictions if pkv.prefix else 0,
             pkv.preemptions)
 
-    qd_samples.append(len(queue))
+    book.qd_samples.append(len(queue))
     setup.__exit__(None, None, None)
-    while queue and any(o == -1 for o in owner):
-        b = owner.index(-1)
-        if refill(b):
-            if slot_finished(b):
-                retire(b)
-        else:
-            break                   # deferred/short on pages: stop seeding
+    seed()
 
     stalls = 0
-    while any(o >= 0 for o in owner) or queue:
-        step_no = n_steps + 1       # the chunk this pass leads up to
+    while book.active() or queue:
+        step_no = book.steps + 1    # the chunk this pass leads up to
         with ph("loop.other", step=step_no):
-            qd_samples.append(len(queue))
-            occ_samples.append(sum(o >= 0 for o in owner) / n_slots)
-            slo.note_gauges(qd_samples[-1], occ_samples[-1])
-            if _tseries_armed():    # live_slos() sorts both windows
-                _tseries_annotate_best_effort(slo.live_slos())
+            book.sample_gauges()
             _publish()
             if queue:
-                for b in _check_fleet_rejoin():
-                    if queue and refill(b) and slot_finished(b):
-                        retire(b)
-        if not any(o >= 0 for o in owner):
+                for b in book.revive():
+                    if queue and refill(b):
+                        retire_finished(b)
+        if not book.active():
             # All slots idle with requests queued (failure requeues, a
             # deferred seed, or total preemption): reseed. The SLO gate
             # never defers an empty server and admission bounds every
             # queued request, so a stall here means a real bug — bound
             # it instead of spinning.
-            progressed = False
-            while queue and any(o == -1 for o in owner):
-                b = owner.index(-1)
-                if refill(b):
-                    progressed = True
-                    if slot_finished(b):
-                        retire(b)
-                else:
-                    break
-            stalls = 0 if progressed else stalls + 1
+            stalls = 0 if seed() else stalls + 1
             if stalls > len(prompts) + n_slots + 2:
                 raise RuntimeError(
                     "paged scheduler stalled: queue non-empty, no slot "
@@ -1411,102 +1385,34 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
                                (int(pkv.pos[b]) + chunk - 1) // pt + 1):
                     if j < len(pkv.pages[b]):
                         pkv.ensure_writable(b, j)
-        if not any(o >= 0 for o in owner):
+        if not book.active():
             continue                # grow_for_chunk preempted everyone
         with ph("chunk.upload", step=step_no) as upload:
             state = pkv.device_state()
         with ph("chunk.step", step=step_no) as stepped:
             try:
-                state, toks, keys = step_fn(state, jnp.asarray(last_tok),
-                                            keys)
+                state, toks, keys = step_fn(
+                    state, jnp.asarray(book.last_tok), keys)
                 pkv.absorb(state)
             except Exception as exc:  # noqa: BLE001 — any device failure
-                lost_peer = _peer_dead(exc)
-                if _flight_dump_best_effort():
-                    n_hang_dumps += 1
-                for b in range(n_slots):
-                    if owner[b] >= 0:
-                        rid = owner[b]
-                        owner[b] = -1
-                        _requeue(rid, np.asarray(prompts[rid], np.int32),
-                                 exc, charge=not lost_peer)
-                if lost_peer:
-                    _shed_slot()
+                book.step_failed(exc)
                 # The step donated the pool buffers: rebuild from zeros
                 # and drop every reference (prefix cache included — its
                 # pages lived in the donated pool).
                 pkv.reset_pool()
-                last_tok = np.zeros((n_slots,), np.int32)
                 continue
             block = np.asarray(toks, np.int32)       # [chunk, B]: waits
-        step_dt = upload.seconds + stepped.seconds   # the spans' readings
-        n_steps += 1
-        n_slot_steps += block.shape[0] * n_slots
-        reqlog.emit("decode_step", step=n_steps, dt_s=step_dt,
-                    active=sum(o >= 0 for o in owner))
         with ph("chunk.deliver", step=step_no):
-            for b in range(n_slots):
-                last_tok[b] = block[-1, b]
-                if owner[b] < 0:
-                    continue
-                got = 0
-                for c in range(block.shape[0]):
-                    if slot_finished(b):
-                        break
-                    tok = int(block[c, b])
-                    emitted[owner[b]].append(tok)
-                    if on_token is not None:
-                        on_token(owner[b], tok)
-                    itl_samples.append(step_dt / chunk)
-                    slo.note_itl(step_dt / chunk)
-                    got += 1
-                if got:
-                    n_decode_tokens += got
-                    reqlog.emit("stream", owner[b], n=got,
-                                itl_s=step_dt / chunk)
+            # The spans' readings are the step's time.
+            book.deliver(block, upload.seconds + stepped.seconds)
         for b in range(n_slots):
-            while owner[b] >= 0 and slot_finished(b):
-                retire(b)
+            while retire_finished(b):
                 if queue:
                     refill(b)
 
-    with ph("loop.other", step=n_steps) as tail:
+    with ph("loop.other", step=book.steps) as tail:
         _publish()
-        assert all(d is not None for d in done)
-        wall = time.perf_counter() - t0
-        per_request = []
-        total_new = 0
-        for rid in range(len(prompts)):
-            if rid in rejected:
-                continue
-            nt = len(emitted[rid])
-            total_new += nt
-            lat = finish[rid] if finish[rid] is not None else wall
-            queue_wait_s, prefill_s, refill_host_s = refill_times[rid]
-            per_request.append(RequestTelemetry(
-                rid=rid,
-                ttft_s=ttft[rid] if ttft[rid] is not None else lat,
-                latency_s=lat,
-                new_tokens=nt,
-                tokens_per_s=nt / lat if lat > 0 else 0.0,
-                retries=attempts[rid],
-                queue_wait_s=queue_wait_s,
-                prefill_s=prefill_s,
-                refill_host_s=refill_host_s))
-        metrics = ServingMetrics(
-            requests=len(prompts),
-            wall_s=wall,
-            new_tokens=total_new,
-            tokens_per_s=total_new / wall if wall > 0 else 0.0,
-            steps=n_steps,
-            prefills=n_prefills,
-            requeues=n_requeues,
-            peer_requeues=n_peer_requeues,
-            slots_shed=n_shed,
-            slots_revived=n_revived,
-            hang_dumps=n_hang_dumps,
-            rejections=len(rejected),
-            rejection_reasons=_count_reasons(rejected.values()),
+        metrics = book.metrics(
             preemptions=n_preempts,
             prefix_hits=pkv.prefix.hits if pkv.prefix else 0,
             prefix_evictions=pkv.prefix.evictions if pkv.prefix else 0,
@@ -1516,23 +1422,14 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
             paged_kv_write=select_paged_kv_write(cfg.decode_flash,
                                                  pt).__name__,
             slo_deferrals=n_slo_defer,
-            ttft_p50_s=_pct([r.ttft_s for r in per_request], 0.50),
-            ttft_p99_s=_pct([r.ttft_s for r in per_request], 0.99),
-            itl_p50_s=_pct(itl_samples, 0.50),
-            itl_p99_s=_pct(itl_samples, 0.99),
-            queue_depth_max=max(qd_samples) if qd_samples else 0,
-            queue_depth_mean=(sum(qd_samples) / len(qd_samples)
-                              if qd_samples else 0.0),
-            slot_occupancy_mean=(sum(occ_samples) / len(occ_samples)
-                                 if occ_samples else 1.0),
-            per_request=per_request,
-            decode_slot_steps=n_slot_steps,
-            decode_tokens=n_decode_tokens,
             programs_traced=kvpage.programs_traced() - traced_at_entry)
+        for r in metrics.per_request:
+            r.queue_wait_s, r.prefill_s, r.refill_host_s = \
+                refill_times[r.rid]
     # Filled in once the last span has closed: its end is the call's.
     metrics.call_s = tail.t1 - setup.t0
     metrics.phase_s, metrics.phase_n = ph.seconds, ph.count
-    batch = ServedBatch(done, metrics)
+    batch = ServedBatch(book.done, metrics)
     if return_paged_state:
         batch.paged_state = pkv
     return batch

@@ -12,7 +12,7 @@ TPU-first choices:
   sequence axis is the ring-attention/sequence-parallel axis — the
   distributed train step in mpi_acx_tpu.train slices these with shard_map.
 
-GPT-2 125M (BASELINE.json configs[3]) is `gpt2_small()`.
+GPT-2 125M is `gpt2_small()`.
 """
 
 from __future__ import annotations
@@ -55,7 +55,7 @@ class TransformerConfig:
 
 
 def gpt2_small() -> TransformerConfig:
-    """GPT-2 124M: 12L / 768d / 12H / 3072ff (BASELINE.json configs[3])."""
+    """GPT-2 124M: 12L / 768d / 12H / 3072ff."""
     return TransformerConfig()
 
 

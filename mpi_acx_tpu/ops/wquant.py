@@ -1,8 +1,7 @@
 """Int8 weight-only quantization for serving.
 
 Decode is HBM-bandwidth-bound on RE-READING THE WEIGHTS every token
-(BASELINE.md decode roofline: at B=8/GPT-2-125M the weight stream is
-~40x the KV stream), so halving weight bytes — bf16 -> int8 + one f32
+(at B=8/GPT-2-125M the weight stream is ~40x the KV stream), so halving weight bytes — bf16 -> int8 + one f32
 scale per output channel — roughly doubles the bandwidth roofline at a
 small, measured quality cost. This is the serving-side counterpart of
 the int8 gradient ring (parallel/quantized.py): same symmetric
@@ -93,7 +92,7 @@ def quantize_weights_int8(params: Dict[str, Any],
 
 def weight_bytes(params: Dict[str, Any]) -> int:
     """Total parameter bytes as stored — the numerator of the decode
-    bandwidth roofline (bench.py uses this so the int8 row's roofline
-    reflects the actual quantized stream)."""
+    bandwidth roofline, so that an int8 model's roofline reflects the
+    actual quantized stream."""
     return sum(x.size * x.dtype.itemsize
                for x in jax.tree.leaves(params))

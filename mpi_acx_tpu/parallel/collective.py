@@ -55,7 +55,7 @@ def halo_exchange_1d(x: jax.Array, axis_name: str, halo: int) -> jax.Array:
     `halo` rows from both ring neighbors.
 
     TPU-native counterpart of the reference's partitioned halo use-case
-    (BASELINE.json configs[1]): the neighbor's boundary block arrives as
+    (its 8-partition 1D halo config): the neighbor's boundary block arrives as
     one fused collective-permute instead of per-partition MPI messages.
     """
     top = x[:halo]          # my first rows -> left neighbor's bottom halo
@@ -66,8 +66,8 @@ def halo_exchange_1d(x: jax.Array, axis_name: str, halo: int) -> jax.Array:
 
 def halo_exchange_2d(x: jax.Array, row_axis: str, col_axis: str,
                      halo: int) -> jax.Array:
-    """2D halo exchange (periodic) over a 2D mesh (BASELINE.json
-    configs[2]): rows first, then columns of the already-padded block — so
+    """2D halo exchange (periodic) over a 2D mesh (the reference's
+    2D 5-point stencil config): rows first, then columns of the already-padded block — so
     edge halos carry the 4 axis neighbors and corner cells carry the
     DIAGONAL neighbors' corners (sufficient for 9-point as well as 5-point
     stencils).

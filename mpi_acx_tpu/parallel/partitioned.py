@@ -17,7 +17,8 @@ Two shapes are provided:
   ring-partitioned.cu's mark_ready/wait_until_arrived pair.
 * :func:`partitioned_pipeline` — produce-send-consume: a producer makes
   partition k while partition k-1 is in flight, the exact overlap pattern
-  pipeline-parallel microbatch exchange needs (BASELINE.json configs[3,4]).
+  pipeline-parallel microbatch exchange needs (the reference's driver
+  configs 3 and 4, SURVEY.md).
 
 Host-plane partitioned channels (real out-of-order Pready across process
 boundaries) live in the native runtime: mpi_acx_tpu.runtime.psend_init.

@@ -2,7 +2,7 @@
 
 The reference positions partitioned P2P as the substrate for
 pipeline-parallel microbatch exchange (SURVEY.md §2 "Parallelism
-strategies"; BASELINE.json configs[3,4]). This module is that application,
+strategies"). This module is that application,
 TPU-native: a GPipe-style schedule where each pipeline stage is one slice
 of the mesh's 'pp' axis, activations travel stage->stage+1 by
 collective-permute on ICI, and the whole schedule is a single

@@ -54,7 +54,7 @@ def ring_psum(x: jax.Array, axis_name: str,
     ``quantize=True`` sends every hop as int8 + per-block f32 scales
     (~4x less wire traffic, the EQuARX scheme); ``quantize=False`` sends
     raw f32 — the EXACT all-reduce on the IDENTICAL hop schedule, which
-    is what bench.py's wire-byte comparison measures against (one
+    is what a wire-byte comparison measures against (one
     skeleton, so the two variants cannot silently diverge).
     """
     n = lax.axis_size(axis_name)

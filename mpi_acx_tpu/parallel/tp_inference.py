@@ -816,8 +816,7 @@ def make_tp_speculative_generate(draft_cfg, cfg, mesh: Mesh, n_new: int,
     ``_check_moe_target``; a tight-capacity DRAFT is legal, so its
     dispatch must stay bit-equal). Forcing ``"sharded"`` raises at
     trace time when any call's token count is indivisible (same rule
-    as plain TP MoE serving); a compiled FLOP/wire comparison of the
-    modes is recorded in BASELINE.md.
+    as plain TP MoE serving).
 
     Returns a jitted ``generate(draft_params, params, prompt, key) ->
     (tokens [1, S+n_new], stats)`` with stats as in

@@ -6,10 +6,10 @@ device half: XLA/TPU profiler capture and honest wall-clock step
 statistics. The reference's only observability is printf-with--DDEBUG
 (SURVEY.md §5.1/§5.5) — both halves here exceed it.
 
-Timing rule (BASELINE.md): host-side per-call timing of sub-ms device work measures dispatch RTT,
-not the device. ``StepTimer`` forces a ``block_until_ready`` sync per
+Timing rule: host-side per-call timing of sub-ms device work measures
+dispatch RTT, not the device. ``StepTimer`` forces a ``block_until_ready`` sync per
 step so each sample is a true device round-trip; for sub-ms kernels use
-a device-side rep loop (bench.py's methodology) instead.
+a device-side rep loop, or the profiler's trace, instead.
 """
 
 from __future__ import annotations

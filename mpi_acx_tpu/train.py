@@ -1,7 +1,7 @@
 """Distributed training step: dp x pp x tp/sp in one shard_map program.
 
 The flagship composition of the framework's primitives (the counterpart of
-the reference's driver configs, BASELINE.json configs[3,4]):
+the reference's pipeline-exchange driver configs):
 
 * **pp** — pipeline stages over the 'pp' mesh axis; microbatch activations
   travel stage->stage by collective permute
@@ -109,7 +109,7 @@ def _llama_block_sp_tp(cfg, lp: Dict[str, Any], h: jax.Array,
                        tp_axis: str) -> jax.Array:
     """Llama block (RMSNorm + RoPE + GQA + SwiGLU), sequence-parallel
     attention + tensor-parallel MLP — the Llama-family counterpart of
-    :func:`_block_sp_tp` (BASELINE.json configs[4]).
+    :func:`_block_sp_tp`.
 
     h: [mb, S, d] replicated over tp. lp's w_gate/w_up/w_down are the
     LOCAL tp slices of the SwiGLU FFN; attention weights are replicated.

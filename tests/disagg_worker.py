@@ -8,7 +8,7 @@ prefill rank ships per-layer KV handoffs, the decode ranks splice,
 generate, and then each VERIFIES its outputs bit-for-bit against a
 local monolithic ``serve_greedy(..., kv_int8=True)`` of the same
 requests. Prints ``DISAGG_OK`` / ``DISAGG_SHIPPED`` plus one
-``DISAGG_ROW {json}`` line per rank (the bench child parses these).
+``DISAGG_ROW {json}`` line per rank.
 
 Under the chaos leg the prefill rank is killed mid-handoff and
 respawned by the acx_chaos supervisor; the respawn re-runs this script
@@ -16,11 +16,11 @@ from rid 0 — re-shipping is idempotent (decode discards duplicates by
 rid) — and the decode ranks requeue the torn handoff UNCHARGED.
 
 Knobs: ACX_DISAGG_OVERLAP=0 ships only after the full prompt pass (the
-bench baseline), ACX_DISAGG_PREFILL_INT8=1 uses the quantize-at-compute
+A/B baseline), ACX_DISAGG_PREFILL_INT8=1 uses the quantize-at-compute
 prefill cache variant, ACX_DISAGG_REQS scales the request count, and
 ACX_DISAGG_BIG=1 switches to a wider model + longer prompts so the
 exposed-ship time (the wire cost per-layer overlap hides) is well above
-clock noise for the bench A/B.
+clock noise for an overlap A/B.
 """
 
 import json

@@ -11,7 +11,7 @@ and each decode rank runs ``run_decode_worker(page_tokens=...)``, then
 VERIFIES its outputs bit-for-bit against a local monolithic
 ``serve_greedy(..., kv_int8=True)`` of the same requests. Prints
 ``DISAGG_OK`` / ``DISAGG_SHIPPED`` plus one ``PAGED_ROW {json}`` line
-per rank (bench.py's paged dryrun child parses these).
+per rank.
 
 Under the chaos leg the prefill rank is killed mid-handoff and
 respawned by the acx_chaos supervisor; re-shipping is idempotent

@@ -180,28 +180,6 @@ def test_disagg_midhandoff_kill_requeues_uncharged(rt, setup):
     assert dis.metrics.per_request[1].retries == 0  # uncharged
 
 
-def test_disagg_midhandoff_fault_charged(rt, setup):
-    """A non-peer-loss handoff failure charges the retry budget but
-    still restarts bit-equal."""
-    from mpi_acx_tpu.models.disagg import serve_disagg_greedy
-    cfg, params, prompts, n_new, fns, mono = setup
-    fired = []
-
-    def ship_fault(rid, layer):
-        if rid == 3 and layer == 1 and not fired:
-            fired.append((rid, layer))
-            raise RuntimeError("injected mid-handoff failure")
-
-    dis = serve_disagg_greedy(params, cfg, prompts, n_new, n_slots=2,
-                              max_len=64, server_fns=fns, rt=rt,
-                              ship_fault=ship_fault,
-                              max_request_retries=2)
-    assert fired == [(3, 1)]
-    _assert_parity(mono, dis)
-    assert dis.metrics.per_request[3].retries == 1
-    assert dis.metrics.peer_requeues == 0
-
-
 def test_fleet_roles_parsing(monkeypatch):
     from mpi_acx_tpu.models.disagg import fleet_roles
     monkeypatch.delenv("ACX_ROLE", raising=False)

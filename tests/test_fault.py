@@ -162,34 +162,6 @@ def _tiny_prompts(cfg, n=5):
             for i in range(n)]
 
 
-def test_serving_requeues_after_step_failure():
-    """A step_fn that raises once mid-stream: active requests restart
-    from scratch and the final outputs equal the failure-free serve bit
-    for bit (greedy determinism + emitted-token reset)."""
-    from mpi_acx_tpu.models import serving
-    cfg, params, tfm = _tiny()
-    prompts = _tiny_prompts(cfg)
-    want = serving.serve_greedy(params, cfg, prompts, n_new=6, n_slots=2,
-                                max_len=32, family=tfm)
-
-    fns = serving.make_server_fns(params, cfg, tfm)
-    prefill_fn, step_fn, scatter_fn, chunk, kv8, smp = fns
-    calls = {"n": 0}
-
-    def flaky_step(cache, tok, keys):
-        calls["n"] += 1
-        if calls["n"] == 2:
-            raise RuntimeError("injected device step failure")
-        return step_fn(cache, tok, keys)
-
-    got = serving.serve_greedy(
-        params, cfg, prompts, n_new=6, n_slots=2, max_len=32, family=tfm,
-        server_fns=(prefill_fn, flaky_step, scatter_fn, chunk, kv8, smp))
-    assert calls["n"] > 2, "failure fired before the loop finished"
-    for w, g in zip(want, got):
-        np.testing.assert_array_equal(w, g)
-
-
 def test_serving_persistent_failure_raises_with_rid():
     """Past max_request_retries the failure propagates, naming the
     request — a permanently broken step can't spin the server."""

@@ -1,8 +1,8 @@
 """Int8 KV cache (ops/kvquant.py): long-context decode streams the
 cache, not the weights — int8 codes + per-(position, head) scales halve
 that stream. These tests pin quality and mechanics on CPU; the
-bandwidth claim is measured on-chip by bench.py's decode child
-(decode_longctx_* rows).
+bandwidth claim is not measured (PERF.md question 2: the int8-page
+cell).
 """
 
 import jax

@@ -1,8 +1,8 @@
 """Pallas device-side ops: flag signaling kernels + flash attention.
 
 On the CPU test mesh these run through Pallas interpret mode — the exact
-same kernel bodies that compile via Mosaic on a real TPU chip (bench.py /
-entry() exercise the compiled path)."""
+same kernel bodies that compile via Mosaic on a real TPU chip (chip_smoke.py and
+tests/test_tpu_compile.py exercise the compiled path)."""
 
 import jax
 import jax.numpy as jnp

@@ -5,7 +5,7 @@ server is pinned by bit-equality against per-request generate().
 The reference has no serving stack (SURVEY.md §0); this is
 framework-goal surface. The throughput claim (no drain bubble at mixed
 output lengths) is structural — covered here by the refill bookkeeping
-test; wall-clock lands via bench on the chip.
+test; the wall clock is the benchmark's (benchmarks/run.py, on the chip).
 """
 
 import jax
@@ -389,32 +389,287 @@ def test_serving_telemetry():
         assert r.tokens_per_s > 0
 
 
-def test_serving_telemetry_counts_requeues():
-    """A request whose step failed and was re-queued shows up in the
-    telemetry (requeues, per-request retries) — and the batch still
-    completes bit-equal."""
-    cfg, params, mod = _gpt2()
-    fns = serving.make_server_fns(params, cfg, mod)
-    prefill_fn, step_fn, scatter_fn = fns[0], fns[1], fns[2]
-    boom = {"n": 0}
+# -- RequestBook: the rules every serve loop shares, without a model --------
 
-    def flaky_step(slots, tok, keys):
-        boom["n"] += 1
-        if boom["n"] == 2:
-            raise RuntimeError("injected step failure")
-        return step_fn(slots, tok, keys)
 
-    prompts = _prompts(jax.random.key(23), 3, cfg.vocab, lens=[4, 6])
-    got = serving.serve_greedy(
-        params, cfg, prompts, 4, n_slots=2, max_len=24, family=mod,
-        server_fns=(prefill_fn, flaky_step, scatter_fn) + fns[3:])
-    m = got.metrics
-    assert m.requeues >= 1
-    assert sum(r.retries for r in m.per_request) >= 1
-    for p, g in zip(prompts, got):
-        want = mod.generate(params, cfg, jnp.asarray(p)[None], 4,
-                            max_len=24)
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(want)[0])
+def _book(n_req=4, n_slots=3, n_new=3, eos=None, chunk=2, retries=1, **kw):
+    prompts = [np.arange(1, 3 + rid, dtype=np.int32) for rid in range(n_req)]
+    return serving.RequestBook(prompts, [n_new] * n_req, n_slots, eos,
+                               chunk, retries, **kw)
+
+
+def _seat_heads(book, n, first=50):
+    for b in range(n):
+        book.seat(b, book.queue.popleft(), first + b)
+
+
+def test_book_charged_requeue_past_budget_raises():
+    """A charged requeue spends the request's budget and, past
+    max_request_retries, raises naming the request; the stream and the
+    TTFT are reset and the request is back at the END of the queue."""
+    book = _book(retries=1)
+    _seat_heads(book, 1)
+    boom = RuntimeError("boom")
+    book.owner[0] = -1
+    book.requeue(0, boom)
+    assert book.attempts[0] == 1 and book.requeues == 1
+    assert book.emitted[0] == [] and book.ttft[0] is None
+    assert list(book.queue) == [1, 2, 3, 0]
+    with pytest.raises(RuntimeError, match="request 0 failed 2 time"):
+        book.requeue(0, boom)
+    assert book.requeues == 1           # the raising attempt queued nothing
+
+
+def test_book_uncharged_requeue_does_not_count():
+    """Peer loss is not the request's fault: with a budget of ZERO any
+    number of uncharged requeues pass, none moves ``attempts``."""
+    book = _book(retries=0)
+    for _ in range(5):
+        book.queue.remove(2)
+        book.requeue(2, RuntimeError("peer dead"), charge=False)
+    assert book.attempts[2] == 0
+    assert (book.requeues, book.peer_requeues) == (5, 5)
+    assert list(book.queue) == [0, 1, 3, 2]
+
+
+@pytest.mark.parametrize("lost_peer,shed", [(False, True), (True, True),
+                                            (True, False)],
+                         ids=["charged", "peer-sheds", "peer-keeps-width"])
+def test_book_step_failed_requeues_every_active_slot(lost_peer, shed):
+    """After a failed step every active slot's request goes back on the
+    queue IN SLOT ORDER with its stream cleared and the step's input
+    zeroed; a peer-loss failure charges nobody and sheds the highest
+    idle slot, unless the caller keeps its width (the loopback)."""
+    book = _book(n_req=5, n_slots=4)
+    book.seat(2, 0, 7)                  # slots 0 and 2 active, 1 and 3 idle
+    book.seat(0, 1, 8)
+    del book.queue[0], book.queue[0]
+    book.deliver(np.array([[9, 0, 9, 0]], np.int32), 0.01)
+    exc = RuntimeError("tpu-acx: peer dead (error=20)" if lost_peer
+                       else "wedged device")
+    book.step_failed(exc, shed=shed)
+    assert list(book.queue) == [2, 3, 4, 1, 0]      # slot 0's rid first
+    assert book.emitted[0] == [] and book.emitted[1] == []
+    assert not book.active() and not book.last_tok.any()
+    assert book.requeues == 2
+    assert book.peer_requeues == (2 if lost_peer else 0)
+    assert [book.attempts[r] for r in (0, 1)] == ([0, 0] if lost_peer
+                                                   else [1, 1])
+    assert book.owner == ([-1, -1, -1, -2] if lost_peer and shed
+                          else [-1] * 4)
+    assert book.slots_shed == int(lost_peer and shed)
+
+
+def test_book_shed_takes_highest_idle_and_never_the_last():
+    book = _book(n_req=4, n_slots=3)
+    book.seat(1, book.queue.popleft(), 5)   # slot 1 busy: not sheddable
+    book.shed_slot()
+    assert book.owner == [-1, 0, -2]        # highest IDLE slot
+    book.shed_slot()
+    assert book.owner == [-2, 0, -2]
+    book.shed_slot()                        # one alive, and it is busy
+    assert book.owner == [-2, 0, -2] and book.slots_shed == 2
+    lone = _book(n_slots=1)
+    lone.shed_slot()                        # a server of one slot keeps it
+    assert lone.owner == [-1] and lone.slots_shed == 0
+    assert lone.free_slot() == 0 and book.free_slot() is None
+
+
+def test_book_revives_on_a_rise_of_the_fleet_only(monkeypatch):
+    """Shed slots come back when ``_fleet_active()`` RISES: not on the
+    fall that preceded it, not while it is level, and not at all when
+    no native runtime answered at the book's birth."""
+    fleet = {"active": 4}
+    monkeypatch.setattr(serving, "_fleet_active", lambda: fleet["active"])
+    book = _book(n_slots=3)
+    book.shed_slot()
+    book.shed_slot()
+    assert book.owner == [-1, -2, -2]
+    assert book.revive() == []              # level
+    fleet["active"] = 3
+    assert book.revive() == []              # the fall lowers the watermark
+    assert book.revive() == []
+    fleet["active"] = 4                     # back to where it STARTED: a rise
+    assert book.revive() == [1, 2]
+    assert book.owner == [-1, -1, -1] and book.slots_revived == 2
+    fleet["active"] = 5
+    assert book.revive() == [] and book.slots_revived == 2  # nothing shed
+    monkeypatch.setattr(serving, "_fleet_active", lambda: None)
+    dormant = _book(n_slots=2)
+    dormant.shed_slot()
+    monkeypatch.setattr(serving, "_fleet_active", lambda: 9)
+    assert dormant.revive() == [] and dormant.owner == [-1, -2]
+
+
+@pytest.mark.parametrize("eos,block,want,finished", [
+    (None, [[4], [5], [6]], [50, 4, 5], True),      # length: n_new = 3
+    (5, [[4], [5], [6]], [50, 4, 5], True),         # eos as the last token
+    (4, [[4], [5], [6]], [50, 4], True),            # eos before the length
+    (50, [[4], [5], [6]], [50], True),              # the FIRST token is eos
+    (9, [[4]], [50, 4], False),                     # neither, yet
+], ids=["length", "eos-at-length", "eos-early", "eos-first", "running"])
+def test_book_slot_finished_and_deliver_stop_at_the_end(eos, block, want,
+                                                        finished):
+    """``slot_finished`` on length and on eos; ``deliver`` stops a slot
+    at its end MID-CHUNK (later tokens of the block are dropped) and
+    still feeds the block's last row to the next step."""
+    book = _book(n_req=1, n_slots=2, n_new=3, eos=eos, chunk=len(block))
+    _seat_heads(book, 1)
+    block = np.asarray([[t[0], 77] for t in block], np.int32)
+    book.deliver(block, 0.03)
+    assert book.emitted[0] == want
+    assert book.slot_finished(0) is finished
+    assert list(book.last_tok) == [block[-1, 0], 77]    # idle slot too
+    assert book.decode_slot_steps == len(block) * 2
+    assert book.decode_tokens == len(want) - 1
+    assert len(book.itl_samples) == len(want) - 1
+    assert book.steps == 1
+
+
+def test_book_streams_finish_and_metrics_over_its_own_rids():
+    """on_token sees the first token and every delivered one; a rejected
+    request keeps its marker and has no telemetry row; ``rids`` narrows
+    queue, rows and ``requests`` to a rank's share; finish_request
+    returns prompt + emitted and idles the slot."""
+    seen = []
+    rej = {1: serving.RequestRejected(1, "exceeds_max_len", "x")}
+    book = _book(n_req=6, n_slots=2, n_new=2, chunk=1, rejected=rej,
+                 rids=[1, 3, 5], on_token=lambda rid, t: seen.append((rid, t)))
+    assert list(book.queue) == [3, 5] and book.done[1] is rej[1]
+    _seat_heads(book, 2, first=40)
+    book.sample_gauges()
+    book.deliver(np.array([[8, 9]], np.int32), 0.02)
+    assert seen == [(3, 40), (5, 41), (3, 8), (5, 9)]
+    assert book.finish_request(1) == 5 and book.finish_request(0) == 3
+    np.testing.assert_array_equal(book.done[5], [1, 2, 3, 4, 5, 6, 7, 41, 9])
+    assert book.owner == [-1, -1] and book.done[0] is None
+    m = book.metrics(preemptions=3)
+    assert [r.rid for r in m.per_request] == [3, 5]
+    assert (m.requests, m.rejections, m.new_tokens) == (3, 1, 4)
+    assert m.rejection_reasons == {"exceeds_max_len": 1}
+    assert (m.prefills, m.steps, m.preemptions) == (2, 1, 3)
+    assert m.decode_tokens == 2 and m.step_utilization == 1.0
+    assert m.slot_occupancy_mean == 1.0 and m.queue_depth_max == 0
+    assert all(0 < r.ttft_s <= r.latency_s <= m.wall_s
+               for r in m.per_request)
+
+
+# -- one injected failure through each single-process entry point -----------
+
+
+@pytest.fixture(scope="module")
+def loopback_rt():
+    from mpi_acx_tpu import runtime
+    r = runtime.Runtime()
+    yield r
+    r.finalize()
+
+
+def _fail_nth(fn, nth, exc):
+    """``fn`` that raises ``exc`` on its nth call, once."""
+    calls = {"n": 0}
+
+    def flaky(*a, **kw):
+        calls["n"] += 1
+        if calls["n"] == nth:
+            raise exc
+        return fn(*a, **kw)
+    flaky.calls = calls
+    return flaky
+
+
+def _serve_with_failure(entry, failure, exc, monkeypatch, request):
+    """Serve 5 requests through 2 slots, clean and then with one
+    injected failure: ``refill`` = the 3rd prefill / handoff raises,
+    ``step`` = the 2nd decode step raises."""
+    cfg = tfm.tiny_config(vocab=61, d_model=48, n_heads=4, n_layers=2,
+                          d_ff=96, max_seq=64)
+    params = tfm.init_params(jax.random.key(5), cfg)
+    prompts = _prompts(jax.random.key(29), 5, cfg.vocab, lens=[4, 9, 6])
+    kw = dict(n_new=[5, 3, 6, 4, 5], n_slots=2, max_len=32)
+    if entry == "greedy":
+        fns = serving.make_server_fns(params, cfg, tfm)
+
+        def run(fail):
+            prefill_fn, step_fn = fns[0], fns[1]
+            if fail == "refill":
+                prefill_fn = _fail_nth(prefill_fn, 3, exc)
+            elif fail == "step":
+                step_fn = _fail_nth(step_fn, 2, exc)
+            return serving.serve_greedy(
+                params, cfg, prompts, family=tfm,
+                server_fns=(prefill_fn, step_fn) + fns[2:], **kw)
+    elif entry == "paged":
+        from mpi_acx_tpu.models import kvpage
+
+        def run(fail):
+            with monkeypatch.context() as m:
+                if fail == "refill":
+                    m.setattr(serving, "paged_prefill",
+                              _fail_nth(serving.paged_prefill, 3, exc))
+                elif fail == "step":
+                    make = kvpage.make_paged_step_fn
+                    m.setattr(kvpage, "make_paged_step_fn",
+                              lambda *a, **k: _fail_nth(make(*a, **k), 2,
+                                                        exc))
+                return serving.serve_paged_greedy(
+                    params, cfg, prompts, family=tfm, page_tokens=8, **kw)
+    else:
+        from mpi_acx_tpu.models.disagg import serve_disagg_greedy
+        rt = request.getfixturevalue("loopback_rt")
+        fns = serving.make_server_fns(params, cfg, tfm, kv_int8=True)
+
+        def run(fail):
+            step_fn, handoffs = fns[1], {"n": 0}
+
+            def ship_fault(rid, layer):
+                if layer == 1:
+                    handoffs["n"] += 1
+                    if handoffs["n"] == 3:
+                        raise exc
+            if fail == "step":
+                step_fn = _fail_nth(step_fn, 2, exc)
+            return serve_disagg_greedy(
+                params, cfg, prompts, rt=rt,
+                server_fns=(fns[0], step_fn) + fns[2:],
+                ship_fault=ship_fault if fail == "refill" else None, **kw)
+    return run(None), run(failure)
+
+
+@pytest.mark.parametrize("failure", ["refill", "step", "step-peer-loss"])
+@pytest.mark.parametrize("entry", ["greedy", "paged", "disagg"])
+def test_one_failure_costs_a_replay_through_every_entry_point(
+        entry, failure, monkeypatch, request):
+    """The same injected failure through serve_greedy,
+    serve_paged_greedy and the disagg loopback: outputs equal the clean
+    run's bit for bit, and the accounting is the book's through each. A
+    refill that fails once is charged to its request alone; a failed
+    step requeues the two active requests, charged, or — peer-loss
+    shaped — uncharged with one slot shed, except in the loopback,
+    which has no peer whose loss shrinks it."""
+    from mpi_acx_tpu import runtime
+    lost_peer = failure == "step-peer-loss"
+    exc = (runtime.AcxPeerDeadError(
+        "tpu-acx: peer dead (error=20, source=1, tag=0)",
+        runtime.ERR_PEER_DEAD, 1, 0) if lost_peer
+        else RuntimeError("injected failure"))
+    clean, got = _serve_with_failure(entry, failure.split("-")[0], exc,
+                                     monkeypatch, request)
+    for i, (w, g) in enumerate(zip(clean, got)):
+        np.testing.assert_array_equal(w, g, err_msg=f"request {i}")
+    m, retries = got.metrics, [r.retries for r in got.metrics.per_request]
+    assert clean.metrics.requeues == 0 and clean.metrics.prefills == 5
+    if failure == "refill":
+        assert (m.requeues, m.peer_requeues, m.slots_shed) == (1, 0, 0)
+        assert retries == [0, 0, 1, 0, 0]   # the 3rd prefill was rid 2's
+        assert m.prefills == 5              # successful refills only
+    else:
+        assert m.requeues == 2, m           # both slots were active
+        assert m.peer_requeues == (2 if lost_peer else 0)
+        assert sum(retries) == (0 if lost_peer else 2)
+        assert m.slots_shed == int(lost_peer and entry != "disagg")
+        assert m.prefills == 7              # the two victims refilled twice
+    assert m.slots_revived == 0 and m.new_tokens == clean.metrics.new_tokens
 
 
 # -- RollingSLO window semantics (docs/DESIGN.md §13/§20) -------------------

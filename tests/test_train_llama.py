@@ -1,5 +1,5 @@
 """Llama family through the dp x pp x tp/sp distributed train step
-(BASELINE.json configs[4]): the parallel composition must compute EXACTLY
+(the reference's Llama-3-8B pipeline config): the parallel composition must compute EXACTLY
 the same step as the single-device Llama implementation — RoPE with
 global positions on sequence shards, GQA broadcast before ring attention,
 SwiGLU tensor-parallel reduction, and the family's untied unembed head

@@ -1,7 +1,7 @@
 """Int8 weight-only quantization (ops/wquant.py): the decode-roofline
 optimization — weight bytes halve, so the bandwidth-bound decode floor
-drops ~2x (BASELINE.md decode row; measured on-chip via bench.py's
-decode child). These tests pin the quality and mechanics on CPU:
+drops ~2x (no benchmark cell serves int8 weights yet: not measured).
+These tests pin the quality and mechanics on CPU:
 
 * quantized logits stay close to bf16 logits (per-channel int8 bound),
 * greedy decode on a TRAINED model emits the same tokens (quantization

@@ -227,7 +227,7 @@ def audit_knobs(root, allow):
     test_only = knob_allow.get("test_only", {})
     not_knobs = knob_allow.get("not_knobs", {})
     # Documented knobs whose only read sites are outside the audited dirs
-    # (e.g. bench.py at the repo root). Still real knobs — just consumed
+    # (e.g. tests/disagg_worker.py). Still real knobs — just consumed
     # beyond the surface this rule scans.
     external = knob_allow.get("external_readers", {})
 

@@ -1,5 +1,5 @@
 // tpu-acx host-plane benchmark: enqueued ping-pong latency + partitioned
-// bandwidth (the two BASELINE.md metrics the reference never published).
+// bandwidth (two metrics the reference never published).
 //
 // Run under `acxrun -np 2 build/bench_pingpong [msg_bytes]`.
 // Rank 0 prints one parseable line:
@@ -114,8 +114,8 @@ int main(int argc, char** argv) {
   // one-way windowed stream per message size, receiver preposted so every
   // striped message takes the direct zero-copy delivery path. ACX_STRIPES
   // is fixed at transport construction, so one process measures ONE lane
-  // count; the harness (tools/bench.py) sweeps lane counts across runs and
-  // pairs the rows. Run with ACX_RV_THRESHOLD=0 so large messages take the
+  // count; a caller sweeps lane counts across runs and pairs the rows.
+  // Run with ACX_RV_THRESHOLD=0 so large messages take the
   // eager (striping) path rather than rendezvous.
   if (getenv("ACX_BENCH_STRIPE_SWEEP") != nullptr) {
     const char* stripes_s = getenv("ACX_STRIPES");
