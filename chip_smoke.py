@@ -352,6 +352,10 @@ def phase_serve_paged(size: Size = FULL, seed: int = 0,
              prefix_hits=outs.metrics.prefix_hits,
              pages_hwm=outs.metrics.pages_hwm,
              kv_write_path=outs.metrics.paged_kv_write,
+             attend_built=outs.metrics.paged_decode_attend,
+             attend_live_page_share=round(
+                 outs.metrics.attend_live_share, 4),
+             attend_pages_walked=outs.metrics.attend_pages_walked,
              programs_traced=traced,
              token_mismatch_vs_dense=_mismatch_share(outs, ref, prompts),
              **row)

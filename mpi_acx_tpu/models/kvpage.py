@@ -327,7 +327,7 @@ def paged_decode_step(params, cfg, state, token, page_tokens: int,
     off = pos % page_tokens
 
     write = select_paged_kv_write(cfg.decode_flash, page_tokens)
-    attend = select_paged_decode_attend(cfg.decode_flash)
+    attend = select_paged_decode_attend(cfg.decode_flash, page_tokens)
 
     def body(carry, i):
         x, pools = carry
@@ -552,6 +552,15 @@ class PagedKV:
             [[self._park[b]] * self.max_pages
              for b in range(self.n_slots)], np.int32)
         self._dev_table = None
+
+    def live_pages(self, chunk: int) -> int:
+        """Pages one layer's attends fetch over the next ``chunk``
+        steps: every slot, owned or idle, walks its ``pos`` and reads
+        ``pos // page_tokens + 1`` pages a step, at most its table row
+        (an idle slot's are its parking page, again and again)."""
+        ahead = self.pos[:, None] + np.arange(chunk)[None]
+        return int(np.minimum(ahead // self.page_tokens + 1,
+                              self.max_pages).sum())
 
     # -- table bookkeeping -------------------------------------------------
 

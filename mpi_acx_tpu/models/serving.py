@@ -95,6 +95,15 @@ class ServingMetrics:
     pages_hwm: int = 0            # paged: pool pages-in-use high-water mark
     paged_kv_write: str = ""      # paged: the write the step program was built
                                   # with (flash_decode.select_paged_kv_write)
+    # paged: the attend the step program was built with
+    # (flash_decode.select_paged_decode_attend), and what its walk had to
+    # do, counted on the host at each chunk's start (PagedKV.live_pages):
+    # the pages a layer's attends fetch over the chunk, every slot
+    # walking its ``pos``, and ``n_slots x max_pages x chunk``, the
+    # steps of the (slot, page) grid the kernel ran until PR 30.
+    paged_decode_attend: str = ""
+    attend_pages_walked: int = 0
+    attend_pages_grid: int = 0
     slo_deferrals: int = 0        # paged: refills deferred by the SLO gate
     ttft_p50_s: float = 0.0
     ttft_p99_s: float = 0.0
@@ -118,6 +127,13 @@ class ServingMetrics:
     # (kvpage.programs_traced): 10-20 in a process's first call, 0 in
     # every later one with the same static arguments and shapes.
     programs_traced: int = 0
+
+    @property
+    def attend_live_share(self) -> float:
+        """Share of the old (slot, page) grid's steps that had a page
+        to fetch: what the live-page walk is left with."""
+        return (self.attend_pages_walked / self.attend_pages_grid
+                if self.attend_pages_grid else 0.0)
 
     @property
     def step_utilization(self) -> float:
@@ -1137,7 +1153,8 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
     slot-steps delivered one; per request, ``queue_wait_s`` (entry ->
     start of the refill that seated it), ``prefill_s`` and
     ``refill_host_s`` are that refill's spans."""
-    from mpi_acx_tpu.ops.flash_decode import select_paged_kv_write
+    from mpi_acx_tpu.ops.flash_decode import (select_paged_decode_attend,
+                                              select_paged_kv_write)
     from mpi_acx_tpu.profiling import Phases
 
     ph = Phases()
@@ -1183,7 +1200,7 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
     # Per request, of the refill that seated it: (queue_wait_s,
     # prefill_s, refill_host_s), the first on the entry clock.
     refill_times = [(0.0, 0.0, 0.0)] * len(prompts)
-    n_preempts = n_slo_defer = 0
+    n_preempts = n_slo_defer = pages_walked = pages_grid = 0
     # Requests currently evicted by page pressure: membership here turns
     # the next successful seat into a journey "resume" event.
     preempted_rids: set = set()
@@ -1388,6 +1405,8 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
         if not book.active():
             continue                # grow_for_chunk preempted everyone
         with ph("chunk.upload", step=step_no) as upload:
+            pages_walked += pkv.live_pages(chunk)
+            pages_grid += n_slots * max_pages * chunk
             state = pkv.device_state()
         with ph("chunk.step", step=step_no) as stepped:
             try:
@@ -1421,6 +1440,10 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
             pages_hwm=pkv.pages_hwm,
             paged_kv_write=select_paged_kv_write(cfg.decode_flash,
                                                  pt).__name__,
+            paged_decode_attend=select_paged_decode_attend(
+                cfg.decode_flash, pt).__name__,
+            attend_pages_walked=pages_walked,
+            attend_pages_grid=pages_grid,
             slo_deferrals=n_slo_defer,
             programs_traced=kvpage.programs_traced() - traced_at_entry)
         for r in metrics.per_request:

@@ -8,13 +8,20 @@ token, even when a slot sits at position 40 of 4096. This kernel replaces
 that read with an online softmax over K/V blocks that is
 
 * **length-aware** — the per-slot ``pos`` vector is scalar-prefetched
-  into SMEM; the grid walks ``(slot, K/V block)`` and the block index
-  map clamps at the slot's last LIVE block ``ceil((pos + W) / block_k)
-  - 1``. Pallas re-fetches a block only when its index changes, so the
-  dead tail of a cache row never crosses HBM->VMEM, and the steps past
-  the horizon skip their compute. HBM traffic is O(live length), not
-  O(max_len), and the block DMAs are double-buffered against compute by
-  the pipeline.
+  into SMEM and only the ``ceil((pos + W) / block_k)`` LIVE blocks of a
+  slot cross HBM->VMEM, double-buffered against the fold: HBM traffic
+  is O(live length), not O(max_len). One fold (:func:`_fold_block`),
+  two walks. A contiguous cache row is walked by a ``(slot, K/V block)``
+  grid whose index map clamps at the last live block (Pallas re-fetches
+  a block only when its index changes; a dead step fetches and computes
+  nothing but is still a grid step). A page pool is walked by the
+  kernel itself: a grid of ``(slot,)``, inside a step a loop over the
+  slot's live pages, each copied out of the pool (left whole in HBM)
+  with ``make_async_copy`` into one of two VMEM buffers while the page
+  before it is folded, the next slot's first page started behind this
+  slot's last. Nothing is paid per dead page: the ``(32, 8)`` grid it
+  replaces paid 0.30 us for each dead step, 60 us of a 126 us call
+  (PERF.md, PR 30).
 * **GQA-native** — q ``[B, W, Hkv, n_rep, D]`` rides as
   ``[B, Hkv, W*n_rep, D]`` (row ``i`` is window slot ``i // n_rep``),
   attending the UN-repeated KV groups directly; one grid step carries
@@ -29,12 +36,12 @@ that read with an online softmax over K/V blocks that is
   (``sum_d q_d*(K_kd*s_k) == (sum_d q_d*K_kd)*s_k``).
 * **window-capable** — W > 1 for the speculative-decode window passes,
   and ``pos`` scalar or ``[B]`` for continuous-batching serving.
-* **paged or contiguous** — one kernel body: block j is either tokens
+* **paged or contiguous** — one fold: block j is either tokens
   ``[j*block_k, (j+1)*block_k)`` of the slot's own cache row or page
   ``(layer, table[b, j])`` of the WHOLE pool ``[L, P, Hkv, D,
-  page_tokens]`` (models/kvpage.py), resolved in the index map from two
-  more scalar-prefetched operands: the block table and the layer
-  index. The step program never slices a layer out of the pool — a
+  page_tokens]`` (models/kvpage.py), addressed from two more
+  scalar-prefetched operands: the block table and the layer index. The
+  step program never slices a layer out of the pool — a
   ``dynamic_index_in_dim`` there is a copy of 98 MB a layer a step at
   GPT-2 XL's 240 pages, and was 93% of a decode step (PERF.md, PR 25).
 * **written in place** — :func:`paged_kv_write` is the step's other
@@ -99,81 +106,153 @@ def _n_live(pos, W, block_k, n_k):
     return jnp.minimum((pos + W + block_k - 1) // block_k, n_k)
 
 
-def _decode_kernel(*refs, block_k, n_rep, n_k, quant, n_prefetch, scale):
-    """One (batch slot, K/V block) grid step: fold block j of every KV
-    head of this slot into the online-softmax state held in VMEM
-    scratch across the sequential block dimension.
-
-    ``pos_ref`` ([B], SMEM) bounds the live blocks; steps at or past
-    ``n_live`` neither fetch (the index map repeats the last live
-    block) nor compute. Every live block masks with the ABSOLUTE row
-    positions ``pos + i // n_rep`` (row i of the [W*n_rep, D] q tile is
-    window slot i // n_rep); on fully visible blocks the mask is all
-    true. With ``quant`` the K/V blocks are int8 codes and their scales
-    [Hkv, 1, block_k] multiply the scores / probabilities."""
-    pos_ref = refs[0]       # block table and layer after it: the index map's
-    q_ref, k_ref, v_ref, *refs = refs[n_prefetch:]
+def _fold_block(q_ref, kb, vb, scales, j, pos, m_scr, l_scr, acc_scr, *,
+                block_k, n_rep, scale):
+    """THE fold, for both walks: K/V block ``j`` of every KV head of
+    one slot (``kb``/``vb`` ``[Hkv, D, block_k]``; ``scales`` is ``(ks,
+    vs)``, each ``[Hkv, 1, block_k]``, when they are int8 codes, else
+    empty) goes into the online-softmax state held in VMEM scratch. ``q_ref[0]`` is the
+    slot's ``[Hkv, W*n_rep, D]`` tile. The mask is on ABSOLUTE
+    positions: row i is window slot ``i // n_rep`` at ``pos + i //
+    n_rep``, column c of block j is cache position ``j*block_k + c``;
+    on fully visible blocks it is all true. The scales multiply the
+    SMALL tensors: K's the scores, V's the probabilities."""
+    quant = bool(scales)
+    Wn = q_ref.shape[-2]
+    # Pre-scale q once (the _flash_kernel idiom); on the quant path
+    # q stays f32 to dot against the f32-converted codes exactly.
+    q = q_ref[0].astype(jnp.float32) * scale                # [Hkv, Wn, D]
     if quant:
-        ks_ref, vs_ref, *refs = refs
-    o_ref, m_scr, l_scr, acc_scr = refs
+        kb, vb = kb.astype(jnp.float32), vb.astype(jnp.float32)
+        prec = jax.lax.Precision.HIGHEST
+    else:
+        q = q.astype(q_ref.dtype)
+        prec = (jax.lax.Precision.HIGHEST
+                if q_ref.dtype == jnp.float32
+                else jax.lax.Precision.DEFAULT)
+    s = jax.lax.dot_general(
+        q, kb, (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32, precision=prec)
+    if quant:
+        s = s * scales[0]                                   # [Hkv, Wn, bk]
+    rows = pos + jax.lax.broadcasted_iota(
+        jnp.int32, (1, Wn, 1), 1) // n_rep
+    cols = j * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (1, 1, block_k), 2)
+    s = jnp.where(rows >= cols, s, _NEG_INF)
+    m = m_scr[...]
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m - m_new)
+    l_scr[...] = corr * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
+    if quant:
+        p = p * scales[1]
+    acc_scr[...] = corr * acc_scr[...] + jax.lax.dot_general(
+        p.astype(vb.dtype), vb, (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32, precision=prec)
+    m_scr[...] = m_new
+
+
+def _fold_init(m_scr, l_scr, acc_scr):
+    m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+
+def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, *refs, block_k, n_rep,
+                   n_k, scale):
+    """The contiguous walk: one (batch slot, K/V block) grid step folds
+    block j of the slot's own cache row. ``pos_ref`` ([B], SMEM) bounds
+    the live blocks; steps at or past ``n_live`` neither fetch (the
+    index map repeats the last live block) nor compute, but each is
+    still a grid step. ``refs``: the two scale blocks of an int8
+    cache, if any, then the output and the fold's state."""
+    *scale_refs, o_ref, m_scr, l_scr, acc_scr = refs
     j = pl.program_id(1)
     pos = pos_ref[pl.program_id(0)]
-    Wn = q_ref.shape[2]
 
     @pl.when(j == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        _fold_init(m_scr, l_scr, acc_scr)
 
-    @pl.when(j < _n_live(pos, Wn // n_rep, block_k, n_k))
+    @pl.when(j < _n_live(pos, q_ref.shape[2] // n_rep, block_k, n_k))
     def _fold():
-        # Pre-scale q once (the _flash_kernel idiom); on the quant path
-        # q stays f32 to dot against the f32-converted codes exactly.
-        q = q_ref[0].astype(jnp.float32) * scale            # [Hkv, Wn, D]
-        kb, vb = k_ref[0], v_ref[0]                         # [Hkv, D, bk]
-        if quant:
-            kb, vb = kb.astype(jnp.float32), vb.astype(jnp.float32)
-            prec = jax.lax.Precision.HIGHEST
-        else:
-            q = q.astype(q_ref.dtype)
-            prec = (jax.lax.Precision.HIGHEST
-                    if q_ref.dtype == jnp.float32
-                    else jax.lax.Precision.DEFAULT)
-        s = jax.lax.dot_general(
-            q, kb, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32, precision=prec)
-        if quant:
-            s = s * ks_ref[0]                               # [Hkv, Wn, bk]
-        rows = pos + jax.lax.broadcasted_iota(
-            jnp.int32, (1, Wn, 1), 1) // n_rep
-        cols = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, block_k), 2)
-        s = jnp.where(rows >= cols, s, _NEG_INF)
-        m = m_scr[...]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m - m_new)
-        l_scr[...] = corr * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
-        if quant:
-            p = p * vs_ref[0]
-        acc_scr[...] = corr * acc_scr[...] + jax.lax.dot_general(
-            p.astype(vb.dtype), vb, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32, precision=prec)
-        m_scr[...] = m_new
+        _fold_block(q_ref, k_ref[0], v_ref[0], [r[0] for r in scale_refs],
+                    j, pos, m_scr, l_scr, acc_scr,
+                    block_k=block_k, n_rep=n_rep, scale=scale)
 
     @pl.when(j == n_k - 1)
     def _finish():
         o_ref[0] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
 
 
+def _paged_walk_kernel(pos_ref, table_ref, layer_ref, q_ref, *refs,
+                       page_tokens, n_rep, n_k, n_pools, scale):
+    """The paged walk: one grid step a slot, and inside it one pass
+    over the slot's LIVE pages and nothing per dead page. The pools
+    stay in HBM (``refs[:n_pools]``: K, V, and the scale pools of an
+    int8 cache); the kernel copies page ``(layer, table[b, j])`` of
+    each into one of two VMEM buffers a pool and folds it while the
+    next page's copies are in flight. The next page of a slot's LAST
+    page is the next slot's FIRST: it is already on its way when that
+    slot's grid step starts, as a BlockSpec pipeline would have had it
+    (32 exposed copy latencies a call otherwise). ``walked`` (SMEM)
+    counts the pages folded so far in the call: its parity is the
+    buffer the current page sits in, across slots. Needs sequential
+    grid steps (``arbitrary``)."""
+    pools, (o_ref, *refs) = refs[:n_pools], refs[n_pools:]
+    bufs, (sem, m_scr, l_scr, acc_scr, walked) = (refs[:n_pools],
+                                                  refs[n_pools:])
+    b, B = pl.program_id(0), pl.num_programs(0)
+    layer, pos = layer_ref[0], pos_ref[b]
+    n = _n_live(pos, q_ref.shape[2] // n_rep, page_tokens, n_k)
+
+    def copies(slot, j, buf):
+        page = table_ref[slot, j]
+        return [pltpu.make_async_copy(pool.at[layer, page], dst.at[buf],
+                                      sem.at[i, buf])
+                for i, (pool, dst) in enumerate(zip(pools, bufs))]
+
+    @pl.when(b == 0)
+    def _first_page_of_the_call():
+        walked[0] = 0
+        for c in copies(0, 0, 0):
+            c.start()
+
+    first = walked[0]
+    _fold_init(m_scr, l_scr, acc_scr)
+
+    def page(j, _):
+        buf = (first + j) % 2
+        more = j + 1 < n
+
+        @pl.when(more | (b + 1 < B))
+        def _next_page():
+            for c in copies(jnp.where(more, b, b + 1),
+                            jnp.where(more, j + 1, 0), 1 - buf):
+                c.start()
+
+        for c in copies(b, j, buf):
+            c.wait()
+        kb, vb, *scales = [dst[buf] for dst in bufs]
+        _fold_block(q_ref, kb, vb, scales, j, pos, m_scr, l_scr, acc_scr,
+                    block_k=page_tokens, n_rep=n_rep, scale=scale)
+
+    jax.lax.fori_loop(0, n, page, None)
+    walked[0] = first + n
+    o_ref[0] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
+
+
 def _decode_call(q, k, v, pos, n_rep, block_k, n_k, table=None, layer=None):
-    """The one pallas_call behind both kernels. ``k``/``v`` are K/V
+    """The pallas_call behind both kernels. ``k``/``v`` are K/V
     arrays or (codes, scales) tuples in cache layout
     ([B, Hkv, *, max_len]) or, with ``table``, pool layout: the whole
     pool ([L, P, Hkv, *, page_tokens]) with ``layer`` (a traced scalar
     is fine) naming the layer to read, or one layer of it
-    ([P, Hkv, *, page_tokens]) without."""
+    ([P, Hkv, *, page_tokens]) without. What the code observes
+    (``table is not None``) chooses the walk: a cache row's blocks by a
+    ``(B, n_k)`` grid, a slot's live pages by the kernel's own copies
+    out of the pool; the fold is one function."""
     ks = vs = None
     if isinstance(k, tuple):
         k, ks = k
@@ -197,58 +276,57 @@ def _decode_call(q, k, v, pos, n_rep, block_k, n_k, table=None, layer=None):
     pos = jnp.asarray(pos, jnp.int32)
     if pos.ndim == 0:
         pos = jnp.full((B,), pos, jnp.int32)
-    prefetch = [pos]
-    if table is not None:
-        # One layer's pool is a pool of one layer (a free reshape of
-        # major dimensions), so the paged index map has one form.
-        if layer is None:
-            layer = 0
-            k, v = k[None], v[None]
-            if quant:
-                ks, vs = ks[None], vs[None]
-        prefetch += [jnp.asarray(table, jnp.int32),
-                     jnp.asarray(layer, jnp.int32).reshape(1)]
 
     # [B, W, Hkv, n_rep, D] -> [B, Hkv, W*n_rep, D]: row i = w*n_rep + r
     # so the kernel recovers the window slot as i // n_rep.
     qg = q.reshape(B, W, Hkv, n_rep, D).transpose(0, 2, 1, 3, 4).reshape(
         B, Hkv, Wn, D)
+    q_spec = pl.BlockSpec((1, Hkv, Wn, D), lambda b, *_: (b, 0, 0, 0))
+    fold_state = [pltpu.VMEM((Hkv, Wn, 1), jnp.float32),
+                  pltpu.VMEM((Hkv, Wn, 1), jnp.float32),
+                  pltpu.VMEM((Hkv, Wn, D), jnp.float32)]
+    operands = [qg, k, v] + ([ks, vs] if quant else [])
+    static = dict(n_rep=n_rep, n_k=n_k, scale=1.0 / D ** 0.5)
 
-    def kv_index(b, j, pos_ref, *paged):
-        jj = jnp.minimum(j, _n_live(pos_ref[b], W, block_k, n_k) - 1)
-        if paged:
-            table_ref, layer_ref = paged
-            return (layer_ref[0], table_ref[b, jj], 0, 0, 0)
-        return (b, 0, 0, jj)
+    if table is None:
+        def kv_spec(rows):
+            """One slot's block of every KV head: [1, Hkv, rows, bk]."""
+            return pl.BlockSpec(
+                (1, Hkv, rows, block_k),
+                lambda b, j, pos_ref: (b, 0, 0, jnp.minimum(
+                    j, _n_live(pos_ref[b], W, block_k, n_k) - 1)))
 
-    def kv_spec(rows):
-        """One slot's block of every KV head: [1, Hkv, rows, block_k] in
-        the kernel either way (the pool's layer dimension is squeezed)."""
-        block = (1, Hkv, rows, block_k)
-        return pl.BlockSpec(block if table is None else (None,) + block,
-                            kv_index)
+        prefetch = [pos]
+        kernel = functools.partial(_decode_kernel, block_k=block_k,
+                                   **static)
+        grid, semantics = (B, n_k), ("parallel", "arbitrary")
+        in_specs = ([q_spec] + [kv_spec(D)] * 2
+                    + [kv_spec(1)] * (2 if quant else 0))
+        scratch = fold_state
+    else:
+        # One layer's pool is a pool of one layer (a free reshape of
+        # major dimensions), so the kernel addresses one form.
+        if layer is None:
+            layer = 0
+            operands[1:] = [p[None] for p in operands[1:]]
+        pools = operands[1:]
+        prefetch = [pos, jnp.asarray(table, jnp.int32),
+                    jnp.asarray(layer, jnp.int32).reshape(1)]
+        kernel = functools.partial(_paged_walk_kernel, page_tokens=block_k,
+                                   n_pools=len(pools), **static)
+        grid, semantics = (B,), ("arbitrary",)
+        in_specs = [q_spec] + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools)
+        scratch = ([pltpu.VMEM((2,) + p.shape[2:], p.dtype) for p in pools]
+                   + [pltpu.SemaphoreType.DMA((len(pools), 2))]
+                   + fold_state + [pltpu.SMEM((1,), jnp.int32)])
 
-    q_spec = pl.BlockSpec((1, Hkv, Wn, D), lambda b, j, *_: (b, 0, 0, 0))
-    in_specs = [q_spec] + [kv_spec(D)] * 2
-    operands = [qg, k, v]
-    if quant:
-        in_specs += [kv_spec(1)] * 2
-        operands += [ks, vs]
-
-    kernel = functools.partial(
-        _decode_kernel, block_k=block_k, n_rep=n_rep, n_k=n_k,
-        quant=quant, n_prefetch=len(prefetch), scale=1.0 / D ** 0.5)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(prefetch), grid=(B, n_k),
-            in_specs=in_specs, out_specs=q_spec,
-            scratch_shapes=[pltpu.VMEM((Hkv, Wn, 1), jnp.float32),
-                            pltpu.VMEM((Hkv, Wn, 1), jnp.float32),
-                            pltpu.VMEM((Hkv, Wn, D), jnp.float32)]),
+            num_scalar_prefetch=len(prefetch), grid=grid,
+            in_specs=in_specs, out_specs=q_spec, scratch_shapes=scratch),
         out_shape=_out_struct((B, Hkv, Wn, D), q.dtype, q, k, v),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         interpret=interpret,
         # What the device trace prints the kernel as, by variant.
         name=("paged_" if table is not None else "") + "flash_decode_attend",
@@ -307,12 +385,13 @@ def paged_flash_decode_attend(q, kp, vp, table, pos, page_tokens, n_rep,
     """Pallas paged decode attention: K/V pools ``[P, Hkv, D,
     page_tokens]`` (plus (codes, scales) tuples for int8 pools) addressed through
     a ``[B, max_pages]`` block table — or, with ``layer``, the whole
-    pools ``[L, P, Hkv, D, page_tokens]``, of which the index map reads
-    layer ``layer`` in place. Block size IS the page size, so
+    pools ``[L, P, Hkv, D, page_tokens]``, of which the kernel copies
+    layer ``layer``'s live pages out in place (the live-page walk,
+    :func:`_paged_walk_kernel`). Block size IS the page size, so
     at ``block_k == page_tokens`` this and :func:`flash_decode_attend`
-    run identical FLOPs over identical block values (one kernel body).
-    Compiled for the chip, a page that is not a multiple of 128 tokens
-    raises."""
+    run identical FLOPs over identical block values (one fold): every
+    row is bit-equal. Compiled for the chip, a page that is not a
+    multiple of 128 tokens raises."""
     return _decode_call(q, kp, vp, pos, n_rep, page_tokens,
                         table.shape[1], table=table, layer=layer)
 
@@ -323,27 +402,22 @@ def _paged_kernels_fit(page_tokens):
     return backend.on_tpu() and page_tokens % 128 == 0
 
 
-def auto_paged_decode_attend(q, kp, vp, table, pos, page_tokens, n_rep,
-                             layer=None):
-    """Paged auto policy: the Pallas paged kernel on TPU when Mosaic
-    can tile the page (page_tokens % 128 == 0); the gather-dense
-    reference elsewhere — on CPU a dense einsum beats an interpreted
-    kernel, and gather-dense is also the bit-equality anchor."""
-    attend = (paged_flash_decode_attend if _paged_kernels_fit(page_tokens)
-              else paged_gather_attend)
-    return attend(q, kp, vp, table, pos, page_tokens, n_rep, layer=layer)
-
-
-def select_paged_decode_attend(decode_flash):
+def select_paged_decode_attend(decode_flash, page_tokens):
     """The paged arm of the ``select_attention`` idiom, keyed on the
-    same ``decode_flash`` config field: ``None`` -> auto, ``True`` ->
-    paged Pallas kernel, ``False`` -> gather-dense reference. All
-    returned callables take
-    ``(q, kp, vp, table, pos, page_tokens, n_rep, layer=None)``."""
+    same ``decode_flash`` config field: ``None`` -> the Pallas paged
+    kernel on a TPU when Mosaic can tile the page (``page_tokens % 128
+    == 0``) and the gather-dense reference elsewhere (on CPU a dense
+    einsum beats an interpreted kernel, and gather-dense is also the
+    bit-equality anchor); ``True`` -> the kernel (interpret mode
+    off-TPU); ``False`` -> the reference. Both take ``(q, kp, vp,
+    table, pos, page_tokens, n_rep, layer=None)``; the chosen
+    function's ``__name__`` is what
+    ``ServingMetrics.paged_decode_attend`` records
+    (``paged_flash_decode_attend`` is the live-page walk, all or
+    nothing)."""
     if decode_flash is None:
-        return auto_paged_decode_attend
-    return (paged_flash_decode_attend if decode_flash
-            else paged_gather_attend)
+        decode_flash = _paged_kernels_fit(page_tokens)
+    return paged_flash_decode_attend if decode_flash else paged_gather_attend
 
 
 def _kv_write_kernel(layer_ref, page_ref, off_ref, *refs):
@@ -451,8 +525,8 @@ def paged_kv_write_dense(pools, fresh, layer, write_page, off):
 def select_paged_kv_write(decode_flash, page_tokens):
     """The write arm of the same idiom, keyed on the same
     ``decode_flash`` field and choosing as the attend does: ``None`` ->
-    :func:`paged_kv_write` where :func:`auto_paged_decode_attend` takes
-    the Pallas kernel (on a TPU, ``page_tokens % 128 == 0``) and the
+    :func:`paged_kv_write` where :func:`select_paged_decode_attend`
+    takes the Pallas kernel (on a TPU, ``page_tokens % 128 == 0``) and the
     dense write elsewhere; ``True`` -> the kernel (interpret mode
     off-TPU); ``False`` -> the dense write. Both take
     ``(pools, fresh, layer, write_page, off)``; the chosen function's

@@ -141,9 +141,10 @@ def test_paged_flash_bit_equals_fixed_flash(kind, posmode, shared):
 def test_select_paged_decode_attend_dispatch():
     """Same contract as select_decode_attend: None -> auto, True ->
     kernel, False -> gather reference."""
-    assert select_paged_decode_attend(True) is paged_flash_decode_attend
-    assert select_paged_decode_attend(False) is paged_gather_attend
-    auto = select_paged_decode_attend(None)
+    assert select_paged_decode_attend(True, PT) is paged_flash_decode_attend
+    assert select_paged_decode_attend(False, PT) is paged_gather_attend
+    auto = select_paged_decode_attend(None, PT)
+    assert auto is paged_gather_attend                   # off the chip
     q, kc, vc = _fixed_case(n_rep=1, W=1, kind="bf16")
     _, _, pk, pv, table = _paginate(kc, vc)
     out = auto(q, pk, pv, table, 10, PT, 1)
@@ -177,6 +178,122 @@ def test_whole_pool_attend_bit_equals_per_layer(kind, posmode):
         np.testing.assert_array_equal(np.asarray(got, np.float32),
                                       np.asarray(ref, np.float32),
                                       err_msg=attend.__name__)
+
+
+# --------------------------------------------------------------------------
+# the live-page walk: one pass over each slot's pages, copied out of the
+# pool by the kernel itself, the next slot's first page in flight while
+# the last page of this one is folded
+
+WALK_PAGES = 8
+# Slot by slot: an idle slot at pos 0 whose whole table row is its
+# parking page; 1 page then all 8 then 1 again (the cross-slot
+# prefetch, both ways round); a horizon that ends ON a page boundary
+# (pos + 1 == 2 pages exactly) and one a token past it (one visible
+# column of page 4); the last position of the cache.
+WALK_POS = [0, 5, WALK_PAGES * PT - 2, PT - 1, 2 * PT - 1, 3 * PT,
+            WALK_PAGES * PT - 1]
+WALK_IDLE = 0
+
+
+def _walk_case(kind, n_rep, with_layer, seed=3):
+    """(q, pools k and v, table, pos, layer, fixed caches): random
+    pools [L, P, Hkv, *, PT] and a ragged table over them; every
+    table column past a slot's live pages points at a NaN page (bf16)
+    or a page of extreme codes (int8), which a walk that touched a dead
+    page would fold in. The fixed caches hold the same rows, gathered
+    page by page."""
+    rng = np.random.default_rng(seed)
+    nb, L = len(WALK_POS), 3 if with_layer else 1
+    P = nb * WALK_PAGES + nb + 1                 # + parking + the dead page
+    dead, park = P - 1, nb * WALK_PAGES + WALK_IDLE
+    shape = (L, P, Hkv, D, PT)
+    if kind == "int8":
+        def pool():
+            codes = rng.integers(-127, 128, shape)
+            codes[:, dead] = 127
+            scales = rng.random((L, P, Hkv, 1, PT), np.float32) + 0.01
+            scales[:, dead] = 1e30
+            return jnp.asarray(codes, jnp.int8), jnp.asarray(scales)
+        q = jnp.asarray(rng.standard_normal((nb, 1, Hkv * n_rep, D)),
+                        jnp.float32)
+    else:
+        def pool():
+            vals = rng.standard_normal(shape).astype(np.float32)
+            vals[:, dead] = np.nan
+            return jnp.asarray(vals, jnp.bfloat16)
+        q = jnp.asarray(rng.standard_normal((nb, 1, Hkv * n_rep, D)),
+                        jnp.bfloat16)
+    kp, vp = pool(), pool()
+    pos = np.asarray(WALK_POS, np.int32)
+    table = rng.permutation(nb * WALK_PAGES).reshape(
+        nb, WALK_PAGES).astype(np.int32)
+    table[WALK_IDLE] = park
+    live = pos // PT + 1
+    for b in range(nb):
+        if b != WALK_IDLE:
+            table[b, live[b]:] = dead
+    layer = 1 if with_layer else None
+
+    def fixed(pool):
+        lyr = pool[layer if with_layer else 0]
+        t = jnp.moveaxis(jnp.take(lyr, jnp.asarray(table), axis=0), 1, 3)
+        return t.reshape(t.shape[:3] + (WALK_PAGES * PT,))
+
+    if not with_layer:
+        kp, vp = jax.tree.map(lambda p: p[0], (kp, vp))
+    caches = tuple(jax.tree.map(fixed, p if with_layer else
+                                jax.tree.map(lambda a: a[None], p))
+                   for p in (kp, vp))
+    return q, kp, vp, jnp.asarray(table), jnp.asarray(pos), layer, caches
+
+
+@pytest.mark.parametrize("with_layer", [False, True],
+                         ids=["one-layer", "whole-pool"])
+@pytest.mark.parametrize("n_rep", [1, 2])
+@pytest.mark.parametrize("kind", ["bf16", "int8"])
+def test_live_page_walk_bit_equals_the_block_grid(kind, n_rep, with_layer):
+    """The paged kernel's walk (a grid step a slot, the slot's live
+    pages copied out of the pool two buffers deep) against the
+    contiguous kernel's ``(B, n_k)`` grid at ``block_k == page_tokens``
+    on the same values: one fold, the same pages in the same order, so
+    every row is BIT-equal — idle, one page, all pages, page-boundary
+    horizons alike — and no dead page is folded (they hold NaN). And
+    against the gather-dense reference on live rows of finite pages."""
+    q, kp, vp, table, pos, layer, (kc, vc) = _walk_case(kind, n_rep,
+                                                        with_layer)
+    got = paged_flash_decode_attend(q, kp, vp, table, pos, PT, n_rep,
+                                    layer=layer)
+    assert got.shape == (len(WALK_POS), 1, Hkv * n_rep * D)
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    ref = flash_decode_attend(q, kc, vc, pos, WALK_PAGES * PT, n_rep,
+                              block_k=PT)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(ref, np.float32))
+    # The dense reference multiplies the dead pages' NaN by 0: compare
+    # it on a table whose dead columns repeat the slot's first page.
+    alive = jnp.where(jnp.arange(WALK_PAGES)[None] <= (pos // PT)[:, None],
+                      table, table[:, :1])
+    dense = paged_gather_attend(q, kp, vp, alive, pos, PT, n_rep,
+                                layer=layer)
+    tol = 2e-4 if kind == "int8" else 4e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(dense, np.float32),
+                               rtol=tol, atol=tol)
+
+
+def test_live_page_walk_takes_a_speculative_window():
+    """W > 1 (the speculative window passes): the walk's horizon is
+    ``pos + W`` and each row masks on its own position, as the block
+    grid's does; bit-equal to it."""
+    W, n_rep = 3, 2
+    q, kc, vc = _fixed_case(n_rep=n_rep, W=W, kind="bf16", seed=13)
+    kc, vc, pk, pv, table = _paginate(kc, vc)
+    pos = jnp.array([0, PT - 2, MAX_LEN - W], jnp.int32)   # straddles a page
+    ref = flash_decode_attend(q, kc, vc, pos, MAX_LEN, n_rep, block_k=PT)
+    got = paged_flash_decode_attend(q, pk, pv, table, pos, PT, n_rep)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(ref, np.float32))
 
 
 # --------------------------------------------------------------------------
@@ -320,10 +437,31 @@ def test_select_paged_kv_write_follows_the_attend(on_tpu, page_tokens,
     assert write is (paged_kv_write if kernel else paged_kv_write_dense)
     if decode_flash is None:
         assert flash_decode._paged_kernels_fit(page_tokens) is kernel
+    # ... and the attend a serve call builds and records is its partner
+    assert select_paged_decode_attend(decode_flash, page_tokens) is (
+        paged_flash_decode_attend if kernel else paged_gather_attend)
 
 
 # --------------------------------------------------------------------------
 # allocator / trie / PagedKV units
+
+
+@pytest.mark.parametrize("pos,chunk,want", [
+    ([0, 0, 0], 1, 3),                        # idle: the parking page each
+    ([0, 0, 0], 8, 24),
+    ([7, 8, 31], 1, 1 + 2 + 4),               # pos // 8 + 1 pages a slot
+    ([7, 0, 0], 2, (1 + 2) + 2 + 2),          # slot 0 crosses into page 2
+    ([31, 40, 100], 4, 3 * 4 * 4),            # never past the table row
+])
+def test_live_pages_counts_what_the_walk_fetches(pos, chunk, want):
+    """PagedKV.live_pages: a layer's attends over the next chunk, every
+    slot walking its pos (ServingMetrics.attend_pages_walked)."""
+    cfg = tfm.tiny_config(vocab=31, d_model=16, n_heads=2, n_layers=1,
+                          d_ff=32, max_seq=32)
+    pkv = kvpage.PagedKV(cfg, tfm, n_slots=3, max_len=32, page_tokens=8,
+                         n_pages=12)
+    pkv.pos[:] = pos
+    assert pkv.live_pages(chunk) == want
 
 
 def test_allocator_deterministic_and_refcounted():
@@ -638,6 +776,12 @@ def test_serve_paged_phases_cover_the_call_and_count_the_decode_work():
                                       err_msg=f"request {i}")
     m = paged.metrics
     assert m.paged_kv_write == "paged_kv_write_dense"    # off the chip
+    assert m.paged_decode_attend == "paged_gather_attend"
+    # the live-page walk's work, whichever attend ran: max_pages = 4
+    assert m.attend_pages_grid == m.steps * chunk * n_slots * 4
+    assert n_slots * chunk * m.steps <= m.attend_pages_walked
+    assert m.attend_live_share == m.attend_pages_walked / m.attend_pages_grid
+    assert 0.25 <= m.attend_live_share < 0.75           # prompts of 3-12
     assert set(m.phase_s) == set(m.phase_n) == set(PHASES)
     assert all(v >= 0 for v in m.phase_s.values())
     # between two spans the clock is not read: ~8 us of a span's own

@@ -138,6 +138,8 @@ CASES = {
     "decode_fixed_int8": lambda: (_fixed, _decode_args("int8", False)),
     "decode_paged_bf16": lambda: (_paged, _decode_args("bf16", True)),
     "decode_paged_int8": lambda: (_paged, _decode_args("int8", True)),
+    "decode_paged_xl_bf16": lambda: _xl_attend("bf16"),
+    "decode_paged_xl_int8": lambda: _xl_attend("int8"),
     "flags_pready": lambda: (flags.pready, [_FLAGS, _IDX]),
     "flags_pready_many": lambda: (flags.pready_many, [_FLAGS, _IDXS]),
     "flags_parrived": lambda: (flags.parrived, [_FLAGS, _IDX]),
@@ -198,6 +200,20 @@ def _xl_pools(kind, n_layers=XL_L):
         scales = _s(pool[:3] + (1, PAGE), jnp.float32)
         return (_s(pool, jnp.int8),) * 2 + (scales,) * 2
     return (_s(pool, jnp.bfloat16),) * 2
+
+
+def _xl_attend(kind):
+    """The live-page walk alone at the serving cells' geometry: the
+    whole pools (+ the f32 scale pools when int8) left in HBM, a traced
+    layer index, pages copied out by the kernel."""
+    pools = _xl_pools(kind)
+    kv = (pools[0], pools[2]) if kind == "int8" else pools[0]
+    return (lambda q, k, v, table, pos, layer:
+            flash_decode.paged_flash_decode_attend(q, k, v, table, pos,
+                                                   PAGE, 1, layer=layer),
+            [_s((XL_B, 1, XL_H, D), jnp.bfloat16), kv, kv,
+             _s((XL_B, MAX_LEN // PAGE), jnp.int32), _s((XL_B,), jnp.int32),
+             _s((), jnp.int32)])
 
 
 def _compiled_write(write, kind, v5e):
@@ -361,13 +377,36 @@ def test_paged_decode_chunk_moves_no_pool(kind, v5e):
     calls are in the program, once each, and no instruction copies,
     slices or updates an array the size of a layer's pool or of the
     pool. The one-token write once did exactly that: 93% of a decode
-    step on the chip (PERF.md, PR 25), invisible off it."""
+    step on the chip (PERF.md, PR 25), invisible off it. The attend is
+    ONE custom call with the single result ``bf16[32,25,1,64]`` (what
+    the benchmark's roofline reader matches), and its grid is the
+    slots alone: no step a (slot, page) pair, live or dead."""
     paged_decode_chunk, args = _xl_chunk(kind)
     pools = _xl_pools(kind)
-    text = jax.jit(paged_decode_chunk, donate_argnums=(1,)).lower(
-        *_place(args, v5e)).compile().as_text()
-    calls = re.findall(r"%([a-z_]+)[.0-9]* = .*custom_call_target="
-                       r"\"tpu_custom_call\"", text)
-    assert sorted(calls) == ["paged_flash_decode_attend", "paged_kv_write"]
+    lowered = jax.jit(paged_decode_chunk, donate_argnums=(1,)).lower(
+        *_place(args, v5e))
+    text = lowered.compile().as_text()
+    calls = re.findall(r"%([a-z_]+)[.0-9]* = (.+?) custom-call\(.*"
+                       r"custom_call_target=\"tpu_custom_call\"", text)
+    assert sorted(name for name, _ in calls) == [
+        "paged_flash_decode_attend", "paged_kv_write"]
+    result = dict(calls)["paged_flash_decode_attend"]
+    assert result.startswith(f"bf16[{XL_B},{XL_H},1,{D}]{{"), result
     assert not _pool_movers(text, pools[0].shape), \
         "\n".join(_pool_movers(text, pools[0].shape))
+    grids = _pallas_grids(jax.make_jaxpr(paged_decode_chunk)(*args).jaxpr)
+    assert grids["paged_flash_decode_attend"] == [(XL_B,)]
+    assert grids["paged_kv_write"] == [(XL_B,)]
+
+
+def _pallas_grids(jaxpr, found=None):
+    """kernel name -> the grids of its pallas_calls, through every
+    nested jaxpr (the scans, the write's own jit)."""
+    found = {} if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.setdefault(eqn.params["name"], []).append(
+                tuple(eqn.params["grid_mapping"].grid))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _pallas_grids(sub, found)
+    return found
