@@ -14,7 +14,10 @@ One chip:
   serve_paged  serving.serve_paged_greedy at its defaults (128-token
                pages, radix prefix cache on), bf16 pool and int8 pool,
                twice (the second call traces no program), against the
-               dense reference configuration on the same chip
+               dense reference configuration on the same chip; then the
+               same loop over a small LFM2-MoE preset (conv state beside
+               GQA pages, routed experts): what the step was built from,
+               the share of experts a step reads, tails restored on hits
   serve_fixed  serving.serve_greedy at max_len 1024 (auto -> the Pallas
                decode kernel), and disagg.serve_disagg_greedy in loopback
                (native runtime, per-layer Pready/Parrived, int8 wire)
@@ -358,8 +361,57 @@ def phase_serve_paged(size: Size = FULL, seed: int = 0,
              attend_pages_walked=outs.metrics.attend_pages_walked,
              programs_traced=traced,
              token_mismatch_vs_dense=_mismatch_share(outs, ref, prompts),
+             paged_operator=outs.metrics.paged_operator,
+             paged_ffn=outs.metrics.paged_ffn,
              **row)
+    _serve_paged_lfm2(size, seed)
     return True
+
+
+def _serve_paged_lfm2(size: Size, seed: int):
+    """The same serve loop over a family of two operator kinds and two
+    FFN kinds (models/lfm2.py, a small preset: heads of 64 so that the
+    chip's kernels tile, 8 experts top 2): what the step program was
+    built from, what its routed FFN had to read, and that a radix hit
+    restores the conv layers' tails. Tokens against the dense
+    configuration of the same family."""
+    import jax
+
+    from mpi_acx_tpu.models import lfm2, serving
+    over = {} if size.tiny else dict(vocab=512, d_model=256, d_ff=512,
+                                     moe_d_ff=256)
+    cfg = lfm2.tiny_lfm2(max_seq=2 * size.max_len, **over)
+    params = lfm2.cast_params(lfm2.init_params(jax.random.key(seed), cfg))
+    prompts, n_new = _requests(size, cfg.vocab, seed)
+    kw = dict(n_slots=size.n_slots, max_len=size.max_len, family=lfm2,
+              chunk=size.chunk, page_tokens=size.page_tokens,
+              prefix_cache=True, max_request_retries=0)
+    with _Watch() as w:
+        outs = serving.serve_paged_greedy(params, cfg, prompts, n_new, **kw)
+    m = outs.metrics
+    tokens = _check_outputs(outs, prompts, n_new, m)
+    _require(m.prefix_hits >= 2 and m.conv_tail_restores >= 2,
+             f"prefix_hits={m.prefix_hits}, "
+             f"conv_tail_restores={m.conv_tail_restores}")
+    _require(0 < m.moe_live_expert_share <= 1 and m.moe_layer_steps > 0,
+             f"moe_live_expert_share={m.moe_live_expert_share}")
+    again = serving.serve_paged_greedy(params, cfg, prompts, n_new, **kw)
+    traced = [m.programs_traced, again.metrics.programs_traced]
+    _require(traced[0] > 0 and traced[1] == 0
+             and _mismatch_share(again, outs, prompts) == 0,
+             f"second serve call (lfm2): programs_traced={traced}")
+    ref = serving.serve_paged_greedy(params, _reference(cfg), prompts, n_new,
+                                     **kw)
+    _check_outputs(ref, prompts, n_new, ref.metrics)
+    emit(phase="serve_paged/lfm2", ok=True, tokens=tokens, **w.row(),
+         **_serve_stats(m), prefix_hits=m.prefix_hits,
+         conv_tail_restores=m.conv_tail_restores,
+         paged_operator=m.paged_operator, paged_ffn=m.paged_ffn,
+         moe_live_expert_share=round(m.moe_live_expert_share, 4),
+         moe_load_max_over_mean=round(m.moe_load_max_over_mean, 3),
+         kv_write_path=m.paged_kv_write, attend_built=m.paged_decode_attend,
+         programs_traced=traced,
+         token_mismatch_vs_dense=_mismatch_share(outs, ref, prompts))
 
 
 def _handoff_prefill_parity(params, cfg, size: Size, seed: int):

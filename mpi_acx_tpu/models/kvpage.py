@@ -48,17 +48,40 @@ different tensor shapes than the cold full-prompt pass, so hit-path
 outputs are deterministic per backend but not bitwise-pinned to the
 cold path (docs/DESIGN.md §19).
 
-The paged decode step is transformer-family-scoped (the
-``make_layerwise_prefill_fns`` precedent in models/disagg.py): it
-closes over the GPT-2 block internals. Other families raise loudly.
+What the plane asks of a model family it asks through ONE seam,
+:class:`PagedSpec` (``family.paged_spec(cfg)``): per layer an operator
+kind (attention | conv), an FFN kind (dense | moe) and a cache kind
+(pages | state), the layers grouped into :class:`Segment` s of whole
+periods that one ``lax.scan`` each can ride, and the family's own
+functions for each piece. :func:`paged_decode_step`,
+``serving.paged_prefill`` / ``paged_suffix_prefill`` and
+:class:`PagedKV` read nothing else of a family. GPT-2
+(``transformer.paged_spec``) is "every layer attention + dense + pages";
+``lfm2`` mixes both operator kinds and both FFN kinds. A family without
+``paged_spec`` raises by name.
+
+**Two kinds of state.** A layer whose cache kind is ``pages`` owns a
+layer of the pools above. A layer whose cache kind is ``state`` (a
+gated short conv: the last ``taps`` values of its gated input) owns a
+FIXED-size state a slot, ``conv`` ``[L_state, n_slots, *state_shape]``,
+carried by the decode step beside the pools; an idle slot's state is
+its own, as its parking page is. Because a radix hit, a COW copy or a
+resume continues a sequence from the END OF A WHOLE PAGE, the state at
+that point is kept WITH the page: ``pool['tail']`` ``[L_state, P,
+*state_shape]``, indexed by page id. ``scatter_prompt`` writes a page
+and its tail together, ``_copy`` copies both, a trie eviction frees
+both (the id), and a prefix hit starts the suffix prefill's state from
+the last matched page's tail (:meth:`PagedKV.restore_tail`). Only whole
+PROMPT pages enter the trie, so the decode step writes no tail.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import math
 import os
 from functools import partial
-from typing import Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -252,72 +275,211 @@ class RadixPrefixCache:
 # Device pool
 
 
+@dataclasses.dataclass(frozen=True)
+class LayerKind:
+    """What one layer is to the paged plane."""
+    operator: str = "attention"      # "attention" | "conv"
+    ffn: str = "dense"               # "dense" | "moe"
+    cache: str = "pages"             # "pages" | "state"
+
+
+@dataclasses.dataclass(frozen=True)
+class Segment:
+    """``repeats`` whole periods of layers that ride one ``lax.scan``:
+    ``params[key]`` holds the period's leaves stacked on a leading
+    ``[repeats]`` axis, as one layer's dict when the period is one
+    layer and as a tuple of dicts, one a layer of the period, else."""
+    key: str
+    period: Tuple[LayerKind, ...]
+    repeats: int
+
+    def count(self, cache: str) -> int:
+        return self.repeats * sum(k.cache == cache for k in self.period)
+
+
+def compress_layers(kinds, prefix: str = "seg") -> Tuple[Segment, ...]:
+    """Layers of unequal kind cannot ride one scan: group ``kinds``
+    (one :class:`LayerKind` a layer) greedily into runs of whole
+    periods, at each point the period whose repeats cover most layers
+    (the shortest of equals), so that the programs grow with the
+    number of DIFFERENT stretches and not with the depth. Published
+    LFM2-24B-A2B, 40 layers: (conv dense) x 2, (attn, conv, conv, conv
+    moe) x 9, (attn moe), (conv moe)."""
+    kinds, out, at = tuple(kinds), [], 0
+    while at < len(kinds):
+        best = (1, 1)
+        for p in range(1, (len(kinds) - at) // 2 + 1):
+            r = 1
+            while kinds[at + r * p:at + (r + 1) * p] == kinds[at:at + p]:
+                r += 1
+            if r > 1 and r * p > best[0] * best[1]:
+                best = (p, r)
+        p, r = best
+        out.append(Segment(f"{prefix}{len(out)}", kinds[at:at + p], r))
+        at += p * r
+    return tuple(out)
+
+
+@dataclasses.dataclass(frozen=True)
+class PagedSpec:
+    """What the paged plane asks of a family (module docstring). The
+    functions, with ``lp`` one layer's leaves and ``x`` ``[B, 1, d]``
+    in a decode step:
+
+    * ``embed(params, cfg, token [B], pos [B]) -> x``
+    * ``qkv(cfg, lp, x, pos) -> q [B, 1, Hq, Dh], k, v [B, 1, Hkv,
+      Dh]``, as they go into the cache (positions applied)
+    * ``attn_out(cfg, lp, x, o) -> x``: residual + output projection
+    * ``state_op(cfg, lp, x, st [B, *state_shape]) -> (x, st)``: the
+      operator of a ``state`` layer, residual included
+    * ``ffn(cfg, lp, x, kind) -> x``, and ``(x, idx [B, k])``, the
+      experts each row chose, when ``kind`` is ``"moe"``
+    * ``head(params, cfg, x) -> logits [B, vocab]`` f32
+    * ``prefill(params, cfg, tokens [1, S], last_index, kv_int8,
+      page_tokens) -> (logits [1, 1, vocab], one)``: ``one`` holds
+      ``'k','v'[,'ks','vs']`` ``[L_pages, 1, Hkv, *, S]`` in cache layout
+      and, with state layers, ``'tail'`` ``[L_state, S // page_tokens,
+      *state_shape]`` (the state at the end of every whole page) and
+      ``'end'`` ``[L_state, *state_shape]`` (at ``last_index``)
+    * ``suffix_prefill(params, cfg, suffix, hk, hv, tail, last_index,
+      kv_int8, page_tokens)``: the same for a suffix behind gathered
+      history ``hk``/``hv`` and the last matched page's ``tail``."""
+    segments: Tuple[Segment, ...]
+    n_kv_heads: int
+    head_dim: int
+    n_rep: int = 1                       # query heads a K/V head
+    state_shape: Tuple[int, ...] = ()    # one slot's state in a layer
+    n_experts: int = 0                   # of a "moe" FFN's router
+    # Leaves of a "moe" FFN that ``ffn`` is handed WHOLE, still stacked
+    # over the segment's repeats, with the repeat under ``lp["repeat"]``:
+    # a Pallas call cannot take one repeat's slice without a copy of it
+    # (the pools' lesson; at 403 MB an expert matrix stack, two thirds
+    # of a decode step: PERF.md, PR 31).
+    moe_whole: Tuple[str, ...] = ()
+    kv_int8: bool = True                 # int8 pages wired for it
+    # (ffn kind, implementation) pairs: ServingMetrics.paged_ffn
+    ffn_built: Tuple[Tuple[str, str], ...] = (("dense", "dense"),)
+    embed: Callable = None
+    qkv: Callable = None
+    attn_out: Callable = None
+    state_op: Callable = None
+    ffn: Callable = None
+    head: Callable = None
+    prefill: Callable = None
+    suffix_prefill: Callable = None
+
+    @property
+    def n_page_layers(self) -> int:
+        return sum(s.count("pages") for s in self.segments)
+
+    @property
+    def n_state_layers(self) -> int:
+        return sum(s.count("state") for s in self.segments)
+
+    def built(self, what: str) -> str:
+        """``ServingMetrics.paged_operator`` / ``paged_ffn``: the kinds
+        a step program of this family is built from."""
+        if what == "ffn":
+            return "+".join(f"{k}:{impl}" for k, impl in self.ffn_built)
+        return "+".join(sorted({k.operator for s in self.segments
+                                for k in s.period}))
+
+
+def paged_spec(family, cfg) -> PagedSpec:
+    """The family's :class:`PagedSpec` for ``cfg`` (``None``: GPT-2)."""
+    if family is None:
+        from mpi_acx_tpu.models import transformer as family  # noqa: N813
+    if not hasattr(family, "paged_spec"):
+        name = getattr(family, "__name__", repr(family)).rsplit(".", 1)[-1]
+        raise NotImplementedError(
+            f"paged KV serving asks a family for paged_spec(cfg) "
+            f"(kvpage.PagedSpec); family {name!r} gives none")
+    return family.paged_spec(cfg)
+
+
 def init_page_pool(cfg, n_pages: int, page_tokens: int, n_slots: int,
-                   kv_int8: bool = False):
+                   kv_int8: bool = False, spec: Optional[PagedSpec] = None):
     """Zeroed page pool: ``{'k','v': [L, P, H, Dh, page_tokens]}``
     (+ ``'ks','vs'`` [L, P, H, 1, page_tokens] f32 scale pages when
-    int8) with ``P = n_pages + n_slots`` — the trailing ``n_slots``
-    pages are the per-slot parking pages (module docstring), outside
-    the allocator. A pool IS a cache whose batch axis counts pages."""
+    int8) over the ``L`` layers whose cache kind is ``pages``, with
+    ``P = n_pages + n_slots`` — the trailing ``n_slots`` pages are the
+    per-slot parking pages (module docstring), outside the allocator.
+    A pool IS a cache whose batch axis counts pages. With state layers,
+    also ``'tail'`` ``[L_state, P, *state_shape]``: each page's tail."""
     from mpi_acx_tpu.models.decoding import new_kv_cache
-    pool = new_kv_cache(cfg.n_layers, n_pages + n_slots, cfg.n_heads,
-                        cfg.head_dim, page_tokens, cfg.dtype, kv_int8)
+    spec = spec or paged_spec(None, cfg)
+    pool = new_kv_cache(spec.n_page_layers, n_pages + n_slots,
+                        spec.n_kv_heads, spec.head_dim, page_tokens,
+                        cfg.dtype, kv_int8)
     del pool["pos"]
+    if spec.n_state_layers:
+        pool["tail"] = jnp.zeros((spec.n_state_layers, n_pages + n_slots)
+                                 + spec.state_shape, cfg.dtype)
     return pool
 
 
-_POOL_KEYS = ("k", "v", "ks", "vs")
-
-
-def _check_family(family) -> None:
-    name = getattr(family, "__name__", "").rsplit(".", 1)[-1]
-    if family is not None and name != "transformer":
-        raise NotImplementedError(
-            "paged KV serving closes over the GPT-2 block internals "
-            "(the make_layerwise_prefill_fns precedent); family "
-            f"{name!r} is not wired yet — use models.transformer")
+_POOL_KEYS = ("k", "v", "ks", "vs")        # what the decode step carries
+_PAGE_KEYS = _POOL_KEYS + ("tail",)        # what belongs to a page
 
 
 # --------------------------------------------------------------------------
-# Paged decode step (transformer family)
+# Paged decode step (any family, through its PagedSpec)
+
+
+def _moe_tally(idx, owns, n_experts: int):
+    """[4] int32 of one MoE layer's routing in one step, over the slots
+    that OWN a request: (token, expert) pairs routed, distinct experts
+    hit, the fullest expert's pairs, and 1 (the layer-steps counted).
+    An idle slot routes too and its experts are fetched: the first two
+    are a FLOOR on what the expert kernel computes and reads."""
+    hits = jnp.zeros((n_experts,), jnp.int32).at[idx].add(
+        owns.astype(jnp.int32)[:, None])
+    return jnp.stack([hits.sum(), (hits > 0).sum().astype(jnp.int32),
+                      hits.max(), jnp.int32(1)])
 
 
 def paged_decode_step(params, cfg, state, token, page_tokens: int,
-                      ffn=None):
-    """One autoregressive step against the page pool; mirrors
-    ``transformer.decode_step`` exactly (same _qkv/attend/ffn math, so
-    active slots are bit-equal to the fixed-slot step) with the cache
-    writes routed through the block table: layer i's fresh K/V for
-    slot b lands at ``pool[i, table[b, pos_b // pt], :, :, pos_b % pt]``.
-    ``state`` = pool keys + ``'table'`` [B, max_pages] + ``'pos'``
-    [B]. Idle slots write their parking page (their table rows point
-    nowhere else) and the page index is clipped so a long-idle slot's
-    walking pos can never index past its table row.
+                      family=None):
+    """One autoregressive step against the paged state, for any family
+    through its :class:`PagedSpec` (``family`` None: GPT-2, whose step
+    mirrors ``transformer.decode_step`` exactly — same _qkv/attend/ffn
+    math, so active slots are bit-equal to the fixed-slot step).
+    ``state`` = pool keys + ``'table'`` [B, max_pages] + ``'pos'`` [B]
+    and, for a family with state layers, ``'conv'`` [L_state, B,
+    *state_shape]; with ``'moe'`` [4] and ``'owns'`` [B] it also counts
+    its routing (:func:`_moe_tally`).
 
-    The pools ride the layer scan WHOLE: the write and the attend are
-    handed ``[L, P, H, *, pt]`` and the layer index, and on the chip
-    both are Pallas calls whose index maps address ``(layer, page)``
-    from prefetched scalars (ops/flash_decode.py: ``paged_kv_write``
-    updates the pool in place, a page's read-modify-write a slot;
-    ``paged_flash_decode_attend`` reads the live pages). Nothing here
-    may slice a layer out of a pool: that is a copy of the layer every
-    layer of every step. Off the chip, or at a page Mosaic cannot tile,
-    ``select_paged_kv_write`` / ``select_paged_decode_attend`` hand back
-    the dense pair (``.at[].set`` on a sliced layer, gather + dense
-    attend): the same values, the bit-equality anchor."""
-    from mpi_acx_tpu.models import transformer as tfm
+    An attention layer's fresh K/V for slot b lands at ``pool[i,
+    table[b, pos_b // pt], :, :, pos_b % pt]``, ``i`` counting the
+    layers with pages. Idle slots write their parking page (their table
+    rows point nowhere else) and the page index is clipped so a
+    long-idle slot's walking pos can never index past its table row. A
+    state layer reads and writes the slot's own row of ``conv``.
+
+    Each :class:`Segment` is one ``lax.scan`` over its whole periods,
+    the period's layers unrolled inside. The pools ride the scans
+    WHOLE: the write and the attend are handed ``[L, P, H, *, pt]`` and
+    the layer index, and on the chip both are Pallas calls whose index
+    maps address ``(layer, page)`` from prefetched scalars
+    (ops/flash_decode.py: ``paged_kv_write`` updates the pool in place,
+    a page's read-modify-write a slot; ``paged_flash_decode_attend``
+    reads the live pages, GQA-native at ``spec.n_rep`` query heads a
+    K/V head). Nothing here may slice a layer out of a pool: that is a
+    copy of the layer every layer of every step. Off the chip, or at a
+    page Mosaic cannot tile, ``select_paged_kv_write`` /
+    ``select_paged_decode_attend`` hand back the dense pair (``.at[]
+    .set`` on a sliced layer, gather + dense attend): the same values,
+    the bit-equality anchor."""
     from mpi_acx_tpu.ops.flash_decode import (select_paged_decode_attend,
                                               select_paged_kv_write)
     from mpi_acx_tpu.ops.kvquant import kv_quant
-    from mpi_acx_tpu.ops.wquant import wread
 
-    ffn = ffn or tfm._mlp
+    spec = paged_spec(family, cfg)
     table, pos = state["table"], state["pos"]
     B, max_pages = table.shape
     keys = tuple(k for k in _POOL_KEYS if k in state)   # k, v[, ks, vs]
     quant = "ks" in keys
-    pe = params["pos"][pos][:, None, :]
-    x = (params["embed"][token][:, None, :] + pe).astype(cfg.dtype)
+    x = spec.embed(params, cfg, token, pos)
 
     # Slot b's token column: distinct pages per slot (each slot owns its
     # pages; idle slots own their parking page), so writes never collide.
@@ -329,35 +491,86 @@ def paged_decode_step(params, cfg, state, token, page_tokens: int,
     write = select_paged_kv_write(cfg.decode_flash, page_tokens)
     attend = select_paged_decode_attend(cfg.decode_flash, page_tokens)
 
-    def body(carry, i):
-        x, pools = carry
-        lp = jax.tree.map(
-            lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
-            params["layers"])
-        q, k, v = tfm._qkv(cfg, lp, x)
-        if quant:
-            k, ks = kv_quant(k)
-            v, vs = kv_quant(v)
-        pools = write(pools, (k, v, ks, vs) if quant else (k, v), i,
-                      write_page, off)
-        kp, vp = pools[:2]
-        if quant:
-            kp, vp = (kp, pools[2]), (vp, pools[3])
-        o = attend(q, kp, vp, table, pos, page_tokens, 1, layer=i)
-        x = ffn(cfg, lp, x + o @ wread(lp, "wo", x.dtype))
-        return (x, pools), None
+    def nth(base, i, stride, j):
+        """``base + i * stride + j`` without the identities."""
+        i = i if stride == 1 else i * stride
+        return i if base + j == 0 else i + (base + j)
 
-    n_layers = jax.tree.leaves(params["layers"])[0].shape[0]
-    (x, pools), _ = lax.scan(body, (x, tuple(state[k] for k in keys)),
-                             jnp.arange(n_layers))
-    out = dict(zip(keys, pools))
+    def layer(kind, lp, x, pools, rest, at):
+        """``at``: the layer's index among those of its cache kind."""
+        if kind.operator == "attention":
+            q, k, v = spec.qkv(cfg, lp, x, pos)
+            if quant:
+                k, ks = kv_quant(k)
+                v, vs = kv_quant(v)
+            pools = write(pools, (k, v, ks, vs) if quant else (k, v),
+                          at, write_page, off)
+            kp, vp = pools[:2]
+            if quant:
+                kp, vp = (kp, pools[2]), (vp, pools[3])
+            o = attend(q, kp, vp, table, pos, page_tokens, spec.n_rep,
+                       layer=at)
+            x = spec.attn_out(cfg, lp, x, o)
+        else:
+            st = lax.dynamic_index_in_dim(rest["conv"], at, 0,
+                                          keepdims=False)
+            x, st = spec.state_op(cfg, lp, x, st)
+            rest = dict(rest, conv=lax.dynamic_update_index_in_dim(
+                rest["conv"], st.astype(rest["conv"].dtype), at,
+                0))
+        if kind.ffn == "moe":
+            x, idx = spec.ffn(cfg, lp, x, kind.ffn)
+            if "moe" in rest:
+                rest = dict(rest, moe=rest["moe"] + _moe_tally(
+                    idx, state["owns"], spec.n_experts))
+        else:
+            x = spec.ffn(cfg, lp, x, kind.ffn)
+        return x, pools, rest
+
+    carry = (x, tuple(state[k] for k in keys),
+             {k: state[k] for k in ("conv", "moe") if k in state})
+    pages_at = states_at = 0
+    for seg in spec.segments:
+        stacked = params[seg.key]
+        n_pg = sum(k.cache == "pages" for k in seg.period)
+        n_st = len(seg.period) - n_pg
+
+        def body(carry, i, seg=seg, stacked=stacked, n_pg=n_pg, n_st=n_st,
+                 pages_at=pages_at, states_at=states_at):
+            x, pools, rest = carry
+            one = len(seg.period) == 1
+            pg = st = 0
+            for j, kind in enumerate(seg.period):
+                whole = spec.moe_whole if kind.ffn == "moe" else ()
+                lp = jax.tree.map(
+                    lambda a: lax.dynamic_index_in_dim(a, i, 0,
+                                                       keepdims=False),
+                    {n: a for n, a in (stacked if one else stacked[j]).items()
+                     if n not in whole})
+                if whole:
+                    lp.update({n: (stacked if one else stacked[j])[n]
+                               for n in whole}, repeat=i)
+                x, pools, rest = layer(
+                    kind, lp, x, pools, rest,
+                    nth(pages_at, i, n_pg, pg) if kind.cache == "pages"
+                    else nth(states_at, i, n_st, st))
+                pg += kind.cache == "pages"
+                st += kind.cache == "state"
+            return (x, pools, rest), None
+
+        # (the depth is the stacked leaves', as it always was: a tree
+        # cut or grown in depth runs at its own)
+        repeats = jax.tree.leaves(stacked)[0].shape[0]
+        carry, _ = lax.scan(body, carry, jnp.arange(repeats))
+        pages_at += repeats * n_pg
+        states_at += repeats * n_st
+    x, pools, rest = carry
+    out = dict(zip(keys, pools), **rest)
     out["table"] = table
     out["pos"] = pos + 1
-    x = tfm.layernorm(x, params["lnf_g"], params["lnf_b"])
-    logits = jnp.einsum("bsd,vd->bsv", x,
-                        params["embed"].astype(x.dtype),
-                        preferred_element_type=jnp.float32)[:, 0]
-    return logits, out
+    if "owns" in state:
+        out["owns"] = state["owns"]
+    return spec.head(params, cfg, x), out
 
 
 # The paged serve path's programs (the chunk below, PagedKV's _scatter /
@@ -388,16 +601,17 @@ def note_trace() -> None:
     _programs_traced += 1
 
 
-@partial(jax.jit, static_argnames=("cfg", "chunk", "page_tokens", "on_tpu"),
+@partial(jax.jit, static_argnames=("cfg", "family", "chunk", "page_tokens",
+                                   "on_tpu"),
          donate_argnames="state")
 def paged_decode_chunk(params, state, tok, keys, *, cfg, chunk,
-                       page_tokens, on_tpu):
+                       page_tokens, on_tpu, family=None):
     note_trace()
 
     def one(carry, _):
         state, tok, keys = carry
         logits, state = paged_decode_step(params, cfg, state, tok,
-                                          page_tokens)
+                                          page_tokens, family)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return (state, nxt, keys), nxt
     (state, _, keys), toks = lax.scan(one, (state, tok, keys), None,
@@ -414,9 +628,10 @@ def make_paged_step_fn(params, cfg, family, chunk: int,
     constant) and the static key to the process's one
     :func:`paged_decode_chunk`; nothing is traced here."""
     from mpi_acx_tpu import backend
-    _check_family(family)
-    return partial(paged_decode_chunk, params, cfg=cfg, chunk=chunk,
-                   page_tokens=page_tokens, on_tpu=backend.on_tpu())
+    paged_spec(family, cfg)             # a family without one raises here
+    return partial(paged_decode_chunk, params, cfg=cfg, family=family,
+                   chunk=chunk, page_tokens=page_tokens,
+                   on_tpu=backend.on_tpu())
 
 
 # --------------------------------------------------------------------------
@@ -424,8 +639,8 @@ def make_paged_step_fn(params, cfg, family, chunk: int,
 
 
 def prefill_with_history(params, cfg, suffix, hk, hv, last_index,
-                         ffn=None, kv_int8: bool = False):
-    """Prefill ONLY the suffix of a prompt whose first ``P`` tokens'
+                         kv_int8: bool = False):
+    """GPT-2's ``PagedSpec.suffix_prefill``. Prefill ONLY the suffix of a prompt whose first ``P`` tokens'
     K/V are already paged in (a radix prefix hit): ``suffix``
     [1, S_suf] tokens occupying absolute positions ``P..P+S_suf-1``,
     ``hk``/``hv`` [L, H, Dh, P] the gathered (dequantized) history in
@@ -447,7 +662,6 @@ def prefill_with_history(params, cfg, suffix, hk, hv, last_index,
                                              to_cache_layout)
     from mpi_acx_tpu.ops.wquant import wread
 
-    ffn = ffn or tfm._mlp
     B, Sb = suffix.shape
     P = hk.shape[-1]
     x = (params["embed"][suffix]
@@ -462,7 +676,7 @@ def prefill_with_history(params, cfg, suffix, hk, hv, last_index,
             [hvl[None].astype(x.dtype), to_cache_layout(v)], axis=-1)
         o = dense_decode_attend(q, kcat, vcat, P, P + Sb, 1)
         x = x + o @ wread(lp, "wo", x.dtype)
-        return ffn(cfg, lp, x), (k, v)
+        return tfm._mlp(cfg, lp, x), (k, v)
 
     x, (ks, vs) = lax.scan(body, x, (params["layers"], hk, hv))
     x = tfm.layernorm(x, params["lnf_g"], params["lnf_b"])
@@ -491,7 +705,12 @@ class PagedKV:
             (f"max_len={max_len} must be a multiple of "
              f"page_tokens={page_tokens} (the block table tiles the "
              "cache exactly)")
-        _check_family(family)
+        self.spec = paged_spec(family, cfg)
+        if kv_int8 and not self.spec.kv_int8:
+            raise NotImplementedError(
+                "kv_int8 pages are not wired for family "
+                f"{getattr(family, '__name__', family)!r} (its PagedSpec "
+                "says kv_int8=False): serve it with kv_int8=False")
         self.cfg = cfg
         self.n_slots = int(n_slots)
         self.max_len = int(max_len)
@@ -503,7 +722,13 @@ class PagedKV:
         self.prefix = (RadixPrefixCache(self.alloc, page_tokens)
                        if prefix_cache else None)
         self.pool = init_page_pool(cfg, n_pages, page_tokens, n_slots,
-                                   kv_int8=kv_int8)
+                                   kv_int8=kv_int8, spec=self.spec)
+        # The slots' fixed state (module docstring), None without state
+        # layers; the routing each chunk's steps counted ([4] as
+        # _moe_tally's) and the tails loaded to continue a sequence.
+        self.conv = self._fresh_conv()
+        self.moe_chunks: List[Tuple[int, ...]] = []     # one a chunk
+        self.tail_restores = 0
         # Slot b's parking page sits past the allocator's range.
         self._park = [n_pages + b for b in range(n_slots)]
         self.pages: List[List[int]] = [[] for _ in range(n_slots)]
@@ -517,20 +742,38 @@ class PagedKV:
 
     # -- device state ------------------------------------------------------
 
+    def _fresh_conv(self):
+        if not self.spec.n_state_layers:
+            return None
+        return jnp.zeros((self.spec.n_state_layers, self.n_slots)
+                         + self.spec.state_shape, self.cfg.dtype)
+
     def device_state(self):
         if self._dev_table is None:
             self._dev_table = jnp.asarray(self.table)
-        state = dict(self.pool)
+        state = {k: self.pool[k] for k in _POOL_KEYS if k in self.pool}
         state["table"] = self._dev_table
         state["pos"] = jnp.asarray(self.pos)
+        if self.conv is not None:
+            state["conv"] = self.conv
+        if self.spec.n_experts:
+            # A slot owns a request exactly while it holds pages.
+            state["owns"] = jnp.asarray([bool(p) for p in self.pages])
+            state["moe"] = jnp.zeros((4,), jnp.int32)
         return state
 
     def absorb(self, state) -> None:
-        self.pool = {k: state[k] for k in _POOL_KEYS if k in state}
+        self.pool = dict(self.pool, **{k: state[k] for k in _POOL_KEYS
+                                       if k in state})
         self._dev_table = state["table"]
         # np.array (copy): np.asarray of a device array is a read-only
         # view, and the host mirror gets written by seat/release.
         self.pos = np.array(state["pos"], np.int32)
+        if "conv" in state:
+            self.conv = state["conv"]
+        if "moe" in state:
+            self.moe_chunks.append(tuple(int(n) for n in
+                                         np.asarray(state["moe"])))
 
     def reset_pool(self) -> None:
         """Rebuild the device pool from zeros (after a failed donated
@@ -538,7 +781,8 @@ class PagedKV:
         allocator, tables, and the prefix cache start over."""
         self.pool = init_page_pool(self.cfg, self.n_pages,
                                    self.page_tokens, self.n_slots,
-                                   kv_int8=self.kv_int8)
+                                   kv_int8=self.kv_int8, spec=self.spec)
+        self.conv = self._fresh_conv()
         self.alloc = PageAllocator(self.n_pages)
         if self.prefix is not None:
             hits, ev, reused = (self.prefix.hits, self.prefix.evictions,
@@ -585,12 +829,20 @@ class PagedKV:
         return got
 
     def seat(self, b: int, prompt_pages: List[int],
-             fresh_pages: List[int], new_pos: int, rid: int = -1) -> None:
+             fresh_pages: List[int], new_pos: int, rid: int = -1,
+             state=None) -> None:
         """Slot b takes ownership of ``prompt_pages + fresh_pages``
         (references already held by the caller) at position
         ``new_pos``. ``rid`` only labels the journey event (ACX_REQLOG,
-        docs/DESIGN.md §20) — the allocator itself is request-blind."""
+        docs/DESIGN.md §20) — the allocator itself is request-blind.
+        With state layers the slot's fixed state becomes ``state``
+        ``[L_state, *state_shape]``, the prefill's ``'end'`` (zeros
+        when None: whatever the slot's last request left is dropped)."""
         assert not self.pages[b], (b, "seat of an occupied slot")
+        if self.conv is not None:
+            if state is None:
+                state = jnp.zeros_like(self.conv[:, 0])
+            self.conv = _seat_state(self.conv, state, jnp.int32(b))
         self.pages[b] = list(prompt_pages) + list(fresh_pages)
         assert len(self.pages[b]) <= self.max_pages, \
             (b, len(self.pages[b]), self.max_pages)
@@ -601,7 +853,8 @@ class PagedKV:
 
     def release(self, b: int) -> None:
         """Drop slot b's page references (shared prefix pages survive
-        through the trie's reference) and park the slot."""
+        through the trie's reference) and park the slot. Its fixed
+        state is dropped with them: the next seat overwrites it."""
         for p in self.pages[b]:
             self.alloc.decref(p)
         self.pages[b] = []
@@ -637,6 +890,7 @@ class PagedKV:
                 "copy-on-write with a dry pool (admission should have "
                 "bounded the request)")
         self.pool = _copy(self.pool, jnp.int32(page), jnp.int32(got[0]))
+        self.tail_restores += "tail" in self.pool   # the tail went along
         self.pages[b][j] = got[0]
         self.alloc.decref(page)
         self._sync_row(b)
@@ -653,7 +907,7 @@ class PagedKV:
         (0 for a cold full-prompt scatter; unused pages cost
         nothing — only ``len(pages)`` pages are written)."""
         if pages:
-            one = {k: one[k] for k in _POOL_KEYS
+            one = {k: one[k] for k in _PAGE_KEYS
                    if k in one and k in self.pool}
             self.pool = _scatter(self.pool, one,
                                  jnp.asarray(pages, jnp.int32))
@@ -662,13 +916,27 @@ class PagedKV:
         """Gather ``pages`` into contiguous [L, H, Dh, n*pt] history
         K/V (cache layout) in compute dtype (dequantizing int8 pages —
         the only page-resident form — through their f32 scales)."""
-        return _gather(self.pool, jnp.asarray(pages, jnp.int32),
-                       dtype=self.cfg.dtype)
+        return _gather({k: v for k, v in self.pool.items() if k != "tail"},
+                       jnp.asarray(pages, jnp.int32), dtype=self.cfg.dtype)
+
+    def restore_tail(self, page: int):
+        """The state at the end of ``page`` ``[L_state, *state_shape]``,
+        to continue a sequence from there (a radix hit's suffix
+        prefill; a resume is one when the trie kept its pages); None
+        without state layers."""
+        if "tail" not in self.pool:
+            return None
+        self.tail_restores += 1
+        return _tail(self.pool["tail"], jnp.int32(page))
 
 
-# PagedKV's three programs, one compile per shape in jit's own cache:
+# PagedKV's programs, one compile per shape in jit's own cache:
 # what the per-instance closures captured (pages written, bucket, pool
 # keys, page size) is all in the arguments' shapes and tree.
+
+
+def _at_page(arr, page):
+    return (0, page) + (0,) * (arr.ndim - 2)
 
 
 @partial(jax.jit, donate_argnums=(0,))
@@ -679,7 +947,7 @@ def _copy(pool, src, dst):
         page_data = lax.dynamic_index_in_dim(pool[key], src, 1,
                                              keepdims=True)
         out[key] = lax.dynamic_update_slice(pool[key], page_data,
-                                            (0, dst, 0, 0, 0))
+                                            _at_page(pool[key], dst))
     return out
 
 
@@ -692,11 +960,29 @@ def _scatter(pool, one, pages_arr):
         if n <= 0:
             break
         for key in one:
-            src = one[key][:, 0, ..., j * pt:j * pt + n]
+            if key == "tail":           # [L_state, whole pages, ...]
+                if j >= one[key].shape[1]:
+                    continue
+                src = one[key][:, j]
+            else:
+                src = one[key][:, 0, ..., j * pt:j * pt + n]
             pool[key] = lax.dynamic_update_slice(
                 pool[key], src[:, None].astype(pool[key].dtype),
-                (0, pages_arr[j], 0, 0, 0))
+                _at_page(pool[key], pages_arr[j]))
     return pool
+
+
+@jax.jit
+def _tail(tails, page):
+    note_trace()
+    return lax.dynamic_index_in_dim(tails, page, 1, keepdims=False)
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _seat_state(conv, state, b):
+    note_trace()
+    return lax.dynamic_update_index_in_dim(conv, state.astype(conv.dtype),
+                                           b, 1)
 
 
 @partial(jax.jit, static_argnames="dtype")

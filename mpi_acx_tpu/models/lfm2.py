@@ -1,0 +1,453 @@
+"""LFM2-MoE family (``model_type`` ``lfm2_moe``: LiquidAI LFM2-24B-A2B),
+pure functional JAX.
+
+A decoder whose layers are of two OPERATOR kinds and two FFN kinds
+(HF ``Lfm2Moe*``; ``u`` is the RMS-normed input, no bias anywhere):
+
+* layer ``l``: ``h = x + Op_l(norm(x))``, ``out = h + FFN_l(norm(h))``;
+  a final RMSNorm, then the (tied) head.
+* ``Op`` = grouped-query attention where ``layer_types[l] ==
+  "full_attention"``: q as ``n_heads`` heads, k and v as ``n_kv_heads``;
+  q and k RMS-normed over the head's dims (one weight ``[head_dim]``
+  each) BEFORE RoPE (rotate-half over the whole head); causal softmax
+  attention scaled ``1 / sqrt(head_dim)``; an output projection.
+* ``Op`` = gated short convolution where ``layer_types[l] == "conv"``:
+  ``[B | C | X] = W_in u``; ``z = B * X``; a depthwise causal
+  convolution of ``conv_L_cache`` taps over ``z`` (zeros before the
+  sequence, no bias); ``y = W_out (C * conv)``. What a decode step
+  needs of the past is the last ``conv_L_cache - 1`` values of ``z``:
+  a FIXED-size state a layer a sequence, where attention has pages.
+* ``FFN`` = dense SwiGLU (width ``d_ff``) for ``l < num_dense_layers``,
+  else routed experts (``moe.route_sigmoid_topk`` +
+  ``moe.sorted_expert_ffn``: sigmoid scores, a selection bias, the
+  ``top_k`` renormalised, every routed token computed, none dropped).
+
+Parameters are stacked by STRETCH, not by layer: ``kvpage.
+compress_layers`` groups the layers into segments of whole periods
+(``params["seg<i>"]``: one dict of leaves ``[repeats, ...]`` a layer of
+the period), each one ``lax.scan``, so that compile time does not grow
+with the 40 published layers. :func:`forward` is the plain whole-
+sequence pass; :func:`paged_spec` is what ``serve_paged_greedy``'s
+plane asks of a family (``kvpage.PagedSpec``).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from mpi_acx_tpu.models import kvpage, moe
+from mpi_acx_tpu.models.llama import _repeat_kv, rmsnorm, rope
+
+_PUBLISHED_LAYERS = ("conv", "conv") + (
+    "full_attention", "conv", "conv", "conv") * 9 + ("full_attention", "conv")
+
+
+@dataclasses.dataclass(frozen=True)
+class Lfm2Config:
+    vocab: int = 65536
+    d_model: int = 2048
+    n_heads: int = 32
+    n_kv_heads: int = 8
+    d_ff: int = 11776                # dense SwiGLU width
+    moe_d_ff: int = 1536             # one expert's width
+    n_experts: int = 64
+    top_k: int = 4
+    layer_types: Tuple[str, ...] = _PUBLISHED_LAYERS
+    num_dense_layers: int = 2
+    conv_L_cache: int = 3
+    norm_eps: float = 1e-5
+    rope_theta: float = 1e6
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    max_seq: int = 128000
+    # The experts held HERE: ``experts_held`` of them from
+    # ``experts_first`` (None: all). The router keeps its width.
+    experts_first: int = 0
+    experts_held: Optional[int] = None
+    dtype: Any = jnp.bfloat16
+    use_flash: Optional[bool] = None     # prefill attention; None = auto
+    decode_flash: Optional[bool] = None  # paged decode kernels; None = auto
+
+    @property
+    def head_dim(self) -> int:
+        return self.d_model // self.n_heads
+
+    @property
+    def n_held(self) -> int:
+        return (self.n_experts if self.experts_held is None
+                else self.experts_held)
+
+
+def lfm2_24b_a2b() -> Lfm2Config:
+    """LFM2-24B-A2B as published (40 layers, 64 experts top 4)."""
+    return Lfm2Config()
+
+
+def tiny_lfm2(**over) -> Lfm2Config:
+    """Small config for tests: one dense conv layer, then two periods
+    ``attn, conv, conv, conv`` with 8 experts top 2, d = 64."""
+    base = dict(vocab=96, d_model=64, n_heads=4, n_kv_heads=2, d_ff=96,
+                moe_d_ff=32, n_experts=8, top_k=2,
+                layer_types=("conv",) + ("full_attention", "conv", "conv",
+                                         "conv") * 2,
+                num_dense_layers=1, max_seq=256)
+    base.update(over)
+    return Lfm2Config(**base)
+
+
+Params = Dict[str, Any]
+_EXPERT_STACKS = ("w1", "w3", "w2")    # of a routed FFN: never sliced
+
+
+def layer_kinds(cfg: Lfm2Config) -> Tuple[kvpage.LayerKind, ...]:
+    return tuple(
+        kvpage.LayerKind(
+            operator="attention" if t == "full_attention" else "conv",
+            ffn="dense" if l < cfg.num_dense_layers else "moe",
+            cache="pages" if t == "full_attention" else "state")
+        for l, t in enumerate(cfg.layer_types))
+
+
+def segments(cfg: Lfm2Config) -> Tuple[kvpage.Segment, ...]:
+    return kvpage.compress_layers(layer_kinds(cfg))
+
+
+def leaf_shapes(cfg: Lfm2Config, kind: kvpage.LayerKind) -> Dict[str, tuple]:
+    """One layer's leaves: name -> (shape, init; None = ones, "bias" =
+    the router's selection bias, else a normal's scale)."""
+    d, dh = cfg.d_model, cfg.head_dim
+    s = 0.02
+    out = {"op_norm": ((d,), None), "ffn_norm": ((d,), None)}
+    if kind.operator == "attention":
+        out.update(wq=((d, cfg.n_heads * dh), s),
+                   wk=((d, cfg.n_kv_heads * dh), s),
+                   wv=((d, cfg.n_kv_heads * dh), s),
+                   wo=((cfg.n_heads * dh, d), s),
+                   q_norm=((dh,), None), k_norm=((dh,), None))
+    else:
+        out.update(w_in=((d, 3 * d), s), conv_w=((d, cfg.conv_L_cache), s),
+                   w_out=((d, d), s))
+    if kind.ffn == "dense":
+        out.update(w1=((d, cfg.d_ff), s), w3=((d, cfg.d_ff), s),
+                   w2=((cfg.d_ff, d), s))
+    else:
+        n, f = cfg.n_held, cfg.moe_d_ff
+        out.update(gate=((d, cfg.n_experts), s),
+                   bias=((cfg.n_experts,), "bias"),
+                   w1=((n, d, f), s), w3=((n, d, f), s), w2=((n, f, d), s))
+    return out
+
+
+def init_params(key: jax.Array, cfg: Lfm2Config) -> Params:
+    """f32 parameters, stacked by segment (module docstring); tied
+    embedding and head. The selection bias is small and NOT zero (HF
+    initialises zeros), so that selection and weight differ."""
+    params = {"embed": jax.random.normal(
+        jax.random.fold_in(key, 0), (cfg.vocab, cfg.d_model)) * 0.02,
+        "final_norm": jnp.ones((cfg.d_model,))}
+    n = 0
+    for seg in segments(cfg):
+        layers = []
+        for kind in seg.period:
+            leaves = {}
+            for name, (shape, init) in sorted(leaf_shapes(cfg, kind).items()):
+                n += 1
+                k = jax.random.fold_in(key, n)
+                shape = (seg.repeats,) + shape
+                if init is None:
+                    leaves[name] = jnp.ones(shape)
+                elif init == "bias":
+                    leaves[name] = jax.random.uniform(k, shape, jnp.float32,
+                                                      -0.05, 0.05)
+                else:
+                    leaves[name] = jax.random.normal(k, shape) * init
+            layers.append(leaves)
+        params[seg.key] = layers[0] if len(layers) == 1 else tuple(layers)
+    return params
+
+
+def cast_params(params: Params, dtype=jnp.bfloat16) -> Params:
+    """The tree in ``dtype`` for inference; the router (``gate``,
+    ``bias``) and the norms stay f32: they are computed in f32."""
+    def cast(path, p):
+        name = path[-1].key
+        keep = name in ("gate", "bias") or name.endswith("norm")
+        return p if keep else p.astype(dtype)
+    return jax.tree_util.tree_map_with_path(cast, params)
+
+
+# -- the layer functions -----------------------------------------------------
+
+
+def _w(lp, name, dtype):
+    return lp[name].astype(dtype)
+
+
+def _qkv(cfg: Lfm2Config, lp: Params, x: jax.Array, positions: jax.Array):
+    """q [B, S, Hq, Dh], k, v [B, S, Hkv, Dh] as they go into attention
+    and the cache: QK-norm over the head's dims, then RoPE, both in
+    float32 with ONE rounding to the compute type at the end (a
+    rounding between them is in every cached key: 0.003 of the pages'
+    relative error)."""
+    B, S, _ = x.shape
+    u = rmsnorm(x, lp["op_norm"], cfg.norm_eps)
+    dh = cfg.head_dim
+    q = (u @ _w(lp, "wq", x.dtype)).reshape(B, S, cfg.n_heads, dh)
+    k = (u @ _w(lp, "wk", x.dtype)).reshape(B, S, cfg.n_kv_heads, dh)
+    v = (u @ _w(lp, "wv", x.dtype)).reshape(B, S, cfg.n_kv_heads, dh)
+
+    def norm_rope(t, g):
+        t = rmsnorm(t.astype(jnp.float32), g, cfg.norm_eps)
+        return rope(t, positions, cfg.rope_theta).astype(x.dtype)
+    return norm_rope(q, lp["q_norm"]), norm_rope(k, lp["k_norm"]), v
+
+
+def _attn_out(cfg: Lfm2Config, lp: Params, x: jax.Array, o: jax.Array):
+    return x + o @ _w(lp, "wo", x.dtype)
+
+
+def _self_attend(cfg: Lfm2Config, q, k, v):
+    """Causal attention of a whole sequence on itself, through the
+    shared flash/dense policy (ops/attention.py), which takes as many
+    K/V heads as query heads: the K/V heads are REPEATED for it, as
+    llama's are. [B, S, Hq * Dh]."""
+    from mpi_acx_tpu.ops.attention import select_attention
+    n_rep = cfg.n_heads // cfg.n_kv_heads
+    o = select_attention(cfg.use_flash)(q, _repeat_kv(k, n_rep),
+                                        _repeat_kv(v, n_rep))
+    return o.reshape(q.shape[0], q.shape[1], -1)
+
+
+def _conv_op(cfg: Lfm2Config, lp: Params, x: jax.Array, z_before: jax.Array):
+    """The gated short conv with its residual. x [B, S, d]; ``z_before``
+    [B, taps, d] the gated input at the ``taps = conv_L_cache - 1``
+    positions before x (zeros before a sequence). Returns (x + y,
+    ``zs`` [B, taps + S, d]: ``z_before`` then this call's z, whose last
+    ``taps`` rows are the state the next token needs)."""
+    S, L = x.shape[1], cfg.conv_L_cache
+    u = rmsnorm(x, lp["op_norm"], cfg.norm_eps)
+    b, c, xx = jnp.split(u @ _w(lp, "w_in", x.dtype), 3, axis=-1)
+    zs = jnp.concatenate([z_before.astype(x.dtype), b * xx], axis=1)
+    w = lp["conv_w"].astype(jnp.float32)                       # [d, L]
+    conv = sum(w[:, j] * zs[:, j:j + S].astype(jnp.float32)
+               for j in range(L))
+    y = (c * conv.astype(x.dtype)) @ _w(lp, "w_out", x.dtype)
+    return x + y, zs
+
+
+def _dense_ffn(cfg: Lfm2Config, lp: Params, x: jax.Array):
+    u = rmsnorm(x, lp["ffn_norm"], cfg.norm_eps)
+    h = jax.nn.silu(u @ _w(lp, "w1", x.dtype)) * (u @ _w(lp, "w3", x.dtype))
+    return x + h @ _w(lp, "w2", x.dtype)
+
+
+def _moe_ffn(cfg: Lfm2Config, lp: Params, x: jax.Array):
+    """(x + the held experts' part, idx [T, k] the experts chosen)."""
+    u = rmsnorm(x, lp["ffn_norm"], cfg.norm_eps).reshape(-1, cfg.d_model)
+    idx, p = moe.route_sigmoid_topk(
+        u, lp["gate"], lp["bias"], cfg.top_k, cfg.routed_scaling_factor,
+        cfg.norm_topk_prob)
+    # (with "repeat" the expert matrices are the segment's whole stacks)
+    y = moe.sorted_expert_ffn(u, _w(lp, "w1", x.dtype), _w(lp, "w3", x.dtype),
+                              _w(lp, "w2", x.dtype), idx, p,
+                              first=cfg.experts_first, layer=lp.get("repeat"))
+    return x + y.astype(x.dtype).reshape(x.shape), idx
+
+
+def _ffn(cfg: Lfm2Config, lp: Params, x: jax.Array, kind: str):
+    return _dense_ffn(cfg, lp, x) if kind == "dense" else _moe_ffn(cfg, lp, x)
+
+
+def _head(params: Params, cfg: Lfm2Config, x: jax.Array):
+    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    return jnp.einsum("bsd,vd->bsv", x, params["embed"].astype(x.dtype),
+                      preferred_element_type=jnp.float32)
+
+
+# -- whole sequences: forward, prefill, suffix prefill -----------------------
+
+
+def _by_layer(per_segment):
+    """Scan outputs ``[(segment, [[repeats, ...] a layer of the period
+    that has one])]`` -> ``[layers, ...]`` in model order (repeat-major
+    inside a segment), or None when no layer has one."""
+    parts = [jnp.stack(outs, axis=1).reshape(
+        (len(outs) * outs[0].shape[0],) + outs[0].shape[1:])
+        for outs in per_segment if outs]
+    return jnp.concatenate(parts, axis=0) if parts else None
+
+
+def _sequence_pass(params: Params, cfg: Lfm2Config, x: jax.Array, positions,
+                   history=None, page_tokens=None, last_index=None):
+    """x [B, S, d] through every layer. ``history`` = (hk, hv [L_attn,
+    Hkv, Dh, P], tail [L_conv, taps, d]): the sequence continues one
+    whose first P positions are cached (B = 1): attention sees the
+    history's keys and values before its own, each conv starts from the
+    tail. Returns (x, k, v [L_attn, B, S, Hkv, Dh], and with
+    ``page_tokens`` the conv layers' ``tail`` [L_conv, S // page_tokens,
+    taps, d] at the end of every whole page and ``end`` [L_conv, taps,
+    d] at ``last_index``, else None, None)."""
+    from mpi_acx_tpu.models.decoding import (dense_decode_attend,
+                                             to_cache_layout)
+    B, S, _ = x.shape
+    taps = cfg.conv_L_cache - 1
+    n_rep = cfg.n_heads // cfg.n_kv_heads
+    hk, hv, tail0 = history if history is not None else (None, None, None)
+    P = 0 if hk is None else hk.shape[-1]
+    ks, vs, tails, ends = [], [], [], []
+    attn_at = conv_at = 0
+    for seg in segments(cfg):
+        n_attn = sum(k.operator == "attention" for k in seg.period)
+        n_conv = len(seg.period) - n_attn
+
+        def cut(a, at, n):
+            """Rows [at, at + repeats * n) of a per-layer array as
+            scan inputs [repeats, n, ...]."""
+            a = a[at:at + seg.repeats * n]
+            return a.reshape((seg.repeats, n) + a.shape[1:])
+
+        # The expert stacks stay out of the scan's slicing (moe.
+        # sorted_expert_ffn, ``layer``): closed over whole.
+        subs = params[seg.key] if len(seg.period) > 1 else (params[seg.key],)
+        whole = tuple({n: a for n, a in sub.items()
+                       if kind.ffn == "moe" and n in _EXPERT_STACKS}
+                      for kind, sub in zip(seg.period, subs))
+        xs = {"lp": tuple({n: a for n, a in sub.items() if n not in held}
+                          for sub, held in zip(subs, whole)),
+              "i": jnp.arange(seg.repeats)}
+        if hk is not None and n_attn:
+            xs["hk"], xs["hv"] = (cut(hk, attn_at, n_attn),
+                                  cut(hv, attn_at, n_attn))
+        if tail0 is not None and n_conv:
+            xs["tail"] = cut(tail0, conv_at, n_conv)
+
+        def body(x, xs, seg=seg, whole=whole):
+            kv, zt, a, c = [], [], 0, 0
+            for kind, lp, held in zip(seg.period, xs["lp"], whole):
+                if held:
+                    lp = dict(lp, **held, repeat=xs["i"])
+                if kind.operator == "attention":
+                    q, k, v = _qkv(cfg, lp, x, positions)
+                    if "hk" in xs:
+                        kcat = jnp.concatenate(
+                            [xs["hk"][a][None].astype(x.dtype),
+                             to_cache_layout(k)], axis=-1)
+                        vcat = jnp.concatenate(
+                            [xs["hv"][a][None].astype(x.dtype),
+                             to_cache_layout(v)], axis=-1)
+                        o = dense_decode_attend(q, kcat, vcat, P, P + S,
+                                                n_rep)
+                    else:
+                        o = _self_attend(cfg, q, k, v)
+                    x = _attn_out(cfg, lp, x, o)
+                    kv.append((k, v))
+                    a += 1
+                else:
+                    before = (jnp.broadcast_to(xs["tail"][c][None],
+                                               (B, taps, cfg.d_model))
+                              if "tail" in xs else
+                              jnp.zeros((B, taps, cfg.d_model), x.dtype))
+                    x, zs = _conv_op(cfg, lp, x, before)
+                    if page_tokens is not None:
+                        n = S // page_tokens
+                        pages = zs[0, taps:taps + n * page_tokens].reshape(
+                            n, page_tokens, cfg.d_model)
+                        zt.append((
+                            pages[:, page_tokens - taps:],
+                            lax.dynamic_slice_in_dim(zs[0], last_index + 1,
+                                                     taps, axis=0)))
+                    c += 1
+                x = (_dense_ffn(cfg, lp, x) if kind.ffn == "dense"
+                     else _moe_ffn(cfg, lp, x)[0])
+            return x, (tuple(kv), tuple(zt))
+
+        x, (kv, zt) = lax.scan(body, x, xs)
+        ks.append([k for k, _ in kv])
+        vs.append([v for _, v in kv])
+        tails.append([t for t, _ in zt])
+        ends.append([e for _, e in zt])
+        attn_at += seg.repeats * n_attn
+        conv_at += seg.repeats * n_conv
+    return x, _by_layer(ks), _by_layer(vs), _by_layer(tails), _by_layer(ends)
+
+
+def forward(params: Params, cfg: Lfm2Config, tokens: jax.Array) -> jax.Array:
+    """tokens [B, S] int32 -> logits [B, S, vocab] (f32): the plain
+    whole-sequence pass, no cache."""
+    x = params["embed"][tokens].astype(cfg.dtype)
+    x = _sequence_pass(params, cfg, x, jnp.arange(tokens.shape[1]))[0]
+    return _head(params, cfg, x)
+
+
+def _prefilled(params, cfg, x, ks, vs, tails, ends, last_index, kv_int8):
+    from mpi_acx_tpu.models.decoding import pack_kv
+    x = lax.dynamic_slice_in_dim(x, last_index, 1, axis=1)
+    one = pack_kv(ks, vs, kv_int8)
+    if tails is not None:
+        one["tail"], one["end"] = tails, ends
+    return _head(params, cfg, x), one
+
+
+def prefill(params: Params, cfg: Lfm2Config, tokens: jax.Array, last_index,
+            kv_int8: bool = False, page_tokens: Optional[int] = None):
+    """``PagedSpec.prefill``: one prompt [1, S] (bucket-padded, its
+    real last token at ``last_index``) -> (logits [1, 1, vocab] there,
+    ``one``: the attention layers' K/V in cache layout and the conv
+    layers' tails and end state, ``kvpage.PagedSpec``'s docstring)."""
+    S = tokens.shape[1]
+    x = params["embed"][tokens].astype(cfg.dtype)
+    x, *got = _sequence_pass(params, cfg, x, jnp.arange(S),
+                             page_tokens=page_tokens, last_index=last_index)
+    return _prefilled(params, cfg, x, *got, last_index, kv_int8)
+
+
+def suffix_prefill(params: Params, cfg: Lfm2Config, suffix: jax.Array, hk, hv,
+                   tail, last_index, kv_int8: bool = False,
+                   page_tokens: Optional[int] = None):
+    """``PagedSpec.suffix_prefill``: only the suffix [1, S_suf] of a
+    prompt whose first P tokens are paged in (a radix hit): attention
+    against the gathered history ``hk``/``hv`` [L_attn, Hkv, Dh, P]
+    (positions P.., keys cached post-RoPE), each conv from the last
+    matched page's ``tail`` [L_conv, taps, d]."""
+    P, S = hk.shape[-1], suffix.shape[1]
+    x = params["embed"][suffix].astype(cfg.dtype)
+    x, *got = _sequence_pass(params, cfg, x, P + jnp.arange(S),
+                             history=(hk, hv, tail), page_tokens=page_tokens,
+                             last_index=last_index)
+    return _prefilled(params, cfg, x, *got, last_index, kv_int8)
+
+
+# -- the paged plane's seam --------------------------------------------------
+
+
+def paged_spec(cfg: Lfm2Config) -> kvpage.PagedSpec:
+    """What ``serve_paged_greedy``'s plane asks of this family: pages
+    for the attention layers ([L_attn, P, n_kv_heads, head_dim, pt],
+    GQA-native through the shared write and walk), a fixed state
+    ``[conv_L_cache - 1, d_model]`` a slot a conv layer, the router's
+    width for the routing counters. int8 pages are not wired: the
+    tails would want a precision of their own."""
+    def decode_conv(cfg, lp, x, st):
+        x, zs = _conv_op(cfg, lp, x, st)
+        return x, zs[:, -(cfg.conv_L_cache - 1):]
+
+    return kvpage.PagedSpec(
+        segments=segments(cfg),
+        n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim,
+        n_rep=cfg.n_heads // cfg.n_kv_heads,
+        state_shape=(cfg.conv_L_cache - 1, cfg.d_model),
+        n_experts=cfg.n_experts, kv_int8=False, moe_whole=_EXPERT_STACKS,
+        ffn_built=(("dense", "_dense_ffn"),
+                   ("moe", "sorted_expert_ffn/"
+                    + moe.select_grouped_matmul().__name__)),
+        embed=lambda params, cfg, token, pos:
+            params["embed"][token][:, None, :].astype(cfg.dtype),
+        qkv=lambda cfg, lp, x, pos: _qkv(cfg, lp, x, pos[:, None]),
+        attn_out=_attn_out, state_op=decode_conv, ffn=_ffn,
+        head=lambda params, cfg, x: _head(params, cfg, x)[:, 0],
+        prefill=prefill, suffix_prefill=suffix_prefill)

@@ -312,3 +312,140 @@ def make_moe_train_step(cfg: MoeConfig, mesh, ep_axis: str = "ep",
         return loss, jax.tree.map(lambda p, gg: p - lr * gg, params, g)
 
     return step
+
+
+# --------------------------------------------------------------------------
+# Drop-free routed experts: sigmoid router, sorted dispatch, grouped matmuls
+#
+# Beside the capacity layer above (the train steps and moe_transformer
+# keep theirs): nothing here has a capacity, so nothing is dropped, and
+# no [T, E, C] tensor is made. The holder of the layer is told WHICH
+# experts it holds (``first`` and the leading axis of the weights),
+# routes over all of them and returns its own experts' part of the
+# result; what absent experts would have added is left out (on one
+# chip that holds every expert: nothing). No exchange lives here.
+
+
+def route_sigmoid_topk(x, gate, bias, top_k: int, scale: float = 1.0,
+                       normalise: bool = True):
+    """The ``lfm2_moe`` router, in f32: ``s = sigmoid(x @ gate)`` [T, E];
+    the ``top_k`` of ``s + bias`` are selected (the bias only selects;
+    of equal scores the lower index wins, ``lax.top_k``'s rule); the
+    weights are ``s`` of the selected, divided by ``sum + 1e-6`` when
+    ``normalise``, times ``scale``. Returns (idx [T, k] int32, p [T, k]
+    f32)."""
+    s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
+                               gate.astype(jnp.float32),
+                               precision=lax.Precision.HIGHEST))
+    _, idx = lax.top_k(s + bias.astype(jnp.float32), top_k)
+    p = jnp.take_along_axis(s, idx, axis=-1)
+    if normalise:
+        p = p / (jnp.sum(p, axis=-1, keepdims=True) + 1e-6)
+    return idx.astype(jnp.int32), p * scale
+
+
+def ragged_dot_matmul(xs, w, sizes):
+    """Rows ``xs`` [M, a], sorted by group, times each group's own
+    matrix ``w`` [G, a, b]; ``sizes`` [G] rows a group, rows past their
+    sum belong to none. f32 accumulation and result. XLA's own
+    ``lax.ragged_dot``: right anywhere, the reference the kernel below
+    is held to."""
+    return lax.ragged_dot(xs, w, sizes,
+                          preferred_element_type=jnp.float32)
+
+
+def megablox_matmul(xs, w, sizes):
+    """:func:`ragged_dot_matmul` by the Pallas grouped matmul that ships
+    with JAX (``pallas.ops.tpu.megablox.gmm``): a grid over (group, row
+    tile) pairs, so an expert's matrix is read once a row tile its rows
+    touch and an expert with no row not at all. Tiling, from the chip
+    (PERF.md, PR 31: one layer of 64 experts 2048 x 1536, top 4, ms a
+    layer; the weights' read alone is 1.45): row tiles of 128 (a
+    (token, expert) pair in 16 is an expert's at a 256-token prefill: a
+    taller tile multiplies padding), the whole contraction in one block,
+    and the widest block of result columns whose double-buffered tiles
+    fit Mosaic's 16 MiB of scoped VMEM, here all of them (one step an
+    expert; 15.25 and 15.75 MiB). T = 64: 1.62 (512 columns 1.66, ``ragged_dot``
+    2.36); T = 256: 1.87 (row tile 256 x 512 columns 2.07); T = 768:
+    2.23 (2.44; ``ragged_dot`` 4.36). Rows are padded to whole tiles
+    (the padding in no group). Interpret mode off the chip."""
+    import sys
+
+    import jax.experimental.pallas.ops.tpu.megablox  # noqa: F401
+    from mpi_acx_tpu import backend
+    # (the package rebinds its ``gmm`` attribute to the differentiable
+    # wrapper; the plain forward kernel is the module's function)
+    gmm = sys.modules["jax.experimental.pallas.ops.tpu.megablox.gmm"].gmm
+    m, a = xs.shape
+    n = w.shape[2]
+    tm = min(128, -(-m // 8) * 8)
+    item = xs.dtype.itemsize
+    cols = [t for t in range(n, 0, -128) if n % t == 0]
+    tn = next((t for t in cols if 2 * (tm * a + a * t) * item
+               + 3 * tm * t * 4 <= _GMM_VMEM_BYTES), cols[-1])
+    pad = -m % tm
+    if pad:
+        xs = jnp.pad(xs, ((0, pad), (0, 0)))
+    out = gmm(xs, w, sizes.astype(jnp.int32),
+              preferred_element_type=jnp.float32, tiling=(tm, a, tn),
+              interpret=not backend.on_tpu())
+    return out[:m] if pad else out
+
+
+# What the grouped matmul's tiles may take, Mosaic's scoped VMEM: both
+# inputs and the f32 result double-buffered, and the accumulator.
+_GMM_VMEM_BYTES = 16 * 2 ** 20
+
+
+def select_grouped_matmul():
+    """The grouped matmul an expert layer is built with, in the
+    ``select_attention`` idiom: the Pallas kernel on a TPU, XLA's
+    ``ragged_dot`` elsewhere (on the CPU it beats an interpreted
+    kernel). The chosen function's ``__name__`` is part of what
+    ``ServingMetrics.paged_ffn`` records."""
+    from mpi_acx_tpu import backend
+    return megablox_matmul if backend.on_tpu() else ragged_dot_matmul
+
+
+def sorted_expert_ffn(x, w1, w3, w2, idx, p, first: int = 0,
+                      grouped_matmul=None, layer=None):
+    """SwiGLU experts over sorted rows, no drop: x [T, d]; ``idx``,
+    ``p`` [T, k] the routing over ALL experts; ``w1``, ``w3`` [n, d, f]
+    and ``w2`` [n, f, d] the experts ``first .. first + n - 1`` held
+    here. Every (token, expert) pair whose expert is held is computed:
+    the pairs are sorted by expert (pairs of absent experts last, in no
+    group), each expert's rows meet its matrices in one grouped matmul
+    a projection, and the rows go back to their tokens weighted by
+    ``p``. Returns [T, d] f32: ``sum_i p_i W2_i (silu(W1_i x) * W3_i
+    x)`` over the held experts ``i`` of each token. ``grouped_matmul``:
+    :func:`select_grouped_matmul`'s unless given.
+
+    With ``layer`` (a traced scalar is fine) the matrices are STACKS
+    over layers, ``[R, n, ...]``, and this call is layer ``layer``'s:
+    the stack goes to the grouped matmul whole, as ``R * n`` groups of
+    which only this layer's have rows. Slicing the layer out instead
+    costs a copy of it in front of every Pallas call (1.2 ms for each
+    of the three 403 MB stacks at 64 experts of 2048 x 1536, against
+    1.6 ms for the matmuls themselves: PERF.md, PR 31)."""
+    grouped_matmul = grouped_matmul or select_grouped_matmul()
+    T, d = x.shape
+    k, n = idx.shape[1], w1.shape[-3]
+    local = idx.reshape(-1) - first
+    held = (local >= 0) & (local < n)
+    key = jnp.where(held, local, n)
+    order = jnp.argsort(key, stable=True)                  # [T*k]
+    sizes = jnp.zeros((n + 1,), jnp.int32).at[key].add(1)[:n]
+    if layer is not None:
+        groups = w1.shape[0] * n
+        w1, w3, w2 = (w.reshape((groups,) + w.shape[2:])
+                      for w in (w1, w3, w2))
+        sizes = lax.dynamic_update_slice(jnp.zeros((groups,), jnp.int32),
+                                         sizes, (layer * n,))
+    xs = x[order // k]
+    h = grouped_matmul(xs, w1, sizes)
+    g = grouped_matmul(xs, w3, sizes)
+    y = grouped_matmul((jax.nn.silu(h) * g).astype(x.dtype), w2, sizes)
+    w = jnp.where(held, p.reshape(-1), 0.0)[order]
+    y = jnp.where(held[order][:, None], y * w[:, None], 0.0)
+    back = jnp.zeros_like(order).at[order].set(jnp.arange(T * k))
+    return y[back].reshape(T, k, d).sum(axis=1)

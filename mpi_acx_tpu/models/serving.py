@@ -104,6 +104,28 @@ class ServingMetrics:
     paged_decode_attend: str = ""
     attend_pages_walked: int = 0
     attend_pages_grid: int = 0
+    # paged: the kinds of operator and of FFN the step program was built
+    # from (kvpage.PagedSpec.built: "attention", "attention+conv";
+    # "dense:_mlp", "dense:_dense_ffn+moe:sorted_expert_ffn"), and what a
+    # routed FFN had to do, counted ON THE DEVICE in every decode step
+    # and MoE layer over the slots that OWN a request at the chunk's
+    # start (kvpage._moe_tally): (token, expert) pairs routed, distinct
+    # experts hit, the fullest expert's pairs. An idle slot routes too
+    # and its experts are read: the first two are a floor on the expert
+    # kernel's work. ``moe_layer_steps``: (MoE layer, decode step) pairs
+    # counted; ``moe_experts``: the router's width (0 without).
+    paged_operator: str = ""
+    paged_ffn: str = ""
+    moe_assignments: int = 0
+    moe_experts_live: int = 0
+    moe_load_max: int = 0
+    moe_layer_steps: int = 0
+    moe_experts: int = 0
+    # (pairs, experts hit, fullest, layer-steps) of each decode chunk
+    moe_by_chunk: List[tuple] = field(default_factory=list)
+    # paged: tails of fixed-state layers loaded to continue a sequence
+    # from a page's end (radix hits, resumes that hit, COW copies).
+    conv_tail_restores: int = 0
     slo_deferrals: int = 0        # paged: refills deferred by the SLO gate
     ttft_p50_s: float = 0.0
     ttft_p99_s: float = 0.0
@@ -134,6 +156,22 @@ class ServingMetrics:
         to fetch: what the live-page walk is left with."""
         return (self.attend_pages_walked / self.attend_pages_grid
                 if self.attend_pages_grid else 0.0)
+
+    @property
+    def moe_live_expert_share(self) -> float:
+        """Share of (expert, MoE layer, decode step) triples whose
+        expert an owning slot routed to: how much of the expert weights
+        a step has to read."""
+        return (self.moe_experts_live
+                / (self.moe_experts * self.moe_layer_steps)
+                if self.moe_layer_steps else 0.0)
+
+    @property
+    def moe_load_max_over_mean(self) -> float:
+        """The fullest expert's pairs over the mean expert's, averaged
+        over steps and MoE layers by pairs: a fact about the routing."""
+        return (self.moe_load_max * self.moe_experts / self.moe_assignments
+                if self.moe_assignments else 0.0)
 
     @property
     def step_utilization(self) -> float:
@@ -1031,20 +1069,27 @@ def _slo_admit_targets(slo_admit) -> tuple:
 # trace prints ``PjitFunction(paged_prefill)``.
 
 
-@partial(jax.jit, static_argnames=("cfg", "family", "kv_int8", "on_tpu"))
+# What they ask of the family they ask through its kvpage.PagedSpec:
+# pages for the layers that keep pages and, for the layers that keep a
+# fixed state, each whole page's tail and the state at the prompt's end.
+
+
+@partial(jax.jit, static_argnames=("cfg", "family", "kv_int8", "on_tpu",
+                                   "page_tokens"))
 def paged_prefill(params, tokens, last_index, *, cfg, family, kv_int8,
-                  on_tpu):
+                  on_tpu, page_tokens=None):
     kvpage.note_trace()
-    return family.prefill(params, cfg, tokens, tokens.shape[1],
-                          kv_int8=kv_int8, last_index=last_index)
+    return kvpage.paged_spec(family, cfg).prefill(
+        params, cfg, tokens, last_index, kv_int8, page_tokens)
 
 
-@partial(jax.jit, static_argnames=("cfg", "kv_int8", "on_tpu"))
-def paged_suffix_prefill(params, suffix, hk, hv, last_index, *, cfg,
-                         kv_int8, on_tpu):
+@partial(jax.jit, static_argnames=("cfg", "family", "kv_int8", "on_tpu",
+                                   "page_tokens"))
+def paged_suffix_prefill(params, suffix, hk, hv, tail, last_index, *, cfg,
+                         kv_int8, on_tpu, family=None, page_tokens=None):
     kvpage.note_trace()
-    return kvpage.prefill_with_history(params, cfg, suffix, hk, hv,
-                                       last_index, kv_int8=kv_int8)
+    return kvpage.paged_spec(family, cfg).suffix_prefill(
+        params, cfg, suffix, hk, hv, tail, last_index, kv_int8, page_tokens)
 
 
 def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
@@ -1184,10 +1229,14 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
     # weights are arguments; what a trace reads from the process is in
     # the static key.
     on_tpu = backend.on_tpu()
+    # (the page size is in the prefills' key only where they cut tails
+    # at page ends)
+    tail_pt = pt if pkv.spec.n_state_layers else None
     prefill_fn = partial(paged_prefill, params, cfg=cfg, family=family,
-                         kv_int8=kv_int8, on_tpu=on_tpu)
+                         kv_int8=kv_int8, on_tpu=on_tpu, page_tokens=tail_pt)
     suffix_prefill_fn = partial(paged_suffix_prefill, params, cfg=cfg,
-                                kv_int8=kv_int8, on_tpu=on_tpu)
+                                family=family, kv_int8=kv_int8,
+                                on_tpu=on_tpu, page_tokens=tail_pt)
 
     step_fn = kvpage.make_paged_step_fn(params, cfg, family, chunk, pt)
 
@@ -1266,11 +1315,16 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
                     padded = _padded(suffix, max_len - P, cfg.max_seq - P)
                     hk, hv = pkv.gather_history(hit_pages)
                     logits, one = suffix_prefill_fn(
-                        jnp.asarray(padded), hk, hv, len(suffix) - 1)
+                        jnp.asarray(padded), hk, hv,
+                        pkv.restore_tail(hit_pages[-1]), len(suffix) - 1)
                 else:
                     padded = _padded(prompt, max_len, cfg.max_seq)
                     logits, one = prefill_fn(jnp.asarray(padded), S - 1)
-                    one = {k: v for k, v in one.items() if k != "pos"}
+                # The pages (and their tails) go to the pool, the fixed
+                # state at the prompt's end to the slot.
+                end = one.get("end")
+                one = {k: v for k, v in one.items()
+                       if k not in ("pos", "end")}
                 first = int(jnp.argmax(logits[0, 0]))   # the host waits
                 reqlog.emit("prefill_end", rid, first_token=first)
             with ph("refill.scatter", rid=rid) as scatter:
@@ -1284,7 +1338,7 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
             if spanned:
                 _span_app_end_best_effort()
         with ph("refill.seat", rid=rid) as seat:
-            pkv.seat(b, hit_pages, fresh, S, rid=rid)
+            pkv.seat(b, hit_pages, fresh, S, rid=rid, state=end)
             if pkv.prefix is not None:
                 pkv.prefix.insert(prompt, pkv.pages[b])
             if rid in preempted_rids:
@@ -1442,6 +1496,14 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
                                                  pt).__name__,
             paged_decode_attend=select_paged_decode_attend(
                 cfg.decode_flash, pt).__name__,
+            paged_operator=pkv.spec.built("operator"),
+            paged_ffn=pkv.spec.built("ffn"),
+            **dict(zip(("moe_assignments", "moe_experts_live",
+                        "moe_load_max", "moe_layer_steps"),
+                       map(sum, zip(*pkv.moe_chunks)))),
+            moe_experts=pkv.spec.n_experts,
+            moe_by_chunk=list(pkv.moe_chunks),
+            conv_tail_restores=pkv.tail_restores,
             attend_pages_walked=pages_walked,
             attend_pages_grid=pages_grid,
             slo_deferrals=n_slo_defer,

@@ -296,6 +296,38 @@ def decode_step(params: Params, cfg: TransformerConfig, cache,
     return logits, out_cache
 
 
+def paged_spec(cfg: TransformerConfig):
+    """What the paged plane asks of a family (``kvpage.PagedSpec``), for
+    GPT-2: every layer attention + dense MLP + pages, one segment of
+    ``n_layers`` one-layer periods over ``params["layers"]``."""
+    from mpi_acx_tpu.models import kvpage
+
+    def embed(params, cfg, token, pos):
+        pe = params["pos"][pos][:, None, :]
+        return (params["embed"][token][:, None, :] + pe).astype(cfg.dtype)
+
+    def head(params, cfg, x):
+        x = layernorm(x, params["lnf_g"], params["lnf_b"])
+        return jnp.einsum("bsd,vd->bsv", x, params["embed"].astype(x.dtype),
+                          preferred_element_type=jnp.float32)[:, 0]
+
+    return kvpage.PagedSpec(
+        segments=(kvpage.Segment("layers", (kvpage.LayerKind(),),
+                                 cfg.n_layers),),
+        n_kv_heads=cfg.n_heads, head_dim=cfg.head_dim,
+        ffn_built=(("dense", "_mlp"),),
+        embed=embed, head=head,
+        qkv=lambda cfg, lp, x, pos: _qkv(cfg, lp, x),
+        attn_out=lambda cfg, lp, x, o: x + o @ wread(lp, "wo", x.dtype),
+        ffn=lambda cfg, lp, x, kind: _mlp(cfg, lp, x),
+        prefill=lambda params, cfg, tokens, last_index, kv_int8, page_tokens:
+            prefill(params, cfg, tokens, tokens.shape[1], kv_int8=kv_int8,
+                    last_index=last_index),
+        suffix_prefill=lambda params, cfg, suffix, hk, hv, tail, last_index,
+            kv_int8, page_tokens: kvpage.prefill_with_history(
+                params, cfg, suffix, hk, hv, last_index, kv_int8=kv_int8))
+
+
 def generate(params: Params, cfg: TransformerConfig, prompt: jax.Array,
              n_new: int, max_len: Optional[int] = None,
              kv_int8: bool = False) -> jax.Array:

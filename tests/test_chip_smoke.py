@@ -145,3 +145,16 @@ def test_phase_runs_at_tiny_size(phase, capsys, loopback_runtime_closed):
                                     require_kernel=False)
     rows = [ln for ln in capsys.readouterr().out.splitlines() if ln]
     assert rows and all('"ok": true' in ln for ln in rows), rows
+    if phase == "serve_paged":
+        # the engagement facts of both families through the one loop
+        import json
+        by = {r["phase"]: r for r in map(json.loads, rows)}
+        assert by["serve_paged/bf16"]["paged_operator"] == "attention"
+        assert by["serve_paged/bf16"]["paged_ffn"] == "dense:_mlp"
+        row = by["serve_paged/lfm2"]
+        assert row["paged_operator"] == "attention+conv"
+        assert row["paged_ffn"].startswith(
+            "dense:_dense_ffn+moe:sorted_expert_ffn/")
+        assert 0 < row["moe_live_expert_share"] <= 1
+        assert row["conv_tail_restores"] >= 2 <= row["prefix_hits"]
+        assert row["programs_traced"][1] == 0

@@ -410,3 +410,84 @@ def _pallas_grids(jaxpr, found=None):
         for sub in jax.core.jaxprs_in_params(eqn.params):
             _pallas_grids(sub, found)
     return found
+
+
+# -- a second family through the same programs (PR 31) -----------------------
+
+LFM2_B, LFM2_PAGES = 64, 1024 + 64                 # + parking pages
+
+
+def _lfm2_cut():
+    """The benchmark's cut of LFM2-24B-A2B at published widths (one
+    dense conv layer + two whole periods attn, conv, conv, conv; 64
+    experts top 4, GQA 32 / 8 heads of 64), bf16 weights. Shapes only."""
+    from mpi_acx_tpu.models import lfm2
+    cfg = lfm2.Lfm2Config(
+        layer_types=("conv",) + ("full_attention", "conv", "conv",
+                                 "conv") * 2, num_dense_layers=1)
+    params = jax.eval_shape(lambda: lfm2.cast_params(
+        lfm2.init_params(jax.random.key(0), cfg)))
+    return lfm2, cfg, params
+
+
+def test_lfm2_decode_chunk_compiles_and_moves_no_pool(v5e):
+    """``paged_decode_chunk`` as a serve call binds it for the LFM2
+    cell's geometry (64 slots, page 128): the shared write and the
+    live-page walk at ``n_rep`` = 4 on a pool of 8 K/V heads, the expert
+    layer's three grouped matmuls as Mosaic calls with the result shapes
+    the benchmark's roofline reader matches, no instruction that moves
+    a pool or a layer of it, and temporaries far below a chip."""
+    lfm2, cfg, params = _lfm2_cut()
+    spec = kvpage.paged_spec(lfm2, cfg)
+    pool = jax.eval_shape(lambda: kvpage.init_page_pool(
+        cfg, LFM2_PAGES - LFM2_B, PAGE, LFM2_B, spec=spec))
+    assert pool["k"].shape == (2, LFM2_PAGES, 8, 64, PAGE)
+    assert pool["tail"].shape == (7, LFM2_PAGES, 2, 2048)
+    state = dict(k=pool["k"], v=pool["v"],
+                 table=_s((LFM2_B, MAX_LEN // PAGE), jnp.int32),
+                 pos=_s((LFM2_B,), jnp.int32),
+                 conv=_s((7, LFM2_B, 2, 2048), jnp.bfloat16),
+                 owns=_s((LFM2_B,), jnp.bool_), moe=_s((4,), jnp.int32))
+    keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0), LFM2_B))
+    step = kvpage.make_paged_step_fn(params, cfg, lfm2, 2, PAGE)
+    compiled = step.func.lower(
+        *_place([*step.args, state, _s((LFM2_B,), jnp.int32), keys], v5e),
+        **step.keywords).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"%([a-z_]+)[.0-9]* = (\(?[a-z0-9]+\[[0-9,]*\])",
+                       "\n".join(l for l in text.splitlines()
+                                 if "tpu_custom_call" in l))
+    assert {n for n, _ in calls} == {"paged_flash_decode_attend",
+                                     "paged_kv_write", "gmm"}
+    assert ("paged_flash_decode_attend", "bf16[64,8,4,64]") in calls
+    assert {r for n, r in calls if n == "gmm"} == {"f32[256,1536]",
+                                                   "f32[256,2048]"}
+    assert not _pool_movers(text, pool["k"].shape), \
+        "\n".join(_pool_movers(text, pool["k"].shape))
+    # nor one that moves a layer's experts: the stacks [2, 64, ...] go to
+    # the grouped matmul whole (moe.sorted_expert_ffn, ``layer``); a
+    # layer sliced out in front of each call was two thirds of a step
+    stack = re.compile(r" = bf16\[(?:2,)?(?:64|128),(?:2048,1536|1536,2048)\]"
+                       r".*?\s(?!(?:parameter|get-tuple-element|bitcast)\()"
+                       r"[a-z][a-z0-9-]*\(")
+    moved = [l.strip()[:160] for l in text.splitlines() if stack.search(l)]
+    assert not moved, "\n".join(moved)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("bucket", [64, 1024])
+def test_lfm2_prefill_compiles_for_v5e(bucket, v5e):
+    """``serving.paged_prefill`` for the family at the smallest and the
+    largest bucket the cell reaches: the grouped matmuls over 4 x
+    bucket sorted rows, and flash attention (K/V heads repeated) at
+    1024."""
+    from mpi_acx_tpu.models import serving
+    lfm2, cfg, params = _lfm2_cut()
+    compiled = serving.paged_prefill.lower(
+        *_place([params, _s((1, bucket), jnp.int32), _s((), jnp.int32)],
+                v5e), cfg=cfg, family=lfm2, kv_int8=False, on_tpu=True,
+        page_tokens=PAGE).compile()
+    text = compiled.as_text()
+    assert f"f32[{4 * bucket},1536]" in text and "%gmm" in text
+    assert ("%flash_attention" in text) == (bucket == 1024)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
