@@ -8,18 +8,25 @@ are skipped entirely (the inner loop's trip count is ``i + 1``), so the
 kernel does ~half the FLOPs of the dense-mask reference implementation and
 O(S) memory instead of O(S^2).
 
-Differentiable: a ``jax.custom_vjp`` backward recomputes everything
-blockwise from (q, k, v, o) in pure JAX — one streaming pass rebuilds the
-row logsumexp, a second applies the standard flash-backward formulas
-(dS = P * (dP - rowsum(dO*O))) — O(S * block_k) peak memory, so training
-(e.g. make_train_step on long sequences) differentiates straight through
-the Pallas call. (For plain :func:`flash_attention` the lse is recomputed
-rather than emitted: the extra QK sweep costs ~1/5 of the backward's
-FLOPs and keeps the inference forward at zero overhead.)
+Differentiable: the ``jax.custom_vjp`` backward is a Pallas kernel too
+(:func:`_flash_bwd_kernel`): one program per (batch, head) holds the
+head's q, k, v and dO in VMEM, recomputes each block pair's
+probabilities from the forward's row logsumexp and applies the standard
+flash-backward formulas (dS = P * (dP - rowsum(dO*O))) with the
+forward's conventions (MXU operands in the input dtype, f32
+accumulation, causal blocks above the diagonal skipped by trip count).
+Nothing of size [S, S] or [block, block] reaches HBM, so training
+differentiates straight through the Pallas calls. Under differentiation
+plain :func:`flash_attention` runs the lse-emitting forward, so the
+backward has no lse sweep; the inference forward is untouched. From the
+streaming forward's lengths on (a head no longer fits VMEM) the backward
+is :func:`_flash_bwd_blockwise`, the same formulas as two nested loops
+in plain JAX, which is also the definition the tests hold the kernel to.
 
 :func:`flash_attention_lse` is the variant that DOES emit the row
 logsumexp — packed into one extra lane column of a single output — and
-its backward reuses the emitted lse and folds the lse cotangent into dS.
+its backward reuses the emitted lse and folds the lse cotangent into dS
+(``rowsum(dO*O) - dLSE`` is the one row term the kernel subtracts).
 It is the single-chip building block of
 :func:`mpi_acx_tpu.parallel.ring_attention.ring_attention`: ring
 attention rotates K/V shards around the mesh while each step runs exactly
@@ -53,6 +60,9 @@ from jax.experimental.pallas import tpu as pltpu
 from mpi_acx_tpu import backend
 
 _NEG_INF = -1e30
+# From this length on the forward streams K/V tiles (a head no longer fits
+# VMEM whole) and the backward runs blockwise in plain JAX.
+_STREAMING_MIN = 16384
 
 
 def attention_reference(q, k, v, causal: bool = True):
@@ -340,8 +350,14 @@ def _flash(qt, kt, vt, causal, block_q, block_k, streaming=False):
 
 
 def _flash_vjp_fwd(qt, kt, vt, causal, block_q, block_k, streaming=False):
-    o = _flash(qt, kt, vt, causal, block_q, block_k, streaming)
-    return o, (qt, kt, vt, o)
+    # Runs only under differentiation: the resident forward then emits
+    # its lse (the packed kernel), so the backward has no lse sweep. The
+    # streaming kernel emits none and keeps the blockwise backward.
+    if streaming:
+        o = _flash_stream_fwd_impl(qt, kt, vt, causal, block_q, block_k)
+        return o, (qt, kt, vt, o, None)
+    o, lse = _flash_lse_fwd_impl(qt, kt, vt, causal, block_q, block_k)
+    return o, (qt, kt, vt, o, lse)
 
 
 def _flash_bwd_blockwise(qt, kt, vt, o, do, causal, block_q, block_k,
@@ -445,11 +461,146 @@ def _flash_bwd_blockwise(qt, kt, vt, o, do, causal, block_q, block_k,
     return dq.astype(qt.dtype), dk.astype(kt.dtype), dv.astype(vt.dtype)
 
 
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, row_ref,
+                      dq_ref, dk_ref, dv_ref, dq_acc, *, block_q, block_k,
+                      scale, causal):
+    """One (batch, head) program of the backward: all of the head's q, k,
+    v and dO resident in VMEM, an outer loop over k blocks that carries
+    that block's dK and dV, an inner loop over the q blocks at or below
+    the diagonal, dQ summed into an f32 VMEM scratch. Every block pair is
+    visited ONCE: five block products, one pass over the probabilities.
+
+    The pair is held TRANSPOSED, ``[block_k, block_q]``: the per-row
+    statistics (``lse`` and ``row = rowsum(dO * O) - dLSE``) then lie
+    along lanes, as they come from HBM (``[.., n_q, block_q]`` rows; a
+    ``[.., S, 1]`` column is padded to 128 lanes there), and dV and dK
+    are plain products; only dQ contracts over the block's rows.
+
+    The forward's conventions: q pre-scaled in the operand dtype, MXU
+    operands in the operand dtype with f32 accumulation (HIGHEST for f32
+    operands), ``p`` and ``ds`` cast to the operand dtype before their
+    products; the mask is evaluated only on blocks that straddle the
+    diagonal, blocks above it are skipped by trip count."""
+    dtype = q_ref.dtype
+    prec = (jax.lax.Precision.HIGHEST if dtype == jnp.float32
+            else jax.lax.Precision.DEFAULT)
+    n_q = q_ref.shape[2] // block_q
+    n_k = k_ref.shape[2] // block_k
+    D = q_ref.shape[3]
+
+    def dot(a, b, contract):
+        return jax.lax.dot_general(a, b, (contract, ((), ())),
+                                   preferred_element_type=jnp.float32,
+                                   precision=prec)
+
+    dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    def kblock(j, _):
+        k0 = pl.multiple_of(j * block_k, block_k)
+        kb = k_ref[0, 0, pl.ds(k0, block_k), :]
+        vb = v_ref[0, 0, pl.ds(k0, block_k), :]
+
+        def pair(i, carry, masked):
+            dk, dv = carry
+            q0 = pl.multiple_of(i * block_q, block_q)
+            qb = q_ref[0, 0, pl.ds(q0, block_q), :]
+            dob = do_ref[0, 0, pl.ds(q0, block_q), :]
+            qs = (qb.astype(jnp.float32) * scale).astype(dtype)
+            st = dot(kb, qs, ((1,), (1,)))               # [BK, BQ] f32
+            if masked:
+                keys = k0 + jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
+                rows = q0 + jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
+                st = jnp.where(rows >= keys, st, _NEG_INF)
+            pt = jnp.exp(st - lse_ref[0, 0, pl.ds(i, 1), :])
+            dv = dv + dot(pt.astype(dtype), dob, ((1,), (0,)))
+            dpt = dot(vb, dob, ((1,), (1,)))             # [BK, BQ] f32
+            dst = (pt * (dpt - row_ref[0, 0, pl.ds(i, 1), :])).astype(dtype)
+            dk = dk + dot(dst, qb, ((1,), (0,)))
+            dq_acc[pl.ds(q0, block_q), :] += dot(dst, kb, ((0,), (0,)))
+            return dk, dv
+
+        zero = jnp.zeros((block_k, D), jnp.float32)
+        if causal:
+            # q blocks [first, n_diag) straddle the diagonal of this k
+            # block, [n_diag, n_q) lie wholly below it.
+            first = k0 // block_q
+            n_diag = (k0 + block_k + block_q - 1) // block_q
+            carry = jax.lax.fori_loop(
+                first, n_diag, lambda i, c: pair(i, c, masked=True),
+                (zero, zero))
+            dk, dv = jax.lax.fori_loop(
+                n_diag, n_q, lambda i, c: pair(i, c, masked=False), carry)
+        else:
+            dk, dv = jax.lax.fori_loop(
+                0, n_q, lambda i, c: pair(i, c, masked=False), (zero, zero))
+        dk_ref[0, 0, pl.ds(k0, block_k), :] = (dk * scale).astype(dk_ref.dtype)
+        dv_ref[0, 0, pl.ds(k0, block_k), :] = dv.astype(dv_ref.dtype)
+        return 0
+
+    jax.lax.fori_loop(0, n_k, kblock, 0)
+    dq_ref[0, 0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+
+
+def _flash_bwd_impl(qt, kt, vt, do, lse, row, causal, block_q, block_k):
+    """The backward pallas call on [B, H, S, D] operands; ``lse`` and
+    ``row`` [B, H, Sq] f32. Returns (dq, dk, dv) in the operand dtypes.
+    Named apart from the forward (the compiled program would otherwise
+    name it after the jitted function around it, which is the name the
+    benchmark's forward reader counts)."""
+    B, H, S, D = qt.shape
+    Sk = kt.shape[2]
+    assert not causal or S == Sk, (S, Sk)
+    kernel = functools.partial(_flash_bwd_kernel, block_q=block_q,
+                               block_k=block_k, scale=1.0 / (D ** 0.5),
+                               causal=causal)
+
+    def head(s):
+        return pl.BlockSpec((1, 1, s, D), lambda b, h: (b, h, 0, 0),
+                            memory_space=pltpu.VMEM)
+
+    lanes = (B, H, S // block_q, block_q)       # lse and row: S on lanes
+    stats = pl.BlockSpec((1, 1) + lanes[2:], lambda b, h: (b, h, 0, 0),
+                         memory_space=pltpu.VMEM)
+    # What a program holds: q, dO, dQ over S and k, v, dK, dV over Sk,
+    # double-buffered and padded to 128 lanes, the f32 dQ scratch, and
+    # the block pair's f32 temporaries.
+    padded = -(-D // 128) * 128
+    vmem = (2 * 3 * (S + Sk) * padded * qt.dtype.itemsize
+            + S * padded * 4 + 8 * block_q * block_k * 4)
+    return pl.pallas_call(
+        kernel,
+        grid=(B, H),
+        in_specs=[head(S), head(Sk), head(Sk), head(S), stats, stats],
+        out_specs=[head(S), head(Sk), head(Sk)],
+        out_shape=[_out_struct(x.shape, x.dtype, qt, kt, vt, do)
+                   for x in (qt, kt, vt)],
+        scratch_shapes=[pltpu.VMEM((S, D), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=max(32 << 20, vmem)),
+        name="attn_bwd",
+        interpret=not backend.on_tpu(),
+    )(qt, kt, vt, do, lse.reshape(lanes), row.reshape(lanes))
+
+
+def _flash_bwd(qt, kt, vt, o, do, causal, block_q, block_k, lse=None,
+               dlse=None):
+    """The backward rule of both custom VJPs, chosen by shape: the Pallas
+    kernel wherever a head is resident (below the streaming forward's
+    lengths) and the forward emitted its lse, else the blockwise path."""
+    if lse is None or max(qt.shape[2], kt.shape[2]) >= _STREAMING_MIN:
+        return _flash_bwd_blockwise(qt, kt, vt, o, do, causal, block_q,
+                                    block_k, lse=lse, dlse=dlse)
+    row = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    if dlse is not None:
+        row = row - dlse.astype(jnp.float32)
+    return _flash_bwd_impl(qt, kt, vt, do, lse, row, causal, block_q,
+                           block_k)
+
+
 def _flash_vjp_bwd(causal, block_q, block_k, streaming, res, do):
-    # The blockwise backward is kernel-independent (pure JAX recompute),
-    # so resident and streaming forwards share it.
-    qt, kt, vt, o = res
-    return _flash_bwd_blockwise(qt, kt, vt, o, do, causal, block_q, block_k)
+    qt, kt, vt, o, lse = res
+    return _flash_bwd(qt, kt, vt, o, do, causal, block_q, block_k, lse=lse)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -502,8 +653,8 @@ def _flash_lse_vjp_fwd(qt, kt, vt, causal, block_q, block_k):
 def _flash_lse_vjp_bwd(causal, block_q, block_k, res, cts):
     do, dlse = cts
     qt, kt, vt, o, lse = res
-    return _flash_bwd_blockwise(qt, kt, vt, o, do, causal, block_q, block_k,
-                                lse=lse, dlse=dlse)
+    return _flash_bwd(qt, kt, vt, o, do, causal, block_q, block_k, lse=lse,
+                      dlse=dlse)
 
 
 _flash_lse.defvjp(_flash_lse_vjp_fwd, _flash_lse_vjp_bwd)
@@ -531,7 +682,7 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 512,
     B, S, H, D = q.shape
     Sk = k.shape[1]
     if streaming is None:
-        streaming = Sk >= 16384
+        streaming = Sk >= _STREAMING_MIN
     fit_q = _fit_blocks(S, block_q, block_k)
     fit_k = _fit_blocks(Sk, block_q, block_k)
     if fit_q is None or fit_k is None:
@@ -558,7 +709,7 @@ def flash_attention_lse(q, k, v, causal: bool = True, block_q: int = 512,
       ``lse = logaddexp(lse1, lse2); o = o1*exp(lse1-lse) + o2*exp(lse2-lse)``
     Differentiable in both outputs (custom VJP; the backward reuses the
     emitted lse instead of recomputing it, and folds the lse cotangent
-    into dS — see _flash_bwd_blockwise). Same shape rules as
+    into dS — see _flash_bwd). Same shape rules as
     :func:`flash_attention`, except K/V sequence length may differ from
     Q's in the non-causal case (ring/cross attention blocks).
     """

@@ -251,8 +251,9 @@ class TestFlashAttentionLse:
 
 
 class TestFlashAttentionGrad:
-    """The custom VJP (blockwise lse-recompute backward) must match
-    gradients of the dense reference to machine precision."""
+    """The custom VJP (a Pallas backward kernel; blockwise in plain JAX
+    from the streaming lengths on) must match gradients of the dense
+    reference to machine precision."""
 
     @pytest.mark.parametrize("causal", [True, False])
     def test_grad_matches_dense(self, causal):
@@ -270,6 +271,90 @@ class TestFlashAttentionGrad:
         for a, b in zip(gf, gd):
             err = float(jnp.abs(a - b).max() / (jnp.abs(b).max() + 1e-9))
             assert err < 1e-5, (causal, err)
+
+    # name: (causal, Sq, Sk, dtype, requested block, lse cotangent).
+    # Without an lse cotangent the gradient goes through flash_attention
+    # (whose fwd rule emits the lse), with one through
+    # flash_attention_lse; 384 is a length _fit_blocks shrinks 256 for.
+    BWD_CASES = {
+        "causal_f32_b128_dlse": (True, 256, 256, "float32", 128, True),
+        "causal_f32_b128": (True, 256, 256, "float32", 128, False),
+        "causal_f32_b512_dlse": (True, 512, 512, "float32", 512, True),
+        "causal_f32_one_block": (True, 128, 128, "float32", 512, False),
+        "causal_f32_shrunk_dlse": (True, 384, 384, "float32", 256, True),
+        "causal_bf16_b128_dlse": (True, 256, 256, "bfloat16", 128, True),
+        "causal_bf16_b512": (True, 512, 512, "bfloat16", 512, False),
+        "full_f32_b128_dlse": (False, 256, 256, "float32", 128, True),
+        "full_f32_sq_lt_sk_dlse": (False, 128, 384, "float32", 128, True),
+        "full_f32_sq_gt_sk": (False, 256, 128, "float32", 128, False),
+        "full_bf16_sq_ne_sk_dlse": (False, 256, 128, "bfloat16", 128, True),
+    }
+
+    @pytest.mark.parametrize("case", list(BWD_CASES))
+    def test_backward_kernel_matches_blockwise_and_dense(self, case):
+        """dq, dk, dv of the Pallas backward (what jax.vjp of the two
+        public functions runs) against _flash_bwd_blockwise on the same
+        residuals and against the dense reference's gradients."""
+        from mpi_acx_tpu.ops import attention as A
+        causal, sq, sk, dtype, block, with_dlse = self.BWD_CASES[case]
+        ks = jax.random.split(jax.random.key(11), 5)
+        q = jax.random.normal(ks[0], (1, sq, 2, 64)).astype(dtype)
+        k = jax.random.normal(ks[1], (1, sk, 2, 64)).astype(dtype)
+        v = jax.random.normal(ks[2], (1, sk, 2, 64)).astype(dtype)
+        do = jax.random.normal(ks[3], q.shape).astype(dtype)
+        dlse = jax.random.normal(ks[4], (1, 2, sq)) if with_dlse else None
+
+        if with_dlse:
+            (o, lse), vjp = jax.vjp(lambda *a: A.flash_attention_lse(
+                *a, causal=causal, block_q=block, block_k=block), q, k, v)
+            got = vjp((do, dlse))
+        else:
+            o, vjp = jax.vjp(lambda *a: A.flash_attention(
+                *a, causal=causal, block_q=block, block_k=block), q, k, v)
+            got = vjp(do)
+            lse = A._reference_lse(q, k, v, causal=causal)[1]
+
+        bq = A._fit_blocks(sq, block, block)[0]
+        bk = A._fit_blocks(sk, block, block)[1]
+        t = lambda x: jnp.transpose(x, (0, 2, 1, 3))
+        blockwise = [t(g) for g in A._flash_bwd_blockwise(
+            t(q), t(k), t(v), t(o), t(do), causal, bq, bk, lse=lse,
+            dlse=dlse)]
+
+        f32 = lambda x: x.astype(jnp.float32)
+        _, dense_vjp = jax.vjp(lambda *a: A._reference_lse(
+            *a, causal=causal), f32(q), f32(k), f32(v))
+        dense = dense_vjp((f32(do), dlse if with_dlse
+                           else jnp.zeros((1, 2, sq))))
+
+        tol = 1e-5 if dtype == "float32" else 2e-2
+        for name, g, b, d in zip("qkv", got, blockwise, dense):
+            assert g.dtype == jnp.dtype(dtype) and g.shape == d.shape
+            for ref in (b, d):
+                err = float(jnp.abs(f32(g) - f32(ref)).max()
+                            / jnp.abs(f32(ref)).max())
+                assert err < tol, (case, name, err)
+
+    def test_streaming_lengths_take_the_blockwise_backward(self,
+                                                           monkeypatch):
+        """The backward rule chooses by shape: from the streaming
+        forward's lengths on (S >= 16384) it builds no kernel; below, it
+        builds the one Pallas call. Traced only, nothing runs."""
+        from mpi_acx_tpu.ops import attention as A
+
+        def traced(s, sk, refuse):
+            if refuse:
+                monkeypatch.setattr(A, "_flash_bwd_impl", lambda *a: 1 / 0)
+            x = lambda n: jax.ShapeDtypeStruct((1, 1, n, 32), jnp.bfloat16)
+            row = jax.ShapeDtypeStruct((1, 1, s), jnp.float32)
+            return str(jax.make_jaxpr(
+                lambda q, k, v, o, do, lse, dlse: A._flash_bwd(
+                    q, k, v, o, do, sk == s, 512, 512, lse=lse, dlse=dlse))(
+                x(s), x(sk), x(sk), x(s), x(s), row, row))
+
+        assert "pallas_call" in traced(8192, 8192, refuse=False)
+        assert "pallas_call" not in traced(16384, 16384, refuse=True)
+        assert "pallas_call" not in traced(1024, 16384, refuse=True)
 
     def test_grad_through_model_loss(self):
         """value_and_grad through a model whose attention is the Pallas

@@ -166,6 +166,61 @@ def test_compiles_for_v5e(name, v5e):
         f"{name}: compiled, but with no Mosaic kernel in the program"
 
 
+# The attention backward at the training cell's geometry
+# ([8, 1024, 16, 64] bf16: GPT-2 medium, 4 micro-batches of 8 rows) and
+# beyond: the gradient's program holds the backward Mosaic call under
+# its own name and nothing of the blockwise path it replaced.
+
+def _grad_lse(causal):
+    def loss(q, k, v):
+        o, lse = attention.flash_attention_lse(q, k, v, causal=causal)
+        return (o.astype(jnp.float32) ** 2).sum() + lse.sum()
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
+def _grad_plain(q, k, v):
+    return jax.grad(lambda *a: (attention.flash_attention(*a).astype(
+        jnp.float32) ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
+
+
+def _bhsd(b, s, h, dtype=jnp.bfloat16):
+    return _s((b, s, h, D), dtype)
+
+
+BWD_CASES = {
+    "lse_cell_1024": (_grad_lse(True), [_bhsd(8, 1024, 16)] * 3),
+    "plain_cell_1024": (_grad_plain, [_bhsd(8, 1024, 16)] * 3),
+    "lse_4096": (_grad_lse(True), [_bhsd(8, 4096, H)] * 3),
+    "lse_full_sq_ne_sk": (_grad_lse(False), [_bhsd(8, 1024, 16)]
+                          + [_bhsd(8, 2048, 16)] * 2),
+    "lse_f32_1024": (_grad_lse(True),
+                     [_bhsd(2, 1024, 4, jnp.float32)] * 3),
+}
+
+
+@pytest.mark.parametrize("name", list(BWD_CASES))
+def test_flash_backward_is_one_mosaic_call(name, v5e):
+    fn, args = BWD_CASES[name]
+    text = jax.jit(fn).lower(*_place(args, v5e)).compile().as_text()
+    shapes = dict(re.findall(r"(%[\w.\-]+) = \(?(\w+\[[\d,]*\])", text))
+    calls = [l for l in text.splitlines()
+             if "tpu_custom_call" in l and " custom-call(" in l]
+    bwd = [l for l in calls if l.strip().startswith("%attn_bwd")]
+    fwd = [l for l in calls if "flash_attention" in l.split(" = ")[0]]
+    assert len(bwd) == 1 and len(fwd) == 1, [l[:80] for l in calls]
+    # the forward reader of the benchmark counts ``%flash_attention``
+    assert "flash_attention" not in bwd[0].split(" = ")[0]
+    operands = re.findall(r"%[\w.\-]+", bwd[0].split("custom-call(")[1]
+                          .split(")")[0])
+    assert len(operands) == 6
+    for op in operands:            # a lane-1 operand is padded to 128
+        assert not shapes[op].endswith(",1]"), (op, shapes[op])
+    # nothing of the blockwise path: no loop over q blocks, no block of
+    # probabilities in HBM
+    assert not re.search(r"\swhile\(", text)
+    assert not re.search(r"\[\d+,\d+,512,512\]", text)
+
+
 @pytest.mark.parametrize("attend,args", [
     (lambda q, k, v, pos: flash_decode.flash_decode_attend(
         q, k, v, pos, 200, 1),
