@@ -365,6 +365,7 @@ def phase_serve_paged(size: Size = FULL, seed: int = 0,
              paged_ffn=outs.metrics.paged_ffn,
              **row)
     _serve_paged_lfm2(size, seed)
+    _serve_paged_jamba(size, seed)
     return True
 
 
@@ -409,6 +410,61 @@ def _serve_paged_lfm2(size: Size, seed: int):
          paged_operator=m.paged_operator, paged_ffn=m.paged_ffn,
          moe_live_expert_share=round(m.moe_live_expert_share, 4),
          moe_load_max_over_mean=round(m.moe_load_max_over_mean, 3),
+         kv_write_path=m.paged_kv_write, attend_built=m.paged_decode_attend,
+         programs_traced=traced,
+         token_mismatch_vs_dense=_mismatch_share(outs, ref, prompts))
+
+
+def _serve_paged_jamba(size: Size, seed: int):
+    """The same serve loop over a family whose state is a matrix a
+    channel (models/jamba.py, a small preset: heads of 128 and 512
+    channels so that the chip's kernels tile): Mamba layers through
+    ``ssm_scan`` and ``ssm_update`` beside multi-query pages, a snapshot
+    store of its own size, and that a radix hit restores a snapshot.
+    Tokens against the plain-JAX configuration of the same family."""
+    import jax
+
+    from mpi_acx_tpu.models import jamba, serving
+    pt = size.page_tokens or 128
+    over = {} if size.tiny else dict(vocab=512, d_model=256, n_heads=2,
+                                     d_ff=512, mamba_d_state=16,
+                                     mamba_dt_rank=16)
+    cfg = jamba.tiny_jamba(max_seq=2 * size.max_len,
+                           snapshot_every=size.shared_prefix // pt, **over)
+    params = jamba.cast_params(jamba.init_params(jax.random.key(seed), cfg))
+    prompts, n_new = _requests(size, cfg.vocab, seed)
+    kw = dict(n_slots=size.n_slots, max_len=size.max_len, family=jamba,
+              chunk=size.chunk, page_tokens=size.page_tokens,
+              prefix_cache=True, max_request_retries=0, n_snapshots=4)
+    with _Watch() as w:
+        outs = serving.serve_paged_greedy(params, cfg, prompts, n_new, **kw)
+    m = outs.metrics
+    tokens = _check_outputs(outs, prompts, n_new, m)
+    _require(m.prefix_hits >= 2 and m.conv_tail_restores >= 2,
+             f"prefix_hits={m.prefix_hits}, "
+             f"conv_tail_restores={m.conv_tail_restores}")
+    _require(0 < m.state_snapshot_rows_hwm <= 4
+             and m.state_snapshots_taken >= m.state_snapshot_rows_hwm
+             + m.state_snapshot_evictions,
+             f"state_snapshot_rows_hwm={m.state_snapshot_rows_hwm}, "
+             f"state_snapshots_taken={m.state_snapshots_taken}")
+    again = serving.serve_paged_greedy(params, cfg, prompts, n_new, **kw)
+    traced = [m.programs_traced, again.metrics.programs_traced]
+    _require(traced[0] > 0 and traced[1] == 0
+             and _mismatch_share(again, outs, prompts) == 0,
+             f"second serve call (jamba): programs_traced={traced}")
+    ref = serving.serve_paged_greedy(
+        params, dataclasses.replace(_reference(cfg), ssm_kernel=False),
+        prompts, n_new, **kw)
+    _check_outputs(ref, prompts, n_new, ref.metrics)
+    emit(phase="serve_paged/jamba", ok=True, tokens=tokens, **w.row(),
+         **_serve_stats(m), prefix_hits=m.prefix_hits,
+         conv_tail_restores=m.conv_tail_restores,
+         paged_operator=m.paged_operator, paged_ffn=m.paged_ffn,
+         state_bytes_slot=m.state_bytes_slot,
+         state_snapshots_taken=m.state_snapshots_taken,
+         state_snapshot_rows_hwm=m.state_snapshot_rows_hwm,
+         state_snapshot_evictions=m.state_snapshot_evictions,
          kv_write_path=m.paged_kv_write, attend_built=m.paged_decode_attend,
          programs_traced=traced,
          token_mismatch_vs_dense=_mismatch_share(outs, ref, prompts))

@@ -50,29 +50,38 @@ cold path (docs/DESIGN.md §19).
 
 What the plane asks of a model family it asks through ONE seam,
 :class:`PagedSpec` (``family.paged_spec(cfg)``): per layer an operator
-kind (attention | conv), an FFN kind (dense | moe) and a cache kind
+kind (attention | conv | mamba), an FFN kind (dense | moe) and a cache kind
 (pages | state), the layers grouped into :class:`Segment` s of whole
 periods that one ``lax.scan`` each can ride, and the family's own
 functions for each piece. :func:`paged_decode_step`,
 ``serving.paged_prefill`` / ``paged_suffix_prefill`` and
 :class:`PagedKV` read nothing else of a family. GPT-2
 (``transformer.paged_spec``) is "every layer attention + dense + pages";
-``lfm2`` mixes both operator kinds and both FFN kinds. A family without
-``paged_spec`` raises by name.
+``lfm2`` mixes both operator kinds and both FFN kinds, ``jamba`` Mamba
+and attention layers. A family without ``paged_spec`` raises by name.
 
 **Two kinds of state.** A layer whose cache kind is ``pages`` owns a
 layer of the pools above. A layer whose cache kind is ``state`` (a
-gated short conv: the last ``taps`` values of its gated input) owns a
-FIXED-size state a slot, ``conv`` ``[L_state, n_slots, *state_shape]``,
-carried by the decode step beside the pools; an idle slot's state is
-its own, as its parking page is. Because a radix hit, a COW copy or a
-resume continues a sequence from the END OF A WHOLE PAGE, the state at
-that point is kept WITH the page: ``pool['tail']`` ``[L_state, P,
-*state_shape]``, indexed by page id. ``scatter_prompt`` writes a page
-and its tail together, ``_copy`` copies both, a trie eviction frees
-both (the id), and a prefix hit starts the suffix prefill's state from
-the last matched page's tail (:meth:`PagedKV.restore_tail`). Only whole
-PROMPT pages enter the trie, so the decode step writes no tail.
+gated short conv: the last ``taps`` values of its gated input; a
+state-space scan: a matrix a channel and the conv's window) owns a
+FIXED-size state a slot, described by the spec as a tree of leaves
+(``PagedSpec.state``: a shape and a type each) and held ``[L_state,
+n_slots, *leaf]`` a leaf, carried WHOLE by the decode step beside the
+pools (``state['held']``); an idle slot's state is its own, as its
+parking page is. A radix hit or a resume continues a sequence from the
+END OF A WHOLE PAGE, so the state at such points is kept as SNAPSHOTS:
+:class:`SnapshotStore`, rows ``[L_state, n_snapshots + 1, *leaf]`` with
+a free list of their own (the last row is a sink for what a scatter
+program writes and nobody keeps). Which whole prompt pages get a row is
+the spec's rule (``snapshot_every``: every page where the state is
+small beside a page, every 4th where one snapshot outweighs 71 pages);
+``scatter_prompt`` writes pages and snapshots in one program, a radix
+match is cut back to the deepest matched page that holds a snapshot
+(:meth:`PagedKV.restore_tail` starts the suffix prefill's state from
+it), a freed page frees its row, and when no row is free the least
+recently used one goes and its page stops being a resume point. Only
+whole PROMPT pages enter the trie, so the decode step writes no
+snapshot, and a copy-on-write copy (a page no trie holds) needs none.
 """
 
 from __future__ import annotations
@@ -122,6 +131,9 @@ class PageAllocator:
         # page 0 first — deterministic layouts for reproducible tests.
         self._free = list(range(self.n_pages - 1, -1, -1))
         self._ref = [0] * self.n_pages
+        # Called with a page's id when it is reclaimed (PagedKV: the
+        # page's snapshot row goes with it).
+        self.on_free: Optional[Callable[[int], None]] = None
 
     @property
     def free_count(self) -> int:
@@ -161,6 +173,8 @@ class PageAllocator:
             # Keep the free list sorted descending so reclaimed pages
             # re-issue lowest-first too (determinism under churn).
             self._free.sort(reverse=True)
+            if self.on_free is not None:
+                self.on_free(page)
             return True
         return False
 
@@ -191,11 +205,20 @@ class RadixPrefixCache:
     hit depth at ``(S - 1) // page_tokens`` — the suffix keeps >= 1
     token and starts exactly at a page boundary, so every position a
     prefill or decode write touches lands in a freshly allocated page.
+
+    With ``snaps`` (a :class:`SnapshotStore`: the family keeps a state
+    beside its pages) a sequence can only be continued from a page that
+    holds a snapshot: ``match`` is cut back to the deepest such page,
+    ``insert`` adopts no page past the prompt's last snapshot (nothing
+    could resume from it) and hands a node whose page has lost its
+    snapshot the new page that has one, and of the leaves those that
+    are no resume point are evicted first.
     """
 
-    def __init__(self, alloc: PageAllocator, page_tokens: int):
+    def __init__(self, alloc: PageAllocator, page_tokens: int, snaps=None):
         self.alloc = alloc
         self.page_tokens = page_tokens
+        self.snaps = snaps
         self.root = _TrieNode()
         self._clock = 0
         self.hits = 0            # matches with depth >= 1 page
@@ -223,6 +246,11 @@ class RadixPrefixCache:
             nxt.stamp = stamp
             pages.append(nxt.page)
             node = nxt
+        if self.snaps is not None:
+            while pages and not self.snaps.has(pages[-1]):
+                pages.pop()
+            if pages:
+                self.snaps.touch(pages[-1])
         for p in pages:
             self.alloc.incref(p)
         if pages:
@@ -235,8 +263,11 @@ class RadixPrefixCache:
         into the trie; returns how many pages were newly adopted."""
         node, adopted = self.root, 0
         stamp = self._tick()
-        n_full = len(prompt) // self.page_tokens
-        for d in range(min(n_full, len(pages))):
+        n_full = min(len(prompt) // self.page_tokens, len(pages))
+        has = self.snaps.has if self.snaps is not None else None
+        while has and n_full and not has(pages[n_full - 1]):
+            n_full -= 1
+        for d in range(n_full):
             chunk = tuple(
                 int(t) for t in
                 prompt[d * self.page_tokens:(d + 1) * self.page_tokens])
@@ -246,20 +277,29 @@ class RadixPrefixCache:
                 node.children[chunk] = nxt
                 self.alloc.incref(pages[d])
                 adopted += 1
+            elif (has and nxt.page != pages[d] and has(pages[d])
+                  and not has(nxt.page)):
+                self.alloc.incref(pages[d])
+                self.alloc.decref(nxt.page)
+                nxt.page = pages[d]
             nxt.stamp = stamp
             node = nxt
         return adopted
 
     def evict_one(self) -> bool:
-        """Drop the least-recently-matched leaf (decref its page).
-        Returns False when the trie is empty."""
-        best = None  # (stamp, parent, key, node)
+        """Drop the least-recently-matched leaf (decref its page), of
+        those that are no resume point first. Returns False when the
+        trie is empty."""
+        best = None  # (rank, parent, key, node)
+        has = self.snaps.has if self.snaps is not None else None
         stack = [(self.root, None, None)]
         while stack:
             node, parent, key = stack.pop()
             if parent is not None and not node.children:
-                if best is None or node.stamp < best[0]:
-                    best = (node.stamp, parent, key, node)
+                rank = (node.stamp if has is None
+                        else (has(node.page), node.stamp))
+                if best is None or rank < best[0]:
+                    best = (rank, parent, key, node)
             for k, ch in node.children.items():
                 stack.append((ch, node, k))
         if best is None:
@@ -272,13 +312,77 @@ class RadixPrefixCache:
 
 
 # --------------------------------------------------------------------------
+# Snapshots of the state layers
+
+
+class SnapshotStore:
+    """The state at the end of some whole prompt pages, to continue a
+    sequence from there: device ``rows`` ``[L_state, n_rows + 1,
+    *leaf]`` a leaf of ``spec.state`` and the host's book of which page
+    holds which row. Its size is its own (``n_rows``), not the page
+    count's: one Mamba snapshot outweighs 71 pages. Row ``n_rows`` is
+    the SINK: what a scatter program writes for a page that keeps no
+    snapshot lands there. ``take`` hands a page a row, the least
+    recently used (taken or matched) one's when none is free; that
+    row's page is then no resume point any more."""
+
+    def __init__(self, spec: "PagedSpec", n_rows: int):
+        self.n_rows = int(n_rows)
+        self.rows = jax.tree.map(
+            lambda l: jnp.zeros((spec.n_state_layers, self.n_rows + 1)
+                                + l.shape, l.dtype), spec.state)
+        self._free = list(range(self.n_rows - 1, -1, -1))
+        self.row_of: Dict[int, int] = {}        # page -> row
+        self._stamp: Dict[int, int] = {}        # page -> last use
+        self._clock = 0
+        self.taken = self.evictions = self.rows_hwm = 0
+
+    @property
+    def sink(self) -> int:
+        return self.n_rows
+
+    def has(self, page: int) -> bool:
+        return page in self.row_of
+
+    def touch(self, page: int) -> None:
+        self._clock += 1
+        self._stamp[page] = self._clock
+
+    def take(self, page: int) -> int:
+        """A row for ``page`` (the sink when the store has none)."""
+        if page in self.row_of:
+            row = self.row_of[page]
+        elif self._free:
+            row = self._free.pop()
+        elif self.row_of:
+            old = min(self.row_of, key=self._stamp.__getitem__)
+            row = self.row_of.pop(old)
+            del self._stamp[old]
+            self.evictions += 1
+        else:
+            return self.sink
+        self.row_of[page] = row
+        self.touch(page)
+        self.taken += 1
+        self.rows_hwm = max(self.rows_hwm, len(self.row_of))
+        return row
+
+    def drop(self, page: int) -> None:
+        """``page`` was reclaimed: its row is free again."""
+        if page in self.row_of:
+            self._free.append(self.row_of.pop(page))
+            self._free.sort(reverse=True)
+            del self._stamp[page]
+
+
+# --------------------------------------------------------------------------
 # Device pool
 
 
 @dataclasses.dataclass(frozen=True)
 class LayerKind:
     """What one layer is to the paged plane."""
-    operator: str = "attention"      # "attention" | "conv"
+    operator: str = "attention"      # "attention" | "conv" | "mamba"
     ffn: str = "dense"               # "dense" | "moe"
     cache: str = "pages"             # "pages" | "state"
 
@@ -330,25 +434,38 @@ class PagedSpec:
     * ``qkv(cfg, lp, x, pos) -> q [B, 1, Hq, Dh], k, v [B, 1, Hkv,
       Dh]``, as they go into the cache (positions applied)
     * ``attn_out(cfg, lp, x, o) -> x``: residual + output projection
-    * ``state_op(cfg, lp, x, st [B, *state_shape]) -> (x, st)``: the
-      operator of a ``state`` layer, residual included
+    * ``state_op(cfg, lp, x, held, at) -> (x, held)``: the operator of
+      a ``state`` layer, residual included. ``held`` is the slots'
+      state of ALL the state layers, ``[L_state, B, *leaf]`` a leaf of
+      ``state``, and ``at`` this layer's index in it: the operator
+      reads and writes ``(at, slot)`` and hands the stack back whole (a
+      small state may slice its layer out and put it back; one of a
+      GB goes to a Pallas call whole, aliased to its result)
     * ``ffn(cfg, lp, x, kind) -> x``, and ``(x, idx [B, k])``, the
       experts each row chose, when ``kind`` is ``"moe"``
     * ``head(params, cfg, x) -> logits [B, vocab]`` f32
     * ``prefill(params, cfg, tokens [1, S], last_index, kv_int8,
       page_tokens) -> (logits [1, 1, vocab], one)``: ``one`` holds
       ``'k','v'[,'ks','vs']`` ``[L_pages, 1, Hkv, *, S]`` in cache layout
-      and, with state layers, ``'tail'`` ``[L_state, S // page_tokens,
-      *state_shape]`` (the state at the end of every whole page) and
-      ``'end'`` ``[L_state, *state_shape]`` (at ``last_index``)
+      and, with state layers, ``'tail'`` ``[L_state, S // (page_tokens
+      * snapshot_every), *leaf]`` a leaf (the state at the end of every
+      ``snapshot_every``-th whole page) and ``'end'`` ``[L_state,
+      *leaf]`` (at ``last_index``; padding behind it leaves no mark)
     * ``suffix_prefill(params, cfg, suffix, hk, hv, tail, last_index,
       kv_int8, page_tokens)``: the same for a suffix behind gathered
-      history ``hk``/``hv`` and the last matched page's ``tail``."""
+      history ``hk``/``hv`` and the last matched page's snapshot
+      ``tail`` ``[L_state, *leaf]``."""
     segments: Tuple[Segment, ...]
     n_kv_heads: int
     head_dim: int
     n_rep: int = 1                       # query heads a K/V head
-    state_shape: Tuple[int, ...] = ()    # one slot's state in a layer
+    # One slot's state in ONE state layer: a tree of
+    # ``jax.ShapeDtypeStruct`` (a gated short conv: one leaf; a
+    # state-space scan: the conv window and the scan's matrix, each in
+    # its own type). None without state layers.
+    state: Any = None
+    # Which whole prompt pages get a snapshot row: every n-th.
+    snapshot_every: int = 1
     n_experts: int = 0                   # of a "moe" FFN's router
     # Leaves of a "moe" FFN that ``ffn`` is handed WHOLE, still stacked
     # over the segment's repeats, with the repeat under ``lp["repeat"]``:
@@ -375,6 +492,13 @@ class PagedSpec:
     @property
     def n_state_layers(self) -> int:
         return sum(s.count("state") for s in self.segments)
+
+    @property
+    def state_bytes_slot(self) -> int:
+        """Bytes of one slot's state over all the state layers."""
+        return self.n_state_layers * sum(
+            math.prod(l.shape) * jnp.dtype(l.dtype).itemsize
+            for l in jax.tree.leaves(self.state))
 
     def built(self, what: str) -> str:
         """``ServingMetrics.paged_operator`` / ``paged_ffn``: the kinds
@@ -404,22 +528,17 @@ def init_page_pool(cfg, n_pages: int, page_tokens: int, n_slots: int,
     int8) over the ``L`` layers whose cache kind is ``pages``, with
     ``P = n_pages + n_slots`` — the trailing ``n_slots`` pages are the
     per-slot parking pages (module docstring), outside the allocator.
-    A pool IS a cache whose batch axis counts pages. With state layers,
-    also ``'tail'`` ``[L_state, P, *state_shape]``: each page's tail."""
+    A pool IS a cache whose batch axis counts pages."""
     from mpi_acx_tpu.models.decoding import new_kv_cache
     spec = spec or paged_spec(None, cfg)
     pool = new_kv_cache(spec.n_page_layers, n_pages + n_slots,
                         spec.n_kv_heads, spec.head_dim, page_tokens,
                         cfg.dtype, kv_int8)
     del pool["pos"]
-    if spec.n_state_layers:
-        pool["tail"] = jnp.zeros((spec.n_state_layers, n_pages + n_slots)
-                                 + spec.state_shape, cfg.dtype)
     return pool
 
 
 _POOL_KEYS = ("k", "v", "ks", "vs")        # what the decode step carries
-_PAGE_KEYS = _POOL_KEYS + ("tail",)        # what belongs to a page
 
 
 # --------------------------------------------------------------------------
@@ -445,16 +564,17 @@ def paged_decode_step(params, cfg, state, token, page_tokens: int,
     mirrors ``transformer.decode_step`` exactly — same _qkv/attend/ffn
     math, so active slots are bit-equal to the fixed-slot step).
     ``state`` = pool keys + ``'table'`` [B, max_pages] + ``'pos'`` [B]
-    and, for a family with state layers, ``'conv'`` [L_state, B,
-    *state_shape]; with ``'moe'`` [4] and ``'owns'`` [B] it also counts
-    its routing (:func:`_moe_tally`).
+    and, for a family with state layers, ``'held'`` ([L_state, B,
+    *leaf] a leaf); with ``'moe'`` [4] and ``'owns'`` [B] it also
+    counts its routing (:func:`_moe_tally`).
 
     An attention layer's fresh K/V for slot b lands at ``pool[i,
     table[b, pos_b // pt], :, :, pos_b % pt]``, ``i`` counting the
     layers with pages. Idle slots write their parking page (their table
     rows point nowhere else) and the page index is clipped so a
     long-idle slot's walking pos can never index past its table row. A
-    state layer reads and writes the slot's own row of ``conv``.
+    state layer reads and writes the slot's own rows of ``held``, which
+    its operator is handed whole (``PagedSpec.state_op``).
 
     Each :class:`Segment` is one ``lax.scan`` over its whole periods,
     the period's layers unrolled inside. The pools ride the scans
@@ -512,12 +632,8 @@ def paged_decode_step(params, cfg, state, token, page_tokens: int,
                        layer=at)
             x = spec.attn_out(cfg, lp, x, o)
         else:
-            st = lax.dynamic_index_in_dim(rest["conv"], at, 0,
-                                          keepdims=False)
-            x, st = spec.state_op(cfg, lp, x, st)
-            rest = dict(rest, conv=lax.dynamic_update_index_in_dim(
-                rest["conv"], st.astype(rest["conv"].dtype), at,
-                0))
+            x, held = spec.state_op(cfg, lp, x, rest["held"], at)
+            rest = dict(rest, held=held)
         if kind.ffn == "moe":
             x, idx = spec.ffn(cfg, lp, x, kind.ffn)
             if "moe" in rest:
@@ -528,7 +644,7 @@ def paged_decode_step(params, cfg, state, token, page_tokens: int,
         return x, pools, rest
 
     carry = (x, tuple(state[k] for k in keys),
-             {k: state[k] for k in ("conv", "moe") if k in state})
+             {k: state[k] for k in ("held", "moe") if k in state})
     pages_at = states_at = 0
     for seg in spec.segments:
         stacked = params[seg.key]
@@ -700,7 +816,8 @@ class PagedKV:
 
     def __init__(self, cfg, family, n_slots: int, max_len: int,
                  page_tokens: int, n_pages: int, kv_int8: bool = False,
-                 prefix_cache: bool = False):
+                 prefix_cache: bool = False,
+                 n_snapshots: Optional[int] = None):
         assert max_len % page_tokens == 0, \
             (f"max_len={max_len} must be a multiple of "
              f"page_tokens={page_tokens} (the block table tiles the "
@@ -718,15 +835,18 @@ class PagedKV:
         self.n_pages = int(n_pages)
         self.max_pages = max_len // page_tokens
         self.kv_int8 = bool(kv_int8)
-        self.alloc = PageAllocator(n_pages)
-        self.prefix = (RadixPrefixCache(self.alloc, page_tokens)
-                       if prefix_cache else None)
+        # Snapshot rows (module docstring): by default one for every
+        # page that the spec's rule can give one, so that none is ever
+        # evicted; fewer is a size, and costs resume points only.
+        self.n_snapshots = (pages_needed(n_pages, self.spec.snapshot_every)
+                            if n_snapshots is None else int(n_snapshots))
         self.pool = init_page_pool(cfg, n_pages, page_tokens, n_slots,
                                    kv_int8=kv_int8, spec=self.spec)
+        self._fresh_books(prefix_cache)
         # The slots' fixed state (module docstring), None without state
         # layers; the routing each chunk's steps counted ([4] as
-        # _moe_tally's) and the tails loaded to continue a sequence.
-        self.conv = self._fresh_conv()
+        # _moe_tally's) and the snapshots loaded to continue a sequence.
+        self.held = self._fresh_held()
         self.moe_chunks: List[Tuple[int, ...]] = []     # one a chunk
         self.tail_restores = 0
         # Slot b's parking page sits past the allocator's range.
@@ -742,11 +862,22 @@ class PagedKV:
 
     # -- device state ------------------------------------------------------
 
-    def _fresh_conv(self):
-        if not self.spec.n_state_layers:
-            return None
-        return jnp.zeros((self.spec.n_state_layers, self.n_slots)
-                         + self.spec.state_shape, self.cfg.dtype)
+    def _fresh_held(self):
+        return jax.tree.map(
+            lambda l: jnp.zeros((self.spec.n_state_layers, self.n_slots)
+                                + l.shape, l.dtype), self.spec.state)
+
+    def _fresh_books(self, prefix_cache: bool) -> None:
+        """Allocator, snapshot store and trie, all empty; a page that is
+        reclaimed frees its snapshot row."""
+        self.alloc = PageAllocator(self.n_pages)
+        self.snaps = (SnapshotStore(self.spec, self.n_snapshots)
+                      if self.spec.n_state_layers else None)
+        if self.snaps is not None:
+            self.alloc.on_free = self.snaps.drop
+        self.prefix = (RadixPrefixCache(self.alloc, self.page_tokens,
+                                        self.snaps)
+                       if prefix_cache else None)
 
     def device_state(self):
         if self._dev_table is None:
@@ -754,8 +885,8 @@ class PagedKV:
         state = {k: self.pool[k] for k in _POOL_KEYS if k in self.pool}
         state["table"] = self._dev_table
         state["pos"] = jnp.asarray(self.pos)
-        if self.conv is not None:
-            state["conv"] = self.conv
+        if self.held is not None:
+            state["held"] = self.held
         if self.spec.n_experts:
             # A slot owns a request exactly while it holds pages.
             state["owns"] = jnp.asarray([bool(p) for p in self.pages])
@@ -769,8 +900,8 @@ class PagedKV:
         # np.array (copy): np.asarray of a device array is a read-only
         # view, and the host mirror gets written by seat/release.
         self.pos = np.array(state["pos"], np.int32)
-        if "conv" in state:
-            self.conv = state["conv"]
+        if "held" in state:
+            self.held = state["held"]
         if "moe" in state:
             self.moe_chunks.append(tuple(int(n) for n in
                                          np.asarray(state["moe"])))
@@ -782,14 +913,16 @@ class PagedKV:
         self.pool = init_page_pool(self.cfg, self.n_pages,
                                    self.page_tokens, self.n_slots,
                                    kv_int8=self.kv_int8, spec=self.spec)
-        self.conv = self._fresh_conv()
-        self.alloc = PageAllocator(self.n_pages)
-        if self.prefix is not None:
-            hits, ev, reused = (self.prefix.hits, self.prefix.evictions,
-                                self.prefix.pages_reused)
-            self.prefix = RadixPrefixCache(self.alloc, self.page_tokens)
-            self.prefix.hits, self.prefix.evictions = hits, ev
-            self.prefix.pages_reused = reused
+        self.held = self._fresh_held()
+        was, was_snaps = self.prefix, self.snaps
+        self._fresh_books(was is not None)
+        if was is not None:
+            self.prefix.hits, self.prefix.evictions = was.hits, was.evictions
+            self.prefix.pages_reused = was.pages_reused
+        if was_snaps is not None:
+            self.snaps.taken, self.snaps.evictions = (was_snaps.taken,
+                                                      was_snaps.evictions)
+            self.snaps.rows_hwm = was_snaps.rows_hwm
         self.pages = [[] for _ in range(self.n_slots)]
         self.pos = np.zeros((self.n_slots,), np.int32)
         self.table = np.asarray(
@@ -836,13 +969,14 @@ class PagedKV:
         ``new_pos``. ``rid`` only labels the journey event (ACX_REQLOG,
         docs/DESIGN.md §20) — the allocator itself is request-blind.
         With state layers the slot's fixed state becomes ``state``
-        ``[L_state, *state_shape]``, the prefill's ``'end'`` (zeros
+        (``[L_state, *leaf]`` a leaf), the prefill's ``'end'`` (zeros
         when None: whatever the slot's last request left is dropped)."""
         assert not self.pages[b], (b, "seat of an occupied slot")
-        if self.conv is not None:
+        if self.held is not None:
             if state is None:
-                state = jnp.zeros_like(self.conv[:, 0])
-            self.conv = _seat_state(self.conv, state, jnp.int32(b))
+                state = jax.tree.map(lambda h: jnp.zeros_like(h[:, 0]),
+                                     self.held)
+            self.held = _seat_state(self.held, state, jnp.int32(b))
         self.pages[b] = list(prompt_pages) + list(fresh_pages)
         assert len(self.pages[b]) <= self.max_pages, \
             (b, len(self.pages[b]), self.max_pages)
@@ -889,8 +1023,9 @@ class PagedKV:
             raise RuntimeError(
                 "copy-on-write with a dry pool (admission should have "
                 "bounded the request)")
+        # (no snapshot goes along: no trie holds the copy, and the
+        # slot's own state is where the sequence continues from)
         self.pool = _copy(self.pool, jnp.int32(page), jnp.int32(got[0]))
-        self.tail_restores += "tail" in self.pool   # the tail went along
         self.pages[b][j] = got[0]
         self.alloc.decref(page)
         self._sync_row(b)
@@ -898,36 +1033,55 @@ class PagedKV:
 
     # -- prompt scatter / history gather -----------------------------------
 
-    def scatter_prompt(self, one, pages: List[int], start_page: int = 0
-                       ) -> None:
+    def scatter_prompt(self, one, pages: List[int], start_page: int = 0,
+                       whole: Optional[int] = None) -> None:
         """Write a prefilled cache (``one`` = {'k','v'[,'ks','vs']:
         [L, 1, H, *, S_bucket]}) into ``pages`` — page d takes bucket
         tokens [d*pt, (d+1)*pt) (zero-padded tokens past the prompt are
         never attended). ``start_page`` offsets the SOURCE rows only
         (0 for a cold full-prompt scatter; unused pages cost
-        nothing — only ``len(pages)`` pages are written)."""
-        if pages:
-            one = {k: one[k] for k in _PAGE_KEYS
-                   if k in one and k in self.pool}
-            self.pool = _scatter(self.pool, one,
-                                 jnp.asarray(pages, jnp.int32))
+        nothing — only ``len(pages)`` pages are written). With state
+        layers, ``one['tail']`` goes to the snapshot store in the same
+        program: of the first ``whole`` pages (the prompt's whole
+        pages; None: all that ``one`` has a tail for) every
+        ``snapshot_every``-th takes a row, the rest write the sink."""
+        if not pages:
+            return
+        idx, rows = [pages], []
+        if self.snaps is not None:
+            every = self.spec.snapshot_every
+            n_tail = jax.tree.leaves(one["tail"])[0].shape[1]
+            whole = len(pages) if whole is None else whole
+            rows = [self.snaps.take(pages[(j + 1) * every - 1])
+                    if (j + 1) * every <= whole else self.snaps.sink
+                    for j in range(min(len(pages) // every, n_tail))]
+        if rows:        # one upload: the pages, and under them the rows
+            idx.append(rows + [self.snaps.sink] * (len(pages) - len(rows)))
+        self.pool, snaps = _scatter(
+            self.pool, self.snaps.rows if rows else None,
+            {k: one[k] for k in _POOL_KEYS if k in one and k in self.pool},
+            one["tail"] if rows else None,
+            jnp.asarray(idx if rows else pages, jnp.int32))
+        if rows:
+            self.snaps.rows = snaps
 
     def gather_history(self, pages: List[int]):
         """Gather ``pages`` into contiguous [L, H, Dh, n*pt] history
         K/V (cache layout) in compute dtype (dequantizing int8 pages —
         the only page-resident form — through their f32 scales)."""
-        return _gather({k: v for k, v in self.pool.items() if k != "tail"},
-                       jnp.asarray(pages, jnp.int32), dtype=self.cfg.dtype)
+        return _gather(self.pool, jnp.asarray(pages, jnp.int32),
+                       dtype=self.cfg.dtype)
 
     def restore_tail(self, page: int):
-        """The state at the end of ``page`` ``[L_state, *state_shape]``,
-        to continue a sequence from there (a radix hit's suffix
-        prefill; a resume is one when the trie kept its pages); None
-        without state layers."""
-        if "tail" not in self.pool:
+        """The snapshot at the end of ``page`` (``[L_state, *leaf]`` a
+        leaf of the spec's state tree), to continue a sequence from
+        there (a radix hit's suffix prefill; a resume is one when the
+        trie kept its pages); None without state layers. ``page`` is
+        one a match returned last, so it holds a row."""
+        if self.snaps is None:
             return None
         self.tail_restores += 1
-        return _tail(self.pool["tail"], jnp.int32(page))
+        return _tail(self.snaps.rows, jnp.int32(self.snaps.row_of[page]))
 
 
 # PagedKV's programs, one compile per shape in jit's own cache:
@@ -951,38 +1105,48 @@ def _copy(pool, src, dst):
     return out
 
 
-@partial(jax.jit, donate_argnums=(0,))
-def _scatter(pool, one, pages_arr):
+@partial(jax.jit, donate_argnums=(0, 1))
+def _scatter(pool, snaps, one, tail, idx):
+    """Pages and snapshot rows in one program. ``idx`` is the pages
+    [n], or with snapshots to write [2, n]: the pages, and under them
+    the rows of ``snaps`` that ``tail`` ([L_state, m, *leaf] a leaf)
+    goes to, row j to ``idx[1, j]`` (the sink where no page keeps it)."""
     note_trace()
+    pages_arr = idx if tail is None else idx[0]
     pt, bucket = pool["k"].shape[-1], one["k"].shape[-1]
     for j in range(pages_arr.shape[0]):
         n = min(pt, bucket - j * pt)
         if n <= 0:
             break
         for key in one:
-            if key == "tail":           # [L_state, whole pages, ...]
-                if j >= one[key].shape[1]:
-                    continue
-                src = one[key][:, j]
-            else:
-                src = one[key][:, 0, ..., j * pt:j * pt + n]
+            src = one[key][:, 0, ..., j * pt:j * pt + n]
             pool[key] = lax.dynamic_update_slice(
                 pool[key], src[:, None].astype(pool[key].dtype),
                 _at_page(pool[key], pages_arr[j]))
-    return pool
+    if tail is not None:
+        m = jax.tree.leaves(tail)[0].shape[1]
+        for j in range(min(m, pages_arr.shape[0])):
+            snaps = jax.tree.map(
+                lambda rows, t: lax.dynamic_update_slice(
+                    rows, t[:, j:j + 1].astype(rows.dtype),
+                    _at_page(rows, idx[1, j])), snaps, tail)
+    return pool, snaps
 
 
 @jax.jit
-def _tail(tails, page):
+def _tail(snaps, row):
     note_trace()
-    return lax.dynamic_index_in_dim(tails, page, 1, keepdims=False)
+    return jax.tree.map(
+        lambda rows: lax.dynamic_index_in_dim(rows, row, 1, keepdims=False),
+        snaps)
 
 
 @partial(jax.jit, donate_argnums=(0,))
-def _seat_state(conv, state, b):
+def _seat_state(held, state, b):
     note_trace()
-    return lax.dynamic_update_index_in_dim(conv, state.astype(conv.dtype),
-                                           b, 1)
+    return jax.tree.map(
+        lambda h, s: lax.dynamic_update_index_in_dim(h, s.astype(h.dtype),
+                                                     b, 1), held, state)
 
 
 @partial(jax.jit, static_argnames="dtype")

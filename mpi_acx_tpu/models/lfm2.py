@@ -429,18 +429,24 @@ def paged_spec(cfg: Lfm2Config) -> kvpage.PagedSpec:
     """What ``serve_paged_greedy``'s plane asks of this family: pages
     for the attention layers ([L_attn, P, n_kv_heads, head_dim, pt],
     GQA-native through the shared write and walk), a fixed state
-    ``[conv_L_cache - 1, d_model]`` a slot a conv layer, the router's
-    width for the routing counters. int8 pages are not wired: the
-    tails would want a precision of their own."""
-    def decode_conv(cfg, lp, x, st):
+    ``[conv_L_cache - 1, d_model]`` a slot a conv layer (one leaf, a
+    snapshot of it with every whole prompt page: 8 KB beside a page's
+    512 KB), the router's width for the routing counters. int8 pages
+    are not wired: the tails would want a precision of their own."""
+    def decode_conv(cfg, lp, x, held, at):
+        # (a layer of it is 0.5 MB at 64 slots: sliced out and put back)
+        st = lax.dynamic_index_in_dim(held, at, 0, keepdims=False)
         x, zs = _conv_op(cfg, lp, x, st)
-        return x, zs[:, -(cfg.conv_L_cache - 1):]
+        st = zs[:, -(cfg.conv_L_cache - 1):]
+        return x, lax.dynamic_update_index_in_dim(
+            held, st.astype(held.dtype), at, 0)
 
     return kvpage.PagedSpec(
         segments=segments(cfg),
         n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim,
         n_rep=cfg.n_heads // cfg.n_kv_heads,
-        state_shape=(cfg.conv_L_cache - 1, cfg.d_model),
+        state=jax.ShapeDtypeStruct((cfg.conv_L_cache - 1, cfg.d_model),
+                                   cfg.dtype),
         n_experts=cfg.n_experts, kv_int8=False, moe_whole=_EXPERT_STACKS,
         ffn_built=(("dense", "_dense_ffn"),
                    ("moe", "sorted_expert_ffn/"

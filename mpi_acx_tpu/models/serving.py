@@ -105,7 +105,8 @@ class ServingMetrics:
     attend_pages_walked: int = 0
     attend_pages_grid: int = 0
     # paged: the kinds of operator and of FFN the step program was built
-    # from (kvpage.PagedSpec.built: "attention", "attention+conv";
+    # from (kvpage.PagedSpec.built: "attention", "attention+conv",
+    # "attention+mamba";
     # "dense:_mlp", "dense:_dense_ffn+moe:sorted_expert_ffn"), and what a
     # routed FFN had to do, counted ON THE DEVICE in every decode step
     # and MoE layer over the slots that OWN a request at the chunk's
@@ -123,9 +124,17 @@ class ServingMetrics:
     moe_experts: int = 0
     # (pairs, experts hit, fullest, layer-steps) of each decode chunk
     moe_by_chunk: List[tuple] = field(default_factory=list)
-    # paged: tails of fixed-state layers loaded to continue a sequence
-    # from a page's end (radix hits, resumes that hit, COW copies).
+    # paged: snapshots of the state layers loaded to continue a sequence
+    # from a page's end (radix hits, resumes that hit; the name is from
+    # when the only state was a conv's tail), and the snapshot store's
+    # book (kvpage.SnapshotStore): bytes of one slot's state over the
+    # state layers, rows handed to whole prompt pages, the most rows
+    # held at once, rows taken from the least recently used page.
     conv_tail_restores: int = 0
+    state_bytes_slot: int = 0
+    state_snapshots_taken: int = 0
+    state_snapshot_rows_hwm: int = 0
+    state_snapshot_evictions: int = 0
     slo_deferrals: int = 0        # paged: refills deferred by the SLO gate
     ttft_p50_s: float = 0.0
     ttft_p99_s: float = 0.0
@@ -1102,7 +1111,8 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
                        slo_admit=None,
                        on_token=None,
                        max_request_retries: int = 2,
-                       return_paged_state: bool = False) -> ServedBatch:
+                       return_paged_state: bool = False,
+                       n_snapshots: Optional[int] = None) -> ServedBatch:
     """Greedy continuous batching over a PAGED KV cache
     (models/kvpage.py): slots share a pool of ``page_tokens``-sized
     pages through per-slot block tables, so HBM-resident KV bytes
@@ -1150,7 +1160,10 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
     ``page_tokens`` defaults to $ACX_KV_PAGE_TOKENS (128 — the
     flash-decode block granularity) stepped down to divide ``max_len``;
     ``n_pages`` defaults to ``n_slots * max_len / page_tokens``
-    (capacity parity with the fixed-slot cache). The returned
+    (capacity parity with the fixed-slot cache); ``n_snapshots``, for a
+    family with state layers, is the size of its snapshot store
+    (``kvpage.SnapshotStore``; default: a row for every page the
+    family's rule gives one, so that none is evicted). The returned
     ServedBatch carries the paged counters (preemptions, prefix_hits,
     prefix_evictions, prefix_pages_reused, pages_hwm) in ``.metrics``;
     ``return_paged_state=True`` additionally exposes the live
@@ -1179,10 +1192,12 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
                         dispatch, int(argmax): WAITS for the device;
                         the process's first use of a shape traces and
                         loads its program here (programs_traced)
-        refill.scatter  scatter_prompt's DISPATCH; its device time
-                        lands in whichever span syncs next (the next
-                        refill.prefill or chunk.step)
-        refill.seat     pkv.seat, prefix.insert, bookkeeping, the first
+        refill.scatter  scatter_prompt's DISPATCH (pages, and the
+                        snapshots of a state family with them); its
+                        device time lands in whichever span syncs next
+                        (the next refill.prefill or chunk.step)
+        refill.seat     pkv.seat (a state family's end state written to
+                        the slot), prefix.insert, bookkeeping, the first
                         on_token
         chunk.grow      grow_for_chunk (preemptions inside) + COW guard
         chunk.upload    pkv.device_state()
@@ -1222,7 +1237,8 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
                       page_budget=n_pages, page_tokens=pt)
 
     pkv = kvpage.PagedKV(cfg, family, n_slots, max_len, pt, n_pages,
-                         kv_int8=kv_int8, prefix_cache=prefix_cache)
+                         kv_int8=kv_int8, prefix_cache=prefix_cache,
+                         n_snapshots=n_snapshots)
 
     # One compile per (bucket) / (suffix bucket, history length) a
     # PROCESS: the module-level programs, in jit's own cache. The
@@ -1309,7 +1325,8 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
                             fresh_pages=len(fresh))
                 if hit_pages:
                     # Radix hit: prefill ONLY the suffix against the
-                    # cached pages' gathered history.
+                    # cached pages' gathered history (with state layers
+                    # the match ends at a page that holds a snapshot).
                     P = len(hit_pages) * pt
                     suffix = prompt[P:]
                     padded = _padded(suffix, max_len - P, cfg.max_seq - P)
@@ -1320,15 +1337,17 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
                 else:
                     padded = _padded(prompt, max_len, cfg.max_seq)
                     logits, one = prefill_fn(jnp.asarray(padded), S - 1)
-                # The pages (and their tails) go to the pool, the fixed
-                # state at the prompt's end to the slot.
+                # The pages go to the pool (snapshots of the state at
+                # whole prompt pages' ends with them), the fixed state
+                # at the prompt's end to the slot.
                 end = one.get("end")
                 one = {k: v for k, v in one.items()
                        if k not in ("pos", "end")}
                 first = int(jnp.argmax(logits[0, 0]))   # the host waits
                 reqlog.emit("prefill_end", rid, first_token=first)
             with ph("refill.scatter", rid=rid) as scatter:
-                pkv.scatter_prompt(one, fresh)
+                pkv.scatter_prompt(one, fresh,
+                                   whole=(S - len(hit_pages) * pt) // pt)
         except Exception as exc:  # noqa: BLE001 — any device failure
             for p in hit_pages + fresh:
                 pkv.alloc.decref(p)
@@ -1504,6 +1523,11 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
             moe_experts=pkv.spec.n_experts,
             moe_by_chunk=list(pkv.moe_chunks),
             conv_tail_restores=pkv.tail_restores,
+            state_bytes_slot=pkv.spec.state_bytes_slot,
+            **({} if pkv.snaps is None else dict(
+                state_snapshots_taken=pkv.snaps.taken,
+                state_snapshot_rows_hwm=pkv.snaps.rows_hwm,
+                state_snapshot_evictions=pkv.snaps.evictions)),
             attend_pages_walked=pages_walked,
             attend_pages_grid=pages_grid,
             slo_deferrals=n_slo_defer,

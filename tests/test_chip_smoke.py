@@ -158,3 +158,9 @@ def test_phase_runs_at_tiny_size(phase, capsys, loopback_runtime_closed):
         assert 0 < row["moe_live_expert_share"] <= 1
         assert row["conv_tail_restores"] >= 2 <= row["prefix_hits"]
         assert row["programs_traced"][1] == 0
+        row = by["serve_paged/jamba"]
+        assert row["paged_operator"] == "attention+mamba"
+        assert row["conv_tail_restores"] >= 2 <= row["prefix_hits"]
+        assert 0 < row["state_snapshot_rows_hwm"] <= 4
+        assert row["state_snapshots_taken"] >= 2
+        assert row["state_bytes_slot"] > 0 and row["programs_traced"][1] == 0

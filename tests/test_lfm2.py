@@ -112,7 +112,7 @@ def test_a_family_without_a_spec_and_int8_pages_raise_by_name():
     assert spec.built("ffn") == "dense:_mlp"
     spec = kvpage.paged_spec(lfm2, CFG)
     assert (spec.n_page_layers, spec.n_state_layers, spec.n_rep) == (2, 7, 2)
-    assert spec.state_shape == (2, 64)
+    assert spec.state.shape == (2, 64) and spec.snapshot_every == 1
     assert spec.built("operator") == "attention+conv"
 
 
@@ -234,7 +234,7 @@ def test_pages_and_conv_state_the_chunk_writes_are_the_references(tree):
                                k, atol=1e-5, rtol=0)
     np.testing.assert_allclose(np.asarray(gv)[..., :T].transpose(0, 3, 1, 2),
                                v, atol=1e-5, rtol=0)
-    np.testing.assert_allclose(np.asarray(pkv.conv)[:, 0], z[:, T - 2:T],
+    np.testing.assert_allclose(np.asarray(pkv.held)[:, 0], z[:, T - 2:T],
                                atol=1e-5, rtol=0)
     for j in range(n // PT):
         np.testing.assert_allclose(

@@ -497,11 +497,10 @@ def test_lfm2_decode_chunk_compiles_and_moves_no_pool(v5e):
     pool = jax.eval_shape(lambda: kvpage.init_page_pool(
         cfg, LFM2_PAGES - LFM2_B, PAGE, LFM2_B, spec=spec))
     assert pool["k"].shape == (2, LFM2_PAGES, 8, 64, PAGE)
-    assert pool["tail"].shape == (7, LFM2_PAGES, 2, 2048)
     state = dict(k=pool["k"], v=pool["v"],
                  table=_s((LFM2_B, MAX_LEN // PAGE), jnp.int32),
                  pos=_s((LFM2_B,), jnp.int32),
-                 conv=_s((7, LFM2_B, 2, 2048), jnp.bfloat16),
+                 held=_s((7, LFM2_B, 2, 2048), jnp.bfloat16),
                  owns=_s((LFM2_B,), jnp.bool_), moe=_s((4,), jnp.int32))
     keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0), LFM2_B))
     step = kvpage.make_paged_step_fn(params, cfg, lfm2, 2, PAGE)
@@ -546,3 +545,183 @@ def test_lfm2_prefill_compiles_for_v5e(bucket, v5e):
     assert f"f32[{4 * bucket},1536]" in text and "%gmm" in text
     assert ("%flash_attention" in text) == (bucket == 1024)
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+# -- a state that is a matrix a channel (PR 33) -------------------------------
+
+JAMBA_B, JAMBA_PAGES, JAMBA_LEN = 128, 1280 + 128, 1280    # + parking pages
+_F32 = jnp.float32
+
+
+def _jamba():
+    """AI21-Jamba2-3B at every published width, bf16 weights. Shapes
+    only."""
+    from mpi_acx_tpu.models import jamba
+    cfg = jamba.jamba2_3b()
+    params = jax.eval_shape(lambda: jamba.cast_params(
+        jamba.init_params(jax.random.key(0), cfg)))
+    return jamba, cfg, params
+
+
+def _ssm_case(name):
+    """(function, argument shapes) at the configuration's widths: 5120
+    channels of 16 numbers, 26 layers, 128 slots."""
+    from mpi_acx_tpu.ops import ssm
+    N, C, bf16 = 16, 5120, jnp.bfloat16
+    if name == "update":
+        return ssm.ssm_update, [
+            _s((26, JAMBA_B, N, C), _F32), _s((), jnp.int32),
+            _s((JAMBA_B, C), _F32), _s((JAMBA_B, C), bf16),
+            _s((JAMBA_B, C), bf16), _s((JAMBA_B, N), _F32),
+            _s((JAMBA_B, N), _F32), _s((N, C), _F32), _s((C,), _F32)]
+    S = int(name.split("_")[1])
+    return (lambda *a: ssm.ssm_scan(*a, snapshot=512, block=PAGE)), [
+        _s((S, C), bf16), _s((S, C), _F32), _s((S, C), bf16),
+        _s((S, N), _F32), _s((S, N), _F32), _s((N, C), _F32), _s((C,), _F32),
+        _s((N, C), _F32)]
+
+
+@pytest.mark.parametrize("name", ["update", "scan_64", "scan_512",
+                                  "scan_1024"])
+def test_ssm_kernels_compile_for_v5e(name, v5e):
+    """``ops/ssm.py``'s two Pallas calls at the published widths: the
+    update with the 1.09 GB stacked state aliased to its result and the
+    layer a prefetched scalar; the scan over a bucket shorter than a
+    page, of one snapshot, and of two."""
+    fn, args = _ssm_case(name)
+    donate = (0,) if name == "update" else ()
+    compiled = jax.jit(fn, donate_argnums=donate).lower(
+        *_place(args, v5e)).compile()
+    text = compiled.as_text()
+    kernel = "%ssm_update" if name == "update" else "%ssm_scan"
+    assert kernel in text and "tpu_custom_call" in text
+    if name == "update":
+        # in place: the state is neither copied in front of the call
+        # nor allocated a second time behind it
+        assert not _state_movers(text)
+        assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    else:
+        snaps = int(name.split("_")[1]) // 512
+        # (a row more than the snapshots kept; the bucket of 64 hands
+        # out none: a call of two results)
+        assert (f"f32[{snaps + 1},16,5120]" in text) == bool(snaps)
+
+
+def _state_movers(text):
+    """Instructions of a compiled program that mention an array the
+    size of the slots' stacked scan state, or of one layer of it, and
+    are anything but plumbing or a Mosaic call; and any ``copy`` of the
+    stacked conv windows (XLA's fusions read a layer of those in place
+    and write it back in place: a dynamic-slice inside a fusion and a
+    dynamic-update-slice as a fusion's root are not moves)."""
+    scan_state = re.compile(rf"f32\[(?:26,)?{JAMBA_B},16,5120\]")
+    windows = re.compile(rf" = bf16\[26,{JAMBA_B},15360\].*\scopy\(")
+    plumbing = {"parameter", "get-tuple-element", "tuple", "while",
+                "bitcast", "custom-call"}
+    found = []
+    for line in text.splitlines():
+        op = re.search(r"\s([a-z][a-z0-9-]*)\(", line)
+        if " = " not in line or not op:
+            continue
+        result = line.split(" = ", 1)[1].split(" ", 1)[0]
+        if ((scan_state.search(result) and op.group(1) not in plumbing)
+                or windows.search(line)):
+            found.append(line.strip()[:160])
+    return found
+
+
+def test_state_movers_guard_sees_a_moved_state(v5e):
+    """The guard itself: the plain update slices the layer out of the
+    stack and puts it back, which it must report."""
+    from mpi_acx_tpu.ops import ssm
+    _, args = _ssm_case("update")
+    text = jax.jit(ssm.ssm_update_ref, donate_argnums=(0,)).lower(
+        *_place(args, v5e)).compile().as_text()
+    assert _state_movers(text)
+
+
+def test_jamba_decode_chunk_compiles_and_moves_no_state(v5e):
+    """``paged_decode_chunk`` as a serve call binds it for the Jamba
+    cell's geometry (128 slots, 1,408 pages of 128 tokens, 14 layers a
+    scan body): the shared write and the live-page walk at ONE K/V head
+    of 128 under 20 query heads, ``ssm_update`` with the result shape
+    the benchmark's reader expects, no instruction that moves a pool,
+    the stacked scan state (1.09 GB) or a layer of it, and temporaries
+    far below a chip."""
+    jamba, cfg, params = _jamba()
+    spec = kvpage.paged_spec(jamba, cfg)
+    pool = jax.eval_shape(lambda: kvpage.init_page_pool(
+        cfg, JAMBA_PAGES - JAMBA_B, PAGE, JAMBA_B, spec=spec))
+    assert pool["k"].shape == (2, JAMBA_PAGES, 1, 128, PAGE)
+    assert set(pool) == {"k", "v"}
+    held = jax.tree.map(lambda l: _s((26, JAMBA_B) + l.shape, l.dtype),
+                        spec.state)
+    assert held["ssm"].shape == (26, JAMBA_B, 16, 5120)
+    assert held["conv"].shape == (26, JAMBA_B, 3 * 5120)
+    state = dict(k=pool["k"], v=pool["v"],
+                 table=_s((JAMBA_B, JAMBA_LEN // PAGE), jnp.int32),
+                 pos=_s((JAMBA_B,), jnp.int32), held=held)
+    keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0),
+                                                   JAMBA_B))
+    step = kvpage.make_paged_step_fn(params, cfg, jamba, 2, PAGE)
+    compiled = step.func.lower(
+        *_place([*step.args, state, _s((JAMBA_B,), jnp.int32), keys], v5e),
+        **step.keywords).compile()
+    text = compiled.as_text()
+    calls = set(re.findall(r"%([a-z_]+)[.0-9]* = (\(?[a-z0-9]+\[[0-9,]*\])",
+                           "\n".join(l for l in text.splitlines()
+                                     if "tpu_custom_call" in l)))
+    assert calls == {("paged_flash_decode_attend", "bf16[128,1,20,128]"),
+                     ("paged_kv_write", "(bf16[2,1408,1,128,128]"),
+                     ("ssm_update", "(f32[128,5120]")}
+    # 13 Mamba layers a scan body, each its own call on the whole stack
+    assert len(re.findall(r"%ssm_update[.0-9]* = ", text)) == 13
+    assert not _pool_movers(text, pool["k"].shape)
+    assert not _state_movers(text), "\n".join(_state_movers(text))
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("bucket", [64, 1024])
+def test_jamba_prefill_compiles_for_v5e(bucket, v5e):
+    """``serving.paged_prefill`` for the family at the smallest and the
+    largest bucket the cell reaches: ``ssm_scan`` (two snapshots at
+    1024), and flash attention (the one K/V head repeated) at 1024."""
+    from mpi_acx_tpu.models import serving
+    jamba, cfg, params = _jamba()
+    compiled = serving.paged_prefill.lower(
+        *_place([params, _s((1, bucket), jnp.int32), _s((), jnp.int32)],
+                v5e), cfg=cfg, family=jamba, kv_int8=False, on_tpu=True,
+        page_tokens=PAGE).compile()
+    text = compiled.as_text()
+    assert "%ssm_scan" in text and f"f32[{bucket},5120]" in text
+    assert ("%flash_attention" in text) == (bucket == 1024)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("family,sha,length", [
+    ("gpt2", "eba9c26cd9e53be9", 29812), ("lfm2", "9b50436dd25fd033", 128490)])
+def test_the_accepted_families_chunk_jaxprs_are_the_parents(family, sha,
+                                                            length,
+                                                            monkeypatch):
+    """The widened state seam changed nothing the accepted cells trace:
+    ``paged_decode_chunk``'s jaxpr for a tiny GPT-2 and a tiny LFM2, as
+    text, is the one commit 1b2bb85 (PR 32) gives, to the byte (its
+    hash and length, taken there with this function)."""
+    import hashlib
+
+    from mpi_acx_tpu.models import lfm2
+    monkeypatch.setattr(backend, "on_tpu", lambda: False)
+    if family == "lfm2":
+        fam, cfg = lfm2, lfm2.tiny_lfm2()
+        params = lfm2.cast_params(lfm2.init_params(jax.random.key(0), cfg))
+    else:
+        fam, cfg = None, tfm.tiny_config()
+        params = tfm.init_params(jax.random.key(0), cfg)
+    state = kvpage.PagedKV(cfg, fam, 4, 128, 16, 32).device_state()
+    text = str(jax.make_jaxpr(
+        lambda p, s, t, k: kvpage.paged_decode_chunk.__wrapped__(
+            p, s, t, k, cfg=cfg, chunk=2, page_tokens=16, on_tpu=False,
+            family=fam))(params, state, jnp.zeros((4,), jnp.int32),
+                         jax.random.split(jax.random.key(0), 4)))
+    assert (hashlib.sha256(text.encode()).hexdigest()[:16], len(text)) == (
+        sha, length)
