@@ -241,6 +241,13 @@ def _serve_stats(m):
             "steps": m.steps}
 
 
+def _rewrites_per_token(m):
+    """Pages a layer's flushes read and wrote back for each token it
+    stored: 1 / chunk to 2 / chunk with the chunk's stage (the write a
+    token that it replaced read 1)."""
+    return round(m.kv_page_rewrites / max(m.kv_tokens_staged, 1), 4)
+
+
 def _seated_batch(size: Size, vocab: int, seed: int):
     """One prompt per slot, lengths spread over one bucket, for the
     first-step logit comparison."""
@@ -355,6 +362,7 @@ def phase_serve_paged(size: Size = FULL, seed: int = 0,
              prefix_hits=outs.metrics.prefix_hits,
              pages_hwm=outs.metrics.pages_hwm,
              kv_write_path=outs.metrics.paged_kv_write,
+             kv_page_rewrites_per_token=_rewrites_per_token(outs.metrics),
              attend_built=outs.metrics.paged_decode_attend,
              attend_live_page_share=round(
                  outs.metrics.attend_live_share, 4),
@@ -410,7 +418,9 @@ def _serve_paged_lfm2(size: Size, seed: int):
          paged_operator=m.paged_operator, paged_ffn=m.paged_ffn,
          moe_live_expert_share=round(m.moe_live_expert_share, 4),
          moe_load_max_over_mean=round(m.moe_load_max_over_mean, 3),
-         kv_write_path=m.paged_kv_write, attend_built=m.paged_decode_attend,
+         kv_write_path=m.paged_kv_write,
+         kv_page_rewrites_per_token=_rewrites_per_token(m),
+         attend_built=m.paged_decode_attend,
          programs_traced=traced,
          token_mismatch_vs_dense=_mismatch_share(outs, ref, prompts))
 
@@ -465,7 +475,9 @@ def _serve_paged_jamba(size: Size, seed: int):
          state_snapshots_taken=m.state_snapshots_taken,
          state_snapshot_rows_hwm=m.state_snapshot_rows_hwm,
          state_snapshot_evictions=m.state_snapshot_evictions,
-         kv_write_path=m.paged_kv_write, attend_built=m.paged_decode_attend,
+         kv_write_path=m.paged_kv_write,
+         kv_page_rewrites_per_token=_rewrites_per_token(m),
+         attend_built=m.paged_decode_attend,
          programs_traced=traced,
          token_mismatch_vs_dense=_mismatch_share(outs, ref, prompts))
 

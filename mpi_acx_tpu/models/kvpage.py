@@ -22,7 +22,10 @@ block table:
   pages of P are per-slot PARKING pages: an idle slot's table points
   every entry at its own parking page, so the lockstep decode step's
   writes for idle slots land somewhere harmless instead of corrupting
-  pages a live request owns.
+  pages a live request owns. A decode CHUNK does not write a page a
+  token: it keeps its own tokens in a stage and rewrites each page it
+  touched once, after its steps (:func:`paged_decode_chunk`); between
+  chunks every page is complete.
 * **Block tables** — host-side ``[n_slots, max_pages]`` int32 rows
   (mirrored to the device per step) mapping token position
   ``t`` of slot ``b`` to pool page ``table[b, t // page_tokens]``.
@@ -589,23 +592,30 @@ def paged_decode_step(params, cfg, state, token, page_tokens: int,
     page Mosaic cannot tile, ``select_paged_kv_write`` /
     ``select_paged_decode_attend`` hand back the dense pair (``.at[]
     .set`` on a sliced layer, gather + dense attend): the same values,
-    the bit-equality anchor."""
-    from mpi_acx_tpu.ops.flash_decode import (select_paged_decode_attend,
-                                              select_paged_kv_write)
+    the bit-equality anchor.
+
+    With ``state['stage']`` (a decode chunk's ``(arrays, step)``:
+    :func:`paged_decode_chunk`) the step writes NO page: the fresh K/V
+    go to row ``step`` of the stage (``flash_decode.stage_put``), the
+    attend reads the pool
+    up to the slot's position at the chunk's start and the stage's
+    tokens behind it, and the chunk flushes the stage into the pages
+    once. A step by itself has no stage and writes its page."""
+    from mpi_acx_tpu.ops.flash_decode import (chunk_write_pages,
+                                              select_paged_decode_attend,
+                                              select_paged_kv_write,
+                                              stage_put)
     from mpi_acx_tpu.ops.kvquant import kv_quant
 
     spec = paged_spec(family, cfg)
     table, pos = state["table"], state["pos"]
-    B, max_pages = table.shape
     keys = tuple(k for k in _POOL_KEYS if k in state)   # k, v[, ks, vs]
     quant = "ks" in keys
     x = spec.embed(params, cfg, token, pos)
 
     # Slot b's token column: distinct pages per slot (each slot owns its
     # pages; idle slots own their parking page), so writes never collide.
-    write_page = jnp.take_along_axis(
-        table, jnp.minimum(pos // page_tokens, max_pages - 1)[:, None],
-        axis=1)[:, 0]                                  # [B]
+    write_page = chunk_write_pages(table, pos, 1, page_tokens)      # [B, 1]
     off = pos % page_tokens
 
     write = select_paged_kv_write(cfg.decode_flash, page_tokens)
@@ -623,13 +633,19 @@ def paged_decode_step(params, cfg, state, token, page_tokens: int,
             if quant:
                 k, ks = kv_quant(k)
                 v, vs = kv_quant(v)
-            pools = write(pools, (k, v, ks, vs) if quant else (k, v),
-                          at, write_page, off)
+            fresh = (k, v, ks, vs) if quant else (k, v)
+            if step is None:
+                pools = write(pools, fresh, at, write_page, off)
+                stage = None
+            else:
+                rest = dict(rest, stage=stage_put(rest["stage"], fresh, at,
+                                                  step))
+                stage = rest["stage"], step
             kp, vp = pools[:2]
             if quant:
                 kp, vp = (kp, pools[2]), (vp, pools[3])
             o = attend(q, kp, vp, table, pos, page_tokens, spec.n_rep,
-                       layer=at)
+                       layer=at, stage=stage)
             x = spec.attn_out(cfg, lp, x, o)
         else:
             x, held = spec.state_op(cfg, lp, x, rest["held"], at)
@@ -643,8 +659,11 @@ def paged_decode_step(params, cfg, state, token, page_tokens: int,
             x = spec.ffn(cfg, lp, x, kind.ffn)
         return x, pools, rest
 
-    carry = (x, tuple(state[k] for k in keys),
-             {k: state[k] for k in ("held", "moe") if k in state})
+    rest = {k: state[k] for k in ("held", "moe") if k in state}
+    step = None
+    if "stage" in state:
+        rest["stage"], step = state["stage"]
+    carry = (x, tuple(state[k] for k in keys), rest)
     pages_at = states_at = 0
     for seg in spec.segments:
         stacked = params[seg.key]
@@ -681,6 +700,8 @@ def paged_decode_step(params, cfg, state, token, page_tokens: int,
         pages_at += repeats * n_pg
         states_at += repeats * n_st
     x, pools, rest = carry
+    if step is not None:
+        rest["stage"] = rest["stage"], step + 1
     out = dict(zip(keys, pools), **rest)
     out["table"] = table
     out["pos"] = pos + 1
@@ -722,7 +743,30 @@ def note_trace() -> None:
          donate_argnames="state")
 def paged_decode_chunk(params, state, tok, keys, *, cfg, chunk,
                        page_tokens, on_tpu, family=None):
+    """``chunk`` greedy steps of :func:`paged_decode_step` as ONE
+    program. Every slot writes at the same chunk step whatever its
+    ``pos``, so the chunk's fresh K/V go to a STAGE, a scratch of this
+    program addressed ``(layer, slot, ..., step)``
+    (``flash_decode.new_kv_stage``: what it weighs), which the attend
+    reads behind the pool; after the steps the stage is flushed: a
+    scan over the layers with pages, in each ONE ``paged_kv_write`` of
+    ``chunk`` tokens a slot (one for each run of a page's length where
+    a chunk is longer than a page: ``paged_kv_write_runs``), so that a
+    page is read and written once a chunk (twice where the tokens cross
+    into the next page) and not once a token. Between chunks every page is complete: what the host
+    side reads of a pool (``grow``, copy-on-write, ``scatter_prompt``,
+    the trie, ``gather_history``) sees what a chunk of one-token writes
+    left, bit for bit."""
+    from mpi_acx_tpu.ops.flash_decode import (new_kv_stage,
+                                              paged_kv_write_runs,
+                                              select_paged_kv_write,
+                                              stage_tokens)
     note_trace()
+    pool_keys = tuple(k for k in _POOL_KEYS if k in state)
+    table, pos0 = state["table"], state["pos"]
+    state = dict(state, stage=(
+        new_kv_stage([state[k] for k in pool_keys], table.shape[0], chunk),
+        jnp.int32(0)))
 
     def one(carry, _):
         state, tok, keys = carry
@@ -732,7 +776,16 @@ def paged_decode_chunk(params, state, tok, keys, *, cfg, chunk,
         return (state, nxt, keys), nxt
     (state, _, keys), toks = lax.scan(one, (state, tok, keys), None,
                                       length=chunk)
-    return state, toks, keys
+
+    stage, _ = state.pop("stage")
+    write = select_paged_kv_write(cfg.decode_flash, page_tokens)
+
+    def flush(pools, layer):
+        return paged_kv_write_runs(write, pools, stage_tokens(stage, layer),
+                                   layer, table, pos0, page_tokens), None
+    pools, _ = lax.scan(flush, tuple(state[k] for k in pool_keys),
+                        jnp.arange(stage[0].shape[0]))
+    return dict(state, **dict(zip(pool_keys, pools))), toks, keys
 
 
 def make_paged_step_fn(params, cfg, family, chunk: int,
@@ -931,13 +984,31 @@ class PagedKV:
         self._dev_table = None
 
     def live_pages(self, chunk: int) -> int:
-        """Pages one layer's attends fetch over the next ``chunk``
-        steps: every slot, owned or idle, walks its ``pos`` and reads
-        ``pos // page_tokens + 1`` pages a step, at most its table row
-        (an idle slot's are its parking page, again and again)."""
-        ahead = self.pos[:, None] + np.arange(chunk)[None]
-        return int(np.minimum(ahead // self.page_tokens + 1,
-                              self.max_pages).sum())
+        """Pages one layer's attends fetch out of the pool over the
+        next ``chunk`` steps: every slot, owned or idle, reads in each
+        step the pages that hold a token below its ``pos`` now (the
+        chunk's own tokens come from the stage), at least one and at
+        most its table row (an idle slot's are its parking page, again
+        and again)."""
+        pt = self.page_tokens
+        return chunk * int(np.clip((self.pos + pt - 1) // pt, 1,
+                                   self.max_pages).sum())
+
+    def chunk_rewrites(self, chunk: int) -> int:
+        """Pages one layer's flush reads and writes back after the
+        next ``chunk`` steps (``flash_decode.paged_kv_write_runs``): for
+        each run of at most a page's tokens a page a slot, two where the
+        run crosses into another (an idle slot's next page is its
+        parking page again)."""
+        pt, rows, total = self.page_tokens, np.arange(self.n_slots), 0
+        for s in range(0, chunk, pt):
+            pos = self.pos + s
+            first = np.minimum(pos // pt, self.max_pages - 1)
+            after = np.minimum(first + 1, self.max_pages - 1)
+            crosses = ((pos % pt + min(pt, chunk - s) > pt)
+                       & (self.table[rows, after] != self.table[rows, first]))
+            total += self.n_slots + int(crosses.sum())
+        return total
 
     # -- table bookkeeping -------------------------------------------------
 

@@ -93,14 +93,14 @@ class ServingMetrics:
     prefix_evictions: int = 0     # paged: trie pages evicted under pressure
     prefix_pages_reused: int = 0  # paged: prompt pages seated from the trie
     pages_hwm: int = 0            # paged: pool pages-in-use high-water mark
-    paged_kv_write: str = ""      # paged: the write the step program was built
-                                  # with (flash_decode.select_paged_kv_write)
-    # paged: the attend the step program was built with
-    # (flash_decode.select_paged_decode_attend), and what its walk had to
-    # do, counted on the host at each chunk's start (PagedKV.live_pages):
-    # the pages a layer's attends fetch over the chunk, every slot
-    # walking its ``pos``, and ``n_slots x max_pages x chunk``, the
-    # steps of the (slot, page) grid the kernel ran until PR 30.
+    paged_kv_write: str = ""      # paged: select_paged_kv_write's choice, and
+    # what it had to do (PR 36): the tokens a layer staged, the pages its
+    # flushes rewrote (PagedKV.chunk_rewrites), from pos at a chunk's start
+    kv_tokens_staged: int = 0
+    kv_page_rewrites: int = 0
+    # paged: select_paged_decode_attend's choice, and the pages a layer's
+    # attends fetch out of the pool (PagedKV.live_pages) over ``n_slots x
+    # max_pages x chunk``, the (slot, page) grid's steps until PR 30.
     paged_decode_attend: str = ""
     attend_pages_walked: int = 0
     attend_pages_grid: int = 0
@@ -1266,6 +1266,7 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
     # prefill_s, refill_host_s), the first on the entry clock.
     refill_times = [(0.0, 0.0, 0.0)] * len(prompts)
     n_preempts = n_slo_defer = pages_walked = pages_grid = 0
+    rewritten = staged = 0
     # Requests currently evicted by page pressure: membership here turns
     # the next successful seat into a journey "resume" event.
     preempted_rids: set = set()
@@ -1480,6 +1481,8 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
         with ph("chunk.upload", step=step_no) as upload:
             pages_walked += pkv.live_pages(chunk)
             pages_grid += n_slots * max_pages * chunk
+            rewritten += pkv.chunk_rewrites(chunk)
+            staged += chunk * n_slots
             state = pkv.device_state()
         with ph("chunk.step", step=step_no) as stepped:
             try:
@@ -1488,9 +1491,8 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
                 pkv.absorb(state)
             except Exception as exc:  # noqa: BLE001 — any device failure
                 book.step_failed(exc)
-                # The step donated the pool buffers: rebuild from zeros
-                # and drop every reference (prefix cache included — its
-                # pages lived in the donated pool).
+                # The step donated the pool buffers: rebuild from zeros and
+                # drop every reference (the prefix cache's pages lived there).
                 pkv.reset_pool()
                 continue
             block = np.asarray(toks, np.int32)       # [chunk, B]: waits
@@ -1530,6 +1532,8 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
                 state_snapshot_evictions=pkv.snaps.evictions)),
             attend_pages_walked=pages_walked,
             attend_pages_grid=pages_grid,
+            kv_page_rewrites=rewritten,
+            kv_tokens_staged=staged,
             slo_deferrals=n_slo_defer,
             programs_traced=kvpage.programs_traced() - traced_at_entry)
         for r in metrics.per_request:

@@ -44,14 +44,21 @@ that read with an online softmax over K/V blocks that is
   step program never slices a layer out of the pool — a
   ``dynamic_index_in_dim`` there is a copy of 98 MB a layer a step at
   GPT-2 XL's 240 pages, and was 93% of a decode step (PERF.md, PR 25).
-* **written in place** — :func:`paged_kv_write` is the step's other
-  Pallas call: the one fresh K/V token of every slot goes into lane
-  ``pos % page_tokens`` of page ``(layer, write_page[b])`` of the same
+* **written in place** — :func:`paged_kv_write` is the other Pallas
+  call: the fresh K/V tokens of every slot go into lanes ``pos %
+  page_tokens`` on of page ``(layer, write_page[b])`` of the same
   whole pool, aliased to the call's result. With tokens on lanes one
   token is one lane of every ``(8..32, 128)`` tile of the page, so a
   page is the smallest unit a DMA can move for it: the write is a
   page's read-modify-write, not a scatter (XLA's ``.at[..., off].set``
   on the lane dimension relayouts the whole layer there and back).
+* **staged** — a page's read-modify-write for ONE token moves 256
+  times what it stores, so a decode chunk keeps its own tokens in a
+  stage (:func:`new_kv_stage`, ``(layer, slot)`` a block), the paged
+  walk reads the pool up to the chunk's start and folds the stage
+  behind it (one more block of the one fold), and the chunk writes
+  all its tokens a slot with ONE :func:`paged_kv_write` a layer
+  (models/kvpage.py: ``paged_decode_chunk``; PERF.md, PR 36).
 
 Cache layout (models/decoding.to_cache_layout): ``[B, Hkv, D,
 max_len]``, pool ``[L, P, Hkv, D, page_tokens]`` (one layer of it ``[P,
@@ -106,19 +113,30 @@ def _n_live(pos, W, block_k, n_k):
     return jnp.minimum((pos + W + block_k - 1) // block_k, n_k)
 
 
-def _fold_block(q_ref, kb, vb, scales, j, pos, m_scr, l_scr, acc_scr, *,
-                block_k, n_rep, scale):
-    """THE fold, for both walks: K/V block ``j`` of every KV head of
-    one slot (``kb``/``vb`` ``[Hkv, D, block_k]``; ``scales`` is ``(ks,
-    vs)``, each ``[Hkv, 1, block_k]``, when they are int8 codes, else
-    empty) goes into the online-softmax state held in VMEM scratch. ``q_ref[0]`` is the
-    slot's ``[Hkv, W*n_rep, D]`` tile. The mask is on ABSOLUTE
-    positions: row i is window slot ``i // n_rep`` at ``pos + i //
-    n_rep``, column c of block j is cache position ``j*block_k + c``;
-    on fully visible blocks it is all true. The scales multiply the
-    SMALL tensors: K's the scores, V's the probabilities."""
+def _fold_block(q_ref, kb, vb, scales, col0, pos, m_scr, l_scr, acc_scr, *,
+                n_rep, scale, stop=None, tokens_minor=True):
+    """THE fold, for both walks and for a chunk's stage: one block of
+    K/V of every KV head of one slot goes into the online-softmax state
+    held in VMEM scratch. ``kb``/``vb`` are ``[Hkv, D, block_k]`` (a
+    cache block or a page: tokens on lanes) or, with ``tokens_minor``
+    false, BOTH the one ``[Hkv, block_k, 2 D]`` of a stage's block
+    (tokens on sublanes, a row V then K: the same two products with the other
+    operand dimension contracted, ``q_ref`` then holding ``q`` under
+    K's lanes and zeros under V's, and the output being the leading
+    ``D`` lanes of the second product);
+    ``scales`` is ``(ks, vs)``, each ``[Hkv, 1, block_k]``, when they
+    are int8 codes, else empty. ``q_ref[0]`` is the slot's ``[Hkv,
+    W*n_rep, D]`` tile. The mask is on ABSOLUTE positions: row i is
+    window slot ``i // n_rep`` at ``pos + i // n_rep``, column c of the
+    block is cache position ``col0 + c``, visible up to the row's own
+    position and below ``stop`` when one is given (a pool behind a
+    stage is stale from the chunk's start on); on fully visible blocks
+    it is all true. The scales multiply the SMALL tensors: K's the
+    scores, V's the probabilities."""
     quant = bool(scales)
     Wn = q_ref.shape[-2]
+    tok = 2 if tokens_minor else 1          # the tokens' axis of kb, vb
+    block_k = kb.shape[tok]
     # Pre-scale q once (the _flash_kernel idiom); on the quant path
     # q stays f32 to dot against the f32-converted codes exactly.
     q = q_ref[0].astype(jnp.float32) * scale                # [Hkv, Wn, D]
@@ -131,15 +149,18 @@ def _fold_block(q_ref, kb, vb, scales, j, pos, m_scr, l_scr, acc_scr, *,
                 if q_ref.dtype == jnp.float32
                 else jax.lax.Precision.DEFAULT)
     s = jax.lax.dot_general(
-        q, kb, (((2,), (1,)), ((0,), (0,))),
+        q, kb, (((2,), (3 - tok,)), ((0,), (0,))),
         preferred_element_type=jnp.float32, precision=prec)
     if quant:
         s = s * scales[0]                                   # [Hkv, Wn, bk]
     rows = pos + jax.lax.broadcasted_iota(
         jnp.int32, (1, Wn, 1), 1) // n_rep
-    cols = j * block_k + jax.lax.broadcasted_iota(
+    cols = col0 + jax.lax.broadcasted_iota(
         jnp.int32, (1, 1, block_k), 2)
-    s = jnp.where(rows >= cols, s, _NEG_INF)
+    seen = rows >= cols
+    if stop is not None:
+        seen = seen & (cols < stop)
+    s = jnp.where(seen, s, _NEG_INF)
     m = m_scr[...]
     m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
@@ -147,9 +168,10 @@ def _fold_block(q_ref, kb, vb, scales, j, pos, m_scr, l_scr, acc_scr, *,
     l_scr[...] = corr * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
     if quant:
         p = p * scales[1]
-    acc_scr[...] = corr * acc_scr[...] + jax.lax.dot_general(
-        p.astype(vb.dtype), vb, (((2,), (2,)), ((0,), (0,))),
+    pv = jax.lax.dot_general(
+        p.astype(vb.dtype), vb, (((2,), (tok,)), ((0,), (0,))),
         preferred_element_type=jnp.float32, precision=prec)
+    acc_scr[...] = corr * acc_scr[...] + pv[..., :acc_scr.shape[-1]]
     m_scr[...] = m_new
 
 
@@ -178,16 +200,16 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, *refs, block_k, n_rep,
     @pl.when(j < _n_live(pos, q_ref.shape[2] // n_rep, block_k, n_k))
     def _fold():
         _fold_block(q_ref, k_ref[0], v_ref[0], [r[0] for r in scale_refs],
-                    j, pos, m_scr, l_scr, acc_scr,
-                    block_k=block_k, n_rep=n_rep, scale=scale)
+                    j * block_k, pos, m_scr, l_scr, acc_scr,
+                    n_rep=n_rep, scale=scale)
 
     @pl.when(j == n_k - 1)
     def _finish():
         o_ref[0] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
 
 
-def _paged_walk_kernel(pos_ref, table_ref, layer_ref, q_ref, *refs,
-                       page_tokens, n_rep, n_k, n_pools, scale):
+def _paged_walk_kernel(pos_ref, table_ref, layer_ref, step_ref, q_ref, *refs,
+                       page_tokens, n_rep, n_k, n_pools, n_stage, scale):
     """The paged walk: one grid step a slot, and inside it one pass
     over the slot's LIVE pages and nothing per dead page. The pools
     stay in HBM (``refs[:n_pools]``: K, V, and the scale pools of an
@@ -199,13 +221,31 @@ def _paged_walk_kernel(pos_ref, table_ref, layer_ref, q_ref, *refs,
     (32 exposed copy latencies a call otherwise). ``walked`` (SMEM)
     counts the pages folded so far in the call: its parity is the
     buffer the current page sits in, across slots. Needs sequential
-    grid steps (``arbitrary``)."""
-    pools, (o_ref, *refs) = refs[:n_pools], refs[n_pools:]
+    grid steps (``arbitrary``).
+
+    ``n_stage``: a decode chunk keeps its own tokens in a stage
+    (:func:`new_kv_stage`; ``refs`` then holds, behind the pools, ``q``
+    widened for it and block ``(layer, slot)`` of each of its arrays,
+    brought in by the grid's pipeline while the slot before is walked)
+    and the pool is stale from the slot's position at the chunk's
+    start on, ``pos0 = pos - step``. The walk then covers the pages
+    that hold a token below ``pos0`` (at least one, so that every slot
+    has a first page to prefetch: with ``pos0`` 0 it is masked whole,
+    and what a masked block leaves in the fold's state the next live
+    column wipes, the weight ``exp(-1e30 - m)`` being 0.0), and the
+    stage's tokens ``0..step`` are one more block of the same fold."""
+    pools, refs = refs[:n_pools], refs[n_pools:]
+    staged, (o_ref, *refs) = refs[:n_stage], refs[n_stage:]
     bufs, (sem, m_scr, l_scr, acc_scr, walked) = (refs[:n_pools],
                                                   refs[n_pools:])
     b, B = pl.program_id(0), pl.num_programs(0)
     layer, pos = layer_ref[0], pos_ref[b]
-    n = _n_live(pos, q_ref.shape[2] // n_rep, page_tokens, n_k)
+    if staged:
+        pos0 = pos - step_ref[0]
+        n = jnp.clip((pos0 + page_tokens - 1) // page_tokens, 1, n_k)
+    else:
+        pos0 = None
+        n = _n_live(pos, q_ref.shape[2] // n_rep, page_tokens, n_k)
 
     def copies(slot, j, buf):
         page = table_ref[slot, j]
@@ -235,15 +275,22 @@ def _paged_walk_kernel(pos_ref, table_ref, layer_ref, q_ref, *refs,
         for c in copies(b, j, buf):
             c.wait()
         kb, vb, *scales = [dst[buf] for dst in bufs]
-        _fold_block(q_ref, kb, vb, scales, j, pos, m_scr, l_scr, acc_scr,
-                    block_k=page_tokens, n_rep=n_rep, scale=scale)
+        _fold_block(q_ref, kb, vb, scales, j * page_tokens, pos, m_scr,
+                    l_scr, acc_scr, n_rep=n_rep, scale=scale, stop=pos0)
 
     jax.lax.fori_loop(0, n, page, None)
     walked[0] = first + n
+    if staged:
+        q_wide, vk, *scales = staged
+        vk = jnp.swapaxes(vk[...], 0, 1)        # [chunk, H, 2 D]: heads first
+        _fold_block(q_wide, vk, vk, [s[...] for s in scales], pos0,
+                    pos, m_scr, l_scr, acc_scr, n_rep=n_rep, scale=scale,
+                    tokens_minor=False)
     o_ref[0] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
 
 
-def _decode_call(q, k, v, pos, n_rep, block_k, n_k, table=None, layer=None):
+def _decode_call(q, k, v, pos, n_rep, block_k, n_k, table=None, layer=None,
+                 stage=None):
     """The pallas_call behind both kernels. ``k``/``v`` are K/V
     arrays or (codes, scales) tuples in cache layout
     ([B, Hkv, *, max_len]) or, with ``table``, pool layout: the whole
@@ -252,7 +299,9 @@ def _decode_call(q, k, v, pos, n_rep, block_k, n_k, table=None, layer=None):
     ([P, Hkv, *, page_tokens]) without. What the code observes
     (``table is not None``) chooses the walk: a cache row's blocks by a
     ``(B, n_k)`` grid, a slot's live pages by the kernel's own copies
-    out of the pool; the fold is one function."""
+    out of the pool; the fold is one function. ``stage`` (paged only):
+    ``(arrays, step)``, a chunk's stage (:func:`new_kv_stage`) and the
+    chunk step it is filled up to."""
     ks = vs = None
     if isinstance(k, tuple):
         k, ks = k
@@ -310,12 +359,29 @@ def _decode_call(q, k, v, pos, n_rep, block_k, n_k, table=None, layer=None):
             layer = 0
             operands[1:] = [p[None] for p in operands[1:]]
         pools = operands[1:]
+        staged, step = [], 0
+        if stage is not None:
+            arrays, step = stage
+            # q under K's lanes of a stage row, zeros under V's; then
+            # the stage, one layer of it being a stage of one layer
+            staged = [jnp.pad(qg, [(0, 0)] * 3 + [(D, 0)])] + [
+                a if a.ndim == 5 else a[None] for a in arrays]
+            operands += staged
         prefetch = [pos, jnp.asarray(table, jnp.int32),
-                    jnp.asarray(layer, jnp.int32).reshape(1)]
+                    jnp.asarray(layer, jnp.int32).reshape(1),
+                    jnp.asarray(step, jnp.int32).reshape(1)]
         kernel = functools.partial(_paged_walk_kernel, page_tokens=block_k,
-                                   n_pools=len(pools), **static)
+                                   n_pools=len(pools), n_stage=len(staged),
+                                   **static)
         grid, semantics = (B,), ("arbitrary",)
         in_specs = [q_spec] + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools)
+        if staged:
+            in_specs += [pl.BlockSpec((1, Hkv, Wn, 2 * D),
+                                      lambda b, *_: (b, 0, 0, 0))] + [
+                pl.BlockSpec((None, None) + a.shape[2:],
+                             lambda b, _, __, layer_ref, *___: (
+                                 layer_ref[0], b, 0, 0, 0))
+                for a in staged[1:]]
         scratch = ([pltpu.VMEM((2,) + p.shape[2:], p.dtype) for p in pools]
                    + [pltpu.SemaphoreType.DMA((len(pools), 2))]
                    + fold_state + [pltpu.SMEM((1,), jnp.int32)])
@@ -354,8 +420,57 @@ def _layer_of(pool, layer):
         p, layer, 0, keepdims=False), pool)
 
 
+def new_kv_stage(pools, n_slots, chunk):
+    """A decode chunk's stage, zeroed: the chunk's own tokens of every
+    layer with pages, ``(layer, slot)`` a block. K and V (or their
+    codes) lie in ONE array ``[L, B, chunk, H, 2 D]``: a token of a slot
+    is ``[H, 2 D]``, every head's V then K side by side on the lanes. A
+    step's update ``[1, B, 1, H, 2 D]`` then covers WHOLE tiles (the
+    token is a major dimension): with the tokens on the sublanes, ``[L,
+    B, H, chunk, 2 D]``, XLA's update of one row of 800 tiles read and
+    wrote every one of them, 13 us a layer a step (PERF.md, PR 36). The
+    attend swaps the block's two major dimensions in VMEM (3 us a call)
+    and folds ``[H, chunk, 2 D]``: the lanes contracted for the scores
+    (``q`` under K's, zeros under V's), the tokens for the output (whose
+    leading ``D`` lanes are V's), both products natural on that block.
+    The minor dimension is two heads' widths, not the chunk (an array
+    whose last dimension is 32 is laid out with 128 lanes in HBM, 4x;
+    this one fills its 128 at a head of 64; the heads pad to a multiple
+    of 16 rows). Behind it, for an int8 cache, K's and V's scales as in
+    a page, ``[L, B, H, 1, chunk]`` each."""
+    (L, _, H, D, _), kv = pools[0].shape, pools[0].dtype
+    return (jnp.zeros((L, n_slots, chunk, H, 2 * D), kv),) + tuple(
+        jnp.zeros((L, n_slots, H, 1, chunk), p.dtype) for p in pools[2:])
+
+
+def stage_put(stage, fresh, layer, step):
+    """``stage`` (:func:`new_kv_stage`) with every slot's ``fresh``
+    token (``[B, 1, H, *]`` a pool, as the write takes it: K, V, and
+    their scales) as token ``step`` of layer ``layer``: XLA's update in
+    place, one token of every slot's block (one lane of the scales')."""
+    k, v, *scales = fresh
+    zero = jnp.int32(0)
+
+    def put(into, row, at):
+        return jax.lax.dynamic_update_slice(
+            into, row[None].astype(into.dtype), (layer, zero) + at)
+    return (put(stage[0], jnp.concatenate([v, k], axis=-1),
+                (step, zero, zero)),
+            *(put(s, jnp.swapaxes(f, 1, 2), (zero, zero, step))
+              for s, f in zip(stage[1:], scales)))
+
+
+def stage_tokens(stage, layer=None):
+    """Layer ``layer`` of a stage (or the one layer it is) as the write
+    takes tokens: K, V, and their scales, ``[B, chunk, H, *]`` each."""
+    vk, *scales = _layer_of(stage, layer)
+    D = vk.shape[-1] // 2
+    return (vk[..., D:], vk[..., :D],
+            *(a.transpose(0, 3, 1, 2) for a in scales))
+
+
 def paged_gather_attend(q, kp, vp, table, pos, page_tokens, n_rep,
-                        layer=None):
+                        layer=None, stage=None):
     """Dense reference for paged attention: gather each slot's pages
     into the contiguous ``[B, Hkv, D, max_len]`` layout the fixed-slot
     path attends and call :func:`dense_decode_attend` — identical
@@ -364,7 +479,13 @@ def paged_gather_attend(q, kp, vp, table, pos, page_tokens, n_rep,
     past the horizon contributes exactly 0.0 through the masked
     softmax, same as the fixed cache's own dead tail). With ``layer``
     the pools are whole (``[L, P, ...]``) and that layer is sliced out
-    first."""
+    first. With ``stage`` (``(arrays, step)``, as the kernel takes it)
+    the chunk's staged tokens ``0..step`` are first written into the
+    sliced layer by the dense write itself
+    (:func:`paged_kv_write_runs`): what each slot's pages would hold
+    had every token been written as it came, an idle slot's parking
+    page and a row's clipped end included, so a staged chunk is
+    bit-equal to the same steps with the dense write."""
     from mpi_acx_tpu.models.decoding import dense_decode_attend
 
     B, max_pages = table.shape
@@ -375,13 +496,24 @@ def paged_gather_attend(q, kp, vp, table, pos, page_tokens, n_rep,
         t = jnp.moveaxis(t, 1, 3)             # [B, H, *, max_pages, pt]
         return t.reshape(t.shape[:3] + (max_len,))
 
-    kin = jax.tree.map(gather, _layer_of(kp, layer))
-    vin = jax.tree.map(gather, _layer_of(vp, layer))
+    kl, vl = _layer_of(kp, layer), _layer_of(vp, layer)
+    if stage is not None:
+        staged, step = stage
+        quant = isinstance(kl, tuple)
+        pools = (kl[0], vl[0], kl[1], vl[1]) if quant else (kl, vl)
+        pos0 = jnp.broadcast_to(jnp.asarray(pos, jnp.int32) - step, (B,))
+        pools = paged_kv_write_runs(
+            paged_kv_write_dense, [p[None] for p in pools],
+            stage_tokens(staged, layer), 0, table, pos0, page_tokens,
+            n_live=step + 1)
+        k, v, *scales = (p[0] for p in pools)
+        kl, vl = ((k, scales[0]), (v, scales[1])) if quant else (k, v)
+    kin, vin = jax.tree.map(gather, kl), jax.tree.map(gather, vl)
     return dense_decode_attend(q, kin, vin, pos, max_len, n_rep)
 
 
 def paged_flash_decode_attend(q, kp, vp, table, pos, page_tokens, n_rep,
-                              layer=None):
+                              layer=None, stage=None):
     """Pallas paged decode attention: K/V pools ``[P, Hkv, D,
     page_tokens]`` (plus (codes, scales) tuples for int8 pools) addressed through
     a ``[B, max_pages]`` block table — or, with ``layer``, the whole
@@ -391,9 +523,14 @@ def paged_flash_decode_attend(q, kp, vp, table, pos, page_tokens, n_rep,
     at ``block_k == page_tokens`` this and :func:`flash_decode_attend`
     run identical FLOPs over identical block values (one fold): every
     row is bit-equal. Compiled for the chip, a page that is not a
-    multiple of 128 tokens raises."""
+    multiple of 128 tokens raises. With ``stage`` (``(arrays, step)``:
+    a decode chunk's :func:`new_kv_stage` and the chunk step it is
+    filled up to, ``pos`` being the slots' positions NOW) the pool
+    counts up to ``pos - step`` and the stage's tokens ``0..step``
+    follow it: still one call with the one result."""
     return _decode_call(q, kp, vp, pos, n_rep, page_tokens,
-                        table.shape[1], table=table, layer=layer)
+                        table.shape[1], table=table, layer=layer,
+                        stage=stage)
 
 
 def _paged_kernels_fit(page_tokens):
@@ -420,44 +557,59 @@ def select_paged_decode_attend(decode_flash, page_tokens):
     return paged_flash_decode_attend if decode_flash else paged_gather_attend
 
 
-def _kv_write_kernel(layer_ref, page_ref, off_ref, *refs):
-    """One slot's grid step: for each pool, its page ``[H, *, pt]``
-    with lane ``off`` replaced by the slot's fresh vector. ``refs`` =
-    the fresh blocks ``[H, *, B]`` (every slot's vector, slots on
-    lanes), the pages in, the pages out; the layer and the page were
-    the index maps' business. The slot's column is picked by a masked
-    lane sum of the values' BITS (one nonzero term: exact, and a -0.0
-    or a NaN stays what it was), then broadcast along the page's lanes."""
+def _kv_write_kernel(layer_ref, page_ref, off_ref, *refs, n_tokens, stride):
+    """Grid step ``(b, h)``: for each pool, page ``page_ref[b, h]``
+    ``[H, *, pt]`` with the lanes replaced that slot b's fresh tokens
+    land in: token t in lane ``(off + t) % pt`` of the slot's page
+    ``(off + t) // pt`` (0 or 1: ``n_tokens <= pt``). ``refs`` = the
+    fresh blocks ``[H, *, pt]`` (tokens on lanes, slot b's from lane
+    ``b * stride % pt`` on), the pages in, the pages out; the layer and
+    the page were the index maps' business. The tokens are ROLLED into
+    place along the lanes, 32 bits wide, so every value keeps its bits
+    (a -0.0 or a NaN stays what it was). Where step ``(b, 1)`` is given
+    the page of ``(b, 0)`` again (no token crosses into a next page, or
+    the table row ends there and both halves land in its last page) the
+    block is not fetched again and the result block, still resident, is
+    what the second half goes on top of."""
     n = len(refs) // 3
-    b = pl.program_id(0)
+    b, h = pl.program_id(0), pl.program_id(1)
     off = off_ref[b]
+    again = (h > 0) & (page_ref[b, h] == page_ref[b, 0])
     for fresh, page, out in zip(refs[:n], refs[n:2 * n], refs[2 * n:]):
+        pt = page.shape[-1]
         x = fresh[...]
-        floating = jnp.issubdtype(x.dtype, jnp.floating)
-        bits = (jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)
-                if floating else x.astype(jnp.int32))
-        slot = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
-        col = jnp.sum(jnp.where(slot == b, bits, 0), axis=-1, keepdims=True)
-        if floating:
-            col = jax.lax.bitcast_convert_type(col, jnp.float32)
-        lane = jax.lax.broadcasted_iota(jnp.int32, page.shape,
-                                        page.ndim - 1)
-        out[...] = jnp.where(lane == off, col.astype(page.dtype), page[...])
+        wide = jnp.float32 if jnp.issubdtype(x.dtype, jnp.floating) \
+            else jnp.int32
+        placed = pltpu.roll(x.astype(wide), (off - b * stride) % pt,
+                            axis=x.ndim - 1).astype(page.dtype)
+        lane = jax.lax.broadcasted_iota(jnp.int32, page.shape, page.ndim - 1)
+        t = jnp.where(lane < off, lane + pt, lane) - off    # the lane's token
+        mine = (t < n_tokens) & ((off + t >= pt).astype(jnp.int32) == h)
+        out[...] = jnp.where(mine, placed,
+                             jnp.where(again, out[...], page[...]))
 
 
 def paged_kv_write(pools, fresh, layer, write_page, off):
-    """Write one token per slot into the page pools IN PLACE: for every
-    pool ``[L, P, H, *, pt]`` of ``pools`` and its ``fresh`` ``[B, 1, H,
-    *]``, slot b's vector lands at ``pool[layer, write_page[b], :, :,
-    off[b]]``. One Pallas call over grid ``(slots,)`` for all the pools
-    (K and V, and their scale pages when the cache is int8), each
-    passed whole and aliased to its result; ``layer``, ``write_page``
-    and ``off`` are scalar-prefetched and the index maps address
-    ``(layer, page)``, so no value of a layer's pool is ever made. A
-    slot's step reads its page, replaces one lane and writes the page
-    back (module docstring: the page is the unit). Slots write distinct
-    pages (each owns its pages; an idle slot its parking page), so the
-    grid steps never collide. Returns the pools as a tuple.
+    """Write ``n`` tokens per slot into the page pools IN PLACE: for
+    every pool ``[L, P, H, *, pt]`` of ``pools`` and its ``fresh`` ``[B,
+    n, H, *]``, slot b's token t lands at ``pool[layer, write_page[b,
+    (off[b] + t) // pt], :, :, (off[b] + t) % pt]``. ``n`` = 1 is a
+    decode step's write (``write_page`` ``[B]``); a decode chunk's flush
+    writes its ``n <= pt`` staged tokens a slot at once (``write_page``
+    ``[B, 2]``: the page the first token lands in and the next, or the
+    first again where no token crosses: :func:`chunk_write_pages`; more
+    tokens than a page go in runs, :func:`paged_kv_write_runs`), each
+    page read and written once where ``n`` one-token writes moved it
+    ``n`` times. One Pallas
+    call over grid ``(slots, pages a slot)`` for all the pools (K and V,
+    and their scale pages when the cache is int8), each passed whole
+    and aliased to its result; ``layer``, ``write_page`` and ``off`` are
+    scalar-prefetched and the index maps address ``(layer, page)``, so
+    no value of a layer's pool is ever made. A step reads its page,
+    replaces the lanes and writes the page back (module docstring: the
+    page is the unit). Slots write distinct pages (each owns its pages;
+    an idle slot its parking page), so the slots' steps never collide.
+    Returns the pools as a tuple.
 
     The call is jitted on its own, inside whatever program calls it,
     so that it is traced ONCE a process. A Mosaic kernel is serialized
@@ -473,53 +625,116 @@ def paged_kv_write(pools, fresh, layer, write_page, off):
 
 @functools.partial(jax.jit, static_argnames="interpret")
 def _paged_kv_write(pools, fresh, layer, write_page, off, interpret):
-    B, n = write_page.shape[0], len(pools)
-    # All slots' fresh vectors ride as ONE resident block a pool, slots
-    # on lanes ([H, *, B]: a small transpose outside). One lane-wide
-    # block a slot ([H, *, 1]) works too, but a lane-1 array is padded
-    # to 128 lanes in HBM and XLA's relayout into it cost as much as
-    # the page writes themselves (PERF.md, PR 25).
-    fresh = [jnp.moveaxis(f[:, 0], 0, -1).astype(p.dtype)
-             for f, p in zip(fresh, pools)]
+    (B, n_tokens), n = fresh[0].shape[:2], len(pools)
+    pt = pools[0].shape[-1]
+    assert n_tokens <= pt, (n_tokens, pt)
+    write_page = write_page.reshape(B, -1)
+    # All slots' fresh tokens ride with tokens on LANES, a slot's
+    # ``stride`` lanes after the slot's before ([H, *, B * stride]: a
+    # small transpose outside), a page-wide block of them resident a
+    # grid step; ``stride`` is n where the slots' runs then tile the
+    # page-wide blocks, else a whole block a slot. One lane-wide block
+    # a slot ([H, *, 1]) works too, but a lane-1 array is padded to 128
+    # lanes in HBM and XLA's relayout into it cost as much as the page
+    # writes themselves (PERF.md, PR 25).
+    stride = n_tokens if pt % n_tokens == 0 else pt
+
+    def lanes(f, p):
+        f = jnp.moveaxis(f, (0, 1), (2, 3)).astype(p.dtype)    # [H, *, B, n]
+        f = jnp.pad(f, [(0, 0)] * 3 + [(0, stride - n_tokens)])
+        f = f.reshape(f.shape[:2] + (B * stride,))
+        return jnp.pad(f, [(0, 0)] * 2 + [(0, -(B * stride) % pt)])
+
+    fresh = [lanes(f, p) for f, p in zip(fresh, pools)]
 
     def page_spec(p):
         return pl.BlockSpec(
             (None, None) + p.shape[2:],
-            lambda b, layer_ref, page_ref, off_ref: (
-                layer_ref[0], page_ref[b], 0, 0, 0))
+            lambda b, h, layer_ref, page_ref, off_ref: (
+                layer_ref[0], page_ref[b, h], 0, 0, 0))
 
-    fresh_specs = [pl.BlockSpec(f.shape, lambda b, *_: (0, 0, 0))
+    fresh_specs = [pl.BlockSpec(f.shape[:2] + (pt,),
+                                lambda b, h, *_: (0, 0, b * stride // pt))
                    for f in fresh]
     page_specs = [page_spec(p) for p in pools]
     prefetch = (jnp.asarray(layer, jnp.int32).reshape(1),
                 write_page.astype(jnp.int32), off.astype(jnp.int32))
     out = pl.pallas_call(
-        _kv_write_kernel,
+        functools.partial(_kv_write_kernel, n_tokens=n_tokens, stride=stride),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(prefetch), grid=(B,),
+            num_scalar_prefetch=len(prefetch), grid=write_page.shape,
             in_specs=fresh_specs + page_specs, out_specs=page_specs),
         out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools],
         # Operand numbers count the prefetched scalars.
         input_output_aliases={len(prefetch) + n + j: j for j in range(n)},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",)),
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="paged_kv_write",          # what the device trace prints
     )(*prefetch, *fresh, *pools)
     return tuple(out)
 
 
-def paged_kv_write_dense(pools, fresh, layer, write_page, off):
+def paged_kv_write_dense(pools, fresh, layer, write_page, off, n_live=None):
     """Dense reference for :func:`paged_kv_write`, same arguments and
     result: slice the layer out of each pool, scatter the token columns
-    (``.at[write_page, :, :, off].set``), put the layer back. Right
+    (``.at[page, :, :, lane].set``), put the layer back. Right
     anywhere and the anchor the kernel is held bit-equal to; on the
-    chip it copies and relayouts the whole layer for one token."""
+    chip it copies and relayouts the whole layer for one token. With
+    ``n_live`` only the tokens below it are written (the rows of a
+    stage that a chunk has filled so far: :func:`paged_gather_attend`)."""
+    B, n_tokens = fresh[0].shape[:2]
+    P, pt = pools[0].shape[1], pools[0].shape[-1]
+    t = jnp.arange(n_tokens)
+    at = off[:, None] + t                                   # [B, n]
+    page = jnp.take_along_axis(write_page.reshape(B, -1), at // pt, axis=1)
+    if n_live is not None:
+        page = jnp.where(t < n_live, page, P)               # past the pool
+
     def write(pool, f):
         lyr = jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False)
-        lyr = lyr.at[write_page, :, :, off].set(f[:, 0].astype(pool.dtype))
+        lyr = lyr.at[page, :, :, at % pt].set(f.astype(pool.dtype),
+                                              mode="drop")
         return jax.lax.dynamic_update_index_in_dim(pool, lyr, layer, 0)
     return tuple(write(p, f) for p, f in zip(pools, fresh))
+
+
+def chunk_write_pages(table, pos, n: int, page_tokens: int):
+    """``[B, 2]`` (``[B, 1]`` at ``n`` 1): the pages that ``n <=
+    page_tokens`` tokens a slot from ``pos`` on land in, as
+    :func:`paged_kv_write` takes them: the first token's (the index
+    clipped at the table row's end, as a step's write clips it) and the
+    next one's, or the first again where no token crosses into it."""
+    assert n <= page_tokens, (n, page_tokens)
+    max_pages = table.shape[1]
+    first = pos // page_tokens
+    cols = [first]
+    if n > 1:
+        cols.append(jnp.where(pos % page_tokens + n > page_tokens,
+                              first + 1, first))
+    return jnp.take_along_axis(
+        table, jnp.minimum(jnp.stack(cols, axis=1), max_pages - 1), axis=1)
+
+
+def paged_kv_write_runs(write, pools, fresh, layer, table, pos, page_tokens,
+                        n_live=None):
+    """``write`` (:func:`paged_kv_write` or its dense twin) for ANY
+    number of tokens a slot: ``fresh`` ``[B, n, H, *]`` lands at
+    positions ``pos, pos + 1, ...`` of each slot's table row in runs of
+    at most a page, one call a run with the one or two pages that run
+    touches (:func:`chunk_write_pages`), in order, so that where runs
+    share a page (an idle slot's parking page, a row's clipped end) the
+    later token stays, as after one-token writes. A chunk no longer
+    than a page, every cell's, is ONE call. ``n_live``: the dense
+    twin's, counted over all ``n`` tokens."""
+    n = fresh[0].shape[1]
+    for s in range(0, n, page_tokens):
+        m = min(page_tokens, n - s)
+        live = {} if n_live is None else {"n_live": n_live - s}
+        pools = write(pools, [f[:, s:s + m] for f in fresh], layer,
+                      chunk_write_pages(table, pos + s, m, page_tokens),
+                      (pos + s) % page_tokens, **live)
+    return pools
 
 
 def select_paged_kv_write(decode_flash, page_tokens):

@@ -18,6 +18,8 @@ kernel runs in interpret mode (the same discipline as
 tests/test_flash_decode.py).
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -36,6 +38,7 @@ from mpi_acx_tpu.ops.flash_decode import (flash_decode_attend,
                                           paged_gather_attend,
                                           paged_kv_write,
                                           paged_kv_write_dense,
+                                          paged_kv_write_runs,
                                           select_paged_decode_attend,
                                           select_paged_kv_write)
 from mpi_acx_tpu.ops.kvquant import kv_quant
@@ -384,6 +387,265 @@ def test_paged_kv_write_bit_equals_dense_and_touches_one_lane(kind, case):
         assert int(write_page[0]) == MAX_PAGES - 1
 
 
+# name -> (pos [B] at the chunk's start, idle slots): what a chunk's flush
+# has to get right, at PT = 32 lanes a page
+CHUNK_CASES = {
+    "lane-0": ([PT, 0, 2 * PT], ()),
+    "last-lane": ([PT - 1, 2 * PT - 1, PT - 1], ()),       # crosses at once
+    "per-slot-pos": ([3, PT + 20, 2 * PT + 31], ()),
+    "idle-slot-parked": ([5, 3 * PT + 9, PT + 30], (1,)),   # idle pos walks on
+    "row-end-clipped": ([MAX_LEN - 3, 2 * PT, MAX_LEN + 40], ()),
+}
+
+
+def _chunk_case(kind, case, n, seed=0):
+    """(pools, fresh [B, n, H, *], table, pos): ``n`` tokens a slot."""
+    rng = np.random.default_rng(seed)
+    pos, idle = CHUNK_CASES[case]
+    pools, _, _, _ = _write_case(kind, "uniform-off-0", seed)
+    x = rng.standard_normal((2, B, n, Hkv, D))
+    if kind == "int8":
+        (fk, fks), (fv, fvs) = (kv_quant(jnp.asarray(a, jnp.float32))
+                                for a in x)
+        fresh = (fk, fv, fks, fvs)
+    else:
+        fresh = tuple(jnp.asarray(a, jnp.bfloat16).at[0, 0, 0, 0].set(-0.0)
+                      for a in x)
+    table = np.arange(PARK, dtype=np.int32).reshape(B, MAX_PAGES)
+    for b in idle:
+        table[b] = PARK + b
+    return pools, fresh, jnp.asarray(table), jnp.asarray(pos, jnp.int32)
+
+
+@pytest.mark.parametrize("n", [1, 5, PT, PT + 1, 2 * PT + 7])
+@pytest.mark.parametrize("kind", ["bf16", "int8"])
+@pytest.mark.parametrize("case", list(CHUNK_CASES))
+def test_paged_kv_write_of_a_chunk_equals_its_tokens_one_at_a_time(kind, case,
+                                                                   n):
+    """``paged_kv_write`` with ``n`` tokens a slot (a decode chunk's
+    flush; interpret mode) and its dense twin leave every pool bit-equal
+    to ``n`` one-token writes at ``pos, pos + 1, ...``: across a page
+    boundary, from lane 0 and from the last lane, into an idle slot's
+    parking page only, both halves into the last page of a table row
+    that ends, a -0.0 kept; no other page or lane changes. More tokens
+    than a page go in runs of a page (``paged_kv_write_runs``): three
+    or more pages a slot, and where runs share a page (parking, a
+    clipped row end) the later token stays."""
+    pools, fresh, table, pos = _chunk_case(kind, case, n)
+    layer = jnp.int32(1)
+    want = pools
+    for t in range(n):
+        at = pos + t
+        page = jnp.take_along_axis(
+            table, jnp.minimum(at // PT, MAX_PAGES - 1)[:, None], axis=1)
+        want = paged_kv_write_dense(want, [f[:, t:t + 1] for f in fresh],
+                                    layer, page[:, 0], at % PT)
+    pages = jnp.concatenate([
+        flash_decode.chunk_write_pages(table, pos + s, min(PT, n - s), PT)
+        for s in range(0, n, PT)], axis=1)
+    assert pages.shape == (B, 2 * (n // PT) + min(n % PT, 2))   # two a run
+    for write in (paged_kv_write, paged_kv_write_dense):
+        if n <= PT:
+            got = jax.jit(write)(pools, fresh, layer, pages, pos % PT)
+        else:
+            got = jax.jit(functools.partial(
+                paged_kv_write_runs, write, page_tokens=PT))(
+                    pools, fresh, layer, table, pos)
+        for name, g, w in zip("k v ks vs".split(), got, want):
+            assert g.dtype == w.dtype and g.shape == w.shape
+            g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+            np.testing.assert_array_equal(g, w, err_msg=name)
+            np.testing.assert_array_equal(np.signbit(g), np.signbit(w),
+                                          err_msg=name)
+    # what the one-token writes left alone the flush never saw: pages
+    # outside ``pages``, and of an idle slot all but its parking page
+    touched = set(np.asarray(pages).ravel().tolist())
+    for b in CHUNK_CASES[case][1]:
+        assert set(np.asarray(pages[b]).tolist()) == {PARK + b}
+    for g, before in zip(got, pools):
+        for p in set(range(PARK + B)) - touched:
+            np.testing.assert_array_equal(
+                np.asarray(g[:, p], np.float32),
+                np.asarray(before[:, p], np.float32))
+
+
+# (Hkv, D, n_rep): GPT-2's heads, GQA at four query heads a K/V head,
+# ONE K/V head of 128 under many query heads
+STAGE_SHAPES = {"mha": (2, 16, 1), "gqa4": (2, 16, 4), "mqa128": (1, 128, 5)}
+
+
+@pytest.mark.parametrize("chunk", [5, 19])
+@pytest.mark.parametrize("kind", ["bf16", "int8"])
+@pytest.mark.parametrize("shape", list(STAGE_SHAPES))
+def test_staged_attend_reads_the_pool_then_the_stage(kind, shape, chunk):
+    """The attend with a chunk's stage: pool + stage, neither written
+    into the other. The gather reference lays the staged tokens over
+    its gathered rows and is BIT-equal to the same attend after
+    one-token dense writes; the live-page walk (interpret mode) masks
+    the pool at the slot's position at the chunk's start and folds the
+    stage as one more block, equal to numerics. Slots start at lane 0,
+    cross a page boundary, and one idles on its parking page; a chunk
+    of 19 at pages of 8 spans three pages a slot and walks the idle
+    slot past its row's end."""
+    Hk, Dh, n_rep = STAGE_SHAPES[shape]
+    rng = np.random.default_rng(5)
+    L, pt, layer = 2, 8, jnp.int32(1)
+    max_pages = 4
+    P = B * max_pages + B
+    table = np.arange(B * max_pages, dtype=np.int32).reshape(B, max_pages)
+    table[1] = B * max_pages + 1                         # idle: parked
+    table = jnp.asarray(table)
+    pos0 = jnp.asarray([0, 19, 7], jnp.int32)
+    if kind == "int8":
+        pools = tuple(jnp.asarray(rng.integers(-127, 128, (L, P, Hk, Dh, pt)),
+                                  jnp.int8) for _ in range(2)) + tuple(
+            jnp.asarray(rng.random((L, P, Hk, 1, pt), np.float32) * 0.02
+                        + 0.01) for _ in range(2))
+        qdt, tol = jnp.float32, 2e-5
+    else:
+        pools = tuple(jnp.asarray(rng.standard_normal((L, P, Hk, Dh, pt)),
+                                  jnp.bfloat16) for _ in range(2))
+        qdt, tol = jnp.bfloat16, 2e-2
+
+    def kv(ps):
+        return (((ps[0], ps[2]), (ps[1], ps[3])) if kind == "int8"
+                else (ps[0], ps[1]))
+
+    stage = flash_decode.new_kv_stage(pools, B, chunk)
+    assert stage[0].shape == (L, B, chunk, Hk, 2 * Dh)   # a token: V then K
+    assert len(stage) == (3 if kind == "int8" else 1)
+    written = pools
+    for step in range(chunk):
+        x = rng.standard_normal((2, B, 1, Hk, Dh))
+        if kind == "int8":
+            (k, ks), (v, vs) = (kv_quant(jnp.asarray(a, jnp.float32))
+                                for a in x)
+            fresh = (k, v, ks, vs)
+        else:
+            fresh = tuple(jnp.asarray(a, jnp.bfloat16) for a in x)
+        q = jnp.asarray(rng.standard_normal((B, 1, Hk * n_rep, Dh)), qdt)
+        pos, at = pos0 + step, jnp.int32(step)
+        stage = flash_decode.stage_put(stage, fresh, layer, at)
+        page = jnp.take_along_axis(
+            table, jnp.minimum(pos // pt, max_pages - 1)[:, None],
+            axis=1)[:, 0]
+        written = paged_kv_write_dense(written, fresh, layer, page, pos % pt)
+        want = paged_gather_attend(q, *kv(written), table, pos, pt, n_rep,
+                                   layer=layer)
+        dense = paged_gather_attend(q, *kv(pools), table, pos, pt, n_rep,
+                                    layer=layer, stage=(stage, at))
+        np.testing.assert_array_equal(np.asarray(dense, np.float32),
+                                      np.asarray(want, np.float32))
+        walk = paged_flash_decode_attend(q, *kv(pools), table, pos, pt,
+                                         n_rep, layer=layer,
+                                         stage=(stage, at))
+        np.testing.assert_allclose(                      # the owning slots
+            np.asarray(walk, np.float32)[[0, 2]],
+            np.asarray(want, np.float32)[[0, 2]], atol=tol, rtol=tol)
+
+
+def _chunk_setup(kv_int8, pt, chunk, decode_flash=None, seed=4):
+    """A small GPT-2 on four slots at ``pt`` tokens a page: slot 0 at
+    lane 0 of its second page, slot 1 at a page's last lane, slot 2
+    idle, slot 3 three tokens before its table row's end."""
+    import dataclasses
+    cfg = tfm.tiny_config(vocab=61, d_model=32, n_heads=2, n_layers=2,
+                          d_ff=64, max_seq=4 * pt + chunk)
+    cfg = dataclasses.replace(cfg, decode_flash=decode_flash)
+    params = tfm.init_params(jax.random.key(seed), cfg)
+    pkv = kvpage.PagedKV(cfg, tfm, n_slots=4, max_len=4 * pt, page_tokens=pt,
+                         n_pages=14, kv_int8=kv_int8)
+    rng = np.random.default_rng(seed)
+    pkv.pool = {k: jnp.asarray(rng.integers(-3, 4, v.shape), v.dtype)
+                if k in "kv" else jnp.asarray(rng.random(v.shape) + 0.5,
+                                              v.dtype)
+                for k, v in pkv.pool.items()}
+    pkv.seat(0, [], pkv.alloc_evicting(3), new_pos=pt)
+    pkv.seat(1, [], pkv.alloc_evicting(4), new_pos=2 * pt - 1)
+    pkv.seat(3, [], pkv.alloc_evicting(4), new_pos=4 * pt - 3)
+    return cfg, params, pkv
+
+
+def _chunk_and_steps(cfg, params, pkv, chunk, pt):
+    """(the staged chunk's state and tokens, the same steps one at a
+    time through ``paged_decode_step``'s own write)."""
+    tok = jnp.asarray([4, 9, 0, 17], jnp.int32)
+    keys = jax.random.split(jax.random.key(0), 4)
+    step = kvpage.make_paged_step_fn(params, cfg, tfm, chunk, pt)
+    state = pkv.device_state()
+    ref, ref_toks, t = state, [], tok
+    one = jax.jit(lambda p, s, t: kvpage.paged_decode_step(p, cfg, s, t, pt))
+    for _ in range(chunk):
+        logits, ref = one(params, ref, t)
+        t = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        ref_toks.append(np.asarray(t))
+    out, toks, _ = step(jax.tree.map(jnp.copy, state), tok, keys)
+    return out, np.asarray(toks), ref, np.stack(ref_toks)
+
+
+@pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8kv"])
+@pytest.mark.parametrize("pt,chunk", [(128, 32), (128, 1), (8, 5), (8, 8),
+                                      (8, 16), (8, 19)])
+def test_staged_chunk_bit_equals_steps_with_the_dense_write(kv_int8, pt,
+                                                            chunk):
+    """``paged_decode_chunk`` (stage, attend over pool + stage, one
+    flush) against ``paged_decode_step`` iterated a token at a time
+    with its own write, off the chip (the dense pair): tokens equal,
+    every pool and scale pool bit-equal, ``pos`` advanced by the chunk,
+    no stage left in the state, and every page that no slot's tokens
+    land in untouched. Pages of 128 with the serving chunk of 32 and a
+    chunk of 1; small pages that a chunk crosses and fills; chunks of
+    two pages and more (the flush goes in runs of a page)."""
+    cfg, params, pkv = _chunk_setup(kv_int8, pt, chunk)
+    before = pkv.device_state()
+    out, toks, ref, ref_toks = _chunk_and_steps(cfg, params, pkv, chunk, pt)
+    np.testing.assert_array_equal(toks, ref_toks)
+    assert sorted(out) == sorted(ref) and "stage" not in out
+    for key in out:
+        np.testing.assert_array_equal(np.asarray(out[key], np.float32),
+                                      np.asarray(ref[key], np.float32),
+                                      err_msg=key)
+    np.testing.assert_array_equal(np.asarray(out["pos"]),
+                                  np.asarray(before["pos"]) + chunk)
+    cols = np.minimum((np.asarray(before["pos"])[:, None]
+                       + np.arange(chunk)) // pt, 3)
+    touched = set(np.take_along_axis(np.asarray(before["table"]), cols,
+                                     axis=1).ravel().tolist())
+    assert 14 + 2 in touched                     # the idle slot's parking page
+    for key in ("k", "v", "ks", "vs"):
+        if key in out:
+            for p in set(range(14 + 4)) - touched:
+                np.testing.assert_array_equal(
+                    np.asarray(out[key][:, p], np.float32),
+                    np.asarray(before[key][:, p], np.float32), err_msg=key)
+
+
+@pytest.mark.parametrize("chunk", [5, 16])
+@pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8kv"])
+def test_staged_chunk_with_the_kernels_serves_the_dense_tokens(kv_int8,
+                                                               chunk):
+    """The same chunk at ``decode_flash=True`` (the staged walk and the
+    n-token write as Pallas kernels, interpret mode) against the dense
+    pair: the owning slots' tokens equal, their pages equal to numerics
+    (the fold's block boundaries moved: pool to the chunk's start, then
+    the stage), and in the FIRST layer, whose K/V no attend feeds,
+    bit-equal. A chunk of 16 at pages of 8 flushes in two runs."""
+    pt = 8
+    cfg, params, pkv = _chunk_setup(kv_int8, pt, chunk, decode_flash=True)
+    assert select_paged_kv_write(True, pt) is paged_kv_write
+    out, toks, ref, ref_toks = _chunk_and_steps(cfg, params, pkv, chunk, pt)
+    owning = [0, 1]                 # slot 3 runs past its row: not a request
+    np.testing.assert_array_equal(toks[:, owning], ref_toks[:, owning])
+    mine = sorted(p for b in owning for p in pkv.pages[b])
+    for key in ("k", "v", "ks", "vs"):
+        if key in out:
+            got = np.asarray(out[key], np.float32)[:, mine]
+            want = np.asarray(ref[key], np.float32)[:, mine]
+            np.testing.assert_array_equal(got[0], want[0], err_msg=key)
+            np.testing.assert_allclose(got, want, atol=1.01, rtol=0.05,
+                                       err_msg=key)
+
+
 @pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8kv"])
 def test_paged_step_same_with_either_write(kv_int8, monkeypatch):
     """One whole paged_decode_step at decode_flash=True (the kernel
@@ -449,13 +711,14 @@ def test_select_paged_kv_write_follows_the_attend(on_tpu, page_tokens,
 @pytest.mark.parametrize("pos,chunk,want", [
     ([0, 0, 0], 1, 3),                        # idle: the parking page each
     ([0, 0, 0], 8, 24),
-    ([7, 8, 31], 1, 1 + 2 + 4),               # pos // 8 + 1 pages a slot
-    ([7, 0, 0], 2, (1 + 2) + 2 + 2),          # slot 0 crosses into page 2
+    ([7, 8, 31], 1, 1 + 1 + 4),               # the pages below pos
+    ([7, 0, 0], 2, (1 + 1 + 1) * 2),          # the chunk's own: the stage
     ([31, 40, 100], 4, 3 * 4 * 4),            # never past the table row
 ])
 def test_live_pages_counts_what_the_walk_fetches(pos, chunk, want):
     """PagedKV.live_pages: a layer's attends over the next chunk, every
-    slot walking its pos (ServingMetrics.attend_pages_walked)."""
+    slot reading the pool up to its pos at the chunk's start
+    (ServingMetrics.attend_pages_walked)."""
     cfg = tfm.tiny_config(vocab=31, d_model=16, n_heads=2, n_layers=1,
                           d_ff=32, max_seq=32)
     pkv = kvpage.PagedKV(cfg, tfm, n_slots=3, max_len=32, page_tokens=8,
@@ -824,6 +1087,52 @@ def test_serve_paged_phases_cover_the_call_and_count_the_decode_work():
     assert abs(sum(r.refill_host_s for r in m.per_request)
                - (m.phase_s["refill.match"] + m.phase_s["refill.scatter"]
                   + m.phase_s["refill.seat"])) < 1e-6
+
+
+@pytest.mark.parametrize("chunk", [1, 4, 8, 16])
+def test_serve_call_counts_what_it_staged_and_the_pages_it_rewrote(chunk):
+    """``kv_tokens_staged`` and ``kv_page_rewrites`` of a small serve
+    call (pages of 8): a layer stages ``chunk`` tokens a slot a chunk
+    and its flush rewrites a page a slot, two where the chunk's tokens
+    cross a page boundary, so a token costs between 1 / chunk and 2 /
+    chunk page rewrites, and exactly 1 at a chunk of one token."""
+    cfg, params, prompts = _serve_setup()
+    n_new, n_slots = [6, 3, 9, 2, 5, 7, 4], 3
+    m = serving.serve_paged_greedy(
+        params, cfg, prompts, n_new, n_slots=n_slots, max_len=32, family=tfm,
+        chunk=chunk, page_tokens=8).metrics
+    assert m.kv_tokens_staged == m.steps * chunk * n_slots
+    runs = -(-chunk // 8)           # a chunk of 16: two runs of a page
+    assert (runs * m.steps * n_slots <= m.kv_page_rewrites
+            <= 2 * runs * m.steps * n_slots)
+    per_token = m.kv_page_rewrites / m.kv_tokens_staged
+    assert runs / chunk <= per_token <= 2 * runs / chunk
+    if chunk == 1:
+        assert per_token == 1
+    if chunk == 8:              # a whole page a chunk: every start but a
+        assert m.kv_page_rewrites > m.steps * n_slots   # page's lane 0 crosses
+
+
+def test_chunk_rewrites_counts_a_page_a_slot_and_the_crossings():
+    """PagedKV.chunk_rewrites, the host's count of the flush's pages."""
+    cfg = tfm.tiny_config(vocab=31, d_model=16, n_heads=2, n_layers=1,
+                          d_ff=32, max_seq=32)
+    pkv = kvpage.PagedKV(cfg, tfm, n_slots=3, max_len=32, page_tokens=8,
+                         n_pages=12)
+    pkv.seat(0, [], pkv.alloc_evicting(4), new_pos=0)
+    pkv.seat(1, [], pkv.alloc_evicting(4), new_pos=5)
+    pkv.pos[2] = 15                                  # idle: it walks on
+    assert pkv.chunk_rewrites(1) == 3
+    assert pkv.chunk_rewrites(3) == 3                # 5, 6, 7
+    assert pkv.chunk_rewrites(4) == 3 + 1            # 5..8
+    assert pkv.chunk_rewrites(8) == 3 + 1            # lane 0 fills its page
+    np.testing.assert_array_equal(
+        np.asarray(flash_decode.chunk_write_pages(
+            jnp.asarray(pkv.table), jnp.asarray(pkv.pos), 4, 8)),
+        [[0, 0], [4, 5], [14, 14]])                  # idle: parked both
+    assert pkv.chunk_rewrites(16) == 2 * 3 + 2       # 5..12, 13..20
+    pkv.pos[1] = 29                                  # the table row ends:
+    assert pkv.chunk_rewrites(8) == 3                # both halves, one page
 
 
 # --------------------------------------------------------------------------

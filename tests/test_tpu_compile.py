@@ -246,7 +246,7 @@ def test_explicit_kernel_raises_on_untileable_length(attend, args, v5e):
 # vocabulary cut: the page-write kernel alone, then a whole chunk of
 # steps, which must hold both Mosaic calls and move no pool.
 
-XL_B, XL_H, XL_L, XL_PAGES = 32, 25, 2, 16 + 32        # + parking pages
+XL_B, XL_H, XL_L, XL_PAGES = 32, 25, 2, 208 + 32       # + parking pages
 
 
 def _xl_pools(kind, n_layers=XL_L):
@@ -324,30 +324,28 @@ _XL_CFG = tfm.TransformerConfig(vocab=512, d_model=XL_H * D, n_heads=XL_H,
                                 max_seq=MAX_LEN)
 
 
-def _xl_chunk(kind):
-    """(paged_decode_chunk, args): make_paged_step_fn's scan of
-    paged_decode_step at the cell's geometry and default config."""
+XL_CHUNK = 32
+
+
+def _xl_chunk(kind, n_layers=XL_L):
+    """(paged_decode_chunk, args, keywords): the process's one chunk
+    program as ``make_paged_step_fn`` binds it at the cell's geometry
+    and default config, the serving chunk of 32 steps."""
     cfg = _XL_CFG
     params = jax.tree.map(
         lambda a: _s(a.shape, a.dtype),
         tfm.cast_params(tfm.init_params(jax.random.key(0), cfg)))
     params["layers"] = jax.tree.map(
-        lambda a: _s((XL_L,) + a.shape[1:], a.dtype), params["layers"])
-    pools = _xl_pools(kind)
+        lambda a: _s((n_layers,) + a.shape[1:], a.dtype), params["layers"])
+    pools = _xl_pools(kind, n_layers)
     state = dict(zip(("k", "v", "ks", "vs"), pools),
                  table=_s((XL_B, MAX_LEN // PAGE), jnp.int32),
                  pos=_s((XL_B,), jnp.int32))
-
-    def paged_decode_chunk(params, state, tok):
-        def one(carry, _):
-            state, tok = carry
-            logits, state = kvpage.paged_decode_step(params, cfg, state,
-                                                     tok, PAGE)
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return (state, nxt), nxt
-        return lax.scan(one, (state, tok), None, length=2)
-
-    return paged_decode_chunk, [params, state, _s((XL_B,), jnp.int32)]
+    keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0), XL_B))
+    step = kvpage.make_paged_step_fn(params, cfg, tfm, XL_CHUNK, PAGE)
+    assert step.func is kvpage.paged_decode_chunk
+    return (step.func, [*step.args, state, _s((XL_B,), jnp.int32), keys],
+            step.keywords)
 
 
 def test_page_write_kernel_bytes_do_not_depend_on_the_caller(v5e):
@@ -361,9 +359,11 @@ def test_page_write_kernel_bytes_do_not_depend_on_the_caller(v5e):
     in the serve loop its ten frames end inside the package, in this
     shallow test they would not."""
     def lowered():
-        fn, args = _xl_chunk("bf16")      # a new function, as a serve call's
-        return jax.jit(fn, donate_argnums=(1,)).lower(
-            *_place(args, v5e)).compiler_ir("stablehlo")
+        def one_step(params, state, tok):  # a new function, as a caller's
+            return kvpage.paged_decode_step(params, _XL_CFG, state, tok, PAGE)
+        _, (params, state, tok, _), _ = _xl_chunk("bf16")
+        return jax.jit(one_step, donate_argnums=(1,)).lower(
+            *_place([params, state, tok], v5e)).compiler_ir("stablehlo")
 
     def one_caller():
         return lowered()
@@ -391,15 +391,9 @@ def test_the_process_level_chunk_program_is_one_whoever_calls(v5e):
     this shallow a caller would carry the caller's frames in the
     attend's): a call from the benchmark's window cannot make another
     cache entry, and a compile, than its warm-up call did."""
-    _, (params, state, tok) = _xl_chunk("bf16")
-    keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0), XL_B))
-
     def lowered():
-        step = kvpage.make_paged_step_fn(params, _XL_CFG, tfm, 2, PAGE)
-        assert step.func is kvpage.paged_decode_chunk
-        return step.func.lower(
-            *_place([*step.args, state, tok, keys], v5e),
-            **step.keywords).compiler_ir("stablehlo")
+        chunk, args, kw = _xl_chunk("bf16")
+        return chunk.lower(*_place(args, v5e), **kw).compiler_ir("stablehlo")
 
     def one_caller():
         return lowered()
@@ -425,22 +419,47 @@ def test_the_process_level_chunk_program_is_one_whoever_calls(v5e):
     assert kvpage.programs_traced() == traced + 1
 
 
+def _loop_depths(jaxpr, depth=0, found=None):
+    """kernel name -> how many ``scan`` / ``while`` bodies each of its
+    pallas_calls sits in."""
+    found = {} if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.setdefault(eqn.params["name"], []).append(depth)
+        looped = eqn.primitive.name in ("scan", "while")
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _loop_depths(sub, depth + looped, found)
+    return found
+
+
+# What PERF.md states for the cell's stage: 48 layers x 32 slots x 32
+# tokens x 25 heads x (V then K: 2 x 64 = the 128 lanes), bf16, as
+# counted; as the chip lays it out the 25 heads pad to 32 rows.
+XL_STAGE_BYTES = 48 * 32 * 32 * 25 * 128 * 2
+XL_STAGE_BYTES_LAID_OUT = XL_STAGE_BYTES // 25 * 32
+
+
 @pytest.mark.parametrize("kind", ["bf16", "int8"])
 def test_paged_decode_chunk_moves_no_pool(kind, v5e):
-    """A chunk of paged_decode_step (make_paged_step_fn's scan, state
-    donated) at the cell's geometry and default config: both Mosaic
-    calls are in the program, once each, and no instruction copies,
-    slices or updates an array the size of a layer's pool or of the
-    pool. The one-token write once did exactly that: 93% of a decode
-    step on the chip (PERF.md, PR 25), invisible off it. The attend is
-    ONE custom call with the single result ``bf16[32,25,1,64]`` (what
-    the benchmark's roofline reader matches), and its grid is the
-    slots alone: no step a (slot, page) pair, live or dead."""
-    paged_decode_chunk, args = _xl_chunk(kind)
-    pools = _xl_pools(kind)
-    lowered = jax.jit(paged_decode_chunk, donate_argnums=(1,)).lower(
-        *_place(args, v5e))
-    text = lowered.compile().as_text()
+    """``paged_decode_chunk`` (state donated) at the cell's geometry,
+    default config and serving chunk: both Mosaic calls are in the
+    program, once each, and no instruction copies, slices or updates an
+    array the size of a layer's pool or of the pool. The one-token
+    write once did exactly that: 93% of a decode step on the chip
+    (PERF.md, PR 25), invisible off it. The attend is ONE custom call
+    with the single result ``bf16[32,25,1,64]`` (what the benchmark's
+    roofline reader matches), and its grid is the slots alone: no step
+    a (slot, page) pair, live or dead. The write is the chunk's FLUSH:
+    ``chunk`` tokens a slot over a grid of (slot, the two pages they
+    can land in), in the scan over layers BEHIND the scan over steps
+    (one loop deep, where the attend is two deep), so a page is moved
+    once a chunk. The stage is updated in place: nothing copies it, and
+    it weighs what PERF.md says."""
+    n_layers = 48       # whole: a stage of two layers fits fast memory
+    chunk, args, kw = _xl_chunk(kind, n_layers)
+    pools = _xl_pools(kind, n_layers)
+    compiled = chunk.lower(*_place(args, v5e), **kw).compile()
+    text = compiled.as_text()
     calls = re.findall(r"%([a-z_]+)[.0-9]* = (.+?) custom-call\(.*"
                        r"custom_call_target=\"tpu_custom_call\"", text)
     assert sorted(name for name, _ in calls) == [
@@ -449,9 +468,44 @@ def test_paged_decode_chunk_moves_no_pool(kind, v5e):
     assert result.startswith(f"bf16[{XL_B},{XL_H},1,{D}]{{"), result
     assert not _pool_movers(text, pools[0].shape), \
         "\n".join(_pool_movers(text, pools[0].shape))
-    grids = _pallas_grids(jax.make_jaxpr(paged_decode_chunk)(*args).jaxpr)
+    jaxpr = jax.make_jaxpr(lambda *a: chunk.__wrapped__(*a, **kw))(
+        *args).jaxpr
+    grids = _pallas_grids(jaxpr)
     assert grids["paged_flash_decode_attend"] == [(XL_B,)]
-    assert grids["paged_kv_write"] == [(XL_B,)]
+    assert grids["paged_kv_write"] == [(XL_B, 2)]
+    assert _loop_depths(jaxpr) == {"paged_flash_decode_attend": [2],
+                                   "paged_kv_write": [1]}
+    # the stage: [L, B, chunk, H, 2 D] (the scales [L, B, H, 1, chunk],
+    # as in a page), written in place, one token's tiles a layer a step,
+    # and read by the attend. In the computation that holds the attend
+    # (the layer's body) nothing else makes a stage: no copy, no
+    # transpose; the flush may relayout it once a chunk.
+    stage = (rf"\[{n_layers},{XL_B},(?:{XL_CHUNK},{XL_H},{2 * D}|"
+             rf"{XL_H},1,{XL_CHUNK})\]")
+    bodies = re.split(r"\n(?=%|ENTRY )", text)
+    body, = [b for b in bodies if "%paged_flash_decode_attend" in b
+             and "tpu_custom_call" in b]
+    updates = {b.split(" ", 1)[0] for b in bodies          # fused, in place
+               if re.search(r"\n\s*ROOT [^\n]*? dynamic-update-slice\(", b)}
+    made = [l.strip() for l in body.splitlines()
+            if re.search(rf" = [a-z0-9]+{stage}", l)
+            and not re.search(r"\s(?:parameter|get-tuple-element|tuple|"
+                              r"bitcast|dynamic-update-slice)\(", l)]
+    assert made and all(
+        re.search(r"calls=(%[\w.]+)", l).group(1) in updates for l in made), \
+        "\n".join(l[:160] for l in made)
+    if kind == "bf16":
+        staged = jax.eval_shape(lambda: flash_decode.new_kv_stage(
+            pools, XL_B, XL_CHUNK))
+        assert sum(a.size * a.dtype.itemsize
+                   for a in staged) == XL_STAGE_BYTES
+        assert re.search(rf"bf16{stage}{{4,3,2,1,0:T\(8,128\)\(2,1\)}}",
+                         text), "the stage's layout on the chip changed"
+        # the program's temporaries: the stage as laid out, and what
+        # the chunk held before it (1.02 GB at the parent, 0.98 of it
+        # XLA's relayout of the stacked ``w2``: compile, PR 36)
+        assert (compiled.memory_analysis().temp_size_in_bytes
+                < XL_STAGE_BYTES_LAID_OUT + (1.1 * 2 ** 30))
 
 
 def _pallas_grids(jaxpr, found=None):
@@ -468,6 +522,22 @@ def _pallas_grids(jaxpr, found=None):
 
 
 # -- a second family through the same programs (PR 31) -----------------------
+
+
+def _flush_is_behind_the_steps(step, state, n_slots, keys):
+    """The family's chunk as the GPT-2 one: the attend once a scan body,
+    inside the scan over steps and a scan over layers, grid the slots;
+    the write ONCE, ``chunk`` tokens a slot over (slot, two pages), in
+    the flush's scan over layers alone."""
+    jaxpr = jax.make_jaxpr(
+        lambda *a: step.func.__wrapped__(*a, **step.keywords))(
+            *step.args, state, _s((n_slots,), jnp.int32), keys).jaxpr
+    assert _pallas_grids(jaxpr)["paged_kv_write"] == [(n_slots, 2)]
+    assert _pallas_grids(jaxpr)["paged_flash_decode_attend"] == [(n_slots,)]
+    depths = _loop_depths(jaxpr)
+    assert depths["paged_kv_write"] == [1]
+    assert depths["paged_flash_decode_attend"] == [2]
+
 
 LFM2_B, LFM2_PAGES = 64, 1024 + 64                 # + parking pages
 
@@ -503,7 +573,7 @@ def test_lfm2_decode_chunk_compiles_and_moves_no_pool(v5e):
                  held=_s((7, LFM2_B, 2, 2048), jnp.bfloat16),
                  owns=_s((LFM2_B,), jnp.bool_), moe=_s((4,), jnp.int32))
     keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0), LFM2_B))
-    step = kvpage.make_paged_step_fn(params, cfg, lfm2, 2, PAGE)
+    step = kvpage.make_paged_step_fn(params, cfg, lfm2, XL_CHUNK, PAGE)
     compiled = step.func.lower(
         *_place([*step.args, state, _s((LFM2_B,), jnp.int32), keys], v5e),
         **step.keywords).compile()
@@ -518,6 +588,7 @@ def test_lfm2_decode_chunk_compiles_and_moves_no_pool(v5e):
                                                    "f32[256,2048]"}
     assert not _pool_movers(text, pool["k"].shape), \
         "\n".join(_pool_movers(text, pool["k"].shape))
+    _flush_is_behind_the_steps(step, state, LFM2_B, keys)
     # nor one that moves a layer's experts: the stacks [2, 64, ...] go to
     # the grouped matmul whole (moe.sorted_expert_ffn, ``layer``); a
     # layer sliced out in front of each call was two thirds of a step
@@ -663,7 +734,7 @@ def test_jamba_decode_chunk_compiles_and_moves_no_state(v5e):
                  pos=_s((JAMBA_B,), jnp.int32), held=held)
     keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0),
                                                    JAMBA_B))
-    step = kvpage.make_paged_step_fn(params, cfg, jamba, 2, PAGE)
+    step = kvpage.make_paged_step_fn(params, cfg, jamba, XL_CHUNK, PAGE)
     compiled = step.func.lower(
         *_place([*step.args, state, _s((JAMBA_B,), jnp.int32), keys], v5e),
         **step.keywords).compile()
@@ -677,6 +748,7 @@ def test_jamba_decode_chunk_compiles_and_moves_no_state(v5e):
     # 13 Mamba layers a scan body, each its own call on the whole stack
     assert len(re.findall(r"%ssm_update[.0-9]* = ", text)) == 13
     assert not _pool_movers(text, pool["k"].shape)
+    _flush_is_behind_the_steps(step, state, JAMBA_B, keys)
     assert not _state_movers(text), "\n".join(_state_movers(text))
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
@@ -698,17 +770,13 @@ def test_jamba_prefill_compiles_for_v5e(bucket, v5e):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
-@pytest.mark.parametrize("family,sha,length", [
-    ("gpt2", "eba9c26cd9e53be9", 29812), ("lfm2", "9b50436dd25fd033", 128490)])
-def test_the_accepted_families_chunk_jaxprs_are_the_parents(family, sha,
-                                                            length,
-                                                            monkeypatch):
-    """The widened state seam changed nothing the accepted cells trace:
-    ``paged_decode_chunk``'s jaxpr for a tiny GPT-2 and a tiny LFM2, as
-    text, is the one commit 1b2bb85 (PR 32) gives, to the byte (its
-    hash and length, taken there with this function)."""
-    import hashlib
-
+@pytest.mark.parametrize("family", ["gpt2", "lfm2"])
+def test_the_chunk_stages_every_family_alike(family, monkeypatch):
+    """One path, adapted to nothing but the shapes it is handed: the
+    chunk program of a tiny GPT-2 and of a tiny LFM2 (off the chip, the
+    dense pair) carries through its scan over steps ONE stage of ``[page
+    layers, slots, chunk, K/V heads, 2 x head]``, hands no stage back,
+    and a step by itself carries none."""
     from mpi_acx_tpu.models import lfm2
     monkeypatch.setattr(backend, "on_tpu", lambda: False)
     if family == "lfm2":
@@ -718,10 +786,24 @@ def test_the_accepted_families_chunk_jaxprs_are_the_parents(family, sha,
         fam, cfg = None, tfm.tiny_config()
         params = tfm.init_params(jax.random.key(0), cfg)
     state = kvpage.PagedKV(cfg, fam, 4, 128, 16, 32).device_state()
-    text = str(jax.make_jaxpr(
+    tok, keys = jnp.zeros((4,), jnp.int32), jax.random.split(
+        jax.random.key(0), 4)
+    chunk = jax.make_jaxpr(
         lambda p, s, t, k: kvpage.paged_decode_chunk.__wrapped__(
-            p, s, t, k, cfg=cfg, chunk=2, page_tokens=16, on_tpu=False,
-            family=fam))(params, state, jnp.zeros((4,), jnp.int32),
-                         jax.random.split(jax.random.key(0), 4)))
-    assert (hashlib.sha256(text.encode()).hexdigest()[:16], len(text)) == (
-        sha, length)
+            p, s, t, k, cfg=cfg, chunk=3, page_tokens=16, on_tpu=False,
+            family=fam))(params, state, tok, keys)
+    L, _, H, Dh, _ = state["k"].shape
+    staged = (L, 4, 3, H, 2 * Dh)
+    steps = [e for e in chunk.jaxpr.eqns if e.primitive.name == "scan"
+             and e.params["length"] == 3]
+    assert len(steps) == 1
+    carried = [v.aval.shape for v in steps[0].outvars]
+    assert carried.count(staged) == 1
+    assert staged not in [v.aval.shape for v in chunk.jaxpr.outvars]
+    one = jax.make_jaxpr(lambda p, s, t: kvpage.paged_decode_step(
+        p, cfg, s, t, 16, fam))(params, state, tok)
+    assert "stage" not in jax.eval_shape(
+        lambda p, s, t: kvpage.paged_decode_step(p, cfg, s, t, 16, fam)[1],
+        params, state, tok)
+    assert staged not in [v.aval.shape for e in one.jaxpr.eqns
+                          for v in e.outvars]
