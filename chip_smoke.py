@@ -248,6 +248,32 @@ def _rewrites_per_token(m):
     return round(m.kv_page_rewrites / max(m.kv_tokens_staged, 1), 4)
 
 
+def _record_row(first, again):
+    """What two calls' span records hold (``ServingMetrics.spans``):
+    the spans a call kept, the waits far above their like of the first
+    call (it loads the programs: those ARE its stalls) and of the second
+    (none on a quiet machine), and what the first call loaded, by
+    program: seconds traced, lowered, loaded (self seconds)."""
+    from mpi_acx_tpu import profiling
+    _require(len(again.spans) == sum(again.phase_n.values())
+             and all(s.t1 >= s.t0 > 0 for s in again.spans),
+             f"spans={len(again.spans)}, phase_n={again.phase_n}")
+    loaded = [e for s in first.spans for e in s.programs]
+    return {"spans": len(again.spans),
+            "stalls": [first.stalls, again.stalls],
+            "stall_ms": [round(1e3 * first.stall_s, 3),
+                         round(1e3 * again.stall_s, 3)],
+            "programs_loaded": [
+                sum(e.kind == "load" for e in loaded),
+                sum(e.kind == "load" for s in again.spans
+                    for e in s.programs)],
+            "programs_loaded_s": round(profiling.program_seconds(loaded), 3),
+            "programs_trace_lower_load_s": {
+                name: [round(row.get(k, 0.0), 3)
+                       for k in ("trace", "lower", "load")]
+                for name, row in profiling.programs_by_name(loaded).items()}}
+
+
 def _seated_batch(size: Size, vocab: int, seed: int):
     """One prompt per slot, lengths spread over one bucket, for the
     first-step logit comparison."""
@@ -371,7 +397,7 @@ def phase_serve_paged(size: Size = FULL, seed: int = 0,
              token_mismatch_vs_dense=_mismatch_share(outs, ref, prompts),
              paged_operator=outs.metrics.paged_operator,
              paged_ffn=outs.metrics.paged_ffn,
-             **row)
+             **row, **_record_row(outs.metrics, again.metrics))
     _serve_paged_lfm2(size, seed)
     _serve_paged_jamba(size, seed)
     return True
@@ -422,7 +448,8 @@ def _serve_paged_lfm2(size: Size, seed: int):
          kv_page_rewrites_per_token=_rewrites_per_token(m),
          attend_built=m.paged_decode_attend,
          programs_traced=traced,
-         token_mismatch_vs_dense=_mismatch_share(outs, ref, prompts))
+         token_mismatch_vs_dense=_mismatch_share(outs, ref, prompts),
+         **_record_row(m, again.metrics))
 
 
 def _serve_paged_jamba(size: Size, seed: int):
@@ -479,7 +506,8 @@ def _serve_paged_jamba(size: Size, seed: int):
          kv_page_rewrites_per_token=_rewrites_per_token(m),
          attend_built=m.paged_decode_attend,
          programs_traced=traced,
-         token_mismatch_vs_dense=_mismatch_share(outs, ref, prompts))
+         token_mismatch_vs_dense=_mismatch_share(outs, ref, prompts),
+         **_record_row(m, again.metrics))
 
 
 def _handoff_prefill_parity(params, cfg, size: Size, seed: int):

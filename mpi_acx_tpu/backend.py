@@ -22,6 +22,8 @@ import os
 
 import jax
 
+from mpi_acx_tpu import profiling
+
 CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 DEFAULT_CACHE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
@@ -48,7 +50,11 @@ def jit_bound(fn, *bound, **jit_kwargs):
 def enable_compile_cache() -> str:
     """Turn the persistent compilation cache on before the first compile
     and return its directory. Where ``JAX_COMPILATION_CACHE_DIR`` is set
-    JAX has already read it and no directory is set here."""
+    JAX has already read it and no directory is set here. The process's
+    log of the programs it traces, lowers and loads starts here too
+    (``profiling.program_log``): an entry point calls this before its
+    first program."""
+    profiling.install_program_listener()
     path = os.environ.get(CACHE_ENV)
     if not path:
         path = DEFAULT_CACHE_DIR

@@ -30,6 +30,7 @@ import json
 import math
 import os
 import time
+from bisect import bisect_right
 from collections import Counter, deque
 from dataclasses import dataclass, field
 from functools import partial
@@ -45,8 +46,8 @@ from mpi_acx_tpu.models import kvpage
 
 
 def _pct(samples: List[float], p: float) -> float:
-    """Nearest-rank percentile, StepTimer's convention (profiling.py):
-    the ceil(p*n)-th smallest sample, no interpolation."""
+    """Nearest-rank percentile: the ceil(p*n)-th smallest sample, no
+    interpolation."""
     if not samples:
         return 0.0
     s = sorted(samples)
@@ -69,6 +70,14 @@ class RequestTelemetry:
     queue_wait_s: float = 0.0  # entry -> start of the refill that seated it
     prefill_s: float = 0.0     # that refill's ``refill.prefill`` span
     refill_host_s: float = 0.0  # its ``refill.match`` + ``.scatter`` + ``.seat``
+    # ... and read from the call's span record at its end (_request_paths):
+    prefill_wait_s: float = 0.0  # program handed over -> first token on host
+    decode_s: float = 0.0      # first token -> last token delivered: the
+    # end of the ``refill.seat`` that seated it -> the end of the last
+    # ``chunk.deliver`` of a chunk it owned a slot in; of that interval,
+    decode_in_refill_s: float = 0.0  # under OTHER requests' ``refill.*`` spans
+    decode_in_chunk_s: float = 0.0   # under its chunks' ``.upload`` + ``.step``
+    chunks: int = 0            # chunks it owned a slot in
 
 
 @dataclass
@@ -154,6 +163,15 @@ class ServingMetrics:
     call_s: float = 0.0           # function entry -> return (wall_s + set-up)
     phase_s: Dict[str, float] = field(default_factory=dict)
     phase_n: Dict[str, int] = field(default_factory=dict)
+    # ... and every span of the call, in order of opening
+    # (profiling.Phases.spans: name, t0, t1, parent, ids, the hand-over
+    # mark, the programs JAX loaded inside it), with what the loop reads
+    # from it once, at the call's end: the waiting spans that took more
+    # than STALL_FACTOR times their group's median (stalled_spans), as a
+    # count and as the seconds above the median.
+    spans: List[object] = field(default_factory=list)
+    stalls: int = 0
+    stall_s: float = 0.0
     # How many of the paged path's programs were TRACED during this call
     # (kvpage.programs_traced): 10-20 in a process's first call, 0 in
     # every later one with the same static arguments and shapes.
@@ -1101,6 +1119,105 @@ def paged_suffix_prefill(params, suffix, hk, hv, tail, last_index, *, cfg,
         params, cfg, suffix, hk, hv, tail, last_index, kv_int8, page_tokens)
 
 
+# A waiting span that took more than this many times its group's median
+# is a stall. Twice: what the record is to show is several times its
+# span (a machine's ~110 ms stop against a prefill of 11-26 ms, 1.5 s
+# lost against a chunk of 0.3-0.5 s, a program loaded where none should
+# be), and spans of one group (a bucket's prefills, a call's chunks)
+# differ by tens of percent. A constant, not a knob: two runs' stalls
+# compare only under one rule.
+STALL_FACTOR = 2.0
+
+
+def stalled_spans(spans) -> List[tuple]:
+    """``[(span, its group's median seconds)]`` for every waiting span
+    of a ``serve_paged_greedy`` record that took more than STALL_FACTOR
+    times the median of its group: the ``refill.prefill`` spans by
+    ``(bucket, hit_pages)``, the call's first left out (it waits for the
+    pool's zero fill, which the device runs first), and the
+    ``chunk.step`` spans as one group."""
+    groups: Dict[object, list] = {}
+    first = True
+    for sp in spans:
+        if sp.name == "refill.prefill":
+            if first:
+                first = False
+                continue
+            key = (sp.ids.get("bucket"), sp.ids.get("hit_pages"))
+        elif sp.name == "chunk.step":
+            key = sp.name
+        else:
+            continue
+        groups.setdefault(key, []).append(sp)
+    out = []
+    for group in groups.values():
+        mid = _pct([sp.seconds for sp in group], 0.50)
+        out += [(sp, mid) for sp in group
+                if sp.seconds > STALL_FACTOR * mid]
+    return sorted(out, key=lambda pair: pair[0].index)
+
+
+def _request_paths(spans, per_request, t_entry) -> None:
+    """Fill each RequestTelemetry's span-derived fields from the call's
+    record. A request's path is the four ``refill.*`` spans of the
+    refill that seated it (its LAST, where a preemption or a failure
+    replayed it) and every chunk after that whose ``chunk.step`` lists
+    it among the slots' owners; see RequestTelemetry for the fields."""
+    seated, last = {}, {}           # rid -> {name: span} of a refill
+    refills, chunks = [], []        # spans; [upload, step, deliver | None]
+    for sp in spans:
+        if sp.name.startswith("refill."):
+            refills.append(sp)
+            mine = last.setdefault(sp.ids["rid"], {})
+            mine[sp.name] = sp
+            if sp.name == "refill.seat":
+                seated[sp.ids["rid"]] = dict(mine)
+        elif sp.name == "chunk.upload":
+            chunks.append([sp, None, None])
+        elif sp.name == "chunk.step":
+            chunks[-1][1] = sp
+        elif sp.name == "chunk.deliver":
+            chunks[-1][2] = sp
+    owned: Dict[int, list] = {}
+    for chunk in chunks:
+        if chunk[2] is not None:    # a failed step delivered nothing
+            for rid in chunk[1].ids.get("rid", ()):
+                if rid >= 0:
+                    owned.setdefault(rid, []).append(chunk)
+    # The refill spans never overlap one another (one thread, none opened
+    # inside another): seconds under them before t, by prefix sums.
+    starts = [sp.t0 for sp in refills]
+    before = [0.0]
+    for sp in refills:
+        before.append(before[-1] + sp.seconds)
+
+    def under_refills(t):
+        i = bisect_right(starts, t)
+        return before[i] - (max(refills[i - 1].t1 - t, 0.0) if i else 0.0)
+
+    for r in per_request:
+        mine = seated.get(r.rid)
+        if mine is None:
+            continue
+        match, pre, seat = (mine["refill.match"], mine["refill.prefill"],
+                            mine["refill.seat"])
+        r.queue_wait_s = match.t0 - t_entry
+        r.prefill_s = pre.seconds
+        r.refill_host_s = (match.seconds + mine["refill.scatter"].seconds
+                           + seat.seconds)
+        r.prefill_wait_s = pre.t1 - pre.handed
+        took = [c for c in owned.get(r.rid, ()) if c[0].t0 >= seat.t1]
+        r.chunks = len(took)
+        if took:
+            # Its own refill ended before ``seat.t1`` and it is never
+            # refilled again: every refill span in between is another's.
+            end = took[-1][2].t1
+            r.decode_s = end - seat.t1
+            r.decode_in_refill_s = under_refills(end) - under_refills(seat.t1)
+            r.decode_in_chunk_s = sum(c[0].seconds + c[1].seconds
+                                      for c in took)
+
+
 def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
                        n_slots: int, max_len: int, family=None,
                        eos: Optional[int] = None, chunk: int = 1,
@@ -1180,9 +1297,12 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
     **Phases** (``profiling.Phases``): the call is tiled by the spans
     below, each a ``jax.profiler.TraceAnnotation`` (on the device
     trace's clock when the profiler runs; ``refill.*`` carry ``rid``,
-    ``chunk.*`` and ``loop.other`` the chunk's number ``step``) and a
-    self-time counter in ``metrics.phase_s`` / ``phase_n``; the self
-    times sum to ``call_s``. Only two WAIT on the device::
+    ``chunk.*`` and ``loop.other`` the chunk's number ``step``), a
+    self-time counter in ``metrics.phase_s`` / ``phase_n`` (the self
+    times sum to ``call_s``) and a record in ``metrics.spans``. Only two
+    WAIT on the device, and each marks the moment it hands the device
+    its program (``handed``: after the pad, the upload of the arguments
+    and a hit's history gather)::
 
         serve.setup     entry -> first refill: admission, PagedKV (the
                         pool's allocation), the weights bound to the
@@ -1210,9 +1330,20 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
 
     ``decode_slot_steps`` / ``decode_tokens`` / ``step_utilization``
     count, where the tokens are consumed, how many of the chunks'
-    slot-steps delivered one; per request, ``queue_wait_s`` (entry ->
-    start of the refill that seated it), ``prefill_s`` and
-    ``refill_host_s`` are that refill's spans."""
+    slot-steps delivered one.
+
+    **The record** (``metrics.spans``, always kept: about five spans a
+    request and six a chunk). Beyond the annotation's ids the record of
+    a ``refill.prefill`` has ``bucket`` (the padded length it ran at)
+    and ``hit_pages``, that of a ``chunk.step`` ``rid``: the owner of
+    each slot at the chunk's start. From it, once, at the call's end:
+    per request ``queue_wait_s`` (entry -> start of the refill that
+    seated it), ``prefill_s``, ``refill_host_s``, ``prefill_wait_s``,
+    ``decode_s`` and what of it lay under other requests' refills and
+    under its own chunks (RequestTelemetry); per call ``stalls`` /
+    ``stall_s`` (:func:`stalled_spans`). The programs JAX traced,
+    lowered or loaded inside a span are in its ``programs``
+    (``profiling.program_log``)."""
     from mpi_acx_tpu.ops.flash_decode import (select_paged_decode_attend,
                                               select_paged_kv_write)
     from mpi_acx_tpu.profiling import Phases
@@ -1262,9 +1393,6 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
     book = RequestBook(prompts, n_new, n_slots, eos, chunk,
                        max_request_retries, rejected, on_token=on_token)
     queue, owner, slo = book.queue, book.owner, book.slo
-    # Per request, of the refill that seated it: (queue_wait_s,
-    # prefill_s, refill_host_s), the first on the entry clock.
-    refill_times = [(0.0, 0.0, 0.0)] * len(prompts)
     n_preempts = n_slo_defer = pages_walked = pages_grid = 0
     rewritten = staged = 0
     # Requests currently evicted by page pressure: membership here turns
@@ -1296,7 +1424,7 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
         nonlocal n_slo_defer
         rid = queue[0]
         prompt = book.prompts[rid]
-        with ph("refill.match", rid=rid) as match:
+        with ph("refill.match", rid=rid):
             if _slo_defers():
                 n_slo_defer += 1
                 return False
@@ -1332,12 +1460,16 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
                     suffix = prompt[P:]
                     padded = _padded(suffix, max_len - P, cfg.max_seq - P)
                     hk, hv = pkv.gather_history(hit_pages)
-                    logits, one = suffix_prefill_fn(
-                        jnp.asarray(padded), hk, hv,
-                        pkv.restore_tail(hit_pages[-1]), len(suffix) - 1)
+                    args = (jnp.asarray(padded), hk, hv,
+                            pkv.restore_tail(hit_pages[-1]), len(suffix) - 1)
+                    run = suffix_prefill_fn
                 else:
                     padded = _padded(prompt, max_len, cfg.max_seq)
-                    logits, one = prefill_fn(jnp.asarray(padded), S - 1)
+                    args, run = (jnp.asarray(padded), S - 1), prefill_fn
+                pre.ids.update(bucket=padded.shape[1],
+                               hit_pages=len(hit_pages))
+                pre.hand_over()
+                logits, one = run(*args)
                 # The pages go to the pool (snapshots of the state at
                 # whole prompt pages' ends with them), the fixed state
                 # at the prompt's end to the slot.
@@ -1346,7 +1478,7 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
                        if k not in ("pos", "end")}
                 first = int(jnp.argmax(logits[0, 0]))   # the host waits
                 reqlog.emit("prefill_end", rid, first_token=first)
-            with ph("refill.scatter", rid=rid) as scatter:
+            with ph("refill.scatter", rid=rid):
                 pkv.scatter_prompt(one, fresh,
                                    whole=(S - len(hit_pages) * pt) // pt)
         except Exception as exc:  # noqa: BLE001 — any device failure
@@ -1357,7 +1489,7 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
         finally:
             if spanned:
                 _span_app_end_best_effort()
-        with ph("refill.seat", rid=rid) as seat:
+        with ph("refill.seat", rid=rid):
             pkv.seat(b, hit_pages, fresh, S, rid=rid, state=end)
             if pkv.prefix is not None:
                 pkv.prefix.insert(prompt, pkv.pages[b])
@@ -1366,9 +1498,6 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
                 slo.note_resume()
                 reqlog.emit("resume", rid, slot=b)
             book.seat(b, rid, first)
-        refill_times[rid] = (
-            match.t0 - setup.t0, pre.seconds,
-            match.seconds + scatter.seconds + seat.seconds)
         return True
 
     def retire_finished(b):
@@ -1485,9 +1614,11 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
             staged += chunk * n_slots
             state = pkv.device_state()
         with ph("chunk.step", step=step_no) as stepped:
+            stepped.ids["rid"] = tuple(owner)
             try:
-                state, toks, keys = step_fn(
-                    state, jnp.asarray(book.last_tok), keys)
+                last_tok = jnp.asarray(book.last_tok)
+                stepped.hand_over()
+                state, toks, keys = step_fn(state, last_tok, keys)
                 pkv.absorb(state)
             except Exception as exc:  # noqa: BLE001 — any device failure
                 book.step_failed(exc)
@@ -1536,9 +1667,11 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
             kv_tokens_staged=staged,
             slo_deferrals=n_slo_defer,
             programs_traced=kvpage.programs_traced() - traced_at_entry)
-        for r in metrics.per_request:
-            r.queue_wait_s, r.prefill_s, r.refill_host_s = \
-                refill_times[r.rid]
+        _request_paths(ph.spans, metrics.per_request, setup.t0)
+        stalled = stalled_spans(ph.spans)
+        metrics.stalls = len(stalled)
+        metrics.stall_s = sum(sp.seconds - mid for sp, mid in stalled)
+        metrics.spans = ph.spans
     # Filled in once the last span has closed: its end is the call's.
     metrics.call_s = tail.t1 - setup.t0
     metrics.phase_s, metrics.phase_n = ph.seconds, ph.count

@@ -164,3 +164,17 @@ def test_phase_runs_at_tiny_size(phase, capsys, loopback_runtime_closed):
         assert 0 < row["state_snapshot_rows_hwm"] <= 4
         assert row["state_snapshots_taken"] >= 2
         assert row["state_bytes_slot"] > 0 and row["programs_traced"][1] == 0
+        # the span record of every leg: what a call kept, no wait far
+        # above its like in the warm call's few spans unless the machine
+        # stopped, and the first call's programs by name
+        for name in ("bf16", "int8", "lfm2", "jamba"):
+            row = by["serve_paged/" + name]
+            assert row["spans"] >= 5 * row["prefills"] + 5 * row["steps"]
+            assert len(row["stalls"]) == len(row["stall_ms"]) == 2
+            assert (row["stalls"][1] == 0) == (row["stall_ms"][1] == 0)
+            loads = row["programs_trace_lower_load_s"]
+            assert row["programs_loaded"][0] >= row["programs_traced"][0]
+            assert row["programs_loaded"][1] == 0
+            assert {"paged_decode_chunk", "paged_prefill"} <= set(loads)
+            assert all(len(v) == 3 for v in loads.values())
+            assert 0 < row["programs_loaded_s"] <= row["wall_s"]

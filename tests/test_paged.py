@@ -20,6 +20,8 @@ tests/test_flash_decode.py).
 
 import functools
 
+import types
+
 import numpy as np
 import pytest
 
@@ -31,7 +33,7 @@ from mpi_acx_tpu.models import serving
 from mpi_acx_tpu.models import transformer as tfm
 from mpi_acx_tpu.models.decoding import (dense_decode_attend,
                                          to_cache_layout)
-from mpi_acx_tpu import backend
+from mpi_acx_tpu import backend, profiling
 from mpi_acx_tpu.ops import flash_decode
 from mpi_acx_tpu.ops.flash_decode import (flash_decode_attend,
                                           paged_flash_decode_attend,
@@ -1087,6 +1089,181 @@ def test_serve_paged_phases_cover_the_call_and_count_the_decode_work():
     assert abs(sum(r.refill_host_s for r in m.per_request)
                - (m.phase_s["refill.match"] + m.phase_s["refill.scatter"]
                   + m.phase_s["refill.seat"])) < 1e-6
+
+
+_PHASES = profiling.Phases
+
+
+def _serve_recorded(monkeypatch=None, jump_after=None):
+    """The seven requests of the phases test through three slots at a
+    chunk of 4; with ``monkeypatch``, on a clock that advances by one at
+    every reading, and by 100 more once, after reading ``jump_after``."""
+    cfg, params, prompts = _serve_setup()
+    if monkeypatch is not None:
+        readings = [0]
+
+        def clock():
+            readings[0] += 1
+            late = jump_after is not None and readings[0] > jump_after
+            return float(readings[0]) + (100.0 if late else 0.0)
+        monkeypatch.setattr(profiling, "Phases",
+                            lambda: _PHASES(clock=clock))
+    return serving.serve_paged_greedy(
+        params, cfg, prompts, [6, 3, 9, 2, 5, 7, 4], n_slots=3, max_len=32,
+        family=tfm, chunk=4, page_tokens=8).metrics
+
+
+def test_serve_paged_record_tiles_the_call_and_carries_its_ids():
+    """``metrics.spans``: every span of the call in order of opening;
+    the top-level ones follow one another from the call's entry to its
+    end, a child lies inside its parent, and the ids make a request's
+    spans one request's."""
+    m = _serve_recorded()
+    spans = m.spans
+    assert [s.index for s in spans] == list(range(len(spans)))
+    assert {s.name for s in spans} == set(PHASES)
+    assert {n: sum(s.name == n for s in spans) for n in PHASES} == m.phase_n
+    top = [s for s in spans if s.parent is None]
+    assert top[0].name == "serve.setup" and top[-1].name == "loop.other"
+    assert m.call_s == top[-1].t1 - top[0].t0
+    assert all(a.t1 <= b.t0 for a, b in zip(top, top[1:]))
+    assert abs(sum(s.seconds for s in top) - m.call_s) <= (
+        0.02 * m.call_s + 30e-6 * len(spans))
+    for s in spans:
+        if s.parent is not None:
+            assert spans[s.parent].t0 <= s.t0 <= s.t1 <= spans[s.parent].t1
+    # self times from the record are the counters', to the digit
+    own = {}
+    for s in spans:
+        own[s.name] = own.get(s.name, 0.0) + s.seconds
+        if s.parent is not None:
+            own[spans[s.parent].name] -= s.seconds
+    assert own == pytest.approx(m.phase_s, abs=1e-9)
+    # ids: a refill's four spans and the retire name their request, the
+    # prefill its bucket, a chunk its number and the step its owners
+    for rid, bucket in enumerate([8, 16, 8, 16, 8, 8, 16]):
+        mine = [s.name for s in spans if s.ids.get("rid") == rid]
+        assert mine == ["refill.match", "refill.prefill", "refill.scatter",
+                        "refill.seat", "chunk.retire"], rid
+        pre, = [s for s in spans if s.name == "refill.prefill"
+                and s.ids["rid"] == rid]
+        assert (pre.ids["bucket"], pre.ids["hit_pages"]) == (bucket, 0)
+        assert pre.t0 <= pre.handed <= pre.t1
+    steps = [s for s in spans if s.name == "chunk.step"]
+    assert [s.ids["step"] for s in steps] == list(range(1, m.steps + 1))
+    assert steps[0].ids["rid"] == (0, 1, 2)
+    assert all(len(s.ids["rid"]) == 3 and s.t0 <= s.handed <= s.t1
+               for s in steps)
+    assert all(s.handed is None for s in spans
+               if s.name not in ("refill.prefill", "chunk.step"))
+    # the call's first refill.prefill loaded its program inside the span
+    # or an earlier test did: either way nothing was loaded anywhere else
+    assert all(s.programs == () or s.name in (
+        "refill.prefill", "refill.scatter", "refill.seat", "chunk.upload",
+        "chunk.step", "chunk.grow", "serve.setup") for s in spans)
+
+
+def test_serve_paged_request_paths_from_the_record(monkeypatch):
+    """On a clock that advances by one at every reading: a request's
+    chunks equal a hand count, its wait is one reading, and what of its
+    decode interval lay under others' refills and its own chunks is what
+    a walk over the record gives; nothing stalls on an even clock."""
+    m = _serve_recorded(monkeypatch)
+    spans = m.spans
+    by_rid = {r.rid: r for r in m.per_request}
+    # n_new - 1 decode tokens at 4 a chunk
+    assert [by_rid[i].chunks for i in range(7)] == [2, 1, 2, 1, 1, 2, 1]
+    assert sum(r.chunks for r in m.per_request) == sum(
+        rid >= 0 for s in spans if s.name == "chunk.step"
+        for rid in s.ids["rid"])
+    entry = spans[0].t0
+    for r in m.per_request:
+        mine = {s.name: s for s in spans if s.ids.get("rid") == r.rid}
+        seat = mine["refill.seat"]
+        assert r.prefill_wait_s == 1.0 and r.prefill_s == 2.0
+        assert r.queue_wait_s == mine["refill.match"].t0 - entry
+        assert r.refill_host_s == sum(
+            mine[n].seconds for n in ("refill.match", "refill.scatter",
+                                      "refill.seat"))
+        owned = [s for s in spans if s.name == "chunk.step"
+                 and r.rid in s.ids["rid"]]
+        delivers = [s for s in spans if s.name == "chunk.deliver"
+                    and s.ids["step"] == owned[-1].ids["step"]]
+        end = delivers[0].t1
+        assert r.decode_s == end - seat.t1 > 0
+        others = sum(s.seconds for s in spans
+                     if s.name.startswith("refill.")
+                     and s.ids["rid"] != r.rid and seat.t1 <= s.t0 < end)
+        chunks = sum(s.seconds for s in spans
+                     if s.name in ("chunk.upload", "chunk.step")
+                     and s.ids["step"] in [o.ids["step"] for o in owned])
+        assert r.decode_in_refill_s == others
+        assert r.decode_in_chunk_s == chunks == 3.0 * r.chunks
+        assert r.decode_in_refill_s + r.decode_in_chunk_s <= r.decode_s
+    # requests 0-2 are seated at once: 1 and 2 are prefilled inside 0's
+    # decode interval; the last request seated sees nobody's refill
+    assert by_rid[0].decode_in_refill_s > by_rid[2].decode_in_refill_s > 0
+    assert by_rid[6].decode_in_refill_s == 0.0
+    assert (m.stalls, m.stall_s) == (0, 0.0)
+    assert serving.stalled_spans(spans) == []
+
+
+def test_serve_paged_one_long_prefill_is_one_stall_of_its_excess(
+        monkeypatch):
+    """The same call, the clock jumping by 100 while request 3's
+    prefill waits: one stall, of the 100 above its bucket's median,
+    named by the record; the requests decoding meanwhile carry it in
+    ``decode_in_refill_s``."""
+    even = _serve_recorded(monkeypatch)
+    pre, = [s for s in even.spans if s.name == "refill.prefill"
+            and s.ids["rid"] == 3]
+    assert pre.handed == pre.t0 + 1     # readings ARE the clock's values
+    m = _serve_recorded(monkeypatch, jump_after=int(pre.handed))
+    (span, mid), = serving.stalled_spans(m.spans)
+    assert (span.name, span.ids["rid"], span.ids["bucket"]) == (
+        "refill.prefill", 3, 16)
+    assert (span.seconds, mid) == (102.0, 2.0)
+    assert (m.stalls, m.stall_s) == (1, 100.0)
+    by_rid = {r.rid: r for r in m.per_request}
+    assert by_rid[3].prefill_wait_s == 101.0
+    seat_end = {s.ids["rid"]: s.t1 for s in m.spans
+                if s.name == "refill.seat"}
+    for r in m.per_request:     # whoever was decoding meanwhile
+        around = (seat_end[r.rid] <= span.t0
+                  and span.t1 <= seat_end[r.rid] + r.decode_s)
+        assert (r.decode_in_refill_s >= 102.0) == around, r.rid
+    # request 1 has retired, 0 and 2 sit through it before their second
+    # chunk
+    assert [r.rid for r in m.per_request
+            if r.decode_in_refill_s >= 102.0] == [0, 2]
+    # the call's FIRST prefill is left out (the pool's zero fill)
+    first, = [s for s in even.spans if s.name == "refill.prefill"
+              and s.ids["rid"] == 0]
+    m = _serve_recorded(monkeypatch, jump_after=int(first.handed))
+    assert (m.stalls, m.stall_s) == (0, 0.0)
+
+
+def test_stalled_spans_group_prefills_by_bucket_and_chunks_together():
+    """By hand: a prefill is measured against its own bucket's median
+    (a long bucket is no stall), every chunk.step against the call's."""
+    def span(i, name, seconds, **ids):
+        return types.SimpleNamespace(index=i, name=name, seconds=seconds,
+                                     ids=ids)
+    took = [("refill.prefill", 9.0, dict(bucket=8, hit_pages=0)),  # first
+            ("refill.prefill", 1.0, dict(bucket=8, hit_pages=0)),
+            ("refill.prefill", 5.0, dict(bucket=64, hit_pages=0)),
+            ("refill.prefill", 1.2, dict(bucket=8, hit_pages=0)),
+            ("refill.prefill", 5.5, dict(bucket=64, hit_pages=0)),
+            ("refill.prefill", 2.5, dict(bucket=8, hit_pages=0)),
+            ("refill.prefill", 2.5, dict(bucket=8, hit_pages=4)),
+            ("chunk.step", 10.0, dict(step=1)),
+            ("chunk.step", 30.0, dict(step=2)),
+            ("chunk.step", 12.0, dict(step=3)),
+            ("chunk.deliver", 99.0, dict(step=3))]
+    spans = [span(i, n, s, **ids) for i, (n, s, ids) in enumerate(took)]
+    assert [(sp.index, mid) for sp, mid in serving.stalled_spans(spans)] == [
+        (5, 1.2), (8, 12.0)]
+    assert serving.STALL_FACTOR == 2.0
 
 
 @pytest.mark.parametrize("chunk", [1, 4, 8, 16])
