@@ -416,6 +416,9 @@ paged-check: tools
 	@echo "== paged-check: 3-rank fleet, decode ranks on paged pools"
 	@ACX_ROLE=prefill,decode,decode $(BUILD)/acxrun -np 3 -timeout 240 \
 	  -transport socket python3 tests/paged_worker.py || exit 1
+	@echo "== paged-check: the same fleet at a chunk of 4 (requests end mid-chunk)"
+	@ACX_ROLE=prefill,decode,decode ACX_PAGED_CHUNK=4 $(BUILD)/acxrun -np 3 \
+	  -timeout 240 -transport socket python3 tests/paged_worker.py || exit 1
 	@echo "== paged-check: kill prefill mid-handoff (paged intake rollback)"
 	@rm -rf $(BUILD)/paged-oracle
 	@ACX_ROLE=prefill,decode,decode python3 tools/acx_chaos.py run --np 3 \

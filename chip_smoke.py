@@ -393,6 +393,7 @@ def phase_serve_paged(size: Size = FULL, seed: int = 0,
              attend_live_page_share=round(
                  outs.metrics.attend_live_share, 4),
              attend_pages_walked=outs.metrics.attend_pages_walked,
+             attend_dead_share=round(outs.metrics.attend_dead_share, 4),
              programs_traced=traced,
              token_mismatch_vs_dense=_mismatch_share(outs, ref, prompts),
              paged_operator=outs.metrics.paged_operator,
@@ -447,6 +448,7 @@ def _serve_paged_lfm2(size: Size, seed: int):
          kv_write_path=m.paged_kv_write,
          kv_page_rewrites_per_token=_rewrites_per_token(m),
          attend_built=m.paged_decode_attend,
+         attend_dead_share=round(m.attend_dead_share, 4),
          programs_traced=traced,
          token_mismatch_vs_dense=_mismatch_share(outs, ref, prompts),
          **_record_row(m, again.metrics))
@@ -505,6 +507,7 @@ def _serve_paged_jamba(size: Size, seed: int):
          kv_write_path=m.paged_kv_write,
          kv_page_rewrites_per_token=_rewrites_per_token(m),
          attend_built=m.paged_decode_attend,
+         attend_dead_share=round(m.attend_dead_share, 4),
          programs_traced=traced,
          token_mismatch_vs_dense=_mismatch_share(outs, ref, prompts),
          **_record_row(m, again.metrics))

@@ -713,7 +713,7 @@ def run_decode_worker(rt, params, cfg, prompts, n_new, n_slots, max_len,
         book.sample_gauges()
         step_t0 = time.perf_counter()
         if paged:
-            state = pkv.device_state()
+            state = pkv.device_state(book.left())
             state, toks, keys = step_fn(
                 state, jnp.asarray(book.last_tok), keys)
             pkv.absorb(state)

@@ -569,7 +569,15 @@ def paged_decode_step(params, cfg, state, token, page_tokens: int,
     ``state`` = pool keys + ``'table'`` [B, max_pages] + ``'pos'`` [B]
     and, for a family with state layers, ``'held'`` ([L_state, B,
     *leaf] a leaf); with ``'moe'`` [4] and ``'owns'`` [B] it also
-    counts its routing (:func:`_moe_tally`).
+    counts its routing (:func:`_moe_tally`). With ``'left'`` ([B] int32:
+    the tokens each slot's request still owes at the chunk's start, 0
+    for a slot that owns none; what only the loop knows,
+    ``RequestBook.left``) the attend is told which slots can deliver no
+    token at this step (``step >= left[b]``; a step by itself is step
+    0): it fetches and folds nothing for them and their rows are
+    zeros. Absent, every slot is live. Nothing else reads it: a dead
+    slot's token still runs the layers (finite, dropped by the loop),
+    its K/V is staged and flushed, its state moves.
 
     An attention layer's fresh K/V for slot b lands at ``pool[i,
     table[b, pos_b // pt], :, :, pos_b % pt]``, ``i`` counting the
@@ -645,7 +653,7 @@ def paged_decode_step(params, cfg, state, token, page_tokens: int,
             if quant:
                 kp, vp = (kp, pools[2]), (vp, pools[3])
             o = attend(q, kp, vp, table, pos, page_tokens, spec.n_rep,
-                       layer=at, stage=stage)
+                       layer=at, stage=stage, left=state.get("left"))
             x = spec.attn_out(cfg, lp, x, o)
         else:
             x, held = spec.state_op(cfg, lp, x, rest["held"], at)
@@ -705,8 +713,9 @@ def paged_decode_step(params, cfg, state, token, page_tokens: int,
     out = dict(zip(keys, pools), **rest)
     out["table"] = table
     out["pos"] = pos + 1
-    if "owns" in state:
-        out["owns"] = state["owns"]
+    for k in ("owns", "left"):
+        if k in state:
+            out[k] = state[k]
     return spec.head(params, cfg, x), out
 
 
@@ -932,12 +941,17 @@ class PagedKV:
                                         self.snaps)
                        if prefix_cache else None)
 
-    def device_state(self):
+    def device_state(self, left=None):
+        """What the step program takes. ``left`` ([n_slots]: the tokens
+        each slot still owes, ``RequestBook.left``) rides along as
+        ``state['left']`` when the loop has it."""
         if self._dev_table is None:
             self._dev_table = jnp.asarray(self.table)
         state = {k: self.pool[k] for k in _POOL_KEYS if k in self.pool}
         state["table"] = self._dev_table
         state["pos"] = jnp.asarray(self.pos)
+        if left is not None:
+            state["left"] = jnp.asarray(left, jnp.int32)
         if self.held is not None:
             state["held"] = self.held
         if self.spec.n_experts:
@@ -983,16 +997,20 @@ class PagedKV:
              for b in range(self.n_slots)], np.int32)
         self._dev_table = None
 
-    def live_pages(self, chunk: int) -> int:
+    def live_pages(self, chunk: int, left=None) -> int:
         """Pages one layer's attends fetch out of the pool over the
-        next ``chunk`` steps: every slot, owned or idle, reads in each
-        step the pages that hold a token below its ``pos`` now (the
-        chunk's own tokens come from the stage), at least one and at
-        most its table row (an idle slot's are its parking page, again
-        and again)."""
+        next ``chunk`` steps: a slot reads in each step in which it can
+        still deliver a token (the first ``min(left[b], chunk)`` of
+        them; all of them without ``left``, as before PR 38) the pages
+        that hold a token below its ``pos`` now (the chunk's own tokens
+        come from the stage), at least one and at most its table row.
+        A slot that owns no request (``left`` 0) reads none; without
+        ``left`` an idle slot's are its parking page, again and
+        again."""
         pt = self.page_tokens
-        return chunk * int(np.clip((self.pos + pt - 1) // pt, 1,
-                                   self.max_pages).sum())
+        steps = chunk if left is None else np.clip(left, 0, chunk)
+        return int((steps * np.clip((self.pos + pt - 1) // pt, 1,
+                                    self.max_pages)).sum())
 
     def chunk_rewrites(self, chunk: int) -> int:
         """Pages one layer's flush reads and writes back after the

@@ -110,8 +110,13 @@ class ServingMetrics:
     # paged: select_paged_decode_attend's choice, and the pages a layer's
     # attends fetch out of the pool (PagedKV.live_pages) over ``n_slots x
     # max_pages x chunk``, the (slot, page) grid's steps until PR 30.
+    # ``attend_pages_dead`` (PR 38): the pages the same chunks would
+    # have fetched besides, for the slot-steps that can deliver no token
+    # (a slot that owns no request, or whose request ended earlier in
+    # the chunk), and since the loop hands the chunk ``left`` do not.
     paged_decode_attend: str = ""
     attend_pages_walked: int = 0
+    attend_pages_dead: int = 0
     attend_pages_grid: int = 0
     # paged: the kinds of operator and of FFN the step program was built
     # from (kvpage.PagedSpec.built: "attention", "attention+conv",
@@ -183,6 +188,14 @@ class ServingMetrics:
         to fetch: what the live-page walk is left with."""
         return (self.attend_pages_walked / self.attend_pages_grid
                 if self.attend_pages_grid else 0.0)
+
+    @property
+    def attend_dead_share(self) -> float:
+        """Share of the pages a walk that knew no dead slot fetched
+        (every slot's, every step) that the dead slot-steps' were: how
+        often telling the chunk ``left`` engages."""
+        every = self.attend_pages_walked + self.attend_pages_dead
+        return self.attend_pages_dead / every if every else 0.0
 
     @property
     def moe_live_expert_share(self) -> float:
@@ -491,7 +504,9 @@ class RequestBook:
     capacity retired after a peer loss, never refilled, skipped by
     every ``owner[b] >= 0`` loop), ``last_tok`` (the step's input), per
     rid ``emitted`` / ``done`` / ``attempts`` / ``ttft`` / ``finish``,
-    the counters, gauge samples and RollingSLO, and ``metrics()``.
+    the counters, gauge samples and RollingSLO, ``left()`` (what each
+    slot's request still owes: the paged loops hand it to the chunk),
+    and ``metrics()``.
 
     ``rids`` narrows the book to the requests this rank serves (a
     decode rank's share); ``rejected`` rows are never queued. All
@@ -634,6 +649,16 @@ class RequestBook:
         self.ttft[rid] = time.perf_counter() - self.t0
         self.slo.note_ttft(self.ttft[rid])
         reqlog.emit("stream", rid, n=1, ttft_s=self.ttft[rid])
+
+    def left(self) -> np.ndarray:
+        """``[n_slots]`` int32: the tokens each slot's request still
+        owes (``n_new`` less what it has emitted), 0 for a slot that
+        owns none: at a chunk's start, the steps of the chunk in which
+        the slot can deliver a token. An ``eos`` may end a request
+        sooner; it is then only not known to."""
+        return np.asarray([self.n_new[rid] - len(self.emitted[rid])
+                           if rid >= 0 else 0 for rid in self.owner],
+                          np.int32)
 
     def slot_finished(self, b) -> bool:
         """Slot b's request has its ``n_new`` tokens, or ended on
@@ -1320,7 +1345,8 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
                         the slot), prefix.insert, bookkeeping, the first
                         on_token
         chunk.grow      grow_for_chunk (preemptions inside) + COW guard
-        chunk.upload    pkv.device_state()
+        chunk.upload    book.left(), pkv.device_state(left): the table,
+                        ``pos`` and what each slot still owes go up
         chunk.step      step_fn dispatch, absorb, np.asarray(tokens):
                         WAITS for the device
         chunk.deliver   the token loop and its on_token calls
@@ -1393,7 +1419,7 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
     book = RequestBook(prompts, n_new, n_slots, eos, chunk,
                        max_request_retries, rejected, on_token=on_token)
     queue, owner, slo = book.queue, book.owner, book.slo
-    n_preempts = n_slo_defer = pages_walked = pages_grid = 0
+    n_preempts = n_slo_defer = pages_walked = pages_dead = pages_grid = 0
     rewritten = staged = 0
     # Requests currently evicted by page pressure: membership here turns
     # the next successful seat into a journey "resume" event.
@@ -1608,11 +1634,14 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
         if not book.active():
             continue                # grow_for_chunk preempted everyone
         with ph("chunk.upload", step=step_no) as upload:
-            pages_walked += pkv.live_pages(chunk)
+            left = book.left()
+            walked = pkv.live_pages(chunk, left)
+            pages_walked += walked
+            pages_dead += pkv.live_pages(chunk) - walked
             pages_grid += n_slots * max_pages * chunk
             rewritten += pkv.chunk_rewrites(chunk)
             staged += chunk * n_slots
-            state = pkv.device_state()
+            state = pkv.device_state(left)
         with ph("chunk.step", step=step_no) as stepped:
             stepped.ids["rid"] = tuple(owner)
             try:
@@ -1662,6 +1691,7 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
                 state_snapshot_rows_hwm=pkv.snaps.rows_hwm,
                 state_snapshot_evictions=pkv.snaps.evictions)),
             attend_pages_walked=pages_walked,
+            attend_pages_dead=pages_dead,
             attend_pages_grid=pages_grid,
             kv_page_rewrites=rewritten,
             kv_tokens_staged=staged,

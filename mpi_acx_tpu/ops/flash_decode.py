@@ -21,7 +21,14 @@ that read with an online softmax over K/V blocks that is
   before it is folded, the next slot's first page started behind this
   slot's last. Nothing is paid per dead page: the ``(32, 8)`` grid it
   replaces paid 0.30 us for each dead step, 60 us of a 126 us call
-  (PERF.md, PR 30).
+  (PERF.md, PR 30). Nor per dead SLOT: told how many tokens each slot
+  still owes (``left``, what only the serve loop knows), the walk
+  starts no copy, folds nothing and fetches no block of ``q`` or of
+  the stage for a slot that can deliver no token in this step (it
+  owns no request, or its request ended earlier in the chunk), hops
+  it in the cross-slot prefetch, and writes its row as zeros; a third
+  of the pages a window's attends fetched were such slots' (PERF.md,
+  PR 38). Every LIVE slot still walks at least one page.
 * **GQA-native** — q ``[B, W, Hkv, n_rep, D]`` rides as
   ``[B, Hkv, W*n_rep, D]`` (row ``i`` is window slot ``i // n_rep``),
   attending the UN-repeated KV groups directly; one grid step carries
@@ -208,20 +215,36 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, *refs, block_k, n_rep,
         o_ref[0] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
 
 
-def _paged_walk_kernel(pos_ref, table_ref, layer_ref, step_ref, q_ref, *refs,
-                       page_tokens, n_rep, n_k, n_pools, n_stage, scale):
+def _paged_walk_kernel(pos_ref, table_ref, layer_ref, step_ref, nxt_ref,
+                       held_ref, q_ref, *refs, page_tokens, n_rep, n_k,
+                       n_pools, n_stage, scale):
     """The paged walk: one grid step a slot, and inside it one pass
     over the slot's LIVE pages and nothing per dead page. The pools
     stay in HBM (``refs[:n_pools]``: K, V, and the scale pools of an
     int8 cache); the kernel copies page ``(layer, table[b, j])`` of
     each into one of two VMEM buffers a pool and folds it while the
     next page's copies are in flight. The next page of a slot's LAST
-    page is the next slot's FIRST: it is already on its way when that
-    slot's grid step starts, as a BlockSpec pipeline would have had it
-    (32 exposed copy latencies a call otherwise). ``walked`` (SMEM)
-    counts the pages folded so far in the call: its parity is the
-    buffer the current page sits in, across slots. Needs sequential
+    page is the next LIVE slot's FIRST: it is already on its way when
+    that slot's grid step starts, as a BlockSpec pipeline would have
+    had it (32 exposed copy latencies a call otherwise). ``walked``
+    (SMEM) counts the pages folded so far in the call: its parity is
+    the buffer the current page sits in, across slots. Needs sequential
     grid steps (``arbitrary``).
+
+    A DEAD slot (one that can deliver no token in this step: it owns no
+    request, or its request ended earlier in the chunk; ``_live_slots``)
+    costs the walk a grid step (0.23 us) and nothing else: no page copy
+    is started or waited for, nothing is folded, its tile of ``q`` and
+    its block of the stage are not fetched (the index maps hold the
+    last live slot's, ``held_ref``: 205 KB and 0.25 us a dead slot a
+    call, 14% of the attend in a draining chunk: PERF.md, PR 38), and
+    its output row is ZEROS: defined and finite, where ``acc / l`` of an
+    empty fold is NaN and would reach the stage, the slot's pages and,
+    through the pool, a later owner. ``nxt_ref`` (``[B + 1]``) is the
+    first live slot at or after each slot, ``B`` where there is none:
+    slot ``b`` is live iff ``nxt_ref[b] == b``, the call's first copy is
+    ``nxt_ref[0]``'s, and the slot prefetched behind ``b``'s last page
+    is ``nxt_ref[b + 1]``.
 
     ``n_stage``: a decode chunk keeps its own tokens in a stage
     (:func:`new_kv_stage`; ``refs`` then holds, behind the pools, ``q``
@@ -229,17 +252,20 @@ def _paged_walk_kernel(pos_ref, table_ref, layer_ref, step_ref, q_ref, *refs,
     brought in by the grid's pipeline while the slot before is walked)
     and the pool is stale from the slot's position at the chunk's
     start on, ``pos0 = pos - step``. The walk then covers the pages
-    that hold a token below ``pos0`` (at least one, so that every slot
-    has a first page to prefetch: with ``pos0`` 0 it is masked whole,
-    and what a masked block leaves in the fold's state the next live
-    column wipes, the weight ``exp(-1e30 - m)`` being 0.0), and the
-    stage's tokens ``0..step`` are one more block of the same fold."""
+    that hold a token below ``pos0`` (for a live slot at least one, so
+    that it has a first page to prefetch: with ``pos0`` 0 it is masked
+    whole, and what a masked block leaves in the fold's state the next
+    live column wipes, the weight ``exp(-1e30 - m)`` being 0.0), and
+    the stage's tokens ``0..step`` are one more block of the same
+    fold."""
+    del held_ref                                # the index maps' alone
     pools, refs = refs[:n_pools], refs[n_pools:]
     staged, (o_ref, *refs) = refs[:n_stage], refs[n_stage:]
     bufs, (sem, m_scr, l_scr, acc_scr, walked) = (refs[:n_pools],
                                                   refs[n_pools:])
     b, B = pl.program_id(0), pl.num_programs(0)
     layer, pos = layer_ref[0], pos_ref[b]
+    live, after = nxt_ref[b] == b, nxt_ref[b + 1]
     if staged:
         pos0 = pos - step_ref[0]
         n = jnp.clip((pos0 + page_tokens - 1) // page_tokens, 1, n_k)
@@ -256,41 +282,73 @@ def _paged_walk_kernel(pos_ref, table_ref, layer_ref, step_ref, q_ref, *refs,
     @pl.when(b == 0)
     def _first_page_of_the_call():
         walked[0] = 0
-        for c in copies(0, 0, 0):
-            c.start()
 
-    first = walked[0]
-    _fold_init(m_scr, l_scr, acc_scr)
-
-    def page(j, _):
-        buf = (first + j) % 2
-        more = j + 1 < n
-
-        @pl.when(more | (b + 1 < B))
-        def _next_page():
-            for c in copies(jnp.where(more, b, b + 1),
-                            jnp.where(more, j + 1, 0), 1 - buf):
+        @pl.when(nxt_ref[0] < B)
+        def _():
+            for c in copies(nxt_ref[0], 0, 0):
                 c.start()
 
-        for c in copies(b, j, buf):
-            c.wait()
-        kb, vb, *scales = [dst[buf] for dst in bufs]
-        _fold_block(q_ref, kb, vb, scales, j * page_tokens, pos, m_scr,
-                    l_scr, acc_scr, n_rep=n_rep, scale=scale, stop=pos0)
+    @pl.when(live)
+    def _walk():
+        first = walked[0]
+        _fold_init(m_scr, l_scr, acc_scr)
 
-    jax.lax.fori_loop(0, n, page, None)
-    walked[0] = first + n
-    if staged:
-        q_wide, vk, *scales = staged
-        vk = jnp.swapaxes(vk[...], 0, 1)        # [chunk, H, 2 D]: heads first
-        _fold_block(q_wide, vk, vk, [s[...] for s in scales], pos0,
-                    pos, m_scr, l_scr, acc_scr, n_rep=n_rep, scale=scale,
-                    tokens_minor=False)
-    o_ref[0] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
+        def page(j, _):
+            buf = (first + j) % 2
+            more = j + 1 < n
+
+            @pl.when(more | (after < B))
+            def _next_page():
+                for c in copies(jnp.where(more, b, after),
+                                jnp.where(more, j + 1, 0), 1 - buf):
+                    c.start()
+
+            for c in copies(b, j, buf):
+                c.wait()
+            kb, vb, *scales = [dst[buf] for dst in bufs]
+            _fold_block(q_ref, kb, vb, scales, j * page_tokens, pos, m_scr,
+                        l_scr, acc_scr, n_rep=n_rep, scale=scale, stop=pos0)
+
+        jax.lax.fori_loop(0, n, page, None)
+        walked[0] = first + n
+        if staged:
+            q_wide, vk, *scales = staged
+            vk = jnp.swapaxes(vk[...], 0, 1)    # [chunk, H, 2 D]: heads first
+            _fold_block(q_wide, vk, vk, [s[...] for s in scales], pos0,
+                        pos, m_scr, l_scr, acc_scr, n_rep=n_rep, scale=scale,
+                        tokens_minor=False)
+        o_ref[0] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
+
+    @pl.when(jnp.logical_not(live))
+    def _dead():
+        o_ref[0] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
+
+
+def _live_slots(left, step, B):
+    """What the paged walk is told of the slots' lives, from ``left``
+    (``[B]``: the tokens each slot's request still owes at the chunk's
+    start; None: every slot live) and the chunk step: slot ``b`` can
+    deliver a token iff ``step < left[b]``. Returns ``nxt`` ``[B +
+    1]``, the first live slot at or after each slot (``B``: none, and
+    behind the last), and ``held`` ``[B]``, the slot whose blocks the
+    grid's pipeline holds at each grid step: the slot itself while it
+    lives, else the last live one before it (slot 0 in front of them
+    all), so that a dead slot's step changes no block index and
+    fetches nothing. Two running extrema that depend on the step
+    alone: XLA lifts them out of the scan over layers (an elementwise
+    pass behind one it leaves inside, a fusion a call: each vector is
+    a cumulative op's own result)."""
+    slots = jnp.arange(B + 1, dtype=jnp.int32)
+    if left is None:
+        return slots, slots[:B]
+    live = step < jnp.asarray(left, jnp.int32)
+    nxt = jax.lax.cummin(jnp.where(jnp.append(live, True), slots, B),
+                         reverse=True)
+    return nxt, jax.lax.cummax(jnp.where(live, slots[:B], 0))
 
 
 def _decode_call(q, k, v, pos, n_rep, block_k, n_k, table=None, layer=None,
-                 stage=None):
+                 stage=None, left=None):
     """The pallas_call behind both kernels. ``k``/``v`` are K/V
     arrays or (codes, scales) tuples in cache layout
     ([B, Hkv, *, max_len]) or, with ``table``, pool layout: the whole
@@ -301,7 +359,9 @@ def _decode_call(q, k, v, pos, n_rep, block_k, n_k, table=None, layer=None,
     ``(B, n_k)`` grid, a slot's live pages by the kernel's own copies
     out of the pool; the fold is one function. ``stage`` (paged only):
     ``(arrays, step)``, a chunk's stage (:func:`new_kv_stage`) and the
-    chunk step it is filled up to."""
+    chunk step it is filled up to; ``left`` (paged only): the tokens
+    each slot still owes, of which the walk reads which slots are dead
+    at this step (:func:`_live_slots`)."""
     ks = vs = None
     if isinstance(k, tuple):
         k, ks = k
@@ -369,18 +429,26 @@ def _decode_call(q, k, v, pos, n_rep, block_k, n_k, table=None, layer=None,
             operands += staged
         prefetch = [pos, jnp.asarray(table, jnp.int32),
                     jnp.asarray(layer, jnp.int32).reshape(1),
-                    jnp.asarray(step, jnp.int32).reshape(1)]
+                    jnp.asarray(step, jnp.int32).reshape(1),
+                    *_live_slots(left, step, B)]
         kernel = functools.partial(_paged_walk_kernel, page_tokens=block_k,
                                    n_pools=len(pools), n_stage=len(staged),
                                    **static)
         grid, semantics = (B,), ("arbitrary",)
-        in_specs = [q_spec] + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools)
+
+        def q_held(width):
+            """A slot's own tile of ``q``, a dead slot's left where the
+            last live slot's is (``held``, the last prefetched)."""
+            return pl.BlockSpec((1, Hkv, Wn, width),
+                                lambda b, *refs: (refs[-1][b], 0, 0, 0))
+
+        in_specs = ([q_held(D)]
+                    + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools))
         if staged:
-            in_specs += [pl.BlockSpec((1, Hkv, Wn, 2 * D),
-                                      lambda b, *_: (b, 0, 0, 0))] + [
+            in_specs += [q_held(2 * D)] + [
                 pl.BlockSpec((None, None) + a.shape[2:],
-                             lambda b, _, __, layer_ref, *___: (
-                                 layer_ref[0], b, 0, 0, 0))
+                             lambda b, _, __, layer_ref, *refs: (
+                                 layer_ref[0], refs[-1][b], 0, 0, 0))
                 for a in staged[1:]]
         scratch = ([pltpu.VMEM((2,) + p.shape[2:], p.dtype) for p in pools]
                    + [pltpu.SemaphoreType.DMA((len(pools), 2))]
@@ -470,7 +538,7 @@ def stage_tokens(stage, layer=None):
 
 
 def paged_gather_attend(q, kp, vp, table, pos, page_tokens, n_rep,
-                        layer=None, stage=None):
+                        layer=None, stage=None, left=None):
     """Dense reference for paged attention: gather each slot's pages
     into the contiguous ``[B, Hkv, D, max_len]`` layout the fixed-slot
     path attends and call :func:`dense_decode_attend` — identical
@@ -485,11 +553,16 @@ def paged_gather_attend(q, kp, vp, table, pos, page_tokens, n_rep,
     (:func:`paged_kv_write_runs`): what each slot's pages would hold
     had every token been written as it came, an idle slot's parking
     page and a row's clipped end included, so a staged chunk is
-    bit-equal to the same steps with the dense write."""
+    bit-equal to the same steps with the dense write. With ``left``
+    (``[B]``: the tokens each slot still owes at the chunk's start) the
+    row of a slot that is dead at this step (``step >= left[b]``; step 0
+    without a stage) is zeros, as the kernel's is: the two agree on
+    every row."""
     from mpi_acx_tpu.models.decoding import dense_decode_attend
 
     B, max_pages = table.shape
     max_len = max_pages * page_tokens
+    step = 0
 
     def gather(pool):
         t = jnp.take(pool, table, axis=0)     # [B, max_pages, H, *, pt]
@@ -509,11 +582,14 @@ def paged_gather_attend(q, kp, vp, table, pos, page_tokens, n_rep,
         k, v, *scales = (p[0] for p in pools)
         kl, vl = ((k, scales[0]), (v, scales[1])) if quant else (k, v)
     kin, vin = jax.tree.map(gather, kl), jax.tree.map(gather, vl)
-    return dense_decode_attend(q, kin, vin, pos, max_len, n_rep)
+    out = dense_decode_attend(q, kin, vin, pos, max_len, n_rep)
+    if left is None:
+        return out
+    return jnp.where((step < jnp.asarray(left))[:, None, None], out, 0)
 
 
 def paged_flash_decode_attend(q, kp, vp, table, pos, page_tokens, n_rep,
-                              layer=None, stage=None):
+                              layer=None, stage=None, left=None):
     """Pallas paged decode attention: K/V pools ``[P, Hkv, D,
     page_tokens]`` (plus (codes, scales) tuples for int8 pools) addressed through
     a ``[B, max_pages]`` block table — or, with ``layer``, the whole
@@ -527,10 +603,14 @@ def paged_flash_decode_attend(q, kp, vp, table, pos, page_tokens, n_rep,
     a decode chunk's :func:`new_kv_stage` and the chunk step it is
     filled up to, ``pos`` being the slots' positions NOW) the pool
     counts up to ``pos - step`` and the stage's tokens ``0..step``
-    follow it: still one call with the one result."""
+    follow it: still one call with the one result. With ``left``
+    (``[B]``: the tokens each slot still owes at the chunk's start) a
+    slot that can deliver nothing at this step (``step >= left[b]``)
+    costs no page copy, no fold and no stage block, and its row is
+    zeros; live rows are what they are without ``left``, bit for bit."""
     return _decode_call(q, kp, vp, pos, n_rep, page_tokens,
                         table.shape[1], table=table, layer=layer,
-                        stage=stage)
+                        stage=stage, left=left)
 
 
 def _paged_kernels_fit(page_tokens):
@@ -547,7 +627,8 @@ def select_paged_decode_attend(decode_flash, page_tokens):
     einsum beats an interpreted kernel, and gather-dense is also the
     bit-equality anchor); ``True`` -> the kernel (interpret mode
     off-TPU); ``False`` -> the reference. Both take ``(q, kp, vp,
-    table, pos, page_tokens, n_rep, layer=None)``; the chosen
+    table, pos, page_tokens, n_rep, layer=None, stage=None,
+    left=None)``; the chosen
     function's ``__name__`` is what
     ``ServingMetrics.paged_decode_attend`` records
     (``paged_flash_decode_attend`` is the live-page walk, all or

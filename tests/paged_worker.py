@@ -21,7 +21,10 @@ intake's allocate/rollback path in the loop.
 
 Knobs: ACX_DISAGG_REQS scales the request count; ACX_PAGED_PT
 overrides the page size (default 8 — several pages per request on the
-tiny config, so the allocator actually cycles).
+tiny config, so the allocator actually cycles); ACX_PAGED_CHUNK the
+decode chunk (default 1; at 4 requests end mid-chunk, and the worker
+tells each chunk so: every ``device_state`` call is checked to carry
+the book's ``left``).
 """
 
 import json
@@ -38,6 +41,7 @@ import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
 from mpi_acx_tpu import runtime  # noqa: E402
+from mpi_acx_tpu.models import kvpage  # noqa: E402
 from mpi_acx_tpu.models import transformer as tfm  # noqa: E402
 from mpi_acx_tpu.models.disagg import (fleet_roles, run_decode_worker,  # noqa: E402
                                        run_prefill_worker)
@@ -50,7 +54,8 @@ def main():
 
     cfg = tfm.tiny_config()
     lens = [5, 11, 3, 17, 8, 13, 7, 21, 4, 9]
-    max_len, n_slots, chunk = 64, 2, 1
+    max_len, n_slots = 64, 2
+    chunk = int(os.environ.get("ACX_PAGED_CHUNK", "1"))
     params = tfm.init_params(jax.random.key(0), cfg)
     rng = np.random.default_rng(11)
     prompts = [rng.integers(0, cfg.vocab, size=lens[i % len(lens)])
@@ -72,6 +77,13 @@ def main():
             "rank": rt.rank, "role": "prefill",
             "wall_s": round(wall, 4)}), flush=True)
     else:
+        # every chunk is told how many tokens each slot still owes
+        handed, device_state = [], kvpage.PagedKV.device_state
+
+        def recording(self, left=None):
+            handed.append(left)
+            return device_state(self, left)
+        kvpage.PagedKV.device_state = recording
         batch = run_decode_worker(
             rt, params, cfg, prompts, n_new, n_slots=n_slots,
             max_len=max_len, family=tfm, chunk=chunk,
@@ -82,6 +94,9 @@ def main():
         m = batch.metrics
         mine = [r.rid for r in m.per_request]
         assert mine, "decode rank owns no requests"
+        assert handed and all(h is not None for h in handed), handed
+        assert m.decode_tokens == sum(
+            int(np.minimum(h, chunk).sum()) for h in handed), handed
         for rid in mine:
             assert batch[rid] is not None, f"request {rid} unserved"
             np.testing.assert_array_equal(

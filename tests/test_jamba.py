@@ -576,6 +576,33 @@ def test_serve_paged_greedy_serves_it_hits_and_counts(tree):
     assert all((a == b).all() for a, b in zip(tight, outs))
 
 
+def test_requests_that_end_mid_chunk_get_the_references_tokens(tree,
+                                                                monkeypatch):
+    """Outputs of 2 to 9 tokens against a chunk of 4, five requests into
+    two slots: requests end mid-chunk and the last drains beside an
+    empty slot. The loop tells each chunk what every slot still owes
+    (``state['left']``) and the attend zeroes the rows of the slot-steps
+    that can deliver nothing; this family's other operators run on as
+    they did. Every served token is still the reference's choice to
+    ATOL, and the tokens are, bit for bit, those of the same call with
+    every slot said to be live throughout."""
+    prompts = [_seq(41, 20), _seq(9, 21), _seq(23, 22), _seq(35, 23),
+               _seq(17, 24)]
+    n_new = [6, 3, 9, 2, 5]
+    told = _serve(tree, prompts, n_new, n_snapshots=6)
+    assert [len(o) - len(p) for o, p in zip(told, prompts)] == n_new
+    assert _gaps(tree, prompts, told).max() <= ATOL
+    assert 0 < told.metrics.attend_dead_share < 1
+    monkeypatch.setattr(serving.RequestBook, "left",
+                        lambda self: np.full(self.n_slots, self.chunk,
+                                             np.int32))
+    untold = _serve(tree, prompts, n_new, n_snapshots=6)
+    assert untold.metrics.attend_pages_dead == 0
+    assert untold.metrics.attend_pages_walked == (
+        told.metrics.attend_pages_walked + told.metrics.attend_pages_dead)
+    assert all((a == b).all() for a, b in zip(told, untold))
+
+
 @pytest.mark.parametrize("prefix_cache", [True, False],
                          ids=["prefix_cache_on", "prefix_cache_off"])
 def test_serve_loop_preempts_and_resumes_to_the_same_tokens(tree,

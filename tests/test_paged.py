@@ -546,6 +546,156 @@ def test_staged_attend_reads_the_pool_then_the_stage(kind, shape, chunk):
             np.asarray(want, np.float32)[[0, 2]], atol=tol, rtol=tol)
 
 
+# name -> left [n slots] for an attend at chunk step ``step``: a slot is
+# live iff ``step < left[b]`` (None: no ``left`` at all)
+LEFT_CASES = {
+    "first-dead": lambda n, step: [0] + [step + 9] * (n - 1),
+    "last-dead": lambda n, step: [step + 9] * (n - 1) + [0],
+    "two-in-a-row": lambda n, step: [step + 1, 0, step] + [step + 1] * (n - 3),
+    "all-but-one": lambda n, step: [0] * (n - 2) + [step + 1, step],
+    "about-to-die": lambda n, step: [step + 1, step] * (n // 2) + [step] * (
+        n % 2),
+    "all-dead": lambda n, step: [0] * n,
+    "none-dead": lambda n, step: [step + 1] * n,
+    "absent": lambda n, step: None,
+}
+
+
+def _planted(arrays, where, axis):
+    """``arrays`` with NaN at ``where`` along ``axis`` of every array
+    that can hold one (an int8 cache's codes cannot; its scales do)."""
+    idx = (slice(None),) * axis + (np.asarray(where, np.int32),)
+    return tuple(a.at[idx].set(jnp.nan)
+                 if jnp.issubdtype(a.dtype, jnp.floating) and len(where)
+                 else a for a in arrays)
+
+
+@pytest.mark.parametrize("case", list(LEFT_CASES))
+@pytest.mark.parametrize("staged", [False, True], ids=["no-stage", "stage"])
+@pytest.mark.parametrize("kind", ["bf16", "int8"])
+def test_dead_slots_cost_the_walk_nothing_and_read_zero(kind, staged, case):
+    """The attend told how many tokens each slot still owes: a slot
+    with ``step >= left[b]`` is DEAD. The walk (interpret mode) copies
+    and folds nothing for it: every page of its table row and its block
+    of the stage hold NaN here (NaN scales where the codes are int8)
+    and no row of the result is non-finite; its row is exactly 0.0;
+    every live row is BIT-equal to the same call without ``left`` on
+    clean data; and the gather reference, given the same ``left``,
+    zeroes the same rows and leaves its live rows bit-equal to its own
+    without. First slot dead (the call's first copy is the first LIVE
+    slot's), last slot dead, dead slots in a row (the prefetch behind a
+    slot's last page hops them), one slot alive, none, all; a slot on
+    its last token and one just past it; no ``left`` at all."""
+    n_rep = 2
+    q, kp, vp, table, pos, layer, _ = _walk_case(kind, n_rep, True)
+    nb, quant = len(WALK_POS), kind == "int8"
+    pools = (kp[0], vp[0], kp[1], vp[1]) if quant else (kp, vp)
+
+    def kv(ps):
+        return ((ps[0], ps[2]), (ps[1], ps[3])) if quant else ps
+
+    step, stage = 0, None
+    if staged:
+        rng = np.random.default_rng(11)
+        step = 2
+        arrays = flash_decode.new_kv_stage(pools, nb, 5)
+        for t in range(step + 1):
+            x = rng.standard_normal((2, nb, 1, Hkv, D))
+            if quant:
+                (k, ks), (v, vs) = (kv_quant(jnp.asarray(a, jnp.float32))
+                                    for a in x)
+                fresh = (k, v, ks, vs)
+            else:
+                fresh = tuple(jnp.asarray(a, jnp.bfloat16) for a in x)
+            arrays = flash_decode.stage_put(arrays, fresh, jnp.int32(layer),
+                                            jnp.int32(t))
+        stage = (arrays, jnp.int32(step))
+        pos = pos + step
+    left = LEFT_CASES[case](nb, step)
+    live = (np.ones(nb, bool) if left is None
+            else step < np.asarray(left))
+    assert case in ("absent", "none-dead") or not live.all()
+    # the gather reference multiplies what it gathered by 0: give it a
+    # table whose dead COLUMNS repeat the slot's first page (the walk
+    # never reads them: they are NaN pages)
+    alive = jnp.where(jnp.arange(WALK_PAGES)[None]
+                      <= (jnp.asarray(WALK_POS) // PT)[:, None],
+                      table, table[:, :1])
+    kw = dict(layer=layer, stage=stage)
+    base = np.asarray(paged_flash_decode_attend(
+        q, *kv(pools), table, pos, PT, n_rep, **kw), np.float32)
+    dense = np.asarray(paged_gather_attend(
+        q, *kv(pools), alive, pos, PT, n_rep, **kw), np.float32)
+    assert np.isfinite(base).all() and np.isfinite(dense).all()
+
+    dead = np.flatnonzero(~live)
+    bad = _planted(pools, np.asarray(table)[dead].ravel(), 1)
+    if staged:
+        kw["stage"] = (_planted(stage[0], dead, 1), stage[1])
+    if left is not None:
+        kw["left"] = jnp.asarray(left, jnp.int32)
+    got = np.asarray(paged_flash_decode_attend(
+        q, *kv(bad), table, pos, PT, n_rep, **kw), np.float32)
+    np.testing.assert_array_equal(got[live], base[live])
+    assert (got[~live] == 0.0).all() and not np.signbit(got[~live]).any()
+    ref = np.asarray(paged_gather_attend(
+        q, *kv(bad), alive, pos, PT, n_rep, **kw), np.float32)
+    np.testing.assert_array_equal(ref[live], dense[live])
+    assert (ref[~live] == 0.0).all()
+    # walk against gather, where the stage's tokens stay inside the
+    # slot's last live page (the aliased table has no next one to take
+    # them; test_staged_attend_reads_the_pool_then_the_stage crosses)
+    fits = np.asarray(WALK_POS) % PT + step < PT
+    tol = 2e-4 if quant else 4e-2
+    np.testing.assert_allclose(got[fits], ref[fits], rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("decode_flash", [None, True],
+                         ids=["dense", "kernels"])
+def test_a_dead_slot_puts_nothing_non_finite_into_a_pool(decode_flash):
+    """A whole ``paged_decode_chunk`` with ``left``: slot 2 owns no
+    request and its parking page holds NaN, slot 1's request ends two
+    steps into the chunk. Told so, the attend gives both zeros from
+    then on, so what the chunk stages for them and flushes into their
+    pages is FINITE in every layer (without ``left`` the idle slot
+    reads its NaN page, its row is NaN, and the second layer's K/V of
+    every one of its tokens is NaN: pages that go back to the pool). No
+    page a live slot owns holds a non-finite value, and the tokens the
+    loop keeps (a slot's first ``left``) are those of the same chunk
+    told nothing, on a clean pool."""
+    pt, chunk = 8, 5
+    cfg, params, pkv = _chunk_setup(False, pt, chunk,
+                                    decode_flash=decode_flash)
+    tok = jnp.asarray([4, 9, 0, 17], jnp.int32)
+    keys = jax.random.split(jax.random.key(0), 4)
+    step = kvpage.make_paged_step_fn(params, cfg, tfm, chunk, pt)
+    clean = pkv.device_state()
+    _, want, _ = step(jax.tree.map(jnp.copy, clean), tok, keys)
+    park = 14 + 2                               # slot 2's parking page
+    left = np.asarray([chunk, 2, 0, chunk], np.int32)
+    state = pkv.device_state(left)
+    for key in ("k", "v"):
+        state[key] = state[key].at[:, park].set(jnp.nan)
+    for told in (True, False):
+        s = jax.tree.map(jnp.copy, state)
+        if not told:
+            del s["left"]
+        out, toks, _ = step(s, tok, keys)
+        fresh = np.asarray(out["k"], np.float32)[:, park, :, :, :chunk]
+        assert np.isfinite(fresh[0]).all()          # K/V of the embedding
+        assert np.isfinite(fresh[1]).all() == told, told
+    out, toks, _ = step(jax.tree.map(jnp.copy, state), tok, keys)
+    assert sorted(out) == sorted(state)             # ``left`` rides along
+    np.testing.assert_array_equal(np.asarray(out["left"]), left)
+    owned = sorted(p for b in (0, 1, 3) for p in pkv.pages[b])
+    for key in ("k", "v"):
+        assert np.isfinite(np.asarray(out[key], np.float32)[:, owned]).all()
+    toks, want = np.asarray(toks), np.asarray(want)
+    for b, n in enumerate(left):
+        np.testing.assert_array_equal(toks[:n, b], want[:n, b],
+                                      err_msg=f"slot {b}")
+
+
 def _chunk_setup(kv_int8, pt, chunk, decode_flash=None, seed=4):
     """A small GPT-2 on four slots at ``pt`` tokens a page: slot 0 at
     lane 0 of its second page, slot 1 at a page's last lane, slot 2
@@ -710,23 +860,33 @@ def test_select_paged_kv_write_follows_the_attend(on_tpu, page_tokens,
 # allocator / trie / PagedKV units
 
 
-@pytest.mark.parametrize("pos,chunk,want", [
-    ([0, 0, 0], 1, 3),                        # idle: the parking page each
-    ([0, 0, 0], 8, 24),
-    ([7, 8, 31], 1, 1 + 1 + 4),               # the pages below pos
-    ([7, 0, 0], 2, (1 + 1 + 1) * 2),          # the chunk's own: the stage
-    ([31, 40, 100], 4, 3 * 4 * 4),            # never past the table row
+@pytest.mark.parametrize("pos,chunk,left,want", [
+    ([0, 0, 0], 1, None, 3),                  # idle: the parking page each
+    ([0, 0, 0], 8, None, 24),
+    ([7, 8, 31], 1, None, 1 + 1 + 4),         # the pages below pos
+    ([7, 0, 0], 2, None, (1 + 1 + 1) * 2),    # the chunk's own: the stage
+    ([31, 40, 100], 4, None, 3 * 4 * 4),      # never past the table row
+    # told what each slot owes: a slot reads in its live steps alone
+    ([0, 0, 0], 8, [0, 0, 0], 0),             # nobody owns a request
+    ([7, 8, 31], 4, [9, 4, 5], (1 + 1 + 4) * 4),      # all live throughout
+    ([7, 8, 31], 4, [1, 0, 3], 1 * 1 + 0 + 4 * 3),    # ends mid-chunk, idle
+    ([31, 40, 100], 4, [2, 0, 7], 4 * 2 + 0 + 4 * 4),
 ])
-def test_live_pages_counts_what_the_walk_fetches(pos, chunk, want):
-    """PagedKV.live_pages: a layer's attends over the next chunk, every
-    slot reading the pool up to its pos at the chunk's start
-    (ServingMetrics.attend_pages_walked)."""
+def test_live_pages_counts_what_the_walk_fetches(pos, chunk, left, want):
+    """PagedKV.live_pages: a layer's attends over the next chunk, a
+    slot reading the pool up to its pos at the chunk's start in every
+    step in which it can still deliver a token: all of them when told
+    nothing (ServingMetrics.attend_pages_walked +
+    attend_pages_dead), the first ``left[b]`` when told
+    (attend_pages_walked)."""
     cfg = tfm.tiny_config(vocab=31, d_model=16, n_heads=2, n_layers=1,
                           d_ff=32, max_seq=32)
     pkv = kvpage.PagedKV(cfg, tfm, n_slots=3, max_len=32, page_tokens=8,
                          n_pages=12)
     pkv.pos[:] = pos
-    assert pkv.live_pages(chunk) == want
+    assert pkv.live_pages(
+        chunk, None if left is None else np.asarray(left)) == want
+    assert pkv.live_pages(chunk, np.full(3, chunk)) == pkv.live_pages(chunk)
 
 
 def test_allocator_deterministic_and_refcounted():
@@ -835,23 +995,31 @@ def _serve_setup():
 # which invokes this file unfiltered. Each full serve jit-compiles its
 # own step functions (~4-7s on this box), and the tier-1 sweep runs
 # against a hard wall-clock budget.
+RAGGED = [6, 3, 9, 2, 5, 7, 4]       # new tokens a request of _serve_setup
+
+
 @pytest.mark.parametrize("kv_int8", [
     pytest.param(False, marks=pytest.mark.slow),
     True,
 ], ids=["bf16", "int8kv"])
-@pytest.mark.parametrize("chunk", [
-    1,
-    pytest.param(4, marks=pytest.mark.slow),
-])
-def test_serve_paged_bit_equals_fixed(kv_int8, chunk):
+@pytest.mark.parametrize("chunk,n_new", [
+    (1, 6),
+    pytest.param(4, 6, marks=pytest.mark.slow),
+    (4, RAGGED),
+], ids=["1-even", "4-even", "4-ragged"])
+def test_serve_paged_bit_equals_fixed(kv_int8, chunk, n_new):
     """The §19 acceptance bar: on identical schedules the paged server
     reproduces fixed-slot serve_greedy BIT for BIT — bf16 and int8
-    caches, chunked dispatch included."""
+    caches, chunked dispatch included; and with outputs of 2 to 9
+    tokens against a chunk of 4, where requests end mid-chunk and the
+    last ones drain beside empty slots: slot-steps the loop tells the
+    chunk are dead (``state['left']``), whose rows the attend zeroes
+    and the loop never reads."""
     cfg, params, prompts = _serve_setup()
-    fixed = serving.serve_greedy(params, cfg, prompts, 6, n_slots=3,
+    fixed = serving.serve_greedy(params, cfg, prompts, n_new, n_slots=3,
                                  max_len=32, family=tfm, chunk=chunk,
                                  kv_int8=kv_int8)
-    paged = serving.serve_paged_greedy(params, cfg, prompts, 6, n_slots=3,
+    paged = serving.serve_paged_greedy(params, cfg, prompts, n_new, n_slots=3,
                                        max_len=32, family=tfm, chunk=chunk,
                                        kv_int8=kv_int8, page_tokens=8)
     for i, (f, p) in enumerate(zip(fixed, paged)):
@@ -861,6 +1029,63 @@ def test_serve_paged_bit_equals_fixed(kv_int8, chunk):
     # The HBM claim in miniature: 7 staggered requests through 3 slots
     # peak well under the fixed-equivalent 12 pages (3 slots * 4 pages).
     assert 0 < paged.metrics.pages_hwm < 12
+    # seven requests through three slots drain beside empty slots, and
+    # the ragged ones end mid-chunk: dead slot-steps, whatever the chunk
+    assert paged.metrics.attend_pages_dead > 0
+
+
+@pytest.mark.parametrize("decode_flash", [None, True],
+                         ids=["dense", "kernels"])
+def test_the_loop_tells_the_chunk_what_each_slot_owes(decode_flash,
+                                                      monkeypatch):
+    """``serve_paged_greedy`` hands every chunk ``RequestBook.left``:
+    for an owner its ``n_new`` less what it has emitted (at least 1: a
+    finished request was retired before the chunk), 0 for a slot that
+    owns none. The tokens a request gets do not depend on it: the same
+    call with every slot said to be live throughout (today's walk)
+    serves the same tokens bit for bit, with the dense pair and with
+    the kernels (interpret mode); the second call traces nothing (one
+    program, ``left`` a plain operand); and what the two calls count
+    adds up: walked + dead told is walked untold."""
+    import dataclasses
+    cfg, params, prompts = _serve_setup()
+    cfg = dataclasses.replace(cfg, decode_flash=decode_flash)
+    chunk, n_slots, handed = 4, 3, []
+    device_state = kvpage.PagedKV.device_state
+
+    def recording(self, left=None):
+        handed.append(None if left is None else np.array(left))
+        return device_state(self, left)
+
+    monkeypatch.setattr(kvpage.PagedKV, "device_state", recording)
+
+    def serve():
+        return serving.serve_paged_greedy(
+            params, cfg, prompts, RAGGED, n_slots=n_slots, max_len=32,
+            family=tfm, chunk=chunk, page_tokens=8)
+
+    told = serve()
+    assert len(handed) == told.metrics.steps
+    assert all(h is not None and h.dtype == np.int32
+               and h.shape == (n_slots,) for h in handed)
+    # the first chunk: requests 0-2 seated, one token each from prefill
+    np.testing.assert_array_equal(handed[0], [5, 2, 8])
+    assert all((h >= 0).all() and h.max() >= 1 for h in handed)
+    assert any((h == 0).any() for h in handed)          # the draining tail
+    # a slot-step delivers a token iff it is live
+    assert told.metrics.decode_tokens == sum(
+        int(np.minimum(h, chunk).sum()) for h in handed)
+    monkeypatch.setattr(serving.RequestBook, "left",
+                        lambda self: np.full(self.n_slots, self.chunk,
+                                             np.int32))
+    untold = serve()
+    assert untold.metrics.programs_traced == 0
+    for i, (a, b) in enumerate(zip(told, untold)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=f"request {i}")
+    assert untold.metrics.attend_pages_dead == 0
+    assert 0 < told.metrics.attend_pages_dead == (
+        untold.metrics.attend_pages_walked - told.metrics.attend_pages_walked)
 
 
 @pytest.mark.slow
@@ -1044,9 +1269,16 @@ def test_serve_paged_phases_cover_the_call_and_count_the_decode_work():
     assert m.paged_decode_attend == "paged_gather_attend"
     # the live-page walk's work, whichever attend ran: max_pages = 4
     assert m.attend_pages_grid == m.steps * chunk * n_slots * 4
-    assert n_slots * chunk * m.steps <= m.attend_pages_walked
+    # a slot-step that delivers a token fetches 1-3 pages (prompts of
+    # 3-12, outputs to 9); one that cannot, none: the loop told the chunk
+    # (``left``). What it would have fetched is counted beside
+    assert m.decode_tokens <= m.attend_pages_walked <= 3 * m.decode_tokens
+    every = m.attend_pages_walked + m.attend_pages_dead
+    assert n_slots * chunk * m.steps <= every <= m.attend_pages_grid
+    assert m.attend_dead_share == m.attend_pages_dead / every
+    assert 0.25 < m.attend_dead_share < 0.75   # mid-chunk ends and a tail
     assert m.attend_live_share == m.attend_pages_walked / m.attend_pages_grid
-    assert 0.25 <= m.attend_live_share < 0.75           # prompts of 3-12
+    assert 0.1 <= m.attend_live_share < 0.5
     assert set(m.phase_s) == set(m.phase_n) == set(PHASES)
     assert all(v >= 0 for v in m.phase_s.values())
     # between two spans the clock is not read: ~8 us of a span's own

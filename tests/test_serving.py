@@ -526,6 +526,28 @@ def test_book_slot_finished_and_deliver_stop_at_the_end(eos, block, want,
     assert book.steps == 1
 
 
+@pytest.mark.parametrize("n_new,blocks,want", [
+    ([5, 2, 9], [], [4, 1, 0]),               # seated: one token each had
+    ([5, 2, 9], [[[4, 5, 0]], [[6, 7, 0]]], [2, 0, 0]),  # slot 1 done, kept
+    ([1, 3, 9], [], [0, 2, 0]),               # the prefill's token was all
+], ids=["seated", "after-two-steps", "one-token-request"])
+def test_book_left_is_what_each_slot_still_owes(n_new, blocks, want):
+    """``left``: per slot the owner's ``n_new`` less what it has emitted,
+    0 for a slot that owns no request (idle or shed), ``[n_slots]``
+    int32: the steps of the next chunk in which the slot can deliver a
+    token. A request that has its tokens and is not yet retired owes 0."""
+    prompts = [np.arange(1, 4, dtype=np.int32)] * 2
+    book = serving.RequestBook(prompts, n_new[:2], 3, None, 1, 0)
+    _seat_heads(book, 2)
+    for block in blocks:
+        book.deliver(np.asarray(block, np.int32), 0.01)
+    got = book.left()
+    assert got.dtype == np.int32 and got.shape == (3,)
+    assert list(got) == want
+    book.owner[2] = -2                                  # shed: owes nothing
+    assert list(book.left()) == want
+
+
 def test_book_streams_finish_and_metrics_over_its_own_rids():
     """on_token sees the first token and every delivered one; a rejected
     request keeps its marker and has no telemetry row; ``rids`` narrows

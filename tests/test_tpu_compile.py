@@ -271,6 +271,42 @@ def _xl_attend(kind):
              _s((), jnp.int32)])
 
 
+# slots, K/V heads, query heads a K/V head, head width, table columns,
+# layers with pages, pages: the three serving cells' walks
+WALK_GEOMETRIES = {
+    "xl": (XL_B, XL_H, 1, D, MAX_LEN // PAGE, 48, XL_PAGES),
+    "lfm2": (64, 8, 4, 64, MAX_LEN // PAGE, 2, 1024 + 64),
+    "jamba": (128, 1, 20, 128, 1280 // PAGE, 2, 1280 + 128),
+}
+
+
+@pytest.mark.parametrize("staged", [True, False], ids=["stage", "no-stage"])
+@pytest.mark.parametrize("cell", list(WALK_GEOMETRIES))
+def test_paged_walk_with_left_compiles_for_v5e(cell, staged, v5e):
+    """The live-page walk told which slots are dead (``left``: two more
+    prefetched vectors, a ``pl.when`` around the walk, index maps that
+    read one of them) at each serving cell's geometry, behind a chunk's
+    stage and without one: Mosaic takes it, as ONE call with the result
+    the benchmark's readers match."""
+    nb, hkv, n_rep, d, cols, layers, pages = WALK_GEOMETRIES[cell]
+    pool = _s((layers, pages, hkv, d, PAGE), jnp.bfloat16)
+    stage = _s((layers, nb, XL_CHUNK, hkv, 2 * d), jnp.bfloat16)
+
+    def attend(q, k, v, table, pos, layer, left, stage, step):
+        return flash_decode.paged_flash_decode_attend(
+            q, k, v, table, pos, PAGE, n_rep, layer=layer, left=left,
+            stage=((stage,), step) if staged else None)
+
+    args = [_s((nb, 1, hkv * n_rep, d), jnp.bfloat16), pool, pool,
+            _s((nb, cols), jnp.int32), _s((nb,), jnp.int32),
+            _s((), jnp.int32), _s((nb,), jnp.int32), stage,
+            _s((), jnp.int32)]
+    text = jax.jit(attend).lower(*_place(args, v5e)).compile().as_text()
+    calls = re.findall(r"%paged_flash_decode_attend[.0-9]* = "
+                       r"([a-z0-9]+\[[0-9,]*\]).* custom-call\(", text)
+    assert calls == [f"bf16[{nb},{hkv},{n_rep},{d}]"], calls
+
+
 def _compiled_write(write, kind, v5e):
     """(compiled text, pool shape) of a page write on donated pools:
     (pools, fresh [B, 1, H, *], layer, write_page [B], off [B])."""
@@ -330,7 +366,8 @@ XL_CHUNK = 32
 def _xl_chunk(kind, n_layers=XL_L):
     """(paged_decode_chunk, args, keywords): the process's one chunk
     program as ``make_paged_step_fn`` binds it at the cell's geometry
-    and default config, the serving chunk of 32 steps."""
+    and default config, the serving chunk of 32 steps, the state with
+    ``left`` as a serve loop hands it over."""
     cfg = _XL_CFG
     params = jax.tree.map(
         lambda a: _s(a.shape, a.dtype),
@@ -340,7 +377,7 @@ def _xl_chunk(kind, n_layers=XL_L):
     pools = _xl_pools(kind, n_layers)
     state = dict(zip(("k", "v", "ks", "vs"), pools),
                  table=_s((XL_B, MAX_LEN // PAGE), jnp.int32),
-                 pos=_s((XL_B,), jnp.int32))
+                 pos=_s((XL_B,), jnp.int32), left=_s((XL_B,), jnp.int32))
     keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0), XL_B))
     step = kvpage.make_paged_step_fn(params, cfg, tfm, XL_CHUNK, PAGE)
     assert step.func is kvpage.paged_decode_chunk
@@ -449,7 +486,9 @@ def test_paged_decode_chunk_moves_no_pool(kind, v5e):
     (PERF.md, PR 25), invisible off it. The attend is ONE custom call
     with the single result ``bf16[32,25,1,64]`` (what the benchmark's
     roofline reader matches), and its grid is the slots alone: no step
-    a (slot, page) pair, live or dead. The write is the chunk's FLUSH:
+    a (slot, page) pair, live or dead; with ``left`` in the state it is
+    told which slots are dead, by two vectors made once a step. The
+    write is the chunk's FLUSH:
     ``chunk`` tokens a slot over a grid of (slot, the two pages they
     can land in), in the scan over layers BEHIND the scan over steps
     (one loop deep, where the attend is two deep), so a page is moved
@@ -485,6 +524,9 @@ def test_paged_decode_chunk_moves_no_pool(kind, v5e):
     bodies = re.split(r"\n(?=%|ENTRY )", text)
     body, = [b for b in bodies if "%paged_flash_decode_attend" in b
              and "tpu_custom_call" in b]
+    # which slots are dead (``flash_decode._live_slots``) depends on the
+    # step alone: worked out once a step, not in front of each call
+    assert "_live_slots" in text and "_live_slots" not in body
     updates = {b.split(" ", 1)[0] for b in bodies          # fused, in place
                if re.search(r"\n\s*ROOT [^\n]*? dynamic-update-slice\(", b)}
     made = [l.strip() for l in body.splitlines()
@@ -569,7 +611,7 @@ def test_lfm2_decode_chunk_compiles_and_moves_no_pool(v5e):
     assert pool["k"].shape == (2, LFM2_PAGES, 8, 64, PAGE)
     state = dict(k=pool["k"], v=pool["v"],
                  table=_s((LFM2_B, MAX_LEN // PAGE), jnp.int32),
-                 pos=_s((LFM2_B,), jnp.int32),
+                 pos=_s((LFM2_B,), jnp.int32), left=_s((LFM2_B,), jnp.int32),
                  held=_s((7, LFM2_B, 2, 2048), jnp.bfloat16),
                  owns=_s((LFM2_B,), jnp.bool_), moe=_s((4,), jnp.int32))
     keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0), LFM2_B))
@@ -731,7 +773,8 @@ def test_jamba_decode_chunk_compiles_and_moves_no_state(v5e):
     assert held["conv"].shape == (26, JAMBA_B, 3 * 5120)
     state = dict(k=pool["k"], v=pool["v"],
                  table=_s((JAMBA_B, JAMBA_LEN // PAGE), jnp.int32),
-                 pos=_s((JAMBA_B,), jnp.int32), held=held)
+                 pos=_s((JAMBA_B,), jnp.int32),
+                 left=_s((JAMBA_B,), jnp.int32), held=held)
     keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0),
                                                    JAMBA_B))
     step = kvpage.make_paged_step_fn(params, cfg, jamba, XL_CHUNK, PAGE)
