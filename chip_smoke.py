@@ -224,6 +224,17 @@ def _mismatch_share(a_outs, b_outs, prompts):
     return round(diff / max(total, 1), 4)
 
 
+def _mismatch_vs_dense(outs, ref, prompts):
+    """Share of a kernel serve's tokens that its dense twin's are not,
+    REQUIRED under a half: near-tie flips (and what follows a flip) read
+    0.0-0.15 here; a path that turns non-finite serves token 0 from then
+    on, every request's (0.93 in the latent leg before its walk masked
+    the stage's unfilled rows: PERF.md, PR 40)."""
+    share = _mismatch_share(outs, ref, prompts)
+    _require(share < 0.5, f"token_mismatch_vs_dense={share}")
+    return share
+
+
 def _logit_check(got, want, what):
     import numpy as np
     got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
@@ -395,12 +406,13 @@ def phase_serve_paged(size: Size = FULL, seed: int = 0,
              attend_pages_walked=outs.metrics.attend_pages_walked,
              attend_dead_share=round(outs.metrics.attend_dead_share, 4),
              programs_traced=traced,
-             token_mismatch_vs_dense=_mismatch_share(outs, ref, prompts),
+             token_mismatch_vs_dense=_mismatch_vs_dense(outs, ref, prompts),
              paged_operator=outs.metrics.paged_operator,
              paged_ffn=outs.metrics.paged_ffn,
              **row, **_record_row(outs.metrics, again.metrics))
     _serve_paged_lfm2(size, seed)
     _serve_paged_jamba(size, seed)
+    _serve_paged_gigachat(size, seed)
     return True
 
 
@@ -450,7 +462,7 @@ def _serve_paged_lfm2(size: Size, seed: int):
          attend_built=m.paged_decode_attend,
          attend_dead_share=round(m.attend_dead_share, 4),
          programs_traced=traced,
-         token_mismatch_vs_dense=_mismatch_share(outs, ref, prompts),
+         token_mismatch_vs_dense=_mismatch_vs_dense(outs, ref, prompts),
          **_record_row(m, again.metrics))
 
 
@@ -509,7 +521,71 @@ def _serve_paged_jamba(size: Size, seed: int):
          attend_built=m.paged_decode_attend,
          attend_dead_share=round(m.attend_dead_share, 4),
          programs_traced=traced,
-         token_mismatch_vs_dense=_mismatch_share(outs, ref, prompts),
+         token_mismatch_vs_dense=_mismatch_vs_dense(outs, ref, prompts),
+         **_record_row(m, again.metrics))
+
+
+def _serve_paged_gigachat(size: Size, seed: int):
+    """The same serve loop over a LATENT page pool (models/gigachat.py, a
+    small preset: a 512 + 64 wide row read by 4 heads, so that the chip's
+    kernels tile; 8 experts in 2 groups of which this chip holds group
+    1, beside a shared expert): the absorbed decode attend over the one
+    pool, suffix prefills that up-project hit pages, and what of the
+    routing the held share computes. Tokens against the dense
+    configuration of the same family."""
+    import jax
+
+    from mpi_acx_tpu.models import gigachat, serving
+    over = {} if size.tiny else dict(
+        vocab=512, d_model=256, q_lora_rank=128, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=192, d_ff=512,
+        moe_d_ff=256, moe_block=256)
+    cfg = gigachat.tiny_gigachat(max_seq=2 * size.max_len, experts_first=4,
+                                 experts_held=4, **over)
+    params = gigachat.cast_params(
+        gigachat.init_params(jax.random.key(seed), cfg))
+    prompts, n_new = _requests(size, cfg.vocab, seed)
+    kw = dict(n_slots=size.n_slots, max_len=size.max_len, family=gigachat,
+              chunk=size.chunk, page_tokens=size.page_tokens,
+              prefix_cache=True, max_request_retries=0)
+    with _Watch() as w:
+        outs = serving.serve_paged_greedy(params, cfg, prompts, n_new, **kw)
+    m = outs.metrics
+    tokens = _check_outputs(outs, prompts, n_new, m)
+    _require(m.prefix_hits >= 2 and m.prefix_pages_reused >= 2,
+             f"prefix_hits={m.prefix_hits}")
+    _require(0 < m.moe_pairs_held < m.moe_assignments
+             and m.moe_pairs_held == 2 * m.moe_group_hits
+             and 0 < m.moe_live_expert_share <= 1,
+             f"moe_pairs_held={m.moe_pairs_held} of {m.moe_assignments}, "
+             f"moe_group_hits={m.moe_group_hits}")
+    _require(m.kv_bytes_token == 3 * 2 * cfg.row_dim,
+             f"kv_bytes_token={m.kv_bytes_token}")
+    again = serving.serve_paged_greedy(params, cfg, prompts, n_new, **kw)
+    traced = [m.programs_traced, again.metrics.programs_traced]
+    _require(traced[0] > 0 and traced[1] == 0
+             and _mismatch_share(again, outs, prompts) == 0,
+             f"second serve call (gigachat): programs_traced={traced}")
+    ref = serving.serve_paged_greedy(params, _reference(cfg), prompts, n_new,
+                                     **kw)
+    _check_outputs(ref, prompts, n_new, ref.metrics)
+    mismatch = _mismatch_vs_dense(outs, ref, prompts)
+    emit(phase="serve_paged/gigachat", ok=True, tokens=tokens, **w.row(),
+         **_serve_stats(m), prefix_hits=m.prefix_hits,
+         prefix_pages_reused=m.prefix_pages_reused,
+         paged_operator=m.paged_operator, paged_ffn=m.paged_ffn,
+         kv_bytes_token=m.kv_bytes_token,
+         moe_pairs_routed=m.moe_assignments,
+         moe_pairs_held=m.moe_pairs_held, moe_group_hits=m.moe_group_hits,
+         moe_experts_live=m.moe_experts_live,
+         moe_live_expert_share=round(m.moe_live_expert_share, 4),
+         kv_write_path=m.paged_kv_write,
+         kv_page_rewrites_per_token=_rewrites_per_token(m),
+         attend_built=m.paged_decode_attend,
+         attend_pages_walked=m.attend_pages_walked,
+         attend_dead_share=round(m.attend_dead_share, 4),
+         programs_traced=traced,
+         token_mismatch_vs_dense=mismatch,
          **_record_row(m, again.metrics))
 
 

@@ -74,7 +74,7 @@ def grouped_decode_attend(q, kc, vc, pos, max_len, n_rep, flash=None):
     return select_decode_attend(flash)(q, kc, vc, pos, max_len, n_rep)
 
 
-def dense_decode_attend(q, kc, vc, pos, max_len, n_rep):
+def dense_decode_attend(q, kc, vc, pos, max_len, n_rep, scale=None):
     """Dense-einsum reference for :func:`grouped_decode_attend` — reads
     the whole [B, Hkv, D, max_len] cache every step (the flash kernel's
     parity ground truth; also the dispatch target below the kernel's
@@ -91,7 +91,11 @@ def dense_decode_attend(q, kc, vc, pos, max_len, n_rep):
     the factoring, the full-cache operands stay int8 end-to-end.
     Algebraically identical: sum_d q_d*(K_kd*s_k) == (sum_d q_d*K_kd)
     * s_k, and the f32 logits/probs multiply is if anything MORE
-    precise than rounding each dequantized element to bf16."""
+    precise than rounding each dequantized element to bf16.
+
+    ``scale`` replaces the scores' ``1 / sqrt(Dh)``; ``vc`` may be
+    narrower than ``kc`` (a latent cache's values are its rows' heads),
+    the result then ``[B, W, Hq * Dv]``."""
     ks = vs = None
     if isinstance(kc, tuple):
         kc, ks = kc
@@ -102,7 +106,8 @@ def dense_decode_attend(q, kc, vc, pos, max_len, n_rep):
     qg = q.reshape(B, W, Hkv, n_rep, Dh)
     # Pre-scale q by 1/sqrt(Dh) (W*Hq*Dh elements) instead of dividing
     # the [B, g, r, W, max_len] f32 logits — same trick as _flash_kernel.
-    qg = (qg.astype(jnp.float32) * (1.0 / Dh ** 0.5)).astype(q.dtype)
+    qg = (qg.astype(jnp.float32)
+          * (1.0 / Dh ** 0.5 if scale is None else scale)).astype(q.dtype)
     kin = kc if ks is None else kc.astype(q.dtype)  # int8 exact in bf16
     logits = jnp.einsum("bqgrd,bgdk->bgrqk", qg, kin).astype(jnp.float32)
     if ks is not None:
@@ -126,7 +131,7 @@ def dense_decode_attend(q, kc, vc, pos, max_len, n_rep):
     p = p.astype(q.dtype)
     vin = vc if vs is None else vc.astype(q.dtype)
     return jnp.einsum("bgrqk,bgdk->bqgrd", p, vin).reshape(
-        B, W, Hkv * n_rep * Dh)
+        B, W, Hkv * n_rep * vin.shape[2])
 
 
 def decode_layer_scan(layers, x, kc_all, vc_all, pos, qkv_fn, attend_fn,

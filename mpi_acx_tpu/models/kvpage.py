@@ -385,7 +385,8 @@ class SnapshotStore:
 @dataclasses.dataclass(frozen=True)
 class LayerKind:
     """What one layer is to the paged plane."""
-    operator: str = "attention"      # "attention" | "conv" | "mamba"
+    # "attention" | "latent_attention" (pages) | "conv" | "mamba" (state)
+    operator: str = "attention"
     ffn: str = "dense"               # "dense" | "moe"
     cache: str = "pages"             # "pages" | "state"
 
@@ -462,6 +463,16 @@ class PagedSpec:
     n_kv_heads: int
     head_dim: int
     n_rep: int = 1                       # query heads a K/V head
+    # A LATENT pool (None: K and V pools of ``head_dim``): a page layer
+    # holds ONE row of ``head_dim`` values a token (``n_kv_heads`` 1),
+    # read by all ``n_rep`` query heads, whose first ``v_dim`` values
+    # ARE the value: there is no V pool, ``qkv`` returns ``(q, row)``,
+    # ``prefill`` 's ``one`` holds ``'k'`` alone, ``suffix_prefill`` is
+    # handed ``hv`` None, and the attend's result is ``v_dim`` wide a
+    # head. ``attn_scale``: the decode attend's factor on the scores
+    # where it is not ``1 / sqrt(head_dim)``.
+    v_dim: Optional[int] = None
+    attn_scale: Optional[float] = None
     # One slot's state in ONE state layer: a tree of
     # ``jax.ShapeDtypeStruct`` (a gated short conv: one leaf; a
     # state-space scan: the conv window and the scan's matrix, each in
@@ -470,6 +481,10 @@ class PagedSpec:
     # Which whole prompt pages get a snapshot row: every n-th.
     snapshot_every: int = 1
     n_experts: int = 0                   # of a "moe" FFN's router
+    # The experts held HERE where that is a share of the router's
+    # (``(first, count)``; None: all): the routing counters then also
+    # say what of the routed pairs the share computes (_moe_tally).
+    experts_held: Optional[Tuple[int, int]] = None
     # Leaves of a "moe" FFN that ``ffn`` is handed WHOLE, still stacked
     # over the segment's repeats, with the repeat under ``lp["repeat"]``:
     # a Pallas call cannot take one repeat's slice without a copy of it
@@ -534,6 +549,10 @@ def init_page_pool(cfg, n_pages: int, page_tokens: int, n_slots: int,
     A pool IS a cache whose batch axis counts pages."""
     from mpi_acx_tpu.models.decoding import new_kv_cache
     spec = spec or paged_spec(None, cfg)
+    if spec.v_dim is not None:          # latent: the value lies in the row
+        return {"k": jnp.zeros((spec.n_page_layers, n_pages + n_slots,
+                                spec.n_kv_heads, spec.head_dim, page_tokens),
+                               cfg.dtype)}
     pool = new_kv_cache(spec.n_page_layers, n_pages + n_slots,
                         spec.n_kv_heads, spec.head_dim, page_tokens,
                         cfg.dtype, kv_int8)
@@ -548,16 +567,30 @@ _POOL_KEYS = ("k", "v", "ks", "vs")        # what the decode step carries
 # Paged decode step (any family, through its PagedSpec)
 
 
-def _moe_tally(idx, owns, n_experts: int):
+def _moe_tally(idx, owns, n_experts: int, held=None, kept=None):
     """[4] int32 of one MoE layer's routing in one step, over the slots
     that OWN a request: (token, expert) pairs routed, distinct experts
     hit, the fullest expert's pairs, and 1 (the layer-steps counted).
     An idle slot routes too and its experts are fetched: the first two
-    are a FLOOR on what the expert kernel computes and reads."""
+    are a FLOOR on what the expert kernel computes and reads.
+
+    Where the experts held here are a share of the router's (``held`` =
+    ``(first, count)``) the experts hit and the fullest are counted over
+    the HELD ones, whose weights are what this chip reads, and two more
+    follow, [6]: the pairs whose expert is held, and the owning slots'
+    tokens whose kept routing groups (``kept`` [B, groups] bool, of a
+    group-limited router) include the held experts' own."""
     hits = jnp.zeros((n_experts,), jnp.int32).at[idx].add(
         owns.astype(jnp.int32)[:, None])
-    return jnp.stack([hits.sum(), (hits > 0).sum().astype(jnp.int32),
-                      hits.max(), jnp.int32(1)])
+    if held is None:
+        return jnp.stack([hits.sum(), (hits > 0).sum().astype(jnp.int32),
+                          hits.max(), jnp.int32(1)])
+    first, count = held
+    mine = hits[first:first + count]
+    group = first * kept.shape[1] // n_experts
+    return jnp.stack([hits.sum(), (mine > 0).sum().astype(jnp.int32),
+                      mine.max(), jnp.int32(1), mine.sum(),
+                      (kept[:, group] & owns).sum().astype(jnp.int32)])
 
 
 def paged_decode_step(params, cfg, state, token, page_tokens: int,
@@ -636,33 +669,35 @@ def paged_decode_step(params, cfg, state, token, page_tokens: int,
 
     def layer(kind, lp, x, pools, rest, at):
         """``at``: the layer's index among those of its cache kind."""
-        if kind.operator == "attention":
-            q, k, v = spec.qkv(cfg, lp, x, pos)
+        if kind.cache == "pages":
+            q, *fresh = spec.qkv(cfg, lp, x, pos)   # k, v; a latent row
             if quant:
-                k, ks = kv_quant(k)
-                v, vs = kv_quant(v)
-            fresh = (k, v, ks, vs) if quant else (k, v)
+                (k, ks), (v, vs) = kv_quant(fresh[0]), kv_quant(fresh[1])
+                fresh = (k, v, ks, vs)
             if step is None:
                 pools = write(pools, fresh, at, write_page, off)
                 stage = None
             else:
                 rest = dict(rest, stage=stage_put(rest["stage"], fresh, at,
-                                                  step))
+                                                  step, spec.v_dim))
                 stage = rest["stage"], step
-            kp, vp = pools[:2]
+            kp, vp = pools[0], pools[1] if spec.v_dim is None else None
             if quant:
                 kp, vp = (kp, pools[2]), (vp, pools[3])
             o = attend(q, kp, vp, table, pos, page_tokens, spec.n_rep,
-                       layer=at, stage=stage, left=state.get("left"))
+                       layer=at, stage=stage, left=state.get("left"),
+                       v_dim=spec.v_dim, scale=spec.attn_scale)
             x = spec.attn_out(cfg, lp, x, o)
         else:
             x, held = spec.state_op(cfg, lp, x, rest["held"], at)
             rest = dict(rest, held=held)
         if kind.ffn == "moe":
-            x, idx = spec.ffn(cfg, lp, x, kind.ffn)
+            # (idx, and the routing groups a group-limited router kept)
+            x, *routed = spec.ffn(cfg, lp, x, kind.ffn)
             if "moe" in rest:
                 rest = dict(rest, moe=rest["moe"] + _moe_tally(
-                    idx, state["owns"], spec.n_experts))
+                    routed[0], state["owns"], spec.n_experts,
+                    spec.experts_held, *routed[1:]))
         else:
             x = spec.ffn(cfg, lp, x, kind.ffn)
         return x, pools, rest
@@ -771,10 +806,12 @@ def paged_decode_chunk(params, state, tok, keys, *, cfg, chunk,
                                               select_paged_kv_write,
                                               stage_tokens)
     note_trace()
+    spec = paged_spec(family, cfg)
     pool_keys = tuple(k for k in _POOL_KEYS if k in state)
     table, pos0 = state["table"], state["pos"]
     state = dict(state, stage=(
-        new_kv_stage([state[k] for k in pool_keys], table.shape[0], chunk),
+        new_kv_stage([state[k] for k in pool_keys], table.shape[0], chunk,
+                     spec.v_dim),
         jnp.int32(0)))
 
     def one(carry, _):
@@ -790,8 +827,9 @@ def paged_decode_chunk(params, state, tok, keys, *, cfg, chunk,
     write = select_paged_kv_write(cfg.decode_flash, page_tokens)
 
     def flush(pools, layer):
-        return paged_kv_write_runs(write, pools, stage_tokens(stage, layer),
-                                   layer, table, pos0, page_tokens), None
+        return paged_kv_write_runs(
+            write, pools, stage_tokens(stage, layer, spec.v_dim), layer,
+            table, pos0, page_tokens), None
     pools, _ = lax.scan(flush, tuple(state[k] for k in pool_keys),
                         jnp.arange(stage[0].shape[0]))
     return dict(state, **dict(zip(pool_keys, pools))), toks, keys
@@ -957,7 +995,8 @@ class PagedKV:
         if self.spec.n_experts:
             # A slot owns a request exactly while it holds pages.
             state["owns"] = jnp.asarray([bool(p) for p in self.pages])
-            state["moe"] = jnp.zeros((4,), jnp.int32)
+            state["moe"] = jnp.zeros(
+                (4 if self.spec.experts_held is None else 6,), jnp.int32)
         return state
 
     def absorb(self, state) -> None:
@@ -1157,9 +1196,11 @@ class PagedKV:
     def gather_history(self, pages: List[int]):
         """Gather ``pages`` into contiguous [L, H, Dh, n*pt] history
         K/V (cache layout) in compute dtype (dequantizing int8 pages —
-        the only page-resident form — through their f32 scales)."""
+        the only page-resident form — through their f32 scales); of a
+        latent pool ``(rows [L, 1, D, n*pt], None)``."""
         return _gather(self.pool, jnp.asarray(pages, jnp.int32),
-                       dtype=self.cfg.dtype)
+                       dtype=self.cfg.dtype,
+                       latent=self.spec.v_dim is not None)
 
     def restore_tail(self, page: int):
         """The snapshot at the end of ``page`` (``[L_state, *leaf]`` a
@@ -1238,20 +1279,22 @@ def _seat_state(held, state, b):
                                                      b, 1), held, state)
 
 
-@partial(jax.jit, static_argnames="dtype")
-def _gather(pool, pages_arr, *, dtype):
+@partial(jax.jit, static_argnames=("dtype", "latent"))
+def _gather(pool, pages_arr, *, dtype, latent=False):
     note_trace()
 
     def grab(key):
         return jnp.take(pool[key], pages_arr, axis=1)
-    k, v = grab("k"), grab("v")
-    if "ks" in pool:
-        k = k.astype(jnp.float32) * grab("ks")
-        v = v.astype(jnp.float32) * grab("vs")
 
     def join(t):          # [L, n, H, Dh, pt] -> [L, H, Dh, n*pt]
         t = jnp.moveaxis(t, 1, 3)
         return t.reshape(t.shape[:3] + (-1,)).astype(dtype)
+    if latent:            # a latent pool: its rows; no V to gather
+        return join(grab("k")), None
+    k, v = grab("k"), grab("v")
+    if "ks" in pool:
+        k = k.astype(jnp.float32) * grab("ks")
+        v = v.astype(jnp.float32) * grab("vs")
     return join(k), join(v)
 
 

@@ -344,6 +344,37 @@ def route_sigmoid_topk(x, gate, bias, top_k: int, scale: float = 1.0,
     return idx.astype(jnp.int32), p * scale
 
 
+def route_sigmoid_group_topk(x, gate, bias, top_k: int, n_group: int,
+                             topk_group: int, scale: float = 1.0,
+                             normalise: bool = True):
+    """The ``deepseek_v3`` router (``topk_method`` ``noaux_tc``), in
+    f32: ``s = sigmoid(x @ gate)`` [T, E]; ``s' = s + bias`` (the bias
+    only selects); the experts lie in ``n_group`` groups of consecutive
+    ones, a group's score is the sum of its two largest ``s'``, the
+    ``topk_group`` best groups are KEPT and the ``top_k`` largest ``s'``
+    inside them are selected (of equal scores the lower index wins, for
+    groups and experts alike: ``lax.top_k``'s rule); the weights are
+    ``s`` of the selected, divided by ``sum + 1e-20`` when
+    ``normalise``, times ``scale``. Returns (idx [T, k] int32, p [T, k]
+    f32, kept [T, n_group] bool). HF fills the dropped groups' scores
+    with 0.0 where this takes them out (-inf): the same choice unless a
+    kept group's ``top_k``-th best ``s'`` is negative."""
+    T = x.shape[0]
+    s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
+                               gate.astype(jnp.float32),
+                               precision=lax.Precision.HIGHEST))
+    sb = (s + bias.astype(jnp.float32)).reshape(T, n_group, -1)
+    _, best = lax.top_k(lax.top_k(sb, 2)[0].sum(-1), topk_group)
+    kept = jnp.zeros((T, n_group), bool).at[
+        jnp.arange(T)[:, None], best].set(True)
+    _, idx = lax.top_k(jnp.where(kept[:, :, None], sb, -jnp.inf).reshape(
+        T, -1), top_k)
+    p = jnp.take_along_axis(s, idx, axis=-1)
+    if normalise:
+        p = p / (jnp.sum(p, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), p * scale, kept
+
+
 def ragged_dot_matmul(xs, w, sizes):
     """Rows ``xs`` [M, a], sorted by group, times each group's own
     matrix ``w`` [G, a, b]; ``sizes`` [G] rows a group, rows past their

@@ -136,6 +136,16 @@ class ServingMetrics:
     moe_load_max: int = 0
     moe_layer_steps: int = 0
     moe_experts: int = 0
+    # Where the experts held here are a share of the router's
+    # (PagedSpec.experts_held): ``moe_experts_live`` / ``moe_load_max``
+    # count over the HELD experts, ``moe_pairs_held`` the routed pairs
+    # whose expert is held (``moe_assignments``: all of them), and
+    # ``moe_group_hits`` the owning slots' tokens whose kept routing
+    # groups include the held experts' own; ``moe_experts_held``: how
+    # many are held (0: every one).
+    moe_pairs_held: int = 0
+    moe_group_hits: int = 0
+    moe_experts_held: int = 0
     # (pairs, experts hit, fullest, layer-steps) of each decode chunk
     moe_by_chunk: List[tuple] = field(default_factory=list)
     # paged: snapshots of the state layers loaded to continue a sequence
@@ -146,6 +156,10 @@ class ServingMetrics:
     # held at once, rows taken from the least recently used page.
     conv_tail_restores: int = 0
     state_bytes_slot: int = 0
+    # paged: bytes a token keeps in the page pools over all the layers
+    # with pages (from the pools' shapes: K and V of every K/V head and
+    # an int8 cache's scales, or a latent pool's one row)
+    kv_bytes_token: int = 0
     state_snapshots_taken: int = 0
     state_snapshot_rows_hwm: int = 0
     state_snapshot_evictions: int = 0
@@ -203,7 +217,8 @@ class ServingMetrics:
         expert an owning slot routed to: how much of the expert weights
         a step has to read."""
         return (self.moe_experts_live
-                / (self.moe_experts * self.moe_layer_steps)
+                / ((self.moe_experts_held or self.moe_experts)
+                   * self.moe_layer_steps)
                 if self.moe_layer_steps else 0.0)
 
     @property
@@ -1680,9 +1695,14 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
             paged_operator=pkv.spec.built("operator"),
             paged_ffn=pkv.spec.built("ffn"),
             **dict(zip(("moe_assignments", "moe_experts_live",
-                        "moe_load_max", "moe_layer_steps"),
+                        "moe_load_max", "moe_layer_steps",
+                        "moe_pairs_held", "moe_group_hits"),
                        map(sum, zip(*pkv.moe_chunks)))),
             moe_experts=pkv.spec.n_experts,
+            moe_experts_held=(pkv.spec.experts_held or (0, 0))[1],
+            kv_bytes_token=sum(
+                p.shape[0] * p.shape[2] * p.shape[3] * p.dtype.itemsize
+                for p in pkv.pool.values()),
             moe_by_chunk=list(pkv.moe_chunks),
             conv_tail_restores=pkv.tail_restores,
             state_bytes_slot=pkv.spec.state_bytes_slot,

@@ -728,3 +728,85 @@ def flash_attention_lse(q, k, v, causal: bool = True, block_q: int = 512,
     o, lse = _flash_lse(to_bhsd(q), to_bhsd(k), to_bhsd(v), causal,
                         block_q, block_k)
     return jnp.transpose(o, (0, 2, 1, 3)), lse           # [B, S, H, D]
+
+
+# --------------------------------------------------------------------------
+# Rows of a longer sequence against all of its keys (appended: the lines
+# above, which the kernels' cache keys hold, stay where they were)
+
+
+def _flash_rows_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
+                       block_q, block_k, scale, q_offset):
+    """:func:`_flash_stream_kernel` for queries that are rows
+    ``q_offset ..`` of the keys' sequence: grid step ``(head, q block,
+    K block)``, causal on ABSOLUTE positions (row ``q_offset + r`` sees
+    columns ``<= q_offset + r``), K blocks wholly above a q block's
+    last row skipped, only those that straddle its diagonal masked."""
+    i, j = pl.program_id(1), pl.program_id(2)
+    row0 = q_offset + i * block_q
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    def fold(masked):
+        q = (q_ref[0].astype(jnp.float32) * scale).astype(q_ref.dtype)
+        m_scr[:], l_scr[:], acc_scr[:] = _online_softmax_step(
+            q, k_ref[0], v_ref[0], m_scr[:], l_scr[:], acc_scr[:], row0,
+            j * block_k, masked, jax.lax.Precision.DEFAULT)
+
+    below = (j + 1) * block_k - 1 <= row0       # every column seen by all
+    pl.when(below)(lambda: fold(False))
+    pl.when(jnp.logical_not(below)
+            & (j * block_k <= row0 + block_q - 1))(lambda: fold(True))
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _finish():
+        o_ref[0] = (acc_scr[:] / l_scr[:]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("q_offset", "scale", "block_q",
+                                             "block_k"))
+def flash_rows_attention(q, k, v, q_offset: int = 0, scale=None,
+                         block_q: int = 512, block_k: int = 512):
+    """Causal attention of the rows ``q_offset .. q_offset + S - 1`` of a
+    sequence against ALL of its keys: q ``[H, S, D]``, k ``[H, Sk, D]``,
+    v ``[H, Sk, Dv]`` (heads first, one sequence; ``Sk >= q_offset +
+    S``; key columns past a row's own position, padding among them, are
+    masked) -> ``[H, S, Dv]``. A whole-sequence prefill is ``q_offset``
+    0 with ``S == Sk``; a prefill behind cached history is the suffix's
+    rows against history + suffix. K/V stream a ``[block_k, D]`` tile a
+    grid step (no whole-head residency: a head of 8192 x 192 does not
+    fit beside its double buffer), so the length is bounded by HBM.
+    ``scale``: the scores' factor, ``1 / sqrt(D)`` unless given. S and
+    Sk must divide into the blocks (multiples of 128 on the chip, of 8
+    in interpret mode): the caller pads."""
+    H, S, D = q.shape
+    Sk, Dv = k.shape[1], v.shape[2]
+    fit = _fit_blocks(S, block_q, block_q), _fit_blocks(Sk, block_k, block_k)
+    assert fit[0] and fit[1] and Sk >= q_offset + S, (S, Sk, q_offset)
+    block_q, block_k = fit[0][0], fit[1][1]
+    kernel = functools.partial(
+        _flash_rows_kernel, block_q=block_q, block_k=block_k,
+        scale=1.0 / D ** 0.5 if scale is None else scale, q_offset=q_offset)
+    return pl.pallas_call(
+        kernel,
+        grid=(H, S // block_q, Sk // block_k),
+        in_specs=[
+            pl.BlockSpec((1, block_q, D), lambda h, i, j: (h, i, 0)),
+            pl.BlockSpec((1, block_k, D), lambda h, i, j: (h, j, 0)),
+            pl.BlockSpec((1, block_k, Dv), lambda h, i, j: (h, j, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, block_q, Dv), lambda h, i, j: (h, i, 0)),
+        out_shape=_out_struct((H, S, Dv), q.dtype, q, k, v),
+        scratch_shapes=[pltpu.VMEM((block_q, 1), jnp.float32),
+                        pltpu.VMEM((block_q, 1), jnp.float32),
+                        pltpu.VMEM((block_q, Dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=not backend.on_tpu(),
+        name="flash_rows_attention",    # what the device trace prints
+    )(q, k, v)
+

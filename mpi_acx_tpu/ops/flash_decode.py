@@ -217,7 +217,7 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, *refs, block_k, n_rep,
 
 def _paged_walk_kernel(pos_ref, table_ref, layer_ref, step_ref, nxt_ref,
                        held_ref, q_ref, *refs, page_tokens, n_rep, n_k,
-                       n_pools, n_stage, scale):
+                       n_pools, n_stage, scale, v_dim=None):
     """The paged walk: one grid step a slot, and inside it one pass
     over the slot's LIVE pages and nothing per dead page. The pools
     stay in HBM (``refs[:n_pools]``: K, V, and the scale pools of an
@@ -257,7 +257,15 @@ def _paged_walk_kernel(pos_ref, table_ref, layer_ref, step_ref, nxt_ref,
     whole, and what a masked block leaves in the fold's state the next
     live column wipes, the weight ``exp(-1e30 - m)`` being 0.0), and
     the stage's tokens ``0..step`` are one more block of the same
-    fold."""
+    fold.
+
+    ``v_dim``: a LATENT pool (one row a token, ``[1, D, page_tokens]`` a
+    page, read by all ``n_rep`` query heads; no V pool): the value is
+    the first ``v_dim`` features of the key's row, so ``P @ V`` runs
+    against the leading sublanes of the SAME block that the scores were
+    taken from, and the result is ``v_dim`` wide. Its stage block is
+    ``[chunk, D]``, the rows as the pages hold them, met by ``q``
+    itself."""
     del held_ref                                # the index maps' alone
     pools, refs = refs[:n_pools], refs[n_pools:]
     staged, (o_ref, *refs) = refs[:n_stage], refs[n_stage:]
@@ -305,15 +313,30 @@ def _paged_walk_kernel(pos_ref, table_ref, layer_ref, step_ref, nxt_ref,
 
             for c in copies(b, j, buf):
                 c.wait()
-            kb, vb, *scales = [dst[buf] for dst in bufs]
+            kb, *rest = [dst[buf] for dst in bufs]
+            if v_dim is not None:
+                rest = [kb[:, :v_dim]]
+            vb, *scales = rest
             _fold_block(q_ref, kb, vb, scales, j * page_tokens, pos, m_scr,
                         l_scr, acc_scr, n_rep=n_rep, scale=scale, stop=pos0)
 
         jax.lax.fori_loop(0, n, page, None)
         walked[0] = first + n
         if staged:
-            q_wide, vk, *scales = staged
-            vk = jnp.swapaxes(vk[...], 0, 1)    # [chunk, H, 2 D]: heads first
+            if v_dim is None:
+                q_wide, vk, *scales = staged
+                vk = jnp.swapaxes(vk[...], 0, 1)    # [chunk, H, 2 D]
+            else:               # the one head's rows, met by q itself
+                q_wide, vk, scales = q_ref, staged[0][...][None], []
+                # Rows the chunk has not filled yet count as zeros
+                # whatever they hold: their columns weigh 0.0, and 0.0
+                # times a non-finite value is NaN, which the flush would
+                # carry into pages a later request inherits. (They ARE
+                # zeros as long as the compiled chunk fills its stage:
+                # see stage_put. This costs nothing that a trace shows.)
+                filled = jax.lax.broadcasted_iota(
+                    jnp.int32, vk.shape, 1) <= step_ref[0]
+                vk = jnp.where(filled, vk, jnp.zeros_like(vk))
             _fold_block(q_wide, vk, vk, [s[...] for s in scales], pos0,
                         pos, m_scr, l_scr, acc_scr, n_rep=n_rep, scale=scale,
                         tokens_minor=False)
@@ -348,7 +371,7 @@ def _live_slots(left, step, B):
 
 
 def _decode_call(q, k, v, pos, n_rep, block_k, n_k, table=None, layer=None,
-                 stage=None, left=None):
+                 stage=None, left=None, v_dim=None, scale=None):
     """The pallas_call behind both kernels. ``k``/``v`` are K/V
     arrays or (codes, scales) tuples in cache layout
     ([B, Hkv, *, max_len]) or, with ``table``, pool layout: the whole
@@ -361,7 +384,12 @@ def _decode_call(q, k, v, pos, n_rep, block_k, n_k, table=None, layer=None,
     ``(arrays, step)``, a chunk's stage (:func:`new_kv_stage`) and the
     chunk step it is filled up to; ``left`` (paged only): the tokens
     each slot still owes, of which the walk reads which slots are dead
-    at this step (:func:`_live_slots`)."""
+    at this step (:func:`_live_slots`). ``v_dim`` (paged only, with
+    ``v`` None): ``k`` is a latent pool whose rows' first ``v_dim``
+    features are the values (:func:`_paged_walk_kernel`); ``scale``:
+    the scores' factor where it is not ``1 / sqrt(D)``."""
+    assert (v is None) == (v_dim is not None) and (v is not None
+                                                   or table is not None)
     ks = vs = None
     if isinstance(k, tuple):
         k, ks = k
@@ -390,12 +418,16 @@ def _decode_call(q, k, v, pos, n_rep, block_k, n_k, table=None, layer=None,
     # so the kernel recovers the window slot as i // n_rep.
     qg = q.reshape(B, W, Hkv, n_rep, D).transpose(0, 2, 1, 3, 4).reshape(
         B, Hkv, Wn, D)
+    Dv = D if v_dim is None else v_dim
     q_spec = pl.BlockSpec((1, Hkv, Wn, D), lambda b, *_: (b, 0, 0, 0))
+    o_spec = pl.BlockSpec((1, Hkv, Wn, Dv), lambda b, *_: (b, 0, 0, 0))
     fold_state = [pltpu.VMEM((Hkv, Wn, 1), jnp.float32),
                   pltpu.VMEM((Hkv, Wn, 1), jnp.float32),
-                  pltpu.VMEM((Hkv, Wn, D), jnp.float32)]
-    operands = [qg, k, v] + ([ks, vs] if quant else [])
-    static = dict(n_rep=n_rep, n_k=n_k, scale=1.0 / D ** 0.5)
+                  pltpu.VMEM((Hkv, Wn, Dv), jnp.float32)]
+    operands = ([qg, k] + ([v] if v is not None else [])
+                + ([ks, vs] if quant else []))
+    static = dict(n_rep=n_rep, n_k=n_k,
+                  scale=1.0 / D ** 0.5 if scale is None else scale)
 
     if table is None:
         def kv_spec(rows):
@@ -422,10 +454,13 @@ def _decode_call(q, k, v, pos, n_rep, block_k, n_k, table=None, layer=None,
         staged, step = [], 0
         if stage is not None:
             arrays, step = stage
-            # q under K's lanes of a stage row, zeros under V's; then
-            # the stage, one layer of it being a stage of one layer
-            staged = [jnp.pad(qg, [(0, 0)] * 3 + [(D, 0)])] + [
-                a if a.ndim == 5 else a[None] for a in arrays]
+            # the stage, one layer of it being a stage of one layer; in
+            # front of it q under K's lanes of a stage row, zeros under
+            # V's (a latent stage's rows are met by q itself)
+            whole = 5 if v_dim is None else 4
+            staged = [a if a.ndim == whole else a[None] for a in arrays]
+            if v_dim is None:
+                staged.insert(0, jnp.pad(qg, [(0, 0)] * 3 + [(D, 0)]))
             operands += staged
         prefetch = [pos, jnp.asarray(table, jnp.int32),
                     jnp.asarray(layer, jnp.int32).reshape(1),
@@ -433,7 +468,7 @@ def _decode_call(q, k, v, pos, n_rep, block_k, n_k, table=None, layer=None,
                     *_live_slots(left, step, B)]
         kernel = functools.partial(_paged_walk_kernel, page_tokens=block_k,
                                    n_pools=len(pools), n_stage=len(staged),
-                                   **static)
+                                   v_dim=v_dim, **static)
         grid, semantics = (B,), ("arbitrary",)
 
         def q_held(width):
@@ -445,11 +480,11 @@ def _decode_call(q, k, v, pos, n_rep, block_k, n_k, table=None, layer=None,
         in_specs = ([q_held(D)]
                     + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools))
         if staged:
-            in_specs += [q_held(2 * D)] + [
+            in_specs += [q_held(2 * D)] * (v_dim is None) + [
                 pl.BlockSpec((None, None) + a.shape[2:],
-                             lambda b, _, __, layer_ref, *refs: (
-                                 layer_ref[0], refs[-1][b], 0, 0, 0))
-                for a in staged[1:]]
+                             lambda b, _, __, layer_ref, *refs, n=a.ndim - 2:
+                             (layer_ref[0], refs[-1][b]) + (0,) * n)
+                for a in staged[v_dim is None:]]
         scratch = ([pltpu.VMEM((2,) + p.shape[2:], p.dtype) for p in pools]
                    + [pltpu.SemaphoreType.DMA((len(pools), 2))]
                    + fold_state + [pltpu.SMEM((1,), jnp.int32)])
@@ -458,15 +493,16 @@ def _decode_call(q, k, v, pos, n_rep, block_k, n_k, table=None, layer=None,
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch), grid=grid,
-            in_specs=in_specs, out_specs=q_spec, scratch_shapes=scratch),
-        out_shape=_out_struct((B, Hkv, Wn, D), q.dtype, q, k, v),
+            in_specs=in_specs, out_specs=o_spec, scratch_shapes=scratch),
+        out_shape=_out_struct((B, Hkv, Wn, Dv), q.dtype, q, k,
+                              *(() if v is None else (v,))),
         compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         interpret=interpret,
         # What the device trace prints the kernel as, by variant.
         name=("paged_" if table is not None else "") + "flash_decode_attend",
     )(*prefetch, *operands)
-    return out.reshape(B, Hkv, W, n_rep, D).transpose(0, 2, 1, 3, 4).reshape(
-        B, W, Hq * D)
+    return out.reshape(B, Hkv, W, n_rep, Dv).transpose(
+        0, 2, 1, 3, 4).reshape(B, W, Hq * Dv)
 
 
 def flash_decode_attend(q, kc, vc, pos, max_len, n_rep, block_k: int = 256):
@@ -488,7 +524,7 @@ def _layer_of(pool, layer):
         p, layer, 0, keepdims=False), pool)
 
 
-def new_kv_stage(pools, n_slots, chunk):
+def new_kv_stage(pools, n_slots, chunk, v_dim=None):
     """A decode chunk's stage, zeroed: the chunk's own tokens of every
     layer with pages, ``(layer, slot)`` a block. K and V (or their
     codes) lie in ONE array ``[L, B, chunk, H, 2 D]``: a token of a slot
@@ -505,19 +541,44 @@ def new_kv_stage(pools, n_slots, chunk):
     whose last dimension is 32 is laid out with 128 lanes in HBM, 4x;
     this one fills its 128 at a head of 64; the heads pad to a multiple
     of 16 rows). Behind it, for an int8 cache, K's and V's scales as in
-    a page, ``[L, B, H, 1, chunk]`` each."""
+    a page, ``[L, B, H, 1, chunk]`` each.
+
+    A LATENT pool (``v_dim``: ``pools`` is the one pool ``[L, P, 1, D,
+    pt]``, a row a token, its value the row's head: as every stage
+    helper is told which layout it has) stages the rows as they are,
+    ``[L, B, chunk, D]``: one head has no second major dimension to
+    swap, and a row is met by ``q`` unwidened."""
     (L, _, H, D, _), kv = pools[0].shape, pools[0].dtype
+    if v_dim is not None:
+        return (jnp.zeros((L, n_slots, chunk, D), kv),)
     return (jnp.zeros((L, n_slots, chunk, H, 2 * D), kv),) + tuple(
         jnp.zeros((L, n_slots, H, 1, chunk), p.dtype) for p in pools[2:])
 
 
-def stage_put(stage, fresh, layer, step):
+def stage_put(stage, fresh, layer, step, v_dim=None):
     """``stage`` (:func:`new_kv_stage`) with every slot's ``fresh``
     token (``[B, 1, H, *]`` a pool, as the write takes it: K, V, and
     their scales) as token ``step`` of layer ``layer``: XLA's update in
-    place, one token of every slot's block (one lane of the scales')."""
-    k, v, *scales = fresh
+    place, one token of every slot's block (one lane of the scales').
+    A latent pool's ``fresh`` is its one row a slot ``[B, 1, 1, D]``."""
     zero = jnp.int32(0)
+    if v_dim is not None:
+        # The layer's block rewritten whole with row ``step`` selected
+        # in, NOT an update of one row: a one-row update (one SUBLANE
+        # row of packed bf16 tiles) lets XLA's TPU compiler see a loop
+        # that overwrites every row, and it then drops the stage's zero
+        # fill (``AllocateBuffer``: uninitialised memory) without
+        # counting the attend's reads of rows not yet written inside
+        # that loop. Reading the block keeps the fill
+        # (tests/test_tpu_compile.py::_unfilled_stage holds every
+        # family's chunk to it; PERF.md, PR 40). 2.4 MB a layer-step at
+        # 64 slots, about what the one-row update's tiles cost.
+        slab = jax.lax.dynamic_index_in_dim(stage[0], layer, 0)
+        row = fresh[0][None, :, :, 0].astype(slab.dtype)    # [1, B, 1, D]
+        at = jax.lax.broadcasted_iota(jnp.int32, slab.shape, 2) == step
+        return (jax.lax.dynamic_update_slice(
+            stage[0], jnp.where(at, row, slab), (layer, zero, zero, zero)),)
+    k, v, *scales = fresh
 
     def put(into, row, at):
         return jax.lax.dynamic_update_slice(
@@ -528,17 +589,21 @@ def stage_put(stage, fresh, layer, step):
               for s, f in zip(stage[1:], scales)))
 
 
-def stage_tokens(stage, layer=None):
+def stage_tokens(stage, layer=None, v_dim=None):
     """Layer ``layer`` of a stage (or the one layer it is) as the write
-    takes tokens: K, V, and their scales, ``[B, chunk, H, *]`` each."""
+    takes tokens: K, V, and their scales, ``[B, chunk, H, *]`` each (a
+    latent pool's stage: its rows, ``[B, chunk, 1, D]``)."""
     vk, *scales = _layer_of(stage, layer)
+    if v_dim is not None:
+        return (vk[:, :, None],)
     D = vk.shape[-1] // 2
     return (vk[..., D:], vk[..., :D],
             *(a.transpose(0, 3, 1, 2) for a in scales))
 
 
 def paged_gather_attend(q, kp, vp, table, pos, page_tokens, n_rep,
-                        layer=None, stage=None, left=None):
+                        layer=None, stage=None, left=None, v_dim=None,
+                        scale=None):
     """Dense reference for paged attention: gather each slot's pages
     into the contiguous ``[B, Hkv, D, max_len]`` layout the fixed-slot
     path attends and call :func:`dense_decode_attend` — identical
@@ -557,7 +622,9 @@ def paged_gather_attend(q, kp, vp, table, pos, page_tokens, n_rep,
     (``[B]``: the tokens each slot still owes at the chunk's start) the
     row of a slot that is dead at this step (``step >= left[b]``; step 0
     without a stage) is zeros, as the kernel's is: the two agree on
-    every row."""
+    every row. A latent pool (``vp`` None, ``v_dim``, ``scale``: as the
+    kernel takes them) is gathered once and its rows' first ``v_dim``
+    features are the values."""
     from mpi_acx_tpu.models.decoding import dense_decode_attend
 
     B, max_pages = table.shape
@@ -573,23 +640,27 @@ def paged_gather_attend(q, kp, vp, table, pos, page_tokens, n_rep,
     if stage is not None:
         staged, step = stage
         quant = isinstance(kl, tuple)
-        pools = (kl[0], vl[0], kl[1], vl[1]) if quant else (kl, vl)
+        pools = ((kl[0], vl[0], kl[1], vl[1]) if quant
+                 else (kl, vl) if v_dim is None else (kl,))
         pos0 = jnp.broadcast_to(jnp.asarray(pos, jnp.int32) - step, (B,))
         pools = paged_kv_write_runs(
             paged_kv_write_dense, [p[None] for p in pools],
-            stage_tokens(staged, layer), 0, table, pos0, page_tokens,
+            stage_tokens(staged, layer, v_dim), 0, table, pos0, page_tokens,
             n_live=step + 1)
-        k, v, *scales = (p[0] for p in pools)
+        k, *rest = (p[0] for p in pools)
+        v, *scales = rest if v_dim is None else [None]      # (no V pool)
         kl, vl = ((k, scales[0]), (v, scales[1])) if quant else (k, v)
-    kin, vin = jax.tree.map(gather, kl), jax.tree.map(gather, vl)
-    out = dense_decode_attend(q, kin, vin, pos, max_len, n_rep)
+    kin = jax.tree.map(gather, kl)
+    vin = jax.tree.map(gather, vl) if v_dim is None else kin[:, :, :v_dim]
+    out = dense_decode_attend(q, kin, vin, pos, max_len, n_rep, scale=scale)
     if left is None:
         return out
     return jnp.where((step < jnp.asarray(left))[:, None, None], out, 0)
 
 
 def paged_flash_decode_attend(q, kp, vp, table, pos, page_tokens, n_rep,
-                              layer=None, stage=None, left=None):
+                              layer=None, stage=None, left=None, v_dim=None,
+                              scale=None):
     """Pallas paged decode attention: K/V pools ``[P, Hkv, D,
     page_tokens]`` (plus (codes, scales) tuples for int8 pools) addressed through
     a ``[B, max_pages]`` block table — or, with ``layer``, the whole
@@ -607,10 +678,15 @@ def paged_flash_decode_attend(q, kp, vp, table, pos, page_tokens, n_rep,
     (``[B]``: the tokens each slot still owes at the chunk's start) a
     slot that can deliver nothing at this step (``step >= left[b]``)
     costs no page copy, no fold and no stage block, and its row is
-    zeros; live rows are what they are without ``left``, bit for bit."""
+    zeros; live rows are what they are without ``left``, bit for bit.
+    With ``vp`` None and ``v_dim`` the pool is a LATENT one (``[L, P,
+    1, D, page_tokens]``, a row a token read by all ``n_rep`` heads, no
+    V pool): the values are the first ``v_dim`` features of the rows,
+    the result ``[B, W, Hq * v_dim]``; ``scale`` replaces the scores'
+    ``1 / sqrt(D)``."""
     return _decode_call(q, kp, vp, pos, n_rep, page_tokens,
                         table.shape[1], table=table, layer=layer,
-                        stage=stage, left=left)
+                        stage=stage, left=left, v_dim=v_dim, scale=scale)
 
 
 def _paged_kernels_fit(page_tokens):
@@ -628,7 +704,7 @@ def select_paged_decode_attend(decode_flash, page_tokens):
     bit-equality anchor); ``True`` -> the kernel (interpret mode
     off-TPU); ``False`` -> the reference. Both take ``(q, kp, vp,
     table, pos, page_tokens, n_rep, layer=None, stage=None,
-    left=None)``; the chosen
+    left=None, v_dim=None, scale=None)``; the chosen
     function's ``__name__`` is what
     ``ServingMetrics.paged_decode_attend`` records
     (``paged_flash_decode_attend`` is the live-page walk, all or

@@ -469,6 +469,27 @@ def _loop_depths(jaxpr, depth=0, found=None):
     return found
 
 
+def _unfilled_stage(text, pools, n_slots, v_dim=None):
+    """Lines of a compiled chunk that make its stage WITHOUT filling it.
+
+    XLA's TPU compiler turns a zero fill into an ``AllocateBuffer``
+    (uninitialised memory) where it sees a loop overwrite every element
+    of the buffer, and it does not count a Pallas call's reads inside
+    that loop: the walk's fold reads the rows of the stage that the
+    chunk has not reached yet behind a weight of exactly 0.0, and 0.0
+    times whatever the memory held before is NaN where that is not
+    finite. PR 40's latent stage, updated ONE sublane row a step, lost
+    its zeros that way (chip: every token 0 at the smoke's size); it is
+    now rewritten a layer at a time (``flash_decode.stage_put``), which
+    reads it, and keeps them."""
+    made = jax.eval_shape(lambda: flash_decode.new_kv_stage(
+        list(pools), n_slots, XL_CHUNK, v_dim))
+    shapes = ["[" + ",".join(map(str, a.shape)) + "]" for a in made]
+    return [l.strip()[:160] for l in text.splitlines()
+            if "AllocateBuffer" in l
+            and any(s in l.split(" custom-call(")[0] for s in shapes)]
+
+
 # What PERF.md states for the cell's stage: 48 layers x 32 slots x 32
 # tokens x 25 heads x (V then K: 2 x 64 = the 128 lanes), bf16, as
 # counted; as the chip lays it out the 25 heads pad to 32 rows.
@@ -507,6 +528,7 @@ def test_paged_decode_chunk_moves_no_pool(kind, v5e):
     assert result.startswith(f"bf16[{XL_B},{XL_H},1,{D}]{{"), result
     assert not _pool_movers(text, pools[0].shape), \
         "\n".join(_pool_movers(text, pools[0].shape))
+    assert not _unfilled_stage(text, pools, XL_B)
     jaxpr = jax.make_jaxpr(lambda *a: chunk.__wrapped__(*a, **kw))(
         *args).jaxpr
     grids = _pallas_grids(jaxpr)
@@ -630,6 +652,7 @@ def test_lfm2_decode_chunk_compiles_and_moves_no_pool(v5e):
                                                    "f32[256,2048]"}
     assert not _pool_movers(text, pool["k"].shape), \
         "\n".join(_pool_movers(text, pool["k"].shape))
+    assert not _unfilled_stage(text, [pool["k"], pool["v"]], LFM2_B)
     _flush_is_behind_the_steps(step, state, LFM2_B, keys)
     # nor one that moves a layer's experts: the stacks [2, 64, ...] go to
     # the grouped matmul whole (moe.sorted_expert_ffn, ``layer``); a
@@ -791,6 +814,7 @@ def test_jamba_decode_chunk_compiles_and_moves_no_state(v5e):
     # 13 Mamba layers a scan body, each its own call on the whole stack
     assert len(re.findall(r"%ssm_update[.0-9]* = ", text)) == 13
     assert not _pool_movers(text, pool["k"].shape)
+    assert not _unfilled_stage(text, [pool["k"], pool["v"]], JAMBA_B)
     _flush_is_behind_the_steps(step, state, JAMBA_B, keys)
     assert not _state_movers(text), "\n".join(_state_movers(text))
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
@@ -850,3 +874,109 @@ def test_the_chunk_stages_every_family_alike(family, monkeypatch):
         params, state, tok)
     assert staged not in [v.aval.shape for e in one.jaxpr.eqns
                           for v in e.outvars]
+
+
+# -- a latent page pool read by 64 heads (PR 40) ------------------------------
+
+GIGA_B, GIGA_PAGES, GIGA_LEN = 64, 2560 + 64, 8576         # + parking pages
+
+
+def _gigachat_cut():
+    """The benchmark's cut of GigaChat3.1-702B-A36B at published widths
+    (one dense layer + five expert layers, 16 of 256 experts held, 1/8
+    of the vocabulary), bf16 weights. Shapes only."""
+    from mpi_acx_tpu.models import gigachat
+    cfg = gigachat.GigaChatConfig(vocab=16032, n_layers=6, first_k_dense=1,
+                                  experts_held=16)
+    params = jax.eval_shape(lambda: gigachat.cast_params(
+        gigachat.init_params(jax.random.key(0), cfg)))
+    return gigachat, cfg, params
+
+
+def test_gigachat_decode_chunk_compiles_and_moves_no_pool(v5e):
+    """``paged_decode_chunk`` as a serve call binds it for the cell's
+    geometry (64 slots of 67 pages, 2,624 latent pages of ``[1, 576,
+    128]``): the walk's fold of 64 query rows of 576 against a page and
+    ``P @ V`` against the same block's first 512 sublanes is taken by
+    Mosaic, with the result shape the benchmark's reader matches; ONE
+    pool goes through the write; the held experts' grouped matmuls are
+    Mosaic calls with the shapes their reader matches; no instruction
+    moves the pool or a layer of it, nor the expert stacks; the stage is
+    ``[6, 64, 32, 576]`` in HBM; temporaries far below a chip."""
+    gigachat, cfg, params = _gigachat_cut()
+    spec = kvpage.paged_spec(gigachat, cfg)
+    pool = jax.eval_shape(lambda: kvpage.init_page_pool(
+        cfg, GIGA_PAGES - GIGA_B, PAGE, GIGA_B, spec=spec))
+    assert set(pool) == {"k"}
+    assert pool["k"].shape == (6, GIGA_PAGES, 1, 576, PAGE)
+    state = dict(k=pool["k"], table=_s((GIGA_B, GIGA_LEN // PAGE), jnp.int32),
+                 pos=_s((GIGA_B,), jnp.int32), left=_s((GIGA_B,), jnp.int32),
+                 owns=_s((GIGA_B,), jnp.bool_), moe=_s((6,), jnp.int32))
+    keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0), GIGA_B))
+    step = kvpage.make_paged_step_fn(params, cfg, gigachat, XL_CHUNK, PAGE)
+    compiled = step.func.lower(
+        *_place([*step.args, state, _s((GIGA_B,), jnp.int32), keys], v5e),
+        **step.keywords).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"%([a-z_]+)[.0-9]* = (\(?[a-z0-9]+\[[0-9,]*\])",
+                       "\n".join(l for l in text.splitlines()
+                                 if "tpu_custom_call" in l))
+    assert {n for n, _ in calls} == {"paged_flash_decode_attend",
+                                     "paged_kv_write", "gmm"}
+    assert ("paged_flash_decode_attend", "bf16[64,1,64,512]") in calls
+    assert ("paged_kv_write", "bf16[6,2624,1,576,128]") in calls
+    assert {r for n, r in calls if n == "gmm"} == {"f32[512,2048]",
+                                                   "f32[512,7168]"}
+    sized = re.compile(r" = [a-z0-9]+\[(?:6,)?2624,1,576,128\].*?"
+                       r"\s(?!(?:parameter|get-tuple-element|tuple|while|"
+                       r"bitcast|custom-call)\()[a-z][a-z0-9-]*\(")
+    moved = [l.strip()[:160] for l in text.splitlines() if sized.search(l)]
+    assert not moved, "\n".join(moved)
+    stack = re.compile(r" = bf16\[(?:5,)?(?:16|80),(?:7168,2048|2048,7168)\]"
+                       r".*?\s(?!(?:parameter|get-tuple-element|bitcast)\()"
+                       r"[a-z][a-z0-9-]*\(")
+    moved = [l.strip()[:160] for l in text.splitlines() if stack.search(l)]
+    assert not moved, "\n".join(moved)
+    # the attend once a SEGMENT's scan body (the dense layer's, the
+    # expert layers'), the write once, in the flush's scan alone
+    jaxpr = jax.make_jaxpr(
+        lambda *a: step.func.__wrapped__(*a, **step.keywords))(
+            *step.args, state, _s((GIGA_B,), jnp.int32), keys).jaxpr
+    assert _pallas_grids(jaxpr)["paged_kv_write"] == [(GIGA_B, 2)]
+    assert _pallas_grids(jaxpr)["paged_flash_decode_attend"] == [
+        (GIGA_B,)] * 2
+    depths = _loop_depths(jaxpr)
+    assert depths["paged_kv_write"] == [1]
+    assert depths["paged_flash_decode_attend"] == [2, 2]
+    assert re.search(r"bf16\[6,64,32,576\]{3,2,1,0:T\(8,128\)\(2,1\)}", text)
+    assert not _unfilled_stage(text, [pool["k"]], GIGA_B, spec.v_dim)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("bucket,history", [(8192, 0), (64, 7936),
+                                            (256, 7936)])
+def test_gigachat_prefill_compiles_for_v5e(bucket, history, v5e):
+    """``serving.paged_prefill`` at the cell's ONE cold bucket and
+    ``paged_suffix_prefill`` at its smallest and largest suffix bucket
+    behind 62 hit pages: the rows-attention kernel at head width 192
+    (K/V streamed a tile a grid step), the expert layer a block of 2,048
+    tokens at a time, and temporaries that fit beside 10.35 GB of
+    weights and 2.3 GB of pages."""
+    from mpi_acx_tpu.models import serving
+    gigachat, cfg, params = _gigachat_cut()
+    kw = dict(cfg=cfg, family=gigachat, kv_int8=False, on_tpu=True,
+              page_tokens=None)
+    if history:
+        hk = _s((6, 1, 576, history), jnp.bfloat16)
+        compiled = serving.paged_suffix_prefill.lower(
+            *_place([params, _s((1, bucket), jnp.int32), hk], v5e), None,
+            None, *_place([_s((), jnp.int32)], v5e), **kw).compile()
+    else:
+        compiled = serving.paged_prefill.lower(
+            *_place([params, _s((1, bucket), jnp.int32), _s((), jnp.int32)],
+                    v5e), **kw).compile()
+    text = compiled.as_text()
+    assert f"%flash_rows_attention" in text
+    assert f" = bf16[64,{bucket},192]" in text
+    assert f"f32[{8 * min(bucket, 2048)},2048]" in text and "%gmm" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.6 * 2 ** 30
