@@ -416,6 +416,20 @@ def phase_serve_paged(size: Size = FULL, seed: int = 0,
     return True
 
 
+def _moe_mask_row(m, top_k: int, n_slots: int) -> dict:
+    """How the expert layers' mask engaged in a serve call (the chunk
+    routes no pair of a slot-step that can deliver no token): the pairs
+    it left out beside those computed add up to every slot's."""
+    _require(m.moe_pairs_dead > 0 and m.moe_assignments + m.moe_pairs_dead
+             == top_k * n_slots * m.moe_layer_steps,
+             f"moe_pairs_dead={m.moe_pairs_dead}, "
+             f"moe_assignments={m.moe_assignments}, "
+             f"moe_layer_steps={m.moe_layer_steps}")
+    return dict(moe_pairs_dead=m.moe_pairs_dead,
+                moe_dead_share=round(m.moe_pairs_dead / (
+                    m.moe_pairs_dead + m.moe_assignments), 4))
+
+
 def _serve_paged_lfm2(size: Size, seed: int):
     """The same serve loop over a family of two operator kinds and two
     FFN kinds (models/lfm2.py, a small preset: heads of 64 so that the
@@ -457,6 +471,7 @@ def _serve_paged_lfm2(size: Size, seed: int):
          paged_operator=m.paged_operator, paged_ffn=m.paged_ffn,
          moe_live_expert_share=round(m.moe_live_expert_share, 4),
          moe_load_max_over_mean=round(m.moe_load_max_over_mean, 3),
+         **_moe_mask_row(m, cfg.top_k, size.n_slots),
          kv_write_path=m.paged_kv_write,
          kv_page_rewrites_per_token=_rewrites_per_token(m),
          attend_built=m.paged_decode_attend,
@@ -579,6 +594,7 @@ def _serve_paged_gigachat(size: Size, seed: int):
          moe_pairs_held=m.moe_pairs_held, moe_group_hits=m.moe_group_hits,
          moe_experts_live=m.moe_experts_live,
          moe_live_expert_share=round(m.moe_live_expert_share, 4),
+         **_moe_mask_row(m, cfg.top_k, size.n_slots),
          kv_write_path=m.paged_kv_write,
          kv_page_rewrites_per_token=_rewrites_per_token(m),
          attend_built=m.paged_decode_attend,

@@ -379,9 +379,12 @@ def _shared_ffn(cfg: GigaChatConfig, lp: Params, u: jax.Array):
     return _swiglu(u, *(_w(lp, n, u.dtype) for n in ("ws1", "ws3", "ws2")))
 
 
-def _moe_ffn(cfg: GigaChatConfig, lp: Params, x: jax.Array):
+def _moe_ffn(cfg: GigaChatConfig, lp: Params, x: jax.Array, live=None):
     """(x + shared + the held experts' part, idx [T, k] the experts
-    chosen, kept [T, n_group] the routing groups they were chosen in)."""
+    chosen, kept [T, n_group] the routing groups they were chosen in).
+    ``live`` [T] bool: the tokens whose result anybody receives (None:
+    all); the others' held experts are not computed (the shared expert
+    is: its weights are read once whatever the rows)."""
     d = cfg.d_model
     u = rmsnorm(x, lp["ffn_norm"], cfg.norm_eps).reshape(-1, d)
     idx, p, kept = moe.route_sigmoid_group_topk(
@@ -390,22 +393,25 @@ def _moe_ffn(cfg: GigaChatConfig, lp: Params, x: jax.Array):
     # (with "repeat" the expert matrices are the segment's whole stacks)
     w = tuple(_w(lp, n, x.dtype) for n in _EXPERT_STACKS)
 
-    def held(u, idx, p):
+    def held(u, idx, p, live=None):
         return moe.sorted_expert_ffn(u, *w, idx, p, first=cfg.experts_first,
-                                     layer=lp.get("repeat"))
+                                     layer=lp.get("repeat"), live=live)
     T, blk = u.shape[0], cfg.moe_block
+    rows = (u, idx, p) if live is None else (u, idx, p, live)
     if T > blk and T % blk == 0:
         y = lax.map(lambda a: held(*a), tuple(
-            a.reshape((T // blk, blk) + a.shape[1:]) for a in (u, idx, p)))
+            a.reshape((T // blk, blk) + a.shape[1:]) for a in rows))
         y = y.reshape(T, d)
     else:
-        y = held(u, idx, p)
+        y = held(*rows)
     y = y + _shared_ffn(cfg, lp, u).astype(jnp.float32)
     return x + y.astype(x.dtype).reshape(x.shape), idx, kept
 
 
-def _ffn(cfg: GigaChatConfig, lp: Params, x: jax.Array, kind: str):
-    return _dense_ffn(cfg, lp, x) if kind == "dense" else _moe_ffn(cfg, lp, x)
+def _ffn(cfg: GigaChatConfig, lp: Params, x: jax.Array, kind: str,
+         live=None):
+    return (_dense_ffn(cfg, lp, x) if kind == "dense"
+            else _moe_ffn(cfg, lp, x, live))
 
 
 def _head(params: Params, cfg: GigaChatConfig, x: jax.Array):

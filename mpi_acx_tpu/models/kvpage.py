@@ -446,7 +446,9 @@ class PagedSpec:
       small state may slice its layer out and put it back; one of a
       GB goes to a Pallas call whole, aliased to its result)
     * ``ffn(cfg, lp, x, kind) -> x``, and ``(x, idx [B, k])``, the
-      experts each row chose, when ``kind`` is ``"moe"``
+      experts each row chose, when ``kind`` is ``"moe"``; such a layer
+      also takes ``live=`` ([B] bool, or None: the rows whose result
+      anybody receives; the others' experts need not be computed)
     * ``head(params, cfg, x) -> logits [B, vocab]`` f32
     * ``prefill(params, cfg, tokens [1, S], last_index, kv_int8,
       page_tokens) -> (logits [1, 1, vocab], one)``: ``one`` holds
@@ -567,30 +569,40 @@ _POOL_KEYS = ("k", "v", "ks", "vs")        # what the decode step carries
 # Paged decode step (any family, through its PagedSpec)
 
 
-def _moe_tally(idx, owns, n_experts: int, held=None, kept=None):
-    """[4] int32 of one MoE layer's routing in one step, over the slots
-    that OWN a request: (token, expert) pairs routed, distinct experts
-    hit, the fullest expert's pairs, and 1 (the layer-steps counted).
-    An idle slot routes too and its experts are fetched: the first two
-    are a FLOOR on what the expert kernel computes and reads.
+def _moe_tally(idx, owns, n_experts: int, held=None, kept=None, live=None):
+    """[5] int32 of one MoE layer's routing in one step, over the slots
+    that OWN a request and are ``live`` ([B] bool, the mask the expert
+    layer was handed; None: every slot): (token, expert) pairs routed,
+    distinct experts hit, the fullest expert's pairs, 1 (the layer-steps
+    counted) and, last, the pairs the mask left out (``top_k`` a slot
+    that is not live, whether it owns a request or idles). Where the
+    loop says what each slot owes (``state['left']``) a live slot owns
+    its request, and the first two are what the expert kernel computes
+    and reads; without it an idle slot routes too and its experts are
+    fetched: they are then a floor.
 
     Where the experts held here are a share of the router's (``held`` =
     ``(first, count)``) the experts hit and the fullest are counted over
     the HELD ones, whose weights are what this chip reads, and two more
-    follow, [6]: the pairs whose expert is held, and the owning slots'
-    tokens whose kept routing groups (``kept`` [B, groups] bool, of a
-    group-limited router) include the held experts' own."""
+    stand before the last, [7]: the pairs whose expert is held, and the
+    counted slots' tokens whose kept routing groups (``kept`` [B,
+    groups] bool, of a group-limited router) include the held experts'
+    own."""
+    dead = jnp.int32(0)
+    if live is not None:
+        owns = owns & live
+        dead = idx.shape[1] * (~live).sum().astype(jnp.int32)
     hits = jnp.zeros((n_experts,), jnp.int32).at[idx].add(
         owns.astype(jnp.int32)[:, None])
     if held is None:
         return jnp.stack([hits.sum(), (hits > 0).sum().astype(jnp.int32),
-                          hits.max(), jnp.int32(1)])
+                          hits.max(), jnp.int32(1), dead])
     first, count = held
     mine = hits[first:first + count]
     group = first * kept.shape[1] // n_experts
     return jnp.stack([hits.sum(), (mine > 0).sum().astype(jnp.int32),
                       mine.max(), jnp.int32(1), mine.sum(),
-                      (kept[:, group] & owns).sum().astype(jnp.int32)])
+                      (kept[:, group] & owns).sum().astype(jnp.int32), dead])
 
 
 def paged_decode_step(params, cfg, state, token, page_tokens: int,
@@ -601,16 +613,20 @@ def paged_decode_step(params, cfg, state, token, page_tokens: int,
     math, so active slots are bit-equal to the fixed-slot step).
     ``state`` = pool keys + ``'table'`` [B, max_pages] + ``'pos'`` [B]
     and, for a family with state layers, ``'held'`` ([L_state, B,
-    *leaf] a leaf); with ``'moe'`` [4] and ``'owns'`` [B] it also
+    *leaf] a leaf); with ``'moe'`` [5 or 7] and ``'owns'`` [B] it also
     counts its routing (:func:`_moe_tally`). With ``'left'`` ([B] int32:
     the tokens each slot's request still owes at the chunk's start, 0
     for a slot that owns none; what only the loop knows,
-    ``RequestBook.left``) the attend is told which slots can deliver no
-    token at this step (``step >= left[b]``; a step by itself is step
-    0): it fetches and folds nothing for them and their rows are
-    zeros. Absent, every slot is live. Nothing else reads it: a dead
-    slot's token still runs the layers (finite, dropped by the loop),
-    its K/V is staged and flushed, its state moves.
+    ``RequestBook.left``) the attend and the expert layers (FFN kind
+    ``"moe"``) are told which slots can deliver no token at this step
+    (``step >= left[b]``; a step by itself is step 0): the attend
+    fetches and folds nothing for them and their rows are zeros, the
+    expert layer routes none of their pairs to an expert, so an expert
+    that only they chose is not read, and adds zeros to their residual.
+    Absent, every slot is live. Nothing else reads it: a dead slot's
+    token still runs the dense weights, the router and a family's
+    state operators (finite, dropped by the loop), its K/V is staged
+    and flushed, its state moves.
 
     An attention layer's fresh K/V for slot b lands at ``pool[i,
     table[b, pos_b // pt], :, :, pos_b % pt]``, ``i`` counting the
@@ -661,6 +677,13 @@ def paged_decode_step(params, cfg, state, token, page_tokens: int,
 
     write = select_paged_kv_write(cfg.decode_flash, page_tokens)
     attend = select_paged_decode_attend(cfg.decode_flash, page_tokens)
+    rest = {k: state[k] for k in ("held", "moe") if k in state}
+    step = None
+    if "stage" in state:
+        rest["stage"], step = state["stage"]
+    # [B] bool: the slots that can still deliver a token at this step
+    live = ((0 if step is None else step) < state["left"]
+            if "left" in state else None)
 
     def nth(base, i, stride, j):
         """``base + i * stride + j`` without the identities."""
@@ -693,19 +716,15 @@ def paged_decode_step(params, cfg, state, token, page_tokens: int,
             rest = dict(rest, held=held)
         if kind.ffn == "moe":
             # (idx, and the routing groups a group-limited router kept)
-            x, *routed = spec.ffn(cfg, lp, x, kind.ffn)
+            x, *routed = spec.ffn(cfg, lp, x, kind.ffn, live=live)
             if "moe" in rest:
                 rest = dict(rest, moe=rest["moe"] + _moe_tally(
                     routed[0], state["owns"], spec.n_experts,
-                    spec.experts_held, *routed[1:]))
+                    spec.experts_held, *routed[1:], live=live))
         else:
             x = spec.ffn(cfg, lp, x, kind.ffn)
         return x, pools, rest
 
-    rest = {k: state[k] for k in ("held", "moe") if k in state}
-    step = None
-    if "stage" in state:
-        rest["stage"], step = state["stage"]
     carry = (x, tuple(state[k] for k in keys), rest)
     pages_at = states_at = 0
     for seg in spec.segments:
@@ -944,7 +963,7 @@ class PagedKV:
                                    kv_int8=kv_int8, spec=self.spec)
         self._fresh_books(prefix_cache)
         # The slots' fixed state (module docstring), None without state
-        # layers; the routing each chunk's steps counted ([4] as
+        # layers; the routing each chunk's steps counted (a tuple as
         # _moe_tally's) and the snapshots loaded to continue a sequence.
         self.held = self._fresh_held()
         self.moe_chunks: List[Tuple[int, ...]] = []     # one a chunk
@@ -996,7 +1015,7 @@ class PagedKV:
             # A slot owns a request exactly while it holds pages.
             state["owns"] = jnp.asarray([bool(p) for p in self.pages])
             state["moe"] = jnp.zeros(
-                (4 if self.spec.experts_held is None else 6,), jnp.int32)
+                (5 if self.spec.experts_held is None else 7,), jnp.int32)
         return state
 
     def absorb(self, state) -> None:

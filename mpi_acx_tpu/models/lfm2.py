@@ -246,8 +246,10 @@ def _dense_ffn(cfg: Lfm2Config, lp: Params, x: jax.Array):
     return x + h @ _w(lp, "w2", x.dtype)
 
 
-def _moe_ffn(cfg: Lfm2Config, lp: Params, x: jax.Array):
-    """(x + the held experts' part, idx [T, k] the experts chosen)."""
+def _moe_ffn(cfg: Lfm2Config, lp: Params, x: jax.Array, live=None):
+    """(x + the held experts' part, idx [T, k] the experts chosen).
+    ``live`` [T] bool: the tokens whose result anybody receives (None:
+    all); the others' experts are not computed, their part is zeros."""
     u = rmsnorm(x, lp["ffn_norm"], cfg.norm_eps).reshape(-1, cfg.d_model)
     idx, p = moe.route_sigmoid_topk(
         u, lp["gate"], lp["bias"], cfg.top_k, cfg.routed_scaling_factor,
@@ -255,12 +257,14 @@ def _moe_ffn(cfg: Lfm2Config, lp: Params, x: jax.Array):
     # (with "repeat" the expert matrices are the segment's whole stacks)
     y = moe.sorted_expert_ffn(u, _w(lp, "w1", x.dtype), _w(lp, "w3", x.dtype),
                               _w(lp, "w2", x.dtype), idx, p,
-                              first=cfg.experts_first, layer=lp.get("repeat"))
+                              first=cfg.experts_first, layer=lp.get("repeat"),
+                              live=live)
     return x + y.astype(x.dtype).reshape(x.shape), idx
 
 
-def _ffn(cfg: Lfm2Config, lp: Params, x: jax.Array, kind: str):
-    return _dense_ffn(cfg, lp, x) if kind == "dense" else _moe_ffn(cfg, lp, x)
+def _ffn(cfg: Lfm2Config, lp: Params, x: jax.Array, kind: str, live=None):
+    return (_dense_ffn(cfg, lp, x) if kind == "dense"
+            else _moe_ffn(cfg, lp, x, live))
 
 
 def _head(params: Params, cfg: Lfm2Config, x: jax.Array):

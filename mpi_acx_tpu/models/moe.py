@@ -439,7 +439,7 @@ def select_grouped_matmul():
 
 
 def sorted_expert_ffn(x, w1, w3, w2, idx, p, first: int = 0,
-                      grouped_matmul=None, layer=None):
+                      grouped_matmul=None, layer=None, live=None):
     """SwiGLU experts over sorted rows, no drop: x [T, d]; ``idx``,
     ``p`` [T, k] the routing over ALL experts; ``w1``, ``w3`` [n, d, f]
     and ``w2`` [n, f, d] the experts ``first .. first + n - 1`` held
@@ -450,6 +450,16 @@ def sorted_expert_ffn(x, w1, w3, w2, idx, p, first: int = 0,
     ``p``. Returns [T, d] f32: ``sum_i p_i W2_i (silu(W1_i x) * W3_i
     x)`` over the held experts ``i`` of each token. ``grouped_matmul``:
     :func:`select_grouped_matmul`'s unless given.
+
+    ``live`` ([T] bool; None: every token) says whose result anybody
+    receives (a decode chunk's slots that can still deliver a token at
+    this step: ``kvpage.paged_decode_step``). The pairs of a token that
+    is not live are treated as pairs of an absent expert: behind every
+    group and in none, so an expert that only such tokens chose has no
+    row and its matrices are not read, and the token's result is zeros,
+    written by a ``where`` (what a grouped matmul leaves in rows of no
+    group is not defined; a call with NO live pair gives it no group
+    with a row at all). A live token's result does not depend on it.
 
     With ``layer`` (a traced scalar is fine) the matrices are STACKS
     over layers, ``[R, n, ...]``, and this call is layer ``layer``'s:
@@ -463,6 +473,8 @@ def sorted_expert_ffn(x, w1, w3, w2, idx, p, first: int = 0,
     k, n = idx.shape[1], w1.shape[-3]
     local = idx.reshape(-1) - first
     held = (local >= 0) & (local < n)
+    if live is not None:
+        held = held & jnp.repeat(live, k)
     key = jnp.where(held, local, n)
     order = jnp.argsort(key, stable=True)                  # [T*k]
     sizes = jnp.zeros((n + 1,), jnp.int32).at[key].add(1)[:n]

@@ -123,18 +123,22 @@ class ServingMetrics:
     # "attention+mamba";
     # "dense:_mlp", "dense:_dense_ffn+moe:sorted_expert_ffn"), and what a
     # routed FFN had to do, counted ON THE DEVICE in every decode step
-    # and MoE layer over the slots that OWN a request at the chunk's
-    # start (kvpage._moe_tally): (token, expert) pairs routed, distinct
-    # experts hit, the fullest expert's pairs. An idle slot routes too
-    # and its experts are read: the first two are a floor on the expert
-    # kernel's work. ``moe_layer_steps``: (MoE layer, decode step) pairs
-    # counted; ``moe_experts``: the router's width (0 without).
+    # and MoE layer over the slots that own a request and can still
+    # deliver a token at that step (kvpage._moe_tally): (token, expert)
+    # pairs routed, distinct experts hit, the fullest expert's pairs:
+    # what the expert kernel computes and reads, since the chunk is told
+    # ``left`` and routes no other slot's pairs. ``moe_pairs_dead``: the
+    # pairs that mask left out (idle slots' and those of requests that
+    # ended earlier in the chunk). ``moe_layer_steps``: (MoE layer,
+    # decode step) pairs counted; ``moe_experts``: the router's width
+    # (0 without).
     paged_operator: str = ""
     paged_ffn: str = ""
     moe_assignments: int = 0
     moe_experts_live: int = 0
     moe_load_max: int = 0
     moe_layer_steps: int = 0
+    moe_pairs_dead: int = 0
     moe_experts: int = 0
     # Where the experts held here are a share of the router's
     # (PagedSpec.experts_held): ``moe_experts_live`` / ``moe_load_max``
@@ -146,7 +150,8 @@ class ServingMetrics:
     moe_pairs_held: int = 0
     moe_group_hits: int = 0
     moe_experts_held: int = 0
-    # (pairs, experts hit, fullest, layer-steps) of each decode chunk
+    # (pairs, experts hit, fullest, layer-steps[, pairs held, group
+    # hits], pairs dead) of each decode chunk
     moe_by_chunk: List[tuple] = field(default_factory=list)
     # paged: snapshots of the state layers loaded to continue a sequence
     # from a page's end (radix hits, resumes that hit; the name is from
@@ -1694,10 +1699,12 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
                 cfg.decode_flash, pt).__name__,
             paged_operator=pkv.spec.built("operator"),
             paged_ffn=pkv.spec.built("ffn"),
+            # (a chunk's tally ends with the pairs its mask left out)
             **dict(zip(("moe_assignments", "moe_experts_live",
                         "moe_load_max", "moe_layer_steps",
                         "moe_pairs_held", "moe_group_hits"),
-                       map(sum, zip(*pkv.moe_chunks)))),
+                       map(sum, zip(*(c[:-1] for c in pkv.moe_chunks))))),
+            moe_pairs_dead=sum(c[-1] for c in pkv.moe_chunks),
             moe_experts=pkv.spec.n_experts,
             moe_experts_held=(pkv.spec.experts_held or (0, 0))[1],
             kv_bytes_token=sum(

@@ -339,9 +339,10 @@ def test_prefill_then_absorbed_decode_against_the_references_full_forward(
     # held experts are all 8, so every pair is held; a token keeps ONE of
     # the two groups and the held experts' own (group 0) some of the time
     assert len(pkv.moe_chunks) == 2
-    for pairs, live, fullest, layer_steps, held, hits in pkv.moe_chunks:
-        assert (pairs, layer_steps, held) == (2 * 2 * 2 * steps, 2 * steps,
-                                              pairs)
+    # (a state built without ``left``: no slot is masked, none is dead)
+    for pairs, live, fullest, layer_steps, held, hits, dead in pkv.moe_chunks:
+        assert (pairs, layer_steps, held, dead) == (
+            2 * 2 * 2 * steps, 2 * steps, pairs, 0)
         assert fullest <= live <= pairs and 0 <= hits <= 2 * 2 * steps
 
 
@@ -486,6 +487,13 @@ def test_serve_paged_greedy_serves_it_hits_and_counts(tree, use_flash):
     assert m.moe_pairs_held == m.moe_assignments > 0
     assert 0 < m.moe_group_hits <= m.moe_assignments // 2
     assert 0 < m.moe_live_expert_share <= 4 / 8
+    # the counters count what the expert layer was left with: the pairs
+    # of slot-steps that deliver a token, and beside them the pairs the
+    # mask took (requests end mid-chunk, the burst drains)
+    assert sum(c[0] for c in m.moe_by_chunk) == m.moe_assignments
+    assert m.moe_assignments == 2 * 2 * m.decode_tokens
+    assert m.moe_pairs_dead == sum(c[-1] for c in m.moe_by_chunk) > 0
+    assert m.moe_assignments + m.moe_pairs_dead == 2 * 2 * m.moe_layer_steps
     assert m.kv_tokens_staged == 4 * 2 * m.steps
     assert m.kv_page_rewrites >= 2 * m.steps
     assert 0 < m.attend_dead_share < 1 and m.attend_pages_walked > 0
@@ -514,6 +522,8 @@ def test_a_share_of_the_experts_is_served_and_counted():
     assert m.moe_pairs_held == 2 * m.moe_group_hits
     assert 0 < m.moe_pairs_held < m.moe_assignments
     assert 0 < m.moe_live_expert_share <= 1
+    assert m.moe_pairs_dead > 0         # three requests into two slots
+    assert m.moe_assignments + m.moe_pairs_dead == 2 * 2 * m.moe_layer_steps
 
 
 # -- the shares add up ----------------------------------------------------------
@@ -567,7 +577,43 @@ def test_the_tally_of_a_share_counts_held_pairs_and_group_hits():
     # experts 0..1 held of 8 (group 0 of 2): pairs routed by owners 6,
     # held experts hit {0, 1}, fullest 2 (expert 1), 1 layer-step, held
     # pairs 3, owners whose kept groups include group 0: slots 0 and 3
+    # and, appended, the pairs a mask left out: without one, none
     tally = kvpage._moe_tally(idx, owns, 8, (0, 2), kept)
-    assert list(np.asarray(tally)) == [6, 2, 2, 1, 3, 2]
+    assert list(np.asarray(tally)) == [6, 2, 2, 1, 3, 2, 0]
     tally = kvpage._moe_tally(idx, owns, 8, (4, 4), kept)
-    assert list(np.asarray(tally)) == [6, 3, 1, 1, 3, 2]
+    assert list(np.asarray(tally)) == [6, 3, 1, 1, 3, 2, 0]
+    # slot 3 can deliver no token at this step, nor can the idle slot 1:
+    # owners live are slots 0 and 2, 4 pairs, held {0, 1} once each,
+    # group 0 kept by slot 0; 2 slots x top 2 left out
+    live = jnp.asarray([True, False, True, False])
+    tally = kvpage._moe_tally(idx, owns, 8, (0, 2), kept, live=live)
+    assert list(np.asarray(tally)) == [4, 2, 1, 1, 2, 1, 4]
+    tally = kvpage._moe_tally(idx, owns, 8, (4, 4), kept,
+                              live=jnp.zeros((4,), bool))
+    assert list(np.asarray(tally)) == [0, 0, 0, 1, 0, 0, 8]
+
+
+def test_a_layer_in_blocks_masks_each_blocks_dead_tokens(tree):
+    """32 tokens through the layer in two blocks of ``moe_block`` 16
+    (``lax.map``), a share of the experts held: the mask goes to each
+    block with its rows. Live tokens get the layer's own result bit for
+    bit, dead ones the shared expert alone (every token's, whatever the
+    rows)."""
+    cfg = dataclasses.replace(CFG, experts_first=2, experts_held=4)
+    lp = {n: a[1] for n, a in tree["seg1"].items()}
+    lp.update({n: tree["seg1"][n][1, 2:6] for n in ("w1", "w3", "w2")})
+    x = jax.random.normal(jax.random.key(8), (1, 32, CFG.d_model))
+    live = np.arange(32) % 3 == 0
+    live[16:] = False                   # the second block: no live token
+    live[20] = True
+    want, idx, kept = gigachat._moe_ffn(cfg, lp, x)
+    got, idx2, kept2 = gigachat._moe_ffn(cfg, lp, x, jnp.asarray(live))
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(idx2))
+    np.testing.assert_array_equal(np.asarray(kept), np.asarray(kept2))
+    want, got = np.asarray(want)[0], np.asarray(got)[0]
+    np.testing.assert_array_equal(got[live], want[live])
+    u = gigachat.rmsnorm(x, lp["ffn_norm"], CFG.norm_eps)[0]
+    alone = np.asarray(x[0] + gigachat._shared_ffn(cfg, lp, u))
+    np.testing.assert_allclose(got[~live], alone[~live], atol=1e-6, rtol=0)
+    held = ((np.asarray(idx) >= 2) & (np.asarray(idx) < 6)).any(-1)
+    assert np.abs(want - alone)[held & ~live].max() > 1e-3

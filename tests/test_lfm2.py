@@ -212,8 +212,9 @@ def test_prefill_then_paged_decode_against_the_references_full_forward(tree):
             rtol=0)
     # the routing counters counted both slots, every step and MoE layer
     assert len(pkv.moe_chunks) == steps and pkv.tail_restores == 0
-    for pairs, live, fullest, layer_steps in pkv.moe_chunks:
-        assert (pairs, layer_steps) == (2 * 2 * 8, 8)
+    # (a state built without ``left``: no slot is masked, none is dead)
+    for pairs, live, fullest, layer_steps, dead in pkv.moe_chunks:
+        assert (pairs, layer_steps, dead) == (2 * 2 * 8, 8, 0)
         assert fullest <= live <= pairs
 
 
@@ -344,9 +345,13 @@ def test_serve_paged_greedy_serves_it_hits_and_counts(tree):
     assert m.moe_experts == 8 and m.moe_layer_steps == 8 * 4 * m.steps
     assert len(m.moe_by_chunk) == m.steps
     assert sum(c[0] for c in m.moe_by_chunk) == m.moe_assignments
-    # only owning slots are counted: at most 2 slots x top 2 pairs a
-    # layer-step, and fewer while a slot idles
-    assert 0 < m.moe_assignments <= 4 * m.moe_layer_steps
+    # only slot-steps that deliver a token are counted, and only they
+    # reach an expert: top 2 pairs in each of the 8 MoE layers a decode
+    # token; what the mask took (a request's steps behind its last
+    # token, an idle slot's) is counted beside them
+    assert m.moe_assignments == 2 * 8 * m.decode_tokens
+    assert m.moe_pairs_dead == sum(c[-1] for c in m.moe_by_chunk) > 0
+    assert m.moe_assignments + m.moe_pairs_dead == 2 * 2 * m.moe_layer_steps
     assert 0 < m.moe_live_expert_share <= 4 / 8
     assert 1.0 <= m.moe_load_max_over_mean <= 8.0
     # the same requests with nothing cached: the same tokens
@@ -370,11 +375,13 @@ def test_requests_that_end_mid_chunk_get_the_references_tokens(tree,
     """Outputs of 2 to 9 tokens against a chunk of 4, five requests into
     two slots: requests end mid-chunk and the last drains beside an
     empty slot. The loop tells each chunk what every slot still owes
-    (``state['left']``) and the attend zeroes the rows of the slot-steps
-    that can deliver nothing; this family's other operators run on as
-    they did. Every served token is still the reference's choice to
-    ATOL, and the tokens are, bit for bit, those of the same call with
-    every slot said to be live throughout."""
+    (``state['left']``): the attend zeroes the rows of the slot-steps
+    that can deliver nothing and the expert layers route none of their
+    pairs; the conv operators and the dense weights run on as they did.
+    Every served token is still the reference's choice to ATOL, and the
+    tokens are, bit for bit, those of the same call with every slot
+    said to be live throughout, which masks no pair and counts the
+    owners' pairs whether they deliver or not."""
     prompts = [_seq(41, 20), _seq(9, 21), _seq(23, 22), _seq(35, 23),
                _seq(17, 24)]
     n_new = [6, 3, 9, 2, 5]
@@ -390,6 +397,11 @@ def test_requests_that_end_mid_chunk_get_the_references_tokens(tree,
     assert untold.metrics.attend_pages_walked == (
         told.metrics.attend_pages_walked + told.metrics.attend_pages_dead)
     assert all((a == b).all() for a, b in zip(told, untold))
+    t, u = told.metrics, untold.metrics
+    assert t.moe_assignments == 2 * 8 * t.decode_tokens < u.moe_assignments
+    assert t.moe_pairs_dead > 0 == u.moe_pairs_dead
+    assert t.moe_assignments + t.moe_pairs_dead == 2 * 2 * t.moe_layer_steps
+    assert t.moe_experts_live < u.moe_experts_live
 
 
 @pytest.mark.parametrize("prefix_cache", [True, False],
@@ -414,10 +426,29 @@ def test_serve_loop_preempts_and_resumes_to_the_same_tokens(tree,
 
 def test_an_idle_slots_experts_are_not_counted():
     idx = jnp.asarray([[0, 1], [2, 3], [0, 5]])
-    tally = kvpage._moe_tally(idx, jnp.asarray([True, False, True]), 8)
-    assert list(np.asarray(tally)) == [4, 3, 2, 1]   # pairs, live, max, 1
+    owns = jnp.asarray([True, False, True])
+    tally = kvpage._moe_tally(idx, owns, 8)
+    # pairs, live, max, 1, and the pairs a mask left out: there is none
+    assert list(np.asarray(tally)) == [4, 3, 2, 1, 0]
     tally = kvpage._moe_tally(idx, jnp.asarray([False] * 3), 8)
-    assert list(np.asarray(tally)) == [0, 0, 0, 1]
+    assert list(np.asarray(tally)) == [0, 0, 0, 1, 0]
+
+
+@pytest.mark.parametrize("live,want", [
+    ([True, False, True], [4, 3, 2, 1, 2]),      # the idle slot's 2 pairs
+    ([True, False, False], [2, 2, 1, 1, 4]),     # + an owner that ended
+    ([False, False, False], [0, 0, 0, 1, 6]),    # a chunk's last steps
+    ([True, True, True], [4, 3, 2, 1, 0]),       # told nothing is dead
+], ids=["idle", "ended", "none_live", "all_live"])
+def test_the_tally_counts_what_the_mask_leaves_and_what_it_takes(live, want):
+    """With the mask the expert layer was handed, the tally counts the
+    pairs of slots that own a request AND are live (what the kernel
+    computes), and, last, ``top_k`` pairs for every slot the mask left
+    out, owner or not."""
+    idx = jnp.asarray([[0, 1], [2, 3], [0, 5]])
+    owns = jnp.asarray([True, False, True])
+    tally = kvpage._moe_tally(idx, owns, 8, live=jnp.asarray(live))
+    assert list(np.asarray(tally)) == want
 
 
 # -- the expert layer ---------------------------------------------------------
@@ -508,6 +539,80 @@ def test_eight_shares_of_8_experts_add_up_to_the_whole_layer():
     np.testing.assert_allclose(whole, _ref_moe(x, e, 4), atol=1e-5, rtol=0)
     np.testing.assert_allclose(whole, _dense_loop(x, e, idx, p), atol=1e-5,
                                rtol=0)
+
+
+# how the layer is handed its experts -> (stack depth, the layer's index
+# in it, first held expert, how many held); None: not stacked, all held
+HANDED = {"whole": (None, None, 0, 64), "stacked": (3, 1, 0, 64),
+          "share": (None, None, 16, 8), "stacked_share": (2, 1, 40, 16)}
+# which of SHARES_X's 21 tokens anybody receives
+LIVE = {"some": [0, 3, 4, 10, 17], "one": [12], "none": []}
+
+
+@pytest.mark.parametrize("who", sorted(LIVE))
+@pytest.mark.parametrize("how", sorted(HANDED))
+def test_a_dead_tokens_pairs_reach_no_expert(how, who):
+    """``sorted_expert_ffn(live=)``: a live token's rows are BIT-equal
+    to the call without it, a dead token's exactly zero; the ``sizes``
+    each of the three grouped matmuls is handed count the live tokens'
+    held pairs and nothing else, so an expert that only dead tokens
+    chose has no row (its matrices are not read); and with NO live
+    token the result is finite zeros. Whole, as one layer of a stack,
+    as a share of the experts, and both."""
+    depth, layer, first, count = HANDED[how]
+    e, x = SHARES, SHARES_X
+    idx, p = moe.route_sigmoid_topk(x, e["gate"], e["bias"], 4)
+    live = np.zeros(len(x), bool)
+    live[LIVE[who]] = True
+    w = [e[n][first:first + count] for n in ("w1", "w3", "w2")]
+    kw = dict(first=first)
+    if depth:
+        w = [jnp.full((depth,) + a.shape, 7.0).at[layer].set(a) for a in w]
+        kw["layer"] = jnp.int32(layer)
+    seen = []
+
+    def spy(xs, w, sizes):
+        seen.append(np.asarray(sizes))
+        return moe.ragged_dot_matmul(xs, w, sizes)
+
+    want = np.asarray(moe.sorted_expert_ffn(x, *w, idx, p, **kw))
+    got = np.asarray(moe.sorted_expert_ffn(
+        x, *w, idx, p, live=jnp.asarray(live), grouped_matmul=spy, **kw))
+    np.testing.assert_array_equal(got[live], want[live])
+    assert (got[~live] == 0.0).all() and np.isfinite(got).all()
+    chosen = np.asarray(idx)
+    mine = np.bincount(chosen[live].ravel(), minlength=64)[
+        first:first + count]
+    sizes = mine
+    if depth:
+        sizes = np.zeros(depth * count, int)
+        sizes[layer * count:(layer + 1) * count] = mine
+    assert len(seen) == 3
+    for s in seen:
+        np.testing.assert_array_equal(s, sizes)
+    # some held expert was chosen by dead tokens alone: it has no row
+    everyone = np.bincount(chosen.ravel(), minlength=64)[first:first + count]
+    assert ((everyone > 0) & (mine == 0)).any()
+    if who == "none":
+        assert not got.any() and not sizes.any()
+
+
+@pytest.mark.parametrize("who", ["some", "none"])
+def test_the_pallas_grouped_matmul_takes_dead_tokens_too(who):
+    """The same through ``megablox.gmm`` (interpret mode here): with NO
+    live pair its grid has no active tile, and the rows it leaves
+    unwritten never reach the sum."""
+    e, x = SHARES, SHARES_X
+    idx, p = moe.route_sigmoid_topk(x, e["gate"], e["bias"], 4)
+    live = np.zeros(len(x), bool)
+    live[LIVE[who]] = True
+    w = [e[n] for n in ("w1", "w3", "w2")]
+    want = np.asarray(moe.sorted_expert_ffn(x, *w, idx, p))
+    got = np.asarray(moe.sorted_expert_ffn(
+        x, *w, idx, p, live=jnp.asarray(live),
+        grouped_matmul=moe.megablox_matmul))
+    np.testing.assert_allclose(got[live], want[live], atol=1e-5, rtol=0)
+    assert (got[~live] == 0.0).all() and np.isfinite(got).all()
 
 
 def test_router_tie_break_and_normalisation_are_the_references():

@@ -1034,9 +1034,20 @@ def test_serve_paged_bit_equals_fixed(kv_int8, chunk, n_new):
     assert paged.metrics.attend_pages_dead > 0
 
 
-@pytest.mark.parametrize("decode_flash", [None, True],
-                         ids=["dense", "kernels"])
-def test_the_loop_tells_the_chunk_what_each_slot_owes(decode_flash,
+def _moe_serve_setup(name):
+    """A small MoE family as its own test file has it: (family, cfg,
+    the benchmark's weights at the raised ``init_scale``, so that the
+    layers and not the embedding decide the tokens)."""
+    import importlib
+    t = importlib.import_module("test_" + name)
+    make = getattr(getattr(t, "weights_" + name), "make_" + name)
+    return getattr(t, name), t.CFG, make(t.C, 7, jnp.float32)
+
+
+@pytest.mark.parametrize("family,decode_flash", [
+    ("gpt2", None), ("gpt2", True), ("lfm2", None), ("gigachat", None),
+], ids=["dense", "kernels", "lfm2", "gigachat"])
+def test_the_loop_tells_the_chunk_what_each_slot_owes(family, decode_flash,
                                                       monkeypatch):
     """``serve_paged_greedy`` hands every chunk ``RequestBook.left``:
     for an owner its ``n_new`` less what it has emitted (at least 1: a
@@ -1046,10 +1057,19 @@ def test_the_loop_tells_the_chunk_what_each_slot_owes(decode_flash,
     serves the same tokens bit for bit, with the dense pair and with
     the kernels (interpret mode); the second call traces nothing (one
     program, ``left`` a plain operand); and what the two calls count
-    adds up: walked + dead told is walked untold."""
+    adds up: walked + dead told is walked untold. The same of the two
+    families with expert layers, whose dead slot-steps route no pair:
+    the pairs counted told are those of the tokens delivered, the rest
+    of ``top_k x slots`` a MoE layer-step are counted as left out, and
+    untold nothing is left out."""
     import dataclasses
     cfg, params, prompts = _serve_setup()
-    cfg = dataclasses.replace(cfg, decode_flash=decode_flash)
+    if family == "gpt2":
+        family, top_k = tfm, 0
+        cfg = dataclasses.replace(cfg, decode_flash=decode_flash)
+    else:
+        family, cfg, params = _moe_serve_setup(family)
+        top_k = cfg.top_k
     chunk, n_slots, handed = 4, 3, []
     device_state = kvpage.PagedKV.device_state
 
@@ -1062,7 +1082,7 @@ def test_the_loop_tells_the_chunk_what_each_slot_owes(decode_flash,
     def serve():
         return serving.serve_paged_greedy(
             params, cfg, prompts, RAGGED, n_slots=n_slots, max_len=32,
-            family=tfm, chunk=chunk, page_tokens=8)
+            family=family, chunk=chunk, page_tokens=8)
 
     told = serve()
     assert len(handed) == told.metrics.steps
@@ -1086,6 +1106,16 @@ def test_the_loop_tells_the_chunk_what_each_slot_owes(decode_flash,
     assert untold.metrics.attend_pages_dead == 0
     assert 0 < told.metrics.attend_pages_dead == (
         untold.metrics.attend_pages_walked - told.metrics.attend_pages_walked)
+    t, u = told.metrics, untold.metrics
+    moe_layers = t.moe_layer_steps // (chunk * t.steps)
+    assert t.moe_assignments == top_k * moe_layers * t.decode_tokens
+    assert sum(c[0] for c in t.moe_by_chunk) == t.moe_assignments
+    assert (t.moe_assignments + t.moe_pairs_dead
+            == top_k * n_slots * t.moe_layer_steps)
+    assert u.moe_pairs_dead == 0
+    if top_k:
+        assert t.moe_pairs_dead > 0 and moe_layers > 0
+        assert u.moe_assignments > t.moe_assignments
 
 
 @pytest.mark.slow

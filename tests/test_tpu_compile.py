@@ -635,7 +635,7 @@ def test_lfm2_decode_chunk_compiles_and_moves_no_pool(v5e):
                  table=_s((LFM2_B, MAX_LEN // PAGE), jnp.int32),
                  pos=_s((LFM2_B,), jnp.int32), left=_s((LFM2_B,), jnp.int32),
                  held=_s((7, LFM2_B, 2, 2048), jnp.bfloat16),
-                 owns=_s((LFM2_B,), jnp.bool_), moe=_s((4,), jnp.int32))
+                 owns=_s((LFM2_B,), jnp.bool_), moe=_s((5,), jnp.int32))
     keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0), LFM2_B))
     step = kvpage.make_paged_step_fn(params, cfg, lfm2, XL_CHUNK, PAGE)
     compiled = step.func.lower(
@@ -911,7 +911,7 @@ def test_gigachat_decode_chunk_compiles_and_moves_no_pool(v5e):
     assert pool["k"].shape == (6, GIGA_PAGES, 1, 576, PAGE)
     state = dict(k=pool["k"], table=_s((GIGA_B, GIGA_LEN // PAGE), jnp.int32),
                  pos=_s((GIGA_B,), jnp.int32), left=_s((GIGA_B,), jnp.int32),
-                 owns=_s((GIGA_B,), jnp.bool_), moe=_s((6,), jnp.int32))
+                 owns=_s((GIGA_B,), jnp.bool_), moe=_s((7,), jnp.int32))
     keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0), GIGA_B))
     step = kvpage.make_paged_step_fn(params, cfg, gigachat, XL_CHUNK, PAGE)
     compiled = step.func.lower(
