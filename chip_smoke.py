@@ -413,6 +413,7 @@ def phase_serve_paged(size: Size = FULL, seed: int = 0,
     _serve_paged_lfm2(size, seed)
     _serve_paged_jamba(size, seed)
     _serve_paged_gigachat(size, seed)
+    _serve_paged_nemotron(size, seed)
     return True
 
 
@@ -602,6 +603,83 @@ def _serve_paged_gigachat(size: Size, seed: int):
          attend_dead_share=round(m.attend_dead_share, 4),
          programs_traced=traced,
          token_mismatch_vs_dense=mismatch,
+         **_record_row(m, again.metrics))
+
+
+def _serve_paged_nemotron(size: Size, seed: int):
+    """The same serve loop over a family whose layers are ONE mixer each
+    (models/nemotron_h.py, the published period MEMEMEM*EME at a small
+    width, the Mamba-2 heads at their published geometry, 128 x 64 x 128
+    in 8 groups, so that the chip's kernels tile as in the cell): Mamba-2
+    layers through ``ssd_scan`` and ``ssd_update``, GQA pages of the one
+    ``*`` layer, latent experts of two matrices of which this chip holds
+    half, and that a radix hit restores a snapshot. Tokens against the
+    plain-JAX configuration of the same family."""
+    import jax
+
+    from mpi_acx_tpu.models import nemotron_h, serving
+    pt = size.page_tokens or 128
+    over = {} if size.tiny else dict(
+        vocab=512, d_model=256, n_heads=4, n_kv_heads=2, head_dim=128,
+        mamba_heads=128, mamba_head_dim=64, ssm_state=128, n_groups=8,
+        chunk_size=128, moe_latent=128, moe_d_ff=256, shared_d_ff=512,
+        moe_block=256)
+    cfg = nemotron_h.tiny_nemotron(
+        max_seq=2 * size.max_len, experts_first=4, experts_held=4,
+        snapshot_every=size.shared_prefix // pt, **over)
+    params = nemotron_h.cast_params(
+        nemotron_h.init_params(jax.random.key(seed), cfg))
+    prompts, n_new = _requests(size, cfg.vocab, seed)
+    kw = dict(n_slots=size.n_slots, max_len=size.max_len, family=nemotron_h,
+              chunk=size.chunk, page_tokens=size.page_tokens,
+              prefix_cache=True, max_request_retries=0, n_snapshots=4)
+    with _Watch() as w:
+        outs = serving.serve_paged_greedy(params, cfg, prompts, n_new, **kw)
+    m = outs.metrics
+    tokens = _check_outputs(outs, prompts, n_new, m)
+    _require(m.prefix_hits >= 2 and m.state_snapshot_seats >= 2
+             and m.state_snapshot_restores >= m.state_snapshot_seats,
+             f"prefix_hits={m.prefix_hits}, "
+             f"state_snapshot_seats={m.state_snapshot_seats}")
+    _require(0 < m.moe_pairs_held < m.moe_assignments
+             and m.moe_latent_rows == m.moe_pairs_held
+             and m.moe_row_dim == cfg.moe_latent,
+             f"moe_pairs_held={m.moe_pairs_held} of {m.moe_assignments}, "
+             f"moe_latent_rows={m.moe_latent_rows}")
+    _require(m.state_slot_steps == m.decode_tokens
+             and m.state_bytes_moved == 2 * m.state_slot_steps
+             * m.state_bytes_slot,
+             f"state_slot_steps={m.state_slot_steps}")
+    again = serving.serve_paged_greedy(params, cfg, prompts, n_new, **kw)
+    traced = [m.programs_traced, again.metrics.programs_traced]
+    _require(traced[0] > 0 and traced[1] == 0
+             and _mismatch_share(again, outs, prompts) == 0,
+             f"second serve call (nemotron): programs_traced={traced}")
+    ref = serving.serve_paged_greedy(
+        params, dataclasses.replace(_reference(cfg), ssm_kernel=False),
+        prompts, n_new, **kw)
+    _check_outputs(ref, prompts, n_new, ref.metrics)
+    emit(phase="serve_paged/nemotron", ok=True, tokens=tokens, **w.row(),
+         **_serve_stats(m), prefix_hits=m.prefix_hits,
+         state_snapshot_restores=m.state_snapshot_restores,
+         state_snapshot_seats=m.state_snapshot_seats,
+         paged_operator=m.paged_operator, paged_ffn=m.paged_ffn,
+         state_bytes_slot=m.state_bytes_slot,
+         state_slot_steps=m.state_slot_steps,
+         state_bytes_moved=m.state_bytes_moved,
+         state_snapshots_taken=m.state_snapshots_taken,
+         state_snapshot_rows_hwm=m.state_snapshot_rows_hwm,
+         moe_pairs_routed=m.moe_assignments,
+         moe_pairs_held=m.moe_pairs_held,
+         moe_latent_rows=m.moe_latent_rows, moe_row_dim=m.moe_row_dim,
+         moe_experts_live=m.moe_experts_live,
+         **_moe_mask_row(m, cfg.top_k, size.n_slots),
+         kv_write_path=m.paged_kv_write,
+         kv_page_rewrites_per_token=_rewrites_per_token(m),
+         attend_built=m.paged_decode_attend,
+         attend_dead_share=round(m.attend_dead_share, 4),
+         programs_traced=traced,
+         token_mismatch_vs_dense=_mismatch_vs_dense(outs, ref, prompts),
          **_record_row(m, again.metrics))
 
 

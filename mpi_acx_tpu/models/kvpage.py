@@ -53,8 +53,8 @@ cold path (docs/DESIGN.md §19).
 
 What the plane asks of a model family it asks through ONE seam,
 :class:`PagedSpec` (``family.paged_spec(cfg)``): per layer an operator
-kind (attention | conv | mamba), an FFN kind (dense | moe) and a cache kind
-(pages | state), the layers grouped into :class:`Segment` s of whole
+kind (attention | conv | mamba | mamba2 | none), an FFN kind (dense | moe |
+none) and a cache kind (pages | state | none), the layers grouped into :class:`Segment` s of whole
 periods that one ``lax.scan`` each can ride, and the family's own
 functions for each piece. :func:`paged_decode_step`,
 ``serving.paged_prefill`` / ``paged_suffix_prefill`` and
@@ -384,11 +384,18 @@ class SnapshotStore:
 
 @dataclasses.dataclass(frozen=True)
 class LayerKind:
-    """What one layer is to the paged plane."""
-    # "attention" | "latent_attention" (pages) | "conv" | "mamba" (state)
+    """What one layer is to the paged plane: an operator on the
+    sequence, then a feed-forward part. A family whose layers are ONE of
+    the two (``nemotron_h``: a mixer OR a feed-forward part, each with
+    its own norm and residual) says ``"none"`` for the other: a layer
+    with no FFN ends behind its operator, a layer with no operator
+    (``cache`` ``"none"`` too: it keeps nothing of the past) is its FFN
+    alone."""
+    # "attention" | "latent_attention" (pages) | "conv" | "mamba" |
+    # "mamba2" (state) | "none"
     operator: str = "attention"
-    ffn: str = "dense"               # "dense" | "moe"
-    cache: str = "pages"             # "pages" | "state"
+    ffn: str = "dense"               # "dense" | "moe" | "none"
+    cache: str = "pages"             # "pages" | "state" | "none"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -493,6 +500,11 @@ class PagedSpec:
     # (the pools' lesson; at 403 MB an expert matrix stack, two thirds
     # of a decode step: PERF.md, PR 31).
     moe_whole: Tuple[str, ...] = ()
+    # Width of the row a routed (token, expert) pair carries where that
+    # is not the model's: the experts work in a LATENT, and what an
+    # exchange between chips would carry is the latent row (0: the
+    # model's width).
+    moe_row_dim: int = 0
     kv_int8: bool = True                 # int8 pages wired for it
     # (ffn kind, implementation) pairs: ServingMetrics.paged_ffn
     ffn_built: Tuple[Tuple[str, str], ...] = (("dense", "dense"),)
@@ -526,7 +538,7 @@ class PagedSpec:
         if what == "ffn":
             return "+".join(f"{k}:{impl}" for k, impl in self.ffn_built)
         return "+".join(sorted({k.operator for s in self.segments
-                                for k in s.period}))
+                                for k in s.period} - {"none"}))
 
 
 def paged_spec(family, cfg) -> PagedSpec:
@@ -711,7 +723,7 @@ def paged_decode_step(params, cfg, state, token, page_tokens: int,
                        layer=at, stage=stage, left=state.get("left"),
                        v_dim=spec.v_dim, scale=spec.attn_scale)
             x = spec.attn_out(cfg, lp, x, o)
-        else:
+        elif kind.cache == "state":
             x, held = spec.state_op(cfg, lp, x, rest["held"], at)
             rest = dict(rest, held=held)
         if kind.ffn == "moe":
@@ -721,7 +733,7 @@ def paged_decode_step(params, cfg, state, token, page_tokens: int,
                 rest = dict(rest, moe=rest["moe"] + _moe_tally(
                     routed[0], state["owns"], spec.n_experts,
                     spec.experts_held, *routed[1:], live=live))
-        else:
+        elif kind.ffn != "none":
             x = spec.ffn(cfg, lp, x, kind.ffn)
         return x, pools, rest
 
@@ -730,7 +742,7 @@ def paged_decode_step(params, cfg, state, token, page_tokens: int,
     for seg in spec.segments:
         stacked = params[seg.key]
         n_pg = sum(k.cache == "pages" for k in seg.period)
-        n_st = len(seg.period) - n_pg
+        n_st = sum(k.cache == "state" for k in seg.period)
 
         def body(carry, i, seg=seg, stacked=stacked, n_pg=n_pg, n_st=n_st,
                  pages_at=pages_at, states_at=states_at):

@@ -439,7 +439,7 @@ def select_grouped_matmul():
 
 
 def sorted_expert_ffn(x, w1, w3, w2, idx, p, first: int = 0,
-                      grouped_matmul=None, layer=None, live=None):
+                      grouped_matmul=None, layer=None, live=None, act=None):
     """SwiGLU experts over sorted rows, no drop: x [T, d]; ``idx``,
     ``p`` [T, k] the routing over ALL experts; ``w1``, ``w3`` [n, d, f]
     and ``w2`` [n, f, d] the experts ``first .. first + n - 1`` held
@@ -480,14 +480,18 @@ def sorted_expert_ffn(x, w1, w3, w2, idx, p, first: int = 0,
     sizes = jnp.zeros((n + 1,), jnp.int32).at[key].add(1)[:n]
     if layer is not None:
         groups = w1.shape[0] * n
-        w1, w3, w2 = (w.reshape((groups,) + w.shape[2:])
+        w1, w3, w2 = (w if w is None else w.reshape((groups,) + w.shape[2:])
                       for w in (w1, w3, w2))
         sizes = lax.dynamic_update_slice(jnp.zeros((groups,), jnp.int32),
                                          sizes, (layer * n,))
     xs = x[order // k]
     h = grouped_matmul(xs, w1, sizes)
-    g = grouped_matmul(xs, w3, sizes)
-    y = grouped_matmul((jax.nn.silu(h) * g).astype(x.dtype), w2, sizes)
+    if w3 is None:
+        h = act(h)
+    else:
+        g = grouped_matmul(xs, w3, sizes)
+        h = jax.nn.silu(h) * g
+    y = grouped_matmul(h.astype(x.dtype), w2, sizes)
     w = jnp.where(held, p.reshape(-1), 0.0)[order]
     y = jnp.where(held[order][:, None], y * w[:, None], 0.0)
     back = jnp.zeros_like(order).at[order].set(jnp.arange(T * k))

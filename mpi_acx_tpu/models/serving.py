@@ -150,6 +150,9 @@ class ServingMetrics:
     moe_pairs_held: int = 0
     moe_group_hits: int = 0
     moe_experts_held: int = 0
+    # Where the experts work in a latent (PagedSpec.moe_row_dim): its
+    # width (``moe_latent_rows`` below: the rows of it dispatched).
+    moe_row_dim: int = 0
     # (pairs, experts hit, fullest, layer-steps[, pairs held, group
     # hits], pairs dead) of each decode chunk
     moe_by_chunk: List[tuple] = field(default_factory=list)
@@ -161,6 +164,13 @@ class ServingMetrics:
     # held at once, rows taken from the least recently used page.
     conv_tail_restores: int = 0
     state_bytes_slot: int = 0
+    # ... and the seats that began from one (a restore whose prefill
+    # failed seats nobody); the slot-steps of each decode chunk that
+    # could still deliver a token (``RequestBook.left`` clipped to the
+    # chunk): the state a chunk is ASKED to move is theirs; the chunk
+    # itself moves every slot's, idle or not.
+    state_snapshot_seats: int = 0
+    state_steps_by_chunk: List[int] = field(default_factory=list)
     # paged: bytes a token keeps in the page pools over all the layers
     # with pages (from the pools' shapes: K and V of every K/V head and
     # an int8 cache's scales, or a latent pool's one row)
@@ -200,6 +210,32 @@ class ServingMetrics:
     # (kvpage.programs_traced): 10-20 in a process's first call, 0 in
     # every later one with the same static arguments and shapes.
     programs_traced: int = 0
+
+    @property
+    def state_snapshot_restores(self) -> int:
+        """``conv_tail_restores`` under the name of what it is."""
+        return self.conv_tail_restores
+
+    @property
+    def state_slot_steps(self) -> int:
+        """Delivering slot-steps over the call's decode chunks."""
+        return sum(self.state_steps_by_chunk)
+
+    @property
+    def state_bytes_moved(self) -> int:
+        """Bytes of state the decode chunks were asked to move: a
+        delivering slot-step reads and writes ``state_bytes_slot``."""
+        return 2 * self.state_slot_steps * self.state_bytes_slot
+
+    @property
+    def moe_latent_rows(self) -> int:
+        """Latent rows dispatched to experts held here (the computed
+        pairs: what an exchange between chips would carry); 0 where the
+        experts work at the model's width."""
+        if not self.moe_row_dim:
+            return 0
+        return (self.moe_pairs_held if self.moe_experts_held
+                else self.moe_assignments)
 
     @property
     def attend_live_share(self) -> float:
@@ -1440,7 +1476,8 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
                        max_request_retries, rejected, on_token=on_token)
     queue, owner, slo = book.queue, book.owner, book.slo
     n_preempts = n_slo_defer = pages_walked = pages_dead = pages_grid = 0
-    rewritten = staged = 0
+    rewritten = staged = snapshot_seats = 0
+    state_steps: List[int] = []     # delivering slot-steps, a chunk
     # Requests currently evicted by page pressure: membership here turns
     # the next successful seat into a journey "resume" event.
     preempted_rids: set = set()
@@ -1467,7 +1504,7 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
         gate deferred (request left at the queue head), the pool could
         not cover the prompt (ditto — a retire will free pages), or
         the prefill failed (request re-queued via the retry rules)."""
-        nonlocal n_slo_defer
+        nonlocal n_slo_defer, snapshot_seats
         rid = queue[0]
         prompt = book.prompts[rid]
         with ph("refill.match", rid=rid):
@@ -1537,6 +1574,7 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
                 _span_app_end_best_effort()
         with ph("refill.seat", rid=rid):
             pkv.seat(b, hit_pages, fresh, S, rid=rid, state=end)
+            snapshot_seats += bool(hit_pages and pkv.snaps is not None)
             if pkv.prefix is not None:
                 pkv.prefix.insert(prompt, pkv.pages[b])
             if rid in preempted_rids:
@@ -1669,6 +1707,8 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
                 stepped.hand_over()
                 state, toks, keys = step_fn(state, last_tok, keys)
                 pkv.absorb(state)
+                if pkv.held is not None:
+                    state_steps.append(int(np.clip(left, 0, chunk).sum()))
             except Exception as exc:  # noqa: BLE001 — any device failure
                 book.step_failed(exc)
                 # The step donated the pool buffers: rebuild from zeros and
@@ -1711,8 +1751,11 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
                 p.shape[0] * p.shape[2] * p.shape[3] * p.dtype.itemsize
                 for p in pkv.pool.values()),
             moe_by_chunk=list(pkv.moe_chunks),
+            moe_row_dim=pkv.spec.moe_row_dim,
             conv_tail_restores=pkv.tail_restores,
             state_bytes_slot=pkv.spec.state_bytes_slot,
+            state_snapshot_seats=snapshot_seats,
+            state_steps_by_chunk=state_steps,
             **({} if pkv.snaps is None else dict(
                 state_snapshots_taken=pkv.snaps.taken,
                 state_snapshot_rows_hwm=pkv.snaps.rows_hwm,

@@ -172,10 +172,21 @@ def test_phase_runs_at_tiny_size(phase, capsys, loopback_runtime_closed):
         assert row["moe_pairs_held"] == 2 * row["moe_group_hits"]
         assert row["kv_bytes_token"] == 3 * 2 * 48
         assert row["prefix_hits"] >= 2 and row["programs_traced"][1] == 0
+        row = by["serve_paged/nemotron"]
+        assert row["paged_operator"] == "attention+mamba2"
+        assert row["paged_ffn"].startswith(
+            "moe:_shared_ffn+latent:sorted_expert_ffn/")
+        assert row["state_snapshot_seats"] >= 2 <= row["prefix_hits"]
+        assert 0 < row["moe_latent_rows"] == row["moe_pairs_held"] \
+            < row["moe_pairs_routed"]
+        assert row["state_bytes_moved"] == 2 * row["state_slot_steps"] \
+            * row["state_bytes_slot"] > 0
+        assert row["programs_traced"][1] == 0
         # the span record of every leg: what a call kept, no wait far
         # above its like in the warm call's few spans unless the machine
         # stopped, and the first call's programs by name
-        for name in ("bf16", "int8", "lfm2", "jamba", "gigachat"):
+        for name in ("bf16", "int8", "lfm2", "jamba", "gigachat",
+                     "nemotron"):
             row = by["serve_paged/" + name]
             assert row["spans"] >= 5 * row["prefills"] + 5 * row["steps"]
             assert len(row["stalls"]) == len(row["stall_ms"]) == 2

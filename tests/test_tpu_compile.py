@@ -980,3 +980,211 @@ def test_gigachat_prefill_compiles_for_v5e(bucket, history, v5e):
     assert f" = bf16[64,{bucket},192]" in text
     assert f"f32[{8 * min(bucket, 2048)},2048]" in text and "%gmm" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1.6 * 2 ** 30
+
+
+# -- layers that are ONE mixer; a state of 3-D heads; latent experts (PR 44) --
+
+NEMO_B, NEMO_PAGES, NEMO_LEN = 96, 1536 + 96, 5120          # + parking pages
+_NEMO_STATE = (128, 64, 128)
+
+
+def _nemotron_cut():
+    """The benchmark's cut of Nemotron-3-Super at published widths: one
+    chip of four of stage 0 (MEMEMEM*EME, 128 of the router's 512
+    experts, a quarter of the vocabulary), bf16 weights. Shapes only."""
+    from mpi_acx_tpu.models import nemotron_h
+    cfg = nemotron_h.NemotronHConfig(vocab=32768, pattern="MEMEMEM*EME",
+                                     experts_held=128)
+    params = jax.eval_shape(lambda: nemotron_h.cast_params(
+        nemotron_h.init_params(jax.random.key(0), cfg)))
+    return nemotron_h, cfg, params
+
+
+def _ssd_case(name):
+    """(function, argument shapes) at the published widths: 128 heads of
+    64 values x 128 state numbers in 8 groups, 5 layers, 96 slots."""
+    from mpi_acx_tpu.ops import ssd
+    (H, P, N), G, bf16 = _NEMO_STATE, 8, jnp.bfloat16
+    if name == "update":
+        return ssd.ssd_update, [
+            _s((5, NEMO_B, H, P, N), _F32), _s((), jnp.int32),
+            _s((NEMO_B, H), _F32), _s((NEMO_B, H, P), bf16),
+            _s((NEMO_B, G, N), bf16), _s((NEMO_B, G, N), bf16),
+            _s((H,), _F32)]
+    S = int(name.split("_")[1])
+    return (lambda *a: ssd.ssd_scan(*a, snapshot=512, chunk=PAGE)), [
+        _s((S, H, P), bf16), _s((S, H), _F32), _s((S, G, N), bf16),
+        _s((S, G, N), bf16), _s((H,), _F32), _s((H, P, N), _F32)]
+
+
+def _ssd_state_movers(text):
+    """Instructions of a compiled program that mention the slots'
+    stacked Mamba-2 state (2.06 GB) or one layer of it and are anything
+    but plumbing or a Mosaic call."""
+    sized = re.compile(rf"f32\[(?:5,)?{NEMO_B},128,64,128\]")
+    plumbing = {"parameter", "get-tuple-element", "tuple", "while",
+                "bitcast", "custom-call"}
+    found = []
+    for line in text.splitlines():
+        op = re.search(r"\s([a-z][a-z0-9-]*)\(", line)
+        if (" = " in line and op and op.group(1) not in plumbing
+                and sized.search(line.split(" = ", 1)[1].split("(")[0])):
+            found.append(line.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("name", ["update", "scan_32", "scan_512",
+                                  "scan_5120"])
+def test_ssd_kernels_compile_for_v5e(name, v5e):
+    """``ops/ssd.py``'s two Pallas calls at the published widths: the
+    update with the 2.06 GB stacked state aliased to its result and the
+    layer a prefetched scalar (a lane rotated to a group's heads, one
+    lane a head broadcast down a ``[64, 128]`` state, the read-out one
+    product a group); the chunked scan over a bucket shorter than a
+    chunk (padded), of one snapshot, and of the cold bucket's ten."""
+    fn, args = _ssd_case(name)
+    donate = (0,) if name == "update" else ()
+    compiled = jax.jit(fn, donate_argnums=donate).lower(
+        *_place(args, v5e)).compile()
+    text = compiled.as_text()
+    kernel = "%ssd_update" if name == "update" else "%ssd_scan"
+    assert kernel in text and "tpu_custom_call" in text
+    if name == "update":
+        # in place: the state is neither copied in front of the call
+        # nor allocated a second time behind it
+        assert not _ssd_state_movers(text), "\n".join(_ssd_state_movers(text))
+        assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    else:
+        S = int(name.split("_")[1])
+        # whole chunks; a row more than the snapshots kept
+        assert f"f32[{-(-S // PAGE) * PAGE},8192]" in text
+        assert (f"f32[{S // 512 + 1},128,64,128]" in text) == bool(S // 512)
+
+
+def test_nemotron_decode_chunk_compiles_and_moves_no_state(v5e):
+    """``paged_decode_chunk`` as a serve call binds it for the Nemotron
+    cell's geometry (96 slots, 1,632 pages of 128 tokens, layers that are
+    a mixer OR an expert layer alone: three ``ssd_update`` and three
+    pairs of grouped matmuls in the program, one of each inside the
+    ``(M E) x 3`` scan): the shared write and the live-page walk at 2
+    K/V heads of 128 under 32 query heads, the latent experts' TWO
+    grouped matmuls with the result shapes the benchmark's reader
+    matches (2,112 pairs padded to 17 row tiles), no instruction that
+    moves a pool, the stacked state (2.06 GB), a layer of it, or an
+    expert stack, and temporaries far below a chip. (With ONE layer of
+    pages XLA keeps the stage's zero fill as a select on the chunk's
+    first step, inside the loop: ``chip_smoke.py`` holds the served
+    tokens to the dense pair's.)"""
+    nemotron_h, cfg, params = _nemotron_cut()
+    spec = kvpage.paged_spec(nemotron_h, cfg)
+    pool = jax.eval_shape(lambda: kvpage.init_page_pool(
+        cfg, NEMO_PAGES - NEMO_B, PAGE, NEMO_B, spec=spec))
+    assert pool["k"].shape == (1, NEMO_PAGES, 2, 128, PAGE)
+    held = jax.tree.map(lambda l: _s((5, NEMO_B) + l.shape, l.dtype),
+                        spec.state)
+    assert held["ssm"].shape == (5, NEMO_B) + _NEMO_STATE
+    assert held["conv"].shape == (5, NEMO_B, 3 * 10240)
+    state = dict(k=pool["k"], v=pool["v"],
+                 table=_s((NEMO_B, NEMO_LEN // PAGE), jnp.int32),
+                 pos=_s((NEMO_B,), jnp.int32),
+                 left=_s((NEMO_B,), jnp.int32), held=held,
+                 owns=_s((NEMO_B,), jnp.bool_), moe=_s((7,), jnp.int32))
+    keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0),
+                                                   NEMO_B))
+    step = kvpage.make_paged_step_fn(params, cfg, nemotron_h, XL_CHUNK, PAGE)
+    compiled = step.func.lower(
+        *_place([*step.args, state, _s((NEMO_B,), jnp.int32), keys], v5e),
+        **step.keywords).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"%([a-z_]+)[.0-9]* = (\(?[a-z0-9]+\[[0-9,]*\])",
+                       "\n".join(l for l in text.splitlines()
+                                 if "tpu_custom_call" in l))
+    assert sorted(calls) == sorted(
+        [("ssd_update", "(f32[96,64,128]")] * 3
+        + [("gmm", "f32[2176,2688]"), ("gmm", "f32[2176,1024]")] * 3
+        + [("paged_flash_decode_attend", "bf16[96,2,16,128]"),
+           ("paged_kv_write", "(bf16[1,1632,2,128,128]")])
+    assert not _pool_movers(text, pool["k"].shape)
+    _flush_is_behind_the_steps(step, state, NEMO_B, keys)
+    assert not _ssd_state_movers(text), "\n".join(_ssd_state_movers(text))
+    stack = re.compile(r" = bf16\[(?:[13],)?128,(?:1024,2688|2688,1024)\]"
+                       r".*?\s(?!(?:parameter|get-tuple-element|bitcast)\()"
+                       r"[a-z][a-z0-9-]*\(")
+    moved = [l.strip()[:160] for l in text.splitlines() if stack.search(l)]
+    assert not moved, "\n".join(moved)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("bucket,history", [(5120, 0), (256, 4096)])
+def test_nemotron_prefill_compiles_for_v5e(bucket, history, v5e):
+    """``serving.paged_prefill`` at the cell's ONE cold bucket (8,192
+    capped at ``max_len``) and ``paged_suffix_prefill`` behind 32 hit
+    pages and a restored snapshot: ``ssd_scan`` over whole chunks, the
+    latent experts a block of 1,024 tokens at a time (22 sorted rows a
+    token), flash attention on the cold bucket, and temporaries that fit
+    beside 9.3 GB of weights, 2.04 GB of state, 1.36 GB of snapshots and
+    0.2 GB of pages."""
+    from mpi_acx_tpu.models import serving
+    nemotron_h, cfg, params = _nemotron_cut()
+    kw = dict(cfg=cfg, family=nemotron_h, kv_int8=False, on_tpu=True,
+              page_tokens=PAGE)
+    if history:
+        hk = _s((1, 2, 128, history), jnp.bfloat16)
+        tail = jax.tree.map(lambda l: _s((5,) + l.shape, l.dtype),
+                            kvpage.paged_spec(nemotron_h, cfg).state)
+        compiled = serving.paged_suffix_prefill.lower(
+            *_place([params, _s((1, bucket), jnp.int32), hk, hk, tail,
+                     _s((), jnp.int32)], v5e), **kw).compile()
+    else:
+        compiled = serving.paged_prefill.lower(
+            *_place([params, _s((1, bucket), jnp.int32), _s((), jnp.int32)],
+                    v5e), **kw).compile()
+    text = compiled.as_text()
+    assert "%ssd_scan" in text and f"f32[{bucket},8192]" in text
+    rows = 22 * min(bucket, 1024)
+    assert f"f32[{rows},2688]" in text and f"f32[{rows},1024]" in text
+    assert ("%flash_attention" in text) == (not history)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.4e9
+
+
+# sha256 (16 hex) of each family's decode chunk as a jaxpr, off the chip
+# (the dense pair), tiny preset, 2 slots, pages of 16, chunk 4: read at
+# PR 44's parent commit and again on its tree. To read them again:
+# ``_chunk_digest(name)`` below, in a checkout of the commit to pin.
+_CHUNK_DIGESTS = {"gpt2": "b81a421f78dcef1f", "lfm2": "ddd3e63f20a11fc1",
+                  "jamba": "959ebaf1b15f03f0", "gigachat": "0b576a3c3712b233"}
+
+
+def _chunk_digest(name):
+    import hashlib
+    from mpi_acx_tpu.models import gigachat, jamba, lfm2
+    family, cfg = {
+        "gpt2": (None, tfm.tiny_config()), "lfm2": (lfm2, lfm2.tiny_lfm2()),
+        "jamba": (jamba, jamba.tiny_jamba()),
+        "gigachat": (gigachat, gigachat.tiny_gigachat(
+            experts_first=4, experts_held=4))}[name]
+    fam = family or tfm
+    params = fam.cast_params(fam.init_params(jax.random.key(0), cfg))
+    pkv = kvpage.PagedKV(cfg, family, 2, 64, 16, 8)
+    state = jax.eval_shape(
+        lambda: pkv.device_state(jnp.zeros((2,), jnp.int32)))
+    keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0), 2))
+    jaxpr = jax.make_jaxpr(
+        lambda p, s, t, k: kvpage.paged_decode_chunk.__wrapped__(
+            p, s, t, k, cfg=cfg, chunk=4, page_tokens=16, on_tpu=False,
+            family=family))(params, state, _s((2,), jnp.int32), keys)
+    return hashlib.sha256(
+        re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr)).encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("name", sorted(_CHUNK_DIGESTS))
+def test_the_other_families_chunk_programs_are_the_parents(name,
+                                                           monkeypatch):
+    """What PR 44 taught the shared plane (a ``LayerKind`` with no FFN or
+    no operator, ``sorted_expert_ffn(w3=None)``, a stage of one layer
+    rewritten whole) is branches in Python on what a family's spec and
+    shapes say: the decode chunk GPT-2, LFM2, Jamba and GigaChat trace
+    is, equation for equation, the one they traced at the parent. A PR
+    that MEANS to change a family's chunk reads the digest anew."""
+    monkeypatch.setattr(backend, "on_tpu", lambda: False)
+    assert _chunk_digest(name) == _CHUNK_DIGESTS[name]
