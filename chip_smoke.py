@@ -650,6 +650,12 @@ def _serve_paged_nemotron(size: Size, seed: int):
              and m.state_bytes_moved == 2 * m.state_slot_steps
              * m.state_bytes_slot,
              f"state_slot_steps={m.state_slot_steps}")
+    # every slot-step of the chunks either delivers or was left alone
+    _require(m.state_steps_dead > 0 and m.state_slot_steps
+             + m.state_steps_dead == m.decode_slot_steps,
+             f"state_steps_dead={m.state_steps_dead}, "
+             f"state_slot_steps={m.state_slot_steps}, "
+             f"decode_slot_steps={m.decode_slot_steps}")
     again = serving.serve_paged_greedy(params, cfg, prompts, n_new, **kw)
     traced = [m.programs_traced, again.metrics.programs_traced]
     _require(traced[0] > 0 and traced[1] == 0
@@ -666,6 +672,8 @@ def _serve_paged_nemotron(size: Size, seed: int):
          paged_operator=m.paged_operator, paged_ffn=m.paged_ffn,
          state_bytes_slot=m.state_bytes_slot,
          state_slot_steps=m.state_slot_steps,
+         state_steps_dead=m.state_steps_dead,
+         state_dead_share=round(m.state_dead_share, 4),
          state_bytes_moved=m.state_bytes_moved,
          state_snapshots_taken=m.state_snapshots_taken,
          state_snapshot_rows_hwm=m.state_snapshot_rows_hwm,

@@ -295,13 +295,14 @@ def _mamba_seq(cfg: JambaConfig, lp: Params, x: jax.Array, before, h0,
     return x + y.astype(x.dtype) @ _w(lp, "w_out", x.dtype), us, snaps, end
 
 
-def _mamba_step(cfg: JambaConfig, lp: Params, x: jax.Array, held, at):
+def _mamba_step(cfg: JambaConfig, lp: Params, x: jax.Array, held, at,
+                live=None):
     """``PagedSpec.state_op``: one token a slot, x [B, 1, d], against
     the slots' state of ALL the Mamba layers, ``held`` = {"conv": [L, B,
     taps * C], "ssm": [L, B, N, C]}, of which this layer is ``at``. The
     scan's state goes to ``ssm_update`` whole and comes back updated in
     place; the window (30 KB a slot) is read and written back by XLA's
-    own fusions."""
+    own fusions. ``live`` is not read: every slot's state moves."""
     taps = cfg.mamba_d_conv - 1
     u, z = jnp.split(rmsnorm(x[:, 0], lp["norm1"], cfg.norm_eps)
                      @ _w(lp, "w_in", x.dtype), 2, axis=-1)

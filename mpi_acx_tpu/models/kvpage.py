@@ -445,13 +445,16 @@ class PagedSpec:
     * ``qkv(cfg, lp, x, pos) -> q [B, 1, Hq, Dh], k, v [B, 1, Hkv,
       Dh]``, as they go into the cache (positions applied)
     * ``attn_out(cfg, lp, x, o) -> x``: residual + output projection
-    * ``state_op(cfg, lp, x, held, at) -> (x, held)``: the operator of
-      a ``state`` layer, residual included. ``held`` is the slots'
-      state of ALL the state layers, ``[L_state, B, *leaf]`` a leaf of
-      ``state``, and ``at`` this layer's index in it: the operator
-      reads and writes ``(at, slot)`` and hands the stack back whole (a
-      small state may slice its layer out and put it back; one of a
-      GB goes to a Pallas call whole, aliased to its result)
+    * ``state_op(cfg, lp, x, held, at, live=None) -> (x, held)``: the
+      operator of a ``state`` layer, residual included. ``held`` is the
+      slots' state of ALL the state layers, ``[L_state, B, *leaf]`` a
+      leaf of ``state``, and ``at`` this layer's index in it: the
+      operator reads and writes ``(at, slot)`` and hands the stack back
+      whole (a small state may slice its layer out and put it back; one
+      of a GB goes to a Pallas call whole, aliased to its result).
+      ``live`` ([B] bool, or None) as an expert layer's: the state of a
+      slot that is not is read by nobody afterwards and need not move;
+      an operator that makes use of it says so (``state_live``)
     * ``ffn(cfg, lp, x, kind) -> x``, and ``(x, idx [B, k])``, the
       experts each row chose, when ``kind`` is ``"moe"``; such a layer
       also takes ``live=`` ([B] bool, or None: the rows whose result
@@ -489,6 +492,9 @@ class PagedSpec:
     state: Any = None
     # Which whole prompt pages get a snapshot row: every n-th.
     snapshot_every: int = 1
+    # ``state_op`` reads its ``live``: a dead slot-step moves no state
+    # (the step program then counts them, ``state['state_dead']``).
+    state_live: bool = False
     n_experts: int = 0                   # of a "moe" FFN's router
     # The experts held HERE where that is a share of the router's
     # (``(first, count)``; None: all): the routing counters then also
@@ -634,11 +640,17 @@ def paged_decode_step(params, cfg, state, token, page_tokens: int,
     (``step >= left[b]``; a step by itself is step 0): the attend
     fetches and folds nothing for them and their rows are zeros, the
     expert layer routes none of their pairs to an expert, so an expert
-    that only they chose is not read, and adds zeros to their residual.
+    that only they chose is not read, and adds zeros to their residual,
+    and a state operator that reads ``live`` (``PagedSpec.state_live``:
+    Mamba-2's ``ssd_update``) neither reads nor writes their state and
+    adds what a read-out of zeros gives; with ``'state_dead'`` (a
+    scalar) the step counts them there, once whatever the state layers.
     Absent, every slot is live. Nothing else reads it: a dead slot's
-    token still runs the dense weights, the router and a family's
-    state operators (finite, dropped by the loop), its K/V is staged
-    and flushed, its state moves.
+    token still runs the dense weights, the router and the projections
+    and short conv of a state layer (finite, dropped by the loop), its
+    K/V is staged and flushed, and in a family whose state operator
+    ignores ``live`` (Jamba's ``ssm_update``, LFM2's conv) its state
+    moves.
 
     An attention layer's fresh K/V for slot b lands at ``pool[i,
     table[b, pos_b // pt], :, :, pos_b % pt]``, ``i`` counting the
@@ -696,6 +708,10 @@ def paged_decode_step(params, cfg, state, token, page_tokens: int,
     # [B] bool: the slots that can still deliver a token at this step
     live = ((0 if step is None else step) < state["left"]
             if "left" in state else None)
+    state_dead = state.get("state_dead")
+    if state_dead is not None and live is not None:
+        # the slot-steps whose state the state operators leave alone
+        state_dead = state_dead + (~live).sum().astype(jnp.int32)
 
     def nth(base, i, stride, j):
         """``base + i * stride + j`` without the identities."""
@@ -724,7 +740,8 @@ def paged_decode_step(params, cfg, state, token, page_tokens: int,
                        v_dim=spec.v_dim, scale=spec.attn_scale)
             x = spec.attn_out(cfg, lp, x, o)
         elif kind.cache == "state":
-            x, held = spec.state_op(cfg, lp, x, rest["held"], at)
+            x, held = spec.state_op(cfg, lp, x, rest["held"], at,
+                                    live=live)
             rest = dict(rest, held=held)
         if kind.ffn == "moe":
             # (idx, and the routing groups a group-limited router kept)
@@ -782,6 +799,8 @@ def paged_decode_step(params, cfg, state, token, page_tokens: int,
     for k in ("owns", "left"):
         if k in state:
             out[k] = state[k]
+    if state_dead is not None:
+        out["state_dead"] = state_dead
     return spec.head(params, cfg, x), out
 
 
@@ -979,6 +998,8 @@ class PagedKV:
         # _moe_tally's) and the snapshots loaded to continue a sequence.
         self.held = self._fresh_held()
         self.moe_chunks: List[Tuple[int, ...]] = []     # one a chunk
+        # ... and the slot-steps its state operators were told are dead
+        self.state_dead_chunks: List[int] = []
         self.tail_restores = 0
         # Slot b's parking page sits past the allocator's range.
         self._park = [n_pages + b for b in range(n_slots)]
@@ -1023,6 +1044,8 @@ class PagedKV:
             state["left"] = jnp.asarray(left, jnp.int32)
         if self.held is not None:
             state["held"] = self.held
+        if self.spec.state_live:
+            state["state_dead"] = jnp.zeros((), jnp.int32)
         if self.spec.n_experts:
             # A slot owns a request exactly while it holds pages.
             state["owns"] = jnp.asarray([bool(p) for p in self.pages])
@@ -1042,6 +1065,8 @@ class PagedKV:
         if "moe" in state:
             self.moe_chunks.append(tuple(int(n) for n in
                                          np.asarray(state["moe"])))
+        if "state_dead" in state:
+            self.state_dead_chunks.append(int(state["state_dead"]))
 
     def reset_pool(self) -> None:
         """Rebuild the device pool from zeros (after a failed donated

@@ -437,8 +437,9 @@ def paged_spec(cfg: Lfm2Config) -> kvpage.PagedSpec:
     snapshot of it with every whole prompt page: 8 KB beside a page's
     512 KB), the router's width for the routing counters. int8 pages
     are not wired: the tails would want a precision of their own."""
-    def decode_conv(cfg, lp, x, held, at):
-        # (a layer of it is 0.5 MB at 64 slots: sliced out and put back)
+    def decode_conv(cfg, lp, x, held, at, live=None):
+        # (a layer of it is 0.5 MB at 64 slots: sliced out and put back,
+        # every slot's: ``live`` is not read)
         st = lax.dynamic_index_in_dim(held, at, 0, keepdims=False)
         x, zs = _conv_op(cfg, lp, x, st)
         st = zs[:, -(cfg.conv_L_cache - 1):]

@@ -333,13 +333,16 @@ def _mamba_seq(cfg: NemotronHConfig, lp: Params, x: jax.Array, before, h0,
     return x + _mixer_out(cfg, lp, y, xs, z, x.dtype), us, snaps, end
 
 
-def _mamba_step(cfg: NemotronHConfig, lp: Params, x: jax.Array, held, at):
+def _mamba_step(cfg: NemotronHConfig, lp: Params, x: jax.Array, held, at,
+                live=None):
     """``PagedSpec.state_op``: one token a slot, x [B, 1, d], against
     the slots' state of ALL the Mamba layers, ``held`` = {"conv": [L, B,
     taps * conv_dim], "ssm": [L, B, H, P, N]}, of which this layer is
     ``at``. The recurrence's state goes to ``ssd_update`` whole and comes
-    back updated in place; the window (61 KB a slot) is read and written
-    back by XLA's own fusions."""
+    back updated in place, the rows of the slots that are ``live`` ([B]
+    bool; None: all) alone: a dead slot's 4.19 MB stay where they are
+    and its read-out is zeros. The window (61 KB a slot) is read and
+    written back by XLA's own fusions, every slot's."""
     taps = cfg.conv_kernel - 1
     z, u, dt = _split_in(cfg, rmsnorm(x[:, 0], lp["norm1"], cfg.norm_eps)
                          @ _w(lp, "w_in", x.dtype))
@@ -349,9 +352,11 @@ def _mamba_step(cfg: NemotronHConfig, lp: Params, x: jax.Array, held, at):
     conv = lax.dynamic_update_index_in_dim(
         held["conv"], jnp.concatenate(win[1:] + [u], axis=-1).astype(
             held["conv"].dtype), at, 0)
+    # (``live`` by position: ``control_nemotron``'s wrapper of the update
+    # hands its arguments on as ``*a``)
     y, h = ssd.select_ssd(cfg.ssm_kernel)[0](
         held["ssm"], at, _dt(lp, dt), xs, b, c,
-        -jnp.exp(lp["A_log"].astype(F32)))
+        -jnp.exp(lp["A_log"].astype(F32)), live)
     out = _mixer_out(cfg, lp, y, xs, z, x.dtype)
     return x + out[:, None], {"conv": conv, "ssm": h}
 
@@ -596,7 +601,7 @@ def paged_spec(cfg: NemotronHConfig) -> kvpage.PagedSpec:
                "ssm": jax.ShapeDtypeStruct(
                    (cfg.mamba_heads, cfg.mamba_head_dim, cfg.ssm_state),
                    F32)},
-        snapshot_every=cfg.snapshot_every, kv_int8=False,
+        snapshot_every=cfg.snapshot_every, state_live=True, kv_int8=False,
         n_experts=cfg.n_experts,
         experts_held=(cfg.experts_first, cfg.n_held),
         moe_whole=_EXPERT_STACKS, moe_row_dim=cfg.moe_latent,

@@ -167,10 +167,16 @@ class ServingMetrics:
     # ... and the seats that began from one (a restore whose prefill
     # failed seats nobody); the slot-steps of each decode chunk that
     # could still deliver a token (``RequestBook.left`` clipped to the
-    # chunk): the state a chunk is ASKED to move is theirs; the chunk
-    # itself moves every slot's, idle or not.
+    # chunk): the state a chunk is ASKED to move is theirs. A state
+    # operator that reads ``live`` (``PagedSpec.state_live``: Mamba-2's
+    # ``ssd_update``) moves theirs alone, and ``state_steps_dead`` is
+    # what the step program counted of the others, once a slot-step
+    # whatever the state layers (with the delivering ones: every
+    # slot-step of the chunks); elsewhere (Jamba, LFM2) every slot's
+    # state moves, idle or not, and it is 0.
     state_snapshot_seats: int = 0
     state_steps_by_chunk: List[int] = field(default_factory=list)
+    state_steps_dead: int = 0
     # paged: bytes a token keeps in the page pools over all the layers
     # with pages (from the pools' shapes: K and V of every K/V head and
     # an int8 cache's scales, or a latent pool's one row)
@@ -220,6 +226,14 @@ class ServingMetrics:
     def state_slot_steps(self) -> int:
         """Delivering slot-steps over the call's decode chunks."""
         return sum(self.state_steps_by_chunk)
+
+    @property
+    def state_dead_share(self) -> float:
+        """Share of the decode chunks' slot-steps whose state a state
+        operator that reads ``live`` left where it was: how often
+        telling it engages (0 where the operator does not read it)."""
+        every = self.state_slot_steps + self.state_steps_dead
+        return self.state_steps_dead / every if every else 0.0
 
     @property
     def state_bytes_moved(self) -> int:
@@ -1756,6 +1770,7 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
             state_bytes_slot=pkv.spec.state_bytes_slot,
             state_snapshot_seats=snapshot_seats,
             state_steps_by_chunk=state_steps,
+            state_steps_dead=sum(pkv.state_dead_chunks),
             **({} if pkv.snaps is None else dict(
                 state_snapshots_taken=pkv.snaps.taken,
                 state_snapshot_rows_hwm=pkv.snaps.rows_hwm,

@@ -29,7 +29,9 @@ cum_s)``, the state carried in float32 between chunks.
   x`` and under it the decay), the kernel rotates its group's heads to
   the first lanes and broadcasts one lane a head; the read-out ``h C`` is
   one matrix product a group against ``C`` in every row, of which each
-  head keeps its own lane.
+  head keeps its own lane. Told which slots can still deliver a token
+  (``live``), the grid visits THEIR blocks and then stays where it is:
+  a dead slot's state is neither read nor written.
 * :func:`ssd_scan`: ONE Pallas call (``%ssd_scan``) over (group, chunk of
   ``chunk`` tokens in order), a group's state resident in VMEM between
   its chunks. It hands out the state after every ``snapshot`` tokens
@@ -86,58 +88,101 @@ def _first_lanes(t, g, hb: int):
 # -- the decode step's update -------------------------------------------------
 
 
-def ssd_update_ref(h, layer, dt, x, b, c, a):
+def ssd_update_ref(h, layer, dt, x, b, c, a, live=None):
     """``h`` [L, B, H, P, N] f32, the slots' state of every layer;
     ``dt`` [B, H]; ``x`` [B, H, P]; ``b``, ``c`` [B, G, N]; ``a`` [H]:
     one token a slot through layer ``layer``. Returns (y [B, H, P] f32,
-    ``h`` with that layer's rows replaced). Plain JAX: it slices the
-    layer out and puts it back."""
-    hl = lax.dynamic_index_in_dim(h, layer, 0, keepdims=False)
+    ``h`` with that layer's rows replaced). ``live`` ([B] bool; None:
+    every slot): a slot that is not keeps its rows of ``h`` as they
+    were, whatever they hold, and its ``y`` is zeros. Plain JAX: it
+    slices the layer out and puts it back."""
+    was = lax.dynamic_index_in_dim(h, layer, 0, keepdims=False)
     hl, y = jax.vmap(_token, in_axes=(0, 0, 0, 0, 0, None))(
-        hl, dt.astype(F32), x.astype(F32), b.astype(F32), c.astype(F32),
+        was, dt.astype(F32), x.astype(F32), b.astype(F32), c.astype(F32),
         a.astype(F32))
+    if live is not None:
+        y = jnp.where(live[:, None, None], y, 0.0)
+        hl = jnp.where(live[:, None, None, None], hl, was)
     return y, lax.dynamic_update_index_in_dim(h, hl, layer, 0)
 
 
-def _update_kernel(layer_ref, xt_ref, b_ref, c_ref, h_ref, yt_ref, out_ref):
-    g = pl.program_id(1)
+def _visit(live, B: int):
+    """[B + 1] int32, what the update's grid is told of ``live`` ([B]
+    bool; None: every slot): the slot whose blocks step ``i`` of axis 0
+    holds, and last how many are live. The live slots come first, in
+    their order; every later step is given the LAST live slot again, so
+    that it changes no block index and the pipeline copies nothing in
+    or out for it (slot 0 where none lives)."""
+    slots = jnp.arange(B, dtype=jnp.int32)
+    if live is None:
+        return jnp.append(slots, jnp.int32(B))
+    # a live slot's place among the live (two small fusions a call: a
+    # cumulative sum is five)
+    place = jnp.sum(live[None, :] & (slots[None, :] < slots[:, None]),
+                    axis=1, dtype=jnp.int32)
+    n = jnp.sum(live, dtype=jnp.int32)
+    held = live[None, :] & (place[None, :]
+                            == jnp.minimum(slots, n - 1)[:, None])
+    return jnp.append(jnp.sum(jnp.where(held, slots[None, :], 0), axis=1), n)
+
+
+def _update_kernel(layer_ref, visit_ref, xt_ref, b_ref, c_ref, h_ref, yt_ref,
+                   out_ref):
+    i, g = pl.program_id(0), pl.program_id(1)
+    n_live = visit_ref[visit_ref.shape[0] - 1]
     hb, P, N = h_ref.shape
     H = xt_ref.shape[1]
-    xr = _first_lanes(xt_ref[...], g, hb)       # [2P, H]: dt x; the decay
-    bg, cg = b_ref[pl.ds(g, 1), :], c_ref[pl.ds(g, 1), :]       # [1, N]
-    for j in range(hb):
-        out_ref[j] = (xr[P:, j:j + 1] * h_ref[j] + xr[:P, j:j + 1] * bg)
-    # h C for the whole group: C in every row, so that every lane of
-    # row (head, p) holds y[head, p]; a head keeps its own lane.
-    res = lax.dot_general(out_ref[...].reshape(hb * P, N),
-                          jnp.broadcast_to(cg, (H, N)), _NT, precision=_HI,
-                          preferred_element_type=F32).reshape(hb, P, H)
-    mine = (lax.broadcasted_iota(jnp.int32, (hb, P, H), 2)
-            == lax.broadcasted_iota(jnp.int32, (hb, P, H), 0))
-    part = jnp.sum(jnp.where(mine, res, 0.0), axis=0)           # [P, H]
-    if H != hb:
-        part = pltpu.roll(part, g * hb, 1)
 
-    @pl.when(g == 0)
+    @pl.when(i < n_live)
     def _():
-        yt_ref[...] = part
+        xr = _first_lanes(xt_ref[...], g, hb)   # [2P, H]: dt x; the decay
+        bg, cg = b_ref[pl.ds(g, 1), :], c_ref[pl.ds(g, 1), :]   # [1, N]
+        for j in range(hb):
+            out_ref[j] = (xr[P:, j:j + 1] * h_ref[j] + xr[:P, j:j + 1] * bg)
+        # h C for the whole group: C in every row, so that every lane of
+        # row (head, p) holds y[head, p]; a head keeps its own lane.
+        res = lax.dot_general(out_ref[...].reshape(hb * P, N),
+                              jnp.broadcast_to(cg, (H, N)), _NT,
+                              precision=_HI, preferred_element_type=F32
+                              ).reshape(hb, P, H)
+        mine = (lax.broadcasted_iota(jnp.int32, (hb, P, H), 2)
+                == lax.broadcasted_iota(jnp.int32, (hb, P, H), 0))
+        part = jnp.sum(jnp.where(mine, res, 0.0), axis=0)       # [P, H]
+        if H != hb:
+            part = pltpu.roll(part, g * hb, 1)
 
-    @pl.when(g > 0)
+        @pl.when(g == 0)
+        def _():
+            yt_ref[...] = part
+
+        @pl.when(g > 0)
+        def _():
+            yt_ref[...] += part
+
+    # No slot lives: the grid holds ONE pair of state blocks from its
+    # first step to its last, and the result block goes back behind it.
+    # What goes back is what came.
+    @pl.when((n_live == 0) & (i == 0) & (g == 0))
     def _():
-        yt_ref[...] += part
+        out_ref[...] = h_ref[...]
 
 
-def ssd_update(h, layer, dt, x, b, c, a):
+def ssd_update(h, layer, dt, x, b, c, a, live=None):
     """:func:`ssd_update_ref` as one Pallas call: ``h`` whole and
     aliased to its result, ``(layer, slot, group)`` addressed by the
-    index maps. Jitted on its own so that it is traced once a process
-    (``flash_decode.paged_kv_write``'s note)."""
-    return _ssd_update(h, layer, dt, x, b, c, a,
+    index maps. The grid stays ``(slots, groups)`` whatever ``live``
+    says: its first steps visit the live slots, the others hold the
+    last of those blocks and run no body (:func:`_visit`), so a dead
+    slot's rows of ``h`` are not touched and its row of ``y``, which
+    nobody wrote, is made zeros behind the call. Jitted on its own so
+    that it is traced once a process (``flash_decode.paged_kv_write``'s
+    note)."""
+    return _ssd_update(h, layer, dt, x, b, c, a, live,
                        interpret=not backend.on_tpu())
 
 
 @functools.partial(jax.jit, static_argnames="interpret")
-def _ssd_update(h, layer, dt, x, b, c, a, interpret):
+def _ssd_update(h, layer, dt, x, b, c, a, live, interpret):
     _, B, H, P, N = h.shape
     G = b.shape[1]
     hb = H // G
@@ -147,29 +192,37 @@ def _ssd_update(h, layer, dt, x, b, c, a, interpret):
         [jnp.swapaxes(dt[:, :, None] * x.astype(F32), 1, 2),
          jnp.broadcast_to(jnp.exp(dt * a.astype(F32))[:, None, :],
                           (B, P, H))], axis=1)
-    rows = pl.BlockSpec((None, 2 * P, H), lambda i, g, _: (i, 0, 0))
-    group = pl.BlockSpec((None, G, N), lambda i, g, _: (i, 0, 0))
-    state = pl.BlockSpec((None, None, hb, P, N),
-                         lambda i, g, lyr: (lyr[0], i, g, 0, 0))
+
+    def slot(i, g, lyr, visit):
+        return visit[i], 0, 0
+
+    def block(i, g, lyr, visit):
+        # behind the live slots: the last group of the last of them
+        return (lyr[0], visit[i], jnp.where(i < visit[B], g, G - 1), 0, 0)
+
+    rows = pl.BlockSpec((None, 2 * P, H), slot)
+    group = pl.BlockSpec((None, G, N), slot)
+    state = pl.BlockSpec((None, None, hb, P, N), block)
     yt, h = pl.pallas_call(
         _update_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(B, G),
+            num_scalar_prefetch=2, grid=(B, G),
             in_specs=[rows, group, group, state],
-            out_specs=[pl.BlockSpec((None, P, H), lambda i, g, _: (i, 0, 0)),
-                       state]),
+            out_specs=[pl.BlockSpec((None, P, H), slot), state]),
         out_shape=[jax.ShapeDtypeStruct((B, P, H), F32),
                    jax.ShapeDtypeStruct(h.shape, h.dtype)],
-        # Operand numbers count the prefetched scalar.
-        input_output_aliases={4: 1},
+        # Operand numbers count the prefetched scalars.
+        input_output_aliases={5: 1},
+        # (a result block that steps of axis 0 come back to: in order)
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM),
         interpret=interpret,
         name="ssd_update",              # what the device trace prints
-    )(jnp.asarray(layer, jnp.int32).reshape(1), xt, b.astype(F32),
-      c.astype(F32), h)
-    return jnp.swapaxes(yt, 1, 2), h
+    )(jnp.asarray(layer, jnp.int32).reshape(1), _visit(live, B), xt,
+      b.astype(F32), c.astype(F32), h)
+    y = jnp.swapaxes(yt, 1, 2)
+    return (y if live is None else jnp.where(live[:, None, None], y, 0.0)), h
 
 
 # -- the prefill's scan -------------------------------------------------------

@@ -181,6 +181,9 @@ def test_phase_runs_at_tiny_size(phase, capsys, loopback_runtime_closed):
             < row["moe_pairs_routed"]
         assert row["state_bytes_moved"] == 2 * row["state_slot_steps"] \
             * row["state_bytes_slot"] > 0
+        assert 0 < row["state_steps_dead"] and 0 < row["state_dead_share"] \
+            == round(row["state_steps_dead"] / (row["state_steps_dead"]
+                                                + row["state_slot_steps"]), 4)
         assert row["programs_traced"][1] == 0
         # the span record of every leg: what a call kept, no wait far
         # above its like in the warm call's few spans unless the machine

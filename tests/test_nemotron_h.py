@@ -195,6 +195,49 @@ def test_ssd_update_kernel_is_the_plain_update_in_place(layer):
     assert (np.asarray(h1)[others] == np.asarray(h)[others]).all()
 
 
+_MASKS = {"all_live": [1, 1, 1, 1, 1], "none_live": [0, 0, 0, 0, 0],
+          "every_other": [1, 0, 1, 0, 1], "live_last": [0, 0, 0, 1, 1],
+          "one_live": [0, 0, 1, 0, 0]}
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("mask", sorted(_MASKS))
+def test_ssd_update_visits_the_live_slots_alone(mask, layer):
+    """Told which slots can still deliver (``live``), the update gives
+    a live slot the ``y`` and the state of the call that knows no mask,
+    bit for bit; a dead slot's rows of the state stay as they were, not
+    read and not written (NaN in a dead slot's state and inputs reaches
+    nothing), also where NO slot lives, and its ``y`` is zeros. The
+    plain twin says the same."""
+    k = jax.random.split(jax.random.key(5), 5)
+    L, B = 3, 5
+    live = np.asarray(_MASKS[mask], bool)
+    dead = ~live
+    h = jax.random.normal(k[0], (L, B, H, P, N))
+    x, dt, b, c, a, _ = _scan_args(B, seed=4)
+    y0, h0 = ssd.ssd_update(jnp.copy(h), layer, dt, x, b, c, a)
+    # what a dead slot holds is nobody's business
+    h = h.at[layer, dead].set(jnp.nan)
+    x = x.at[dead].set(jnp.nan)
+    for update in (ssd.ssd_update, ssd.ssd_update_ref):
+        y1, h1 = update(jnp.copy(h), layer, dt, x, b, c, a,
+                        live=jnp.asarray(live))
+        y1, h1 = np.asarray(y1), np.asarray(h1)
+        assert np.isfinite(y1).all() and (y1[dead] == 0).all()
+        assert (h1[layer, dead].view(np.uint32)
+                == np.asarray(h)[layer, dead].view(np.uint32)).all()
+        if update is ssd.ssd_update:
+            assert (y1[live] == np.asarray(y0)[live]).all()
+            assert (h1[layer, live] == np.asarray(h0)[layer, live]).all()
+        else:
+            np.testing.assert_allclose(y1[live], np.asarray(y0)[live],
+                                       atol=2e-5)
+            np.testing.assert_allclose(h1[layer, live],
+                                       np.asarray(h0)[layer, live], atol=2e-6)
+        others = [l for l in range(L) if l != layer]
+        assert (h1[others] == np.asarray(h)[others]).all()
+
+
 def test_the_scan_is_the_update_step_by_step():
     """Prefill's scan and decode's update are one recurrence: the state
     after S tokens of the scan is S updates' state, and the ``y`` rows
@@ -568,3 +611,53 @@ def test_requests_that_end_mid_chunk_and_dead_slots_keep_the_tokens(tree):
     b = _serve(tree, prompts, [2, 11], chunk=1, prefix_cache=False)
     assert [o.tolist() for o in a] == [o.tolist() for o in b]
     assert a.metrics.moe_pairs_dead > 0
+
+
+@pytest.mark.parametrize("name", ["nemotron_kernel", "nemotron_plain",
+                                  "jamba", "lfm2"])
+def test_a_dead_slot_step_moves_no_state_and_is_counted(name, tree,
+                                                        monkeypatch):
+    """``live=`` reaches the state operator: three requests of 2, 11 and
+    5 tokens through two slots in chunks of 8 (requests that end inside
+    a chunk, a slot that idles at the end) get the tokens of the same
+    call with every slot said to be live throughout, which is the update
+    of every slot's state that the parent ran, with ``ssd_update`` (the
+    Pallas call, interpret mode) and with its plain twin; the program
+    counts the slot-steps it was told are dead, once a step, and with
+    the delivering ones they are all the chunks' slot-steps. Jamba's and
+    LFM2's operators take ``live`` and do not read it: they count
+    none."""
+    import dataclasses
+    from mpi_acx_tpu.models import jamba, lfm2
+    if name.startswith("nemotron"):
+        family, params = nemotron_h, tree
+        cfg = dataclasses.replace(CFG, ssm_kernel=name.endswith("kernel"))
+    else:
+        family = {"jamba": jamba, "lfm2": lfm2}[name]
+        cfg = getattr(family, "tiny_" + name)()
+        params = family.init_params(jax.random.key(0), cfg)
+    prompts = [_seq(18, 50) % cfg.vocab, _seq(25, 51) % cfg.vocab,
+               _seq(9, 52) % cfg.vocab]
+
+    def serve():
+        return serving.serve_paged_greedy(
+            params, cfg, prompts, [2, 11, 5], n_slots=2, max_len=MAX_LEN,
+            family=family, chunk=8, page_tokens=PT)
+
+    told = serve()
+    m = told.metrics
+    assert m.decode_slot_steps == 8 * 2 * m.steps
+    assert m.state_slot_steps == m.decode_tokens == 1 + 10 + 4
+    if family is nemotron_h:
+        assert m.state_slot_steps + m.state_steps_dead == m.decode_slot_steps
+        assert m.state_dead_share == m.state_steps_dead / m.decode_slot_steps
+        assert 0.5 < m.state_dead_share < 1
+    else:
+        assert m.state_steps_dead == 0 == m.state_dead_share
+    monkeypatch.setattr(serving.RequestBook, "left",
+                        lambda self: np.full(self.n_slots, self.chunk,
+                                             np.int32))
+    untold = serve()
+    assert untold.metrics.programs_traced == 0
+    assert [o.tolist() for o in told] == [o.tolist() for o in untold]
+    assert untold.metrics.state_steps_dead == 0

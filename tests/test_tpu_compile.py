@@ -1005,12 +1005,13 @@ def _ssd_case(name):
     64 values x 128 state numbers in 8 groups, 5 layers, 96 slots."""
     from mpi_acx_tpu.ops import ssd
     (H, P, N), G, bf16 = _NEMO_STATE, 8, jnp.bfloat16
-    if name == "update":
+    if name.startswith("update"):
+        # (told which slots are live, or told nothing: one kernel)
         return ssd.ssd_update, [
             _s((5, NEMO_B, H, P, N), _F32), _s((), jnp.int32),
             _s((NEMO_B, H), _F32), _s((NEMO_B, H, P), bf16),
             _s((NEMO_B, G, N), bf16), _s((NEMO_B, G, N), bf16),
-            _s((H,), _F32)]
+            _s((H,), _F32)] + [_s((NEMO_B,), jnp.bool_)] * (name != "update")
     S = int(name.split("_")[1])
     return (lambda *a: ssd.ssd_scan(*a, snapshot=512, chunk=PAGE)), [
         _s((S, H, P), bf16), _s((S, H), _F32), _s((S, G, N), bf16),
@@ -1033,23 +1034,26 @@ def _ssd_state_movers(text):
     return found
 
 
-@pytest.mark.parametrize("name", ["update", "scan_32", "scan_512",
-                                  "scan_5120"])
+@pytest.mark.parametrize("name", ["update", "update_live", "scan_32",
+                                  "scan_512", "scan_5120"])
 def test_ssd_kernels_compile_for_v5e(name, v5e):
     """``ops/ssd.py``'s two Pallas calls at the published widths: the
     update with the 2.06 GB stacked state aliased to its result and the
     layer a prefetched scalar (a lane rotated to a group's heads, one
     lane a head broadcast down a ``[64, 128]`` state, the read-out one
-    product a group); the chunked scan over a bucket shorter than a
-    chunk (padded), of one snapshot, and of the cold bucket's ten."""
+    product a group), told nothing of the slots and told which are live
+    (the visiting order a second prefetched vector, a result block the
+    steps behind the live slots come back to); the chunked scan over a
+    bucket shorter than a chunk (padded), of one snapshot, and of the
+    cold bucket's ten."""
     fn, args = _ssd_case(name)
-    donate = (0,) if name == "update" else ()
-    compiled = jax.jit(fn, donate_argnums=donate).lower(
+    update = name.startswith("update")
+    compiled = jax.jit(fn, donate_argnums=(0,) if update else ()).lower(
         *_place(args, v5e)).compile()
     text = compiled.as_text()
-    kernel = "%ssd_update" if name == "update" else "%ssd_scan"
+    kernel = "%ssd_update" if update else "%ssd_scan"
     assert kernel in text and "tpu_custom_call" in text
-    if name == "update":
+    if update:
         # in place: the state is neither copied in front of the call
         # nor allocated a second time behind it
         assert not _ssd_state_movers(text), "\n".join(_ssd_state_movers(text))
@@ -1071,7 +1075,9 @@ def test_nemotron_decode_chunk_compiles_and_moves_no_state(v5e):
     grouped matmuls with the result shapes the benchmark's reader
     matches (2,112 pairs padded to 17 row tiles), no instruction that
     moves a pool, the stacked state (2.06 GB), a layer of it, or an
-    expert stack, and temporaries far below a chip. (With ONE layer of
+    expert stack, and temporaries far below a chip. The state carries
+    ``left`` as a serve call's does: the update is told which slots
+    live at each step, and is still one call a Mamba-2 layer. (With ONE layer of
     pages XLA keeps the stage's zero fill as a select on the chunk's
     first step, inside the loop: ``chip_smoke.py`` holds the served
     tokens to the dense pair's.)"""
@@ -1088,6 +1094,7 @@ def test_nemotron_decode_chunk_compiles_and_moves_no_state(v5e):
                  table=_s((NEMO_B, NEMO_LEN // PAGE), jnp.int32),
                  pos=_s((NEMO_B,), jnp.int32),
                  left=_s((NEMO_B,), jnp.int32), held=held,
+                 state_dead=_s((), jnp.int32),
                  owns=_s((NEMO_B,), jnp.bool_), moe=_s((7,), jnp.int32))
     keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0),
                                                    NEMO_B))
