@@ -25,12 +25,13 @@ One chip:
                on the chip fires io_callback triggers and a compiled
                Pallas flag kernel, rank 1 on the CPU receives
   train        train.make_train_step on a one-device mesh, three steps at
-               B=8 S=512 and one at S=1024 (flash attention's backward)
+               B=8 S=512 and one at S=1024 (flash attention's backward);
+               a ``tp`` axis of one runs no ring: ``attn_direct_calls``
 Four chips (``--chips 4``):
   tp_serve     make_tp_server_fns at tp=4 under serve_greedy, against the
                one-device serve in the same process
-  train_mesh   make_train_step on dp1 x pp2 x tp2 (ring attention inside),
-               against the one-device step
+  train_mesh   make_train_step on dp1 x pp2 x tp2 (ring attention inside:
+               ``attn_ring_calls``), against the one-device step
 
 A chip belongs to one process, so this parent never imports JAX: it
 builds the native library (``make lib tools`` — build/ is not tracked),
@@ -788,6 +789,16 @@ def _train_data(cfg, n_micro, mb, S, seed):
     return tokens, jnp.roll(tokens, -1, axis=-1)
 
 
+def _attn_calls():
+    """ring_attention.attention_calls_traced() under the names a train
+    phase prints: a program's attention calls are all direct (a ``tp``
+    axis of one) or all a ring, so over a phase one of the two stands
+    still."""
+    from mpi_acx_tpu.parallel.ring_attention import attention_calls_traced
+    return {f"attn_{k}_calls": n
+            for k, n in attention_calls_traced().items()}
+
+
 def _train_run(cfg, mesh, params, tokens, targets, steps, lr=0.1):
     """``steps`` SGD steps of make_train_step on ``mesh``; returns
     (losses, max |delta embed|, the parameters after the last step).
@@ -820,9 +831,11 @@ def phase_train(size: Size = FULL, seed: int = 0, require_kernel=True):
     for S, steps, mb in ((size.train_s, 3, size.train_b // 2),
                          (size.train_s_long, 1, max(size.train_b // 4, 1))):
         tokens, targets = _train_data(cfg, 2, mb, S, seed)
+        before = _attn_calls()
         with _Watch() as w:
             losses, moved, _ = _train_run(cfg, mesh, params, tokens,
                                           targets, steps)
+        calls = {k: n - before[k] for k, n in _attn_calls().items()}
         want = float(jax.jit(lambda p, t, y: tfm.loss_fn(p, cfg, t, y))(
             params, tokens.reshape(-1, S), targets.reshape(-1, S)))
         _require(np.isfinite(losses).all()
@@ -831,9 +844,12 @@ def phase_train(size: Size = FULL, seed: int = 0, require_kernel=True):
         _require(abs(losses[0] - want) <= 2e-2 * abs(want),
                  f"first loss {losses[0]} against loss_fn's {want}")
         _require(moved > 0, "the step did not move the parameters")
+        _require(calls["attn_direct_calls"] > 0
+                 and calls["attn_ring_calls"] == 0,
+                 f"a one-device mesh traced a ring: {calls}")
         emit(phase=f"train/S{S}", ok=True, batch=2 * mb, seq=S, steps=steps,
              losses=[round(x, 4) for x in losses],
-             loss_fn_reference=round(want, 4), **w.row())
+             loss_fn_reference=round(want, 4), **calls, **w.row())
     return True
 
 
@@ -902,9 +918,14 @@ def phase_train_mesh(size: Size = FULL, seed: int = 0, require_kernel=True):
                                  jax.devices()[:1])
     (want,), _, _ = _train_run(cfg, one_mesh, params, tokens, targets, 1)
     mesh = mesh_from_devices({"dp": 1, "pp": 2, "tp": 2}, jax.devices()[:4])
+    before = _attn_calls()
     with _Watch() as w:
         (loss,), moved, new = _train_run(cfg, mesh, params, tokens, targets,
                                          1)
+    calls = {k: n - before[k] for k, n in _attn_calls().items()}
+    _require(calls["attn_ring_calls"] > 0
+             and calls["attn_direct_calls"] == 0,
+             f"a tp axis of two traced a direct call: {calls}")
     _require(np.isfinite(loss) and abs(loss - want) <= 2e-2 * abs(want),
              f"mesh loss {loss} against the one-device step's {want}")
     _require(moved > 0, "the step did not move the parameters")
@@ -912,7 +933,7 @@ def phase_train_mesh(size: Size = FULL, seed: int = 0, require_kernel=True):
     _require(len({s.device for s in w1.addressable_shards}) == 4,
              "the stepped parameters sit on fewer than four devices")
     emit(phase="train_mesh/dp1_pp2_tp2", ok=True, loss=round(loss, 4),
-         one_device_loss=round(want, 4), **w.row(),
+         one_device_loss=round(want, 4), **calls, **w.row(),
          device_bytes_in_use=_device_bytes(),
          shard_shapes={"w1": [list(s.data.shape)
                               for s in w1.addressable_shards]})

@@ -20,6 +20,14 @@ visible (source block before this device's block: unmasked flash call),
 entirely masked (source after: skipped — no FLOPs at all), or diagonal
 (the standard causal flash call); the three cases dispatch by
 ``lax.switch`` on the rotating source index.
+
+An axis of ONE communicates nothing and merges nothing: there
+:func:`ring_attention_batched` returns its one block's attention directly
+(:func:`mpi_acx_tpu.ops.attention.flash_attention`, or one dense block),
+with no accumulator, scan, switch, permute or float32 copy of the output.
+The values are the ring-of-one's bit for bit (a merge with an empty
+accumulator is the identity in floating point too).
+:func:`attention_calls_traced` says which of the two a program holds.
 """
 
 from __future__ import annotations
@@ -43,6 +51,17 @@ _NEG = float(jnp.finfo(jnp.float32).min)
 # identical-math) dense blocks — pass use_flash=True to force the
 # kernel, or keep S/tp >= this threshold for the flash win at scale.
 FLASH_MIN_SHARD = 1024
+
+_calls_traced = {"direct": 0, "ring": 0}
+
+
+def attention_calls_traced() -> dict[str, int]:
+    """How many :func:`ring_attention_batched` calls this process has
+    TRACED so far as one direct block (an axis of one) and as a ring (an
+    axis of two or more): counted in the function's Python body, which
+    runs only under a trace. All or nothing per program: a program's mesh
+    gives every one of its attention calls the same axis size."""
+    return dict(_calls_traced)
 
 
 def _dense_block(q32, kk, vv, mask):
@@ -87,9 +106,19 @@ def ring_attention_batched(q: jax.Array, k: jax.Array, v: jax.Array,
     produce identical math; both yield (normalized block output, lse) and
     merge with logaddexp, so switching kernels never changes numerics
     beyond float roundoff.
+
+    An axis of ONE (``lax.axis_size``, a Python int at trace time: a
+    ``tp = 1`` mesh) runs no ring: the one block IS the answer, so the
+    flash path is one :func:`~mpi_acx_tpu.ops.attention.flash_attention`
+    call and the dense path one :func:`_dense_block`, with no
+    accumulator, ``scan``, ``switch``, ``ppermute`` or float32 copy of
+    the output, and no lse cotangent in the backward. Bit-equal to the
+    ring of one it replaces, outputs and gradients
+    (tests/test_ring_attention.py); from 16384 tokens on
+    ``flash_attention`` picks its streaming kernel, which a ring's
+    ``flash_attention_lse`` blocks do not have.
     """
     n = lax.axis_size(axis_name)
-    my = lax.axis_index(axis_name)
     mb, sq, h, dh = q.shape
     assert k.shape[2] * kv_repeat == h, (k.shape, h, kv_repeat)
     if use_flash is None:
@@ -107,6 +136,17 @@ def ring_attention_batched(q: jax.Array, k: jax.Array, v: jax.Array,
             (mb, x.shape[1], hkv, kv_repeat, dh)).reshape(
                 mb, x.shape[1], h, dh)
 
+    _calls_traced["direct" if n == 1 else "ring"] += 1
+    if n == 1:
+        if use_flash:
+            from mpi_acx_tpu.ops.attention import flash_attention
+            return flash_attention(q, expand(k), expand(v), causal=causal)
+        mask = (jnp.tril(jnp.ones((sq, sq), bool))[None, None] if causal
+                else jnp.ones((1, 1, sq, sq), bool))
+        return _dense_block(q.astype(jnp.float32), expand(k), expand(v),
+                            mask)[0].astype(q.dtype)
+
+    my = lax.axis_index(axis_name)
     if use_flash:
         from mpi_acx_tpu.ops.attention import flash_attention_lse
 

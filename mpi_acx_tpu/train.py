@@ -9,6 +9,10 @@ the reference's pipeline-exchange driver configs):
 * **tp + sp** — inside each stage, attention runs sequence-parallel over
   the 'tp' axis with ring attention (K/V rotating on ICI), and the MLP
   runs tensor-parallel with the FFN dim sharded over 'tp' and one psum.
+  At a 'tp' axis of ONE (a one-device mesh, or dp/pp alone) nothing
+  rotates and nothing merges: ``ring_attention_batched`` is then one
+  direct flash (or dense) attention call in the layer body, and the
+  block's sequence slice, all_gather and psum compile to nothing.
 * **dp** — the microbatch dim is sharded over 'dp'; gradients are averaged
   with one pmean.
 

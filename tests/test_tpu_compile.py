@@ -1195,3 +1195,73 @@ def test_the_other_families_chunk_programs_are_the_parents(name,
     that MEANS to change a family's chunk reads the digest anew."""
     monkeypatch.setattr(backend, "on_tpu", lambda: False)
     assert _chunk_digest(name) == _CHUNK_DIGESTS[name]
+
+
+# The training cell's WHOLE step (``benchmarks/entries/train_step_optax``:
+# GPT-2 medium, AdamW, remat, chunked cross-entropy, a dp1 x pp1 x tp1
+# mesh) at ONE micro-batch of 8 rows: a ``tp`` axis of one runs no ring.
+
+def _train_step_text(v5e, n_micro=1):
+    import json
+
+    import numpy as np
+    import optax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from benchmarks import harness, weights
+    from mpi_acx_tpu.train import make_train_step_optax
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "configs", "gpt2_medium_train.json")) as f:
+        c = json.load(f)
+    tr, o = c["train"], c["train"]["optimizer"]
+    (device,) = v5e.device_set
+    mesh = Mesh(np.array([device]).reshape(1, 1, 1), ("dp", "pp", "tp"))
+    opt = optax.adamw(o["learning_rate"], b1=o["b1"], b2=o["b2"],
+                      eps=o["eps"], weight_decay=o["weight_decay"])
+    step, n_stages = make_train_step_optax(
+        harness.gpt2_program_config(c, c["compute_dtype"]), mesh, n_micro,
+        opt, remat=tr["remat"], xent_chunk=tr["xent_chunk"])
+    params = jax.eval_shape(lambda: tfm.stage_slice(
+        weights.make_gpt2(c, 1, jnp.dtype(c["weights_dtype"])), n_stages))
+    placed = _place((params, jax.eval_shape(opt.init, params),
+                     _s((n_micro, 8, c["n_positions"]), jnp.int32)),
+                    NamedSharding(mesh, PartitionSpec()))
+    return c, step.lower(*placed, placed[2]).compile().as_text()
+
+
+def test_the_train_step_at_tp_one_runs_no_ring(v5e):
+    """The compiled step sends nothing (the one ``collective-permute``
+    left has NO source-target pair: the pipeline's, at a ``pp`` axis of
+    one, once a micro-batch), branches on nothing, and its attention
+    kernels sit in the two loops over the layers: the forward kernel
+    once in the forward loop and once (remat) in the backward loop
+    beside the one ``%attn_bwd``: 2 x layers and 1 x layers calls a
+    micro-batch, under the names the benchmark's readers match."""
+    c, text = _train_step_text(v5e)
+    sends = re.findall(r"collective-permute-start\(.*?source_target_pairs="
+                       r"\{(.*?)\}[,\s]", text)
+    assert all(pairs == "" for pairs in sends) and len(sends) <= 1, sends
+    assert not re.search(r"\sconditional\(", text)
+
+    where, comp = {}, None           # kernel instruction -> its computation
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            comp = line.split()[1 if line.startswith("ENTRY") else 0]
+        elif "tpu_custom_call" in line and " custom-call(" in line:
+            where[line.split(" = ")[0].strip().removeprefix("ROOT ")] = comp
+    fwd = [k for k in where if k.startswith("%flash_attention")]
+    bwd = [k for k in where if k.startswith("%attn_bwd")]
+    assert len(fwd) == 2 and len(bwd) == 1 and len(where) == 3, where
+    assert not any("lse" in k for k in fwd), fwd     # the direct entry
+
+    def trips(body):
+        """The constant a ``while`` over ``body`` counts to."""
+        (cond,) = re.findall(r" while\(.*condition=(%[\w.\-]+), body="
+                             + re.escape(body) + r"[,\s]", text)
+        block = text.split("\n" + cond + " (")[1].split("\n}\n")[0]
+        (n,) = re.findall(r"s32\[\][^=]* constant\((\d+)\)", block)
+        return int(n)
+
+    loops = sorted(where[k] for k in fwd)
+    assert len(set(loops)) == 2 and where[bwd[0]] in loops, where
+    assert [trips(b) for b in loops] == [c["n_layer"]] * 2

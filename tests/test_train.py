@@ -72,13 +72,15 @@ def test_distributed_step_matches_sequential(setup):
     _assert_step_matches_sequential(cfg, mesh, params, tokens, targets)
 
 
-@pytest.mark.parametrize("dp,pp,tp", [(1, 4, 2), (4, 2, 1), (1, 2, 4),
-                                      (2, 1, 4), (8, 1, 1)])
-def test_step_matches_sequential_across_mesh_shapes(dp, pp, tp):
+@pytest.mark.parametrize("dp,pp,tp,remat", [
+    (1, 4, 2, False), (4, 2, 1, False), (1, 2, 4, False), (2, 1, 4, False),
+    (8, 1, 1, False), (1, 1, 1, False), (1, 1, 1, True), (2, 2, 1, True)])
+def test_step_matches_sequential_across_mesh_shapes(dp, pp, tp, remat):
     """The gradient-reduction construction (exclusive loss paths + the
     pp*tp cotangent rescale under check_vma=False) must hold on EVERY
     mesh factorization, not just the 2x2x2 it was derived on (VERDICT r2
-    weak#4: 'validated only on tiny configs')."""
+    weak#4: 'validated only on tiny configs'). At ``tp = 1`` (with
+    remat on and off) the attention half is one direct block, no ring."""
     cfg = tfm.tiny_config(vocab=83, d_model=64, n_heads=4, n_layers=4,
                           d_ff=96, max_seq=32)
     mesh = mesh_from_devices({"dp": dp, "pp": pp, "tp": tp})
@@ -86,7 +88,49 @@ def test_step_matches_sequential_across_mesh_shapes(dp, pp, tp):
     M, mb, S = 2, 2 * dp, 16
     tokens = jax.random.randint(jax.random.key(6), (M, mb, S), 0, cfg.vocab)
     targets = jnp.roll(tokens, -1, axis=-1)
-    _assert_step_matches_sequential(cfg, mesh, params, tokens, targets)
+    _assert_step_matches_sequential(cfg, mesh, params, tokens, targets,
+                                    remat=remat)
+
+
+def _ppermute_axes(jaxpr, out=None):
+    """The axis names of every ``ppermute`` of a jaxpr and its nested
+    bodies, one entry an equation."""
+    out = [] if out is None else out
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "ppermute":
+            out.append(tuple(eqn.params["axis_name"]))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _ppermute_axes(sub, out)
+    return out
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_a_tp_axis_of_one_permutes_nothing(setup, remat):
+    """At ``tp = 1`` the step's jaxpr, backward included, holds no
+    ``ppermute`` over 'tp' (no ring of one) and traces every attention
+    call direct; at ``tp = 2`` the ring is there as before. (The one
+    ``ppermute`` a ``pp`` axis of one keeps is the pipeline's, with an
+    empty ``perm``.)"""
+    from mpi_acx_tpu.parallel.ring_attention import attention_calls_traced
+    cfg, _, params, tokens, targets = setup
+
+    def traced(tp):
+        mesh = mesh_from_devices({"dp": 1, "pp": 1, "tp": tp})
+        step, n_stages = make_train_step(cfg, mesh, n_micro=tokens.shape[0],
+                                         remat=remat)
+        before = attention_calls_traced()
+        jaxpr = jax.make_jaxpr(step)(tfm.stage_slice(params, n_stages),
+                                     tokens, targets).jaxpr
+        after = attention_calls_traced()
+        return (_ppermute_axes(jaxpr),
+                {k: after[k] - before[k] for k in after})
+
+    axes, calls = traced(1)
+    assert ("tp",) not in axes and set(axes) <= {("pp",)}, axes
+    assert calls["direct"] > 0 and calls["ring"] == 0, calls
+    axes, calls = traced(2)
+    assert ("tp",) in axes, axes
+    assert calls["ring"] > 0 and calls["direct"] == 0, calls
 
 
 def test_interleaved_schedule_matches_sequential(setup):

@@ -36,11 +36,20 @@ def _sequential_step(cfg, params, tokens, targets, lr):
     return loss, jax.tree.map(lambda p, g: p - lr * g, params, grads)
 
 
-def test_llama_distributed_step_matches_sequential(setup):
-    cfg, mesh, params, tokens, targets = setup
+@pytest.mark.parametrize("shape,remat", [
+    ({"dp": 2, "pp": 2, "tp": 2}, False),
+    ({"dp": 1, "pp": 1, "tp": 1}, False),
+    ({"dp": 1, "pp": 1, "tp": 1}, True)],
+    ids=["dp2_pp2_tp2", "one_device", "one_device_remat"])
+def test_llama_distributed_step_matches_sequential(setup, shape, remat):
+    """On the 2x2x2 mesh (the ring, grouped-query K/V rotating
+    un-expanded) and on ONE device with remat off and on (``tp = 1``: one
+    direct block, K/V expanded as the ring's blocks expand them)."""
+    cfg, _, params, tokens, targets = setup
+    mesh = mesh_from_devices(shape)
     lr = 0.1
     step, n_stages = make_train_step(cfg, mesh, n_micro=tokens.shape[0],
-                                     lr=lr)
+                                     lr=lr, remat=remat)
     staged = tfm.stage_slice(params, n_stages)
 
     dist_loss, dist_new = step(staged, tokens, targets)

@@ -13,6 +13,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from mpi_acx_tpu.models import moe_transformer as mtf
 from mpi_acx_tpu.models import transformer as tfm
@@ -28,11 +29,15 @@ def _unstage(staged):
     return out
 
 
-def test_flagship_loss_matches_dp_ep_trainer_at_pp1():
+@pytest.mark.parametrize("remat", [False, True])
+def test_flagship_loss_matches_dp_ep_trainer_at_pp1(remat):
     """On a dp=2, pp=1, tp=1 mesh with n_micro=1 the flagship loss must
     equal make_moe_transformer_train_step's loss on the same data: the
     per-rank token groups coincide (B/dp x S tokens per router call), so
-    routing is bit-equal, and both normalize aux per (layer, group)."""
+    routing is bit-equal, and both normalize aux per (layer, group).
+    With remat on (the attention half at ``tp = 1`` is one direct block,
+    recomputed in the backward) the loss is the same number and every
+    gradient leaf the plain step's."""
     aw, zw = 1e-2, 1e-3
     dp = 2
     mesh = mesh_from_devices({"dp": dp, "pp": 1, "tp": 1},
@@ -52,12 +57,19 @@ def test_flagship_loss_matches_dp_ep_trainer_at_pp1():
         cfg, ep_mesh, axis="dp", lr=0.0, aux_weight=aw, z_weight=zw)
     ep_loss, _ = ep_step(params, tokens, targets)
 
-    grad_fn, n_st = make_loss_and_grads(cfg, mesh, n_micro=1,
+    grad_fn, n_st = make_loss_and_grads(cfg, mesh, n_micro=1, remat=remat,
                                         aux_weight=aw, z_weight=zw)
     staged = tfm.stage_slice(params, n_st)
-    flag_loss, _ = grad_fn(staged, tokens[None], targets[None])
+    flag_loss, grads = grad_fn(staged, tokens[None], targets[None])
     np.testing.assert_allclose(float(flag_loss), float(ep_loss),
                                rtol=1e-6)
+    if remat:
+        plain_fn, _ = make_loss_and_grads(cfg, mesh, n_micro=1,
+                                          aux_weight=aw, z_weight=zw)
+        _, plain = plain_fn(staged, tokens[None], targets[None])
+        for got, want in zip(jax.tree.leaves(grads), jax.tree.leaves(plain)):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       atol=1e-6, rtol=1e-5)
 
 
 def test_flagship_aux_keeps_routing_balanced_at_pp2():
