@@ -209,11 +209,7 @@ def init_params(key: jax.Array, cfg: GigaChatConfig) -> Params:
 def cast_params(params: Params, dtype=jnp.bfloat16) -> Params:
     """The tree in ``dtype`` for inference; the router (``gate``,
     ``bias``) and the norms stay f32: they are computed in f32."""
-    def cast(path, p):
-        name = path[-1].key
-        keep = name in ("gate", "bias") or name.endswith("norm")
-        return p if keep else p.astype(dtype)
-    return jax.tree_util.tree_map_with_path(cast, params)
+    return kvpage.cast_params(params, dtype, ("gate", "bias"))
 
 
 # -- positions ---------------------------------------------------------------
@@ -420,83 +416,10 @@ def _head(params: Params, cfg: GigaChatConfig, x: jax.Array):
                    preferred_element_type=jnp.float32)
 
 
-# -- whole sequences: forward, prefill, suffix prefill -----------------------
-
-
-def _sequence_pass(params: Params, cfg: GigaChatConfig, x: jax.Array,
-                   positions, history=None):
-    """x [1, S, d] through every layer, the unabsorbed path. ``history``
-    [L, 1, row_dim, P]: the sequence continues one whose first P
-    positions are cached. Returns (x, rows [L, S, row_dim])."""
-    rows, at = [], 0
-    for seg in segments(cfg):
-        kind = seg.period[0]
-        stacked = params[seg.key]
-        # The expert stacks stay out of the scan's slicing (moe.
-        # sorted_expert_ffn, ``layer``): closed over whole.
-        whole = ({n: stacked[n] for n in _EXPERT_STACKS}
-                 if kind.ffn == "moe" else {})
-        xs = {"lp": {n: a for n, a in stacked.items() if n not in whole},
-              "i": jnp.arange(seg.repeats)}
-        if history is not None:
-            xs["h"] = history[at:at + seg.repeats, 0]
-
-        def body(x, xs, kind=kind, whole=whole):
-            lp = dict(xs["lp"], **whole, repeat=xs["i"]) if whole \
-                else xs["lp"]
-            x, row = _sequence_attention(cfg, lp, x, positions, xs.get("h"))
-            x = (_dense_ffn(cfg, lp, x) if kind.ffn == "dense"
-                 else _moe_ffn(cfg, lp, x)[0])
-            return x, row
-
-        x, got = lax.scan(body, x, xs)
-        rows.append(got)
-        at += seg.repeats
-    return x, jnp.concatenate(rows, axis=0)
-
-
 def forward(params: Params, cfg: GigaChatConfig, tokens: jax.Array):
     """tokens [1, S] int32 -> logits [1, S, vocab] (f32): the plain
     whole-sequence pass, no cache, one sequence."""
-    x = params["embed"][tokens].astype(cfg.dtype)
-    x = _sequence_pass(params, cfg, x, jnp.arange(tokens.shape[1]))[0]
-    return _head(params, cfg, x)
-
-
-def _prefilled(params, cfg, x, rows, last_index):
-    from mpi_acx_tpu.models.decoding import to_cache_layout
-    x = lax.dynamic_slice_in_dim(x, last_index, 1, axis=1)
-    # [L, S, row] -> the pool's [L, 1, 1 head, row, S]
-    return _head(params, cfg, x), {
-        "k": to_cache_layout(rows[:, None, :, None, :])}
-
-
-def prefill(params: Params, cfg: GigaChatConfig, tokens: jax.Array,
-            last_index, kv_int8: bool = False,
-            page_tokens: Optional[int] = None):
-    """``PagedSpec.prefill``: one prompt [1, S] (bucket-padded, its real
-    last token at ``last_index``) -> (logits [1, 1, vocab] there,
-    ``one``: ``'k'`` alone, the layers' rows in a latent pool's layout
-    ``[L, 1, 1, row_dim, S]``)."""
-    assert not kv_int8, "int8 latent pages are not wired"
-    x = params["embed"][tokens].astype(cfg.dtype)
-    x, rows = _sequence_pass(params, cfg, x, jnp.arange(tokens.shape[1]))
-    return _prefilled(params, cfg, x, rows, last_index)
-
-
-def suffix_prefill(params: Params, cfg: GigaChatConfig, suffix: jax.Array,
-                   hk, hv, tail, last_index, kv_int8: bool = False,
-                   page_tokens: Optional[int] = None):
-    """``PagedSpec.suffix_prefill``: only the suffix [1, S_suf] of a
-    prompt whose first P tokens are paged in (a radix hit), against the
-    gathered rows ``hk`` [L, 1, row_dim, P] (``hv`` and ``tail`` are
-    None: a latent pool has no V, the family no state), which every
-    layer up-projects beside the suffix's own."""
-    assert not kv_int8 and hv is None and tail is None
-    P, S = hk.shape[-1], suffix.shape[1]
-    x = params["embed"][suffix].astype(cfg.dtype)
-    x, rows = _sequence_pass(params, cfg, x, P + jnp.arange(S), history=hk)
-    return _prefilled(params, cfg, x, rows, last_index)
+    return kvpage.forward(params, cfg, paged_spec(cfg), tokens)
 
 
 # -- the paged plane's seam --------------------------------------------------
@@ -522,4 +445,4 @@ def paged_spec(cfg: GigaChatConfig) -> kvpage.PagedSpec:
             params["embed"][token][:, None, :].astype(cfg.dtype),
         qkv=_decode_qkv, attn_out=_decode_attn_out, ffn=_ffn,
         head=lambda params, cfg, x: _head(params, cfg, x)[:, 0],
-        prefill=prefill, suffix_prefill=suffix_prefill)
+        seq_attention=_sequence_attention, seq_head=_head)

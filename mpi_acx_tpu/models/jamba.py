@@ -48,8 +48,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from mpi_acx_tpu.models import kvpage
-from mpi_acx_tpu.models.lfm2 import _by_layer
-from mpi_acx_tpu.models.llama import _repeat_kv, rmsnorm
+from mpi_acx_tpu.models.llama import rmsnorm
 from mpi_acx_tpu.ops import ssm
 
 F32 = jnp.float32
@@ -184,11 +183,7 @@ def init_params(key: jax.Array, cfg: JambaConfig,
 def cast_params(params: Params, dtype=jnp.bfloat16) -> Params:
     """The tree in ``dtype`` for inference; the norms and the scan's own
     parameters (``A_log``, ``D``, ``b_dt``) stay f32."""
-    def cast(path, p):
-        name = path[-1].key
-        keep = name in _F32_LEAVES or "norm" in name
-        return p if keep else p.astype(dtype)
-    return jax.tree_util.tree_map_with_path(cast, params)
+    return kvpage.cast_params(params, dtype, _F32_LEAVES)
 
 
 # -- the layer functions -----------------------------------------------------
@@ -198,7 +193,7 @@ def _w(lp, name, dtype):
     return lp[name].astype(dtype)
 
 
-def _qkv(cfg: JambaConfig, lp: Params, x: jax.Array):
+def attention_qkv(cfg: JambaConfig, lp: Params, x: jax.Array):
     """q [B, S, Hq, Dh], k, v [B, S, Hkv, Dh]; no position enters."""
     B, S, _ = x.shape
     h = rmsnorm(x, lp["norm1"], cfg.norm_eps)
@@ -208,18 +203,8 @@ def _qkv(cfg: JambaConfig, lp: Params, x: jax.Array):
             (h @ _w(lp, "wv", x.dtype)).reshape(B, S, cfg.n_kv_heads, dh))
 
 
-def _attn_out(cfg: JambaConfig, lp: Params, x: jax.Array, o: jax.Array):
+def attention_out(cfg: JambaConfig, lp: Params, x: jax.Array, o: jax.Array):
     return x + o @ _w(lp, "wo", x.dtype)
-
-
-def _self_attend(cfg: JambaConfig, q, k, v):
-    """Causal attention of a whole sequence on itself through the shared
-    flash/dense policy, the one K/V head repeated for it (llama's way)."""
-    from mpi_acx_tpu.ops.attention import select_attention
-    n_rep = cfg.n_heads // cfg.n_kv_heads
-    o = select_attention(cfg.use_flash)(q, _repeat_kv(k, n_rep),
-                                        _repeat_kv(v, n_rep))
-    return o.reshape(q.shape[0], q.shape[1], -1)
 
 
 def _ffn(cfg: JambaConfig, lp: Params, x: jax.Array, kind: str = "dense"):
@@ -229,7 +214,7 @@ def _ffn(cfg: JambaConfig, lp: Params, x: jax.Array, kind: str = "dense"):
     return x + g @ _w(lp, "w_down", x.dtype)
 
 
-def _conv_taps(lp: Params, rows):
+def conv_taps(lp: Params, rows):
     """silu(bias + sum_j conv_w[j] * rows[j]) in float32: ``rows`` the
     ``d_conv`` inputs [..., C] a position sees, oldest first."""
     w = lp["conv_w"].astype(F32)
@@ -254,37 +239,54 @@ def _scan_inputs(cfg: JambaConfig, lp: Params, u: jax.Array):
     return dt, b, c, -jnp.exp(lp["A_log"].astype(F32))
 
 
-def _window(flat, taps: int):
+def conv_window(flat, taps: int):
     """A conv window leaf [..., taps * C] as ``taps`` [..., C], oldest
     first (static slices along the lanes: no relayout)."""
     c = flat.shape[-1] // taps
     return [flat[..., j * c:(j + 1) * c] for j in range(taps)]
 
 
-def _mamba_seq(cfg: JambaConfig, lp: Params, x: jax.Array, before, h0,
-               last_index, snapshot):
-    """The Mamba mixer with its residual over whole sequences x [B, S,
-    d]. ``before`` [B, taps * C] the conv's inputs at the ``taps = d_conv
-    - 1`` positions before x and ``h0`` [B, N, C] the scan's state there
-    (None: zeros, a sequence's start). Positions past ``last_index``
-    (None: none) are padding and leave the scan's state as it was.
-    Returns (x + y, ``us`` [B, taps + S, C]: ``before`` then this call's
-    conv inputs, whose rows ``t + 1 .. t + taps`` are the window after
-    token t; the scan's state after every ``snapshot`` tokens [B, S //
-    snapshot, N, C] and after the last [B, N, C])."""
+def window_cuts(us, taps: int, last_index, snapshot):
+    """From the conv inputs ``us`` [B, taps + S, C] of a state-space
+    layer (those before the sequences, then their own: rows ``t + 1 ..
+    t + taps`` are the window after token t) the windows of sequence 0
+    that a ``PagedSpec.seq_state`` returns, flat as the state's leaf:
+    after every ``snapshot`` tokens ``[S // snapshot, taps * C]`` and
+    after ``last_index`` ``[taps * C]``."""
+    n = (us.shape[1] - taps) // snapshot
+    at_ends = jnp.stack(
+        [us[0, (j + 1) * snapshot:(j + 1) * snapshot + taps].reshape(-1)
+         for j in range(n)]) if n else jnp.zeros((0, taps * us.shape[2]),
+                                                 us.dtype)
+    return at_ends, lax.dynamic_slice_in_dim(us[0], last_index + 1, taps,
+                                             axis=0).reshape(-1)
+
+
+def _mamba_seq(cfg: JambaConfig, lp: Params, x: jax.Array, start, last_index,
+               snapshot):
+    """``PagedSpec.seq_state``: the Mamba mixer with its residual over
+    whole sequences x [B, S, d], from ``start`` = {"conv": [1, taps * C]
+    the conv's inputs at the ``taps = d_conv - 1`` positions before x,
+    "ssm": [1, N, C] the scan's state there} (None: zeros, a sequence's
+    start). Positions past ``last_index`` (None: none) are padding and
+    leave the scan's state as it was. Returns (x + y, and with
+    ``snapshot`` sequence 0's state after every ``snapshot`` tokens,
+    leaves [S // snapshot, ...], and after ``last_index``; else None,
+    None)."""
     B, S, _ = x.shape
     C, N, taps = cfg.d_inner, cfg.mamba_d_state, cfg.mamba_d_conv - 1
     u, z = jnp.split(rmsnorm(x, lp["norm1"], cfg.norm_eps)
                      @ _w(lp, "w_in", x.dtype), 2, axis=-1)
-    before = (jnp.zeros((B, taps, C), x.dtype) if before is None
-              else jnp.stack(_window(before.astype(x.dtype), taps), axis=1))
+    before = (jnp.zeros((B, taps, C), x.dtype) if start is None
+              else jnp.stack(conv_window(start["conv"].astype(x.dtype),
+                                         taps), axis=1))
     us = jnp.concatenate([before, u], axis=1)
-    uc = _conv_taps(lp, [us[:, j:j + S] for j in range(taps + 1)]
-                    ).astype(x.dtype)
+    uc = conv_taps(lp, [us[:, j:j + S] for j in range(taps + 1)]
+                   ).astype(x.dtype)
     dt, b, c, a = _scan_inputs(cfg, lp, uc)
     if last_index is not None:
         dt = jnp.where((jnp.arange(S) <= last_index)[None, :, None], dt, 0.0)
-    h0 = jnp.zeros((B, N, C), F32) if h0 is None else h0
+    h0 = jnp.zeros((B, N, C), F32) if start is None else start["ssm"]
     scan = ssm.select_ssm(cfg.ssm_kernel)[1]
 
     def one(u, dt, z, b, c, h0):
@@ -292,7 +294,12 @@ def _mamba_seq(cfg: JambaConfig, lp: Params, x: jax.Array, before, h0,
     y, snaps, end = (tuple(t[None] for t in one(uc[0], dt[0], z[0], b[0],
                                                 c[0], h0[0]))
                      if B == 1 else jax.vmap(one)(uc, dt, z, b, c, h0))
-    return x + y.astype(x.dtype) @ _w(lp, "w_out", x.dtype), us, snaps, end
+    x = x + y.astype(x.dtype) @ _w(lp, "w_out", x.dtype)
+    if snapshot is None:
+        return x, None, None
+    at_ends, at_last = window_cuts(us, taps, last_index, snapshot)
+    return (x, {"conv": at_ends, "ssm": snaps[0]},
+            {"conv": at_last, "ssm": end[0]})
 
 
 def _mamba_step(cfg: JambaConfig, lp: Params, x: jax.Array, held, at,
@@ -306,9 +313,9 @@ def _mamba_step(cfg: JambaConfig, lp: Params, x: jax.Array, held, at,
     taps = cfg.mamba_d_conv - 1
     u, z = jnp.split(rmsnorm(x[:, 0], lp["norm1"], cfg.norm_eps)
                      @ _w(lp, "w_in", x.dtype), 2, axis=-1)
-    win = _window(lax.dynamic_index_in_dim(held["conv"], at, 0,
-                                           keepdims=False), taps)
-    uc = _conv_taps(lp, win + [u]).astype(x.dtype)
+    win = conv_window(lax.dynamic_index_in_dim(held["conv"], at, 0,
+                                               keepdims=False), taps)
+    uc = conv_taps(lp, win + [u]).astype(x.dtype)
     conv = lax.dynamic_update_index_in_dim(
         held["conv"], jnp.concatenate(win[1:] + [u], axis=-1).astype(
             held["conv"].dtype), at, 0)
@@ -325,149 +332,10 @@ def _head(params: Params, cfg: JambaConfig, x: jax.Array):
                       preferred_element_type=F32)
 
 
-# -- whole sequences: forward, prefill, suffix prefill -----------------------
-
-
-def _sequence_pass(params: Params, cfg: JambaConfig, x: jax.Array,
-                   history=None, page_tokens=None, last_index=None):
-    """x [B, S, d] through every layer. ``history`` = (hk, hv [L_attn,
-    Hkv, Dh, P], tail {"conv": [L_mamba, taps * C], "ssm": [L_mamba, N,
-    C]}): the sequence continues one whose first P positions are cached
-    (B = 1): attention sees the history's keys and values before its
-    own, each Mamba layer starts from the snapshot. Returns (x, k, v
-    [L_attn, B, S, Hkv, Dh], and with ``page_tokens`` the Mamba layers'
-    ``tail`` (leaves [L_mamba, S // (page_tokens * snapshot_every), ...]:
-    the state at the end of every page that keeps a snapshot) and
-    ``end`` (leaves [L_mamba, ...]: at ``last_index``), else None,
-    None)."""
-    from mpi_acx_tpu.models.decoding import (dense_decode_attend,
-                                             to_cache_layout)
-    B, S, _ = x.shape
-    taps = cfg.mamba_d_conv - 1
-    n_rep = cfg.n_heads // cfg.n_kv_heads
-    hk, hv, tail0 = history if history is not None else (None, None, None)
-    P = 0 if hk is None else hk.shape[-1]
-    snapshot = page_tokens * cfg.snapshot_every if page_tokens else None
-    n_snap = S // snapshot if snapshot else 0
-    ks, vs, tails, ends = [], [], [], []
-    attn_at = mamba_at = 0
-    for seg in segments(cfg):
-        n_attn = sum(k.operator == "attention" for k in seg.period)
-        n_mamba = len(seg.period) - n_attn
-
-        def cut(a, at, n):
-            """Rows [at, at + repeats * n) of a per-layer array as scan
-            inputs [repeats, n, ...]."""
-            a = a[at:at + seg.repeats * n]
-            return a.reshape((seg.repeats, n) + a.shape[1:])
-
-        subs = params[seg.key] if len(seg.period) > 1 else (params[seg.key],)
-        xs = {"lp": tuple(subs)}
-        if hk is not None and n_attn:
-            xs["hk"], xs["hv"] = (cut(hk, attn_at, n_attn),
-                                  cut(hv, attn_at, n_attn))
-        if tail0 is not None and n_mamba:
-            xs["tail"] = jax.tree.map(lambda t: cut(t, mamba_at, n_mamba),
-                                      tail0)
-
-        def body(x, xs, seg=seg):
-            kv, st, a, m = [], [], 0, 0
-            for kind, lp in zip(seg.period, xs["lp"]):
-                if kind.operator == "attention":
-                    q, k, v = _qkv(cfg, lp, x)
-                    if "hk" in xs:
-                        kcat = jnp.concatenate(
-                            [xs["hk"][a][None].astype(x.dtype),
-                             to_cache_layout(k)], axis=-1)
-                        vcat = jnp.concatenate(
-                            [xs["hv"][a][None].astype(x.dtype),
-                             to_cache_layout(v)], axis=-1)
-                        o = dense_decode_attend(q, kcat, vcat, P, P + S,
-                                                n_rep)
-                    else:
-                        o = _self_attend(cfg, q, k, v)
-                    x = _attn_out(cfg, lp, x, o)
-                    kv.append((k, v))
-                    a += 1
-                else:
-                    before, h0 = ((xs["tail"]["conv"][m][None],
-                                   xs["tail"]["ssm"][m][None])
-                                  if "tail" in xs else (None, None))
-                    x, us, snaps, end = _mamba_seq(cfg, lp, x, before, h0,
-                                                   last_index, snapshot)
-                    if page_tokens is not None:
-                        # the window after token t: rows t + 1 .. t + taps
-                        at_ends = jnp.stack(
-                            [us[0, (j + 1) * snapshot:(j + 1) * snapshot
-                                + taps].reshape(-1) for j in range(n_snap)]
-                        ) if n_snap else jnp.zeros((0, taps * cfg.d_inner),
-                                                   x.dtype)
-                        st.append((
-                            {"conv": at_ends, "ssm": snaps[0]},
-                            {"conv": lax.dynamic_slice_in_dim(
-                                us[0], last_index + 1, taps,
-                                axis=0).reshape(-1), "ssm": end[0]}))
-                    m += 1
-                x = _ffn(cfg, lp, x)
-            return x, (tuple(kv), tuple(st))
-
-        x, (kv, st) = lax.scan(body, x, xs)
-        ks.append([k for k, _ in kv])
-        vs.append([v for _, v in kv])
-        tails.append([t for t, _ in st])
-        ends.append([e for _, e in st])
-        attn_at += seg.repeats * n_attn
-        mamba_at += seg.repeats * n_mamba
-
-    def leaves(per_segment):
-        """_by_layer over each leaf of the layers' state trees."""
-        if not any(per_segment):
-            return None
-        return {name: _by_layer([[t[name] for t in outs]
-                                 for outs in per_segment])
-                for name in ("conv", "ssm")}
-    return x, _by_layer(ks), _by_layer(vs), leaves(tails), leaves(ends)
-
-
 def forward(params: Params, cfg: JambaConfig, tokens: jax.Array) -> jax.Array:
     """tokens [B, S] int32 -> logits [B, S, vocab] (f32): the plain
     whole-sequence pass, no cache."""
-    x = params["embed"][tokens].astype(cfg.dtype)
-    return _head(params, cfg, _sequence_pass(params, cfg, x)[0])
-
-
-def _prefilled(params, cfg, x, ks, vs, tails, ends, last_index, kv_int8):
-    from mpi_acx_tpu.models.decoding import pack_kv
-    x = lax.dynamic_slice_in_dim(x, last_index, 1, axis=1)
-    one = pack_kv(ks, vs, kv_int8)
-    one["tail"], one["end"] = tails, ends
-    return _head(params, cfg, x), one
-
-
-def prefill(params: Params, cfg: JambaConfig, tokens: jax.Array, last_index,
-            kv_int8: bool = False, page_tokens: Optional[int] = None):
-    """``PagedSpec.prefill``: one prompt [1, S] (bucket-padded, its real
-    last token at ``last_index``) -> (logits [1, 1, vocab] there,
-    ``one``: the attention layers' K/V in cache layout and the Mamba
-    layers' snapshots and end state, ``kvpage.PagedSpec``'s docstring)."""
-    x = params["embed"][tokens].astype(cfg.dtype)
-    x, *got = _sequence_pass(params, cfg, x, page_tokens=page_tokens,
-                             last_index=last_index)
-    return _prefilled(params, cfg, x, *got, last_index, kv_int8)
-
-
-def suffix_prefill(params: Params, cfg: JambaConfig, suffix: jax.Array, hk,
-                   hv, tail, last_index, kv_int8: bool = False,
-                   page_tokens: Optional[int] = None):
-    """``PagedSpec.suffix_prefill``: only the suffix [1, S_suf] of a
-    prompt whose first P tokens are paged in (a radix hit, cut back to a
-    page that holds a snapshot): attention against the gathered history
-    ``hk``/``hv`` [L_attn, Hkv, Dh, P], each Mamba layer from the
-    snapshot ``tail``."""
-    x = params["embed"][suffix].astype(cfg.dtype)
-    x, *got = _sequence_pass(params, cfg, x, history=(hk, hv, tail),
-                             page_tokens=page_tokens, last_index=last_index)
-    return _prefilled(params, cfg, x, *got, last_index, kv_int8)
+    return kvpage.forward(params, cfg, paged_spec(cfg), tokens)
 
 
 # -- the paged plane's seam --------------------------------------------------
@@ -483,6 +351,8 @@ def paged_spec(cfg: JambaConfig) -> kvpage.PagedSpec:
     int8 pages are not wired: the state would want a precision of its
     own."""
     taps = cfg.mamba_d_conv - 1
+    # (no position enters: a token's and a whole sequence's alike)
+    qkv = lambda cfg, lp, x, pos: attention_qkv(cfg, lp, x)  # noqa: E731
     return kvpage.PagedSpec(
         segments=segments(cfg),
         n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim,
@@ -494,7 +364,6 @@ def paged_spec(cfg: JambaConfig) -> kvpage.PagedSpec:
         ffn_built=(("dense", "_ffn"),),
         embed=lambda params, cfg, token, pos:
             params["embed"][token][:, None, :].astype(cfg.dtype),
-        qkv=lambda cfg, lp, x, pos: _qkv(cfg, lp, x),
-        attn_out=_attn_out, state_op=_mamba_step, ffn=_ffn,
+        qkv=qkv, attn_out=attention_out, state_op=_mamba_step, ffn=_ffn,
         head=lambda params, cfg, x: _head(params, cfg, x)[:, 0],
-        prefill=prefill, suffix_prefill=suffix_prefill)
+        seq_qkv=qkv, seq_state=_mamba_seq, seq_head=_head)

@@ -34,7 +34,7 @@ block table:
 * **Radix prefix cache** — :class:`RadixPrefixCache`: a trie over
   full-page token chunks, so requests sharing a system prompt store
   the shared pages ONCE; a prefix hit seats the cached pages and
-  prefill runs only on the suffix (:func:`prefill_with_history`).
+  prefill runs only on the suffix (:func:`prefill` with a history).
   Shared pages are never written (the matched depth is capped so the
   suffix always starts at a page boundary with >= 1 fresh token);
   copy-on-write (:meth:`PagedKV.ensure_writable`) guards the
@@ -56,9 +56,11 @@ What the plane asks of a model family it asks through ONE seam,
 kind (attention | conv | mamba | mamba2 | none), an FFN kind (dense | moe |
 none) and a cache kind (pages | state | none), the layers grouped into :class:`Segment` s of whole
 periods that one ``lax.scan`` each can ride, and the family's own
-functions for each piece. :func:`paged_decode_step`,
-``serving.paged_prefill`` / ``paged_suffix_prefill`` and
-:class:`PagedKV` read nothing else of a family. GPT-2
+functions for each piece. :func:`paged_decode_step`, its
+whole-sequence sibling :func:`sequence_pass` (under :func:`prefill`,
+which ``serving.paged_prefill`` / ``paged_suffix_prefill`` run, and
+:func:`forward`) and :class:`PagedKV` read nothing else of a family:
+a family is its operators and its spec. GPT-2
 (``transformer.paged_spec``) is "every layer attention + dense + pages";
 ``lfm2`` mixes both operator kinds and both FFN kinds, ``jamba`` Mamba
 and attention layers. A family without ``paged_spec`` raises by name.
@@ -435,6 +437,16 @@ def compress_layers(kinds, prefix: str = "seg") -> Tuple[Segment, ...]:
     return tuple(out)
 
 
+def cast_params(params, dtype, f32: Tuple[str, ...] = ()):
+    """A family's tree (stacked by segment, leaves by name) in ``dtype``
+    for inference; the leaves named in ``f32`` and every norm's weight
+    (``"norm"`` in its name) stay float32: they are computed in it."""
+    def cast(path, p):
+        name = path[-1].key
+        return p if name in f32 or "norm" in name else p.astype(dtype)
+    return jax.tree_util.tree_map_with_path(cast, params)
+
+
 @dataclasses.dataclass(frozen=True)
 class PagedSpec:
     """What the paged plane asks of a family (module docstring). The
@@ -460,17 +472,37 @@ class PagedSpec:
       also takes ``live=`` ([B] bool, or None: the rows whose result
       anybody receives; the others' experts need not be computed)
     * ``head(params, cfg, x) -> logits [B, vocab]`` f32
-    * ``prefill(params, cfg, tokens [1, S], last_index, kv_int8,
-      page_tokens) -> (logits [1, 1, vocab], one)``: ``one`` holds
-      ``'k','v'[,'ks','vs']`` ``[L_pages, 1, Hkv, *, S]`` in cache layout
-      and, with state layers, ``'tail'`` ``[L_state, S // (page_tokens
-      * snapshot_every), *leaf]`` a leaf (the state at the end of every
-      ``snapshot_every``-th whole page) and ``'end'`` ``[L_state,
-      *leaf]`` (at ``last_index``; padding behind it leaves no mark)
-    * ``suffix_prefill(params, cfg, suffix, hk, hv, tail, last_index,
-      kv_int8, page_tokens)``: the same for a suffix behind gathered
-      history ``hk``/``hv`` and the last matched page's snapshot
-      ``tail`` ``[L_state, *leaf]``."""
+
+    and the same operators over a WHOLE sequence, ``x`` ``[B, S, d]`` at
+    ``positions`` [S], which :func:`sequence_pass` (``prefill``,
+    ``forward``) calls:
+
+    * ``seq_head(params, cfg, x) -> logits [B, S, vocab]`` f32 (the
+      pass embeds by itself: the tokens' rows of ``params["embed"]`` in
+      ``cfg.dtype``, what every family with this pass does)
+    * ``seq_qkv(cfg, lp, x, positions) -> q [B, S, Hq, Dh], k, v [B, S,
+      Hkv, Dh]``; the pass attends (causally on itself through the
+      shared flash / dense policy, ``cfg.use_flash``; behind a gathered
+      history densely on ``concat(history, own)``), then ``attn_out``.
+      Of a LATENT pool instead the layer whole, of ONE sequence:
+      ``seq_attention(cfg, lp, x [1, S, d], positions, history) -> (x,
+      rows [S, D])``, ``history`` the cached rows ``[D, P]`` (a pool's
+      layout) or None
+    * ``seq_state(cfg, lp, x, start, last_index, snapshot) -> (x, tails,
+      end)``: a ``state`` layer over the sequence, residual included.
+      ``start``: the layer's state before ``x``, ``[1, *leaf]`` a leaf
+      (a suffix prefill has ONE sequence), or None at a sequence's
+      start. With ``snapshot`` (tokens; None: ``forward``, and nothing
+      is cut) ``tails`` ``[S // snapshot, *leaf]`` a leaf, the state
+      after every ``snapshot``-th token, and ``end`` ``[*leaf]`` after
+      ``last_index``, both of sequence 0: positions past ``last_index``
+      are padding and leave no mark.
+
+    ``prefill`` / ``suffix_prefill``: a family's OWN whole-sequence
+    programs in place of :func:`prefill` 's (arguments and results as
+    there, without the spec). GPT-2 alone sets them (``transformer.
+    prefill``, which the fixed-slot planes share, and
+    :func:`prefill_with_history`): ROADMAP.md, Queue 3."""
     segments: Tuple[Segment, ...]
     n_kv_heads: int
     head_dim: int
@@ -479,7 +511,7 @@ class PagedSpec:
     # holds ONE row of ``head_dim`` values a token (``n_kv_heads`` 1),
     # read by all ``n_rep`` query heads, whose first ``v_dim`` values
     # ARE the value: there is no V pool, ``qkv`` returns ``(q, row)``,
-    # ``prefill`` 's ``one`` holds ``'k'`` alone, ``suffix_prefill`` is
+    # a prefill's ``one`` holds ``'k'`` alone, a suffix prefill is
     # handed ``hv`` None, and the attend's result is ``v_dim`` wide a
     # head. ``attn_scale``: the decode attend's factor on the scores
     # where it is not ``1 / sqrt(head_dim)``.
@@ -520,6 +552,10 @@ class PagedSpec:
     state_op: Callable = None
     ffn: Callable = None
     head: Callable = None
+    seq_qkv: Callable = None
+    seq_attention: Callable = None
+    seq_state: Callable = None
+    seq_head: Callable = None
     prefill: Callable = None
     suffix_prefill: Callable = None
 
@@ -621,6 +657,25 @@ def _moe_tally(idx, owns, n_experts: int, held=None, kept=None, live=None):
     return jnp.stack([hits.sum(), (mine > 0).sum().astype(jnp.int32),
                       mine.max(), jnp.int32(1), mine.sum(),
                       (kept[:, group] & owns).sum().astype(jnp.int32), dead])
+
+
+def _split_leaves(spec: PagedSpec, seg: Segment, stacked):
+    """``params[seg.key]`` as one ``(scanned, whole)`` pair of dicts a
+    layer of the period: ``whole`` the leaves an expert layer is handed
+    still stacked over the segment's repeats (``spec.moe_whole``),
+    ``scanned`` the rest, of which a scan's body sees one repeat."""
+    subs = stacked if len(seg.period) > 1 else (stacked,)
+    names = [spec.moe_whole if kind.ffn == "moe" else ()
+             for kind in seg.period]
+    return [({n: a for n, a in sub.items() if n not in whole},
+             {n: sub[n] for n in whole}) for sub, whole in zip(subs, names)]
+
+
+def _one_layer(scanned, whole, repeat):
+    """One layer's ``lp`` at ``repeat`` of its segment: its own slice of
+    the ``scanned`` leaves and, where it has ``whole`` ones, those and
+    ``lp["repeat"]`` to find its own in them."""
+    return dict(scanned, **whole, repeat=repeat) if whole else scanned
 
 
 def paged_decode_step(params, cfg, state, token, page_tokens: int,
@@ -758,24 +813,19 @@ def paged_decode_step(params, cfg, state, token, page_tokens: int,
     pages_at = states_at = 0
     for seg in spec.segments:
         stacked = params[seg.key]
+        split = _split_leaves(spec, seg, stacked)
         n_pg = sum(k.cache == "pages" for k in seg.period)
         n_st = sum(k.cache == "state" for k in seg.period)
 
-        def body(carry, i, seg=seg, stacked=stacked, n_pg=n_pg, n_st=n_st,
+        def body(carry, i, seg=seg, split=split, n_pg=n_pg, n_st=n_st,
                  pages_at=pages_at, states_at=states_at):
             x, pools, rest = carry
-            one = len(seg.period) == 1
             pg = st = 0
-            for j, kind in enumerate(seg.period):
-                whole = spec.moe_whole if kind.ffn == "moe" else ()
-                lp = jax.tree.map(
+            for kind, (scanned, whole) in zip(seg.period, split):
+                lp = _one_layer(jax.tree.map(
                     lambda a: lax.dynamic_index_in_dim(a, i, 0,
                                                        keepdims=False),
-                    {n: a for n, a in (stacked if one else stacked[j]).items()
-                     if n not in whole})
-                if whole:
-                    lp.update({n: (stacked if one else stacked[j])[n]
-                               for n in whole}, repeat=i)
+                    scanned), whole, i)
                 x, pools, rest = layer(
                     kind, lp, x, pools, rest,
                     nth(pages_at, i, n_pg, pg) if kind.cache == "pages"
@@ -901,7 +951,191 @@ def make_paged_step_fn(params, cfg, family, chunk: int,
 
 
 # --------------------------------------------------------------------------
-# Prefix-hit suffix prefill
+# Whole sequences (any family, through its PagedSpec): the pass, prefill
+# cold and behind a prefix hit, forward
+
+
+def _by_layer(per_segment):
+    """Scan outputs ``[(segment, [a tree of [repeats, ...] leaves a
+    layer of the period that has one])]`` -> the tree with leaves
+    ``[layers, ...]`` in model order (repeat-major inside a segment), or
+    None when no layer has one."""
+    per_segment = [outs for outs in per_segment if outs]
+    if not per_segment:
+        return None
+
+    def leaf(*arrays):      # this leaf of every (segment, layer) above
+        arrays, parts = iter(arrays), []
+        for outs in per_segment:
+            mine = [next(arrays) for _ in outs]
+            parts.append(jnp.stack(mine, axis=1).reshape(
+                (len(mine) * mine[0].shape[0],) + mine[0].shape[1:]))
+        return jnp.concatenate(parts, axis=0)
+    return jax.tree.map(leaf, *(o for outs in per_segment for o in outs))
+
+
+def sequence_pass(params, cfg, spec: PagedSpec, x, positions, history=None,
+                  page_tokens: Optional[int] = None, last_index=None):
+    """``x`` [B, S, d] at ``positions`` [S] through every layer: the
+    whole-sequence sibling of :func:`paged_decode_step`, one
+    ``lax.scan`` a :class:`Segment` over the family's sequence operators
+    (:class:`PagedSpec`). ``history`` = ``(hk, hv, tail)``: the sequence
+    (B = 1) continues one whose first P positions are cached: a page
+    layer sees the gathered ``hk`` / ``hv`` ``[L_pages, Hkv, Dh, P]``
+    (of a latent pool the rows ``hk`` alone, ``hv`` None) in front of
+    its own, a state layer starts from ``tail`` (``[L_state, *leaf]`` a
+    leaf; None without state layers). Returns ``(x, fresh, tails,
+    ends)``: the page layers' ``(k, v)`` ``[L_pages, B, S, Hkv, Dh]`` as
+    ``qkv`` gives them (of a latent pool ``(rows,)`` ``[L_pages, S,
+    D]``), and with ``page_tokens`` the state layers' ``tails`` (leaves
+    ``[L_state, S // (page_tokens * snapshot_every), *leaf]``: the state
+    at the end of every page that keeps a snapshot) and ``ends``
+    (``[L_state, *leaf]``: at ``last_index``), else None, None; all in
+    model order."""
+    from mpi_acx_tpu.models.decoding import (dense_decode_attend,
+                                             to_cache_layout)
+    from mpi_acx_tpu.models.llama import _repeat_kv
+    from mpi_acx_tpu.ops.attention import select_attention
+    S = x.shape[1]
+    hk, hv, tail0 = history if history is not None else (None, None, None)
+    P = 0 if hk is None else hk.shape[-1]
+    snapshot = page_tokens * spec.snapshot_every if page_tokens else None
+
+    def page_layer(lp, x, xs, pg):
+        """A layer with pages -> (x, what it leaves in them). ``xs``
+        holds the gathered history of the period's page layers, of
+        which this is ``pg``, where there is one."""
+        if spec.v_dim is not None:          # latent: the family's, whole
+            x, rows = spec.seq_attention(cfg, lp, x, positions, xs.get("hk"))
+            return x, (rows,)
+        q, k, v = spec.seq_qkv(cfg, lp, x, positions)
+        if "hk" in xs:
+            kcat, vcat = (jnp.concatenate(
+                [xs[h][pg][None].astype(x.dtype), to_cache_layout(own)],
+                axis=-1) for h, own in (("hk", k), ("hv", v)))
+            o = dense_decode_attend(q, kcat, vcat, P, P + S, spec.n_rep)
+        else:
+            # (the policy takes as many K/V heads as query heads: they
+            # are REPEATED for it, as llama's are)
+            o = select_attention(cfg.use_flash)(
+                q, _repeat_kv(k, spec.n_rep), _repeat_kv(v, spec.n_rep))
+            o = o.reshape(q.shape[0], S, -1)
+        return spec.attn_out(cfg, lp, x, o), (k, v)
+
+    fresh, tails, ends = [], [], []
+    pages_at = states_at = 0
+    for seg in spec.segments:
+        split = _split_leaves(spec, seg, params[seg.key])
+        n_pg = sum(k.cache == "pages" for k in seg.period)
+        n_st = sum(k.cache == "state" for k in seg.period)
+
+        def cut(a, at, n):
+            """Rows [at, at + repeats * n) of a per-layer array as scan
+            inputs [repeats, n, ...]."""
+            a = a[at:at + seg.repeats * n]
+            return a.reshape((seg.repeats, n) + a.shape[1:])
+
+        xs = {"lp": tuple(scanned for scanned, _ in split)}
+        if spec.moe_whole:              # the repeat, to find a layer's own
+            xs["i"] = jnp.arange(seg.repeats)
+        if hk is not None and n_pg:
+            if spec.v_dim is None:
+                xs["hk"], xs["hv"] = (cut(hk, pages_at, n_pg),
+                                      cut(hv, pages_at, n_pg))
+            else:
+                # a latent layer stands alone in its period: the rows of
+                # its one head ride the scan as they lie, [repeats, D, P]
+                assert n_pg == 1, seg
+                xs["hk"] = hk[pages_at:pages_at + seg.repeats, 0]
+        if tail0 is not None and n_st:
+            xs["tail"] = jax.tree.map(lambda t: cut(t, states_at, n_st),
+                                      tail0)
+
+        def body(x, xs, seg=seg, split=split):
+            kv, kept, pg, st = [], [], 0, 0
+            for kind, scanned, (_, whole) in zip(seg.period, xs["lp"],
+                                                 split):
+                lp = _one_layer(scanned, whole, xs.get("i"))
+                if kind.cache == "pages":
+                    x, new = page_layer(lp, x, xs, pg)
+                    kv.append(new)
+                    pg += 1
+                elif kind.cache == "state":
+                    start = (jax.tree.map(lambda t: t[st][None], xs["tail"])
+                             if "tail" in xs else None)
+                    x, tail, end = spec.seq_state(cfg, lp, x, start,
+                                                  last_index, snapshot)
+                    if snapshot is not None:
+                        kept.append((tail, end))
+                    st += 1
+                if kind.ffn == "moe":
+                    x = spec.ffn(cfg, lp, x, kind.ffn)[0]
+                elif kind.ffn != "none":
+                    x = spec.ffn(cfg, lp, x, kind.ffn)
+            return x, (tuple(kv), tuple(kept))
+
+        x, (kv, kept) = lax.scan(body, x, xs)
+        fresh.append(kv)
+        tails.append([t for t, _ in kept])
+        ends.append([e for _, e in kept])
+        pages_at += seg.repeats * n_pg
+        states_at += seg.repeats * n_st
+    return x, _by_layer(fresh), _by_layer(tails), _by_layer(ends)
+
+
+def forward(params, cfg, spec: PagedSpec, tokens):
+    """tokens [B, S] int32 -> logits [B, S, vocab] (f32): a family's
+    plain whole-sequence pass, no cache."""
+    x = params["embed"][tokens].astype(cfg.dtype)
+    x = sequence_pass(params, cfg, spec, x, jnp.arange(tokens.shape[1]))[0]
+    return spec.seq_head(params, cfg, x)
+
+
+def prefill(params, cfg, spec: PagedSpec, tokens, last_index,
+            kv_int8: bool = False, page_tokens: Optional[int] = None,
+            history=None):
+    """What ``serving.paged_prefill`` / ``paged_suffix_prefill`` run.
+    One prompt ``tokens`` [1, S] (bucket-padded, its real last token at
+    ``last_index``) -> (logits [1, 1, vocab] there, ``one``). ``one``
+    holds ``'k','v'[,'ks','vs']`` ``[L_pages, 1, Hkv, *, S]`` in cache
+    layout (:func:`decoding.pack_kv`; of a latent pool ``'k'`` alone,
+    the rows) ready for :meth:`PagedKV.scatter_prompt` and, with state
+    layers and ``page_tokens``, ``'tail'`` ``[L_state, S // (page_tokens
+    * snapshot_every), *leaf]`` a leaf (the state at the end of every
+    ``snapshot_every``-th whole page) and ``'end'`` ``[L_state, *leaf]``
+    (at ``last_index``; padding behind it leaves no mark).
+
+    With ``history`` = ``(hk, hv, tail)`` ``tokens`` is only the SUFFIX
+    of a prompt whose first P tokens are paged in (a radix hit, with
+    state layers cut back to a page that holds a snapshot), at positions
+    ``P ..``: :meth:`PagedKV.gather_history` 's ``hk`` / ``hv`` and
+    :meth:`PagedKV.restore_tail` 's ``tail``, as :func:`sequence_pass`
+    takes them. The compute skipped is the point: a hit at depth P runs
+    S rows through the trunk instead of P + S. The cost is bitwise
+    freedom: the shapes differ from the cold pass's, so hit-path logits
+    match cold only to numerics (docs/DESIGN.md §19)."""
+    from mpi_acx_tpu.models.decoding import pack_kv, to_cache_layout
+    if spec.prefill is not None:            # GPT-2's own: PagedSpec
+        if history is None:
+            return spec.prefill(params, cfg, tokens, last_index, kv_int8,
+                                page_tokens)
+        return spec.suffix_prefill(params, cfg, tokens, *history, last_index,
+                                   kv_int8, page_tokens)
+    x = params["embed"][tokens].astype(cfg.dtype)
+    positions = jnp.arange(tokens.shape[1])
+    if history is not None:
+        positions = history[0].shape[-1] + positions
+    x, fresh, tails, ends = sequence_pass(
+        params, cfg, spec, x, positions, history, page_tokens, last_index)
+    x = lax.dynamic_slice_in_dim(x, last_index, 1, axis=1)
+    if spec.v_dim is not None:
+        # rows [L, S, D] -> the pool's [L, 1, 1 head, D, S]
+        one = {"k": to_cache_layout(fresh[0][:, None, :, None, :])}
+    else:
+        one = pack_kv(*fresh, kv_int8)
+    if tails is not None:
+        one["tail"], one["end"] = tails, ends
+    return spec.seq_head(params, cfg, x), one
 
 
 def prefill_with_history(params, cfg, suffix, hk, hv, last_index,

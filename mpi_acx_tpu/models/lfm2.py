@@ -41,7 +41,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from mpi_acx_tpu.models import kvpage, moe
-from mpi_acx_tpu.models.llama import _repeat_kv, rmsnorm, rope
+from mpi_acx_tpu.models.llama import rmsnorm, rope
 
 _PUBLISHED_LAYERS = ("conv", "conv") + (
     "full_attention", "conv", "conv", "conv") * 9 + ("full_attention", "conv")
@@ -174,11 +174,7 @@ def init_params(key: jax.Array, cfg: Lfm2Config) -> Params:
 def cast_params(params: Params, dtype=jnp.bfloat16) -> Params:
     """The tree in ``dtype`` for inference; the router (``gate``,
     ``bias``) and the norms stay f32: they are computed in f32."""
-    def cast(path, p):
-        name = path[-1].key
-        keep = name in ("gate", "bias") or name.endswith("norm")
-        return p if keep else p.astype(dtype)
-    return jax.tree_util.tree_map_with_path(cast, params)
+    return kvpage.cast_params(params, dtype, ("gate", "bias"))
 
 
 # -- the layer functions -----------------------------------------------------
@@ -209,18 +205,6 @@ def _qkv(cfg: Lfm2Config, lp: Params, x: jax.Array, positions: jax.Array):
 
 def _attn_out(cfg: Lfm2Config, lp: Params, x: jax.Array, o: jax.Array):
     return x + o @ _w(lp, "wo", x.dtype)
-
-
-def _self_attend(cfg: Lfm2Config, q, k, v):
-    """Causal attention of a whole sequence on itself, through the
-    shared flash/dense policy (ops/attention.py), which takes as many
-    K/V heads as query heads: the K/V heads are REPEATED for it, as
-    llama's are. [B, S, Hq * Dh]."""
-    from mpi_acx_tpu.ops.attention import select_attention
-    n_rep = cfg.n_heads // cfg.n_kv_heads
-    o = select_attention(cfg.use_flash)(q, _repeat_kv(k, n_rep),
-                                        _repeat_kv(v, n_rep))
-    return o.reshape(q.shape[0], q.shape[1], -1)
 
 
 def _conv_op(cfg: Lfm2Config, lp: Params, x: jax.Array, z_before: jax.Array):
@@ -273,157 +257,29 @@ def _head(params: Params, cfg: Lfm2Config, x: jax.Array):
                       preferred_element_type=jnp.float32)
 
 
-# -- whole sequences: forward, prefill, suffix prefill -----------------------
-
-
-def _by_layer(per_segment):
-    """Scan outputs ``[(segment, [[repeats, ...] a layer of the period
-    that has one])]`` -> ``[layers, ...]`` in model order (repeat-major
-    inside a segment), or None when no layer has one."""
-    parts = [jnp.stack(outs, axis=1).reshape(
-        (len(outs) * outs[0].shape[0],) + outs[0].shape[1:])
-        for outs in per_segment if outs]
-    return jnp.concatenate(parts, axis=0) if parts else None
-
-
-def _sequence_pass(params: Params, cfg: Lfm2Config, x: jax.Array, positions,
-                   history=None, page_tokens=None, last_index=None):
-    """x [B, S, d] through every layer. ``history`` = (hk, hv [L_attn,
-    Hkv, Dh, P], tail [L_conv, taps, d]): the sequence continues one
-    whose first P positions are cached (B = 1): attention sees the
-    history's keys and values before its own, each conv starts from the
-    tail. Returns (x, k, v [L_attn, B, S, Hkv, Dh], and with
-    ``page_tokens`` the conv layers' ``tail`` [L_conv, S // page_tokens,
-    taps, d] at the end of every whole page and ``end`` [L_conv, taps,
-    d] at ``last_index``, else None, None)."""
-    from mpi_acx_tpu.models.decoding import (dense_decode_attend,
-                                             to_cache_layout)
+def _conv_seq(cfg: Lfm2Config, lp: Params, x: jax.Array, start, last_index,
+              snapshot):
+    """``PagedSpec.seq_state``: the gated short conv over whole
+    sequences from the ``taps`` gated inputs before them (``start`` [1,
+    taps, d]; None: zeros) and, with ``snapshot``, the last ``taps`` of
+    every ``snapshot`` tokens and those at ``last_index``."""
     B, S, _ = x.shape
     taps = cfg.conv_L_cache - 1
-    n_rep = cfg.n_heads // cfg.n_kv_heads
-    hk, hv, tail0 = history if history is not None else (None, None, None)
-    P = 0 if hk is None else hk.shape[-1]
-    ks, vs, tails, ends = [], [], [], []
-    attn_at = conv_at = 0
-    for seg in segments(cfg):
-        n_attn = sum(k.operator == "attention" for k in seg.period)
-        n_conv = len(seg.period) - n_attn
-
-        def cut(a, at, n):
-            """Rows [at, at + repeats * n) of a per-layer array as
-            scan inputs [repeats, n, ...]."""
-            a = a[at:at + seg.repeats * n]
-            return a.reshape((seg.repeats, n) + a.shape[1:])
-
-        # The expert stacks stay out of the scan's slicing (moe.
-        # sorted_expert_ffn, ``layer``): closed over whole.
-        subs = params[seg.key] if len(seg.period) > 1 else (params[seg.key],)
-        whole = tuple({n: a for n, a in sub.items()
-                       if kind.ffn == "moe" and n in _EXPERT_STACKS}
-                      for kind, sub in zip(seg.period, subs))
-        xs = {"lp": tuple({n: a for n, a in sub.items() if n not in held}
-                          for sub, held in zip(subs, whole)),
-              "i": jnp.arange(seg.repeats)}
-        if hk is not None and n_attn:
-            xs["hk"], xs["hv"] = (cut(hk, attn_at, n_attn),
-                                  cut(hv, attn_at, n_attn))
-        if tail0 is not None and n_conv:
-            xs["tail"] = cut(tail0, conv_at, n_conv)
-
-        def body(x, xs, seg=seg, whole=whole):
-            kv, zt, a, c = [], [], 0, 0
-            for kind, lp, held in zip(seg.period, xs["lp"], whole):
-                if held:
-                    lp = dict(lp, **held, repeat=xs["i"])
-                if kind.operator == "attention":
-                    q, k, v = _qkv(cfg, lp, x, positions)
-                    if "hk" in xs:
-                        kcat = jnp.concatenate(
-                            [xs["hk"][a][None].astype(x.dtype),
-                             to_cache_layout(k)], axis=-1)
-                        vcat = jnp.concatenate(
-                            [xs["hv"][a][None].astype(x.dtype),
-                             to_cache_layout(v)], axis=-1)
-                        o = dense_decode_attend(q, kcat, vcat, P, P + S,
-                                                n_rep)
-                    else:
-                        o = _self_attend(cfg, q, k, v)
-                    x = _attn_out(cfg, lp, x, o)
-                    kv.append((k, v))
-                    a += 1
-                else:
-                    before = (jnp.broadcast_to(xs["tail"][c][None],
-                                               (B, taps, cfg.d_model))
-                              if "tail" in xs else
-                              jnp.zeros((B, taps, cfg.d_model), x.dtype))
-                    x, zs = _conv_op(cfg, lp, x, before)
-                    if page_tokens is not None:
-                        n = S // page_tokens
-                        pages = zs[0, taps:taps + n * page_tokens].reshape(
-                            n, page_tokens, cfg.d_model)
-                        zt.append((
-                            pages[:, page_tokens - taps:],
-                            lax.dynamic_slice_in_dim(zs[0], last_index + 1,
-                                                     taps, axis=0)))
-                    c += 1
-                x = (_dense_ffn(cfg, lp, x) if kind.ffn == "dense"
-                     else _moe_ffn(cfg, lp, x)[0])
-            return x, (tuple(kv), tuple(zt))
-
-        x, (kv, zt) = lax.scan(body, x, xs)
-        ks.append([k for k, _ in kv])
-        vs.append([v for _, v in kv])
-        tails.append([t for t, _ in zt])
-        ends.append([e for _, e in zt])
-        attn_at += seg.repeats * n_attn
-        conv_at += seg.repeats * n_conv
-    return x, _by_layer(ks), _by_layer(vs), _by_layer(tails), _by_layer(ends)
+    before = (jnp.zeros((B, taps, cfg.d_model), x.dtype) if start is None
+              else jnp.broadcast_to(start, (B, taps, cfg.d_model)))
+    x, zs = _conv_op(cfg, lp, x, before)
+    if snapshot is None:
+        return x, None, None
+    n = S // snapshot
+    pages = zs[0, taps:taps + n * snapshot].reshape(n, snapshot, cfg.d_model)
+    return (x, pages[:, snapshot - taps:],
+            lax.dynamic_slice_in_dim(zs[0], last_index + 1, taps, axis=0))
 
 
 def forward(params: Params, cfg: Lfm2Config, tokens: jax.Array) -> jax.Array:
     """tokens [B, S] int32 -> logits [B, S, vocab] (f32): the plain
     whole-sequence pass, no cache."""
-    x = params["embed"][tokens].astype(cfg.dtype)
-    x = _sequence_pass(params, cfg, x, jnp.arange(tokens.shape[1]))[0]
-    return _head(params, cfg, x)
-
-
-def _prefilled(params, cfg, x, ks, vs, tails, ends, last_index, kv_int8):
-    from mpi_acx_tpu.models.decoding import pack_kv
-    x = lax.dynamic_slice_in_dim(x, last_index, 1, axis=1)
-    one = pack_kv(ks, vs, kv_int8)
-    if tails is not None:
-        one["tail"], one["end"] = tails, ends
-    return _head(params, cfg, x), one
-
-
-def prefill(params: Params, cfg: Lfm2Config, tokens: jax.Array, last_index,
-            kv_int8: bool = False, page_tokens: Optional[int] = None):
-    """``PagedSpec.prefill``: one prompt [1, S] (bucket-padded, its
-    real last token at ``last_index``) -> (logits [1, 1, vocab] there,
-    ``one``: the attention layers' K/V in cache layout and the conv
-    layers' tails and end state, ``kvpage.PagedSpec``'s docstring)."""
-    S = tokens.shape[1]
-    x = params["embed"][tokens].astype(cfg.dtype)
-    x, *got = _sequence_pass(params, cfg, x, jnp.arange(S),
-                             page_tokens=page_tokens, last_index=last_index)
-    return _prefilled(params, cfg, x, *got, last_index, kv_int8)
-
-
-def suffix_prefill(params: Params, cfg: Lfm2Config, suffix: jax.Array, hk, hv,
-                   tail, last_index, kv_int8: bool = False,
-                   page_tokens: Optional[int] = None):
-    """``PagedSpec.suffix_prefill``: only the suffix [1, S_suf] of a
-    prompt whose first P tokens are paged in (a radix hit): attention
-    against the gathered history ``hk``/``hv`` [L_attn, Hkv, Dh, P]
-    (positions P.., keys cached post-RoPE), each conv from the last
-    matched page's ``tail`` [L_conv, taps, d]."""
-    P, S = hk.shape[-1], suffix.shape[1]
-    x = params["embed"][suffix].astype(cfg.dtype)
-    x, *got = _sequence_pass(params, cfg, x, P + jnp.arange(S),
-                             history=(hk, hv, tail), page_tokens=page_tokens,
-                             last_index=last_index)
-    return _prefilled(params, cfg, x, *got, last_index, kv_int8)
+    return kvpage.forward(params, cfg, paged_spec(cfg), tokens)
 
 
 # -- the paged plane's seam --------------------------------------------------
@@ -461,4 +317,4 @@ def paged_spec(cfg: Lfm2Config) -> kvpage.PagedSpec:
         qkv=lambda cfg, lp, x, pos: _qkv(cfg, lp, x, pos[:, None]),
         attn_out=_attn_out, state_op=decode_conv, ffn=_ffn,
         head=lambda params, cfg, x: _head(params, cfg, x)[:, 0],
-        prefill=prefill, suffix_prefill=suffix_prefill)
+        seq_qkv=_qkv, seq_state=_conv_seq, seq_head=_head)

@@ -57,9 +57,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from mpi_acx_tpu.models import kvpage, moe
-from mpi_acx_tpu.models.jamba import (_attn_out, _conv_taps, _qkv,
-                                      _self_attend, _window)
-from mpi_acx_tpu.models.lfm2 import _by_layer
+from mpi_acx_tpu.models.jamba import (attention_out, attention_qkv,
+                                      conv_taps, conv_window, window_cuts)
 from mpi_acx_tpu.models.llama import rmsnorm
 from mpi_acx_tpu.ops import ssd
 
@@ -242,11 +241,7 @@ def init_params(key: jax.Array, cfg: NemotronHConfig,
 def cast_params(params: Params, dtype=jnp.bfloat16) -> Params:
     """The tree in ``dtype`` for inference; the norms, the router and the
     recurrence's own parameters stay f32."""
-    def cast(path, p):
-        name = path[-1].key
-        keep = name in _F32_LEAVES or "norm" in name
-        return p if keep else p.astype(dtype)
-    return jax.tree_util.tree_map_with_path(cast, params)
+    return kvpage.cast_params(params, dtype, _F32_LEAVES)
 
 
 # -- the layer functions -----------------------------------------------------
@@ -295,33 +290,33 @@ def _mixer_out(cfg: NemotronHConfig, lp: Params, y, xs, z, dtype):
     return y.astype(dtype) @ _w(lp, "w_out", dtype)
 
 
-def _mamba_seq(cfg: NemotronHConfig, lp: Params, x: jax.Array, before, h0,
+def _mamba_seq(cfg: NemotronHConfig, lp: Params, x: jax.Array, start,
                last_index, snapshot):
-    """The Mamba-2 mixer with its residual over whole sequences x [B, S,
-    d]. ``before`` [B, taps * conv_dim] the conv's inputs at the ``taps =
-    conv_kernel - 1`` positions before x and ``h0`` [B, H, P, N] the
-    recurrence's state there (None: zeros, a sequence's start).
-    Positions past ``last_index`` (None: none) are padding and leave the
-    state as it was. Returns (x + y, ``us`` [B, taps + S, conv_dim]:
-    ``before`` then this call's conv inputs, whose rows ``t + 1 .. t +
-    taps`` are the window after token t; the state after every
-    ``snapshot`` tokens [B, S // snapshot, H, P, N] and after the
-    last)."""
+    """``PagedSpec.seq_state``: the Mamba-2 mixer with its residual over
+    whole sequences x [B, S, d], from ``start`` = {"conv": [1, taps *
+    conv_dim] the conv's inputs at the ``taps = conv_kernel - 1``
+    positions before x, "ssm": [1, H, P, N] the recurrence's state
+    there} (None: zeros, a sequence's start). Positions past
+    ``last_index`` (None: none) are padding and leave the state as it
+    was. Returns (x + y, and with ``snapshot`` sequence 0's state after
+    every ``snapshot`` tokens, leaves [S // snapshot, ...], and after
+    ``last_index``; else None, None)."""
     B, S, _ = x.shape
     taps = cfg.conv_kernel - 1
     z, u, dt = _split_in(cfg, rmsnorm(x, lp["norm1"], cfg.norm_eps)
                          @ _w(lp, "w_in", x.dtype))
-    before = (jnp.zeros((B, taps, cfg.conv_dim), x.dtype) if before is None
-              else jnp.stack(_window(before.astype(x.dtype), taps), axis=1))
+    before = (jnp.zeros((B, taps, cfg.conv_dim), x.dtype) if start is None
+              else jnp.stack(conv_window(start["conv"].astype(x.dtype),
+                                         taps), axis=1))
     us = jnp.concatenate([before, u], axis=1)
-    xs, b, c = _split_conv(cfg, _conv_taps(
+    xs, b, c = _split_conv(cfg, conv_taps(
         lp, [us[:, j:j + S] for j in range(taps + 1)]).astype(x.dtype))
     dt = _dt(lp, dt)
     if last_index is not None:
         dt = jnp.where((jnp.arange(S) <= last_index)[None, :, None], dt, 0.0)
     a = -jnp.exp(lp["A_log"].astype(F32))
     h0 = (jnp.zeros((B, cfg.mamba_heads, cfg.mamba_head_dim, cfg.ssm_state),
-                    F32) if h0 is None else h0)
+                    F32) if start is None else start["ssm"])
     scan = ssd.select_ssd(cfg.ssm_kernel)[1]
 
     def one(xs, dt, b, c, h0):
@@ -330,7 +325,12 @@ def _mamba_seq(cfg: NemotronHConfig, lp: Params, x: jax.Array, before, h0,
     y, snaps, end = (tuple(t[None] for t in one(xs[0], dt[0], b[0], c[0],
                                                 h0[0]))
                      if B == 1 else jax.vmap(one)(xs, dt, b, c, h0))
-    return x + _mixer_out(cfg, lp, y, xs, z, x.dtype), us, snaps, end
+    x = x + _mixer_out(cfg, lp, y, xs, z, x.dtype)
+    if snapshot is None:
+        return x, None, None
+    at_ends, at_last = window_cuts(us, taps, last_index, snapshot)
+    return (x, {"conv": at_ends, "ssm": snaps[0]},
+            {"conv": at_last, "ssm": end[0]})
 
 
 def _mamba_step(cfg: NemotronHConfig, lp: Params, x: jax.Array, held, at,
@@ -346,9 +346,9 @@ def _mamba_step(cfg: NemotronHConfig, lp: Params, x: jax.Array, held, at,
     taps = cfg.conv_kernel - 1
     z, u, dt = _split_in(cfg, rmsnorm(x[:, 0], lp["norm1"], cfg.norm_eps)
                          @ _w(lp, "w_in", x.dtype))
-    win = _window(lax.dynamic_index_in_dim(held["conv"], at, 0,
+    win = conv_window(lax.dynamic_index_in_dim(held["conv"], at, 0,
                                            keepdims=False), taps)
-    xs, b, c = _split_conv(cfg, _conv_taps(lp, win + [u]).astype(x.dtype))
+    xs, b, c = _split_conv(cfg, conv_taps(lp, win + [u]).astype(x.dtype))
     conv = lax.dynamic_update_index_in_dim(
         held["conv"], jnp.concatenate(win[1:] + [u], axis=-1).astype(
             held["conv"].dtype), at, 0)
@@ -423,158 +423,11 @@ def _head(params: Params, cfg: NemotronHConfig, x: jax.Array):
                    preferred_element_type=F32)
 
 
-# -- whole sequences: forward, prefill, suffix prefill -----------------------
-
-
-def _sequence_pass(params: Params, cfg: NemotronHConfig, x: jax.Array,
-                   history=None, page_tokens=None, last_index=None):
-    """x [B, S, d] through every layer. ``history`` = (hk, hv [L_attn,
-    Hkv, Dh, P], tail {"conv": [L_mamba, taps * conv_dim], "ssm":
-    [L_mamba, H, P, N]}): the sequence continues one whose first P
-    positions are cached (B = 1): attention sees the history's keys and
-    values before its own, each Mamba layer starts from the snapshot.
-    Returns (x, k, v [L_attn, B, S, Hkv, Dh], and with ``page_tokens``
-    the Mamba layers' ``tail`` (leaves [L_mamba, S // (page_tokens *
-    snapshot_every), ...]: the state at the end of every page that keeps
-    a snapshot) and ``end`` (leaves [L_mamba, ...]: at ``last_index``),
-    else None, None)."""
-    from mpi_acx_tpu.models.decoding import (dense_decode_attend,
-                                             to_cache_layout)
-    B, S, _ = x.shape
-    taps = cfg.conv_kernel - 1
-    n_rep = cfg.n_heads // cfg.n_kv_heads
-    hk, hv, tail0 = history if history is not None else (None, None, None)
-    P = 0 if hk is None else hk.shape[-1]
-    snapshot = page_tokens * cfg.snapshot_every if page_tokens else None
-    n_snap = S // snapshot if snapshot else 0
-    ks, vs, tails, ends = [], [], [], []
-    attn_at = mamba_at = 0
-    for seg in segments(cfg):
-        n_attn = seg.count("pages") // seg.repeats
-        n_mamba = seg.count("state") // seg.repeats
-
-        def cut(a, at, n):
-            """Rows [at, at + repeats * n) of a per-layer array as scan
-            inputs [repeats, n, ...]."""
-            a = a[at:at + seg.repeats * n]
-            return a.reshape((seg.repeats, n) + a.shape[1:])
-
-        subs = params[seg.key] if len(seg.period) > 1 else (params[seg.key],)
-        # The expert stacks stay out of the scan's slicing (moe.
-        # sorted_expert_ffn, ``layer``): closed over whole.
-        whole = tuple({n: lp[n] for n in _EXPERT_STACKS if n in lp}
-                      for lp in subs)
-        xs = {"lp": tuple({n: a for n, a in lp.items() if n not in w}
-                          for lp, w in zip(subs, whole)),
-              "i": jnp.arange(seg.repeats)}
-        if hk is not None and n_attn:
-            xs["hk"], xs["hv"] = (cut(hk, attn_at, n_attn),
-                                  cut(hv, attn_at, n_attn))
-        if tail0 is not None and n_mamba:
-            xs["tail"] = jax.tree.map(lambda t: cut(t, mamba_at, n_mamba),
-                                      tail0)
-
-        def body(x, xs, seg=seg, whole=whole):
-            kv, st, a, m = [], [], 0, 0
-            for kind, lp, w in zip(seg.period, xs["lp"], whole):
-                if kind.operator == "attention":
-                    q, k, v = _qkv(cfg, lp, x)
-                    if "hk" in xs:
-                        kcat = jnp.concatenate(
-                            [xs["hk"][a][None].astype(x.dtype),
-                             to_cache_layout(k)], axis=-1)
-                        vcat = jnp.concatenate(
-                            [xs["hv"][a][None].astype(x.dtype),
-                             to_cache_layout(v)], axis=-1)
-                        o = dense_decode_attend(q, kcat, vcat, P, P + S,
-                                                n_rep)
-                    else:
-                        o = _self_attend(cfg, q, k, v)
-                    x = _attn_out(cfg, lp, x, o)
-                    kv.append((k, v))
-                    a += 1
-                elif kind.operator == "mamba2":
-                    before, h0 = ((xs["tail"]["conv"][m][None],
-                                   xs["tail"]["ssm"][m][None])
-                                  if "tail" in xs else (None, None))
-                    x, us, snaps, end = _mamba_seq(cfg, lp, x, before, h0,
-                                                   last_index, snapshot)
-                    if page_tokens is not None:
-                        # the window after token t: rows t + 1 .. t + taps
-                        at_ends = jnp.stack(
-                            [us[0, (j + 1) * snapshot:(j + 1) * snapshot
-                                + taps].reshape(-1) for j in range(n_snap)]
-                        ) if n_snap else jnp.zeros((0, taps * cfg.conv_dim),
-                                                   x.dtype)
-                        st.append((
-                            {"conv": at_ends, "ssm": snaps[0]},
-                            {"conv": lax.dynamic_slice_in_dim(
-                                us[0], last_index + 1, taps,
-                                axis=0).reshape(-1), "ssm": end[0]}))
-                    m += 1
-                else:
-                    x = _moe_ffn(cfg, dict(lp, **w, repeat=xs["i"]), x)[0]
-            return x, (tuple(kv), tuple(st))
-
-        x, (kv, st) = lax.scan(body, x, xs)
-        ks.append([k for k, _ in kv])
-        vs.append([v for _, v in kv])
-        tails.append([t for t, _ in st])
-        ends.append([e for _, e in st])
-        attn_at += seg.repeats * n_attn
-        mamba_at += seg.repeats * n_mamba
-
-    def leaves(per_segment):
-        """_by_layer over each leaf of the layers' state trees."""
-        if not any(per_segment):
-            return None
-        return {name: _by_layer([[t[name] for t in outs]
-                                 for outs in per_segment])
-                for name in ("conv", "ssm")}
-    return x, _by_layer(ks), _by_layer(vs), leaves(tails), leaves(ends)
-
-
 def forward(params: Params, cfg: NemotronHConfig,
             tokens: jax.Array) -> jax.Array:
     """tokens [B, S] int32 -> logits [B, S, vocab] (f32): the plain
     whole-sequence pass, no cache."""
-    x = params["embed"][tokens].astype(cfg.dtype)
-    return _head(params, cfg, _sequence_pass(params, cfg, x)[0])
-
-
-def _prefilled(params, cfg, x, ks, vs, tails, ends, last_index, kv_int8):
-    from mpi_acx_tpu.models.decoding import pack_kv
-    x = lax.dynamic_slice_in_dim(x, last_index, 1, axis=1)
-    one = pack_kv(ks, vs, kv_int8)
-    one["tail"], one["end"] = tails, ends
-    return _head(params, cfg, x), one
-
-
-def prefill(params: Params, cfg: NemotronHConfig, tokens: jax.Array,
-            last_index, kv_int8: bool = False,
-            page_tokens: Optional[int] = None):
-    """``PagedSpec.prefill``: one prompt [1, S] (bucket-padded, its real
-    last token at ``last_index``) -> (logits [1, 1, vocab] there,
-    ``one``: the attention layers' K/V in cache layout and the Mamba
-    layers' snapshots and end state, ``kvpage.PagedSpec``'s docstring)."""
-    x = params["embed"][tokens].astype(cfg.dtype)
-    x, *got = _sequence_pass(params, cfg, x, page_tokens=page_tokens,
-                             last_index=last_index)
-    return _prefilled(params, cfg, x, *got, last_index, kv_int8)
-
-
-def suffix_prefill(params: Params, cfg: NemotronHConfig, suffix: jax.Array,
-                   hk, hv, tail, last_index, kv_int8: bool = False,
-                   page_tokens: Optional[int] = None):
-    """``PagedSpec.suffix_prefill``: only the suffix [1, S_suf] of a
-    prompt whose first P tokens are paged in (a radix hit, cut back to a
-    page that holds a snapshot): attention against the gathered history
-    ``hk``/``hv`` [L_attn, Hkv, Dh, P], each Mamba layer from the
-    snapshot ``tail``."""
-    x = params["embed"][suffix].astype(cfg.dtype)
-    x, *got = _sequence_pass(params, cfg, x, history=(hk, hv, tail),
-                             page_tokens=page_tokens, last_index=last_index)
-    return _prefilled(params, cfg, x, *got, last_index, kv_int8)
+    return kvpage.forward(params, cfg, paged_spec(cfg), tokens)
 
 
 # -- the paged plane's seam --------------------------------------------------
@@ -592,6 +445,8 @@ def paged_spec(cfg: NemotronHConfig) -> kvpage.PagedSpec:
     width and which of its experts are held here. int8 pages are not
     wired: the state would want a precision of its own."""
     taps = cfg.conv_kernel - 1
+    # (no position enters: a token's and a whole sequence's alike)
+    qkv = lambda cfg, lp, x, pos: attention_qkv(cfg, lp, x)  # noqa: E731
     return kvpage.PagedSpec(
         segments=segments(cfg),
         n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim,
@@ -609,7 +464,6 @@ def paged_spec(cfg: NemotronHConfig) -> kvpage.PagedSpec:
                     + moe.select_grouped_matmul().__name__),),
         embed=lambda params, cfg, token, pos:
             params["embed"][token][:, None, :].astype(cfg.dtype),
-        qkv=lambda cfg, lp, x, pos: _qkv(cfg, lp, x),
-        attn_out=_attn_out, state_op=_mamba_step, ffn=_ffn,
+        qkv=qkv, attn_out=attention_out, state_op=_mamba_step, ffn=_ffn,
         head=lambda params, cfg, x: _head(params, cfg, x)[:, 0],
-        prefill=prefill, suffix_prefill=suffix_prefill)
+        seq_qkv=qkv, seq_state=_mamba_seq, seq_head=_head)

@@ -1201,8 +1201,8 @@ def _slo_admit_targets(slo_admit) -> tuple:
 def paged_prefill(params, tokens, last_index, *, cfg, family, kv_int8,
                   on_tpu, page_tokens=None):
     kvpage.note_trace()
-    return kvpage.paged_spec(family, cfg).prefill(
-        params, cfg, tokens, last_index, kv_int8, page_tokens)
+    return kvpage.prefill(params, cfg, kvpage.paged_spec(family, cfg), tokens,
+                          last_index, kv_int8, page_tokens)
 
 
 @partial(jax.jit, static_argnames=("cfg", "family", "kv_int8", "on_tpu",
@@ -1210,8 +1210,9 @@ def paged_prefill(params, tokens, last_index, *, cfg, family, kv_int8,
 def paged_suffix_prefill(params, suffix, hk, hv, tail, last_index, *, cfg,
                          kv_int8, on_tpu, family=None, page_tokens=None):
     kvpage.note_trace()
-    return kvpage.paged_spec(family, cfg).suffix_prefill(
-        params, cfg, suffix, hk, hv, tail, last_index, kv_int8, page_tokens)
+    return kvpage.prefill(params, cfg, kvpage.paged_spec(family, cfg), suffix,
+                          last_index, kv_int8, page_tokens,
+                          history=(hk, hv, tail))
 
 
 # A waiting span that took more than this many times its group's median

@@ -1711,3 +1711,89 @@ def test_paged_state_is_freed_without_the_collector():
         assert pool() is None and leaf() is None
     finally:
         gc.enable()
+
+
+# --------------------------------------------------------------------------
+# the whole-sequence pass is kvpage's: a family is its operators and a spec
+
+_FAMILIES = {"lfm2": "tiny_lfm2", "jamba": "tiny_jamba",
+             "gigachat": "tiny_gigachat", "nemotron_h": "tiny_nemotron"}
+
+
+@pytest.mark.parametrize("name", sorted(_FAMILIES))
+def test_a_family_hands_the_shared_pass_operators_and_no_pass_of_its_own(
+        name):
+    """``kvpage.sequence_pass`` / ``prefill`` / ``forward`` are the ONE
+    whole-sequence pass: a family's spec overrides neither prefill (GPT-2
+    alone does: ROADMAP.md, Queue 3), its module defines no pass and
+    reaches for no private name of another family's, and the pass reads
+    a state tree by its STRUCTURE: with every leaf of the spec's state
+    under another name (LFM2's is a bare leaf: no name at all) the
+    prefill gives the same tails and end state, leaf for leaf."""
+    import ast
+    import dataclasses
+    import importlib
+    import inspect
+    module = importlib.import_module(f"mpi_acx_tpu.models.{name}")
+    cfg = getattr(module, _FAMILIES[name])()
+    spec = module.paged_spec(cfg)
+    assert spec.prefill is None and spec.suffix_prefill is None
+    assert tfm.paged_spec(tfm.tiny_config()).prefill is not None
+
+    tree = ast.parse(inspect.getsource(module))
+    defined = {n.name for n in ast.walk(tree)
+               if isinstance(n, ast.FunctionDef)}
+    assert not defined & {"_sequence_pass", "_prefilled", "prefill",
+                          "suffix_prefill"}
+    others = {f"mpi_acx_tpu.models.{f}" for f in _FAMILIES if f != name}
+    borrowed = [a.name for n in ast.walk(tree)
+                if isinstance(n, ast.ImportFrom) and n.module in others
+                for a in n.names]
+    reached = [n.attr for n in ast.walk(tree)
+               if isinstance(n, ast.Attribute)
+               and isinstance(n.value, ast.Name)
+               and n.value.id in set(_FAMILIES) - {name}]
+    assert not [n for n in borrowed + reached if n.startswith("_")]
+
+    params = module.cast_params(module.init_params(jax.random.key(0), cfg))
+    tokens = jnp.asarray(np.arange(32)[None] % cfg.vocab, jnp.int32)
+
+    def prefill(spec, history=None):
+        return jax.jit(lambda p, t, h: kvpage.prefill(
+            p, cfg, spec, t, 20, False, 8, h))(params, tokens, history)[1]
+    one = prefill(spec)
+    if spec.state is None:
+        assert "tail" not in one and "end" not in one
+        return
+    n_tails = 32 // (8 * spec.snapshot_every)
+    for got, lead in ((one["tail"], (spec.n_state_layers, n_tails)),
+                      (one["end"], (spec.n_state_layers,))):
+        assert jax.tree.structure(got) == jax.tree.structure(spec.state)
+        for leaf, want in zip(jax.tree.leaves(got),
+                              jax.tree.leaves(spec.state)):
+            assert leaf.shape == lead + want.shape
+            assert leaf.dtype == want.dtype
+
+    def renamed(tree):          # the same leaves under names of no family
+        return (None if tree is None else
+                {f"leaf{i}": l for i, l in enumerate(jax.tree.leaves(tree))})
+
+    def seq_state(cfg, lp, x, start, last_index, snapshot):
+        start = jax.tree.unflatten(jax.tree.structure(spec.state),
+                                   jax.tree.leaves(start))
+        x, tails, end = spec.seq_state(cfg, lp, x, start, last_index,
+                                       snapshot)
+        return x, renamed(tails), renamed(end)
+    other = dataclasses.replace(spec, state=renamed(spec.state),
+                                seq_state=seq_state)
+    # a suffix behind 16 cached tokens, from the first call's snapshot
+    tail = jax.tree.map(lambda t: t[:, 0], one["tail"])
+    hk = jnp.zeros((spec.n_page_layers, spec.n_kv_heads, spec.head_dim, 16),
+                   cfg.dtype)
+    want = prefill(spec, (hk, hk, tail))
+    got = prefill(other, (hk, hk, renamed(tail)))
+    for key in ("tail", "end"):
+        assert sorted(got[key]) == sorted(renamed(spec.state))
+        for a, b in zip(jax.tree.leaves(got[key]),
+                        jax.tree.leaves(want[key])):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

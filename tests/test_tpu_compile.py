@@ -16,6 +16,8 @@ compile or interpret; the fixture patches that one name.
 """
 
 import dataclasses
+import functools
+import hashlib
 import os
 import re
 
@@ -665,18 +667,48 @@ def test_lfm2_decode_chunk_compiles_and_moves_no_pool(v5e):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+@functools.lru_cache(maxsize=None)
+def _compiled_prefill(name, bucket, history, v5e):
+    """``serving.paged_prefill`` at ``bucket`` tokens (``history`` 0) or
+    ``paged_suffix_prefill`` behind ``history`` cached ones, as a serve
+    call binds it for the family's cell (``_lfm2_cut``, ``_jamba``,
+    ``_gigachat_cut``, ``_nemotron_cut``), compiled for the described
+    chip. One compile a process, whichever test asks first."""
+    family, cfg, params = {"lfm2": _lfm2_cut, "jamba": _jamba,
+                           "gigachat": _gigachat_cut,
+                           "nemotron": _nemotron_cut}[name]()
+    fn, args, kw = _prefill_call(family, cfg, params, bucket, history, PAGE)
+    return fn.lower(*_place(args, v5e), on_tpu=True, **kw).compile()
+
+
+def _prefill_call(family, cfg, params, bucket, history, page_tokens):
+    """(program, argument shapes, static arguments) of a cold prefill or
+    a suffix prefill: what ``serve_paged_greedy`` hands them (the page
+    size only where a state layer cuts its tails at page ends, the
+    history as ``gather_history`` and ``restore_tail`` return it)."""
+    from mpi_acx_tpu.models import serving
+    spec = kvpage.paged_spec(family, cfg)
+    kw = dict(cfg=cfg, family=family, kv_int8=False,
+              page_tokens=page_tokens if spec.n_state_layers else None)
+    tokens, last = _s((1, bucket), jnp.int32), _s((), jnp.int32)
+    if not history:
+        return serving.paged_prefill, [params, tokens, last], kw
+    hk = _s((spec.n_page_layers, spec.n_kv_heads, spec.head_dim, history),
+            cfg.dtype)
+    tail = jax.tree.map(lambda l: _s((spec.n_state_layers,) + l.shape,
+                                     l.dtype), spec.state)
+    return serving.paged_suffix_prefill, [
+        params, tokens, hk, hk if spec.v_dim is None else None, tail,
+        last], kw
+
+
 @pytest.mark.parametrize("bucket", [64, 1024])
 def test_lfm2_prefill_compiles_for_v5e(bucket, v5e):
     """``serving.paged_prefill`` for the family at the smallest and the
     largest bucket the cell reaches: the grouped matmuls over 4 x
     bucket sorted rows, and flash attention (K/V heads repeated) at
     1024."""
-    from mpi_acx_tpu.models import serving
-    lfm2, cfg, params = _lfm2_cut()
-    compiled = serving.paged_prefill.lower(
-        *_place([params, _s((1, bucket), jnp.int32), _s((), jnp.int32)],
-                v5e), cfg=cfg, family=lfm2, kv_int8=False, on_tpu=True,
-        page_tokens=PAGE).compile()
+    compiled = _compiled_prefill("lfm2", bucket, 0, v5e)
     text = compiled.as_text()
     assert f"f32[{4 * bucket},1536]" in text and "%gmm" in text
     assert ("%flash_attention" in text) == (bucket == 1024)
@@ -825,12 +857,7 @@ def test_jamba_prefill_compiles_for_v5e(bucket, v5e):
     """``serving.paged_prefill`` for the family at the smallest and the
     largest bucket the cell reaches: ``ssm_scan`` (two snapshots at
     1024), and flash attention (the one K/V head repeated) at 1024."""
-    from mpi_acx_tpu.models import serving
-    jamba, cfg, params = _jamba()
-    compiled = serving.paged_prefill.lower(
-        *_place([params, _s((1, bucket), jnp.int32), _s((), jnp.int32)],
-                v5e), cfg=cfg, family=jamba, kv_int8=False, on_tpu=True,
-        page_tokens=PAGE).compile()
+    compiled = _compiled_prefill("jamba", bucket, 0, v5e)
     text = compiled.as_text()
     assert "%ssm_scan" in text and f"f32[{bucket},5120]" in text
     assert ("%flash_attention" in text) == (bucket == 1024)
@@ -962,19 +989,7 @@ def test_gigachat_prefill_compiles_for_v5e(bucket, history, v5e):
     (K/V streamed a tile a grid step), the expert layer a block of 2,048
     tokens at a time, and temporaries that fit beside 10.35 GB of
     weights and 2.3 GB of pages."""
-    from mpi_acx_tpu.models import serving
-    gigachat, cfg, params = _gigachat_cut()
-    kw = dict(cfg=cfg, family=gigachat, kv_int8=False, on_tpu=True,
-              page_tokens=None)
-    if history:
-        hk = _s((6, 1, 576, history), jnp.bfloat16)
-        compiled = serving.paged_suffix_prefill.lower(
-            *_place([params, _s((1, bucket), jnp.int32), hk], v5e), None,
-            None, *_place([_s((), jnp.int32)], v5e), **kw).compile()
-    else:
-        compiled = serving.paged_prefill.lower(
-            *_place([params, _s((1, bucket), jnp.int32), _s((), jnp.int32)],
-                    v5e), **kw).compile()
+    compiled = _compiled_prefill("gigachat", bucket, history, v5e)
     text = compiled.as_text()
     assert f"%flash_rows_attention" in text
     assert f" = bf16[64,{bucket},192]" in text
@@ -1131,21 +1146,7 @@ def test_nemotron_prefill_compiles_for_v5e(bucket, history, v5e):
     token), flash attention on the cold bucket, and temporaries that fit
     beside 9.3 GB of weights, 2.04 GB of state, 1.36 GB of snapshots and
     0.2 GB of pages."""
-    from mpi_acx_tpu.models import serving
-    nemotron_h, cfg, params = _nemotron_cut()
-    kw = dict(cfg=cfg, family=nemotron_h, kv_int8=False, on_tpu=True,
-              page_tokens=PAGE)
-    if history:
-        hk = _s((1, 2, 128, history), jnp.bfloat16)
-        tail = jax.tree.map(lambda l: _s((5,) + l.shape, l.dtype),
-                            kvpage.paged_spec(nemotron_h, cfg).state)
-        compiled = serving.paged_suffix_prefill.lower(
-            *_place([params, _s((1, bucket), jnp.int32), hk, hk, tail,
-                     _s((), jnp.int32)], v5e), **kw).compile()
-    else:
-        compiled = serving.paged_prefill.lower(
-            *_place([params, _s((1, bucket), jnp.int32), _s((), jnp.int32)],
-                    v5e), **kw).compile()
+    compiled = _compiled_prefill("nemotron", bucket, history, v5e)
     text = compiled.as_text()
     assert "%ssd_scan" in text and f"f32[{bucket},8192]" in text
     rows = 22 * min(bucket, 1024)
@@ -1156,32 +1157,43 @@ def test_nemotron_prefill_compiles_for_v5e(bucket, history, v5e):
 
 # sha256 (16 hex) of each family's decode chunk as a jaxpr, off the chip
 # (the dense pair), tiny preset, 2 slots, pages of 16, chunk 4: read at
-# PR 44's parent commit and again on its tree. To read them again:
-# ``_chunk_digest(name)`` below, in a checkout of the commit to pin.
+# PR 44's parent commit and again on its tree (``nemotron``: at PR 47's
+# parent). To read them again: ``_chunk_digest(name)`` below, in a
+# checkout of the commit to pin.
 _CHUNK_DIGESTS = {"gpt2": "b81a421f78dcef1f", "lfm2": "ddd3e63f20a11fc1",
-                  "jamba": "959ebaf1b15f03f0", "gigachat": "0b576a3c3712b233"}
+                  "jamba": "959ebaf1b15f03f0", "gigachat": "0b576a3c3712b233",
+                  "nemotron": "4a9f96af35cdefa8"}
+
+
+def _tiny(name):
+    from mpi_acx_tpu.models import gigachat, jamba, lfm2, nemotron_h
+    return {
+        "gpt2": lambda: (None, tfm.tiny_config()),
+        "lfm2": lambda: (lfm2, lfm2.tiny_lfm2()),
+        "jamba": lambda: (jamba, jamba.tiny_jamba()),
+        "gigachat": lambda: (gigachat, gigachat.tiny_gigachat(
+            experts_first=4, experts_held=4)),
+        "nemotron": lambda: (nemotron_h, nemotron_h.tiny_nemotron(
+            experts_first=4, experts_held=4))}[name]()
+
+
+def _jaxpr_digest(fn, *args):
+    return hashlib.sha256(re.sub(r"0x[0-9a-f]+", "0x", str(
+        jax.make_jaxpr(fn)(*args))).encode()).hexdigest()[:16]
 
 
 def _chunk_digest(name):
-    import hashlib
-    from mpi_acx_tpu.models import gigachat, jamba, lfm2
-    family, cfg = {
-        "gpt2": (None, tfm.tiny_config()), "lfm2": (lfm2, lfm2.tiny_lfm2()),
-        "jamba": (jamba, jamba.tiny_jamba()),
-        "gigachat": (gigachat, gigachat.tiny_gigachat(
-            experts_first=4, experts_held=4))}[name]
+    family, cfg = _tiny(name)
     fam = family or tfm
     params = fam.cast_params(fam.init_params(jax.random.key(0), cfg))
     pkv = kvpage.PagedKV(cfg, family, 2, 64, 16, 8)
     state = jax.eval_shape(
         lambda: pkv.device_state(jnp.zeros((2,), jnp.int32)))
     keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0), 2))
-    jaxpr = jax.make_jaxpr(
+    return _jaxpr_digest(
         lambda p, s, t, k: kvpage.paged_decode_chunk.__wrapped__(
             p, s, t, k, cfg=cfg, chunk=4, page_tokens=16, on_tpu=False,
-            family=family))(params, state, _s((2,), jnp.int32), keys)
-    return hashlib.sha256(
-        re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr)).encode()).hexdigest()[:16]
+            family=family), params, state, _s((2,), jnp.int32), keys)
 
 
 @pytest.mark.parametrize("name", sorted(_CHUNK_DIGESTS))
@@ -1191,10 +1203,77 @@ def test_the_other_families_chunk_programs_are_the_parents(name,
     no operator, ``sorted_expert_ffn(w3=None)``, a stage of one layer
     rewritten whole) is branches in Python on what a family's spec and
     shapes say: the decode chunk GPT-2, LFM2, Jamba and GigaChat trace
-    is, equation for equation, the one they traced at the parent. A PR
-    that MEANS to change a family's chunk reads the digest anew."""
+    is, equation for equation, the one they traced at the parent (and
+    since PR 47, whose shared split of a segment's leaves is the one
+    place it touched the step, Nemotron's). A PR that MEANS to change a
+    family's chunk reads the digest anew."""
     monkeypatch.setattr(backend, "on_tpu", lambda: False)
     assert _chunk_digest(name) == _CHUNK_DIGESTS[name]
+
+
+# The four families' prefill and suffix-prefill programs
+# (``serving.paged_prefill`` / ``paged_suffix_prefill``), read at PR 47's
+# parent commit, where each family had its own whole-sequence pass; PR 47
+# gave them ``kvpage.sequence_pass``. ``jaxpr``: sha256 (16 hex) of the
+# program as a jaxpr, off the chip, tiny preset, one bucket of 32 tokens,
+# behind 64 cached ones for a suffix, pages of 16: LFM2's pair, whose
+# trace the shared pass keeps equation for equation. ``(bucket,
+# history)``: of the program compiled for the described v5e at the cell's
+# widths (``_compiled_prefill``), as ``_program_text`` reads it: the
+# others', whose traces gained an equation that the compiler drops
+# (Jamba's and Nemotron's attention takes no positions and the shared
+# prefill makes them: an ``iota`` nobody reads) or moved a reshape of
+# unit axes (GigaChat's rows pass the shared ``_by_layer``).
+_PREFILL_DIGESTS = {
+    ("lfm2", "prefill"): ("jaxpr", "0172d1da6ccceb7a"),
+    ("lfm2", "suffix"): ("jaxpr", "07fcc5b266886a54"),
+    ("jamba", "prefill"): ((1024, 0), "056b819ec172144b"),
+    ("jamba", "suffix"): ((64, 1024), "7406bdf0e954829a"),
+    ("gigachat", "prefill"): ((8192, 0), "8bc02fb9d8dc93de"),
+    ("gigachat", "suffix"): ((256, 7936), "094a76496047bbe5"),
+    ("nemotron", "prefill"): ((5120, 0), "836fa8fa594bb4db"),
+    ("nemotron", "suffix"): ((256, 4096), "eaa8c29be2976f83")}
+
+
+def _program_text(compiled):
+    """A compiled program's optimized HLO with what is not the program
+    taken out: the tables of source files and stack frames, each
+    instruction's ``metadata``, addresses, the Mosaic kernels' serialized
+    bodies (they hold their callers' file names and line numbers:
+    ROADMAP.md, Queue 3) and the numbering of instruction names, which
+    differs between two compiles of one commit."""
+    text = re.sub(r"(?m)^(FileNames|FunctionNames|FileLocations|StackFrames)"
+                  r"\n(.+\n)*", "", compiled.as_text())
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    text = re.sub(r"0x[0-9a-f]+", "0x", text)
+    text = re.sub(r'"body":\s*"[^"]*"', '"body":""', text)
+    return re.sub(r"\b([A-Za-z_][\w\-]*?)(?:\.clone|\.\d+)+\b", r"\1",
+                  text)
+
+
+@pytest.mark.parametrize("name,program", sorted(_PREFILL_DIGESTS))
+def test_the_families_prefill_programs_are_the_parents(name, program, v5e,
+                                                       monkeypatch):
+    """The whole-sequence pass written once (``kvpage.sequence_pass``,
+    ``kvpage.prefill``) runs each family's operators in the order its own
+    copy ran them: the eight programs are the parent's. A PR that MEANS
+    to change a family's prefill reads its digest anew (``jaxpr``: in a
+    checkout of the commit to pin; compiled: twice, two compiles of one
+    commit must agree)."""
+    at, want = _PREFILL_DIGESTS[name, program]
+    if at != "jaxpr":
+        got = hashlib.sha256(_program_text(_compiled_prefill(
+            name, *at, v5e)).encode()).hexdigest()[:16]
+    else:
+        monkeypatch.setattr(backend, "on_tpu", lambda: False)
+        family, cfg = _tiny(name)
+        params = jax.eval_shape(lambda: family.cast_params(
+            family.init_params(jax.random.key(0), cfg)))
+        fn, args, kw = _prefill_call(family, cfg, params, 32,
+                                     64 * (program == "suffix"), 16)
+        got = _jaxpr_digest(
+            lambda *a: fn.__wrapped__(*a, on_tpu=False, **kw), *args)
+    assert got == want
 
 
 # The training cell's WHOLE step (``benchmarks/entries/train_step_optax``:
