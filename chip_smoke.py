@@ -415,6 +415,7 @@ def phase_serve_paged(size: Size = FULL, seed: int = 0,
     _serve_paged_jamba(size, seed)
     _serve_paged_gigachat(size, seed)
     _serve_paged_nemotron(size, seed)
+    _serve_paged_sdar(size, seed)
     return True
 
 
@@ -685,6 +686,73 @@ def _serve_paged_nemotron(size: Size, seed: int):
          **_moe_mask_row(m, cfg.top_k, size.n_slots),
          kv_write_path=m.paged_kv_write,
          kv_page_rewrites_per_token=_rewrites_per_token(m),
+         attend_built=m.paged_decode_attend,
+         attend_dead_share=round(m.attend_dead_share, 4),
+         programs_traced=traced,
+         token_mismatch_vs_dense=_mismatch_vs_dense(outs, ref, prompts),
+         **_record_row(m, again.metrics))
+
+
+def _serve_paged_sdar(size: Size, seed: int):
+    """The same serve loop over a family that generates by diffusion
+    over blocks (models/sdar.py, a small preset: heads of 64 so that the
+    chip's kernels tile, 8 experts top 2, blocks of 4 positions denoised
+    in 4 forwards and stored by a fifth): a slot-step yields a block,
+    not a token; the attend is the token step's call with a block's rows
+    folded into one position's heads; the prefill (cold: dense and flash
+    buckets; behind a radix hit) is block-causal and hands out no token.
+    Tokens against the dense configuration of the same family."""
+    import jax
+
+    from mpi_acx_tpu.models import sdar, serving
+    over = {} if size.tiny else dict(vocab=512, d_model=256, head_dim=64,
+                                     moe_d_ff=256, mask_token_id=511)
+    cfg = sdar.tiny_sdar(max_seq=2 * size.max_len, **over)
+    params = sdar.cast_params(sdar.init_params(jax.random.key(seed), cfg))
+    prompts, n_new = _requests(size, cfg.vocab - 1, seed)
+    kw = dict(n_slots=size.n_slots, max_len=size.max_len, family=sdar,
+              chunk=size.chunk, page_tokens=size.page_tokens,
+              prefix_cache=True, max_request_retries=0)
+    with _Watch() as w:
+        outs = serving.serve_paged_greedy(params, cfg, prompts, n_new, **kw)
+    m = outs.metrics
+    tokens = _check_outputs(outs, prompts, n_new, m)
+    W, steps = cfg.block_length, cfg.denoising_steps
+    _require(m.prefix_hits >= 2, f"prefix_hits={m.prefix_hits}")
+    _require(m.block_length == W and m.forwards_store * steps
+             == m.forwards_denoise > 0
+             and m.decode_tokens == tokens
+             and m.decode_slot_steps == (tokens + m.block_positions_kept
+                                         + m.block_positions_dead),
+             f"forwards={m.forwards_denoise}+{m.forwards_store}, "
+             f"positions={m.decode_slot_steps}, delivered={m.decode_tokens}, "
+             f"kept={m.block_positions_kept}, dead={m.block_positions_dead}")
+    _require(m.moe_pairs_dead > 0 and m.moe_assignments + m.moe_pairs_dead
+             == cfg.top_k * size.n_slots * W * m.moe_layer_steps,
+             f"moe_pairs_dead={m.moe_pairs_dead}, "
+             f"moe_assignments={m.moe_assignments}, "
+             f"moe_layer_steps={m.moe_layer_steps}")
+    again = serving.serve_paged_greedy(params, cfg, prompts, n_new, **kw)
+    traced = [m.programs_traced, again.metrics.programs_traced]
+    _require(traced[0] > 0 and traced[1] == 0
+             and _mismatch_share(again, outs, prompts) == 0,
+             f"second serve call (sdar): programs_traced={traced}")
+    ref = serving.serve_paged_greedy(params, _reference(cfg), prompts, n_new,
+                                     **kw)
+    _check_outputs(ref, prompts, n_new, ref.metrics)
+    emit(phase="serve_paged/sdar", ok=True, tokens=tokens, **w.row(),
+         **_serve_stats(m), prefix_hits=m.prefix_hits,
+         paged_operator=m.paged_operator, paged_ffn=m.paged_ffn,
+         block_length=W, denoise_steps=steps,
+         forwards_denoise=m.forwards_denoise,
+         forwards_store=m.forwards_store,
+         block_token_share=round(m.step_utilization, 4),
+         block_positions_kept=m.block_positions_kept,
+         block_positions_dead=m.block_positions_dead,
+         moe_live_expert_share=round(m.moe_live_expert_share, 4),
+         moe_dead_share=round(m.moe_pairs_dead / (
+             m.moe_pairs_dead + m.moe_assignments), 4),
+         kv_write_path=m.paged_kv_write,
          attend_built=m.paged_decode_attend,
          attend_dead_share=round(m.attend_dead_share, 4),
          programs_traced=traced,

@@ -64,6 +64,11 @@ a family is its operators and its spec. GPT-2
 (``transformer.paged_spec``) is "every layer attention + dense + pages";
 ``lfm2`` mixes both operator kinds and both FFN kinds, ``jamba`` Mamba
 and attention layers. A family without ``paged_spec`` raises by name.
+The spec also says HOW a family generates: a token a slot a step, or
+(``sdar``) a BLOCK of positions by diffusion, denoised over several
+forwards and stored by one more (:func:`paged_block_forward`, the block
+arm of :func:`paged_decode_chunk`, the block-causal mask of
+:func:`sequence_pass`).
 
 **Two kinds of state.** A layer whose cache kind is ``pages`` owns a
 layer of the pools above. A layer whose cache kind is ``state`` (a
@@ -136,6 +141,11 @@ class PageAllocator:
         # page 0 first — deterministic layouts for reproducible tests.
         self._free = list(range(self.n_pages - 1, -1, -1))
         self._ref = [0] * self.n_pages
+        # How many pages have been handed out so far, and each page's
+        # number among them when it was last: a page nobody has been
+        # handed since some moment still holds what it held then.
+        self.issues = 0
+        self._issued = [0] * self.n_pages
         # Called with a page's id when it is reclaimed (PagedKV: the
         # page's snapshot row goes with it).
         self.on_free: Optional[Callable[[int], None]] = None
@@ -163,7 +173,14 @@ class PageAllocator:
         pages = [self._free.pop() for _ in range(n)]
         for p in pages:
             self._ref[p] = 1
+            self.issues += 1
+            self._issued[p] = self.issues
         return pages
+
+    def untouched_since(self, pages, issues: int) -> bool:
+        """None of ``pages`` was handed out after ``issues`` pages had
+        been."""
+        return all(self._issued[p] <= issues for p in pages)
 
     def incref(self, page: int) -> None:
         assert self._ref[page] > 0, (page, "incref of a free page")
@@ -544,6 +561,20 @@ class PagedSpec:
     # model's width).
     moe_row_dim: int = 0
     kv_int8: bool = True                 # int8 pages wired for it
+    # How the family GENERATES. ``block`` 0 (or 1): a token a slot a
+    # step, the argmax of the step's logits. ``block`` W > 1: generation
+    # by diffusion over blocks (:func:`paged_block_forward`, the block
+    # arm of :func:`paged_decode_chunk`): a slot's step is a block of W
+    # positions, denoised together over ``denoise_steps`` forwards that
+    # commit ``W / denoise_steps`` positions each, the still masked ones
+    # fed ``mask_token``, and stored by one more forward; the mask of
+    # the whole-sequence pass is then block-causal, a prefill needs no
+    # head and hands out no token. The family's ``embed`` / ``qkv`` take
+    # ``token`` [B, W] and ``x`` [B, W, d] at the block's first position
+    # ``pos``, its ``head`` gives [B, W, vocab].
+    block: int = 0
+    denoise_steps: int = 0
+    mask_token: int = 0
     # (ffn kind, implementation) pairs: ServingMetrics.paged_ffn
     ffn_built: Tuple[Tuple[str, str], ...] = (("dense", "dense"),)
     embed: Callable = None
@@ -678,6 +709,50 @@ def _one_layer(scanned, whole, repeat):
     return dict(scanned, **whole, repeat=repeat) if whole else scanned
 
 
+def _through_layers(spec: PagedSpec, params, carry, layer):
+    """``carry`` = ``(x, pools, rest)`` through every layer of a step:
+    each :class:`Segment` one ``lax.scan`` over its whole periods, the
+    period's layers unrolled inside. ``layer(kind, lp, x, pools, rest,
+    at)`` is one layer, ``at`` its index among those of its cache
+    kind."""
+    def nth(base, i, stride, j):
+        """``base + i * stride + j`` without the identities."""
+        i = i if stride == 1 else i * stride
+        return i if base + j == 0 else i + (base + j)
+
+    pages_at = states_at = 0
+    for seg in spec.segments:
+        stacked = params[seg.key]
+        split = _split_leaves(spec, seg, stacked)
+        n_pg = sum(k.cache == "pages" for k in seg.period)
+        n_st = sum(k.cache == "state" for k in seg.period)
+
+        def body(carry, i, seg=seg, split=split, n_pg=n_pg, n_st=n_st,
+                 pages_at=pages_at, states_at=states_at):
+            x, pools, rest = carry
+            pg = st = 0
+            for kind, (scanned, whole) in zip(seg.period, split):
+                lp = _one_layer(jax.tree.map(
+                    lambda a: lax.dynamic_index_in_dim(a, i, 0,
+                                                       keepdims=False),
+                    scanned), whole, i)
+                x, pools, rest = layer(
+                    kind, lp, x, pools, rest,
+                    nth(pages_at, i, n_pg, pg) if kind.cache == "pages"
+                    else nth(states_at, i, n_st, st))
+                pg += kind.cache == "pages"
+                st += kind.cache == "state"
+            return (x, pools, rest), None
+
+        # (the depth is the stacked leaves', as it always was: a tree
+        # cut or grown in depth runs at its own)
+        repeats = jax.tree.leaves(stacked)[0].shape[0]
+        carry, _ = lax.scan(body, carry, jnp.arange(repeats))
+        pages_at += repeats * n_pg
+        states_at += repeats * n_st
+    return carry
+
+
 def paged_decode_step(params, cfg, state, token, page_tokens: int,
                       family=None):
     """One autoregressive step against the paged state, for any family
@@ -768,11 +843,6 @@ def paged_decode_step(params, cfg, state, token, page_tokens: int,
         # the slot-steps whose state the state operators leave alone
         state_dead = state_dead + (~live).sum().astype(jnp.int32)
 
-    def nth(base, i, stride, j):
-        """``base + i * stride + j`` without the identities."""
-        i = i if stride == 1 else i * stride
-        return i if base + j == 0 else i + (base + j)
-
     def layer(kind, lp, x, pools, rest, at):
         """``at``: the layer's index among those of its cache kind."""
         if kind.cache == "pages":
@@ -809,38 +879,8 @@ def paged_decode_step(params, cfg, state, token, page_tokens: int,
             x = spec.ffn(cfg, lp, x, kind.ffn)
         return x, pools, rest
 
-    carry = (x, tuple(state[k] for k in keys), rest)
-    pages_at = states_at = 0
-    for seg in spec.segments:
-        stacked = params[seg.key]
-        split = _split_leaves(spec, seg, stacked)
-        n_pg = sum(k.cache == "pages" for k in seg.period)
-        n_st = sum(k.cache == "state" for k in seg.period)
-
-        def body(carry, i, seg=seg, split=split, n_pg=n_pg, n_st=n_st,
-                 pages_at=pages_at, states_at=states_at):
-            x, pools, rest = carry
-            pg = st = 0
-            for kind, (scanned, whole) in zip(seg.period, split):
-                lp = _one_layer(jax.tree.map(
-                    lambda a: lax.dynamic_index_in_dim(a, i, 0,
-                                                       keepdims=False),
-                    scanned), whole, i)
-                x, pools, rest = layer(
-                    kind, lp, x, pools, rest,
-                    nth(pages_at, i, n_pg, pg) if kind.cache == "pages"
-                    else nth(states_at, i, n_st, st))
-                pg += kind.cache == "pages"
-                st += kind.cache == "state"
-            return (x, pools, rest), None
-
-        # (the depth is the stacked leaves', as it always was: a tree
-        # cut or grown in depth runs at its own)
-        repeats = jax.tree.leaves(stacked)[0].shape[0]
-        carry, _ = lax.scan(body, carry, jnp.arange(repeats))
-        pages_at += repeats * n_pg
-        states_at += repeats * n_st
-    x, pools, rest = carry
+    x, pools, rest = _through_layers(
+        spec, params, (x, tuple(state[k] for k in keys), rest), layer)
     if step is not None:
         rest["stage"] = rest["stage"], step + 1
     out = dict(zip(keys, pools), **rest)
@@ -852,6 +892,188 @@ def paged_decode_step(params, cfg, state, token, page_tokens: int,
     if state_dead is not None:
         out["state_dead"] = state_dead
     return spec.head(params, cfg, x), out
+
+
+def paged_block_forward(params, cfg, state, tokens, page_tokens: int,
+                        family):
+    """One forward of every slot's BLOCK (``PagedSpec.block`` = W > 1
+    positions: generation by diffusion over blocks) against the paged
+    state: the block sibling of :func:`paged_decode_step`, inside a
+    chunk only. ``tokens`` [B, W]: what the block holds now (the mask
+    token where a position is not committed yet); ``state['pos']`` [B]
+    the slots' positions at the CHUNK's start (it does not move inside
+    a chunk) and ``state['stage']`` = ``(arrays, row)``, ``row`` the
+    block's first row of the chunk's stage (block ``j``: ``j W``): the
+    block sits at positions ``pos + row ..``. Every row of the block
+    sees the pages up to ``pos``, the stage's rows of the chunk's
+    earlier blocks and ALL W rows of its own block, which this forward
+    writes (rows ``row .. row + W - 1`` of every page layer's stage,
+    over what the forward before left there): no causal mask inside a
+    block, so a K/V head's ``W x n_rep`` query rows meet ONE key set and
+    ride the attend as ``W n_rep`` heads of one position (``pos + row +
+    W - 1``, the stage filled up to that row): the call the token step
+    makes, the rows folded (no kernel of its own). NO forward writes a
+    page: the last forward of a block (its tokens all committed) leaves
+    in the stage what the chunk's flush then stores.
+
+    ``state['left']`` ([B]: the POSITIONS each slot still owes at the
+    chunk's start, the tokens of its request and what of its prompt
+    lies in its first block) says which blocks are dead (``row >=
+    left``): as in the token step the attend fetches nothing for them
+    and the expert layers route none of their pairs. Returns (``x`` [B,
+    W, d] behind the last layer, state): the head is the caller's, the
+    forward that stores a finished block needs none."""
+    from mpi_acx_tpu.ops.flash_decode import select_paged_decode_attend
+    from mpi_acx_tpu.ops.kvquant import kv_quant
+
+    spec = paged_spec(family, cfg)
+    W, n_rep = spec.block, spec.n_rep
+    assert W > 1 and spec.v_dim is None and not spec.n_state_layers, spec
+    table, pos = state["table"], state["pos"]
+    keys = tuple(k for k in _POOL_KEYS if k in state)
+    quant = "ks" in keys
+    stage, row = state["stage"]
+    B = tokens.shape[0]
+    first = pos + row                       # [B]: the block's first position
+    x = spec.embed(params, cfg, tokens, first)
+    attend = select_paged_decode_attend(cfg.decode_flash, page_tokens)
+    rest = {"stage": stage}
+    if "moe" in state:
+        rest["moe"] = state["moe"]
+    left = state.get("left")
+    # [B * W] bool: the rows of the blocks that can still deliver
+    live = None if left is None else jnp.repeat(row < left, W)
+    # (the attend reads ``step < left`` with the stage's LAST filled row
+    # for ``step``: ``row + W - 1 < left + W - 1`` iff ``row < left``)
+    last = row + (W - 1)
+    left_attend = None if left is None else left + (W - 1)
+
+    def put(into, rows, at, lanes: bool):
+        """``rows`` [B, W, H, *] as rows ``row ..`` of layer ``at`` of a
+        stage array: tokens a major axis of K/V's [L, B, chunk, H, 2 D],
+        the lanes of a scale's [L, B, H, 1, chunk]."""
+        zero = jnp.int32(0)
+        if lanes:
+            return lax.dynamic_update_slice(
+                into, rows.transpose(0, 2, 3, 1)[None].astype(into.dtype),
+                (at, zero, zero, zero, row))
+        return lax.dynamic_update_slice(
+            into, rows[None].astype(into.dtype), (at, zero, row, zero, zero))
+
+    def layer(kind, lp, x, pools, rest, at):
+        if kind.cache == "pages":
+            q, k, v = spec.qkv(cfg, lp, x, first)       # [B, W, H*, Dh]
+            scales = ()
+            if quant:
+                (k, ks), (v, vs) = kv_quant(k), kv_quant(v)
+                scales = (ks, vs)
+            st = rest["stage"]
+            st = (put(st[0], jnp.concatenate([v, k], axis=-1), at, False),
+                  *(put(s, f, at, True) for s, f in zip(st[1:], scales)))
+            rest = dict(rest, stage=st)
+            kp, vp = pools[0], pools[1]
+            if quant:
+                kp, vp = (kp, pools[2]), (vp, pools[3])
+            # [B, W, Hkv, n_rep, D] -> one position of Hkv x (W n_rep)
+            # heads, and back
+            D = q.shape[-1]
+            qf = q.reshape(B, W, spec.n_kv_heads, n_rep, D).transpose(
+                0, 2, 1, 3, 4).reshape(B, 1, -1, D)
+            o = attend(qf, kp, vp, table, pos + last, page_tokens, W * n_rep,
+                       layer=at, stage=(st, last), left=left_attend,
+                       scale=spec.attn_scale)
+            o = o.reshape(B, spec.n_kv_heads, W, n_rep * D).transpose(
+                0, 2, 1, 3).reshape(B, W, -1)
+            x = spec.attn_out(cfg, lp, x, o)
+        if kind.ffn == "moe":
+            x, *routed = spec.ffn(cfg, lp, x, kind.ffn, live=live)
+            if "moe" in rest:
+                rest = dict(rest, moe=rest["moe"] + _moe_tally(
+                    routed[0], jnp.repeat(state["owns"], W), spec.n_experts,
+                    spec.experts_held, *routed[1:], live=live))
+        elif kind.ffn != "none":
+            x = spec.ffn(cfg, lp, x, kind.ffn)
+        return x, pools, rest
+
+    x, pools, rest = _through_layers(
+        spec, params, (x, tuple(state[k] for k in keys), rest), layer)
+    out = dict(state, **dict(zip(keys, pools)), **rest)
+    out["stage"] = rest["stage"], row
+    return x, out
+
+
+def _block_chunk(params, cfg, spec: PagedSpec, state, tok, chunk: int,
+                 page_tokens: int, family):
+    """The steps of a decode chunk for a family that generates by
+    diffusion over blocks (``spec.block`` = W > 1; ``chunk`` a multiple
+    of W): ``chunk / W`` blocks a slot, in lockstep over the slots, each
+    block ``spec.denoise_steps`` denoising forwards and one storing
+    forward (:func:`paged_block_forward`), all ``denoise_steps + 1`` one
+    ``lax.scan`` whose last turn skips the head. ``tok`` [B, W]: what a
+    slot's FIRST block of the chunk already holds (the last ``P mod W``
+    tokens of a prompt just seated, from the block's start), -1 where a
+    position is masked: "masked" is a flag of the position, never a
+    token's value (a head may emit the mask token's id, and a prompt may
+    hold it). Every later block starts all masked.
+
+    A denoising forward feeds the mask token at the masked positions,
+    takes at each ``t = argmax logits`` and its confidence ``c =
+    softmax(logits)[t]`` (float32) and commits the ``W / denoise_steps``
+    masked positions of highest ``c`` (of equal ``c`` the lower
+    position: the static low-confidence schedule); a block with fewer
+    masks than that commits what it has and nothing in the steps it has
+    no mask for. A committed position never changes. The storing
+    forward runs the finished block; its K/V are what the stage keeps.
+
+    Returns (state, ``toks`` [chunk, B]: the blocks' tokens in position
+    order, ``at`` [chunk, B]: the denoising step that committed each, -1
+    for what ``tok`` brought)."""
+    W, n_steps = spec.block, spec.denoise_steps
+    assert chunk % W == 0 and W % n_steps == 0, (chunk, W, n_steps)
+    per_step = W // n_steps
+    order = jnp.arange(W)
+
+    def block(state, j):
+        state = dict(state, stage=(state["stage"][0], j * W))
+        held = jnp.where(j == 0, tok, -1)                   # [B, W]
+        masked = held < 0
+        tokens = jnp.where(masked, 0, held)
+        at = jnp.where(masked, n_steps, -1).astype(jnp.int32)
+
+        def commit(logits, tokens, masked, at, s):
+            best = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            top = jnp.max(logits, axis=-1, keepdims=True)
+            conf = 1.0 / jnp.sum(jnp.exp(logits - top), axis=-1)
+            conf = jnp.where(masked, conf, -1.0)            # [B, W]
+            # rank among the block's positions: higher confidence first,
+            # of equals the lower position
+            ahead = ((conf[:, None, :] > conf[:, :, None])
+                     | ((conf[:, None, :] == conf[:, :, None])
+                        & (order[None, None, :] < order[None, :, None])))
+            take = masked & (ahead.sum(-1) < per_step)
+            return (jnp.where(take, best, tokens), masked & ~take,
+                    jnp.where(take, s, at))
+
+        def forward(carry, s):
+            state, tokens, masked, at = carry
+            x, state = paged_block_forward(
+                params, cfg, state, jnp.where(masked, spec.mask_token, tokens),
+                page_tokens, family)
+            # (the storing forward, ``s == n_steps``, runs no head)
+            tokens, masked, at = lax.cond(
+                s < n_steps,
+                lambda: commit(spec.head(params, cfg, x), tokens, masked, at,
+                               s),
+                lambda: (tokens, masked, at))
+            return (state, tokens, masked, at), None
+
+        (state, tokens, _, at), _ = lax.scan(
+            forward, (state, tokens, masked, at), jnp.arange(n_steps + 1))
+        return state, (tokens.T, at.T)                      # [W, B] each
+
+    state, (toks, at) = lax.scan(block, state, jnp.arange(chunk // W))
+    B = tok.shape[0]
+    return state, toks.reshape(chunk, B), at.reshape(chunk, B)
 
 
 # The paged serve path's programs (the chunk below, PagedKV's _scatter /
@@ -900,7 +1122,17 @@ def paged_decode_chunk(params, state, tok, keys, *, cfg, chunk,
     into the next page) and not once a token. Between chunks every page is complete: what the host
     side reads of a pool (``grow``, copy-on-write, ``scatter_prompt``,
     the trie, ``gather_history``) sees what a chunk of one-token writes
-    left, bit for bit."""
+    left, bit for bit.
+
+    A family that generates by diffusion over blocks (``PagedSpec.block``
+    = W > 1) takes the BLOCK arm: ``chunk`` stays the tokens a slot a
+    program call, ``chunk / W`` blocks of ``denoise_steps + 1`` forwards
+    each (:func:`_block_chunk`); ``tok`` is then ``[B, W]``, what each
+    slot's first block already holds (-1: masked), and the result's
+    tokens ``[2, chunk, B]``: under them the denoising step that
+    committed each. The stage and its flush are the token arm's: no
+    forward writes a page, the last forward of a block leaves its K/V in
+    the stage, and a page is still written once a chunk."""
     from mpi_acx_tpu.ops.flash_decode import (new_kv_stage,
                                               paged_kv_write_runs,
                                               select_paged_kv_write,
@@ -920,8 +1152,17 @@ def paged_decode_chunk(params, state, tok, keys, *, cfg, chunk,
                                           page_tokens, family)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return (state, nxt, keys), nxt
-    (state, _, keys), toks = lax.scan(one, (state, tok, keys), None,
-                                      length=chunk)
+    if spec.block > 1:
+        # ``chunk / W`` blocks a slot (:func:`_block_chunk`); the tokens
+        # come back with the step that committed each under them, [2,
+        # chunk, B], and the slots have moved on by the whole chunk
+        state, toks, at = _block_chunk(params, cfg, spec, state, tok, chunk,
+                                       page_tokens, family)
+        toks = jnp.stack([toks, at])
+        state = dict(state, pos=pos0 + chunk)
+    else:
+        (state, _, keys), toks = lax.scan(one, (state, tok, keys), None,
+                                          length=chunk)
 
     stage, _ = state.pop("stage")
     write = select_paged_kv_write(cfg.decode_flash, page_tokens)
@@ -991,11 +1232,17 @@ def sequence_pass(params, cfg, spec: PagedSpec, x, positions, history=None,
     ``[L_state, S // (page_tokens * snapshot_every), *leaf]``: the state
     at the end of every page that keeps a snapshot) and ``ends``
     (``[L_state, *leaf]``: at ``last_index``), else None, None; all in
-    model order."""
+    model order.
+
+    A family that generates by diffusion over blocks (``spec.block`` = W
+    > 1) is attended BLOCK-causally, cold and behind a history alike:
+    position ``i`` sees ``j`` where ``j // W <= i // W`` (the history a
+    whole number of blocks, as a whole number of pages is)."""
     from mpi_acx_tpu.models.decoding import (dense_decode_attend,
                                              to_cache_layout)
     from mpi_acx_tpu.models.llama import _repeat_kv
-    from mpi_acx_tpu.ops.attention import select_attention
+    from mpi_acx_tpu.ops.attention import (select_attention,
+                                           select_block_attention)
     S = x.shape[1]
     hk, hv, tail0 = history if history is not None else (None, None, None)
     P = 0 if hk is None else hk.shape[-1]
@@ -1013,11 +1260,17 @@ def sequence_pass(params, cfg, spec: PagedSpec, x, positions, history=None,
             kcat, vcat = (jnp.concatenate(
                 [xs[h][pg][None].astype(x.dtype), to_cache_layout(own)],
                 axis=-1) for h, own in (("hk", k), ("hv", v)))
-            o = dense_decode_attend(q, kcat, vcat, P, P + S, spec.n_rep)
+            o = (dense_decode_attend(q, kcat, vcat, P, P + S, spec.n_rep)
+                 if spec.block <= 1 else
+                 _block_attend_behind(q, kcat, vcat, P, spec.block,
+                                      spec.n_rep))
         else:
             # (the policy takes as many K/V heads as query heads: they
             # are REPEATED for it, as llama's are)
-            o = select_attention(cfg.use_flash)(
+            attention = (select_attention(cfg.use_flash) if spec.block <= 1
+                         else select_block_attention(cfg.use_flash,
+                                                     spec.block))
+            o = attention(
                 q, _repeat_kv(k, spec.n_rep), _repeat_kv(v, spec.n_rep))
             o = o.reshape(q.shape[0], S, -1)
         return spec.attn_out(cfg, lp, x, o), (k, v)
@@ -1083,6 +1336,24 @@ def sequence_pass(params, cfg, spec: PagedSpec, x, positions, history=None,
     return x, _by_layer(fresh), _by_layer(tails), _by_layer(ends)
 
 
+def _block_attend_behind(q, kc, vc, P: int, block: int, n_rep: int):
+    """``decoding.dense_decode_attend`` under the block-causal mask: q
+    [B, S, Hq, D], rows at positions ``P ..`` (``P`` a whole number of
+    blocks), against ``kc`` / ``vc`` [B, Hkv, D, P + S] in cache layout;
+    row ``w`` sees the columns below the end of its own block, ``P + (w
+    // block + 1) * block``. -> [B, S, Hq * D]."""
+    B, S = q.shape[:2]
+    Hkv, Dh = kc.shape[1], kc.shape[2]
+    qg = (q.reshape(B, S, Hkv, n_rep, Dh).astype(jnp.float32)
+          * (1.0 / Dh ** 0.5)).astype(q.dtype)
+    logits = jnp.einsum("bqgrd,bgdk->bgrqk", qg, kc).astype(jnp.float32)
+    ends = P + (jnp.arange(S) // block + 1) * block
+    seen = jnp.arange(kc.shape[-1])[None, :] < ends[:, None]
+    logits = jnp.where(seen, logits, jnp.finfo(jnp.float32).min)
+    p = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    return jnp.einsum("bgrqk,bgdk->bqgrd", p, vc).reshape(B, S, -1)
+
+
 def forward(params, cfg, spec: PagedSpec, tokens):
     """tokens [B, S] int32 -> logits [B, S, vocab] (f32): a family's
     plain whole-sequence pass, no cache."""
@@ -1135,6 +1406,11 @@ def prefill(params, cfg, spec: PagedSpec, tokens, last_index,
         one = pack_kv(*fresh, kv_int8)
     if tails is not None:
         one["tail"], one["end"] = tails, ends
+    if spec.block > 1:
+        # generation by diffusion over blocks: a prompt's whole blocks
+        # are stored and NO token comes of them (no head runs); the first
+        # arrives with the first block of a chunk
+        return None, one
     return spec.seq_head(params, cfg, x), one
 
 
@@ -1207,6 +1483,13 @@ class PagedKV:
              f"page_tokens={page_tokens} (the block table tiles the "
              "cache exactly)")
         self.spec = paged_spec(family, cfg)
+        # Generation by diffusion over blocks attends a block whole: a
+        # page's K/V are a function of the tokens up to ITS end, which
+        # is what lets the trie share it, only where no block straddles
+        # a page boundary.
+        assert self.spec.block <= 1 or page_tokens % self.spec.block == 0, \
+            (f"page_tokens={page_tokens} must be a multiple of the "
+             f"family's block of {self.spec.block}")
         if kv_int8 and not self.spec.kv_int8:
             raise NotImplementedError(
                 "kv_int8 pages are not wired for family "
@@ -1245,6 +1528,10 @@ class PagedKV:
         self._dev_table = None
         self.pages_hwm = 0
         self.preemptions = 0
+        # What a finished request left behind (:meth:`release` with its
+        # rid): its pages, the position it stopped at, and how many
+        # pages the allocator had handed out by then.
+        self.retired: Dict[int, Tuple[List[int], int, int]] = {}
 
     # -- device state ------------------------------------------------------
 
@@ -1320,6 +1607,7 @@ class PagedKV:
                                                       was_snaps.evictions)
             self.snaps.rows_hwm = was_snaps.rows_hwm
         self.pages = [[] for _ in range(self.n_slots)]
+        self.retired = {}
         self.pos = np.zeros((self.n_slots,), np.int32)
         self.table = np.asarray(
             [[self._park[b]] * self.max_pages
@@ -1337,7 +1625,12 @@ class PagedKV:
         ``left`` an idle slot's are its parking page, again and
         again."""
         pt = self.page_tokens
-        steps = chunk if left is None else np.clip(left, 0, chunk)
+        steps = np.asarray(chunk if left is None else np.clip(left, 0, chunk))
+        if self.spec.block > 1:
+            # a block family: ``left`` counts positions, and a slot
+            # reads in every forward of each block that is live
+            steps = (-(-steps // self.spec.block)
+                     * (self.spec.denoise_steps + 1))
         return int((steps * np.clip((self.pos + pt - 1) // pt, 1,
                                     self.max_pages)).sum())
 
@@ -1403,15 +1696,31 @@ class PagedKV:
                     shared=len(prompt_pages), pos=new_pos)
         self._sync_row(b)
 
-    def release(self, b: int) -> None:
+    def release(self, b: int, rid: int = -1) -> None:
         """Drop slot b's page references (shared prefix pages survive
         through the trie's reference) and park the slot. Its fixed
-        state is dropped with them: the next seat overwrites it."""
+        state is dropped with them: the next seat overwrites it. With
+        ``rid`` (a request that FINISHED there) what it leaves behind is
+        noted for :meth:`left_behind`."""
+        if rid >= 0:
+            self.retired[rid] = (list(self.pages[b]), int(self.pos[b]),
+                                 self.alloc.issues)
         for p in self.pages[b]:
             self.alloc.decref(p)
         self.pages[b] = []
         self.pos[b] = 0
         self._sync_row(b)
+
+    def left_behind(self, rid: int):
+        """``(pages, pos)`` of finished request ``rid`` if the pages it
+        held when it retired still hold its K/V up to ``pos`` (nobody
+        has been handed one of them since; a page reclaimed keeps its
+        content until then), else None: what a reader of the pool
+        (tests, the benchmark's comparison) may gather after the call."""
+        pages, pos, issues = self.retired.get(rid, ((), 0, 0))
+        if not pages or not self.alloc.untouched_since(pages, issues):
+            return None
+        return pages, pos
 
     def grow(self, b: int, need_pages: int) -> bool:
         """Extend slot b's page list to ``need_pages``; False when the

@@ -496,3 +496,21 @@ def sorted_expert_ffn(x, w1, w3, w2, idx, p, first: int = 0,
     y = jnp.where(held[order][:, None], y * w[:, None], 0.0)
     back = jnp.zeros_like(order).at[order].set(jnp.arange(T * k))
     return y[back].reshape(T, k, d).sum(axis=1)
+
+
+def route_softmax_topk(x, gate, top_k: int, normalise: bool = True):
+    """The ``qwen3_moe`` / ``sdar_moe`` router, in f32, beside the two
+    sigmoid routers above (at the file's end: the grouped matmul's lines,
+    which its kernel's cache key holds, stay where they were): ``g =
+    softmax(x @ gate)`` over ALL experts [T, E]; the ``top_k`` largest
+    are selected (of equal scores the lower index wins, ``lax.top_k``'s
+    rule); the weights are ``g`` of the selected, divided by their sum
+    when ``normalise`` (``norm_topk_prob``). No bias, no scale. Returns
+    (idx [T, k] int32, p [T, k] f32)."""
+    g = jax.nn.softmax(jnp.dot(x.astype(jnp.float32),
+                               gate.astype(jnp.float32),
+                               precision=lax.Precision.HIGHEST), axis=-1)
+    p, idx = lax.top_k(g, top_k)
+    if normalise:
+        p = p / jnp.sum(p, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), p

@@ -78,6 +78,12 @@ class RequestTelemetry:
     decode_in_refill_s: float = 0.0  # under OTHER requests' ``refill.*`` spans
     decode_in_chunk_s: float = 0.0   # under its chunks' ``.upload`` + ``.step``
     chunks: int = 0            # chunks it owned a slot in
+    # A family that generates by diffusion over blocks: ``(token, the
+    # denoising step that committed it)`` of every position its blocks
+    # computed, from the prompt's last whole block on, in position order
+    # (-1: the prompt's own tokens in its first block; behind the
+    # ``new_tokens`` delivered, a last block's excess).
+    block_log: List[tuple] = field(default_factory=list)
 
 
 @dataclass
@@ -197,6 +203,28 @@ class ServingMetrics:
     # (RequestBook.deliver).
     decode_slot_steps: int = 0    # every chunk: chunk x n_slots
     decode_tokens: int = 0        # tokens the deliver loop consumed
+    # paged, a family that generates by diffusion over blocks
+    # (kvpage.PagedSpec.block = ``block_length`` > 1; all 0 elsewhere): a
+    # slot-step is a POSITION (``decode_slot_steps``: the positions the
+    # chunks' blocks computed, live and dead slots together;
+    # ``decode_tokens`` of them were delivered). The forwards the chunks
+    # ran, by kind: ``denoise_steps`` a block that commit tokens, one
+    # more that stores the finished block's K/V. The positions computed
+    # and NOT delivered: ``block_positions_kept`` in blocks that
+    # delivered something or could (a prompt's last ``P mod W`` tokens in
+    # its first block, a last block's excess past ``n_new``),
+    # ``block_positions_dead`` in the blocks of slots with nothing left
+    # to deliver (idle, or the request ended earlier in the chunk).
+    # ``block_by_chunk``: (denoising forwards, storing forwards,
+    # positions, delivered, kept, dead, pages a layer's attends walked)
+    # of each chunk.
+    block_length: int = 0
+    denoise_steps: int = 0
+    forwards_denoise: int = 0
+    forwards_store: int = 0
+    block_positions_kept: int = 0
+    block_positions_dead: int = 0
+    block_by_chunk: List[tuple] = field(default_factory=list)
     # serve_paged_greedy only: the call split into the phases of its
     # docstring's table (profiling.Phases: self seconds and entries per
     # span name; the self times sum to call_s).
@@ -286,7 +314,8 @@ class ServingMetrics:
     @property
     def step_utilization(self) -> float:
         """Share of decode slot-steps that delivered a token (an empty
-        slot, and a slot whose request ended mid-chunk, deliver none)."""
+        slot, and a slot whose request ended mid-chunk, deliver none; of
+        a block family: share of the positions computed)."""
         return (self.decode_tokens / self.decode_slot_steps
                 if self.decode_slot_steps else 0.0)
 
@@ -585,7 +614,7 @@ class RequestBook:
 
     def __init__(self, prompts, n_new, n_slots, eos, chunk,
                  max_request_retries, rejected=None, rids=None,
-                 on_token=None):
+                 on_token=None, block: int = 0):
         n = len(prompts)
         self.prompts = [np.asarray(p, np.int32) for p in prompts]
         self.n_new, self.n_slots, self.eos, self.chunk = (
@@ -600,6 +629,17 @@ class RequestBook:
         self.queue = deque(self.rids)
         self.owner = [-1] * n_slots
         self.last_tok = np.zeros((n_slots,), np.int32)
+        # A family that generates by diffusion over blocks of ``block``
+        # positions (0: a token a step): the step's input is what each
+        # slot's next block already holds, -1 where a position is masked
+        # (a prompt's last ``P mod block`` tokens after a seat, else
+        # nothing), and ``block_log`` keeps per rid (token, committing
+        # step) of every position its blocks computed.
+        self.block = block if block > 1 else 0
+        if self.block:
+            self.last_tok = np.full((n_slots, block), -1, np.int32)
+        self.block_log: List[List[tuple]] = [[] for _ in range(n)]
+        self.block_chunks: List[tuple] = []     # (delivered, kept) a chunk
         self.emitted: List[List[int]] = [[] for _ in range(n)]
         self.done: List[Optional[object]] = [None] * n
         self.attempts = [0] * n
@@ -634,6 +674,7 @@ class RequestBook:
         are discarded and the replayed attempt re-earns its first
         token."""
         self.emitted[rid] = []
+        self.block_log[rid] = []
         self.ttft[rid] = None
         self.queue.append(rid)
 
@@ -709,8 +750,17 @@ class RequestBook:
         return revived
 
     def seat(self, b, rid, first):
-        """Slot b now serves rid, whose prefill emitted ``first``."""
+        """Slot b now serves rid, whose prefill emitted ``first``. Of a
+        block family no token comes of a prefill: ``first`` is then the
+        prompt's tail (fewer than ``block`` tokens), which lies,
+        committed, at the start of the slot's first block, and the
+        request's first token arrives with a chunk (:meth:`deliver`)."""
         self.owner[b] = rid
+        if self.block:
+            self.last_tok[b] = -1
+            self.last_tok[b, :len(first)] = first
+            self.prefills += 1
+            return
         self.emitted[rid].append(first)
         if self.on_token is not None:
             self.on_token(rid, first)
@@ -725,10 +775,15 @@ class RequestBook:
         owes (``n_new`` less what it has emitted), 0 for a slot that
         owns none: at a chunk's start, the steps of the chunk in which
         the slot can deliver a token. An ``eos`` may end a request
-        sooner; it is then only not known to."""
-        return np.asarray([self.n_new[rid] - len(self.emitted[rid])
+        sooner; it is then only not known to. Of a block family the
+        POSITIONS it still owes: with them the prompt's tokens that its
+        next block holds (a block is live while it starts below)."""
+        owed = np.asarray([self.n_new[rid] - len(self.emitted[rid])
                            if rid >= 0 else 0 for rid in self.owner],
                           np.int32)
+        if self.block:
+            owed += np.where(owed > 0, (self.last_tok >= 0).sum(axis=1), 0)
+        return owed
 
     def slot_finished(self, b) -> bool:
         """Slot b's request has its ``n_new`` tokens, or ended on
@@ -738,35 +793,62 @@ class RequestBook:
                 or (self.eos is not None and bool(out)
                     and out[-1] == self.eos))
 
-    def deliver(self, block, step_dt):
+    def deliver(self, block, step_dt, at=None):
         """Consume one step's ``[chunk, B]`` token block, which took
         ``step_dt`` (each of the chunk's tokens shares it evenly). A
         slot that finishes mid-chunk idles: its further tokens are
-        valid continuations past the request's end, dropped."""
+        valid continuations past the request's end, dropped.
+
+        Of a block family the rows are POSITIONS, ``block`` to a block,
+        and ``at`` [chunk, B] the denoising step that committed each:
+        the first positions of a slot's first block after a seat hold
+        its prompt's tail and are nobody's token, a request that ends
+        inside a block leaves the rest of that block undelivered (both
+        are logged, ``block_log``), and a request's first token, and
+        with it its TTFT, arrives here."""
         self.steps += 1
         self.decode_slot_steps += block.shape[0] * self.n_slots
         reqlog.emit("decode_step", step=self.steps, dt_s=step_dt,
                     active=sum(o >= 0 for o in self.owner))
         itl = step_dt / self.chunk
+        W, delivered, logged = self.block, 0, 0
         for b in range(self.n_slots):
-            self.last_tok[b] = block[-1, b]
+            if W:
+                held = int((self.last_tok[b] >= 0).sum())
+                self.last_tok[b] = -1
+            else:
+                held = 0
+                self.last_tok[b] = block[-1, b]
             rid = self.owner[b]
             if rid < 0:
                 continue
             got = 0
             for c in range(block.shape[0]):
-                if self.slot_finished(b):
+                over = self.slot_finished(b)
+                if over and not (W and c % W):
                     break
                 tok = int(block[c, b])
+                if W:
+                    self.block_log[rid].append((tok, int(at[c, b])))
+                    logged += 1
+                if over or c < held:
+                    continue
                 self.emitted[rid].append(tok)
                 if self.on_token is not None:
                     self.on_token(rid, tok)
+                if self.ttft[rid] is None:
+                    self.ttft[rid] = time.perf_counter() - self.t0
+                    self.slo.note_ttft(self.ttft[rid])
+                    reqlog.emit("stream", rid, n=1, ttft_s=self.ttft[rid])
                 self.itl_samples.append(itl)
                 self.slo.note_itl(itl)
                 got += 1
             if got:
                 self.decode_tokens += got
+                delivered += got
                 reqlog.emit("stream", rid, n=got, itl_s=itl)
+        if W:
+            self.block_chunks.append((delivered, logged - delivered))
 
     def finish_request(self, b) -> int:
         """Slot b's request is done: its output is prompt + emitted and
@@ -805,7 +887,8 @@ class RequestBook:
                 latency_s=lat,
                 new_tokens=nt,
                 tokens_per_s=nt / lat if lat > 0 else 0.0,
-                retries=self.attempts[rid]))
+                retries=self.attempts[rid],
+                block_log=self.block_log[rid]))
         total_new = sum(r.new_tokens for r in per_request)
         qd, occ = self.qd_samples, self.occ_samples
         return cls(
@@ -1429,6 +1512,19 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
     count, where the tokens are consumed, how many of the chunks'
     slot-steps delivered one.
 
+    **A family that generates by diffusion over blocks**
+    (``kvpage.PagedSpec.block`` = W > 1) runs through the same loop, book,
+    pools, prefix cache and spans. What differs, all read from its spec:
+    ``chunk`` is a multiple of W (a program call runs ``chunk / W``
+    blocks a slot); a refill prefills the prompt's WHOLE BLOCKS (no head,
+    no token: ``refill.prefill`` waits for the pages) and seats its last
+    ``P mod W`` tokens in the slot's first block; a request's first token,
+    and its TTFT, arrive with a chunk; ``left`` counts positions; a
+    slot-step of the counters is a position (``ServingMetrics``:
+    ``forwards_denoise`` / ``forwards_store``, ``block_positions_kept`` /
+    ``_dead``, ``block_by_chunk``; a ``chunk.step`` record has
+    ``blocks``; ``RequestTelemetry.block_log``).
+
     **The record** (``metrics.spans``, always kept: about five spans a
     request and six a chunk). Beyond the annotation's ids the record of
     a ``refill.prefill`` has ``bucket`` (the padded length it ran at)
@@ -1483,16 +1579,24 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
                                 on_tpu=on_tpu, page_tokens=tail_pt)
 
     step_fn = kvpage.make_paged_step_fn(params, cfg, family, chunk, pt)
+    # A family that generates by diffusion over blocks (PagedSpec.block;
+    # 0: a token a slot a step): ``chunk`` stays the tokens a slot a
+    # program call, a whole number of blocks.
+    W = pkv.spec.block if pkv.spec.block > 1 else 0
+    assert not W or chunk % W == 0, \
+        f"chunk={chunk} must be a multiple of the family's block of {W}"
 
     keys = jax.random.split(jax.random.key(0), n_slots)  # greedy dummies
     # The requests and their rules (module docstring of RequestBook);
     # its clock ``t0`` starts here, after the set-up.
     book = RequestBook(prompts, n_new, n_slots, eos, chunk,
-                       max_request_retries, rejected, on_token=on_token)
+                       max_request_retries, rejected, on_token=on_token,
+                       block=W)
     queue, owner, slo = book.queue, book.owner, book.slo
     n_preempts = n_slo_defer = pages_walked = pages_dead = pages_grid = 0
     rewritten = staged = snapshot_seats = 0
     state_steps: List[int] = []     # delivering slot-steps, a chunk
+    walked_by_chunk: List[int] = []
     # Requests currently evicted by page pressure: membership here turns
     # the next successful seat into a journey "resume" event.
     preempted_rids: set = set()
@@ -1528,6 +1632,10 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
                 return False
             queue.popleft()
             S = len(prompt)
+            # What a prefill stores: the prompt, or of a block family
+            # its whole blocks (the rest goes into the first block
+            # generated, whose K/V depend on what is generated there)
+            body = S - S % W if W else S
             hit_pages = (pkv.prefix.match(prompt)
                          if pkv.prefix is not None else [])
             if hit_pages:
@@ -1555,30 +1663,42 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
                     # cached pages' gathered history (with state layers
                     # the match ends at a page that holds a snapshot).
                     P = len(hit_pages) * pt
-                    suffix = prompt[P:]
+                    suffix = prompt[P:body]
                     padded = _padded(suffix, max_len - P, cfg.max_seq - P)
                     hk, hv = pkv.gather_history(hit_pages)
                     args = (jnp.asarray(padded), hk, hv,
                             pkv.restore_tail(hit_pages[-1]), len(suffix) - 1)
                     run = suffix_prefill_fn
                 else:
-                    padded = _padded(prompt, max_len, cfg.max_seq)
-                    args, run = (jnp.asarray(padded), S - 1), prefill_fn
+                    padded = _padded(prompt[:body], max_len, cfg.max_seq)
+                    args, run = (jnp.asarray(padded), body - 1), prefill_fn
                 pre.ids.update(bucket=padded.shape[1],
                                hit_pages=len(hit_pages))
                 pre.hand_over()
-                logits, one = run(*args)
+                # (a block family's prompt may leave its prefill nothing:
+                # shorter than a block, or all of its whole blocks hit)
+                logits, one = run(*args) if args[-1] >= 0 else (None, {})
                 # The pages go to the pool (snapshots of the state at
                 # whole prompt pages' ends with them), the fixed state
                 # at the prompt's end to the slot.
                 end = one.get("end")
                 one = {k: v for k, v in one.items()
                        if k not in ("pos", "end")}
-                first = int(jnp.argmax(logits[0, 0]))   # the host waits
-                reqlog.emit("prefill_end", rid, first_token=first)
+                if W:
+                    # No token comes of a block family's prefill: the
+                    # prompt's tail goes, committed, into the slot's
+                    # first block. The host waits for the pages as it
+                    # waits for a token.
+                    first = prompt[body:]
+                    jax.block_until_ready(one)
+                else:
+                    first = int(jnp.argmax(logits[0, 0]))   # the host waits
+                reqlog.emit("prefill_end", rid,
+                            first_token=-1 if W else first)
             with ph("refill.scatter", rid=rid):
-                pkv.scatter_prompt(one, fresh,
-                                   whole=(S - len(hit_pages) * pt) // pt)
+                if one:
+                    pkv.scatter_prompt(one, fresh,
+                                       whole=(S - len(hit_pages) * pt) // pt)
         except Exception as exc:  # noqa: BLE001 — any device failure
             for p in hit_pages + fresh:
                 pkv.alloc.decref(p)
@@ -1588,7 +1708,7 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
             if spanned:
                 _span_app_end_best_effort()
         with ph("refill.seat", rid=rid):
-            pkv.seat(b, hit_pages, fresh, S, rid=rid, state=end)
+            pkv.seat(b, hit_pages, fresh, body, rid=rid, state=end)
             snapshot_seats += bool(hit_pages and pkv.snaps is not None)
             if pkv.prefix is not None:
                 pkv.prefix.insert(prompt, pkv.pages[b])
@@ -1605,8 +1725,7 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
         if owner[b] < 0 or not book.slot_finished(b):
             return False
         with ph("chunk.retire", step=book.steps, rid=owner[b]):
-            book.finish_request(b)
-            pkv.release(b)
+            pkv.release(b, rid=book.finish_request(b))
         return True
 
     def preempt(b):
@@ -1717,6 +1836,8 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
             state = pkv.device_state(left)
         with ph("chunk.step", step=step_no) as stepped:
             stepped.ids["rid"] = tuple(owner)
+            if W:
+                stepped.ids["blocks"] = chunk // W
             try:
                 last_tok = jnp.asarray(book.last_tok)
                 stepped.hand_over()
@@ -1731,9 +1852,12 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
                 pkv.reset_pool()
                 continue
             block = np.asarray(toks, np.int32)       # [chunk, B]: waits
+            # (under a block family's tokens the step that committed each)
+            block, at = block if W else (block, None)
         with ph("chunk.deliver", step=step_no):
             # The spans' readings are the step's time.
-            book.deliver(block, upload.seconds + stepped.seconds)
+            book.deliver(block, upload.seconds + stepped.seconds, at)
+            walked_by_chunk.append(walked)
         for b in range(n_slots):
             while retire_finished(b):
                 if queue:
@@ -1781,6 +1905,19 @@ def serve_paged_greedy(params, cfg, prompts: Sequence[np.ndarray], n_new,
             attend_pages_grid=pages_grid,
             kv_page_rewrites=rewritten,
             kv_tokens_staged=staged,
+            **({} if not W else dict(
+                block_length=W, denoise_steps=pkv.spec.denoise_steps,
+                forwards_denoise=(book.steps * (chunk // W)
+                                  * pkv.spec.denoise_steps),
+                forwards_store=book.steps * (chunk // W),
+                block_positions_kept=sum(k for _, k in book.block_chunks),
+                block_positions_dead=sum(
+                    chunk * n_slots - d - k for d, k in book.block_chunks),
+                block_by_chunk=[
+                    (chunk // W * pkv.spec.denoise_steps, chunk // W,
+                     chunk * n_slots, d, k, chunk * n_slots - d - k, w)
+                    for (d, k), w in zip(book.block_chunks,
+                                         walked_by_chunk)])),
             slo_deferrals=n_slo_defer,
             programs_traced=kvpage.programs_traced() - traced_at_entry)
         _request_paths(ph.spans, metrics.per_request, setup.t0)

@@ -810,3 +810,71 @@ def flash_rows_attention(q, k, v, q_offset: int = 0, scale=None,
         name="flash_rows_attention",    # what the device trace prints
     )(q, k, v)
 
+
+
+# --------------------------------------------------------------------------
+# Block-causal attention (appended, as the rows kernel above: the lines
+# before it stay where they were)
+
+
+def block_causal_reference(q, k, v, block: int):
+    """Dense-mask attention, [B, S, H, D] layout, f32 softmax: row ``i``
+    sees column ``j`` where ``j // block <= i // block`` (every earlier
+    position and the WHOLE of its own block: generation by diffusion
+    over blocks denoises a block's positions together)."""
+    S, d = q.shape[1], q.shape[-1]
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
+    at = jnp.arange(S) // block
+    logits = jnp.where((at[:, None] >= at[None, :])[None, None],
+                       logits / jnp.sqrt(d), _NEG_INF)
+    p = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+def block_causal_flash(q, k, v, block: int):
+    """:func:`block_causal_reference` through the causal flash kernel:
+    the block-causal mask is the causal one and, inside each block, the
+    columns AFTER a row (at most ``block - 1`` of them). The kernel gives
+    the causal part with its row logsumexp (:func:`flash_attention_lse`),
+    the rest is a ``[block, block]`` product a block, and the two merge
+    exactly by their logsumexps (that function's docstring). No kernel
+    of its own: a trace shows ``flash_attention_lse``."""
+    B, S, H, D = q.shape
+    assert S % block == 0, (S, block)
+    o1, lse1 = flash_attention_lse(q, k, v, causal=True)
+    n = S // block
+    qb, kb, vb = (t.reshape(B, n, block, H, D) for t in (q, k, v))
+    s = jnp.einsum("bnqhd,bnkhd->bnhqk", qb, kb).astype(
+        jnp.float32) / jnp.sqrt(D)
+    after = jnp.arange(block)[:, None] < jnp.arange(block)[None, :]
+    s = jnp.where(after, s, _NEG_INF)
+    lse2 = jax.scipy.special.logsumexp(s, axis=-1)        # [B, n, H, blk]
+    o2 = jnp.einsum("bnhqk,bnkhd->bnqhd",
+                    jnp.exp(s - lse2[..., None]), vb.astype(jnp.float32))
+    lse2 = lse2.transpose(0, 2, 1, 3).reshape(B, H, S)
+    lse = jnp.logaddexp(lse1, lse2)
+
+    def weight(part):                                     # [B, S, H, 1]
+        return jnp.exp(part - lse).transpose(0, 2, 1)[..., None]
+    # (a block's last row has no column after it: its ``lse2`` is the
+    # mask's -1e30 and its weight 0.0 on a finite ``o2``)
+    o = (o1.astype(jnp.float32) * weight(lse1)
+         + o2.reshape(B, S, H, D) * weight(lse2))
+    return o.astype(q.dtype)
+
+
+def select_block_attention(use_flash, block: int):
+    """:func:`select_attention` for the block-causal mask: ``None`` ->
+    the flash kernel where :func:`auto_attention` takes it (on a TPU, S
+    >= 1024 and a multiple of 128), ``True`` -> always, ``False`` -> the
+    dense reference. The returned callable takes ``(q, k, v)``."""
+    def auto(q, k, v):
+        S = q.shape[1]
+        flash = backend.on_tpu() and S >= 1024 and S % 128 == 0
+        return (block_causal_flash if flash
+                else block_causal_reference)(q, k, v, block)
+    if use_flash is None:
+        return auto
+    return functools.partial(
+        block_causal_flash if use_flash else block_causal_reference,
+        block=block)

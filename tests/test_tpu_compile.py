@@ -1344,3 +1344,103 @@ def test_the_train_step_at_tp_one_runs_no_ring(v5e):
     loops = sorted(where[k] for k in fwd)
     assert len(set(loops)) == 2 and where[bwd[0]] in loops, where
     assert [trips(b) for b in loops] == [c["n_layer"]] * 2
+
+
+# -- a step that yields a block, not a token (PR 48) --------------------------
+
+SDAR_B, SDAR_LEN, SDAR_CHUNK = 96, 1408, 16
+SDAR_PAGES = SDAR_B * SDAR_LEN // PAGE + SDAR_B             # + parking pages
+
+
+def _sdar_cut():
+    """The benchmark's cut of SDAR-30B-A3B-Chat at published widths: one
+    pipeline stage of eight (6 of 48 layers, every one of the 128
+    experts, the whole vocabulary), bf16 weights. Shapes only."""
+    from mpi_acx_tpu.models import sdar
+    cfg = sdar.SdarConfig(n_layers=6)
+    params = jax.eval_shape(lambda: sdar.cast_params(
+        sdar.init_params(jax.random.key(0), cfg)))
+    return sdar, cfg, params
+
+
+def _sdar_chunk(v5e):
+    sdar, cfg, params = _sdar_cut()
+    spec = kvpage.paged_spec(sdar, cfg)
+    pool = jax.eval_shape(lambda: kvpage.init_page_pool(
+        cfg, SDAR_PAGES - SDAR_B, PAGE, SDAR_B, spec=spec))
+    state = dict(k=pool["k"], v=pool["v"],
+                 table=_s((SDAR_B, SDAR_LEN // PAGE), jnp.int32),
+                 pos=_s((SDAR_B,), jnp.int32), left=_s((SDAR_B,), jnp.int32),
+                 owns=_s((SDAR_B,), jnp.bool_), moe=_s((5,), jnp.int32))
+    keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0), SDAR_B))
+    step = kvpage.make_paged_step_fn(params, cfg, sdar, SDAR_CHUNK, PAGE)
+    args = [*step.args, state, _s((SDAR_B, 4), jnp.int32), keys]
+    return step, args, pool
+
+
+def test_sdar_block_chunk_compiles_and_moves_no_pool(v5e):
+    """``paged_decode_chunk``'s block arm as a serve call binds it for
+    the SDAR cell's geometry (96 slots, 1,152 pages of 128 tokens, chunk
+    16 = 4 blocks of 4 positions, 4 denoising forwards and a storing one
+    a block): ONE copy of the layers in the program (the five forwards
+    of a block are turns of one scan, the head under a conditional), the
+    attend the token step's call with a block's 4 x 8 query rows a K/V
+    head folded into one position's 32 (``bf16[96,4,32,128]``), the
+    expert layer's three grouped matmuls at 96 x 4 x 8 = 3,072 rows with
+    the result shapes the benchmark's reader matches, the flush ONE
+    write a layer behind the blocks, a filled stage, no instruction that
+    moves a pool or an expert stack, and temporaries far below a chip
+    (the logits of a forward are 0.23 GB)."""
+    step, args, pool = _sdar_chunk(v5e)
+    assert pool["k"].shape == (6, SDAR_PAGES, 4, 128, PAGE)
+    compiled = step.func.lower(*_place(args, v5e), **step.keywords).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"%([a-z_]+)[.0-9]* = (\(?[a-z0-9]+\[[0-9,]*\])",
+                       "\n".join(l for l in text.splitlines()
+                                 if "tpu_custom_call" in l))
+    assert sorted(calls) == sorted(
+        [("gmm", "f32[3072,768]")] * 2 + [("gmm", "f32[3072,2048]"),
+         ("paged_flash_decode_attend", "bf16[96,4,32,128]"),
+         ("paged_kv_write", "(bf16[6,1152,4,128,128]")])
+    assert not _pool_movers(text, pool["k"].shape), \
+        "\n".join(_pool_movers(text, pool["k"].shape))
+    made = jax.eval_shape(lambda: flash_decode.new_kv_stage(
+        [pool["k"], pool["v"]], SDAR_B, SDAR_CHUNK))
+    shapes = ["[" + ",".join(map(str, a.shape)) + "]" for a in made]
+    unfilled = [l.strip()[:160] for l in text.splitlines()
+                if "AllocateBuffer" in l
+                and any(s in l.split(" custom-call(")[0] for s in shapes)]
+    assert not unfilled, "\n".join(unfilled)
+    stack = re.compile(r" = bf16\[(?:6,)?(?:128|768),(?:2048,768|768,2048)\]"
+                       r".*?\s(?!(?:parameter|get-tuple-element|bitcast)\()"
+                       r"[a-z][a-z0-9-]*\(")
+    moved = [l.strip()[:160] for l in text.splitlines() if stack.search(l)]
+    assert not moved, "\n".join(moved)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    # the flush is behind the blocks: the write outside every loop, the
+    # attend under blocks x forwards x layers
+    jaxpr = jax.make_jaxpr(functools.partial(
+        step.func.__wrapped__, **step.keywords))(*args).jaxpr
+    depths = _loop_depths(jaxpr)
+    assert depths["paged_kv_write"] == [1]
+    assert depths["paged_flash_decode_attend"] == [3]
+
+
+@pytest.mark.parametrize("bucket", [64, 512, 1024])
+def test_sdar_prefill_compiles_for_v5e(bucket, v5e):
+    """``serving.paged_prefill`` for the family at the smallest bucket
+    the cell reaches, the largest that is attended densely and the one
+    that takes the flash kernel (the causal kernel with its logsumexp,
+    merged with the columns after a row inside its block): the grouped
+    matmuls over 8 x bucket sorted rows, NO head (no ``[1, 151936]``
+    logits: a block family's prefill hands out no token), and
+    temporaries that fit beside 8.7 GB of weights and 1.8 GB of
+    pages."""
+    sdar, cfg, params = _sdar_cut()
+    fn, args, kw = _prefill_call(sdar, cfg, params, bucket, 0, PAGE)
+    compiled = fn.lower(*_place(args, v5e), on_tpu=True, **kw).compile()
+    text = compiled.as_text()
+    assert f"f32[{8 * bucket},768]" in text and "%gmm" in text
+    assert ("%flash_attention_lse" in text) == (bucket == 1024)
+    assert "f32[1,1,151936]" not in text and ",151936]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
