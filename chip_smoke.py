@@ -26,7 +26,9 @@ One chip:
                Pallas flag kernel, rank 1 on the CPU receives
   train        train.make_train_step on a one-device mesh, three steps at
                B=8 S=512 and one at S=1024 (flash attention's backward);
-               a ``tp`` axis of one runs no ring: ``attn_direct_calls``
+               a ``tp`` axis of one runs no ring: ``attn_direct_calls``;
+               on the flash path (S=1024) the remat layer keeps the
+               kernel's ``o`` and ``lse``: ``flash_residuals_named``
 Four chips (``--chips 4``):
   tp_serve     make_tp_server_fns at tp=4 under serve_greedy, against the
                one-device serve in the same process
@@ -861,10 +863,15 @@ def _attn_calls():
     """ring_attention.attention_calls_traced() under the names a train
     phase prints: a program's attention calls are all direct (a ``tp``
     axis of one) or all a ring, so over a phase one of the two stands
-    still."""
+    still. Beside them ``flash_residuals_named``: the direct flash
+    calls whose forward rule named its ``o`` and ``lse`` for the remat
+    layer's policy to keep (none: the backward runs the kernel again)."""
+    from mpi_acx_tpu.ops.attention import flash_residuals_named_traced
     from mpi_acx_tpu.parallel.ring_attention import attention_calls_traced
-    return {f"attn_{k}_calls": n
-            for k, n in attention_calls_traced().items()}
+    calls = {f"attn_{k}_calls": n
+             for k, n in attention_calls_traced().items()}
+    calls["flash_residuals_named"] = flash_residuals_named_traced()
+    return calls
 
 
 def _train_run(cfg, mesh, params, tokens, targets, steps, lr=0.1):
@@ -893,6 +900,7 @@ def phase_train(size: Size = FULL, seed: int = 0, require_kernel=True):
 
     from mpi_acx_tpu.models import transformer as tfm
     from mpi_acx_tpu.parallel.mesh import mesh_from_devices
+    from mpi_acx_tpu.parallel.ring_attention import FLASH_MIN_SHARD
     cfg, _ = _model(size, seed)
     params = tfm.init_params(jax.random.key(seed), cfg)     # f32 masters
     mesh = mesh_from_devices({"dp": 1, "pp": 1, "tp": 1}, jax.devices()[:1])
@@ -915,6 +923,9 @@ def phase_train(size: Size = FULL, seed: int = 0, require_kernel=True):
         _require(calls["attn_direct_calls"] > 0
                  and calls["attn_ring_calls"] == 0,
                  f"a one-device mesh traced a ring: {calls}")
+        if require_kernel and S >= FLASH_MIN_SHARD:
+            _require(calls["flash_residuals_named"] > 0,
+                     f"the flash path at S={S} named no residual: {calls}")
         emit(phase=f"train/S{S}", ok=True, batch=2 * mb, seq=S, steps=steps,
              losses=[round(x, 4) for x in losses],
              loss_fn_reference=round(want, 4), **calls, **w.row())

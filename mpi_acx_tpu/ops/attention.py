@@ -15,13 +15,13 @@ probabilities from the forward's row logsumexp and applies the standard
 flash-backward formulas (dS = P * (dP - rowsum(dO*O))) with the
 forward's conventions (MXU operands in the input dtype, f32
 accumulation, causal blocks above the diagonal skipped by trip count).
-Nothing of size [S, S] or [block, block] reaches HBM, so training
-differentiates straight through the Pallas calls. Under differentiation
-plain :func:`flash_attention` runs the lse-emitting forward, so the
-backward has no lse sweep; the inference forward is untouched. From the
-streaming forward's lengths on (a head no longer fits VMEM) the backward
-is :func:`_flash_bwd_blockwise`, the same formulas as two nested loops
-in plain JAX, which is also the definition the tests hold the kernel to.
+Nothing of size [S, S] or [block, block] reaches HBM. Under
+differentiation plain :func:`flash_attention` runs the lse-emitting
+forward (no lse sweep in the backward; the inference forward is
+untouched) and NAMES its two residuals only the kernel can produce, o
+and lse (:data:`FLASH_RESIDUALS`), so that a remat layer's policy can
+keep them and run no second forward. Past the resident kernel's lengths
+the backward is :func:`_flash_bwd_blockwise`, two loops in plain JAX.
 
 :func:`flash_attention_lse` is the variant that DOES emit the row
 logsumexp — packed into one extra lane column of a single output — and
@@ -351,13 +351,13 @@ def _flash(qt, kt, vt, causal, block_q, block_k, streaming=False):
 
 def _flash_vjp_fwd(qt, kt, vt, causal, block_q, block_k, streaming=False):
     # Runs only under differentiation: the resident forward then emits
-    # its lse (the packed kernel), so the backward has no lse sweep. The
-    # streaming kernel emits none and keeps the blockwise backward.
+    # its lse (no lse sweep in the backward; the streaming kernel emits
+    # none). What only the kernel can produce is named for a remat policy.
     if streaming:
         o = _flash_stream_fwd_impl(qt, kt, vt, causal, block_q, block_k)
-        return o, (qt, kt, vt, o, None)
+        return _fwd_named(qt, kt, vt, o)
     o, lse = _flash_lse_fwd_impl(qt, kt, vt, causal, block_q, block_k)
-    return o, (qt, kt, vt, o, lse)
+    return _fwd_named(qt, kt, vt, o, lse)
 
 
 def _flash_bwd_blockwise(qt, kt, vt, o, do, causal, block_q, block_k,
@@ -878,3 +878,42 @@ def select_block_attention(use_flash, block: int):
     return functools.partial(
         block_causal_flash if use_flash else block_causal_reference,
         block=block)
+
+
+# The two residuals of plain flash attention's backward that only the
+# forward kernel can produce, as a checkpoint policy names them
+# (``jax.checkpoint_policies.save_only_these_names(*FLASH_RESIDUALS)``:
+# train.make_stage_fn). Down here with its counter so that no kernel
+# above moves (a Mosaic program's cache key holds its line numbers).
+FLASH_RESIDUALS = ("flash_o", "flash_lse")
+
+_residuals_named = [0]
+
+
+def flash_residuals_named_traced() -> int:
+    """How many times this process has TRACED :func:`flash_attention`'s
+    forward rule, which names its residuals (:data:`FLASH_RESIDUALS`):
+    none under inference, and once for all the differentiated calls of
+    one shape (``jit`` keeps the trace): all or nothing per program."""
+    return _residuals_named[0]
+
+
+def _fwd_named(qt, kt, vt, o, lse=None):
+    """What :func:`_flash_vjp_fwd` returns, ``o`` and ``lse`` under their
+    names: ``o`` AFTER the cut from the packed output (the packed
+    float32 array is not what a policy keeps) and as ``[B, S, H*D]``
+    rows, the layout the caller reads it in (as ``[B, H, S, D]`` a scan
+    stacks a 64-wide head padded to 128 lanes, twice the bytes; the
+    transposes here cancel against :func:`flash_attention`'s own);
+    ``lse`` f32 ``[B, H, S]`` or None (streaming). The primal output is
+    the NAMED ``o``, or a remat pass would run the kernel again for it.
+    Outside a ``jax.checkpoint`` a name is the identity."""
+    from jax.ad_checkpoint import checkpoint_name
+    _residuals_named[0] += 1
+    B, H, S, D = o.shape
+    rows = checkpoint_name(o.transpose(0, 2, 1, 3).reshape(B, S, H * D),
+                           FLASH_RESIDUALS[0])
+    o = rows.reshape(B, S, H, D).transpose(0, 2, 1, 3)
+    if lse is not None:
+        lse = checkpoint_name(lse, FLASH_RESIDUALS[1])
+    return o, (qt, kt, vt, o, lse)

@@ -5,7 +5,9 @@ the reference's pipeline-exchange driver configs):
 
 * **pp** — pipeline stages over the 'pp' mesh axis; microbatch activations
   travel stage->stage by collective permute
-  (mpi_acx_tpu.parallel.pipeline).
+  (mpi_acx_tpu.parallel.pipeline). At a 'pp' axis of ONE (GPipe, no
+  virtual stages) nothing travels: the stage is called once a
+  micro-batch, with no scan over ticks and no permute.
 * **tp + sp** — inside each stage, attention runs sequence-parallel over
   the 'tp' axis with ring attention (K/V rotating on ICI), and the MLP
   runs tensor-parallel with the FFN dim sharded over 'tp' and one psum.
@@ -273,13 +275,22 @@ def make_loss_and_grads(cfg, mesh: Mesh, n_micro: int, n_virtual: int = 1,
     (bubble / n_virtual; needs n_micro % pp == 0). tokens/targets:
     [n_micro, micro_batch, S] int32, batch over 'dp'.
 
-    ``remat=True`` wraps each layer body in ``jax.checkpoint``: the
-    backward pass recomputes block activations (including the ring
-    attention and its collectives) instead of keeping them live through
-    the whole pipeline scan — activation memory drops from O(layers) to
-    O(1) blocks per stage for ~1/3 more FLOPs, the standard trade when
-    HBM, not the MXU, is the binding constraint. Gradients are the same
-    function, so the exact-match tests hold with remat on
+    ``remat=True`` wraps each layer body in ``jax.checkpoint`` and keeps
+    a layer's INPUT and its ATTENTION KERNEL'S OUTPUT: the backward pass
+    recomputes the block's activations (layer norms, q/k/v, the MLP; at
+    ``tp >= 2`` the ring attention and its collectives too) instead of
+    keeping them live through the whole pipeline scan, but where the
+    layer calls the flash kernel directly (``tp = 1`` on the flash path)
+    the kernel's ``o`` and ``lse`` are saved by name
+    (``ops.attention.FLASH_RESIDUALS``) and the backward runs no second
+    attention forward. That costs ``n_layer x n_micro x (B*S*d*2 +
+    B*H*S*4)`` bytes a stage (B a micro-batch) beside the layer inputs'
+    ``n_layer x n_micro x B*S*d*2``; a ring of two or more and the dense
+    path name nothing and are recomputed whole. The recompute is one
+    more forward of the blocks less that kernel: about a quarter more
+    FLOPs than no remat (a third where nothing is kept), the standard
+    trade when HBM, not the MXU, is the binding constraint. Gradients
+    are the same function, so the exact-match tests hold with remat on
     (tests/test_train.py).
 
     ``dp_quant_bits=8`` replaces the exact dp-gradient pmean with the
@@ -355,7 +366,13 @@ def make_loss_and_grads(cfg, mesh: Mesh, n_micro: int, n_virtual: int = 1,
     def make_stage_fn():
         layer_fn = lambda lp, h: fam.block(cfg, lp, h, "tp")  # noqa: E731
         if remat:
-            layer_fn = jax.checkpoint(layer_fn)
+            # Keeps what only the attention kernel can produce (the
+            # direct flash call's o and lse, where the layer has one);
+            # a layer with no such name is recomputed whole.
+            from mpi_acx_tpu.ops.attention import FLASH_RESIDUALS
+            layer_fn = jax.checkpoint(
+                layer_fn, policy=jax.checkpoint_policies
+                .save_only_these_names(*FLASH_RESIDUALS))
         if fam.has_aux:
             def stage_fn(stage_layers, h):
                 def body(carry, lp):
@@ -388,6 +405,22 @@ def make_loss_and_grads(cfg, mesh: Mesh, n_micro: int, n_virtual: int = 1,
                 ys = pipeline_forward_interleaved(
                     stage_fn, params["layers"], x, "pp", n_virtual,
                     with_aux=fam.has_aux)
+            elif n_stages == 1:
+                # One stage hands nothing on: the stage once a
+                # micro-batch in the trace, no scan over ticks. Each
+                # micro-batch's residuals stay the layer scan's own
+                # arrays; stacked by a scan they cost a copy of a
+                # micro-batch's slice each way and put the remat step
+                # over the TPU compiler's memory budget (PERF.md, PR 49).
+                stage_layers = jax.tree.map(lambda p: p[0], params["layers"])
+                outs = [stage_fn(stage_layers, x[m])
+                        for m in range(x.shape[0])]
+                if fam.has_aux:
+                    ys = (jnp.stack([y for y, _ in outs]),
+                          jax.tree.map(lambda *a: sum(a),
+                                       *[a for _, a in outs]))
+                else:
+                    ys = jnp.stack(outs)
             else:
                 ys = pipeline_forward(stage_fn, params["layers"], x, "pp",
                                       with_aux=fam.has_aux)

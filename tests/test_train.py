@@ -108,9 +108,8 @@ def _ppermute_axes(jaxpr, out=None):
 def test_a_tp_axis_of_one_permutes_nothing(setup, remat):
     """At ``tp = 1`` the step's jaxpr, backward included, holds no
     ``ppermute`` over 'tp' (no ring of one) and traces every attention
-    call direct; at ``tp = 2`` the ring is there as before. (The one
-    ``ppermute`` a ``pp`` axis of one keeps is the pipeline's, with an
-    empty ``perm``.)"""
+    call direct; at ``tp = 2`` the ring is there as before. Nor is
+    there one over 'pp': a pipeline of one stage hands nothing on."""
     from mpi_acx_tpu.parallel.ring_attention import attention_calls_traced
     cfg, _, params, tokens, targets = setup
 
@@ -126,11 +125,140 @@ def test_a_tp_axis_of_one_permutes_nothing(setup, remat):
                 {k: after[k] - before[k] for k in after})
 
     axes, calls = traced(1)
-    assert ("tp",) not in axes and set(axes) <= {("pp",)}, axes
+    assert axes == [], axes              # nor over 'pp': one stage
     assert calls["direct"] > 0 and calls["ring"] == 0, calls
     axes, calls = traced(2)
     assert ("tp",) in axes, axes
     assert calls["ring"] > 0 and calls["direct"] == 0, calls
+
+
+def _primitive_counts(jaxpr, out=None):
+    """primitive name -> equations, a jaxpr's nested bodies included; a
+    ``pallas_call`` is counted under its kernel's name too
+    (``pallas_call:attn_bwd``; the forward kernels have none)."""
+    out = {} if out is None else out
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "pallas_call":
+            name += f":{eqn.params.get('name')}"
+        out[name] = out.get(name, 0) + 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _primitive_counts(sub, out)
+    return out
+
+
+def _remat_variants(cfg, mesh, params, tokens, targets, schedule,
+                    monkeypatch, variants=("plain", "bare", "kept")):
+    """{variant: (primitive counts of the gradient's jaxpr, (loss,
+    grads), forward rules that named their residuals while it was
+    traced)} for ``remat=False`` ("plain"), a bare ``jax.checkpoint``
+    ("bare": the policy taken away) and the step as it is ("kept")."""
+    from mpi_acx_tpu.ops.attention import flash_residuals_named_traced
+    from mpi_acx_tpu.train import make_loss_and_grads
+    out = {}
+    for variant in variants:
+        with monkeypatch.context() as m:
+            if variant == "bare":
+                m.setattr(jax.checkpoint_policies, "save_only_these_names",
+                          lambda *names: None)
+            jax.clear_caches()       # jit keeps flash_attention's traces
+            fn, n_stages = make_loss_and_grads(
+                cfg, mesh, n_micro=tokens.shape[0],
+                remat=variant != "plain", schedule=schedule)
+            staged = tfm.stage_slice(params, n_stages)
+            before = flash_residuals_named_traced()
+            jaxpr = jax.make_jaxpr(fn)(staged, tokens, targets).jaxpr
+            named = flash_residuals_named_traced() - before
+            out[variant] = (_primitive_counts(jaxpr),
+                            jax.tree.map(np.asarray,
+                                         fn(staged, tokens, targets)), named)
+    return out
+
+
+def _assert_bit_equal(got, want, what):
+    for (path, a), b in zip(jax.tree.leaves_with_path(got),
+                            jax.tree.leaves(want)):
+        np.testing.assert_array_equal(
+            a, b, err_msg=f"{jax.tree_util.keystr(path)} differs from "
+            f"{what}")
+
+
+def _assert_same_to_rounding(got, want, what):
+    """A remat program and ``remat=False`` are two compilations: XLA's
+    CPU backend sums a layer norm's gain gradient in another order
+    (parts in 1e-10 of values of 1e-3), whatever the policy."""
+    for (path, a), b in zip(jax.tree.leaves_with_path(got),
+                            jax.tree.leaves(want)):
+        np.testing.assert_allclose(
+            a, b, rtol=1e-5, atol=1e-8,
+            err_msg=f"{jax.tree_util.keystr(path)} differs from {what}")
+
+
+def _tiny(family, use_flash):
+    import dataclasses
+
+    from mpi_acx_tpu.models import llama as lm
+    if family == "llama":
+        cfg = lm.tiny_llama(vocab=89, d_model=64, n_heads=4, n_kv_heads=2,
+                            n_layers=2, d_ff=96, max_seq=32)
+        params = lm.init_params(jax.random.key(3), cfg)
+    else:
+        cfg = tfm.tiny_config(vocab=97, d_model=64, n_heads=4, n_layers=2,
+                              d_ff=128, max_seq=32)
+        params = tfm.init_params(jax.random.key(3), cfg)
+    tokens = jax.random.randint(jax.random.key(4), (2, 2, 16), 0, cfg.vocab)
+    return (dataclasses.replace(cfg, use_flash=use_flash), params, tokens,
+            jnp.roll(tokens, -1, axis=-1))
+
+
+@pytest.mark.parametrize("schedule", ["gpipe", "1f1b"])
+@pytest.mark.parametrize("family", ["gpt2", "llama"])
+def test_a_remat_layer_keeps_the_flash_kernels_output(family, schedule,
+                                                      monkeypatch):
+    """On the flash path at ``tp = 1`` the remat layer's policy keeps
+    the kernel's ``o`` and ``lse`` (named by the forward rule), so the
+    gradient's jaxpr holds as many forward kernels as ``remat=False``
+    does, where a bare ``jax.checkpoint`` holds one more a layer body
+    (the backward running the forward again for the VJP's residuals);
+    the backward kernel is there once in all three; loss and gradients
+    are bit-equal to the bare checkpoint's and equal to rounding to
+    ``remat=False``'s."""
+    cfg, params, tokens, targets = _tiny(family, use_flash=True)
+    mesh = mesh_from_devices({"dp": 1, "pp": 1, "tp": 1})
+    got = _remat_variants(cfg, mesh, params, tokens, targets, schedule,
+                          monkeypatch)
+    fwd = {v: got[v][0]["pallas_call:None"] for v in got}
+    bwd = {v: got[v][0]["pallas_call:attn_bwd"] for v in got}
+    # Layer bodies in the jaxpr: ``gpipe`` at ``pp = 1`` traces the stage
+    # once a micro-batch (2 forward, 2 backward), ``1f1b`` one slot body
+    # that runs the stage forward and, under ``jax.vjp``, once more.
+    assert (fwd["plain"], bwd["plain"]) == (
+        (2, 2) if schedule == "gpipe" else (2, 1)), (fwd, bwd)
+    assert fwd["kept"] == fwd["plain"], fwd
+    assert fwd["bare"] == fwd["plain"] + bwd["plain"], fwd
+    assert len(set(bwd.values())) == 1, bwd
+    assert got["kept"][2] > 0, "no forward rule named its residuals"
+    _assert_bit_equal(got["kept"][1], got["bare"][1], "a bare checkpoint")
+    _assert_same_to_rounding(got["kept"][1], got["plain"][1], "remat=False")
+
+
+@pytest.mark.parametrize("tp,use_flash", [(2, True), (2, False), (1, False)],
+                         ids=["ring_flash", "ring_dense", "dense"])
+def test_the_policy_saves_nothing_it_was_not_given(tp, use_flash,
+                                                   monkeypatch):
+    """A ring of two (``flash_attention_lse`` inside ``scan`` /
+    ``switch``) and the dense path name nothing: their remat step has
+    the primitives of a bare ``jax.checkpoint``'s, one for one (the
+    backward's attention calls among them), no forward rule names a
+    residual, and the gradients are bit-equal."""
+    cfg, params, tokens, targets = _tiny("gpt2", use_flash)
+    mesh = mesh_from_devices({"dp": 1, "pp": 1, "tp": tp})
+    got = _remat_variants(cfg, mesh, params, tokens, targets, "gpipe",
+                          monkeypatch, variants=("bare", "kept"))
+    assert got["kept"][0] == got["bare"][0]
+    assert (got["bare"][2], got["kept"][2]) == (0, 0)
+    assert ("pallas_call:None" in got["kept"][0]) == use_flash
+    _assert_bit_equal(got["kept"][1], got["bare"][1], "a bare checkpoint")
 
 
 def test_interleaved_schedule_matches_sequential(setup):
